@@ -1,10 +1,11 @@
 #!/usr/bin/env python
 """Full-forward breakdown: where the bge-large N=64/s=128 milliseconds go.
 
-Times ``bert.embed`` on the real chip with each cost candidate swapped
-out (monkeypatched) so the device-only budget is attributable:
-attention impl (einsum vs tiled Pallas), GELU (exact erf vs tanh vs
-identity), layernorm (real vs identity).  Grounds VERDICT r3 item 1.
+Times ``bert.embed`` with each cost candidate swapped out
+(monkeypatched) so the device-only budget is attributable: attention
+impl (einsum vs tiled Pallas), GELU (exact erf vs tanh vs identity),
+layernorm (real vs identity).  Runs in bf16 on the devices JAX gives it
+and names them in the record.
 """
 
 from __future__ import annotations
@@ -50,21 +51,17 @@ def main():
     p.add_argument("--model", default="bge-large-en")
     p.add_argument("--b", type=int, default=64)
     p.add_argument("--seq", type=int, default=128)
-    p.add_argument("--probe-timeout", type=float, default=240.0)
     args = p.parse_args()
-    # wedge-proofing: shared bounded-probe preamble (bench.probe_or_exit)
-    # AFTER argparse so --help stays instant
-    from bench import probe_or_exit
-
-    probe_or_exit(args.probe_timeout)
 
     import dataclasses
+
+    from llm_weighted_consensus_tpu.utils import device_summary
 
     from llm_weighted_consensus_tpu.models import bert
     from llm_weighted_consensus_tpu.models.configs import PRESETS
 
     config = PRESETS[args.model]
-    dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
+    dtype = jnp.bfloat16
     params = bert.init_params(jax.random.PRNGKey(0), config, dtype=dtype)
     rng = np.random.default_rng(0)
     ids = jnp.asarray(
@@ -99,7 +96,7 @@ def main():
     out["ln=identity"] = run(cfg)
     bert._layer_norm = real_ln
 
-    out["backend"] = jax.default_backend()
+    out.update(device_summary())
     print(json.dumps(out))
 
 

@@ -6,10 +6,13 @@ resource budgets, everything needed to compute each AOT bucket's
 speed-of-light (SoL) time on a given backend:
 
 * ``peaks`` — PER-CHIP peak compute (``flops_per_sec``) and HBM
-  bandwidth (``hbm_bytes_per_sec``) per jax backend name.  The ``tpu``
-  row is TPU v5e (bf16 peak ~197 TFLOP/s, ~819 GB/s HBM per chip); the
-  ``cpu`` row is a deliberately rough laptop-class figure so the gauge
-  stays meaningful (and testable) on the CPU-simulated stack.
+  bandwidth (``hbm_bytes_per_sec``) keyed by ``device_kind``, the string
+  ``jax.devices()[0].device_kind`` reports.  ``TPU v5 lite`` is the v5e
+  (bf16 peak 197 TFLOP/s, 819 GB/s HBM per chip, Google Cloud's "TPU
+  v5e" page); a kind with no row gets no ``sol_ms``/``attainment``
+  (``known_peaks: false``), never another chip's numbers.  The ``cpu``
+  row is a deliberately rough laptop-class figure so the gauge stays
+  testable on the CPU-simulated stack.
 * ``buckets`` — per-bucket ``flops`` / ``bytes_accessed`` from XLA's
   ``cost_analysis``, the same figures the mesh audit measures for the
   budgets file.  Rows are committed mesh-shape-free: the runtime gauge
@@ -53,12 +56,13 @@ ROOFLINE_METRICS = ("flops", "bytes_accessed")
 DEFAULT_TOLERANCE = 0.25  # same band rationale as budgets.py
 
 # Committed starting peaks, used when --write-roofline creates the file
-# from scratch.  Per chip.  tpu = v5e: 394 TFLOP/s int8 / ~197 bf16; we
-# commit the bf16 figure because the serving matmuls are bf16/f32 with
-# only the int8-pallas path below it.  cpu = rough one-core-ish figure
-# so CPU-simulated runs report a stable, obviously-not-TPU attainment.
+# from scratch.  Per chip, keyed by device_kind.  "TPU v5 lite" = v5e:
+# 394 TOP/s int8 / 197 TFLOP/s bf16; we commit the bf16 figure because
+# the serving matmuls are bf16/f32 with only the int8-pallas path below
+# it.  cpu = rough one-core-ish figure so CPU-simulated runs report a
+# stable, obviously-not-TPU attainment.
 DEFAULT_PEAKS = {
-    "tpu": {"flops_per_sec": 1.97e14, "hbm_bytes_per_sec": 8.19e11},
+    "TPU v5 lite": {"flops_per_sec": 1.97e14, "hbm_bytes_per_sec": 8.19e11},
     "cpu": {"flops_per_sec": 5.0e10, "hbm_bytes_per_sec": 2.0e10},
 }
 
@@ -151,8 +155,8 @@ def compare_roofline(
         )
         return findings
     peaks = roofline.get("peaks", {})
-    for backend in ("tpu", "cpu"):
-        row = peaks.get(backend, {})
+    for kind in sorted(set(peaks) | set(DEFAULT_PEAKS)):
+        row = peaks.get(kind, {})
         if not all(float(row.get(k, 0)) > 0 for k in (
             "flops_per_sec", "hbm_bytes_per_sec"
         )):
@@ -161,11 +165,11 @@ def compare_roofline(
                     rule="JXA013",
                     path="analysis/roofline.json",
                     line=0,
-                    symbol=backend,
+                    symbol=kind,
                     message=(
-                        f"peaks entry for backend `{backend}` is missing or "
-                        "non-positive; the attainment gauge needs per-chip "
-                        "flops_per_sec and hbm_bytes_per_sec"
+                        f"peaks entry for device kind `{kind}` is missing "
+                        "or non-positive; the attainment gauge needs "
+                        "per-chip flops_per_sec and hbm_bytes_per_sec"
                     ),
                 )
             )
@@ -266,12 +270,13 @@ def write_roofline(
 class RooflineGauge:
     """The live ``roofline`` /metrics section: per observed
     (mesh-shape, bucket) device-time key, SoL time for the serving
-    backend and ``attainment = sol_ms / device_p50_ms``."""
+    device kind and ``attainment = sol_ms / device_p50_ms`` — both only
+    when the peaks table has a row for that kind."""
 
-    def __init__(self, roofline: dict, backend: str) -> None:
-        self._peaks = roofline.get("peaks", {}).get(backend)
+    def __init__(self, roofline: dict, device_kind: str) -> None:
+        self._peaks = roofline.get("peaks", {}).get(device_kind)
         self._buckets = roofline.get("buckets", {})
-        self._backend = backend
+        self._device_kind = device_kind
 
     def snapshot(self) -> dict:
         from ..obs import phases as _phases
@@ -292,7 +297,7 @@ class RooflineGauge:
                         row["attainment"] = round(sol / p50, 4)
             rows[label] = row
         return {
-            "backend": self._backend,
+            "device_kind": self._device_kind,
             "known_peaks": self._peaks is not None,
             "buckets": rows,
         }

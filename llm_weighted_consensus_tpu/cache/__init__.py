@@ -11,7 +11,7 @@ fan-out instead of two.  Modules:
   (JSON field order never changes the key);
 * ``store``        — in-memory LRU with TTL + byte budget, optional
   append-only JSONL disk tier for warm restarts (the XLA compile-cache
-  pattern, serve/config.py COMPILE_CACHE_DIR);
+  pattern, serve/config.py configure_compile_cache);
 * ``singleflight`` — concurrent same-fingerprint requests collapse onto
   one in-flight computation (asyncio future per key);
 * ``replay``       — record a streamed score response's chunk frames and

@@ -8,7 +8,7 @@ the head; TTL is enforced lazily on lookup (an expired entry counts as a
 miss and is dropped).
 
 The disk tier mirrors the XLA compile-cache pattern the service already
-uses for jit specializations (serve/config.py COMPILE_CACHE_DIR): warm
+uses for jit specializations (serve/config.py configure_compile_cache): warm
 restarts reload previously computed results instead of recomputing them.
 Each store instance appends to its own JSONL segment (one JSON object per
 line: ``{"k": fingerprint, "e": expiry, "v": value}``); on startup every

@@ -27,16 +27,16 @@ included), so single-device, mesh, and ring executables for the same
 bucket shapes can never collide.
 
 Every path fails open: an unreadable, truncated, or version-skewed
-artifact returns None and the caller compiles exactly as before the
-store existed.  Writes are atomic (tmp + rename) so a replica crashing
-mid-save never poisons a peer.
+artifact returns None — logged once per store with the reason — and the
+caller compiles exactly as before the store existed.  Writes are atomic
+(tmp + rename) so a replica crashing mid-save never poisons a peer.
 """
 
 from __future__ import annotations
 
+import logging
 import os
 import pickle
-from typing import Optional
 
 from ..identity import hash_json_obj, id_string
 
@@ -56,25 +56,43 @@ class AotStore:
         self.saves = 0
         self.load_failures = 0
         self.save_failures = 0
+        self._warned = set()
 
     def _path(self, key) -> str:
         return os.path.join(self.dir, _key_name(key))
 
-    def load(self, key):
-        """The deserialized, loaded executable for ``key``, or None
-        (missing, unreadable, or incompatible — the caller compiles)."""
+    def _warn_once(self, what: str, key) -> None:
+        """Log the first fail-open fault of each kind with its traceback
+        (every warmup bucket failing the same way would otherwise repeat
+        it); call from the except block."""
+        if what not in self._warned:
+            self._warned.add(what)
+            logging.getLogger("lwc.serve").warning(
+                "AOT store %s failed for %r under %s; compiling instead "
+                "(further %s failures are only counted)",
+                what, key, self.dir, what,
+                exc_info=True,
+            )
+
+    def load(self, key, devices):
+        """The deserialized executable for ``key``, loaded onto
+        ``devices`` — the ones it was compiled for, in assignment order;
+        left to its default, jax loads it over every local device and a
+        single-device executable then rejects its arguments.  None when
+        missing, unreadable, or incompatible: the caller compiles."""
         try:
             with open(self._path(key), "rb") as f:
                 payload, in_tree, out_tree = pickle.load(f)
             from jax.experimental import serialize_executable
 
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree
+                payload, in_tree, out_tree, execution_devices=devices
             )
         except FileNotFoundError:
             return None
         except Exception:
             self.load_failures += 1
+            self._warn_once("load", key)
             return None
         self.loads += 1
         return compiled
@@ -101,6 +119,7 @@ class AotStore:
             )
         except Exception:
             self.save_failures += 1
+            self._warn_once("save", key)
             return False
         self.saves += 1
         return True

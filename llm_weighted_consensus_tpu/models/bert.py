@@ -183,15 +183,11 @@ def _use_fused_attention(
         # the kernel's own cost model says no tile fits (e.g. f32
         # activations at s=1024): einsum, not a thrashing kernel
         return False
-    # "auto": measured IN CONTEXT on the real v5e chip (bge-large, bf16,
-    # full forward, bench_fwd.py r4): einsum with bf16-stored logits wins
-    # at s=128/256/384 (31.97 vs 35.54; 36.46 vs 39.56; 30.50 vs 30.87
-    # ms/fwd) because XLA fuses the head transposes into the projection
-    # matmuls, while the Pallas kernel pays them as HBM passes; the
-    # VMEM-resident kernel wins at s=512 (42.79 vs 47.65) where the
-    # [b, nh, s, s] intermediates dominate.  Isolated-op numbers (where
-    # the kernel matches einsum at 128 and wins from 256) are in
-    # ops/attention.py — the in-context crossover is what serving pays.
+    # "auto": the kernel from s=512 on a TPU, where the [b, nh, s, s]
+    # intermediates of the einsum path dominate; below that XLA fuses the
+    # head transposes into the projection matmuls while the kernel pays
+    # them as HBM passes.  The crossover is a builder's round-4 timing on
+    # another toolchain — not measured on this one (ROADMAP S2).
     return jax.default_backend() == "tpu" and s >= 512
 
 

@@ -94,8 +94,8 @@ def _embed_and_vote_many(
     """Batched self-consistency: ids/mask[>=R*N, S] -> confidence[R, N].
 
     R concurrent requests share ONE device dispatch (dynamic batching —
-    the encoder sees one [R*N, S] batch), amortizing the host<->device
-    round-trip that dominates single-request latency on tunneled links.
+    the encoder sees one [R*N, S] batch), amortizing the per-dispatch
+    host<->device round-trip.
     The vote is one R-batched einsum + softmax (same numerics as
     ``ops.similarity.cosine_consensus_vote``) rather than R unrolled
     kernel calls — compile time stays flat in R, and the caller buckets R
@@ -497,6 +497,13 @@ class TpuEmbedder:
             "max_tokens": self.max_tokens,
         }
 
+    def _execution_devices(self) -> list:
+        """The devices this embedder's executables run on, in assignment
+        order: the mesh's, or the one device the params live on."""
+        if self.mesh_mode:
+            return list(self.mesh.devices.flat)
+        return list(jax.tree_util.tree_leaves(self.params)[0].devices())
+
     def _aot_compile(self, timings, key, label, lower) -> None:
         """Fill ``self._aot[key]``: from the shared artifact store when
         a compatible serialized executable exists (AOT_CACHE_DIR), else
@@ -511,7 +518,7 @@ class TpuEmbedder:
         store = self.aot_store
         if store is not None:
             t0 = _time.perf_counter()
-            compiled = store.load(key)
+            compiled = store.load(key, self._execution_devices())
             if compiled is not None:
                 self._aot[key] = compiled
                 self._aot_restored += 1
@@ -542,11 +549,14 @@ class TpuEmbedder:
 
         The executables land in ``self._aot`` and the dispatch methods
         call them directly, bypassing jit dispatch entirely — post-warmup
-        traffic at warmed buckets creates ZERO new jit specializations
-        (``.lower().compile()`` alone does not populate the jit dispatch
-        cache on jax 0.4.x, so caching the executables ourselves is what
-        makes the warmup stick).  With ``COMPILE_CACHE_DIR`` set the
-        lowering also lands in the persistent XLA cache, so restarts
+        traffic at warmed buckets creates ZERO new jit specializations.
+        (On jax 0.9 ``.lower().compile()`` leaves the jit dispatch cache
+        empty — ``_cache_size()`` stays 0 — but memoizes the compilation:
+        a later jit call at the same shapes re-traces and adds its
+        dispatch entry without compiling again.  Holding the executables
+        here skips that trace too, and keeps ``jit_stats`` flat.)  The
+        compilations also land in the persistent XLA cache
+        (serve/config.py ``configure_compile_cache``), so restarts
         deserialize instead of recompiling.
 
         In mesh mode (``shard_embedder_mesh``) the same buckets lower

@@ -29,9 +29,8 @@ def layer_norm(x, params: dict, eps: float):
     mean = jnp.mean(x32, axis=-1, keepdims=True)
     # one-pass variance (E[x^2] - mean^2, clamped): both reductions fuse
     # into a single read of x, unlike jnp.var's subtract-then-reduce
-    # second pass — worth ~0.3 ms/fwd at the headline shape (r4).  The
-    # cancellation risk is bounded: LN inputs are O(1-10) f32, and flax
-    # LayerNorm uses the same formulation.
+    # second pass.  The cancellation risk is bounded: LN inputs are
+    # O(1-10) f32, and flax LayerNorm uses the same formulation.
     meansq = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     var = jnp.maximum(meansq - mean * mean, 0.0)
     normed = (x32 - mean) * jax.lax.rsqrt(var + eps)
@@ -104,7 +103,7 @@ def gelu_erf(x: jax.Array) -> jax.Array:
     f32 inputs always take XLA's exact erf; upcast from bf16 would too
     be exact — but for bf16 activations the erf lowering's ~12-op
     polynomial is the single largest non-matmul cost in the encoder
-    forward (~2.7 ms of the 33.5 ms bge-large N=64/s=128 forward,
+    forward (a builder's round-4 profile on another toolchain,
     bench_fwd.py).  The bf16 path instead uses the Abramowitz-Stegun
     7.1.26 erfc form, which rides the TPU's hardware exp: design error
     2.2e-7 absolute (f64), and after bf16 rounding it agrees with the

@@ -1,9 +1,9 @@
 """Opt-in int8 quantization for the encoder's dense matmuls.
 
 The v5e MXU runs int8 x int8 -> int32 at twice the bf16 rate (394 vs 197
-TOPS), and the headline consensus forward is dense-matmul-bound (~25 of
-its ~32 ms, DESIGN.md r4 breakdown) — so a W8A8 path roughly halves the
-FLOP term on the serving hot path.  No reference analog (the reference
+TOPS), and the consensus forward's FLOPs are almost all dense matmuls —
+so a W8A8 path can halve the FLOP term on the serving hot path (what it
+gains on the chip is not measured).  No reference analog (the reference
 delegates model compute to upstream HTTP APIs); this is a TPU-native
 serving optimization, OFF by default, selected per embedder
 (``TpuEmbedder(..., quantize="int8")`` / ``EMBEDDER_QUANTIZE=int8``).
@@ -24,7 +24,9 @@ Two implementations of the same math, selected by ``impl_for``:
   quantized activations, the int32 accumulator, and any dequantized
   weight copy stay in VMEM — nothing but the input and the finished
   output touches HBM;
-* ``xla`` (non-TPU default / VMEM-overflow fallback) — the jnp
+* ``xla`` (non-TPU default, the default under a mesh — a Mosaic kernel
+  cannot be auto-partitioned, parallel/sharding.py ``gspmd_config`` —
+  and the VMEM-overflow fallback) — the jnp
   composition below with ``preferred_element_type=int32``; XLA fuses the
   quant pass into surrounding elementwise work but stages the int8
   activations through HBM.
@@ -84,8 +86,7 @@ def impl_for(mode: str) -> str:
         return mode[len("int8-"):]
     if mode == "int4-pallas":
         # one spelling: the packed layout exists FOR the fused kernel
-        # (non-TPU backends run it in interpret mode; the only XLA
-        # composition is the in-graph VMEM-overflow fallback)
+        # (non-TPU backends run it in interpret mode)
         return "pallas"
     raise ValueError(f"quantize={mode!r} is not a quantized mode")
 
@@ -170,8 +171,9 @@ def dense_int4(
     activations, same int32 MXU accumulation, same rank-1 dequant — the
     weight block just decodes from nibbles.  The pallas impl unpacks
     IN-KERNEL (ops/kernels.w4a8_matmul) so the int8 weight copy never
-    materializes; shapes past the shared VMEM gate fall back to the XLA
-    composition over an unpacked weight."""
+    materializes; a shape past the shared VMEM gate is an error, not a
+    quiet switch to the XLA composition (``impl="xla"``), which exists
+    as the kernel's test reference."""
     if impl is None:
         impl = impl_for("int4-pallas")
     k = x.shape[-1]
@@ -182,13 +184,19 @@ def dense_int4(
         m = 1
         for d in x.shape[:-1]:
             m *= d
-        if w8a8_shape_fits(
+        if not w8a8_shape_fits(
             m, k, n, jnp.dtype(x.dtype).itemsize, w_bytes=0.5
         ):
-            return w4a8_matmul(
-                x, p["kernel_q"], p["scale"], p["bias"], gelu=gelu
+            # the mode is NAMED for the kernel: serving the XLA
+            # composition under it would hide which path runs
+            raise ValueError(
+                f"int4-pallas: the [{k}, {n}] packed weight block does "
+                "not fit the W4A8 kernel's VMEM budget "
+                "(ops/kernels.w8a8_shape_fits); use quantize=\"int8\""
             )
-        # weight block too big for VMEM: the XLA composition below
+        return w4a8_matmul(
+            x, p["kernel_q"], p["scale"], p["bias"], gelu=gelu
+        )
     wq = _unpack_int4(p["kernel_q"], k)
     xq, sx = _quantize_rows(x)
     acc = jax.lax.dot_general(

@@ -14,26 +14,19 @@ Layout: grid (b*nh // heads_per_step,); each step processes
 per flat tile so a step may straddle batch elements — any power-of-two
 divisor of b*nh inside the VMEM budget works (``best_heads_per_step``).
 
-Measured on the real v5e chip (bge-large shape nh=16, hd=64, bf16,
-bench_attn.py + bench_fwd.py, r4):
+Which path is faster, and from which sequence length, is not measured
+on this toolchain.  The kernel pays the [b, s, nh, hd] -> [b*nh, s, hd]
+transposes as HBM passes that XLA fuses into the einsum path's
+projection matmuls; the serving policy (``models/bert.py``
+``_use_fused_attention``: the kernel from s=512) comes from a builder's
+round-4 timings on another toolchain and is to be re-measured (ROADMAP
+S2).  A native-layout variant (BlockSpec carving [1, s, kh, hd] tiles
+straight out of the encoder layout, no transposes) hit a Mosaic INTERNAL
+error on batched dot_general with a middle batch axis on that toolchain;
+not retried on this one.
 
-  isolated op (b=64)          s=128   s=256   s=512
-    XLA einsum                0.077   0.922   3.233  ms
-    1-head/step kernel (r3)   0.564   0.966   1.716  ms
-    re-tiled kernel (best k)  0.076   0.582   1.553  ms
-
-  in-context full forward     s=128/b64  s=256/b32  s=384/b16  s=512/b16
-    einsum (bf16 logits)      31.97      36.46      30.50      47.65 ms
-    re-tiled kernel           35.54      39.56      30.87      42.79 ms
-
-Isolated, the re-tiled kernel matches einsum at s=128 and wins 1.6-2.1x
-at s>=256.  In context it pays the [b, s, nh, hd] -> [b*nh, s, hd]
-transpose materializations (~0.14 ms/layer at s=128) that XLA fuses into
-the einsum, so the in-context crossover is s>=512.  A native-layout
-variant (BlockSpec carving [1, s, kh, hd] tiles straight out of the
-encoder layout, no transposes) was tried and hits a Mosaic INTERNAL
-error on batched dot_general with a middle batch axis; revisit when the
-toolchain moves.
+The kernel is a single-device program: under a GSPMD-partitioned jit
+Mosaic refuses it (parallel/sharding.py ``gspmd_config``).
 
 On non-TPU backends the kernel runs in interpret mode (same code path,
 same numerics) so the CPU test mesh exercises it; parity with the einsum
@@ -48,11 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x names it TPUCompilerParams; newer jax renamed it
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
 
 # One (s, s) f32 score tile + 3 (s, hd) operand tiles must fit VMEM.
 MAX_FUSED_SEQ = 1024
@@ -138,7 +126,9 @@ def fused_attention_tiled(
         out_specs=qkv_spec,
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
         # independent grid steps: lets Mosaic double-buffer the block DMAs
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=_interpret(),
     )(to_heads(q), to_heads(k), to_heads(v), flat_bias)
     return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
@@ -227,7 +217,9 @@ def fused_attention_tiled_seg(
         in_specs=[qkv_spec, qkv_spec, qkv_spec, seg_spec],
         out_specs=qkv_spec,
         out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
         interpret=_interpret(),
     )(to_heads(q), to_heads(k), to_heads(v), flat_seg)
     return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)

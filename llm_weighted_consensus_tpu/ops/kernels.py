@@ -30,9 +30,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# VMEM is ~16 MB/core; cap single-block shapes well under it
-MAX_FUSED_CHOICES = 1024
-MAX_FUSED_DIM = 2048
+# The vote kernel is one ungridded block under Mosaic's 16 MiB scoped-VMEM
+# default.  At 1024 x 2048 it asks for 41.4 MiB and fails to compile on a
+# v5e; these caps — the service's own: MAX_CONSENSUS_CANDIDATES rows of
+# the widest preset's hidden size — compile and match the reference there
+# (chip runs, PR 21).  Anything larger takes the jnp composition.
+MAX_FUSED_CHOICES = 256
+MAX_FUSED_DIM = 1024
 
 
 def _interpret() -> bool:

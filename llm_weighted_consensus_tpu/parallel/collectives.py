@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import shard_map
 
 
 def sharded_cosine_vote(
@@ -49,7 +48,7 @@ def sharded_cosine_vote(
     temp = jnp.asarray(temperature, jnp.float32)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("dp", None), P()),
         out_specs=P("dp"),
@@ -109,7 +108,7 @@ def sharded_tally(
         weights = jnp.pad(weights, (0, pad))
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("dp", None), P("dp")),
         out_specs=P(),

@@ -153,9 +153,7 @@ def ring_encode(
             params, ids, mask, config, position_offset=offset
         )
 
-    from .compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local_forward,
         mesh=mesh,
         in_specs=(_replicated_like(params), seq_spec, seq_spec),

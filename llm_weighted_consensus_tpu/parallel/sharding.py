@@ -272,6 +272,29 @@ def shard_bert_params(params: dict, mesh: Mesh, tp: bool = True) -> dict:
     return shard_by_rules(params, mesh, rules, tp=tp)
 
 
+def gspmd_config(config):
+    """The model config a GSPMD-partitioned forward must run under.
+
+    A Mosaic kernel cannot ride jit's automatic partitioning ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in
+    a shard_map" — found on the first four-chip run; a CPU mesh never
+    takes these branches), so the two choices that resolve to a Pallas
+    kernel on a TPU are pinned to their XLA twins here: ``"auto"``
+    attention (the fused kernel from s=512) becomes ``"einsum"`` and
+    ``quantize="int8"`` (the fused W8A8 matmul) becomes ``"int8-xla"``.
+    A PINNED kernel mode — ``"fused"``, ``"int8-pallas"``,
+    ``"int4-pallas"`` — is left alone and fails at compile with that
+    message: the name says which path runs."""
+    import dataclasses
+
+    changes = {}
+    if getattr(config, "attention_impl", None) == "auto":
+        changes["attention_impl"] = "einsum"
+    if config.quantize == "int8":
+        changes["quantize"] = "int8-xla"
+    return dataclasses.replace(config, **changes) if changes else config
+
+
 def shard_embedder(embedder, mesh: Mesh, tp: bool = False) -> None:
     """Wire a models.embedder.TpuEmbedder onto a mesh: params placed
     (replicated or TP), batches split over ``dp`` via its put_batch hook.
@@ -281,6 +304,7 @@ def shard_embedder(embedder, mesh: Mesh, tp: bool = False) -> None:
     fallback below is a safety net for direct put_batch callers only.
     """
     embedder.params = shard_bert_params(embedder.params, mesh, tp=tp)
+    embedder.config = gspmd_config(embedder.config)
     b_sharding = batch_sharding(mesh)
     repl = replicated(mesh)
     dp = mesh.shape.get("dp", 1)
@@ -324,6 +348,7 @@ def shard_embedder_mesh(embedder, mesh: Mesh) -> None:
     sp = mesh.shape.get("sp", 1)
     rules = bert_partition_rules(quantized=is_quantized(embedder.params))
     embedder.params = shard_by_rules(embedder.params, mesh, rules, tp=tp > 1)
+    embedder.config = gspmd_config(embedder.config)
     b_sharding = batch_sharding(mesh)
     repl = replicated(mesh)
 
@@ -367,4 +392,5 @@ def shard_reranker_mesh(reranker, mesh: Mesh) -> None:
     tp = mesh.shape.get("tp", 1)
     rules = deberta_partition_rules(quantized=is_quantized(reranker.params))
     reranker.params = shard_by_rules(reranker.params, mesh, rules, tp=tp > 1)
+    reranker.config = gspmd_config(reranker.config)
     reranker.mesh = mesh

@@ -1,6 +1,6 @@
 """Device dispatch watchdog: detect a wedged TPU before the operator does.
 
-A hung PJRT dispatch (wedged tunnel, driver fault, a device-side
+A hung PJRT dispatch (a lost device link, driver fault, a device-side
 deadlock) does not raise — it just never returns, silently eating one
 executor thread while every queued request behind it times out.  The
 watchdog brackets each device dispatch (``begin``/``end`` hooks called

@@ -20,7 +20,7 @@ from ..clients.chat import AiohttpTransport, ApiBase, DefaultChatClient
 from ..clients.multichat import MultichatClient
 from ..clients.score import ScoreClient
 from ..weights import WeightFetchers
-from .config import Config, enable_compile_cache, load_dotenv
+from .config import Config, configure_compile_cache, load_dotenv
 from .gateway import LIFECYCLE_KEY, _parse_error_response, build_app
 
 FAKE_PORT = 5990
@@ -265,8 +265,6 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
     valid but are garbage; it is refused unless explicitly opted into via
     ``allow_synthetic`` (set for --fake-upstream demo mode) or
     ``LWC_ALLOW_RANDOM_PARAMS=1``, and logged loudly even then."""
-    if config.compile_cache_dir:
-        enable_compile_cache(config.compile_cache_dir)
     if not config.embedder_model:
         return None
     from ..models.configs import PRESETS
@@ -719,10 +717,42 @@ def _build_cpu_fallback(config: Config, fake_upstream: bool):
     return fallback, fallback_context
 
 
+def _device_stats(embedder, reranker) -> dict:
+    """The ``device`` section of /metrics (and the start-up log line):
+    what this process computes on.  The models pick their param dtype,
+    their quantized-matmul implementation and Pallas interpret mode from
+    the platform; this is where an operator — or chip_smoke.py — sees
+    which way those went.  Dtypes are read off the params themselves."""
+    import jax
+
+    from ..utils import device_summary
+
+    devices = jax.local_devices()
+    per_device = []
+    for d in devices:
+        row = {"id": d.id}
+        stats = d.memory_stats()  # None where the backend keeps none (CPU)
+        if stats:
+            row["bytes_in_use"] = stats.get("bytes_in_use")
+            row["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
+        per_device.append(row)
+    out = {
+        **device_summary(),
+        "pallas_interpret": devices[0].platform != "tpu",
+        "devices": per_device,
+    }
+    for prefix, model in (("", embedder), ("rm_", reranker)):
+        if model is not None:
+            out[prefix + "param_dtype"] = model.params["token_embed"].dtype.name
+            out[prefix + "quantize"] = model.config.quantize
+    return out
+
+
 def build_service(
     config: Config,
     fake_upstream: bool = False,
     fake_upstream_port: int = FAKE_PORT,
+    compile_cache=None,
 ):
     import os
 
@@ -784,6 +814,15 @@ def build_service(
     # --fake-upstream is demo/test mode: synthetic embedder params are
     # allowed (still logged); production startup refuses them
     embedder = build_embedder(config, allow_synthetic=fake_upstream)
+    reranker = build_reranker(config, allow_synthetic=fake_upstream)
+    if embedder is not None or reranker is not None:
+        import logging
+
+        # before warmup: if a compile hangs or dies, what it ran on is
+        # already in the log
+        logging.getLogger("lwc.serve").info(
+            "device: %s", _device_stats(embedder, reranker)
+        )
     if embedder is not None:
         # per-bucket device timing (phases/roofline sections), measured
         # enqueue-to-ready: under the batcher the readiness wait runs on
@@ -903,12 +942,17 @@ def build_service(
                 )
 
             meshfault.probe_fn = _mesh_probe
-    reranker = build_reranker(config, allow_synthetic=fake_upstream)
     from .metrics import Metrics
 
     # metrics exist regardless of the device side: the result cache's
     # counters (and the HTTP series) are host-only observability
     metrics = Metrics()
+    if compile_cache is not None:
+        metrics.register_provider("compile_cache", compile_cache.snapshot)
+    if embedder is not None or reranker is not None:
+        metrics.register_provider(
+            "device", lambda: _device_stats(embedder, reranker)
+        )
     if embedder is not None:
         # jit-cache introspection on /metrics: AOT bucket count + live
         # specialization counts (asserting "zero new specializations
@@ -1397,7 +1441,9 @@ def build_service(
     return app
 
 
-async def _serve(config: Config, fake_upstream: bool) -> None:
+async def _serve(
+    config: Config, fake_upstream: bool, compile_cache=None
+) -> None:
     if fake_upstream:
         fake_app = web.Application()
         fake_app.router.add_post("/v1/chat/completions", _fake_upstream)
@@ -1405,7 +1451,9 @@ async def _serve(config: Config, fake_upstream: bool) -> None:
         await fake_runner.setup()
         await web.TCPSite(fake_runner, "127.0.0.1", FAKE_PORT).start()
 
-    app = build_service(config, fake_upstream=fake_upstream)
+    app = build_service(
+        config, fake_upstream=fake_upstream, compile_cache=compile_cache
+    )
     runner = web.AppRunner(app)
     await runner.setup()
     await web.TCPSite(runner, config.address, config.port).start()
@@ -1470,6 +1518,15 @@ def main() -> None:
         help="serve against a loopback scripted provider (no API keys)",
     )
     args = parser.parse_args()
+    import logging
+
+    # the package's own start-up lines (device, warmup compile times) at
+    # INFO; everything else — aiohttp's per-request access log — at WARNING
+    logging.basicConfig(
+        level=logging.WARNING,
+        format="%(asctime)s %(name)s %(levelname)s %(message)s",
+    )
+    logging.getLogger("lwc").setLevel(logging.INFO)
     load_dotenv()
     # must precede any jax backend use (mesh construction in build_service)
     from ..parallel.dist import maybe_initialize_distributed
@@ -1485,7 +1542,10 @@ def main() -> None:
             "Either OPENAI_APIS or both OPENAI_API_BASE and OPENAI_API_KEY "
             "must be set (or pass --fake-upstream)"
         )
-    asyncio.run(_serve(config, args.fake_upstream))
+    # before the first compilation: jax decides there whether it has a
+    # persistent cache
+    compile_cache = configure_compile_cache()
+    asyncio.run(_serve(config, args.fake_upstream, compile_cache))
 
 
 if __name__ == "__main__":

@@ -1203,8 +1203,7 @@ class DeviceBatcher:
         embedding [PAD] slots.  Chunks reuse the already-compiled
         specializations and pipeline (``pipeline_depth``); mild padding
         (<=25%) is kept whole because an extra dispatch costs a pipeline
-        slot (~a link round-trip on a tunnel) — not worth a few pad rows
-        (r4 code-review finding)."""
+        slot — not worth a few pad rows (r4 code-review finding)."""
         groups: dict = {}
         order = []
         for item in batch:

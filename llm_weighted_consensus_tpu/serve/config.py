@@ -56,10 +56,11 @@ TPU additions:
   Requires ``MESH_SHAPE=DPxTPxSP``; empty = ring shapes compile lazily.
 * ``MULTIHOST`` — set to 1 on each host of a multi-host slice to call
   ``jax.distributed.initialize`` before mesh construction (parallel/dist.py).
-* ``COMPILE_CACHE_DIR`` — persistent XLA compilation cache: jit
-  specializations compiled on previous runs load from disk, cutting
-  cold-start latency (first-request compiles take tens of seconds for
-  large encoders).  Unset = in-memory cache only.
+* ``JAX_COMPILATION_CACHE_DIR`` — JAX's own variable, read by JAX at
+  import: where the persistent XLA compilation cache lives.  The
+  program sets no directory over it; unset, every entry point uses the
+  fixed ``.jax_cache`` at the checkout root (``configure_compile_cache``), so
+  restarts and sibling processes hit what an earlier one compiled.
 * ``PROFILE_DIR`` — arms ``POST /profile/start`` / ``POST /profile/stop``
   and the one-shot ``POST /v1/profile`` (bounded ``duration_ms`` capture
   window, admission-exempt so an overload can be profiled while the gate
@@ -128,7 +129,7 @@ TPU additions:
 * ``WARMUP`` — consensus shapes to pre-compile at startup, e.g.
   ``64x112,64x128`` (``NxS`` pairs): the first request at a shape
   otherwise pays a multi-second jit compile (each (N, seq-bucket) is
-  its own XLA specialization); pair with ``COMPILE_CACHE_DIR`` to make
+  its own XLA specialization); the persistent compile cache makes
   later restarts near-instant.  Invalid specs fail startup loudly.
 * ``WARMUP_R`` — concurrency buckets to ALSO pre-compile for each
   ``WARMUP`` shape through the batcher's grouped path, e.g. ``2,4``:
@@ -186,7 +187,7 @@ TPU additions:
 * ``SCORE_CACHE_MAX_BYTES`` — byte budget for the in-memory score result
   LRU.  Default 67108864 (64 MiB).
 * ``SCORE_CACHE_DIR`` — append-only JSONL disk tier for the score cache
-  (the COMPILE_CACHE_DIR pattern applied to results): entries persist
+  (the compile-cache pattern applied to results): entries persist
   across restarts and reload at startup, expired ones skipped.  Unset =
   memory only.
 * ``SCORE_CACHE_EMBED`` — also memoize embedding rows per
@@ -332,7 +333,7 @@ shutdown):
   empties, the cache disk tier is flushed exactly once, then exit 0.
   Default 10000.
 * ``DEVICE_WATCHDOG_MILLIS`` — a device dispatch exceeding this marks
-  the device unhealthy (hung PJRT / wedged tunnel): ``/readyz`` flips
+  the device unhealthy (a hung PJRT call): ``/readyz`` flips
   and admission sheds device-dependent endpoints
   (``shed_reason: device_unhealthy``) until the dispatch completes.
   ``0`` (the default) disables the watchdog.
@@ -490,29 +491,60 @@ from typing import Optional
 from ..utils import env_truthy, jsonutil
 
 
-def enable_compile_cache(path: str) -> None:
-    """Persistent XLA compilation cache: warm restarts (and repeat bench
-    runs) skip the first-request compile (SURVEY §7 'cold-start/compile
-    caching').  Must run before the first jit compilation.  Lives here —
-    not serve/__main__ — so bench.py can use it without importing the
-    aiohttp entry-point chain."""
+# The path must not move between processes (a pid, a timestamp or a
+# tmpdir never hits), and it must be somewhere the checkout's owner
+# controls: inside the checkout, ignored by git.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+class CompileCacheStats:
+    """Persistent-cache hit/miss counts of this process, from JAX's own
+    monitoring events (a hit is a compile request answered from the
+    directory; a miss is an executable compiled and written to it)."""
+
+    _EVENTS = {
+        "/jax/compilation_cache/cache_hits": "hits",
+        "/jax/compilation_cache/cache_misses": "misses",
+    }
+
+    def __init__(self, directory: str) -> None:
+        import jax
+
+        self.directory = directory
+        self.counts = {"hits": 0, "misses": 0}
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_event(self, event: str, **kwargs) -> None:
+        name = self._EVENTS.get(event)
+        if name is not None:
+            self.counts[name] += 1
+
+    def snapshot(self) -> dict:
+        return {"dir": self.directory, **self.counts}
+
+
+def configure_compile_cache() -> CompileCacheStats:
+    """Turn the persistent XLA compilation cache on for this process —
+    every entry point calls this once, before its first compilation
+    (JAX decides whether it has a cache at the first compile).  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read it and no
+    directory is set here; otherwise the fixed in-checkout one is.
+    Every specialization is cached, not only slow ones: the serving loop
+    has a handful of bucketed shapes and all of them matter cold."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
-    # cache every specialization, not only slow ones — the serving loop
-    # has a handful of bucketed shapes and all of them matter cold
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", DEFAULT_CACHE_DIR
+        )
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # jax latches the cache-disabled decision at the process's FIRST
-    # compile; enabling the dir afterwards is a silent no-op unless the
-    # latch is reset.  Internal API, so fail open: worst case is the
-    # pre-reset behavior (no persistent cache) rather than no serving.
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    return CompileCacheStats(jax.config.jax_compilation_cache_dir)
 
 
 def _parse_warmup(raw) -> list:
@@ -710,7 +742,6 @@ class Config:
     mesh_shape: Optional[tuple] = None  # (dp, tp[, sp]) from "DPxTP[xSP]"
     # ring AOT buckets (NxS) warmed when MESH_SHAPE carries an sp axis
     long_context_warmup: list = field(default_factory=list)
-    compile_cache_dir: Optional[str] = None
     profile_dir: Optional[str] = None
     archive_path: Optional[str] = None
     archive_write: bool = False
@@ -949,7 +980,6 @@ class Config:
             long_context_warmup=_parse_long_context_warmup(
                 env.get("LONG_CONTEXT_WARMUP")
             ),
-            compile_cache_dir=env.get("COMPILE_CACHE_DIR"),
             profile_dir=env.get("PROFILE_DIR"),
             archive_path=env.get("ARCHIVE_PATH"),
             archive_write=env_truthy(

@@ -8,6 +8,7 @@ and unary failures map to HTTP status + the error's message JSON.
 
 from __future__ import annotations
 
+import asyncio
 import time as _time
 from typing import Optional
 
@@ -133,6 +134,13 @@ def _frame(obj) -> bytes:
     return frames.frame_bytes(obj)
 
 
+def _note_client_disconnect(request: web.Request) -> None:
+    obs.annotate(client_disconnect=True)
+    metrics = request.app.get(METRICS_KEY)
+    if metrics is not None:
+        metrics.observe("http:client_disconnect", 0.0, error=True)
+
+
 async def _respond_streaming(
     request: web.Request, stream, fastpath: bool = False
 ) -> web.StreamResponse:
@@ -160,10 +168,15 @@ async def _respond_streaming(
         # upstream judge pumps and any batcher futures this request has
         # in flight (batcher._submit drops a cancelled item before its
         # group dispatches — no orphaned device work)
-        obs.annotate(client_disconnect=True)
-        metrics = request.app.get(METRICS_KEY)
-        if metrics is not None:
-            metrics.observe("http:client_disconnect", 0.0, error=True)
+        _note_client_disconnect(request)
+    except asyncio.CancelledError:
+        # a server started with handler_cancellation=True (aiohttp's test
+        # server is) cancels the handler when the client leaves, where
+        # the default lets its next write fail: the same event, counted
+        # the same way.  Any other cancellation (shutdown) is not one.
+        if request.transport is None:
+            _note_client_disconnect(request)
+        raise
     finally:
         aclose = getattr(stream, "aclose", None)
         if aclose is not None:
@@ -730,7 +743,7 @@ def _roofline_gauge(embedder):
         roofline = load_roofline(default_roofline_path())
         if not roofline:
             return None
-        return RooflineGauge(roofline, jax.default_backend())
+        return RooflineGauge(roofline, jax.devices()[0].device_kind)
     except Exception:
         return None
 

@@ -41,6 +41,8 @@ KNOWN_SECTIONS = (
     "embed_cache",
     "score_cache",
     "traces",
+    "device",
+    "compile_cache",
     "jit",
     "mesh",
     "meshfault",

@@ -23,6 +23,20 @@ def next_pow2(n: int) -> int:
     return bucket
 
 
+def device_summary() -> dict:
+    """What this process computes on, as JAX reports it — the three
+    fields every record that could be mistaken for a device measurement
+    carries (server /metrics, chip_smoke.py, the bench scripts)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
 def env_truthy(value) -> bool:
     """The framework's one definition of an env-flag truthy value."""
     return str(value).lower() in ("1", "true", "yes", "on")

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import ctypes
 import glob
+import logging
 import os
 import subprocess
 from typing import Optional
@@ -27,6 +28,10 @@ NATIVE_SO = os.path.join(NATIVE_DIR, "liblwc_native.so")
 
 _lib = None
 _tried = False
+# why the last load_library() call returned None (None while it has not
+# run, or when it loaded): callers fall back to slower pure-Python paths,
+# so the reason must be findable (``status``)
+_error: Optional[str] = None
 
 
 def _sources() -> list:
@@ -41,13 +46,15 @@ def _stale(sources: list) -> bool:
 
 
 def load_library() -> Optional[ctypes.CDLL]:
-    """The native library, building it on first call; None — remembered —
-    when it can't be built/loaded or ``LWC_NATIVE=0``."""
-    global _lib, _tried
+    """The native library, building it on first call; None — remembered,
+    and logged once with the reason — when it can't be built/loaded or
+    ``LWC_NATIVE=0``."""
+    global _lib, _tried, _error
     if _tried:
         return _lib
     _tried = True
     if os.environ.get("LWC_NATIVE", "1").lower() in ("0", "false", "no"):
+        _error = "disabled by LWC_NATIVE=0"
         return None
     try:
         sources = _sources()
@@ -65,9 +72,22 @@ def load_library() -> Optional[ctypes.CDLL]:
                 timeout=120,
             )
             os.replace(tmp, NATIVE_SO)
-        if not os.path.exists(NATIVE_SO):
-            return None
         _lib = ctypes.CDLL(NATIVE_SO)
-    except Exception:
-        _lib = None
+    except subprocess.CalledProcessError as e:
+        _error = f"g++ failed: {e.stderr.decode('utf-8', 'replace')[-500:]}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        # no compiler, no sources and no prebuilt .so, or an unloadable one
+        _error = repr(e)
+    if _error is not None:
+        logging.getLogger("lwc.native").warning(
+            "native library unavailable, the SSE parser and the "
+            "tokenizers take their pure-Python paths: %s",
+            _error,
+        )
     return _lib
+
+
+def status() -> dict:
+    """Whether the native library is loaded in this process, and if not,
+    why (``error`` is None before the first ``load_library`` call)."""
+    return {"loaded": _lib is not None, "error": _error}

@@ -1222,13 +1222,36 @@ def test_aot_store_digest_namespaces_and_fail_open(tmp_path):
     # invalidation by construction
     assert a.dir != b.dir
     key = ("vote1", 4, 16, True)
-    assert a.load(key) is None  # missing: silent miss, not a failure
+    assert a.load(key, []) is None  # missing: silent miss, not a failure
     assert a.load_failures == 0
     os.makedirs(a.dir, exist_ok=True)
     with open(os.path.join(a.dir, _key_name(key)), "wb") as f:
         f.write(b"not a pickle")
-    assert a.load(key) is None  # corrupt: fail open, count it
+    assert a.load(key, []) is None  # corrupt: fail open, count it
     assert a.load_failures == 1
+
+
+def test_aot_store_failed_restore_is_logged_once(tmp_path, caplog):
+    """A restore that fails falls back to compiling — with one warning
+    that says why (a second bucket failing the same way is only counted)."""
+    import logging
+
+    from llm_weighted_consensus_tpu.models.aot_store import (
+        AotStore,
+        _key_name,
+    )
+
+    store = AotStore(str(tmp_path), meta={"jax": "1"})
+    os.makedirs(store.dir, exist_ok=True)
+    for key in (("vote1", 4, 16, True), ("embed", 16, 16)):
+        with open(os.path.join(store.dir, _key_name(key)), "wb") as f:
+            f.write(b"not a pickle")
+    with caplog.at_level(logging.WARNING, logger="lwc.serve"):
+        assert store.load(("vote1", 4, 16, True), []) is None
+        assert store.load(("embed", 16, 16), []) is None
+    assert store.load_failures == 2
+    warnings = [r for r in caplog.records if "AOT store load" in r.getMessage()]
+    assert len(warnings) == 1 and warnings[0].exc_info is not None
 
 
 def test_aot_warmup_serializes_then_restores_without_compiling(tmp_path):

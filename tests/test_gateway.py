@@ -1615,49 +1615,6 @@ def test_archive_rescore_endpoint_validates_input():
     go(with_client(app, run))
 
 
-def test_compile_cache_dir_populates(tmp_path):
-    """COMPILE_CACHE_DIR: jit specializations persist to disk so warm
-    restarts skip the cold compile."""
-    pytest.importorskip("jax")
-    import dataclasses
-    import os
-
-    from llm_weighted_consensus_tpu.models.configs import TEST_TINY
-    from llm_weighted_consensus_tpu.models.embedder import TpuEmbedder
-    from llm_weighted_consensus_tpu.serve.config import enable_compile_cache
-
-    import jax
-
-    cache = str(tmp_path / "xla-cache")
-    assert Config.from_env(
-        {"COMPILE_CACHE_DIR": cache}
-    ).compile_cache_dir == cache
-    saved = {
-        name: getattr(jax.config, name)
-        for name in (
-            "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes",
-        )
-    }
-    try:
-        enable_compile_cache(cache)
-        # a config shape nothing else in the suite compiles, so this is
-        # a FRESH compilation (an in-memory jit cache hit writes nothing)
-        novel = dataclasses.replace(TEST_TINY, hidden_size=96, num_heads=4)
-        embedder = TpuEmbedder("test-tiny", config=novel, max_tokens=32)
-        embedder.embed_texts(["cache this compilation"])
-        files = [
-            os.path.join(r, f) for r, _, fs in os.walk(cache) for f in fs
-        ]
-        assert files, "no compilation cache entries written"
-    finally:
-        # process-global config: later tests must not write into this
-        # test's tmp dir
-        for name, value in saved.items():
-            jax.config.update(name, value)
-
-
 def test_endpoints_never_500_on_malformed_bodies():
     """Adversarial input sweep: every POST endpoint answers malformed or
     type-confused JSON with a clean 4xx — never a 500/stack trace."""
@@ -1866,14 +1823,20 @@ def test_client_disconnect_mid_stream_cancels_pipeline():
     keys = ballot_keys(2)
     app, _ = make_app(
         [
-            # frame 1 arrives half a second late: the client is long gone
-            # by the time the server tries to write the final frame
+            # the judge client looks one chunk ahead, so "thinking" goes
+            # out when "still thinking" arrives, at once; the last chunk
+            # arrives half a second late: the client is long gone by the
+            # time the server tries to write the frames behind it.  (Two
+            # chunks would all be written in one burst when the late one
+            # lands — aiohttp 3.13's write does not yield to the loop —
+            # before the server could see the client leave.)
             Script(
                 [
                     chunk_obj("thinking"),
+                    chunk_obj("still thinking"),
                     chunk_obj(f"pick {keys[1]}", finish="stop"),
                 ],
-                delays={1: 0.5},
+                delays={2: 0.5},
             )
         ]
     )

@@ -321,3 +321,18 @@ def test_build_embedder_mesh_enabled_round_trip():
     assert dict(embedder.mesh.shape) == {"dp": DP, "tp": TP}
     out = embedder.embed_texts(["mesh round trip"])
     np.testing.assert_allclose(np.linalg.norm(out, axis=1), 1.0, atol=1e-5)
+
+
+def test_mesh_mode_pins_kernel_choices_gspmd_cannot_partition():
+    """A Mosaic kernel cannot ride jit's automatic partitioning (found on
+    the first four-chip run; the CPU mesh never takes these branches):
+    under a mesh the two AUTO choices resolve to their XLA twins, while a
+    pinned kernel mode stays as named and fails at compile on a TPU."""
+    emb = mesh_embedder(quantize="int8")
+    assert emb.config.attention_impl == "einsum"
+    assert emb.config.quantize == "int8-xla"
+    assert emb._ring_config is None
+    pinned = mesh_embedder(quantize="int8-pallas")
+    assert pinned.config.quantize == "int8-pallas"
+    # the single-device embedder keeps choosing by platform
+    assert make_embedder(quantize="int8").config.attention_impl == "auto"

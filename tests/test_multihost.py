@@ -16,15 +16,6 @@ from llm_weighted_consensus_tpu.parallel.multihost_smoke import (
     run_group,
 )
 
-# the process-group tests dispatch collectives that cross an OS process
-# boundary; tests/conftest.py turns the marker into a STRICT xfail on
-# the CPU backend (which rejects them at dispatch) and runs them for
-# real everywhere else.  test_expected_confidence_fixture stays
-# unmarked: the tally math is single-process.
-multihost = pytest.mark.requires_multiprocess_collectives
-
-
-@multihost
 def test_two_process_group_tallies_and_agrees():
     results = run_group(num_processes=2)
     assert len(results) == 2
@@ -34,7 +25,6 @@ def test_two_process_group_tallies_and_agrees():
     np.testing.assert_allclose(sum(confs[0]), 1.0, atol=1e-6)
 
 
-@multihost
 def test_two_process_four_device_mesh_runs_tp_inside_dp_across():
     """VERDICT r3 item 5: 2 processes x 4 virtual devices, global
     (dp=2, tp=4) mesh.  The TP-sharded encoder forward EXECUTES with the
@@ -60,7 +50,6 @@ def test_expected_confidence_fixture():
     assert exp == sorted(exp, reverse=True)
 
 
-@multihost
 def test_three_process_group_widens_dcn_proof():
     """Nothing bakes in n_processes=2 (the r5 mesh-widening discipline,
     VERDICT r4 next-5, applied to the DCN axis): a 3-process group forms,

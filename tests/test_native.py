@@ -580,3 +580,30 @@ def test_native_unigram_normal_piece_at_unk_index_parity():
     ids_n, _ = native.encode_batch(["ab", "a b ab"], 8)
     ids_p, _ = python.encode_batch(["ab", "a b ab"], 8)
     assert ids_n.tolist() == ids_p.tolist()
+
+
+def test_unavailable_library_is_logged_once_with_its_reason(
+    monkeypatch, caplog, tmp_path
+):
+    """A failed build must not silently put tokenisation and SSE parsing
+    on their pure-Python paths: one warning with the compiler's message,
+    and ``status()`` keeps the reason."""
+    import logging
+
+    from llm_weighted_consensus_tpu.utils import native
+
+    bad = tmp_path / "broken.cpp"
+    bad.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_error", None)
+    monkeypatch.setattr(native, "NATIVE_SO", str(tmp_path / "lib.so"))
+    monkeypatch.setattr(native, "_sources", lambda: [str(bad)])
+    with caplog.at_level(logging.WARNING, logger="lwc.native"):
+        assert native.load_library() is None
+        assert native.load_library() is None  # remembered, not retried
+    warnings = [r for r in caplog.records if r.name == "lwc.native"]
+    assert len(warnings) == 1
+    assert "g++ failed" in warnings[0].getMessage()
+    status = native.status()
+    assert status["loaded"] is False and "g++ failed" in status["error"]

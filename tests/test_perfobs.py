@@ -664,7 +664,7 @@ def test_roofline_gauge_scales_sol_by_mesh_chips():
     obs.observe_device("x(b=1)", 8.0)
     obs.observe_device("x(b=1)@dp2xtp2", 2.0)
     snap = gauge.snapshot()
-    assert snap["backend"] == "cpu" and snap["known_peaks"]
+    assert snap["device_kind"] == "cpu" and snap["known_peaks"]
     single = snap["buckets"]["x(b=1)"]
     meshed = snap["buckets"]["x(b=1)@dp2xtp2"]
     assert single["sol_ms"] == pytest.approx(4.0)  # 4e6 / 1e9 * 1e3

@@ -48,9 +48,8 @@ def test_ring_attention_matches_full(sp):
 
     mesh = sp_mesh(sp)
     spec = P(None, "sp")
-    from llm_weighted_consensus_tpu.parallel.compat import shard_map
 
-    ringed = shard_map(
+    ringed = jax.shard_map(
         lambda q, k, v, b: ring.ring_attention(q, k, v, b, scale, "sp"),
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),

@@ -14,9 +14,11 @@ host with four chips) the same server over a 2x2 mesh.
 The parent process never imports jax: a chip belongs to one process at a
 time, so each stage is a child process, run one after another, and every
 child is stopped before the next starts.  One JSON line per stage on
-stdout; on success the last line is the summary
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}, ...}``.
-On failure the summary goes to stderr and the exit code is 1.
+stdout, then a summary line (``"claim": null``), and as the LAST line the
+result, exactly
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
+with the device as JAX reports it.  On failure the reason goes to stderr and
+the exit code is 1; where no accelerator was found there is no result line.
 
 No timing printed here is a performance number: ``setup_s`` fields are
 cold set-up (process start, weight init, compilation), reported so a
@@ -391,11 +393,25 @@ def drive_server(port, sizes, words, prof_dir, mesh, probe, dry_run) -> dict:
     }
 
 
+def result_line(ok: bool, probe: dict) -> dict:
+    """The result, always the LAST line of stdout when there is one: exactly
+    these keys, the device as JAX reported it to the probe child."""
+    return {
+        "ok": ok,
+        "device": {
+            "platform": probe["platform"],
+            "kind": probe["device_kind"],
+            "count": probe["device_count"],
+        },
+    }
+
+
 def run_parent(dry_run: bool) -> int:
     os.makedirs(OUT_DIR, exist_ok=True)
     sizes = DRY if dry_run else FULL
     stages = {}
     probe = {}
+    device_accepted = False
     try:
         probe = run_child("probe", dry_run, timeout=300)
         if not dry_run:
@@ -403,6 +419,7 @@ def run_parent(dry_run: bool) -> int:
                 probe["platform"] == "tpu",
                 f"platform {probe['platform']} is not tpu",
             )
+        device_accepted = True
         need(probe["native"]["loaded"], f"native library: {probe['native']}")
         stages["serve"] = stage_serve(sizes, dry_run, False, probe)
         emit(stages["serve"])
@@ -418,7 +435,6 @@ def run_parent(dry_run: bool) -> int:
             }
         emit(stages["mesh"])
     except StageFailed as e:
-        # no result on stdout: the summary of a failed run goes to stderr
         print(f"chip_smoke FAILED: {e}", file=sys.stderr)
         print(
             json.dumps(
@@ -427,15 +443,15 @@ def run_parent(dry_run: bool) -> int:
             file=sys.stderr,
             flush=True,
         )
+        # without the device there is no result on stdout at all; with it,
+        # a stage that failed there is a result, and it says so
+        if device_accepted:
+            emit(result_line(False, probe))
         return 1
     emit(
         {
-            "ok": True,
-            "device": {
-                "platform": probe["platform"],
-                "kind": probe["device_kind"],
-                "count": probe["device_count"],
-            },
+            "summary": True,
+            **{key: probe[key] for key in DEVICE_KEYS},
             "dry_run": dry_run,
             "versions": probe["versions"],
             "stages": {
@@ -451,6 +467,7 @@ def run_parent(dry_run: bool) -> int:
             "claim": None,
         }
     )
+    emit(result_line(True, probe))
     return 0
 
 

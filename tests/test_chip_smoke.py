@@ -50,7 +50,8 @@ def dry_run():
     records = [json.loads(line) for line in proc.stdout.splitlines()]
     return {
         "parent": records[-1],
-        "summary": records[-2],
+        "result": records[-2],
+        "summary": records[-3],
         "stages": {r["stage"]: r for r in records if "stage" in r},
         "checks": [r for r in records if "check" in r],
     }
@@ -59,12 +60,21 @@ def dry_run():
 def test_dry_run_passes_every_stage(dry_run):
     assert dry_run["parent"]["parent_rc"] == 0
     summary = dry_run["summary"]
-    assert summary["ok"] is True and summary["dry_run"] is True
+    assert summary["summary"] is True and summary["dry_run"] is True
     # mesh included: the dry run gives itself four virtual CPU devices
     assert summary["stages"] == {
         "serve": "passed", "kernels": "passed", "mesh": "passed"
     }
-    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 4}
+
+
+def test_last_line_is_the_result_and_nothing_else(dry_run):
+    """The driver reads the last line of stdout: one object with exactly
+    ``ok`` and ``device``, the device with exactly platform/kind/count."""
+    assert dry_run["result"] == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 4},
+    }
+    assert isinstance(dry_run["result"]["device"]["count"], int)
 
 
 def test_summary_claims_nothing(dry_run):
@@ -139,7 +149,37 @@ def test_no_argument_run_refuses_a_cpu():
     assert "platform cpu is not tpu" in proc.stderr
     records = [json.loads(line) for line in proc.stdout.splitlines()]
     assert [r.get("stage") for r in records] == ["probe"]
-    assert not any(r.get("ok") for r in records)
+    assert not any("ok" in r for r in records)
+
+
+def test_stage_failure_on_an_accepted_device_is_a_false_result(
+    monkeypatch, capsys, tmp_path
+):
+    """Once the probe's device is accepted, a failed stage ends stdout with
+    ``{"ok": false, "device": ...}`` and the exit code is still 1."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("chip_smoke_ut", SMOKE)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    probe = {
+        "stage": "probe", "platform": "tpu", "device_kind": "TPU v5 lite",
+        "device_count": 1, "versions": {}, "native": {"loaded": True},
+    }
+
+    def fail(*args):
+        raise smoke.StageFailed("boom")
+
+    monkeypatch.setattr(smoke, "OUT_DIR", str(tmp_path))
+    monkeypatch.setattr(smoke, "run_child", lambda *a, **k: probe)
+    monkeypatch.setattr(smoke, "stage_serve", fail)
+    assert smoke.run_parent(dry_run=False) == 1
+    out = capsys.readouterr()
+    assert "boom" in out.err
+    assert json.loads(out.out.splitlines()[-1]) == {
+        "ok": False,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
 
 
 def test_script_alone_fails_without_a_result(tmp_path):
@@ -154,7 +194,7 @@ def test_script_alone_fails_without_a_result(tmp_path):
     )
     assert proc.returncode != 0
     assert "llm_weighted_consensus_tpu" in proc.stderr  # the import error
-    assert '"ok": true' not in proc.stdout
+    assert '"ok"' not in proc.stdout
 
 
 _CACHE_PROBE = textwrap.dedent(

@@ -545,6 +545,7 @@ def child_kernels(dry_run: bool) -> int:
     h, inter = config.hidden_size, config.intermediate_size
     rng = np.random.default_rng(0)
     checks = []
+    device = device_summary()  # every line says where it ran
 
     def normal(shape, scale=1.0, dt=dtype):
         return jnp.asarray(rng.standard_normal(shape) * scale, dt)
@@ -560,7 +561,7 @@ def child_kernels(dry_run: bool) -> int:
     def check(name, kernel_fn, ref_fn, args, tol=tol):
         """Compile ``kernel_fn``, require the Mosaic custom call, run it
         and ``ref_fn`` on the device, compare."""
-        rec = {"check": name}
+        rec = {"check": name, **device}
         try:
             t0 = time.monotonic()
             compiled = jax.jit(kernel_fn).lower(*args).compile()
@@ -689,7 +690,7 @@ def child_kernels(dry_run: bool) -> int:
         ("int8", config.num_layers, 0.98, 0.1),
         ("int4-pallas", 2, 0.95, 0.15),
     ):
-        rec = {"check": f"forward[{mode}]", "layers": layers}
+        rec = {"check": f"forward[{mode}]", **device, "layers": layers}
         try:
             t0 = time.monotonic()
             conf, emb = outputs(
@@ -724,7 +725,7 @@ def child_kernels(dry_run: bool) -> int:
     emit(
         {
             "stage": "kernels",
-            **device_summary(),
+            **device,
             "versions": versions(),
             "setup_s": round(time.monotonic() - t_start, 1),
             "checks": len(checks),

@@ -98,6 +98,10 @@ def test_every_stage_line_names_device_and_versions(dry_run):
         assert stage["device_count"] == 4, name
         assert set(stage["versions"]) == {"jax", "jaxlib", "libtpu"}, name
         assert stage["pass"] is True and stage["dry_run"] is True, name
+    for rec in dry_run["checks"] + [dry_run["summary"]]:
+        assert (rec["platform"], rec["device_kind"], rec["device_count"]) == (
+            "cpu", "cpu", 4
+        ), rec
     assert dry_run["stages"]["probe"]["native"] == {
         "loaded": True, "error": None
     }

@@ -100,9 +100,9 @@ def make_requests(n_requests: int, n_candidates: int, seed: int = 0) -> list:
 
 def phase_summary() -> dict:
     """The phase-breakdown block every BENCH record embeds (ISSUE 11):
-    per-phase p50/p99 from the process-global aggregator plus the device
-    share of attributed time.  Harnesses call ``reset_phases()`` right
-    before their timed window so the summary covers exactly it."""
+    per-phase p50/p99 from the process-global aggregator.  Harnesses call
+    ``reset_phases()`` right before their timed window so the summary
+    covers exactly it."""
     from llm_weighted_consensus_tpu.obs import phases_snapshot
 
     snap = phases_snapshot()
@@ -111,10 +111,7 @@ def phase_summary() -> dict:
         for phase, row in snap.items()
         if isinstance(row, dict) and row.get("count")
     }
-    return {
-        "phases": phases,
-        "device_time_share": snap.get("device_time_share"),
-    }
+    return {"phases": phases}
 
 
 def consensus_quality_summary() -> dict:

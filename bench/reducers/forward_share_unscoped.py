@@ -1,0 +1,8 @@
+"""forward.share.unscoped.*: per cent of the model programs' device time under
+the ``unscoped`` scopes (``scope_time.GROUPS``)."""
+
+import scope_time
+
+
+def reduce(ctx):
+    return scope_time.share(ctx, "unscoped")

@@ -1124,7 +1124,6 @@ async def bench_overlap(args) -> None:
             round(on_good / off_good, 3) if off_good else None
         ),
         overlap=phases.get("overlap"),
-        device_time_share=phases.get("device_time_share"),
         host_tokenizer_workers=batcher_stats.get("host_tokenizer_workers"),
         staging=batcher_stats.get("staging"),
         **results,

@@ -93,6 +93,12 @@ _GENERIC_ATTRS = {
     "snapshot",
     "render",
     "total_seconds",
+    # the context-manager protocol called by hand (obs/hostspan.py
+    # enters the profiler's annotation so): a ``with`` statement is never
+    # resolved through the graph, and the same call spelled out must
+    # not resolve to every ``__exit__`` in the package either
+    "__enter__",
+    "__exit__",
 }
 
 # modules whose functions are never bare-name call-resolution TARGETS:

@@ -11,7 +11,8 @@ registry row that keeps a stale dashboard panel looking healthy.
 
 Project-scoped (the invariant spans modules): collects every
 ``register_provider("name", ...)`` call and every span-creating call
-(``child_span`` / ``start_trace`` / ``span`` / ``.child``) with a
+(``child_span`` / ``start_trace`` / ``span`` / ``.child`` /
+``host_span``) with a
 literal or f-string name across the parsed set, then checks both
 directions against whichever registries the set declares.  A run whose
 module set declares neither registry checks nothing — single-file lint
@@ -26,7 +27,7 @@ from typing import List, Optional, Tuple
 from ..engine import Finding, ParsedModule, enclosing_symbol
 from . import Rule
 
-_SPAN_CALLS = {"child_span", "start_trace", "span", "child"}
+_SPAN_CALLS = {"child_span", "start_trace", "span", "child", "host_span"}
 
 
 def _literal_or_prefix(node: ast.AST) -> Optional[str]:

@@ -193,10 +193,13 @@ def _use_fused_attention(
 
 def _layer(x, p, mask_bias, config: BertConfig, segment_ids=None):
     attn = _attention(x, p, mask_bias, config, segment_ids)
-    x = _layer_norm(x + attn, p["attn_ln"], config.layer_norm_eps)
+    with jax.named_scope("attn_ln"):
+        x = _layer_norm(x + attn, p["attn_ln"], config.layer_norm_eps)
     # GELU fuses into the mlp_in epilogue on the int8 path (layers.mlp_cfg)
-    mlp = _mlp_cfg(x, p["mlp_in"], p["mlp_out"], config)
-    return _layer_norm(x + mlp, p["mlp_ln"], config.layer_norm_eps)
+    with jax.named_scope("mlp"):
+        mlp = _mlp_cfg(x, p["mlp_in"], p["mlp_out"], config)
+    with jax.named_scope("mlp_ln"):
+        return _layer_norm(x + mlp, p["mlp_ln"], config.layer_norm_eps)
 
 
 def encode(
@@ -286,22 +289,23 @@ def pool(
     normalize: bool = True,
 ) -> jax.Array:
     """hidden[b, s, h] -> embedding[b, h] (bge uses CLS + l2-normalize)."""
-    if pooling == "cls":
-        emb = hidden[:, 0, :]
-    elif pooling == "mean":
-        # f32 reductions regardless of activation dtype (module contract):
-        # bf16 cannot even represent token counts > 256 exactly
-        mask = attention_mask[:, :, None].astype(jnp.float32)
-        emb = jnp.sum(hidden.astype(jnp.float32) * mask, axis=1) / jnp.maximum(
-            jnp.sum(mask, axis=1), 1
-        )
-    else:
-        raise ValueError(f"unknown pooling {pooling!r}")
-    emb = emb.astype(jnp.float32)
-    if normalize:
-        norm = jnp.sqrt(jnp.sum(emb * emb, axis=-1, keepdims=True))
-        emb = emb / jnp.maximum(norm, 1e-12)
-    return emb
+    with jax.named_scope("pool"):
+        if pooling == "cls":
+            emb = hidden[:, 0, :]
+        elif pooling == "mean":
+            # f32 reductions regardless of activation dtype (module contract):
+            # bf16 cannot even represent token counts > 256 exactly
+            mask = attention_mask[:, :, None].astype(jnp.float32)
+            emb = jnp.sum(hidden.astype(jnp.float32) * mask, axis=1) / jnp.maximum(
+                jnp.sum(mask, axis=1), 1
+            )
+        else:
+            raise ValueError(f"unknown pooling {pooling!r}")
+        emb = emb.astype(jnp.float32)
+        if normalize:
+            norm = jnp.sqrt(jnp.sum(emb * emb, axis=-1, keepdims=True))
+            emb = emb / jnp.maximum(norm, 1e-12)
+        return emb
 
 
 def pool_segments(
@@ -320,27 +324,28 @@ def pool_segments(
     to the zero vector under ``mean`` and to whatever token sits at
     offset 0 under ``cls`` — the host-side unpack only reads slots the
     planner filled, so both are fine."""
-    if pooling == "cls":
-        emb = jnp.take_along_axis(hidden, seg_starts[:, :, None], axis=1)
-    elif pooling == "mean":
-        # f32 reductions regardless of activation dtype (module contract)
-        k = seg_starts.shape[1]
-        one_hot = (
-            segment_ids[:, :, None] == (jnp.arange(k) + 1)[None, None, :]
-        ).astype(jnp.float32)  # [b, s, k]
-        emb = jnp.einsum(
-            "bsh,bsk->bkh",
-            hidden.astype(jnp.float32),
-            one_hot,
-            preferred_element_type=jnp.float32,
-        ) / jnp.maximum(jnp.sum(one_hot, axis=1), 1.0)[:, :, None]
-    else:
-        raise ValueError(f"unknown pooling {pooling!r}")
-    emb = emb.astype(jnp.float32)
-    if normalize:
-        norm = jnp.sqrt(jnp.sum(emb * emb, axis=-1, keepdims=True))
-        emb = emb / jnp.maximum(norm, 1e-12)
-    return emb
+    with jax.named_scope("pool"):
+        if pooling == "cls":
+            emb = jnp.take_along_axis(hidden, seg_starts[:, :, None], axis=1)
+        elif pooling == "mean":
+            # f32 reductions regardless of activation dtype (module contract)
+            k = seg_starts.shape[1]
+            one_hot = (
+                segment_ids[:, :, None] == (jnp.arange(k) + 1)[None, None, :]
+            ).astype(jnp.float32)  # [b, s, k]
+            emb = jnp.einsum(
+                "bsh,bsk->bkh",
+                hidden.astype(jnp.float32),
+                one_hot,
+                preferred_element_type=jnp.float32,
+            ) / jnp.maximum(jnp.sum(one_hot, axis=1), 1.0)[:, :, None]
+        else:
+            raise ValueError(f"unknown pooling {pooling!r}")
+        emb = emb.astype(jnp.float32)
+        if normalize:
+            norm = jnp.sqrt(jnp.sum(emb * emb, axis=-1, keepdims=True))
+            emb = emb / jnp.maximum(norm, 1e-12)
+        return emb
 
 
 @partial(jax.jit, static_argnames=("config", "pooling", "normalize"))

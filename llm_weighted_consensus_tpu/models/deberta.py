@@ -116,15 +116,19 @@ def _disentangled_attention(x, rel, p, mask_bias, config: DebertaConfig):
     nh, hd = config.num_heads, config.head_dim
     k = config.att_span
 
-    q_c = _dense_cfg(x, p["attn_q"], config).reshape(b, s, nh, hd)
-    k_c = _dense_cfg(x, p["attn_k"], config).reshape(b, s, nh, hd)
-    v = _dense_cfg(x, p["attn_v"], config).reshape(b, s, nh, hd)
-    # relative projections of the shared table: [2k, nh, hd] — always
-    # full precision: one tiny matmul per forward, position-sensitive
-    q_r = _dense(rel, p["pos_q"]).reshape(2 * k, nh, hd)
-    k_r = _dense(rel, p["pos_k"]).reshape(2 * k, nh, hd)
-
-    rel_idx = _rel_index(s, config)  # [s, s]
+    # the scope names are bert.py's where the work is the same, so a
+    # trace reads alike for both families (bench/scope_time.py);
+    # ``rel_bias`` is this family's own: the bucketed position terms
+    with jax.named_scope("qkv_proj"):
+        q_c = _dense_cfg(x, p["attn_q"], config).reshape(b, s, nh, hd)
+        k_c = _dense_cfg(x, p["attn_k"], config).reshape(b, s, nh, hd)
+        v = _dense_cfg(x, p["attn_v"], config).reshape(b, s, nh, hd)
+    with jax.named_scope("rel_bias"):
+        # relative projections of the shared table: [2k, nh, hd] — always
+        # full precision: one tiny matmul per forward, position-sensitive
+        q_r = _dense(rel, p["pos_q"]).reshape(2 * k, nh, hd)
+        k_r = _dense(rel, p["pos_k"]).reshape(2 * k, nh, hd)
+        rel_idx = _rel_index(s, config)  # [s, s]
 
     # The three disentangled score tensors store in the activation dtype
     # (f32 MXU accumulation unchanged) like every other matmul in the
@@ -132,35 +136,41 @@ def _disentangled_attention(x, rel, p, mask_bias, config: DebertaConfig):
     # THREE [b, nh, s, s] intermediates + two bucket gathers (the same
     # r4 cut measured on bert.py's single logits tensor); the f32 parity
     # path is byte-identical.  Softmax stays f32 per the module contract.
-    # content -> content
-    c2c = jnp.einsum(
-        "bqnd,bknd->bnqk", q_c, k_c, preferred_element_type=x.dtype
-    )
-    # content -> position: q_c against every bucket, then gather per (i, j)
-    c2p_all = jnp.einsum(
-        "bqnd,rnd->bnqr", q_c, k_r, preferred_element_type=x.dtype
-    )  # [b, nh, s, 2k]
-    c2p = jnp.take_along_axis(
-        c2p_all, rel_idx[None, None, :, :], axis=-1
-    )  # [b, nh, s, s]
-    # position -> content: k_c against every bucket, transposed gather
-    p2c_all = jnp.einsum(
-        "bknd,rnd->bnkr", k_c, q_r, preferred_element_type=x.dtype
-    )  # [b, nh, s, 2k]
-    p2c = jnp.take_along_axis(
-        p2c_all, rel_idx.T[None, None, :, :], axis=-1
-    )  # [b, nh, k_pos=s, q_pos=s] -> transpose to [b, nh, q, k]
-    p2c = jnp.swapaxes(p2c, -1, -2)
+    with jax.named_scope("rel_bias"):
+        # content -> position: q_c against every bucket, then gather per
+        # (i, j)
+        c2p_all = jnp.einsum(
+            "bqnd,rnd->bnqr", q_c, k_r, preferred_element_type=x.dtype
+        )  # [b, nh, s, 2k]
+        c2p = jnp.take_along_axis(
+            c2p_all, rel_idx[None, None, :, :], axis=-1
+        )  # [b, nh, s, s]
+        # position -> content: k_c against every bucket, transposed gather
+        p2c_all = jnp.einsum(
+            "bknd,rnd->bnkr", k_c, q_r, preferred_element_type=x.dtype
+        )  # [b, nh, s, 2k]
+        p2c = jnp.take_along_axis(
+            p2c_all, rel_idx.T[None, None, :, :], axis=-1
+        )  # [b, nh, k_pos=s, q_pos=s] -> transpose to [b, nh, q, k]
+        p2c = jnp.swapaxes(p2c, -1, -2)
 
-    # python-float scale + same-dtype bias keep the sum in x.dtype (an
-    # f32 scalar would silently promote all three tensors back to f32)
-    scale = 1.0 / float(3 * hd) ** 0.5
-    logits = (c2c + c2p + p2c) * scale + mask_bias.astype(x.dtype)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(x.dtype)
-    ctx = jnp.einsum(
-        "bnqk,bknd->bqnd", probs, v, preferred_element_type=jnp.float32
-    ).astype(x.dtype)
-    return _dense_cfg(ctx.reshape(b, s, h), p["attn_out"], config)
+    with jax.named_scope("attention"):
+        # content -> content
+        c2c = jnp.einsum(
+            "bqnd,bknd->bnqk", q_c, k_c, preferred_element_type=x.dtype
+        )
+        # python-float scale + same-dtype bias keep the sum in x.dtype (an
+        # f32 scalar would silently promote all three tensors back to f32)
+        scale = 1.0 / float(3 * hd) ** 0.5
+        logits = (c2c + c2p + p2c) * scale + mask_bias.astype(x.dtype)
+        probs = jax.nn.softmax(
+            logits.astype(jnp.float32), axis=-1
+        ).astype(x.dtype)
+        ctx = jnp.einsum(
+            "bnqk,bknd->bqnd", probs, v, preferred_element_type=jnp.float32
+        ).astype(x.dtype)
+    with jax.named_scope("attn_out"):
+        return _dense_cfg(ctx.reshape(b, s, h), p["attn_out"], config)
 
 
 def encode(
@@ -177,11 +187,12 @@ def encode(
     row spans, so within-segment relative distances are exactly those of
     the padded forward; the segment mask removes every cross-segment
     (wrong-distance) term.  No position plumbing needed, unlike bert.py."""
-    x = params["token_embed"][input_ids]
-    x = _layer_norm(x, params["embed_ln"], config.layer_norm_eps)
-    rel = _layer_norm(
-        params["rel_embed"], params["rel_ln"], config.layer_norm_eps
-    )
+    with jax.named_scope("embeddings"):
+        x = params["token_embed"][input_ids]
+        x = _layer_norm(x, params["embed_ln"], config.layer_norm_eps)
+        rel = _layer_norm(
+            params["rel_embed"], params["rel_ln"], config.layer_norm_eps
+        )
     if segment_ids is None:
         mask_bias = jnp.where(
             attention_mask[:, None, None, :] > 0, 0.0, -1e9
@@ -193,16 +204,24 @@ def encode(
 
     def body(carry, layer_p):
         attn = _disentangled_attention(carry, rel, layer_p, mask_bias, config)
-        y = _layer_norm(carry + attn, layer_p["attn_ln"], config.layer_norm_eps)
+        with jax.named_scope("attn_ln"):
+            y = _layer_norm(
+                carry + attn, layer_p["attn_ln"], config.layer_norm_eps
+            )
         # exact-erf GELU (layers.gelu_erf: exact for f32, A&S for bf16):
         # HF deberta-v2's hidden_act is "gelu" = erf — jax.nn.gelu's
         # default tanh approximation silently diverged here (r4 fix; the
         # head below already used approximate=False).  On the int8 path
         # mlp_cfg folds the GELU into the mlp_in kernel epilogue.
-        mlp = _mlp_cfg(y, layer_p["mlp_in"], layer_p["mlp_out"], config)
-        return _layer_norm(y + mlp, layer_p["mlp_ln"], config.layer_norm_eps), None
+        with jax.named_scope("mlp"):
+            mlp = _mlp_cfg(y, layer_p["mlp_in"], layer_p["mlp_out"], config)
+        with jax.named_scope("mlp_ln"):
+            return _layer_norm(
+                y + mlp, layer_p["mlp_ln"], config.layer_norm_eps
+            ), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    with jax.named_scope("encoder_layers"):
+        x, _ = jax.lax.scan(body, x, params["layers"])
     return x
 
 
@@ -221,10 +240,11 @@ def reward(
     ``DebertaV2ForSequenceClassification`` RM checkpoints reproduce their
     trained rewards (tests/test_hf_parity.py)."""
     hidden = encode(params, input_ids, attention_mask, config)
-    cls = hidden[:, 0, :].astype(jnp.float32)
-    z = _dense(cls, params["head_dense"]).astype(jnp.float32)
-    z = jax.nn.gelu(z, approximate=False)
-    return _dense(z, params["head_out"]).astype(jnp.float32)[:, 0]
+    with jax.named_scope("head"):
+        cls = hidden[:, 0, :].astype(jnp.float32)
+        z = _dense(cls, params["head_dense"]).astype(jnp.float32)
+        z = jax.nn.gelu(z, approximate=False)
+        return _dense(z, params["head_out"]).astype(jnp.float32)[:, 0]
 
 
 @partial(jax.jit, static_argnames=("config",))
@@ -244,12 +264,13 @@ def reward_packed(
     hidden = encode(
         params, input_ids, attention_mask, config, segment_ids=segment_ids
     )
-    cls = jnp.take_along_axis(
-        hidden, seg_starts[:, :, None], axis=1
-    ).astype(jnp.float32)  # [b, k, h]
-    z = _dense(cls, params["head_dense"]).astype(jnp.float32)
-    z = jax.nn.gelu(z, approximate=False)
-    return _dense(z, params["head_out"]).astype(jnp.float32)[:, :, 0]
+    with jax.named_scope("head"):
+        cls = jnp.take_along_axis(
+            hidden, seg_starts[:, :, None], axis=1
+        ).astype(jnp.float32)  # [b, k, h]
+        z = _dense(cls, params["head_dense"]).astype(jnp.float32)
+        z = jax.nn.gelu(z, approximate=False)
+        return _dense(z, params["head_out"]).astype(jnp.float32)[:, :, 0]
 
 
 @partial(jax.jit, static_argnames=("temperature",))

@@ -17,6 +17,7 @@ MAX_CONSENSUS_CANDIDATES.
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Iterable, Optional
 
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import phases as _phases
 from . import deberta
 from .configs import DEBERTA_TEST_TINY, DEBERTA_V3_BASE, DebertaConfig
 from .tokenizer import BaseTokenizer, load_tokenizer
@@ -105,6 +107,11 @@ class TpuReranker:
         if prompt:
             texts = [f"{prompt}\n{text}" for text in texts]
         ids, mask = self.tokenize(texts)
+        # the dispatch label, like the embedder's ``vote1(n=..,s=..)``:
+        # device time per (N, sequence bucket) in the ``roofline``
+        # section of /metrics and under the ``device_dispatch`` phase
+        label = f"rm_vote(n={ids.shape[0]},s={ids.shape[1]})"
+        t0 = time.perf_counter()
         conf = np.asarray(
             _reward_and_vote(
                 self.params,
@@ -114,6 +121,9 @@ class TpuReranker:
                 self.config,
             )
         )
+        t1 = time.perf_counter()
+        _phases.observe_device(label, (t1 - t0) * 1e3)
+        _phases.observe_device_interval(t0, t1)
         return conf, int(mask.sum())
 
 

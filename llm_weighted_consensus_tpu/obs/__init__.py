@@ -9,9 +9,21 @@ The whole package is dependency-free below ``utils`` so any layer
 (cache, clients, batcher, resilience) can instrument without cycles.
 With no sink configured nothing ever activates a root span, and every
 ambient helper here is a single contextvar read returning None.
+
+``hostspan`` — the one helper behind every boundary of the serving host
+path: a ``host_span`` feeds the phase histograms, the profiler's trace and
+the span tree from one call (ISSUE 24).
 """
 
 from .histogram import Histogram  # noqa: F401
+from .hostspan import (  # noqa: F401
+    HOST_SPANS,
+    arrive,
+    host_span,
+    next_id,
+    request_id,
+    set_profiler_annotation,
+)
 from .ledger import (  # noqa: F401
     LEDGER_SCHEMA,
     OutcomeLedger,

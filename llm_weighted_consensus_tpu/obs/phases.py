@@ -6,14 +6,26 @@ aggregate.  Every scored request is bracketed through a fixed phase
 vocabulary:
 
 * ``admission_wait``  — gateway door to admission slot held
+* ``http_parse``      — body parsed and validated (span ``http:parse``)
+* ``tokenize``        — an item's texts to padded rows, on the host
+  tokenizer pool at submit or inline in the stage hop (``host:tokenize``)
 * ``batcher_queue``   — item enqueued to its group taking the device
 * ``pack_plan``       — host-side ragged packing plan (packed path)
+* ``stage``           — a group's rows joined, padded, put on the device
+  and its program enqueued (``batcher:stage``)
 * ``device_dispatch`` — the device executable itself, measured
   enqueue-to-ready at the embedder seam (models/dispatch_seam.py: the
   batcher's waiter thread blocks; direct callers pay an inline
   bracket), per (mesh-shape, bucket)
+* ``finalize``        — results fetched, converted and split per item
+  (``host:finalize``)
 * ``host_tally``      — consensus tally / packed reassembly on host
 * ``upstream_judge``  — judge LLM streaming fan-out
+* ``http_respond``    — result in hand to response object built
+  (``http:respond``)
+
+The phases named with a span are fed by ``obs.host_span`` (hostspan.py),
+which also puts the span on the profiler's clock and on the request's tree.
 
 Two consumers, two mechanisms:
 
@@ -48,11 +60,16 @@ from .histogram import Histogram
 # section and the BENCH phase summaries render exactly these keys
 PHASES = (
     "admission_wait",
+    "http_parse",
+    "tokenize",
     "batcher_queue",
     "pack_plan",
+    "stage",
     "device_dispatch",
+    "finalize",
     "host_tally",
     "upstream_judge",
+    "http_respond",
 )
 
 
@@ -113,26 +130,19 @@ class PhaseAggregator:
 
     def snapshot(self) -> dict:
         """The /metrics ``phases`` section: per-phase histogram summary
-        plus the device share of all attributed time (the figure
-        BENCH_r03 had to hand-derive) and the ``overlap`` gauge
-        (ISSUE 13): device-busy union-interval over wall time across the
-        retained dispatch window.  ~1.0 means pipelined dispatches keep
-        the device continuously busy; a fully serialized pipeline with
-        host work between dispatches reads well below 1.  None until
-        two dispatches have landed (no overlap to speak of)."""
+        plus the ``overlap`` gauge (ISSUE 13): device-busy
+        union-interval over wall time across the retained dispatch
+        window.  ~1.0 means pipelined dispatches keep the device
+        continuously busy; a fully serialized pipeline with host work
+        between dispatches reads well below 1.  None until two
+        dispatches have landed (no overlap to speak of)."""
         with self._lock:
             rows = {
                 phase: hist.to_json_obj()
                 for phase, hist in self._phases.items()
             }
-            total = sum(h.sum for h in self._phases.values())
-            device = self._phases.get("device_dispatch")
-            device_sum = device.sum if device is not None else 0.0
             intervals = list(self._intervals)
         out: dict = {phase: rows[phase] for phase in PHASES if phase in rows}
-        out["device_time_share"] = (
-            round(device_sum / total, 4) if total > 0 else None
-        )
         overlap = None
         if len(intervals) >= 2:
             wall = max(e for _, e in intervals) - min(s for s, _ in intervals)
@@ -227,20 +237,21 @@ def _union_ms(intervals: List[Tuple[float, float]]) -> float:
 def phase_breakdown(trace) -> dict:
     """Attribute one finished trace's wall time to the phase vocabulary.
 
-    Span-derived: ``batcher:*`` minus its ``device:dispatch`` children
-    is queue time; the dispatch bracket minus the batcher span's
-    annotated host sub-costs (``pack_plan_ms`` / ``host_tally_ms``,
-    stamped per item by the packed dispatch) is device time;
-    ``consensus:tally`` and ``judge:stream`` map directly;
-    ``admission_wait_ms`` rides a root annotation (the admission
-    middleware runs before any child span exists).  Returns
-    ``{phase: ms}`` plus ``e2e_ms`` and the unattributed ``other_ms``
-    remainder — the acceptance bar is that the named phases sum to
-    within 10% of ``e2e_ms`` on a served request."""
+    Span-derived, each interval of wall time attributed once, to the
+    innermost thing that names it: the host spans (``host:tokenize``,
+    ``batcher:stage``, ``host:finalize``) first, then what is left of
+    the ``device:dispatch`` bracket around them (minus the batcher
+    span's annotated host sub-costs ``pack_plan_ms`` / ``host_tally_ms``,
+    stamped per item by the packed dispatch) is device time, then what
+    is left of the item's ``batcher:<kind>`` span is queue time;
+    ``http:parse``, ``http:respond``, ``consensus:tally`` and
+    ``judge:stream`` map directly; ``admission_wait_ms`` rides a root
+    annotation (the admission middleware runs before any child span
+    exists).  Returns ``{phase: ms}`` plus ``e2e_ms`` and the
+    unattributed ``other_ms`` remainder — the acceptance bar is that the
+    named phases sum to within 10% of ``e2e_ms`` on a served request."""
+    by_name: Dict[str, List[Tuple[float, float]]] = {}
     batcher: List[Tuple[float, float]] = []
-    device: List[Tuple[float, float]] = []
-    tally: List[Tuple[float, float]] = []
-    judge: List[Tuple[float, float]] = []
     pack_plan_ms = 0.0
     tally_attr_ms = 0.0
     root = trace.spans[0] if trace.spans else None
@@ -249,31 +260,49 @@ def phase_breakdown(trace) -> dict:
         if dur is None:
             continue
         start = span.start_ms()
-        interval = (start, start + dur)
         name = span.name
-        if name.startswith("batcher:"):
-            batcher.append(interval)
+        if name in _BREAKDOWN_SPANS:
+            by_name.setdefault(name, []).append((start, start + dur))
+        elif name.startswith("batcher:"):
+            batcher.append((start, start + dur))
             pack_plan_ms += float(span.attributes.get("pack_plan_ms", 0.0))
             tally_attr_ms += float(span.attributes.get("host_tally_ms", 0.0))
-        elif name == "device:dispatch":
-            device.append(interval)
-        elif name == "consensus:tally":
-            tally.append(interval)
-        elif name == "judge:stream":
-            judge.append(interval)
-    device_ms = _union_ms(device)
-    batcher_ms = max(0.0, _union_ms(batcher) - device_ms)
+
+    def of(name: str) -> List[Tuple[float, float]]:
+        return by_name.get(name, [])
+
+    # each layer's share is what it adds to the union of those inside it
+    covered: List[Tuple[float, float]] = []
+    total = 0.0
+    grown = {}
+    for key, intervals in (
+        ("tokenize", of("host:tokenize")),
+        ("stage", of("batcher:stage")),
+        ("finalize", of("host:finalize")),
+        ("device_dispatch", of("device:dispatch")),
+        ("batcher_queue", batcher),
+    ):
+        covered += intervals
+        grown[key] = _union_ms(covered) - total
+        total += grown[key]
     out = {
         "admission_wait": float(
             root.attributes.get("admission_wait_ms", 0.0)
         )
         if root is not None
         else 0.0,
-        "batcher_queue": batcher_ms,
+        "http_parse": _union_ms(of("http:parse")),
+        "tokenize": grown["tokenize"],
+        "batcher_queue": grown["batcher_queue"],
         "pack_plan": pack_plan_ms,
-        "device_dispatch": max(0.0, device_ms - pack_plan_ms - tally_attr_ms),
-        "host_tally": _union_ms(tally) + tally_attr_ms,
-        "upstream_judge": _union_ms(judge),
+        "stage": grown["stage"],
+        "device_dispatch": max(
+            0.0, grown["device_dispatch"] - pack_plan_ms - tally_attr_ms
+        ),
+        "finalize": grown["finalize"],
+        "host_tally": _union_ms(of("consensus:tally")) + tally_attr_ms,
+        "upstream_judge": _union_ms(of("judge:stream")),
+        "http_respond": _union_ms(of("http:respond")),
     }
     out = {k: round(v, 3) for k, v in out.items()}
     e2e = root.duration_ms() if root is not None else None
@@ -282,3 +311,19 @@ def phase_breakdown(trace) -> dict:
         out["e2e_ms"] = e2e
         out["other_ms"] = round(max(0.0, e2e - attributed), 3)
     return out
+
+
+# the span names ``phase_breakdown`` reads by name (an item's own
+# ``batcher:<kind>`` span is whatever else starts with ``batcher:``)
+_BREAKDOWN_SPANS = frozenset(
+    (
+        "http:parse",
+        "host:tokenize",
+        "batcher:stage",
+        "device:dispatch",
+        "host:finalize",
+        "consensus:tally",
+        "judge:stream",
+        "http:respond",
+    )
+)

@@ -60,6 +60,13 @@ KNOWN_SPANS = (
     "gateway:*",
     "batcher:*",
     "device:dispatch",
+    "device:wait",
+    "host:tokenize",
+    "host:finalize",
+    "http:arrive",
+    "http:parse",
+    "http:respond",
+    "lwc:clock",
     "singleflight:wait",
     "cache:lookup",
     "consensus:tally",

@@ -733,8 +733,17 @@ def _device_stats(embedder, reranker) -> dict:
         row = {"id": d.id}
         stats = d.memory_stats()  # None where the backend keeps none (CPU)
         if stats:
-            row["bytes_in_use"] = stats.get("bytes_in_use")
-            row["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
+            # ``*_in_use`` counts buffers; a compiled program's
+            # temporaries live in a region the runtime RESERVES (it
+            # grows to the largest program run and is kept), so what the
+            # device holds is the two together
+            for key in (
+                "bytes_in_use",
+                "peak_bytes_in_use",
+                "bytes_reserved",
+                "peak_bytes_reserved",
+            ):
+                row[key] = stats.get(key)
         per_device.append(row)
     out = {
         **device_summary(),
@@ -957,7 +966,15 @@ def build_service(
         # jit-cache introspection on /metrics: AOT bucket count + live
         # specialization counts (asserting "zero new specializations
         # post-warmup" is observable in production, not just in tests)
-        metrics.register_provider("jit", embedder.jit_stats)
+        # and every compilation the backend was asked for, the helper
+        # programs no entry point names included (config.py)
+        if compile_cache is not None:
+            metrics.register_provider(
+                "jit",
+                lambda: {**embedder.jit_stats(), **compile_cache.compiles()},
+            )
+        else:
+            metrics.register_provider("jit", embedder.jit_stats)
     if embedder is not None and getattr(embedder, "mesh_mode", False):
         # mesh-serving introspection: the shape traffic shards over and
         # the per-(mesh-shape, bucket) AOT coverage

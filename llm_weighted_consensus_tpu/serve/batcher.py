@@ -59,12 +59,13 @@ from typing import Optional
 import numpy as np
 
 from ..models import dispatch_seam as _seam
+from ..obs import hostspan as _hostspan
 
 
 class _Item:
     __slots__ = (
         "kind", "key", "payload", "future", "deadline", "span",
-        "redispatches", "submitted", "prepared", "lane",
+        "redispatches", "submitted", "prepared", "lane", "rid",
     )
 
     def __init__(
@@ -92,6 +93,10 @@ class _Item:
         # whose ambient context is stale, so device timing children hang
         # off this explicit handle instead of contextvars
         self.span = span
+        # the request's id on every host span (obs/hostspan.py): its trace
+        # id where it has a root span, else a number of the process-wide
+        # sequence; captured at submit like the span, for the same reason
+        self.rid = _hostspan.request_id()
         # times this item was re-queued after a classified device fault
         # (resilience/meshfault.py) — bounded so a fault loop can never
         # recycle one item forever
@@ -102,17 +107,33 @@ class _Item:
         self.prepared = None
 
 
+def _rids(group: list) -> str:
+    """A group's request ids as one span attribute: joined by a space (a
+    comma would end the value in the profiler's encoding)."""
+    return " ".join(str(item.rid) for item in group)
+
+
+def _labels(sink) -> str:
+    """The dispatch labels a stage hop enqueued (``many(r=8,n=64,s=512)``;
+    several where one group made several device calls)."""
+    return " ".join(record.label for record in sink.pending)
+
+
 class _StagedGroup:
     """What the dispatch hop hands the waiter hop: the group's deferred-
     readiness sink (pending device dispatches + checked-out staging
     buffers) and the finalize closure that materializes per-item results
     after readiness."""
 
-    __slots__ = ("sink", "finalize")
+    __slots__ = ("sink", "finalize", "group", "spans")
 
-    def __init__(self, sink, finalize) -> None:
+    def __init__(self, sink, finalize, group=None, spans=()) -> None:
         self.sink = sink
         self.finalize = finalize
+        # the dispatch group's id and its traced items' spans: the waiter
+        # hop's host spans carry the one and hang on the others
+        self.group = group
+        self.spans = spans
 
 
 class DeviceBatcher:
@@ -246,6 +267,8 @@ class DeviceBatcher:
         # behind queued offline work
         self._pending_offline: list = []
         self._flusher: Optional[asyncio.Task] = None
+        # ``batcher:idle`` held open while no flusher runs (_begin_idle)
+        self._idle_span = None
         self._sem: Optional[asyncio.Semaphore] = None
         # set by _submit so a parked _drain starts new work immediately
         # instead of waiting out an in-flight dispatch
@@ -536,6 +559,7 @@ class DeviceBatcher:
         )
 
     def close(self) -> None:
+        self._end_idle()
         self._executor.shutdown(wait=False)
         self._waiters.shutdown(wait=False)
         if self._tok_pool is not None:
@@ -776,12 +800,13 @@ class DeviceBatcher:
             # re-raise on the dispatch thread, same path as before
             try:
                 item.prepared = self._tok_pool.submit(
-                    self._prepare_item, kind, key, payload
+                    self._prepare_item, item
                 )
             except RuntimeError:  # pool shut down mid-close
                 item.prepared = None
         (self._pending_offline if offline else self._pending).append(item)
         if self._flusher is None or self._flusher.done():
+            self._end_idle()
             self._flusher = loop.create_task(self._drain())
         elif self._wake is not None:
             self._wake.set()  # unpark a flusher waiting on in-flight work
@@ -801,7 +826,28 @@ class DeviceBatcher:
                 span.finish("error")
             raise
 
+    def _begin_idle(self) -> None:
+        """No flusher runs between the last group's end and the next
+        arrival: ``batcher:idle`` stays open across that stretch (begun
+        where ``_drain`` ends, ended where ``_submit`` starts the next
+        one, both on the event loop), so a device gap in which nothing
+        was due reads as such in a profile."""
+        self._idle_span = _hostspan.host_span(
+            "batcher:idle", parents=()
+        ).open_span()
+
+    def _end_idle(self) -> None:
+        span, self._idle_span = self._idle_span, None
+        if span is not None:
+            span.close_span()
+
     async def _drain(self) -> None:
+        try:
+            await self._drain_pending()
+        finally:
+            self._begin_idle()
+
+    async def _drain_pending(self) -> None:
         loop = asyncio.get_running_loop()
         if self._sem is None:
             self._sem = asyncio.Semaphore(self.pipeline_depth)
@@ -820,7 +866,16 @@ class DeviceBatcher:
                 # groups hold the device join the NEXT dispatch group
                 # instead of waiting behind a plan made before they
                 # existed (the old snapshot-everything drain)
-                await self._sem.acquire()
+                if self._sem.locked():
+                    with _hostspan.host_span(
+                        "batcher:slots_full",
+                        parents=(),
+                        pending=len(self._pending)
+                        + len(self._pending_offline),
+                    ):
+                        await self._sem.acquire()
+                else:
+                    await self._sem.acquire()
                 # the slot is owned here until _run_group takes it:
                 # release on every non-handoff exit (shed-to-empty,
                 # _shed_group raising) or the pipeline wedges one
@@ -848,10 +903,11 @@ class DeviceBatcher:
                 self._wake.clear()
                 waker = loop.create_task(self._wake.wait())
                 try:
-                    await asyncio.wait(
-                        {waker, *inflight},
-                        return_when=asyncio.FIRST_COMPLETED,
-                    )
+                    with _hostspan.host_span("batcher:idle", parents=()):
+                        await asyncio.wait(
+                            {waker, *inflight},
+                            return_when=asyncio.FIRST_COMPLETED,
+                        )
                 finally:
                     waker.cancel()
 
@@ -1262,6 +1318,18 @@ class DeviceBatcher:
         fallback paths, or a ``_StagedGroup`` whose device work is
         ENQUEUED but not awaited — ``_finalize_group`` (waiter hop)
         finishes it."""
+        spans = [item.span for item in group if item.span is not None]
+        gid = _hostspan.next_id()
+        with _hostspan.host_span(
+            "batcher:stage", parents=spans, group=gid, rids=_rids(group)
+        ) as stage:
+            staged = self._stage(group)
+            if isinstance(staged, _StagedGroup):
+                staged.group, staged.spans = gid, spans
+                stage.annotate(label=_labels(staged.sink))
+        return staged
+
+    def _stage(self, group: list):
         if group[0].key and group[0].key[0] == "packed":
             fn = self._dispatch_packed
         else:
@@ -1312,13 +1380,22 @@ class DeviceBatcher:
         from ..obs import phases as _phases
 
         pool = getattr(self.embedder, "staging_pool", None)
-        _seam.drain_sink(
-            staged.sink,
-            observe_device=_phases.observe_device,
-            observe_interval=_phases.observe_device_interval,
-            release=pool.release if pool is not None else None,
-        )
-        results = staged.finalize()
+        with _hostspan.host_span(
+            "device:wait",
+            parents=staged.spans,
+            group=staged.group,
+            label=_labels(staged.sink),
+        ):
+            _seam.drain_sink(
+                staged.sink,
+                observe_device=_phases.observe_device,
+                observe_interval=_phases.observe_device_interval,
+                release=pool.release if pool is not None else None,
+            )
+        with _hostspan.host_span(
+            "host:finalize", parents=staged.spans, group=staged.group
+        ):
+            results = staged.finalize()
         if self.meshfault is not None and not self._use_fallback:
             # the success note moves with readiness: a dispatch only
             # resets the transient-fault streak once its device work
@@ -1326,25 +1403,59 @@ class DeviceBatcher:
             self.meshfault.note_dispatch_ok()
         return results
 
-    def _prepare_item(self, kind, key, payload):
+    def _prepare_item(self, item):
         """Submit-time host work for one item (lwc-hosttok thread):
         pre-built padded rows for embed/consensus items, or the local-
         index packed plan for packed-key items.  Always runs against the
         PRIMARY embedder's tokenizer; the dispatch falls back to inline
         tokenization when it is serving the CPU twin."""
+        kind, key, payload = item.kind, item.key, item.payload
         if key and key[0] == "packed":
-            return self._plan_packed_payload(kind, payload, self.embedder)
-        if kind == "embed":
-            texts, cap = payload
-            return self.embedder.tokenize(texts, cap)
-        if kind == "ring_embed":
-            texts, cap = payload
-            return self.embedder.tokenize_ring(texts, cap)
-        if kind == "ring_vote":
-            texts, _temperature = payload
-            return self.embedder.tokenize_ring(texts)
-        texts, _temperature = payload
-        return self.embedder.tokenize(texts)
+            # a packed plan tokenizes segment by segment inside the
+            # planner: timed whole, rows and tokens not counted here
+            with _hostspan.host_span(
+                "host:tokenize", parents=(item.span,), rid=item.rid
+            ):
+                return self._plan_packed_payload(
+                    kind, payload, self.embedder
+                )
+        ring = kind in ("ring_embed", "ring_vote")
+        texts, second = payload  # the cap of an embed, a vote's temperature
+        cap = (second,) if kind in ("embed", "ring_embed") else ()
+        return self._tokenize(
+            self.embedder.tokenize_ring if ring else self.embedder.tokenize,
+            texts,
+            *cap,
+            parents=(item.span,),
+            rid=item.rid,
+        )
+
+    @staticmethod
+    def _tokenize(tokenize, texts, *cap, parents, **ids):
+        """texts -> (ids, mask) under ``host:tokenize``: on the host pool
+        for one item (``rid``), or inline in the stage hop for a whole
+        group whose items came without prepared rows (``rids``)."""
+        with _hostspan.host_span(
+            "host:tokenize", parents=parents, **ids
+        ) as span:
+            rows = tokenize(texts, *cap)
+            span.annotate(rows=len(rows[0]), tokens=int(rows[1].sum()))
+        return rows
+
+    def _group_rows(self, group: list, embedder, tokenize, *cap):
+        """The group's rows: joined from its items' submit-time rows, or
+        tokenized here, inside ``batcher:stage`` (pool off, CPU twin,
+        mid-close)."""
+        prepared = self._prepared_rows(group, embedder)
+        if prepared is not None:
+            return prepared
+        return self._tokenize(
+            tokenize,
+            [t for item in group for t in item.payload[0]],
+            *cap,
+            parents=[item.span for item in group],
+            rids=_rids(group),
+        )
 
     def _prepared_rows(self, group: list, embedder):
         """Concatenate the group's submit-time tokenized rows into the
@@ -1383,12 +1494,9 @@ class DeviceBatcher:
     def _dispatch_embed(self, group: list, embedder):
         max_tokens = group[0].payload[1]
         counts = [len(item.payload[0]) for item in group]
-        prepared = self._prepared_rows(group, embedder)
-        if prepared is not None:
-            ids, mask = prepared
-        else:
-            texts = [t for item in group for t in item.payload[0]]
-            ids, mask = embedder.tokenize(texts, max_tokens)
+        ids, mask = self._group_rows(
+            group, embedder, embedder.tokenize, max_tokens
+        )
         self._count_padded(embedder, ids, mask)
         emb = embedder.embed_tokens(ids, mask)
         tokens = mask.sum(axis=1)
@@ -1417,12 +1525,8 @@ class DeviceBatcher:
     def _dispatch_consensus(self, group: list, embedder):
         texts0, temperature = group[0].payload
         n = len(texts0)
-        prepared = self._prepared_rows(group, embedder)
+        ids, mask = self._group_rows(group, embedder, embedder.tokenize)
         if len(group) == 1:
-            if prepared is not None:
-                ids, mask = prepared
-            else:
-                ids, mask = embedder.tokenize(texts0)
             with self._stats_lock:
                 self._pad_real_tokens += int(mask.sum())
                 self._pad_slot_tokens += int(ids.size)
@@ -1435,11 +1539,6 @@ class DeviceBatcher:
                 return [(np.asarray(conf), tok)]
 
             return finalize_one
-        if prepared is not None:
-            ids, mask = prepared
-        else:
-            all_texts = [t for item in group for t in item.payload[0]]
-            ids, mask = embedder.tokenize(all_texts)
         r = len(group)
         from ..utils import next_pow2
 
@@ -1470,12 +1569,9 @@ class DeviceBatcher:
             return self._dispatch_embed(group, embedder)
         max_tokens = group[0].payload[1]
         counts = [len(item.payload[0]) for item in group]
-        prepared = self._prepared_rows(group, embedder)
-        if prepared is not None:
-            ids, mask = prepared
-        else:
-            texts = [t for item in group for t in item.payload[0]]
-            ids, mask = embedder.tokenize_ring(texts, max_tokens)
+        ids, mask = self._group_rows(
+            group, embedder, embedder.tokenize_ring, max_tokens
+        )
         self._count_padded(embedder, ids, mask)
         emb = embedder.embed_tokens_ring(ids, mask)
         tokens = mask.sum(axis=1)
@@ -1507,12 +1603,10 @@ class DeviceBatcher:
             return self._dispatch_consensus(group, embedder)
         staged = []
         for item in group:
-            texts, temperature = item.payload
-            fut = item.prepared
-            if embedder is self.embedder and fut is not None:
-                ids, mask = fut.result()  # re-raises tokenizer errors
-            else:
-                ids, mask = embedder.tokenize_ring(texts)
+            _texts, temperature = item.payload
+            ids, mask = self._group_rows(
+                [item], embedder, embedder.tokenize_ring
+            )
             with self._stats_lock:
                 self._pad_real_tokens += int(mask.sum())
                 self._pad_slot_tokens += int(ids.size)

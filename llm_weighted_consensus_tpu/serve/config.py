@@ -66,7 +66,17 @@ TPU additions:
   window, admission-exempt so an overload can be profiled while the gate
   sheds): JAX profiler traces (xprof format, viewable in
   TensorBoard/xprof) are written under this directory.  Unset =
-  start/stop disabled (404) and ``/v1/profile`` answers 403.
+  start/stop disabled (404) and ``/v1/profile`` answers 403.  While a
+  profile runs, every ``obs.host_span`` of the serving path
+  (``http:arrive``, ``http:parse``, ``host:tokenize``, ``batcher:idle``,
+  ``batcher:slots_full``, ``batcher:stage``, ``device:wait``,
+  ``host:finalize``, ``http:respond``; each with the request's ``rid``
+  or its dispatch ``group``) is in the trace too, on the profiler's
+  clock beside the device's operations, between two ``lwc:clock`` marks
+  that carry ``perf_counter_ns`` and ``epoch_ns``.  ``/v1/profile``
+  captures with the Python tracer off (the host planes hold those spans
+  and the runtime's own events, not every Python frame);
+  ``/profile/start`` keeps the profiler's defaults.
 * ``RM_MODEL`` / ``RM_WEIGHTS`` / ``RM_VOCAB`` / ``RM_MAX_TOKENS`` /
   ``RM_QUANTIZE`` (``int8`` = W8A8 RM serving, default ``none``) — a
   DeBERTa reward model serving ``POST /consensus {"scorer": "rm"}``
@@ -505,27 +515,52 @@ DEFAULT_CACHE_DIR = os.path.join(
 class CompileCacheStats:
     """Persistent-cache hit/miss counts of this process, from JAX's own
     monitoring events (a hit is a compile request answered from the
-    directory; a miss is an executable compiled and written to it)."""
+    directory; a miss is an executable compiled and written to it), and
+    beside them EVERY compilation the process asked its backend for, with
+    the seconds each took: the jitted entry points' specializations, AOT
+    buckets, and the un-named helper programs that a slice or a
+    ``device_put`` of a new shape builds lazily and that no other counter
+    sees.  A cache hit is one of them too: the request still stalls its
+    caller while the executable loads."""
 
     _EVENTS = {
         "/jax/compilation_cache/cache_hits": "hits",
         "/jax/compilation_cache/cache_misses": "misses",
     }
+    _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
     def __init__(self, directory: str) -> None:
         import jax
 
         self.directory = directory
         self.counts = {"hits": 0, "misses": 0}
+        self.backend_compiles = 0
+        self.backend_compile_s = 0.0
         jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration
+        )
 
     def _on_event(self, event: str, **kwargs) -> None:
         name = self._EVENTS.get(event)
         if name is not None:
             self.counts[name] += 1
 
+    def _on_duration(self, event: str, seconds: float, **kwargs) -> None:
+        if event == self._BACKEND_COMPILE:
+            self.backend_compiles += 1
+            self.backend_compile_s += seconds
+
     def snapshot(self) -> dict:
         return {"dir": self.directory, **self.counts}
+
+    def compiles(self) -> dict:
+        """The ``jit`` section's ``backend_compiles`` /
+        ``backend_compile_s``."""
+        return {
+            "backend_compiles": self.backend_compiles,
+            "backend_compile_s": round(self.backend_compile_s, 6),
+        }
 
 
 def configure_compile_cache() -> CompileCacheStats:

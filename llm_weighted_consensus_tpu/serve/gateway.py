@@ -144,9 +144,12 @@ def _note_client_disconnect(request: web.Request) -> None:
 async def _respond_streaming(
     request: web.Request, stream, fastpath: bool = False
 ) -> web.StreamResponse:
-    resp = web.StreamResponse(headers=SSE_HEADERS)
+    # a stream's ``http:respond`` is its response object built, like a
+    # unary answer's; its frames go out across awaits and are in no span
+    with obs.host_span("http:respond", rid=obs.request_id(), status=200):
+        resp = web.StreamResponse(headers=SSE_HEADERS)
+        encoder = frames.FrameEncoder(fastpath)
     await resp.prepare(request)
-    encoder = frames.FrameEncoder(fastpath)
     try:
         async for item in stream:
             if isinstance(item, Exception):
@@ -209,6 +212,15 @@ def _parse_error_response(e: Exception) -> web.Response:
         text=jsonutil.dumps(with_trace_id({"code": 400, "message": message})),
         content_type="application/json",
     )
+
+
+def _respond(rid, build) -> web.Response:
+    """``http:respond``: from the result (or the error) in hand to the
+    response object built, serialization included."""
+    with obs.host_span("http:respond", rid=rid) as span:
+        resp = build()
+        span.annotate(status=resp.status)
+    return resp
 
 
 def deadline_middleware(resilience):
@@ -504,28 +516,33 @@ async def _offline_rescore_disabled(request: web.Request) -> web.Response:
 
 def _make_handler(params_cls, create_streaming, create_unary, fastpath=False):
     async def handler(request: web.Request):
+        rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            body = jsonutil.loads(await request.text())
-            params = params_cls.from_json_obj(body)
+            raw = await request.text()
+            with obs.host_span("http:parse", rid=rid, bytes=len(raw)):
+                params = params_cls.from_json_obj(jsonutil.loads(raw))
         except web.HTTPException:
             raise  # e.g. 413 body-too-large must keep its status
         except Exception as e:  # parse phase is side-effect free: never
             # a server-state fault — 400 with the path-annotated message
             # (or masked, for non-ValueError: see _parse_error_response)
-            return _parse_error_response(e)
+            return _respond(rid, lambda: _parse_error_response(e))
         ctx = request.headers.get("authorization")
         if params.stream:
             try:
                 stream = await create_streaming(ctx, params)
             except Exception as e:
-                return _error_response(e)
+                return _respond(rid, lambda: _error_response(e))
             return await _respond_streaming(request, stream, fastpath)
         try:
             result = await create_unary(ctx, params)
         except Exception as e:
-            return _error_response(e)
-        return web.Response(
-            text=result.to_json(), content_type="application/json"
+            return _respond(rid, lambda: _error_response(e))
+        return _respond(
+            rid,
+            lambda: web.Response(
+                text=result.to_json(), content_type="application/json"
+            ),
         )
 
     return handler
@@ -621,18 +638,51 @@ def _multichat_unary(multichat_client, embedder, batcher):
 def _profile_handlers(profile_dir: str):
     """JAX profiler control (SURVEY §5 tracing row): traces land under
     ``profile_dir`` in xprof format.  One trace at a time; stop without
-    start is a 400 rather than a crash."""
-    import asyncio
+    start is a 400 rather than a crash.
 
+    While a profile runs, every ``obs.host_span`` of the serving path is
+    in it too, on the profiler's clock (the annotation class is handed to
+    ``obs`` here, the one place a profile starts), and the trace opens
+    and closes with a ``lwc:clock`` mark that carries ``perf_counter_ns``
+    and ``epoch_ns``: any host time of this process, or of a client on
+    the same machine, can be laid on the trace.  ``POST /v1/profile``
+    turns the Python tracer off: the host planes then hold those spans
+    and the runtime's own events instead of every Python frame, and the
+    trace is a third to two thirds smaller.  ``/profile/start`` keeps the
+    profiler's defaults for a look by hand."""
     # one lock serializes start/stop end-to-end: the JAX profiler is a
     # process-global singleton, so overlapping operations (a start racing
     # an in-flight stop's serialization) must queue, and a concurrent
     # duplicate gets the clean 400 once the lock frees
     state = {"active": False, "lock": asyncio.Lock()}
 
-    async def start(request: web.Request):
+    def clock_mark() -> None:
+        with obs.host_span(
+            "lwc:clock",
+            parents=(),
+            perf_counter_ns=_time.perf_counter_ns(),
+            epoch_ns=_time.time_ns(),
+        ):
+            pass
+
+    def start_profiler(python_tracer: bool) -> None:
         import jax
 
+        options = jax.profiler.ProfileOptions()
+        if not python_tracer:
+            options.python_tracer_level = 0
+        jax.profiler.start_trace(profile_dir, profiler_options=options)
+        obs.set_profiler_annotation(jax.profiler.TraceAnnotation)
+        clock_mark()
+
+    def stop_profiler() -> None:
+        import jax
+
+        clock_mark()
+        obs.set_profiler_annotation(None)
+        jax.profiler.stop_trace()
+
+    async def start(request: web.Request):
         async with state["lock"]:
             if state["active"]:
                 return web.json_response(
@@ -642,7 +692,7 @@ def _profile_handlers(profile_dir: str):
             try:
                 # profiler IO runs on the executor; the loop keeps serving
                 await asyncio.get_running_loop().run_in_executor(
-                    None, jax.profiler.start_trace, profile_dir
+                    None, start_profiler, True
                 )
             except Exception as e:
                 return _error_response(e)
@@ -650,8 +700,6 @@ def _profile_handlers(profile_dir: str):
         return web.json_response({"ok": True, "dir": profile_dir})
 
     async def stop(request: web.Request):
-        import jax
-
         async with state["lock"]:
             if not state["active"]:
                 return web.json_response(
@@ -664,7 +712,7 @@ def _profile_handlers(profile_dir: str):
                 # trace serialization can be hundreds of MB — never on
                 # the loop
                 await asyncio.get_running_loop().run_in_executor(
-                    None, jax.profiler.stop_trace
+                    None, stop_profiler
                 )
             except Exception as e:
                 return _error_response(e)
@@ -676,10 +724,6 @@ def _profile_handlers(profile_dir: str):
         fat-fingered duration can't leave the profiler running; the
         admission middleware exempts this path (profiling an overload
         is the point), so the guard here is PROFILE_DIR alone."""
-        import asyncio
-
-        import jax
-
         try:
             body = jsonutil.loads(await request.text() or "{}")
         except Exception:
@@ -695,16 +739,15 @@ def _profile_handlers(profile_dir: str):
             state["active"] = True
         loop = asyncio.get_running_loop()
         try:
-            await loop.run_in_executor(
-                None, jax.profiler.start_trace, profile_dir
-            )
+            await loop.run_in_executor(None, start_profiler, False)
             # capture window: the loop keeps serving, so in-flight and
             # new requests land inside the trace
             await asyncio.sleep(duration_ms / 1e3)
-            await loop.run_in_executor(None, jax.profiler.stop_trace)
+            await loop.run_in_executor(None, stop_profiler)
         except Exception as e:
             return _error_response(e)
         finally:
+            obs.set_profiler_annotation(None)
             async with state["lock"]:
                 state["active"] = False
         return web.json_response(
@@ -1006,66 +1049,72 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
     total_tokens}}.
     """
     import asyncio
+    import math
+    from decimal import Decimal as _Decimal
+
+    def parse(raw: str):
+        """The request body to (texts, scorer, prompt, temperature), or
+        the ValueError the 400 policy echoes."""
+        body = jsonutil.loads(raw)
+        if not isinstance(body, dict):
+            raise ValueError("body must be a JSON object")
+        texts = body.get("input")
+        if (
+            not isinstance(texts, list)
+            or len(texts) < 2
+            or not all(isinstance(t, str) for t in texts)
+        ):
+            raise ValueError(
+                "`input` must be a list of >= 2 candidate strings"
+            )
+        if len(texts) > MAX_CONSENSUS_CANDIDATES:
+            raise ValueError(
+                f"`input` accepts at most {MAX_CONSENSUS_CANDIDATES} "
+                "candidates per request"
+            )
+        scorer = body.get("scorer", "cosine")
+        if scorer not in ("cosine", "rm"):
+            raise ValueError("`scorer` must be 'cosine' or 'rm'")
+        if scorer == "cosine" and embedder is None:
+            raise ValueError(
+                "cosine scorer unavailable: no EMBEDDER_MODEL configured"
+            )
+        if scorer == "rm" and reranker is None:
+            raise ValueError(
+                "rm scorer unavailable: no RM_MODEL configured"
+            )
+        prompt = body.get("prompt")
+        if prompt is not None and not isinstance(prompt, str):
+            raise ValueError("`prompt` must be a string")
+        traw = body.get("temperature", 0.05 if scorer == "cosine" else 1.0)
+        # explicit type check, not bare float(): a non-numeric value
+        # must raise the ValueError the 400 policy echoes, never a
+        # TypeError the policy masks as a server bug (jsonutil.loads
+        # parses JSON floats as Decimal)
+        if isinstance(traw, bool) or not isinstance(
+            traw, (int, float, _Decimal)
+        ):
+            raise ValueError("`temperature` must be a number")
+        temperature = float(traw)
+        if not math.isfinite(temperature) or temperature <= 0:
+            raise ValueError(
+                "`temperature` must be a finite positive number"
+            )
+        return texts, scorer, prompt, temperature
 
     async def handler(request: web.Request):
+        rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            body = jsonutil.loads(await request.text())
-            if not isinstance(body, dict):
-                raise ValueError("body must be a JSON object")
-            texts = body.get("input")
-            if (
-                not isinstance(texts, list)
-                or len(texts) < 2
-                or not all(isinstance(t, str) for t in texts)
-            ):
-                raise ValueError(
-                    "`input` must be a list of >= 2 candidate strings"
-                )
-            if len(texts) > MAX_CONSENSUS_CANDIDATES:
-                raise ValueError(
-                    f"`input` accepts at most {MAX_CONSENSUS_CANDIDATES} "
-                    "candidates per request"
-                )
-            scorer = body.get("scorer", "cosine")
-            if scorer not in ("cosine", "rm"):
-                raise ValueError(
-                    "`scorer` must be 'cosine' or 'rm'"
-                )
-            if scorer == "cosine" and embedder is None:
-                raise ValueError(
-                    "cosine scorer unavailable: no EMBEDDER_MODEL configured"
-                )
-            if scorer == "rm" and reranker is None:
-                raise ValueError(
-                    "rm scorer unavailable: no RM_MODEL configured"
-                )
-            prompt = body.get("prompt")
-            if prompt is not None and not isinstance(prompt, str):
-                raise ValueError("`prompt` must be a string")
-            traw = body.get(
-                "temperature", 0.05 if scorer == "cosine" else 1.0
-            )
-            # explicit type check, not bare float(): a non-numeric value
-            # must raise the ValueError the 400 policy echoes, never a
-            # TypeError the policy masks as a server bug (jsonutil.loads
-            # parses JSON floats as Decimal)
-            from decimal import Decimal as _Decimal
-
-            if isinstance(traw, bool) or not isinstance(
-                traw, (int, float, _Decimal)
-            ):
-                raise ValueError("`temperature` must be a number")
-            temperature = float(traw)
-            import math
-
-            if not math.isfinite(temperature) or temperature <= 0:
-                raise ValueError(
-                    "`temperature` must be a finite positive number"
-                )
+            raw = await request.text()
+            with obs.host_span(
+                "http:parse", rid=rid, bytes=len(raw)
+            ) as parsing:
+                texts, scorer, prompt, temperature = parse(raw)
+                parsing.annotate(n=len(texts))
         except web.HTTPException:
             raise  # e.g. 413 body-too-large must keep its status
         except Exception as e:  # parse phase is side-effect free
-            return _parse_error_response(e)
+            return _respond(rid, lambda: _parse_error_response(e))
         loop = asyncio.get_running_loop()
         try:
             if scorer == "rm":
@@ -1105,23 +1154,25 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
                     )
                 model_name = embedder.model_name
         except Exception as e:
-            return _error_response(e)
+            return _respond(rid, lambda: _error_response(e))
         import numpy as np
 
-        conf = np.asarray(conf)
-        return web.Response(
-            text=jsonutil.dumps(
-                {
-                    "model": model_name,
-                    "scorer": scorer,
-                    "confidence": [float(c) for c in conf],
-                    "usage": {
-                        "prompt_tokens": tokens,
-                        "total_tokens": tokens,
-                    },
-                }
+        return _respond(
+            rid,
+            lambda: web.Response(
+                text=jsonutil.dumps(
+                    {
+                        "model": model_name,
+                        "scorer": scorer,
+                        "confidence": [float(c) for c in np.asarray(conf)],
+                        "usage": {
+                            "prompt_tokens": tokens,
+                            "total_tokens": tokens,
+                        },
+                    }
+                ),
+                content_type="application/json",
             ),
-            content_type="application/json",
         )
 
     return handler
@@ -1129,25 +1180,32 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
 
 def _embeddings_handler(embedder, metrics=None, batcher=None):
     async def handler(request: web.Request):
+        rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            params = CreateEmbeddingParams.from_json_obj(
-                jsonutil.loads(await request.text())
-            )
+            raw = await request.text()
+            with obs.host_span("http:parse", rid=rid, bytes=len(raw)):
+                params = CreateEmbeddingParams.from_json_obj(
+                    jsonutil.loads(raw)
+                )
         except web.HTTPException:
             raise  # e.g. 413 body-too-large must keep its status
         except Exception as e:  # parse phase is side-effect free
-            return _parse_error_response(e)
+            return _respond(rid, lambda: _parse_error_response(e))
         if params.model and params.model != embedder.model_name:
-            return web.Response(
-                status=400,
-                text=jsonutil.dumps(
-                    {
-                        "code": 400,
-                        "message": f"unknown embeddings model {params.model!r}; "
-                        f"this gateway serves {embedder.model_name!r}",
-                    }
+            return _respond(
+                rid,
+                lambda: web.Response(
+                    status=400,
+                    text=jsonutil.dumps(
+                        {
+                            "code": 400,
+                            "message": "unknown embeddings model "
+                            f"{params.model!r}; this gateway serves "
+                            f"{embedder.model_name!r}",
+                        }
+                    ),
+                    content_type="application/json",
                 ),
-                content_type="application/json",
             )
         import asyncio
 
@@ -1172,9 +1230,12 @@ def _embeddings_handler(embedder, metrics=None, batcher=None):
                         "device:embed", (_time.perf_counter() - t0) * 1e3
                     )
         except Exception as e:
-            return _error_response(e)
-        return web.Response(
-            text=resp.to_json(), content_type="application/json"
+            return _respond(rid, lambda: _error_response(e))
+        return _respond(
+            rid,
+            lambda: web.Response(
+                text=resp.to_json(), content_type="application/json"
+            ),
         )
 
     return handler

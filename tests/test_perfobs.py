@@ -137,23 +137,29 @@ def test_merge_is_exact():
 # -- phase aggregator ---------------------------------------------------------
 
 
-def test_aggregator_snapshot_orders_phases_and_computes_device_share():
+def test_aggregator_snapshot_orders_phases_and_feeds_the_device_table():
     agg = PhaseAggregator()
     agg.observe_phase("upstream_judge", 30.0)
+    agg.observe_phase("http_respond", 1.0)
     agg.observe_phase("batcher_queue", 10.0)
+    agg.observe_phase("tokenize", 5.0)
     agg.observe_device("vote1(n=8,s=16)", 60.0)  # also device_dispatch
     snap = agg.snapshot()
-    keys = [k for k in snap if k not in ("device_time_share", "overlap")]
-    assert keys == [
-        "batcher_queue", "device_dispatch", "upstream_judge"
+    assert list(snap) == [
+        "tokenize", "batcher_queue", "device_dispatch", "upstream_judge",
+        "http_respond", "overlap",
     ]  # PHASES order, only observed phases
-    assert snap["device_time_share"] == pytest.approx(0.6)
+    assert snap["device_dispatch"] == {
+        "count": 1, "sum_ms": 60.0,
+        "p50_ms": snap["device_dispatch"]["p50_ms"],
+        "p99_ms": snap["device_dispatch"]["p99_ms"],
+    }
     dev = agg.device_snapshot()
     assert dev["vote1(n=8,s=16)"]["count"] == 1
 
 
-def test_aggregator_empty_share_is_none():
-    assert PhaseAggregator().snapshot()["device_time_share"] is None
+def test_aggregator_empty_snapshot_is_the_overlap_gauge_alone():
+    assert PhaseAggregator().snapshot() == {"overlap": None}
 
 
 def test_interval_union_attributes_concurrent_work_once():

@@ -88,9 +88,12 @@ def init_params(rng: jax.Array, config: BertConfig, dtype=jnp.float32) -> dict:
 def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
     b, s, h = x.shape
     nh, hd = config.num_heads, config.head_dim
+    fused = _use_fused_attention(config, b, s, hd, x.dtype)
 
     def heads(t):
-        return t.reshape(b, s, nh, hd)
+        # ring and einsum work head by head; the kernel reads the
+        # projections' own [b, s, h] and writes what attn_out reads
+        return t if fused else t.reshape(b, s, nh, hd)
 
     with jax.named_scope("qkv_proj"):
         q = heads(_dense_cfg(x, p["attn_q"], config))
@@ -113,7 +116,7 @@ def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
             ctx = ring_attention(
                 q, k, v, mask_bias[:, 0, 0, :], scale, config.ring_axis
             )
-    elif _use_fused_attention(config, b, s, hd, q.dtype):
+    elif fused:
         from ..ops.attention import (
             best_heads_per_step,
             fused_attention_tiled,
@@ -121,21 +124,22 @@ def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
         )
 
         # forced mode may arrive with best==0 (caller takes the
-        # VMEM responsibility); run the minimal 1-tile step then
-        kk = max(best_heads_per_step(b, s, nh, hd, q.dtype.itemsize), 1)
+        # VMEM responsibility); the kernel runs its one-row step then
+        kk = best_heads_per_step(b, s, nh, hd, x.dtype.itemsize)
         if segment_ids is not None:
             with jax.named_scope("fused_attention_seg"):
                 # packed layout: the kernel builds the same-segment mask
                 # in VMEM from the int32 segment row
                 ctx = fused_attention_tiled_seg(
-                    q, k, v, segment_ids, scale, heads_per_step=kk
+                    q, k, v, segment_ids, scale, nh, heads_per_step=kk
                 )
         else:
             with jax.named_scope("fused_attention"):
                 # mask_bias is [b, 1, 1, s]; the kernel wants the
                 # [b, s] key bias
                 ctx = fused_attention_tiled(
-                    q, k, v, mask_bias[:, 0, 0, :], scale, heads_per_step=kk
+                    q, k, v, mask_bias[:, 0, 0, :], scale, nh,
+                    heads_per_step=kk,
                 )
     else:
         with jax.named_scope("einsum_attention"):
@@ -171,7 +175,7 @@ def _use_fused_attention(
     from ..ops.attention import attention_fits, best_heads_per_step
 
     impl = config.attention_impl
-    if impl == "einsum":
+    if impl in ("einsum", "ring"):
         return False
     if impl == "fused":
         # forced: the caller takes responsibility for the VMEM budget
@@ -184,10 +188,11 @@ def _use_fused_attention(
         # activations at s=1024): einsum, not a thrashing kernel
         return False
     # "auto": the kernel from s=512 on a TPU, where the [b, nh, s, s]
-    # intermediates of the einsum path dominate; below that XLA fuses the
-    # head transposes into the projection matmuls while the kernel pays
-    # them as HBM passes.  The crossover is a builder's round-4 timing on
-    # another toolchain — not measured on this one (ROADMAP S2).
+    # intermediates of the einsum path dominate.  The kernel reads and
+    # writes the encoder's [b, s, h] and pays no transposes (PR 25: one
+    # layer's attention at 64 x 512 in 0.94 ms against einsum's 3.06 on
+    # a v5e); the crossover itself is older than that layout and is an
+    # open question of PERF.md, with what the chip says under 512.
     return jax.default_backend() == "tpu" and s >= 512
 
 

@@ -2,35 +2,44 @@
 
 The einsum attention in ``models/bert.py`` materializes the [b, nh, s, s]
 logits and probs tensors in HBM between XLA ops.  For encoder sequence
-lengths (<=1024) a block of flat (batch, head) tiles — q/k/v [k, s, hd]
-plus the [k, s, s] score tile — fits in VMEM, so the whole
-QK^T -> bias -> softmax -> PV chain runs as ONE kernel with f32
-accumulation on the MXU and no HBM round-trips for the intermediates
-(SURVEY §3.5; VERDICT r1 item 2, r3 item 2).
+lengths (<=1024) the q/k/v blocks of a few batch rows plus one [s, s]
+score tile fit in VMEM, so the whole QK^T -> bias -> softmax -> PV chain
+runs as ONE kernel with f32 accumulation on the MXU and no HBM
+round-trips for the intermediates (SURVEY §3.5; VERDICT r1 item 2, r3
+item 2).
 
-Layout: grid (b*nh // heads_per_step,); each step processes
-``heads_per_step`` flat (batch, head) tiles.  The additive padding bias
-[b, s] (0 for real tokens, -1e9 for padding) is pre-expanded to one row
-per flat tile so a step may straddle batch elements — any power-of-two
-divisor of b*nh inside the VMEM budget works (``best_heads_per_step``).
+Layout: the encoder's own.  q, k and v are the projections' [b, s, h]
+outputs and the context is the [b, s, h] array ``attn_out`` reads: no
+head transpose, no reshape to an hd-minor array (at hd 64 such an array
+fills half of every 128-lane tile, so each relayout copy wrote twice the
+bytes it read).  ``BlockSpec``s carve (rows, s, g*hd) column blocks, g
+heads to a whole number of 128-lane tiles (``heads_per_block``: 2 heads
+at hd 64, 4 at hd 32, all of them where h < 128); grid
+(b // rows, nh // g), both parallel.  Inside a step the kernel goes row
+by row and head by head with plain 2-D products on static lane slices of
+the block.  The additive padding bias [b, s] (0 for real tokens, -1e9
+for padding) goes in as [b, 1, s], one row per batch row, shared by its
+heads; the packed layout's segment ids likewise.
 
-Which path is faster, and from which sequence length, is not measured
-on this toolchain.  The kernel pays the [b, s, nh, hd] -> [b*nh, s, hd]
-transposes as HBM passes that XLA fuses into the einsum path's
-projection matmuls; the serving policy (``models/bert.py``
-``_use_fused_attention``: the kernel from s=512) comes from a builder's
-round-4 timings on another toolchain and is to be re-measured (ROADMAP
-S2).  A native-layout variant (BlockSpec carving [1, s, kh, hd] tiles
-straight out of the encoder layout, no transposes) hit a Mosaic INTERNAL
-error on batched dot_general with a middle batch axis on that toolchain;
-not retried on this one.
+What the chip says (TPU v5e, bf16, one bge-large layer's attention, my
+chip runs, PR 25): at 64 x 512 the kernel takes 0.94 ms where the
+transposing kernel it replaces took 2.21 ms with its copies (0.9 ms of
+that the kernel), at 512 x 512 7.45 ms against 24.4 ms, the einsum path
+3.06 and 28.2 ms.  Two other forms of the same block were timed and not
+kept: products on the whole 128-lane block with the other head's lanes
+zeroed (1.12 ms at 64 x 512; faster below s=512 and under the segment
+mask), and every row of a block unrolled (no faster than two).  The
+serving policy (``models/bert.py`` ``_use_fused_attention``: the kernel
+from s=512) is older than this layout; PERF.md's open questions hold
+what the chip says under 512.
 
 The kernel is a single-device program: under a GSPMD-partitioned jit
 Mosaic refuses it (parallel/sharding.py ``gspmd_config``).
 
 On non-TPU backends the kernel runs in interpret mode (same code path,
 same numerics) so the CPU test mesh exercises it; parity with the einsum
-reference is asserted in tests/test_models.py.
+reference is asserted in tests/test_models.py, and
+tests/test_tpu_compile.py compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -42,187 +51,188 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# One (s, s) f32 score tile + 3 (s, hd) operand tiles must fit VMEM.
+from .kernels import _round_up
+
+# One (s, s) f32 score tile + the operand blocks must fit VMEM.
 MAX_FUSED_SEQ = 1024
+LANES = 128
+# Rows of one grid step.  From 1 to 8 rows the chip times the kernel the
+# same (1.00 ms a layer at 64 x 512 either way: the step's arithmetic, not
+# its overhead, is what costs), so the block stays small: its first DMA is
+# the one the pipeline cannot hide.
+MAX_ROWS_PER_STEP = 4
+# Of the ~16 MB/core VMEM, what one grid step's blocks and score tiles may
+# take by ``best_heads_per_step``'s reckoning; Mosaic's own temporaries
+# need the rest (bf16 at s=1024 reckons 10 MB for one row and compiles).
+VMEM_BUDGET = 11 * 1024 * 1024
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _attn_kernel_tiled(
-    q_ref, k_ref, v_ref, bias_ref, out_ref, *, scale: float
+def heads_per_block(nh: int, hd: int) -> int:
+    """Heads to one column block of [b, s, h]: the fewest whose lanes fill
+    whole 128-lane tiles (2 at hd 64, 4 at hd 32), every head where the
+    whole of h is under one tile, 0 where h cannot be carved."""
+    for g in range(1, nh + 1):
+        if nh % g == 0 and (g * hd) % LANES == 0:
+            return g
+    return nh if nh * hd < LANES else 0
+
+
+def _attn_kernel(
+    q_ref, k_ref, v_ref, row_ref, out_ref, *, scale: float, hd: int,
+    segmented: bool,
 ):
-    # q/k/v blocks: [k, s, hd] (k flat (batch, head) tiles); bias block:
-    # [k, 1, s] (pre-expanded per head, so a step may straddle batch
-    # elements).  Matmul inputs stay in the storage dtype (bf16 feeds the
-    # MXU natively with f32 accumulation); softmax is f32 — same numerics
-    # as the einsum path.
-    q = q_ref[:]  # [k, s, hd]
-    k = k_ref[:]
-    v = v_ref[:]
-    logits = (
-        jax.lax.dot_general(
-            q,
-            k,
-            # batch over heads, contract over hd
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
+    # q/k/v/out blocks: [bb, s, g*hd], bb batch rows by the g heads of one
+    # column block of the encoder's [b, s, h]; row block: [bb, 1, s], the
+    # f32 key-padding bias or (packed layout) the int32 segment ids, one
+    # row per batch row and shared by its heads.  One (row, head) tile at
+    # a time: plain 2-D products, matmul inputs in the storage dtype (bf16
+    # feeds the MXU natively with f32 accumulation), softmax in f32 — the
+    # einsum path's numerics.
+    bb, s, width = q_ref.shape
+
+    def one_row(r):
+        row = row_ref[r]  # [1, s]
+        if segmented:
+            # query i attends key j iff seg[i] == seg[j] and seg[j] > 0 (0
+            # marks pad slots); built in VMEM, once for the row's heads (a
+            # pre-materialized [b, s, s] bias would triple the HBM traffic
+            # at s=512)
+            same = (row.reshape(s, 1) == row) & (row > 0)
+        for j in range(width // hd):
+            lanes = slice(j * hd, (j + 1) * hd)
+            q = q_ref[r, :, lanes]  # [s, hd]
+            k = k_ref[r, :, lanes]
+            v = v_ref[r, :, lanes]
+            logits = (
+                jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                * scale
+            )  # [s, s] f32
+            if segmented:
+                # pad-slot query rows are fully masked: every logit is the
+                # same -1e9, so the softmax is uniform (never 0/0) and the
+                # garbage rows are dropped by segment pooling downstream
+                logits = jnp.where(same, logits, -1e9)
+            else:
+                logits = logits + row  # key-side padding bias
+            mx = jnp.max(logits, axis=-1, keepdims=True)
+            e = jnp.exp(logits - mx)
+            probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
+            ctx = jax.lax.dot_general(
+                probs, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [s, hd] f32
+            out_ref[r, :, lanes] = ctx.astype(out_ref.dtype)
+
+    # A loop, not an unrolled block, so Mosaic's compile time does not
+    # grow with the block; two rows an iteration let it overlap one row's
+    # softmax with the next one's products (0.94 against 1.00 ms a layer
+    # at 64 x 512, 7.45 against 7.93 at 512 x 512: chip, PR 25).
+    pair = 2 if bb % 2 == 0 else 1
+
+    def rows(i, carry):
+        for u in range(pair):
+            one_row(i * pair + u)
+        return carry
+
+    jax.lax.fori_loop(0, bb // pair, rows, 0)
+
+
+def _fused_attention(q, k, v, rows, scale, nh, heads_per_step, segmented):
+    b, s, h = q.shape
+    hd = h // nh
+    g = heads_per_block(nh, hd)
+    if g < 1:
+        raise ValueError(
+            f"hidden {h} = {nh} heads x {hd} cannot be carved into "
+            f"{LANES}-lane column blocks"
         )
-        * scale
-    )  # [k, s, s] f32
-    logits = logits + bias_ref[:, 0, :][:, None, :]  # key-side padding bias
-    mx = jnp.max(logits, axis=-1, keepdims=True)
-    e = jnp.exp(logits - mx)
-    probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
-    ctx = jax.lax.dot_general(
-        probs,
-        v,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [k, s, hd] f32
-    out_ref[:] = ctx.astype(out_ref.dtype)
+    bb = max(heads_per_step // g, 1)
+    if b % bb:
+        raise ValueError(
+            f"heads_per_step={heads_per_step} ({bb} rows x {g} heads) "
+            f"must divide b={b} rows"
+        )
+    qkv_spec = pl.BlockSpec(
+        (bb, s, g * hd), lambda i, j: (i, 0, j), memory_space=pltpu.VMEM
+    )
+    row_spec = pl.BlockSpec(
+        (bb, 1, s), lambda i, j: (i, 0, 0), memory_space=pltpu.VMEM
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _attn_kernel, scale=scale, hd=hd, segmented=segmented
+        ),
+        grid=(b // bb, nh // g),
+        in_specs=[qkv_spec, qkv_spec, qkv_spec, row_spec],
+        out_specs=qkv_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, h), q.dtype),
+        # independent grid steps: lets Mosaic double-buffer the block DMAs
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")
+        ),
+        interpret=_interpret(),
+    )(q, k, v, rows[:, None, :])
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "heads_per_step"))
+# The two entries stay two jitted functions: the kernel's device events
+# are named after the jit that holds the pallas_call, and the benchmark
+# reads them by these names (bench/reducers/attention_roofline.py).
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "nh", "heads_per_step"))
 def fused_attention_tiled(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     bias: jax.Array,
     scale: float,
+    nh: int,
     heads_per_step: int = 8,
 ) -> jax.Array:
-    """q/k/v[b, s, nh, hd], bias[b, s] additive key padding -> ctx[b, s, nh, hd].
+    """q/k/v[b, s, h] as the projections leave them, bias[b, s] additive
+    key padding -> ctx[b, s, h] as ``attn_out`` reads it.
 
-    Softmax(QK^T * scale + bias) V fused over ``heads_per_step`` flat
-    (batch, head) tiles per grid step, amortizing per-step grid/DMA
-    overhead (the r3 kernel's 1-head steps were overhead-bound at s=128,
-    0.56 vs 0.08 ms isolated).  ``heads_per_step`` may be any divisor of
-    b*nh within the VMEM budget; ``best_heads_per_step`` picks one.
+    Softmax(QK^T * scale + bias) V fused, ``heads_per_step`` (batch row,
+    head) tiles per grid step: ``heads_per_step // g`` rows of one
+    g-head column block, which amortizes per-step grid/DMA overhead.
+    ``best_heads_per_step`` picks one within the VMEM budget.
     """
-    b, s, nh, hd = q.shape
-    kk = heads_per_step
-    if (b * nh) % kk:
-        raise ValueError(f"heads_per_step={kk} must divide b*nh={b * nh}")
-    grid = (b * nh // kk,)
-
-    def to_heads(t):
-        return t.transpose(0, 2, 1, 3).reshape(b * nh, s, hd)
-
-    flat_bias = jnp.broadcast_to(bias[:, None, :], (b, nh, s)).reshape(
-        b * nh, 1, s
+    return _fused_attention(
+        q, k, v, bias.astype(jnp.float32), scale, nh, heads_per_step, False
     )
-    qkv_spec = pl.BlockSpec(
-        (kk, s, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-    )
-    bias_spec = pl.BlockSpec(
-        (kk, 1, s), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-    )
-    out = pl.pallas_call(
-        functools.partial(_attn_kernel_tiled, scale=scale),
-        grid=grid,
-        in_specs=[qkv_spec, qkv_spec, qkv_spec, bias_spec],
-        out_specs=qkv_spec,
-        out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
-        # independent grid steps: lets Mosaic double-buffer the block DMAs
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        ),
-        interpret=_interpret(),
-    )(to_heads(q), to_heads(k), to_heads(v), flat_bias)
-    return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
 
 
-def _attn_kernel_tiled_seg(
-    q_ref, k_ref, v_ref, seg_ref, out_ref, *, scale: float
-):
-    # Segment-masked variant for the packed (continuous-batching) layout:
-    # instead of a per-key additive padding bias, the block carries the
-    # int32 segment-ids row [k, 1, s] and the mask is computed IN VMEM —
-    # query i attends key j iff seg[i] == seg[j] and seg[j] > 0 (0 marks
-    # pad slots).  Building the [s, s] mask here costs one compare per
-    # logit and keeps the HBM traffic identical to the padded kernel
-    # (a pre-materialized [b*nh, s, s] bias would triple it at s=512).
-    q = q_ref[:]  # [k, s, hd]
-    k = k_ref[:]
-    v = v_ref[:]
-    logits = (
-        jax.lax.dot_general(
-            q,
-            k,
-            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        * scale
-    )  # [k, s, s] f32
-    seg = seg_ref[:, 0, :]  # [k, s] int32
-    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
-    logits = jnp.where(same, logits, -1e9)
-    # pad-slot query rows are fully masked: every logit is the same -1e9,
-    # so the softmax is uniform (never 0/0) and the garbage rows are
-    # dropped by segment pooling downstream
-    mx = jnp.max(logits, axis=-1, keepdims=True)
-    e = jnp.exp(logits - mx)
-    probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
-    ctx = jax.lax.dot_general(
-        probs,
-        v,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [k, s, hd] f32
-    out_ref[:] = ctx.astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "heads_per_step"))
+@functools.partial(jax.jit, static_argnames=("scale", "nh", "heads_per_step"))
 def fused_attention_tiled_seg(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     segment_ids: jax.Array,
     scale: float,
+    nh: int,
     heads_per_step: int = 8,
 ) -> jax.Array:
-    """q/k/v[b, s, nh, hd], segment_ids[b, s] int32 (0 = pad slot) ->
-    ctx[b, s, nh, hd] with attention confined to same-segment tokens.
+    """q/k/v[b, s, h], segment_ids[b, s] int32 (0 = pad slot) ->
+    ctx[b, s, h] with attention confined to same-segment tokens.
 
-    The packed-serving twin of ``fused_attention_tiled``: same grid/tile
-    layout and numerics, but the key-side padding bias is replaced by an
-    in-kernel segment equality mask so one dense row can carry many
+    The packed-serving twin of ``fused_attention_tiled``: the same kernel
+    body, layout and numerics, but the key-side padding bias is replaced
+    by an in-kernel segment equality mask so one dense row can carry many
     independent sequences (serve/packing.py builds the layout).  VMEM
     cost matches the padded kernel (the int32 seg row replaces the f32
     bias row), so ``best_heads_per_step`` applies unchanged.
     """
-    b, s, nh, hd = q.shape
-    kk = heads_per_step
-    if (b * nh) % kk:
-        raise ValueError(f"heads_per_step={kk} must divide b*nh={b * nh}")
-    grid = (b * nh // kk,)
-
-    def to_heads(t):
-        return t.transpose(0, 2, 1, 3).reshape(b * nh, s, hd)
-
-    flat_seg = jnp.broadcast_to(
-        segment_ids.astype(jnp.int32)[:, None, :], (b, nh, s)
-    ).reshape(b * nh, 1, s)
-    qkv_spec = pl.BlockSpec(
-        (kk, s, hd), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
+    return _fused_attention(
+        q, k, v, segment_ids.astype(jnp.int32), scale, nh, heads_per_step,
+        True,
     )
-    seg_spec = pl.BlockSpec(
-        (kk, 1, s), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
-    )
-    out = pl.pallas_call(
-        functools.partial(_attn_kernel_tiled_seg, scale=scale),
-        grid=grid,
-        in_specs=[qkv_spec, qkv_spec, qkv_spec, seg_spec],
-        out_specs=qkv_spec,
-        out_shape=jax.ShapeDtypeStruct((b * nh, s, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        ),
-        interpret=_interpret(),
-    )(to_heads(q), to_heads(k), to_heads(v), flat_seg)
-    return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
 
 
 def best_heads_per_step(
@@ -234,35 +244,35 @@ def best_heads_per_step(
     score_itemsize: int = 4,
     bias_itemsize: int = 4,
 ) -> int:
-    """Largest power-of-two divisor of b*nh whose block set fits VMEM,
-    or 0 if not even a 1-tile step fits (callers fall back to einsum).
+    """(Batch row, head) tiles per grid step: the g heads of one column
+    block (``heads_per_block``) times the largest power-of-two number of
+    rows that divides b and whose block set fits VMEM.  0 if h cannot be
+    carved or not even a one-row step fits (callers fall back to einsum).
 
-    Per step the kernel holds 4 [k, s, hd] operand/output blocks in the
-    storage dtype (``itemsize`` bytes/element, x2 for double-buffering),
-    the [k, s, s] score/prob tiles (``score_itemsize``, f32 today), and
-    the bias row (``bias_itemsize``; the packed variant's int32 segment
-    row has the same width).  The per-dtype byte widths are parameters
-    — not baked-in 4s — so a narrower score accumulator or bias layout
-    reuses this one fit model, mirroring ``w8a8_shape_fits``'s
-    ``w_bytes``.  11 MB of the ~16 MB VMEM admits the measured-best
-    tiles (bf16: kk=32 @ s=128: 8.4 MB; kk=4 @ s=512: 10.5 MB) and
-    rejects the ones Mosaic refuses or that regress from double-buffer
-    pressure (kk=64 @ s=128: 16.8 MB).
+    Per step the kernel holds 4 [bb, s, g*hd] operand/output blocks in the
+    storage dtype (``itemsize`` bytes/element, x2 for double-buffering,
+    lanes padded to whole tiles), the bias rows (``bias_itemsize``; the
+    packed variant's int32 segment row has the same width, 8 sublanes a
+    row, x2), and the [s, s] score/prob tiles of the ONE head in work
+    (``score_itemsize``, f32 today).  The per-dtype byte widths are
+    parameters — not baked-in 4s — so a narrower score accumulator or
+    bias layout reuses this one fit model, mirroring ``w8a8_shape_fits``'s
+    ``w_bytes``.  A function of shapes and item sizes only.
     """
-    budget = 11 * 1024 * 1024
+    g = heads_per_block(nh, hd)
+    if g < 1:
+        return 0
+    width = _round_up(g * hd, LANES)
+    scores = 2 * s * s * score_itemsize
     best = 0
-    kk = 1
-    while kk <= b * nh:
-        if (b * nh) % kk == 0:
-            need = kk * (
-                8 * s * hd * itemsize
-                + 2 * s * s * score_itemsize
-                + s * bias_itemsize
-            )
-            if need <= budget:
-                best = kk
-        kk *= 2
-    return best
+    bb = 1
+    while bb <= min(b, MAX_ROWS_PER_STEP):
+        if b % bb == 0:
+            blocks = bb * (8 * s * width * itemsize + 16 * s * bias_itemsize)
+            if blocks + scores <= VMEM_BUDGET:
+                best = bb
+        bb *= 2
+    return best * g
 
 
 def attention_fits(s: int, hd: int) -> bool:

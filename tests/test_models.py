@@ -342,6 +342,184 @@ def test_fused_attention_padding_invariance(params):
     np.testing.assert_allclose(np.asarray(e1), np.asarray(e2), atol=2e-5)
 
 
+# The shapes that decide the kernel's column block: hd 64 with several
+# 128-lane blocks of two heads, hd 32 (four heads a block), and test-tiny,
+# whose whole hidden (64) is under one 128-lane tile.
+ATTN_SHAPES = {
+    "hd64-h256": dict(hidden_size=256, num_heads=4),
+    "hd32-h384": dict(hidden_size=384, num_heads=12),
+    "test-tiny": dict(hidden_size=64, num_heads=4),
+}
+
+
+def attn_case(shape, dtype, packed, impl, b=4, s=32, seed=0):
+    """One attention block's inputs: (config, layer params, x, mask_bias,
+    segment_ids or None, real-token mask)."""
+    from dataclasses import replace
+
+    cfg = replace(TINY, attention_impl=impl, **ATTN_SHAPES[shape])
+    layer = jax.tree_util.tree_map(
+        lambda a: a[0],
+        bert.init_params(jax.random.PRNGKey(seed), cfg, dtype=dtype)["layers"],
+    )
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.standard_normal((b, s, cfg.hidden_size)), dtype)
+    lens = rng.integers(s // 2, s + 1, b)
+    pos = np.arange(s)[None, :]
+    real = pos < lens[:, None]
+    if not packed:
+        bias = jnp.where(jnp.asarray(real)[:, None, None, :], 0.0, -1e9)
+        return cfg, layer, x, bias.astype(jnp.float32), None, real
+    # two packed sequences a row, then pad slots
+    seg = np.where(pos < lens[:, None] // 2, 1, np.where(real, 2, 0))
+    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
+    bias = jnp.asarray(np.where(same, 0.0, -1e9), jnp.float32)[:, None]
+    return cfg, layer, x, bias, jnp.asarray(seg, jnp.int32), real
+
+
+def walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs it calls (jit, scan,
+    cond), except a pallas_call's kernel body: what is inside the kernel
+    lives in VMEM, not in the program's arrays."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from walk_eqns(inner)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
+def test_fused_attention_stays_in_the_encoder_layout(packed):
+    """The structural guard that the relayout copies cannot come back:
+    around the kernel no transpose, and no array with hd as its minor
+    dimension (half-filled 128-lane tiles)."""
+    cfg, layer, x, bias, seg, _ = attn_case(
+        "hd64-h256", jnp.float32, packed, "fused"
+    )
+    hd = cfg.head_dim
+    assert hd not in (x.shape[1], cfg.hidden_size, cfg.intermediate_size)
+    jaxpr = jax.make_jaxpr(
+        lambda x, bias, seg: bert._attention(x, layer, bias, cfg, seg)
+    )(x, bias, seg)
+    eqns = list(walk_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("pallas_call") == 1
+    assert "transpose" not in names
+    for e in eqns:
+        for var in list(e.invars) + list(e.outvars):
+            shape = getattr(var.aval, "shape", ())
+            assert not shape or shape[-1] != hd, (e.primitive.name, shape)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
+@pytest.mark.parametrize("shape", list(ATTN_SHAPES))
+def test_fused_attention_block_matches_einsum(shape, packed, dtype):
+    dt = jnp.dtype(dtype)
+    cfg, layer, x, bias, seg, real = attn_case(shape, dt, packed, "fused")
+    from dataclasses import replace
+
+    got = bert._attention(x, layer, bias, cfg, seg)
+    want = bert._attention(
+        x, layer, bias, replace(cfg, attention_impl="einsum"), seg
+    )
+    assert got.shape == want.shape == x.shape and got.dtype == dt
+    # pad-slot query rows attend nothing and are dropped by pooling
+    rows = np.asarray(real)[:, :, None]
+    tol = 2e-5 if dt == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32) * rows,
+        np.asarray(want, np.float32) * rows,
+        atol=tol, rtol=tol,
+    )
+
+
+def fit_need(b, s, nh, hd, itemsize, kk):
+    """VMEM bytes of one grid step as best_heads_per_step reckons them."""
+    from llm_weighted_consensus_tpu.ops import attention
+
+    g = attention.heads_per_block(nh, hd)
+    width = -(-g * hd // 128) * 128
+    return (kk // g) * (8 * s * width * itemsize + 16 * s * 4) + 2 * s * s * 4
+
+
+@pytest.mark.parametrize(
+    "b, s, nh, hd, itemsize, g",
+    [
+        (64, 512, 16, 64, 2, 2),  # bge-large, one request
+        (512, 512, 16, 64, 2, 2),  # bge-large, a full group of 8
+        (64, 512, 12, 32, 2, 4),  # bge-small: four heads to 128 lanes
+        (64, 512, 12, 64, 2, 2),  # bge-base
+        (4, 32, 4, 16, 4, 4),  # test-tiny: the whole hidden, under a tile
+        (3, 512, 16, 64, 2, 2),  # an odd batch: one row a step
+        (64, 512, 3, 48, 2, 0),  # 144 lanes: no whole tiles, not under one
+        (64, 1024, 16, 64, 4, 2),  # f32 at 1024: the score tiles alone
+    ],
+)
+def test_best_heads_per_step_is_the_new_blocks_fit(b, s, nh, hd, itemsize, g):
+    from llm_weighted_consensus_tpu.ops import attention
+
+    assert attention.heads_per_block(nh, hd) == g
+    kk = attention.best_heads_per_step(b, s, nh, hd, itemsize)
+    budget = attention.VMEM_BUDGET
+    if g == 0 or fit_need(b, s, nh, hd, itemsize, g) > budget:
+        assert kk == 0  # callers fall back to einsum
+        return
+    rows = kk // g
+    assert kk == rows * g and rows >= 1 and b % rows == 0
+    assert fit_need(b, s, nh, hd, itemsize, kk) <= budget
+
+
+@pytest.mark.parametrize(
+    "backend, impl, nh, hd, b, s, fused",
+    [
+        ("tpu", "auto", 16, 64, 64, 512, True),  # the benchmark's bucket
+        ("tpu", "auto", 16, 64, 512, 512, True),
+        ("tpu", "auto", 16, 64, 64, 256, False),  # the bypass: under 512
+        ("tpu", "auto", 16, 64, 64, 128, False),
+        ("cpu", "auto", 16, 64, 64, 512, False),  # any bucket off a TPU
+        ("tpu", "auto", 3, 48, 64, 512, False),  # cannot be carved
+        ("tpu", "einsum", 16, 64, 64, 512, False),
+        ("tpu", "ring", 16, 64, 64, 512, False),
+        ("cpu", "fused", 16, 64, 64, 128, True),  # forced
+    ],
+)
+def test_use_fused_attention_policy(
+    monkeypatch, backend, impl, nh, hd, b, s, fused
+):
+    from dataclasses import replace
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = replace(
+        TINY, hidden_size=nh * hd, num_heads=nh, attention_impl=impl
+    )
+    dt = jnp.dtype(jnp.bfloat16)
+    assert bert._use_fused_attention(cfg, b, s, hd, dt) is fused
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
+def test_auto_attention_off_tpu_is_the_einsum_path(packed):
+    """What every bucket under 512 runs (and every bucket off a TPU):
+    the einsum path, its head reshape in place and no kernel."""
+    cfg, layer, x, bias, seg, _ = attn_case(
+        "hd64-h256", jnp.float32, packed, "auto"
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda x, bias, seg: bert._attention(x, layer, bias, cfg, seg)
+    )(x, bias, seg)
+    eqns = list(walk_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert "pallas_call" not in names
+    b, s, _ = x.shape
+    heads = (b, s, cfg.num_heads, cfg.head_dim)
+    shapes = [v.aval.shape for e in eqns for v in e.outvars]
+    assert shapes.count(heads) >= 4  # q, k, v and the context
+
+
 def test_embed_and_vote_many_matches_single():
     emb = TpuEmbedder("test-tiny")
     rng = np.random.default_rng(3)

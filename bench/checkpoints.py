@@ -1,198 +1,154 @@
-"""Seeded checkpoints and vocabularies in the public (HuggingFace) layout.
+"""Seeded checkpoints in the public (HuggingFace) layout.
 
 The benchmark makes the model's weights itself, from ``--seed``, and hands
 them to the program the way a user hands it a published checkpoint: a
 ``model.safetensors`` with the HuggingFace tensor names plus the tokenizer
-file beside it.  The plain references read the same file by the same public
-names, so neither side takes anything the other has made.
+file beside it (``bench/tokenizers/``).  The plain references read the same
+file by the same public names, so neither side takes anything the other has
+made.  Which tensors a checkpoint holds is its family's business:
+``bench/families/<family>.py`` gives ``tensors(cfg)``.
 
 Values are drawn per tensor (one generator per tensor, keyed by the seed
-and the tensor's position in the list), so the file does not depend on how
-many threads wrote it.  Every value is a bfloat16 number: the checkpoint IS
-bf16, as served, and the float32 reference upcasts the very same numbers.
+and the tensor's position in the family's list), so the file does not depend
+on how many threads wrote it, nor on how it is cut into shards.  Every value
+is a bfloat16 number: the checkpoint IS bf16, as served, and the float32
+reference upcasts the very same numbers.
+
+A checkpoint over ``SHARD_BYTES`` is written as HuggingFace writes one that
+large: ``model-0000i-of-0000n.safetensors`` and
+``model.safetensors.index.json``, one shard in memory at a time.  Whatever
+the layout, ``read_checkpoint`` gives a mapping that opens a tensor when it
+is asked for, so a reference can run layer by layer over a checkpoint that
+would not fit the host twice.
 """
 
 from __future__ import annotations
 
+import json
+import math
 import os
+from collections.abc import Mapping
 from concurrent.futures import ThreadPoolExecutor
 
 import ml_dtypes
 import numpy as np
 
+import byname
+
 BF16 = ml_dtypes.bfloat16
-SPECIALS_WORDPIECE = ["[PAD]", "[UNK]", "[CLS]", "[SEP]"]
 INIT_STD = 0.02  # BERT's and DeBERTa's published initializer_range
+SHARD_BYTES = 5_000_000_000  # HuggingFace's default max_shard_size, "5GB"
+SINGLE = "model.safetensors"
+INDEX = "model.safetensors.index.json"
 
 
-def bert_tensors(cfg: dict) -> list:
-    """(name, shape, kind) for a BertModel state dict; kind is ``normal``
-    (weights, biases: N(0, 0.02)) or ``ln_scale`` (1 + N(0, 0.02))."""
-    h, inter = cfg["hidden_size"], cfg["intermediate_size"]
-    out = [
-        ("embeddings.word_embeddings.weight", (cfg["vocab_size"], h), "normal"),
-        (
-            "embeddings.position_embeddings.weight",
-            (cfg["max_position_embeddings"], h),
-            "normal",
-        ),
-        (
-            "embeddings.token_type_embeddings.weight",
-            (cfg["type_vocab_size"], h),
-            "normal",
-        ),
-        ("embeddings.LayerNorm.weight", (h,), "ln_scale"),
-        ("embeddings.LayerNorm.bias", (h,), "normal"),
-    ]
-    for i in range(cfg["num_hidden_layers"]):
-        base = f"encoder.layer.{i}"
-        for name, shape in (
-            ("attention.self.query", (h, h)),
-            ("attention.self.key", (h, h)),
-            ("attention.self.value", (h, h)),
-            ("attention.output.dense", (h, h)),
-            ("intermediate.dense", (inter, h)),
-            ("output.dense", (h, inter)),
-        ):
-            out.append((f"{base}.{name}.weight", shape, "normal"))
-            out.append((f"{base}.{name}.bias", (shape[0],), "normal"))
-        for name in ("attention.output.LayerNorm", "output.LayerNorm"):
-            out.append((f"{base}.{name}.weight", (h,), "ln_scale"))
-            out.append((f"{base}.{name}.bias", (h,), "normal"))
-    return out
+def specs_of(family: str, cfg: dict) -> tuple:
+    """The family's tensor list and its initializer's standard deviation."""
+    mod = byname.module("families", family)
+    return mod.tensors(cfg), float(getattr(mod, "INIT_STD", INIT_STD))
 
 
-def deberta_tensors(cfg: dict) -> list:
-    """A DebertaV2ForSequenceClassification state dict (v3 layout: shared
-    position projections, relative embeddings with their LayerNorm, context
-    pooler, one-logit classifier)."""
-    h, inter = cfg["hidden_size"], cfg["intermediate_size"]
-    rel = 2 * (cfg["position_buckets"] or cfg["max_relative_positions"])
-    out = [
-        ("deberta.embeddings.word_embeddings.weight", (cfg["vocab_size"], h), "normal"),
-        ("deberta.embeddings.LayerNorm.weight", (h,), "ln_scale"),
-        ("deberta.embeddings.LayerNorm.bias", (h,), "normal"),
-        ("deberta.encoder.rel_embeddings.weight", (rel, h), "normal"),
-        ("deberta.encoder.LayerNorm.weight", (h,), "ln_scale"),
-        ("deberta.encoder.LayerNorm.bias", (h,), "normal"),
-    ]
-    for i in range(cfg["num_hidden_layers"]):
-        base = f"deberta.encoder.layer.{i}"
-        for name, shape in (
-            ("attention.self.query_proj", (h, h)),
-            ("attention.self.key_proj", (h, h)),
-            ("attention.self.value_proj", (h, h)),
-            ("attention.output.dense", (h, h)),
-            ("intermediate.dense", (inter, h)),
-            ("output.dense", (h, inter)),
-        ):
-            out.append((f"{base}.{name}.weight", shape, "normal"))
-            out.append((f"{base}.{name}.bias", (shape[0],), "normal"))
-        for name in ("attention.output.LayerNorm", "output.LayerNorm"):
-            out.append((f"{base}.{name}.weight", (h,), "ln_scale"))
-            out.append((f"{base}.{name}.bias", (h,), "normal"))
-    out += [
-        ("pooler.dense.weight", (h, h), "normal"),
-        ("pooler.dense.bias", (h,), "normal"),
-        ("classifier.weight", (1, h), "normal"),
-        ("classifier.bias", (1,), "normal"),
-    ]
-    return out
-
-
-FAMILIES = {"bert": bert_tensors, "deberta-v2": deberta_tensors}
-
-
-def _draw(seed: int, index: int, shape: tuple, kind: str) -> np.ndarray:
+def _draw(seed: int, index: int, shape: tuple, kind: str, std: float) -> np.ndarray:
     rng = np.random.default_rng(np.random.SeedSequence([seed, 7001, index]))
     x = rng.standard_normal(shape, dtype=np.float32)
-    x *= np.float32(INIT_STD)
+    x *= np.float32(std)
     if kind == "ln_scale":
         x += np.float32(1.0)
     return x.astype(BF16)
 
 
-def make_state(family: str, cfg: dict, seed: int, threads: int = 0) -> dict:
-    """name -> bfloat16 array, the whole checkpoint, from the seed."""
-    specs = FAMILIES[family](cfg)
+def _draw_all(seed: int, indexed: list, std: float, threads: int = 0) -> dict:
+    """``indexed`` is [(position in the family's list, (name, shape, kind))]."""
     threads = threads or min(16, os.cpu_count() or 1)
     with ThreadPoolExecutor(max_workers=threads) as pool:
         arrays = list(
-            pool.map(
-                lambda item: _draw(seed, item[0], item[1][1], item[1][2]),
-                enumerate(specs),
-            )
+            pool.map(lambda item: _draw(seed, item[0], item[1][1], item[1][2], std), indexed)
         )
-    return {spec[0]: arr for spec, arr in zip(specs, arrays)}
+    return {spec[0]: arr for (_, spec), arr in zip(indexed, arrays)}
 
 
-def write_checkpoint(directory: str, family: str, cfg: dict, seed: int) -> str:
+def make_state(family: str, cfg: dict, seed: int, threads: int = 0) -> dict:
+    """name -> bfloat16 array, the whole checkpoint, from the seed."""
+    specs, std = specs_of(family, cfg)
+    return _draw_all(seed, list(enumerate(specs)), std, threads)
+
+
+def plan_shards(specs: list, shard_bytes: int) -> list:
+    """The family's list cut, in its order, into runs of at most
+    ``shard_bytes`` (a tensor larger than that is a shard of its own)."""
+    shards, size = [[]], 0
+    for index, spec in enumerate(specs):
+        nbytes = 2 * math.prod(spec[1])
+        if shards[-1] and size + nbytes > shard_bytes:
+            shards.append([])
+            size = 0
+        shards[-1].append((index, spec))
+        size += nbytes
+    return shards
+
+
+def write_checkpoint(
+    directory: str, family: str, cfg: dict, seed: int, shard_bytes: int = SHARD_BYTES
+) -> str:
+    """The checkpoint's files under ``directory``; returns the path of the
+    file that names them all (the one file, or the index of the shards)."""
     from safetensors.numpy import save_file
 
     os.makedirs(directory, exist_ok=True)
-    path = os.path.join(directory, "model.safetensors")
-    save_file(make_state(family, cfg, seed), path)
+    for stale in os.listdir(directory):  # a work directory is used again
+        if stale.endswith(".safetensors") or stale == INDEX:
+            os.remove(os.path.join(directory, stale))
+    specs, std = specs_of(family, cfg)
+    shards = plan_shards(specs, shard_bytes)
+    if len(shards) == 1:
+        path = os.path.join(directory, SINGLE)
+        save_file(_draw_all(seed, shards[0], std), path)
+        return path
+    weight_map, total = {}, 0
+    for i, shard in enumerate(shards, start=1):
+        name = f"model-{i:05d}-of-{len(shards):05d}.safetensors"
+        state = _draw_all(seed, shard, std)
+        save_file(state, os.path.join(directory, name))
+        for tensor, array in state.items():
+            weight_map[tensor] = name
+            total += array.nbytes
+        del state
+    path = os.path.join(directory, INDEX)
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump({"metadata": {"total_size": total}, "weight_map": weight_map}, f, indent=2)
     return path
 
 
-def read_checkpoint(directory: str) -> dict:
-    from safetensors.numpy import load_file
+class Checkpoint(Mapping):
+    """name -> array over a checkpoint's files, one or sharded: a tensor is
+    read from its file when it is asked for, and nothing is kept."""
 
-    return load_file(os.path.join(directory, "model.safetensors"))
+    def __init__(self, directory: str):
+        index = os.path.join(directory, INDEX)
+        if os.path.exists(index):
+            with open(index, encoding="utf-8") as f:
+                files = json.load(f)["weight_map"]
+        else:
+            from safetensors import safe_open
 
+            with safe_open(os.path.join(directory, SINGLE), framework="np") as f:
+                files = {name: SINGLE for name in f.keys()}
+        self._directory = directory
+        self._files = files
 
-# -- vocabularies: whole words, so one word is one token ---------------------
+    def __getitem__(self, name: str) -> np.ndarray:
+        from safetensors import safe_open
 
+        path = os.path.join(self._directory, self._files[name])
+        with safe_open(path, framework="np") as f:
+            return f.get_tensor(name)
 
-def words_for(vocab_size: int, specials: int) -> int:
-    return vocab_size - specials
+    def __iter__(self):
+        return iter(self._files)
 
-
-def write_wordpiece_vocab(path: str, vocab_size: int) -> None:
-    """``vocab.txt``: four specials, then the words ``w0`` ... — the real
-    WordPiece path (not the program's hash fallback) maps each word to one
-    id: word k is id 4 + k, [CLS] 2, [SEP] 3, [PAD] 0."""
-    words = (f"w{i}" for i in range(words_for(vocab_size, 4)))
-    with open(path, "w", encoding="utf-8") as f:
-        f.write("\n".join([*SPECIALS_WORDPIECE, *words]) + "\n")
-
-
-
-SPECIALS_DEBERTA = ["[PAD]", "[CLS]", "[SEP]", "[UNK]"]
-
-
-def _varint(value: int) -> bytes:
-    out = bytearray()
-    while True:
-        byte = value & 0x7F
-        value >>= 7
-        out.append(byte | (0x80 if value else 0))
-        if not value:
-            return bytes(out)
+    def __len__(self) -> int:
+        return len(self._files)
 
 
-def write_sentencepiece_model(path: str, vocab_size: int) -> None:
-    """``spm.model``: a SentencePiece ``ModelProto`` holding only its pieces
-    (field 1: piece, score, type), in the DeBERTa-v2/v3 convention: [PAD] 0,
-    [CLS] 1, [SEP] 2 control pieces, [UNK] 3, then one whole-word piece
-    ``▁w<k>`` per word, id 4 + k, all of one score.  A whole word is the only
-    segmentation its characters have, so one word is one token through the
-    real unigram path."""
-    import struct
-
-    normal, unknown, control = 1, 2, 3
-    score = struct.pack("<f", -10.0)
-    chunks = []
-
-    def piece(text: str, kind: int) -> None:
-        raw = text.encode("utf-8")
-        inner = b"\x0a" + _varint(len(raw)) + raw + b"\x15" + score
-        inner += b"\x18" + _varint(kind)
-        chunks.append(b"\x0a" + _varint(len(inner)) + inner)
-
-    for name in SPECIALS_DEBERTA:
-        piece(name, unknown if name == "[UNK]" else control)
-    for k in range(words_for(vocab_size, 4)):
-        piece(f"▁w{k}", normal)
-    with open(path, "wb") as f:
-        f.write(b"".join(chunks))
+def read_checkpoint(directory: str) -> Checkpoint:
+    return Checkpoint(directory)

@@ -29,15 +29,17 @@ every candidate count, the rest drawn from the seed.
 
 from __future__ import annotations
 
-import importlib
 import math
 
 import numpy as np
 
+import byname
+
 
 def sample(served: list, count: int, seed: int) -> list:
-    """``served`` is [(request, confidence)].  The longest request, one of
-    each candidate count, then seeded draws up to ``count``."""
+    """``served`` is [(request, kept)], ``kept`` the fields of the answer
+    that the generator had kept (here ``confidence``).  The longest request,
+    one of each candidate count, then seeded draws up to ``count``."""
     if len(served) <= count:
         return list(served)
     rng = np.random.default_rng(np.random.SeedSequence([int(seed), 21]))
@@ -102,14 +104,14 @@ def collect(port: int, config: dict, picked: list, render_text) -> list:
 
 
 def run(config, cfg, state, picked, vectors, dry, cache_dir, lowered=False) -> dict:
-    """``picked`` is the sample [(request, confidence)], ``vectors`` what
+    """``picked`` is the sample [(request, kept)], ``vectors`` what
     ``collect`` returned for it."""
     params = config["check"]
     setup_jax(cache_dir, dry)
-    ref = importlib.import_module("references." + config["reference"])
+    ref = byname.module("references", config["reference"])
     weights = ref.load(state, cfg)
     diffs, rotated, angles = [], [], []
-    for (req, confidence), served_vecs in zip(picked, vectors):
+    for (req, kept), served_vecs in zip(picked, vectors):
         inputs = ref.inputs(req, cfg, config["tokenizer"])
         if served_vecs is not None:
             emb = np.asarray(
@@ -121,7 +123,7 @@ def run(config, cfg, state, picked, vectors, dry, cache_dir, lowered=False) -> d
         else:
             want = ref.logits(weights, cfg, *inputs, lowered=lowered)
         want = np.asarray(want, dtype=np.float64)
-        got = centred_logits(confidence, float(params["temperature"]))
+        got = centred_logits(kept["confidence"], float(params["temperature"]))
         diffs.append(got - (want - want.mean()))
         rotated.append(np.roll(got, 1) - (want - want.mean()))
     flat = np.concatenate(diffs) if diffs else np.zeros(0)

@@ -34,6 +34,15 @@ Parameters (all in the mix's file):
   scorer      "cosine" (default) | "rm";  prompt_words: length of the prompt
   warm_groups read by the harness, not here: the group sizes it makes the
               batcher dispatch once before the window (bench/run.py)
+
+What the harness takes from a generator besides ``generate`` (bench/run.py
+names no route and no field of an answer): ``PATH``, the route every request
+of a mix is posted to; ``KEEP``, the fields of a 200 answer the load generator
+keeps; ``well_formed(kept, req)``; ``request_tokens(req, overhead)``, a
+request's size as the program's sequence bucket sees it; ``warm_sample``, a
+few requests of the mix's shapes for warming; ``blocker(body)``, the same
+request under another grouping key of the batcher (only a mix with
+``warm_groups`` needs it); ``render_body`` and ``render_text``.
 """
 
 from __future__ import annotations
@@ -42,6 +51,10 @@ import math
 from statistics import NormalDist
 
 import numpy as np
+
+PATH = "/consensus"
+KEEP = ("confidence",)
+BLOCKER_TEMPERATURE = 0.051  # another grouping key than the default 0.05
 
 
 def _rng(seed: int, stream: int) -> np.random.Generator:
@@ -216,3 +229,33 @@ def render_body(req: dict) -> dict:
         if len(req.get("prompt", ())):
             body["prompt"] = render_text(req["prompt"])
     return body
+
+
+def request_tokens(req: dict, overhead: int) -> int:
+    """The vote embeds each candidate alone: a request is as long as its
+    longest candidate, with the prompt where the scorer reads one."""
+    longest = max(len(w) for w in req["words"])
+    return longest + len(req.get("prompt", ())) + overhead
+
+
+def warm_sample(mix: dict, seed: int, vocab_words: int) -> list:
+    """A few requests of the mix's shapes with words of their own."""
+    return generate(
+        {**mix, "loop": "open", "rate": 4.0, "arrivals": {"kind": "poisson"}},
+        seed + 1, 1.0, vocab_words,
+    )
+
+
+def blocker(body: dict) -> dict:
+    return {**body, "temperature": BLOCKER_TEMPERATURE}
+
+
+def well_formed(kept: dict, req: dict) -> bool:
+    """N finite values that sum to 1 within 1e-3."""
+    conf = kept.get("confidence")
+    return (
+        isinstance(conf, list)
+        and len(conf) == req["n"]
+        and all(isinstance(c, float) and math.isfinite(c) for c in conf)
+        and abs(sum(conf) - 1.0) <= 1e-3
+    )

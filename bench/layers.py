@@ -27,10 +27,10 @@ nothing to read returns None and the metric is left out of the line.
 
 from __future__ import annotations
 
-import importlib
 import json
 import os
 
+import byname
 import xplane as xtrace
 from server import BenchError
 
@@ -131,8 +131,7 @@ def reduce_all(bench, cell, config, cfg, before, profile, work, window) -> dict:
         elif spec["from"] == "window":
             value = window.get(spec["name"])
         elif spec["from"] == "trace":
-            reducer = importlib.import_module("reducers." + spec["reducer"])
-            value = reducer.reduce(ctx)
+            value = byname.module("reducers", spec["reducer"]).reduce(ctx)
         else:
             raise BenchError(f"unknown source {spec['from']!r}")
         if value is not None:

@@ -2,7 +2,7 @@
 """Load generator: a process of its own, which never imports jax.
 
     python3 bench/loadgen.py --schedule FILE --out FILE --port P \
-        --seconds S --drain D
+        --path ROUTE --keep FIELD,FIELD --seconds S --drain D
 
 Reads the schedule (one JSON line per request: ``due_s`` for an open loop,
 ``caller``/``turn`` for a closed one, and the request ``body`` as a string),
@@ -17,7 +17,8 @@ the window, requests still out get ``drain`` seconds; what is not back by then
 is recorded as failed (``status`` 0).
 
 One result line per request goes to ``--out``: index, due_s, sent_s, done_s,
-status, and the ``confidence`` the server answered.  The last stdout line is a
+status, and under ``kept`` the fields of a 200 answer that ``--keep`` names
+(the mix's generator says which, and judges them).  The last stdout line is a
 summary.  One thread, one event loop: bodies are strings made before the
 window, so the generator's own work in the window is a write and a parse.
 """
@@ -33,7 +34,7 @@ import time
 import aiohttp
 
 
-async def _send(session, url, item, t0, results):
+async def _send(session, url, keep, item, t0, results):
     rec = {"index": item["index"], "due_s": item.get("due_s"), "status": 0}
     rec["sent_s"] = time.monotonic() - t0
     try:
@@ -46,7 +47,8 @@ async def _send(session, url, item, t0, results):
             rec["status"] = resp.status
         rec["done_s"] = time.monotonic() - t0
         if rec["status"] == 200:
-            rec["confidence"] = json.loads(raw).get("confidence")
+            answer = json.loads(raw)
+            rec["kept"] = {field: answer.get(field) for field in keep}
         else:
             rec["error"] = raw[:200].decode("utf-8", "replace")
     except asyncio.CancelledError:
@@ -59,17 +61,19 @@ async def _send(session, url, item, t0, results):
     results.append(rec)
 
 
-async def _open_loop(session, url, items, seconds, drain, t0, results):
+async def _open_loop(session, url, keep, items, seconds, drain, t0, results):
     tasks = []
     for item in sorted(items, key=lambda it: it["due_s"]):
         wait = item["due_s"] - (time.monotonic() - t0)
         if wait > 0:
             await asyncio.sleep(wait)
-        tasks.append(asyncio.create_task(_send(session, url, item, t0, results)))
+        tasks.append(
+            asyncio.create_task(_send(session, url, keep, item, t0, results))
+        )
     await _finish(tasks, seconds + drain - (time.monotonic() - t0))
 
 
-async def _closed_loop(session, url, items, seconds, drain, t0, results):
+async def _closed_loop(session, url, keep, items, seconds, drain, t0, results):
     by_caller: dict = {}
     for item in sorted(items, key=lambda it: it["turn"]):
         by_caller.setdefault(item["caller"], []).append(item)
@@ -78,7 +82,7 @@ async def _closed_loop(session, url, items, seconds, drain, t0, results):
         for item in mine:
             if time.monotonic() - t0 >= seconds:
                 return
-            await _send(session, url, item, t0, results)
+            await _send(session, url, keep, item, t0, results)
 
     tasks = [asyncio.create_task(caller(mine)) for mine in by_caller.values()]
     await _finish(tasks, seconds + drain)
@@ -102,6 +106,7 @@ async def run(args) -> dict:
         items = [json.loads(line) for line in f if line.strip()]
     closed = bool(items) and "caller" in items[0]
     url = f"http://127.0.0.1:{args.port}{args.path}"
+    keep = [field for field in args.keep.split(",") if field]
     results: list = []
     connector = aiohttp.TCPConnector(limit=0)
     timeout = aiohttp.ClientTimeout(total=None)
@@ -113,7 +118,9 @@ async def run(args) -> dict:
             raise SystemExit("loadgen: stdin closed before the start signal")
         t0 = time.monotonic()
         runner = _closed_loop if closed else _open_loop
-        await runner(session, url, items, args.seconds, args.drain, t0, results)
+        await runner(
+            session, url, keep, items, args.seconds, args.drain, t0, results
+        )
         elapsed = time.monotonic() - t0
     with open(args.out, "w", encoding="utf-8") as f:
         for rec in results:
@@ -131,7 +138,8 @@ def main() -> None:
     parser.add_argument("--schedule", required=True)
     parser.add_argument("--out", required=True)
     parser.add_argument("--port", type=int, required=True)
-    parser.add_argument("--path", default="/consensus")
+    parser.add_argument("--path", required=True)
+    parser.add_argument("--keep", default="", help="fields of a 200 answer to keep")
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--drain", type=float, default=10.0)
     args = parser.parse_args()

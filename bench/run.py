@@ -5,21 +5,30 @@
 
 Everything a cell is made of is found by name, from ``BENCHMARK.json`` at the
 checkout root: its configuration (``bench/configs/<config>.json``), its
-traffic mix (``bench/traffic/<traffic>.json``, read by the one generator the
-mix names), the per-layer metrics that list the cell
+traffic mix (``bench/traffic/<traffic>.json``, read by the generator the mix
+names), the per-layer metrics that list the cell
 (``bench/layer_metrics/<metric>.json``; a metric read from a trace names a
 reducer in ``bench/reducers/``) and the output check the configuration names
-(``bench/checks/``, with its plain reference in ``bench/references/``).
-Adding a cell, a mix, a configuration or a metric adds files and entries and
-edits none.
+(``bench/checks/``, with its plain reference in ``bench/references/``).  The
+configuration's file names the rest: its ``family`` (``bench/families/``: the
+checkpoint's tensors and the forward's operations), its tokenizer ``kind``
+(``bench/tokenizers/``) and, under ``serve``, how the program is handed them:
+the environment variables that take the checkpoint and the vocabulary, the
+``/metrics`` ``device`` key that holds the parameters' dtype, and the warm-up
+recipe (``bench/warmups/``), if the program has a warm-up to spell.  The
+generator owns its route and its answer: the path it posts to, the fields of
+an answer that are kept and whether they are well formed.  This file names no
+family, tokenizer, role, scorer, route or field (``bench/byname.py``), so
+adding any of them adds files and entries and edits none.
 
 One run, in order (what is set-up and what is not):
 
   set-up   checkpoint and vocabulary from --seed (public HF layout); schedule
            from --seed; the program's own server as a child, with the shapes
-           the schedule reaches passed as WARMUP; one warm request per shape
-           and one concurrent burst; the load generator (a second child, no
-           jax) loaded and connected.  ``setup_s`` ends here.
+           the schedule reaches spelled as the configuration's warm-up recipe
+           spells them; one warm request per shape and one concurrent burst;
+           the load generator (a second child, no jax) loaded and connected.
+           ``setup_s`` ends here.
   window   --seconds of load.  With --trace 1 a profile of TRACE_MS is taken
            at its end through POST /v1/profile.
   after    /metrics again: a compilation inside the window fails the run.
@@ -28,8 +37,9 @@ One run, in order (what is set-up and what is not):
            a seeded sample of the window's own answers, and the check decides
            ``correct``.  None of this is in ``setup_s``.
 
-The last stdout line is the result.  No accelerator, a server that is not on
-bf16 parameters with compiled Pallas kernels, or a compile inside the window:
+The last stdout line is the result.  No accelerator, a server whose parameters
+are not in the configuration's ``precision`` or whose Pallas kernels are
+interpreted, or a compile inside the window:
 exit code 1 and no result line.  ``--dry-run`` rehearses the same plumbing on
 the CPU at the configuration's ``dry_run`` sizes and prints counts only.
 """
@@ -37,9 +47,7 @@ the CPU at the configuration's ``dry_run`` sizes and prints counts only.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
-import math
 import os
 import subprocess
 import sys
@@ -51,6 +59,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
+import byname  # noqa: E402
 import checkpoints  # noqa: E402
 import layers  # noqa: E402
 import stats  # noqa: E402
@@ -94,58 +103,42 @@ def prepare_files(work: str, config: dict, cfg: dict, seed: int) -> dict:
     """Checkpoint and tokenizer file from the seed; returns their paths."""
     ckpt = os.path.join(work, "ckpt")
     checkpoints.write_checkpoint(ckpt, config["family"], cfg, seed)
-    tok = config["tokenizer"]
-    if tok["kind"] == "wordpiece":
-        vocab = os.path.join(ckpt, "vocab.txt")
-        checkpoints.write_wordpiece_vocab(vocab, cfg["vocab_size"])
-    elif tok["kind"] == "sentencepiece":
-        vocab = os.path.join(ckpt, "spm.model")
-        checkpoints.write_sentencepiece_model(vocab, cfg["vocab_size"])
-    else:
-        raise BenchError(f"unknown tokenizer kind {tok['kind']!r}")
+    tokenizer = byname.module("tokenizers", config["tokenizer"]["kind"])
+    vocab = os.path.join(ckpt, tokenizer.FILE)
+    tokenizer.write(vocab, cfg["vocab_size"])
     return {"ckpt": ckpt, "vocab": vocab}
 
 
-def request_tokens(req: dict, overhead: int) -> int:
-    longest = max(len(w) for w in req["words"])
-    return longest + len(req.get("prompt", ())) + overhead
-
-
-def warm_shapes(requests: list, overhead: int, cap: int) -> list:
+def warm_shapes(gen, requests: list, overhead: int, cap: int) -> list:
     """Distinct (N, tokens) the schedule reaches; the program snaps tokens to
-    its own sequence bucket."""
+    its own sequence bucket.  How long a request is, is its generator's to
+    say."""
     return sorted(
-        {(r["n"], min(request_tokens(r, overhead), cap)) for r in requests}
+        {(r["n"], min(gen.request_tokens(r, overhead), cap)) for r in requests}
     )
 
 
 def server_env(config, files, shapes, work, dry) -> dict:
+    """The program's environment: the configuration's own ``server_env``,
+    the checkpoint and the vocabulary under the variables its ``serve`` block
+    names, and the warm shapes as its recipe spells them (no recipe, no such
+    variable)."""
     env = dict(config["server_env"])
     if dry:
         env["JAX_PLATFORMS"] = "cpu"
-    role = config["role"]  # "embedder" or "reranker"
-    prefix = "EMBEDDER" if role == "embedder" else "RM"
-    env[f"{prefix}_WEIGHTS"] = files["ckpt"]
-    env[f"{prefix}_VOCAB"] = files["vocab"]
+    serve = config["serve"]
+    env[serve["weights_env"]] = files["ckpt"]
+    env[serve["vocab_env"]] = files["vocab"]
     env["PROFILE_DIR"] = os.path.join(work, "prof")
-    if role == "embedder":
-        env["WARMUP"] = ",".join(f"{n}x{s}" for n, s in shapes)
-        max_rows = int(env.get("BATCH_MAX_ROWS", 512))
-        max_batch = int(env.get("BATCH_MAX", 64))
-        r_top = max(
-            min(max_batch, max_rows // n) for n, _ in shapes
-        )
-        r_buckets = [2**k for k in range(1, 12) if 2**k <= max(r_top, 1)]
-        if r_buckets:
-            env["WARMUP_R"] = ",".join(str(r) for r in r_buckets)
+    if "warmup" in serve:
+        env.update(byname.module("warmups", serve["warmup"]).env(shapes, env))
     return env
 
 
-def post_consensus(port: int, body: dict):
-    status, raw = http_json(port, "POST", "/consensus", body)
+def post_warm(port: int, path: str, body: dict) -> None:
+    status, raw = http_json(port, "POST", path, body)
     if status != 200:
         raise BenchError(f"warm request: HTTP {status}: {raw[:300]!r}")
-    return json.loads(raw)["confidence"]
 
 
 def compile_events(doc: dict) -> int:
@@ -166,7 +159,6 @@ def dispatch_counts(before: dict, after: dict) -> dict:
     return out
 
 
-BLOCKER_TEMPERATURE = 0.051  # another grouping key than the default 0.05
 WARM_ROUNDS = 2
 
 
@@ -176,44 +168,55 @@ def warm_requests(port, gen, requests, overhead: int, mix: dict) -> dict:
     exactly that many requests, ``warm_rounds`` times over (default 2).
 
     The program builds, the first time it meets one, a small helper program
-    per (request bucket, group size), on top of the model programs WARMUP
-    compiled; met inside the window it stalls the dispatcher for the better
-    part of a second (PERF.md, Findings).  How arrivals split into groups is
-    the batcher's business, so a group of exactly r is made like this: six
-    requests with another temperature (another grouping key, the same
-    programs) go first and keep both of the batcher's pipeline slots busy for
-    some 150 ms; r requests sent 60 ms behind them queue up meanwhile and are
-    taken together when a slot frees.  A mix of long requests needs fewer
-    blockers to keep the slots busy that long: ``warm_blockers`` (default 6)."""
+    per (request bucket, group size), on top of the model programs its own
+    warm-up compiled; met inside the window it stalls the dispatcher for the
+    better part of a second (PERF.md, Findings).  How arrivals split into
+    groups is the batcher's business, so a group of exactly r is made like
+    this: six requests under another grouping key (the generator's
+    ``blocker``: the same programs) go first and keep both of the batcher's
+    pipeline slots busy for some 150 ms; r requests sent 60 ms behind them
+    queue up meanwhile and are taken together when a slot frees.  A mix of long requests needs fewer
+    blockers to keep the slots busy that long: ``warm_blockers`` (default 6).
+    One blocker goes 10 ms ahead of the others: sent together they may all
+    fall into the batcher's 3 ms gathering window and fill ONE slot, and the
+    r requests then trickle into the free one in twos and threes, so that the
+    helper of size r is met first inside the window (PERF.md, PR 26)."""
     groups = mix["warm_groups"]
     rounds = int(mix.get("warm_rounds", WARM_ROUNDS)) if groups else 0
     blockers = int(mix.get("warm_blockers", 6))
-    firsts, done = [], set()
-    for req in requests:
-        shape = (req["n"], request_tokens(req, overhead))
-        if shape not in done:
-            done.add(shape)
-            firsts.append(req)
-    for req in firsts:
-        post_consensus(port, gen.render_body(req))
-    bodies = [gen.render_body(req) for req in firsts]
+    bodies = warm_bodies(gen, requests, overhead)
+    for body in bodies:
+        post_warm(port, gen.PATH, body)
     for _ in range(rounds):
         for body in bodies:
-            blocker = {**body, "temperature": BLOCKER_TEMPERATURE}
+            blocker = gen.blocker(body)
             for size in groups:
-                ahead = start_burst(port, blocker, blockers)
+                ahead = start_burst(port, gen.PATH, blocker, 1)
+                time.sleep(0.01)
+                ahead += start_burst(port, gen.PATH, blocker, blockers - 1)
                 time.sleep(0.06)
-                behind = start_burst(port, body, int(size))
+                behind = start_burst(port, gen.PATH, body, int(size))
                 finish_burst(ahead + behind)
-    return {"shapes": len(firsts), "rounds": rounds, "groups": list(groups)}
+    return {"shapes": len(bodies), "rounds": rounds, "groups": list(groups)}
 
 
-def start_burst(port: int, body: dict, size: int) -> list:
+def warm_bodies(gen, requests: list, overhead: int) -> list:
+    """The body of the first request of each distinct (N, tokens)."""
+    bodies, done = [], set()
+    for req in requests:
+        shape = (req["n"], gen.request_tokens(req, overhead))
+        if shape not in done:
+            done.add(shape)
+            bodies.append(gen.render_body(req))
+    return bodies
+
+
+def start_burst(port: int, path: str, body: dict, size: int) -> list:
     errors: list = []
 
     def one():
         try:
-            post_consensus(port, body)
+            post_warm(port, path, body)
         except BenchError as e:
             errors.append(e)
 
@@ -231,16 +234,18 @@ def finish_burst(bursts: list) -> None:
             raise errors[0]
 
 
-def check_device(device: dict, chips: int, dry: bool) -> None:
+def check_device(device: dict, config: dict, chips: int, dry: bool) -> None:
+    """``device`` is the section of that name of the program's /metrics; the
+    configuration says under which key it holds the parameters' dtype."""
     if dry:
         return
     if device.get("platform") != "tpu":
         raise BenchError(f"no accelerator: the server runs on {device}")
     if int(device.get("device_count", 0)) < chips:
         raise BenchError(f"{chips} chips asked, the server sees {device}")
-    dtype = device.get("param_dtype") or device.get("rm_param_dtype")
-    if dtype != "bfloat16":
-        raise BenchError(f"parameters are {dtype}, not bfloat16: {device}")
+    dtype, want = device.get(config["serve"]["param_dtype"]), config["precision"]
+    if dtype != want:
+        raise BenchError(f"parameters are {dtype}, not {want}: {device}")
     if device.get("pallas_interpret") is not False:
         raise BenchError(f"Pallas kernels are interpreted: {device}")
 
@@ -258,11 +263,12 @@ def write_schedule(path: str, gen, requests: list) -> None:
             f.write(json.dumps(item) + "\n")
 
 
-def start_loadgen(port: int, schedule: str, out: str, seconds: float):
+def start_loadgen(port: int, gen, schedule: str, out: str, seconds: float):
     return subprocess.Popen(
         [
             sys.executable, os.path.join(HERE, "loadgen.py"),
             "--schedule", schedule, "--out", out, "--port", str(port),
+            "--path", gen.PATH, "--keep", ",".join(gen.KEEP),
             "--seconds", str(seconds), "--drain", str(DRAIN_S),
         ],
         stdin=subprocess.PIPE,
@@ -316,16 +322,6 @@ def read_results(path: str) -> list:
         return [json.loads(line) for line in f if line.strip()]
 
 
-def well_formed(rec: dict, n: int) -> bool:
-    conf = rec.get("confidence")
-    return (
-        isinstance(conf, list)
-        and len(conf) == n
-        and all(isinstance(c, float) and math.isfinite(c) for c in conf)
-        and abs(sum(conf) - 1.0) <= 1e-3
-    )
-
-
 def end_to_end(results: list, loop: str, seconds: float) -> dict:
     """Every end-to-end number the window gives; the cell reports those that
     BENCHMARK.json lists for it."""
@@ -363,7 +359,7 @@ def load_cell(workload: str, dry: bool, benchmark=None):
     entry = find(bench["configs"], cell["config"], "config")
     config = load_json(ROOT, entry["file"])
     mix = load_json(HERE, "traffic", cell["traffic"] + ".json")
-    gen = importlib.import_module("generators." + mix["generator"])
+    gen = byname.module("generators", mix["generator"])
     cfg = model_cfg(config, dry)
     if dry:
         mix = {**mix, **mix.get("dry_run", {})}
@@ -383,12 +379,9 @@ def run(args) -> int:
     vocab_words = cfg["vocab_size"] - tok["specials"]
     requests = gen.generate(mix, args.seed, args.seconds, vocab_words)
     # a few requests of the mix's shapes with words of their own, for warming
-    warm = gen.generate(
-        {**mix, "loop": "open", "rate": 4.0, "arrivals": {"kind": "poisson"}},
-        args.seed + 1, 1.0, vocab_words,
-    )
+    warm = gen.warm_sample(mix, args.seed, vocab_words)
     cap = int(cfg["max_tokens"])
-    shapes = warm_shapes(requests + warm, tok["overhead"], cap)
+    shapes = warm_shapes(gen, requests + warm, tok["overhead"], cap)
     log(f"[bench] {len(requests)} requests, {len(shapes)} shapes: {shapes[:12]}")
 
     files = prepare_files(work, config, cfg, args.seed)
@@ -404,11 +397,13 @@ def run(args) -> int:
     profile: dict = {}
     with Server(env, server_log) as server:
         write_schedule(schedule, gen, requests)  # while the server starts
-        loadgen = start_loadgen(server.port, schedule, results_path, args.seconds)
+        loadgen = start_loadgen(
+            server.port, gen, schedule, results_path, args.seconds
+        )
         try:
             server.wait_listening(timeout=1150.0)
             first = get_metrics(server.port)
-            check_device(first.get("device") or {}, cell["chips"], dry)
+            check_device(first.get("device") or {}, config, cell["chips"], dry)
             log(f"[bench] t={time.monotonic() - T_START:.1f}s server listening")
             warmed = warm_requests(
                 server.port, gen, requests + warm, tok["overhead"], mix
@@ -440,11 +435,11 @@ def run(args) -> int:
             served = [r for r in results if r["status"] == 200]
             malformed = [
                 r["index"] for r in served
-                if not well_formed(r, by_index[r["index"]]["n"])
+                if not gen.well_formed(r["kept"], by_index[r["index"]])
             ]
-            check = importlib.import_module("checks." + config["check"]["name"])
+            check = byname.module("checks", config["check"]["name"])
             picked = check.sample(
-                [(by_index[r["index"]], r["confidence"]) for r in served
+                [(by_index[r["index"]], r["kept"]) for r in served
                  if r["index"] not in malformed],
                 int(config["check"]["requests"]), args.seed,
             )
@@ -521,17 +516,10 @@ def run(args) -> int:
         {"name": "malformed_answers", "value": len(malformed), "limit": 0},
         *verdict["numbers"],
     ]
-    print(
-        json.dumps(
-            {
-                "check": numbers,
-                "compared": verdict["compared"],
-                "worst_abs": verdict.get("worst_abs"),
-                "logit_rms_if_rotated": verdict.get("logit_rms_if_rotated"),
-            }
-        ),
-        flush=True,
-    )
+    # whatever else the check gives (how many it compared, what a fault
+    # would have read) is printed beside the numbers, and compared with nothing
+    extras = {k: v for k, v in verdict.items() if k != "numbers"}
+    print(json.dumps({"check": numbers, **extras}), flush=True)
     correct = bool(served) and all(n["value"] <= n["limit"] for n in numbers)
     log(f"[bench] t={time.monotonic() - T_START:.1f}s checked")
 
@@ -549,7 +537,7 @@ def run(args) -> int:
             "dispatches": (after.get("device_batcher") or {}).get("dispatches", 0)
             - (before.get("device_batcher") or {}).get("dispatches", 0),
         }
-        print(json.dumps(result), flush=True)
+        print_result(result, numbers)
         return 0 if correct else 1
     result["device"] = {
         "platform": device["platform"],
@@ -573,8 +561,20 @@ def run(args) -> int:
             for m in bench["end_to_end"]
             if layers.reports(m, cell["name"]) and m["name"] in e2e
         }
-    print(json.dumps(result), flush=True)
+    print_result(result, numbers)
     return 0
+
+
+def print_result(result: dict, numbers: list) -> None:
+    """The result, as the last line of stdout, with every number compared
+    beside its limit as its last key; the same numbers are the last lines of
+    stderr."""
+    for n in numbers:
+        log(f"[bench] compared {n['name']}: {n['value']!r} (limit {n['limit']!r})")
+    result["check"] = {
+        n["name"]: {"value": n["value"], "limit": n["limit"]} for n in numbers
+    }
+    print(json.dumps(result), flush=True)
 
 
 def main() -> int:

@@ -121,9 +121,11 @@ def test_closed_loop_callers_and_rm_prompt():
 
 
 def test_poisson_bursts_puts_whole_groups_at_fixed_times():
-    m = {**mix("n64-s512.steady"), "rate": 3.6}
-    arrivals = m["arrivals"]
-    assert arrivals["kind"] == "poisson_bursts"
+    m = mix("n64-s512.steady")
+    assert m["arrivals"]["kind"] == "poisson_bursts"
+    # two bursts in a 50 s window, whatever the mix's own file now asks for
+    arrivals = {**m["arrivals"], "every_s": 25}
+    m = {**m, "rate": 3.6, "arrivals": arrivals}
     due = gen.arrival_times(m, 180, 50.0)
     assert len(due) == 180 and due[0] == 0.0 and due.max() < 50.0
     size, every = arrivals["size"], arrivals["every_s"]

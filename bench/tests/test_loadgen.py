@@ -72,6 +72,7 @@ def drive(tmp_path, port, items, seconds, drain):
         [
             sys.executable, os.path.join(BENCH, "loadgen.py"),
             "--schedule", str(schedule), "--out", str(out), "--port", str(port),
+            "--path", "/consensus", "--keep", "confidence",
             "--seconds", str(seconds), "--drain", str(drain),
         ],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
@@ -98,7 +99,7 @@ def test_open_loop_latency_runs_from_the_due_time(tmp_path):
     with StandIn(0.05) as server:
         summary, results = drive(tmp_path, server.port, items, 1.0, 2.0)
     assert summary["loop"] == "open" and summary["sent"] == 4
-    assert all(r["status"] == 200 and r["confidence"] == [0.5, 0.5] for r in results)
+    assert all(r["status"] == 200 and r["kept"] == {"confidence": [0.5, 0.5]} for r in results)
     latency = sorted(r["done_s"] - r["due_s"] for r in results)
     for k, value in enumerate(latency):
         assert 0.05 * (k + 1) <= value <= 0.05 * (k + 1) + 0.1

@@ -1,5 +1,6 @@
 import pytest
 
+import byname
 import run as bench_run
 
 
@@ -27,11 +28,13 @@ def test_closed_loop_rate_credits_the_share_in_flight_inside_the_window():
 
 
 def test_well_formed_answers():
-    assert bench_run.well_formed({"confidence": [0.25] * 4}, 4)
-    assert not bench_run.well_formed({"confidence": [0.25] * 3}, 4)
-    assert not bench_run.well_formed({"confidence": [0.5, 0.6]}, 2)
-    assert not bench_run.well_formed({"confidence": [float("nan"), 1.0]}, 2)
-    assert not bench_run.well_formed({}, 2)
+    well_formed = byname.module("generators", "consensus").well_formed
+    assert well_formed({"confidence": [0.25] * 4}, {"n": 4})
+    assert not well_formed({"confidence": [0.25] * 3}, {"n": 4})
+    assert not well_formed({"confidence": [0.5, 0.6]}, {"n": 2})
+    assert not well_formed({"confidence": [float("nan"), 1.0]}, {"n": 2})
+    assert not well_formed({}, {"n": 2})
+    assert not well_formed({"confidence": None}, {"n": 2})
 
 
 def test_compiles_are_read_from_the_jit_section():

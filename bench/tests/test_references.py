@@ -110,6 +110,11 @@ def test_log_buckets_at_the_published_sizes():
 WIDER = dict(BERT, hidden_size=128, num_hidden_layers=4, intermediate_size=256)
 
 
+def _kept(logit, temperature):
+    """An answer as the load generator keeps it for this check."""
+    return {"confidence": _confidence(logit, temperature)}
+
+
 def _confidence(logit, temperature):
     z = (logit - logit.max()) / temperature
     return (np.exp(z) / np.exp(z).sum()).tolist()
@@ -150,9 +155,9 @@ def test_the_control_fails_where_the_sound_path_passes_bert(tmp_path):
         emb = np.asarray(
             bert.embed(params, jnp.asarray(ids), jnp.asarray(mask), pcfg), np.float64
         )
-        sound.append((req, _confidence(bert_cls_cosine.vote_logits(emb), 0.05)))
+        sound.append((req, _kept(bert_cls_cosine.vote_logits(emb), 0.05)))
         low = bert_cls_cosine.logits(weights, WIDER, ids, mask, lowered=True)
-        control.append((req, _confidence(low, 0.05)))
+        control.append((req, _kept(low, 0.05)))
     cache = str(tmp_path / "jax_cache")
     a = _check(bert_cls_cosine, WIDER, BERT_TOK, state, sound, 0.05, cache)
     b = _check(bert_cls_cosine, WIDER, BERT_TOK, state, control, 0.05, cache)
@@ -165,9 +170,9 @@ def test_the_control_fails_where_the_sound_path_passes_deberta(tmp_path):
     sound, control = [], []
     for req in requests(11, "rm"):
         ids, mask = deberta_v3_reward.inputs(req, DEBERTA, DEBERTA_TOK)
-        sound.append((req, _confidence(program_rewards(state, DEBERTA, ids, mask), 1.0)))
+        sound.append((req, _kept(program_rewards(state, DEBERTA, ids, mask), 1.0)))
         low = deberta_v3_reward.logits(weights, DEBERTA, ids, mask, lowered=True)
-        control.append((req, _confidence(low, 1.0)))
+        control.append((req, _kept(low, 1.0)))
     cache = str(tmp_path / "jax_cache")
     a = _check(deberta_v3_reward, DEBERTA, DEBERTA_TOK, state, sound, 1.0, cache)
     b = _check(deberta_v3_reward, DEBERTA, DEBERTA_TOK, state, control, 1.0, cache)

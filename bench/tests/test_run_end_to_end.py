@@ -57,7 +57,7 @@ def test_a_broken_timed_path_is_not_correct(cell, capsys, monkeypatch):
         files = sound(work, config, cfg, seed)
         good = os.path.join(work, "reference_ckpt")
         checkpoints.write_checkpoint(good, config["family"], cfg, seed)
-        state = checkpoints.read_checkpoint(files["ckpt"])
+        state = dict(checkpoints.read_checkpoint(files["ckpt"]))
         last = cfg["num_hidden_layers"] - 1
         name = next(
             k for k in state if k.endswith(f"layer.{last}.output.dense.weight")

@@ -47,20 +47,24 @@ def main() -> int:
         for rate in rates
     }
     every = [r for reqs in schedules.values() for r in reqs]
-    shapes = bench_run.warm_shapes(every, tok["overhead"], int(cfg["max_tokens"]))
+    shapes = bench_run.warm_shapes(gen, every, tok["overhead"], int(cfg["max_tokens"]))
     files = bench_run.prepare_files(work, config, cfg, args.seed)
     env = bench_run.server_env(config, files, shapes, work, args.dry_run)
     with Server(env, os.path.join(work, "server.log")) as server:
         server.wait_listening(timeout=1150.0)
         first = get_metrics(server.port)
-        bench_run.check_device(first.get("device") or {}, cell["chips"], args.dry_run)
+        bench_run.check_device(
+            first.get("device") or {}, config, cell["chips"], args.dry_run
+        )
         bench_run.warm_requests(server.port, gen, every, tok["overhead"], mix)
         for rate in rates:
             schedule = os.path.join(work, "schedule.jsonl")
             out = os.path.join(work, "results.jsonl")
             bench_run.write_schedule(schedule, gen, schedules[rate])
             before = get_metrics(server.port)
-            loadgen = bench_run.start_loadgen(server.port, schedule, out, args.seconds)
+            loadgen = bench_run.start_loadgen(
+                server.port, gen, schedule, out, args.seconds
+            )
             if loadgen.stdout.readline().strip() != b"ready":
                 raise SystemExit("load generator did not come up")
             loadgen.stdin.write(b"go\n")

@@ -149,6 +149,11 @@ CONCURRENCY_MODEL = {
             "kind": "lock",
             "guards": ("_edges", "_violations", "_acquisitions"),
         },
+        "TpuJudge._lock": {
+            "module": "llm_weighted_consensus_tpu/models/judge.py",
+            "kind": "lock",
+            "guards": ("_stats",),
+        },
         "DeviceBatcher._stats_lock": {
             "module": "llm_weighted_consensus_tpu/serve/batcher.py",
             "kind": "lock",

@@ -205,3 +205,60 @@ DEBERTA_TEST_TINY = DebertaConfig(
     max_relative_positions=16,
     position_buckets=0,
 )
+
+
+@dataclass(frozen=True)
+class GlmMoeLiteConfig:
+    """A causal decoder with latent attention and sparse experts
+    (``model_type`` ``glm4_moe_lite``, the DeepSeek-V3 layout): the judge
+    behind ``POST /consensus`` ``scorer: judge`` (models/glm_moe.py).
+
+    ``num_layers`` is the published depth; a checkpoint that names fewer
+    layers (one pipeline stage of a deployment) is served at the depth it
+    names (``glm_moe.from_hf_weights``)."""
+
+    vocab_size: int = 154880
+    hidden_size: int = 2048
+    num_layers: int = 47
+    num_heads: int = 20
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    intermediate_size: int = 10240
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64
+    num_experts_per_tok: int = 4
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.8
+    first_k_dense_replace: int = 1
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    # "int8": the dense products (attention projections, the dense layer's
+    # MLP, the shared expert) through quant.dense_int8; router, routed
+    # experts, embedding and head keep the parameters' dtype
+    quantize: str = "none"
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+# zai-org/GLM-4.7-Flash config.json
+GLM_4_7_FLASH = GlmMoeLiteConfig()
+GLM_TEST_TINY = GlmMoeLiteConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=3,
+    num_heads=4,
+    q_lora_rank=32,
+    kv_lora_rank=16,
+    qk_nope_head_dim=24,
+    qk_rope_head_dim=8,
+    v_head_dim=32,
+    intermediate_size=128,
+    moe_intermediate_size=48,
+    n_routed_experts=8,
+    num_experts_per_tok=2,
+)

@@ -119,6 +119,28 @@ class deferred_readiness:
         return False
 
 
+def dispatch(label: str, fn: Callable, timed: bool = True):
+    """Run one device dispatch under its label: the entry point of a model
+    that is no ``TpuEmbedder`` (the judge).  Under a deferred-readiness sink
+    the PJRT call is merely ENQUEUED and a :class:`PendingDispatch` hands
+    (label, t0, output) to the waiter; without one (direct callers) the
+    block-until-ready bracket runs inline and records the same numbers."""
+    sink = active_sink()
+    t0 = time.perf_counter()
+    out = fn()
+    if sink is not None:
+        sink.add(PendingDispatch(label, t0, out, timed=timed))
+        return out
+    wait_device_ready(out)
+    if timed:
+        t1 = time.perf_counter()
+        from ..obs import phases as _phases
+
+        _phases.observe_device(label, (t1 - t0) * 1e3)
+        _phases.observe_device_interval(t0, t1)
+    return out
+
+
 def drain_sink(
     sink: DispatchSink,
     observe_device: Optional[Callable[[str, float], None]] = None,

@@ -1,0 +1,513 @@
+"""A causal decoder with latent attention and sparse experts: the judge.
+
+``model_type`` ``glm4_moe_lite`` (zai-org/GLM-4.7-Flash), the DeepSeek-V3
+layout, written from its configuration:
+
+  x0      = embed[ids]
+  per layer:
+    h     = rms(x, w_in)
+    q     = W_qb · rms(W_qa · h)              heads of nope + rope dims
+    c, kr = W_kva · h ;  c = rms(c)           latent (cached), one rotary key
+    k, v  = W_kvb · c                         per head: nope dims | values
+    a     = causal softmax((q_nope·k + rope(q_rope)·rope(kr)) / sqrt(d)) v
+    x     = x + W_o · a
+    h     = rms(x, w_post)
+    x     = x + SwiGLU(h)                     the first ``first_k_dense_replace`` layers
+    x     = x + Σ_top-k w_e · SwiGLU_e(h) + SwiGLU_shared(h)   the others
+  logits  = W_head · rms(x[last], w_final)
+
+The router scores every expert with ``sigmoid(W_g · h)`` in float32, chooses
+the top k of score + ``e_score_correction_bias`` (the bias chooses, it does
+not weigh), and weighs the chosen by their unbiased scores, normalised to sum
+1, times ``routed_scaling_factor``.
+
+Two attention paths over the same weights.  PREFILL makes keys and values
+from the latent (``W_kvb`` applied) and runs the causal blockwise kernel
+(``ops/causal_attention.py``); what it leaves behind is the latent cache:
+``c`` and the rotary key, 576 values a token a layer.  DECODE is the
+ABSORBED path: ``W_kvb``'s key half is folded into the query and its value
+half into the output, so scores are taken against the cached latent itself
+and no key or value is ever rebuilt.
+
+Rotary dims: the checkpoint stores a head's rotary dims interleaved (pair
+(2i, 2i+1) turns together, as in the DeepSeek-V3 checkpoints).  The loader
+moves them to (i, i + d/2), the same permutation on the query's and the
+key's rows, which leaves every q·k unchanged and lets the rotation be a
+half-swap.
+
+Routed experts run as three grouped products over the tokens routed to each
+(``ops/grouped_matmul.py``), a decode step's handful of tokens in tiles of 16
+rows through the same kernel (tiles that hold no row fetch and compute
+nothing).  ``jax.named_scope`` names every part, so that a
+device trace can be read by layer.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import grouped_matmul as _gmm
+from ..ops.causal_attention import causal_attention_blockwise
+from ..ops.votes import softmax_votes
+from .configs import GlmMoeLiteConfig
+
+
+def _rms(x, weight, eps: float):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def _dense(x, p: dict):
+    """x[..., in] @ kernel[in, out]; the W8A8 twin where the loader
+    quantized this product (``JUDGE_QUANTIZE=int8``)."""
+    if "kernel_q" in p:
+        from .quant import dense_int8
+
+        return dense_int8(x, p, impl="xla")
+    return jnp.einsum(
+        "...i,io->...o", x, p["kernel"], preferred_element_type=jnp.float32
+    ).astype(x.dtype)
+
+
+def _swiglu(x, p: dict):
+    gate = _dense(x, p["gate"]).astype(jnp.float32)
+    up = _dense(x, p["up"]).astype(jnp.float32)
+    return _dense((jax.nn.silu(gate) * up).astype(x.dtype), p["down"])
+
+
+def _rope_angles(positions, dim: int, theta: float):
+    """positions [...] -> (cos, sin) [..., dim / 2], float32."""
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = positions.astype(jnp.float32)[..., None] * inv
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _rope(x, cos, sin):
+    """x [..., d] with pairs (i, i + d/2); cos, sin broadcast to [..., d/2]."""
+    half = x.shape[-1] // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
+    """h [..., hidden] -> q [..., heads, nope + rope], the rope dims turned;
+    cos, sin broadcast against [..., heads, rope / 2]."""
+    nope = config.qk_nope_head_dim
+    cq = _rms(_dense(h, p["q_a"]), p["q_a_norm"], config.rms_norm_eps)
+    q = _dense(cq, p["q_b"])
+    q = q.reshape(*q.shape[:-1], config.num_heads, config.qk_head_dim)
+    return jnp.concatenate(
+        [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1
+    )
+
+
+def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
+    """h [..., hidden] -> (c [..., kv_lora_rank] normalised, rotary key
+    [..., rope] turned): what the cache holds."""
+    rank = config.kv_lora_rank
+    kv = _dense(h, p["kv_a"])
+    c = _rms(kv[..., :rank], p["kv_a_norm"], config.rms_norm_eps)
+    return c, _rope(kv[..., rank:], cos, sin)
+
+
+def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig):
+    """h [b, s, hidden] -> (attention output [b, s, hidden], (c, kr))."""
+    b, s, _ = h.shape
+    heads, dq = config.num_heads, config.qk_head_dim
+    cos, sin = _rope_angles(jnp.arange(s), config.qk_rope_head_dim, config.rope_theta)
+    with jax.named_scope("latent_q"):
+        q = _queries(h, p, cos[:, None, :], sin[:, None, :], config)
+        q = q.reshape(b, s, heads * dq)
+    with jax.named_scope("latent_kv"):
+        c, kr = _latent(h, p, cos, sin, config)
+        # w_k's rope lanes are zero: the one rotary key is added into them
+        k = jnp.einsum(
+            "bsc,chd->bshd", c, p["w_k"], preferred_element_type=jnp.float32
+        ).astype(h.dtype)
+        k = k + jnp.pad(kr, ((0, 0), (0, 0), (config.qk_nope_head_dim, 0)))[:, :, None, :]
+        k = k.reshape(b, s, heads * dq)
+        v = jnp.einsum(
+            "bsc,cv->bsv", c, p["w_v"], preferred_element_type=jnp.float32
+        ).astype(h.dtype)
+    with jax.named_scope("causal_attention"):
+        ctx = causal_attention_blockwise(
+            q, k, v, heads=heads, scale=1.0 / math.sqrt(dq)
+        )
+    with jax.named_scope("attn_out"):
+        out = _dense(ctx, p["o"])
+    return out, (c, kr)
+
+
+def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig):
+    """One token a call through the latent cache, the absorbed path.
+    h [b, hidden] at position ``lens[b]``; cache (c [b, s, rank], kr
+    [b, s, rope]) holds positions < lens[b] (later slots are padding)."""
+    b = h.shape[0]
+    heads, dq, nope = config.num_heads, config.qk_head_dim, config.qk_nope_head_dim
+    cos, sin = _rope_angles(lens, config.qk_rope_head_dim, config.rope_theta)
+    with jax.named_scope("latent_q"):
+        q = _queries(h, p, cos[:, None, :], sin[:, None, :], config)  # [b, heads, dq]
+        # W_kvb's key half folded into the query: scores against the latent
+        q_lat = jnp.einsum(
+            "bhd,chd->bhc", q, p["w_k"], preferred_element_type=jnp.float32
+        ).astype(h.dtype)
+    with jax.named_scope("latent_kv"):
+        c_new, kr_new = _latent(h, p, cos, sin, config)
+        c_all = jnp.concatenate([cache[0], c_new[:, None, :]], axis=1)
+        kr_all = jnp.concatenate([cache[1], kr_new[:, None, :]], axis=1)
+    with jax.named_scope("causal_attention"):
+        scores = jnp.einsum(
+            "bhc,btc->bht", q_lat, c_all, preferred_element_type=jnp.float32
+        ) + jnp.einsum(
+            "bhr,btr->bht", q[..., nope:], kr_all, preferred_element_type=jnp.float32
+        )
+        slots = c_all.shape[1]
+        t = jnp.arange(slots)[None, :]
+        seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
+        scores = jnp.where(seen[:, None, :], scores / math.sqrt(dq), -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+        o_lat = jnp.einsum(
+            "bht,btc->bhc", probs, c_all, preferred_element_type=jnp.float32
+        ).astype(h.dtype)
+        # ... and its value half folded into the output
+        w_v = p["w_v"].reshape(config.kv_lora_rank, heads, config.v_head_dim)
+        ctx = jnp.einsum(
+            "bhc,chv->bhv", o_lat, w_v, preferred_element_type=jnp.float32
+        ).astype(h.dtype)
+    with jax.named_scope("attn_out"):
+        return _dense(ctx.reshape(b, heads * config.v_head_dim), p["o"])
+
+
+def route(h, p: dict, config: GlmMoeLiteConfig):
+    """h [t, hidden] -> (experts [t, k] int32, weights [t, k] float32)."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST
+    )
+    score = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(score + p["bias"], config.num_experts_per_tok)
+    weight = jnp.take_along_axis(score, chosen, axis=1)
+    weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+    return chosen.astype(jnp.int32), weight * config.routed_scaling_factor
+
+
+def _experts_grouped(h, chosen, p: dict, config: GlmMoeLiteConfig):
+    """h [t, hidden], chosen [t, k] -> ([t, k, hidden], counts [E])."""
+    t, k = chosen.shape
+    experts = config.n_routed_experts
+    tile = _gmm.tile_for(t * k, experts)
+    pair_of_row, row_of_pair, tile_expert, used, counts = _gmm.route_layout(
+        chosen.reshape(-1), experts, tile
+    )
+    product = partial(
+        _gmm.grouped_expert_product, tile_expert=tile_expert, tiles_used=used, tile=tile
+    )
+    x = h[pair_of_row // k]
+    gate = product(x, p["w_gate"]).astype(jnp.float32)
+    up = product(x, p["w_up"]).astype(jnp.float32)
+    y = product((jax.nn.silu(gate) * up).astype(h.dtype), p["w_down"])
+    return y[row_of_pair].reshape(t, k, -1), counts
+
+
+def _moe(h, p: dict, config: GlmMoeLiteConfig):
+    """h [t, hidden] -> (output [t, hidden], pairs routed to each expert)."""
+    with jax.named_scope("router"):
+        chosen, weight = route(h, p, config)
+    with jax.named_scope("experts_routed"):
+        y, counts = _experts_grouped(h, chosen, p, config)
+        routed = jnp.sum(y.astype(jnp.float32) * weight[..., None], axis=1)
+    with jax.named_scope("expert_shared"):
+        shared = _swiglu(h, p["shared"])
+    return routed.astype(h.dtype) + shared, counts
+
+
+def _mlp(h, layer: dict, config: GlmMoeLiteConfig):
+    """The layer's second half over h [..., hidden]: (output, counts | None)."""
+    if "mlp" in layer:
+        with jax.named_scope("dense_mlp"):
+            return _swiglu(h, layer["mlp"]), None
+    flat, counts = _moe(h.reshape(-1, h.shape[-1]), layer["moe"], config)
+    return flat.reshape(h.shape), counts
+
+
+def prefill(params: dict, ids, config: GlmMoeLiteConfig):
+    """ids [b, s] -> (hidden [b, s, hidden] before the final norm, the
+    latent cache a layer, pairs routed to each expert a sparse layer)."""
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["token_embed"], ids, axis=0)
+    caches, loads = [], []
+    for layer in params["layers"]:
+        h = _rms(x, layer["input_norm"], config.rms_norm_eps)
+        out, cache = _attention_prefill(h, layer["attn"], config)
+        x = x + out
+        caches.append(cache)
+        out, counts = _mlp(_rms(x, layer["post_norm"], config.rms_norm_eps), layer, config)
+        x = x + out
+        if counts is not None:
+            loads.append(counts)
+    return x, caches, loads
+
+
+def decode_step(params: dict, token, lens, caches, config: GlmMoeLiteConfig):
+    """One token a call at position ``lens`` -> hidden [b, hidden]."""
+    with jax.named_scope("embed_tokens"):
+        x = jnp.take(params["token_embed"], token, axis=0)
+    for layer, cache in zip(params["layers"], caches):
+        h = _rms(x, layer["input_norm"], config.rms_norm_eps)
+        x = x + _attention_decode(h, layer["attn"], lens, cache, config)
+        out, _ = _mlp(_rms(x, layer["post_norm"], config.rms_norm_eps), layer, config)
+        x = x + out
+    return x
+
+
+def head_logprobs(params: dict, hidden, config: GlmMoeLiteConfig):
+    """hidden [b, hidden] -> log-probabilities over the vocabulary, float32."""
+    with jax.named_scope("head_read"):
+        h = _rms(hidden, params["final_norm"], config.rms_norm_eps)
+        logits = jnp.einsum(
+            "bh,hv->bv", h, params["lm_head"], preferred_element_type=jnp.float32
+        )
+        return logits - jax.nn.logsumexp(logits, axis=-1, keepdims=True)
+
+
+def _masked(logprobs, letter_ids, valid):
+    """Vocabulary log-probabilities [b, V] at the letters' token ids [K];
+    letters that are no sibling read -inf."""
+    return jnp.where(valid, logprobs[:, letter_ids], -jnp.inf)
+
+
+@partial(jax.jit, static_argnames=("config", "depth"))
+def judge_panel(
+    params, ids, lens, letter_ids, first_valid, second_valid, *,
+    config: GlmMoeLiteConfig, depth: int,
+):
+    """A panel's calls in one program.  ids [b, s] right-padded prompts of
+    ``lens`` tokens, each ending where the key begins.  ``letter_ids`` [K]
+    are the key letters' token ids; ``first_valid`` [b, K] marks the letters
+    of a ballot's first level, ``second_valid`` [b, K, K] the sibling letters
+    under each first letter.
+
+    Per call: causal prefill (which leaves the latent cache), the head at
+    the last real position, the first level's masked log-probabilities; at
+    depth 2 the likeliest letter is decoded (greedy), one step runs through
+    the cache on the absorbed path, and the head is read again under the
+    chosen branch's mask.  ``votes`` [b, K] is ``softmax_votes`` over the
+    last read, a distribution over the K letters; which candidate a letter
+    selects is the host's to say, so one program serves every candidate
+    count.
+    """
+    b = ids.shape[0]
+    hidden, caches, loads = prefill(params, ids, config)
+    last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
+    first = _masked(head_logprobs(params, last, config), letter_ids, first_valid)
+    chosen = jnp.argmax(first, axis=1).astype(jnp.int32)
+    out = {
+        "first_logprobs": first,
+        "chosen": chosen,
+        "expert_load": jnp.stack(loads) if loads else jnp.zeros((0, 1), jnp.int32),
+    }
+    read, valid = first, first_valid
+    if depth == 2:
+        with jax.named_scope("decode_step"):
+            step = decode_step(params, letter_ids[chosen], lens, caches, config)
+            valid = second_valid[jnp.arange(b), chosen]
+            read = _masked(head_logprobs(params, step, config), letter_ids, valid)
+        out["second_logprobs"] = read
+    with jax.named_scope("ballot_vote"):
+        letters = jnp.broadcast_to(jnp.arange(valid.shape[1]), valid.shape)
+        out["votes"] = softmax_votes(
+            jnp.where(valid, read, 0.0), jnp.where(valid, letters, -1), valid,
+            valid.shape[1],
+        )
+    return out
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32) -> dict:
+    """Random parameters in the served layout (tests, shape work)."""
+    std = 0.02
+    drawn = iter(range(1 << 30))
+
+    def draw(shape):  # a key of its own per tensor
+        return jax.random.normal(jax.random.fold_in(rng, next(drawn)), shape, jnp.float32)
+
+    def normal(*shape, dt=dtype):
+        return (draw(shape) * std).astype(dt)
+
+    def dense(i, o):
+        return {"kernel": normal(i, o)}
+
+    def scale(n):
+        return (1.0 + draw((n,)) * std).astype(dtype)
+
+    def swiglu(width):
+        h = config.hidden_size
+        return {"gate": dense(h, width), "up": dense(h, width), "down": dense(width, h)}
+
+    h, heads, rank = config.hidden_size, config.num_heads, config.kv_lora_rank
+    layers = []
+    for i in range(config.num_layers):
+        w_k = normal(rank, heads, config.qk_head_dim)
+        layer = {
+            "input_norm": scale(h),
+            "post_norm": scale(h),
+            "attn": {
+                "q_a": dense(h, config.q_lora_rank),
+                "q_a_norm": scale(config.q_lora_rank),
+                "q_b": dense(config.q_lora_rank, heads * config.qk_head_dim),
+                "kv_a": dense(h, rank + config.qk_rope_head_dim),
+                "kv_a_norm": scale(rank),
+                "w_k": w_k.at[:, :, config.qk_nope_head_dim:].set(0),
+                "w_v": normal(rank, heads * config.v_head_dim),
+                "o": dense(heads * config.v_head_dim, h),
+            },
+        }
+        if i < config.first_k_dense_replace:
+            layer["mlp"] = swiglu(config.intermediate_size)
+        else:
+            e, inter = config.n_routed_experts, config.moe_intermediate_size
+            layer["moe"] = {
+                "router": normal(h, e, dt=jnp.float32),
+                "bias": normal(e, dt=jnp.float32),
+                "w_gate": normal(e, h, inter),
+                "w_up": normal(e, h, inter),
+                "w_down": normal(e, inter, h),
+                "shared": swiglu(inter * config.n_shared_experts),
+            }
+        layers.append(layer)
+    return {
+        "token_embed": normal(config.vocab_size, h),
+        "final_norm": scale(h),
+        "lm_head": normal(h, config.vocab_size),
+        "layers": layers,
+    }
+
+
+def _deinterleave(rows: int):
+    """Row order that moves interleaved rotary pairs (2i, 2i+1) to
+    (i, i + rows / 2)."""
+    return list(range(0, rows, 2)) + list(range(1, rows, 2))
+
+
+def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
+    """HF-named tensors (a mapping that may open each tensor lazily:
+    ``loading.open_checkpoint``) -> (params, config).  A layer goes to the
+    device before the next is read, so the host never holds the checkpoint
+    whole.  The depth is the checkpoint's: the layers it names, from 0 up.
+    """
+    import numpy as np
+
+    prefix = "model." if "model.embed_tokens.weight" in state else ""
+    depth = 0
+    while f"{prefix}layers.{depth}.input_layernorm.weight" in state:
+        depth += 1
+    if depth == 0:
+        raise ValueError("the checkpoint names no layer (layers.0.input_layernorm.weight)")
+    if depth != config.num_layers:
+        import dataclasses
+
+        config = dataclasses.replace(config, num_layers=depth)
+
+    def get(name):
+        return np.asarray(state[prefix + name])
+
+    def put(array, dt=dtype):
+        return jnp.asarray(array).astype(dt)
+
+    swap = jax.jit(lambda w: jnp.swapaxes(w, -1, -2))
+
+    def dense(name):  # HF [out, in] -> [in, out], transposed on the device
+        return {"kernel": swap(put(get(name + ".weight")))}
+
+    def swiglu(base):
+        return {k: dense(f"{base}.{k}_proj") for k in ("gate", "up", "down")}
+
+    heads, rank = config.num_heads, config.kv_lora_rank
+    nope, rope, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    order = np.asarray(_deinterleave(rope))
+    layers = []
+    for i in range(depth):
+        base = f"layers.{i}"
+        att = f"{base}.self_attn"
+        q_b = get(f"{att}.q_b_proj.weight").reshape(heads, nope + rope, -1)
+        q_b = np.concatenate([q_b[:, :nope], q_b[:, nope:][:, order]], axis=1)
+        kv_a = get(f"{att}.kv_a_proj_with_mqa.weight")
+        kv_a = np.concatenate([kv_a[:rank], kv_a[rank:][order]], axis=0)
+        kv_b = get(f"{att}.kv_b_proj.weight").reshape(heads, nope + dv, rank)
+        w_k = np.zeros((heads, nope + rope, rank), kv_b.dtype)
+        w_k[:, :nope] = kv_b[:, :nope]
+        layer = {
+            "input_norm": put(get(f"{base}.input_layernorm.weight")),
+            "post_norm": put(get(f"{base}.post_attention_layernorm.weight")),
+            "attn": {
+                "q_a": dense(f"{att}.q_a_proj"),
+                "q_a_norm": put(get(f"{att}.q_a_layernorm.weight")),
+                "q_b": {"kernel": swap(put(q_b.reshape(heads * (nope + rope), -1)))},
+                "kv_a": {"kernel": swap(put(kv_a))},
+                "kv_a_norm": put(get(f"{att}.kv_a_layernorm.weight")),
+                "w_k": jnp.transpose(put(w_k), (2, 0, 1)),
+                "w_v": swap(put(kv_b[:, nope:].reshape(heads * dv, rank))),
+                "o": dense(f"{att}.o_proj"),
+            },
+        }
+        if i < config.first_k_dense_replace:
+            layer["mlp"] = swiglu(f"{base}.mlp")
+        else:
+            def experts(kind):
+                stacked = np.stack(
+                    [
+                        get(f"{base}.mlp.experts.{e}.{kind}_proj.weight")
+                        for e in range(config.n_routed_experts)
+                    ]
+                )
+                return swap(put(stacked))
+
+            layer["moe"] = {
+                "router": swap(put(get(f"{base}.mlp.gate.weight"), jnp.float32)),
+                "bias": put(
+                    get(f"{base}.mlp.gate.e_score_correction_bias"), jnp.float32
+                ),
+                "w_gate": experts("gate"),
+                "w_up": experts("up"),
+                "w_down": experts("down"),
+                "shared": swiglu(f"{base}.mlp.shared_experts"),
+            }
+        layers.append(layer)
+    params = {
+        "token_embed": put(get("embed_tokens.weight")),
+        "final_norm": put(get("norm.weight")),
+        "lm_head": swap(put(np.asarray(state["lm_head.weight"]))),
+        "layers": layers,
+    }
+    return params, config
+
+
+def quantize_dense(params: dict) -> dict:
+    """``JUDGE_QUANTIZE=int8``: every ``{"kernel"}`` product (attention
+    projections, the dense layer's MLP, the shared expert) becomes
+    ``quant.dense_int8``'s ``{"kernel_q", "scale", "bias"}``.  Router, routed
+    experts, ``w_k``/``w_v`` (shared with the absorbed path), embedding and
+    head stay as they are."""
+    from .quant import quantize_weight
+
+    def walk(node):
+        if isinstance(node, dict):
+            if set(node) == {"kernel"}:
+                q, scale = quantize_weight(node["kernel"])
+                bias = jnp.zeros((q.shape[-1],), node["kernel"].dtype)
+                return {"kernel_q": q, "scale": scale, "bias": bias}
+            return {k: walk(v) for k, v in node.items()}
+        if isinstance(node, list):
+            return [walk(v) for v in node]
+        return node
+
+    return walk(params)

@@ -48,6 +48,62 @@ def _load_state_dict(path: str) -> dict:
     return {k: v.numpy() for k, v in state.items()}
 
 
+_HF_INDEX = "model.safetensors.index.json"
+
+
+class _LazyCheckpoint:
+    """name -> array over a safetensors checkpoint, one file or HF's
+    sharded layout (``model-0000i-of-0000n.safetensors`` named by
+    ``model.safetensors.index.json``): a tensor is read when it is asked
+    for and nothing is kept, so a loader can go layer by layer over a
+    checkpoint larger than it wants on the host."""
+
+    def __init__(self, directory: str, files: dict) -> None:
+        self._directory = directory
+        self._files = files
+
+    def __contains__(self, name) -> bool:
+        return name in self._files
+
+    def __iter__(self):
+        return iter(self._files)
+
+    def __len__(self) -> int:
+        return len(self._files)
+
+    def keys(self):
+        return self._files.keys()
+
+    def __getitem__(self, name: str) -> np.ndarray:
+        from safetensors import safe_open
+
+        path = os.path.join(self._directory, self._files[name])
+        with safe_open(path, framework="np") as f:
+            return f.get_tensor(name)
+
+
+def open_checkpoint(path: str) -> _LazyCheckpoint:
+    """A lazy name -> array mapping over ``path``: an HF snapshot directory
+    (sharded or one ``model.safetensors``), the shards' index file, or one
+    ``.safetensors`` file."""
+    import json
+
+    from safetensors import safe_open
+
+    if os.path.isdir(path):
+        index = os.path.join(path, _HF_INDEX)
+        path = index if os.path.exists(index) else os.path.join(path, _HF_FILES[0])
+    if not os.path.exists(path):
+        raise FileNotFoundError(path)
+    directory = os.path.dirname(os.path.abspath(path))
+    if path.endswith(".json"):
+        with open(path, encoding="utf-8") as f:
+            return _LazyCheckpoint(directory, json.load(f)["weight_map"])
+    with safe_open(path, framework="np") as f:
+        single = os.path.basename(path)
+        return _LazyCheckpoint(directory, {name: single for name in f.keys()})
+
+
 def _is_orbax_dir(path: str) -> bool:
     if not os.path.isdir(path):
         return False

@@ -492,6 +492,82 @@ def build_reranker(config: Config, allow_synthetic: bool = False):
     return reranker
 
 
+def build_judge(config: Config, allow_synthetic: bool = False):
+    """The local judge panel (POST /consensus {"scorer": "judge"}): a
+    causal sparse-expert decoder from env config, its program compiled for
+    the default panel before the server listens.  Same synthetic-params
+    discipline as ``build_embedder``."""
+    if not config.judge_model:
+        return None
+    import logging
+    import time as _time
+
+    from ..models.judge import JUDGE_PRESETS, TpuJudge, load_judge_params
+    from ..models.tokenizer import HashTokenizer, load_tokenizer
+
+    if config.judge_model not in JUDGE_PRESETS:
+        raise ValueError(
+            f"JUDGE_MODEL={config.judge_model!r} is not a known preset; "
+            f"valid values: {', '.join(sorted(JUDGE_PRESETS))}"
+        )
+    log = logging.getLogger("lwc.serve")
+    preset = JUDGE_PRESETS[config.judge_model]
+    params = None
+    vocab_path = config.judge_vocab
+    if config.judge_weights:
+        from ..models.loading import find_vocab
+
+        t0 = _time.perf_counter()
+        params, preset = load_judge_params(config.judge_weights, preset)
+        log.info(
+            "judge: %d layers of %s loaded in %.1fs",
+            preset.num_layers, config.judge_weights, _time.perf_counter() - t0,
+        )
+        if not vocab_path:
+            vocab_path = find_vocab(config.judge_weights)
+    judge = TpuJudge(
+        config.judge_model,
+        params=params,
+        config=preset,
+        tokenizer=(
+            load_tokenizer(vocab_path, scheme="deberta") if vocab_path else None
+        ),
+        max_tokens=config.judge_max_tokens,
+        quantize=config.judge_quantize,
+    )
+    synthetic = []
+    if params is None:
+        synthetic.append("random-init judge weights (no JUDGE_WEIGHTS)")
+    if isinstance(judge.tokenizer, HashTokenizer):
+        synthetic.append(
+            "hash tokenizer (no JUDGE_VOCAB and no vocab/spm file beside "
+            "JUDGE_WEIGHTS)"
+        )
+    if synthetic:
+        detail = (
+            f"JUDGE_MODEL={config.judge_model} would serve "
+            + " and ".join(synthetic)
+            + " — its votes would be garbage that looks valid."
+        )
+        if not _synthetic_params_allowed(allow_synthetic):
+            raise ValueError(
+                detail
+                + " Point JUDGE_WEIGHTS at a checkpoint, or opt in with "
+                "LWC_ALLOW_RANDOM_PARAMS=1 (tests/demo only)."
+            )
+        log.warning(
+            "SYNTHETIC JUDGE PARAMS: %s Serving anyway "
+            "(LWC_ALLOW_RANDOM_PARAMS / fake-upstream demo mode).",
+            detail,
+        )
+    judge.device_timing = config.metrics_device_timing
+    log.info(
+        "judge: panel program (calls=3, s=%d) compiled in %.1fs",
+        judge.max_tokens, judge.warmup(),
+    )
+    return judge
+
+
 class _ArchivingClient:
     """Wraps a client so every served UNARY completion is archived (its id
     becomes referenceable by later requests); everything else delegates.
@@ -717,7 +793,7 @@ def _build_cpu_fallback(config: Config, fake_upstream: bool):
     return fallback, fallback_context
 
 
-def _device_stats(embedder, reranker) -> dict:
+def _device_stats(embedder, reranker, judge=None) -> dict:
     """The ``device`` section of /metrics (and the start-up log line):
     what this process computes on.  The models pick their param dtype,
     their quantized-matmul implementation and Pallas interpret mode from
@@ -750,7 +826,7 @@ def _device_stats(embedder, reranker) -> dict:
         "pallas_interpret": devices[0].platform != "tpu",
         "devices": per_device,
     }
-    for prefix, model in (("", embedder), ("rm_", reranker)):
+    for prefix, model in (("", embedder), ("rm_", reranker), ("judge_", judge)):
         if model is not None:
             out[prefix + "param_dtype"] = model.params["token_embed"].dtype.name
             out[prefix + "quantize"] = model.config.quantize
@@ -824,13 +900,14 @@ def build_service(
     # allowed (still logged); production startup refuses them
     embedder = build_embedder(config, allow_synthetic=fake_upstream)
     reranker = build_reranker(config, allow_synthetic=fake_upstream)
-    if embedder is not None or reranker is not None:
+    judge = build_judge(config, allow_synthetic=fake_upstream)
+    if embedder is not None or reranker is not None or judge is not None:
         import logging
 
         # before warmup: if a compile hangs or dies, what it ran on is
         # already in the log
         logging.getLogger("lwc.serve").info(
-            "device: %s", _device_stats(embedder, reranker)
+            "device: %s", _device_stats(embedder, reranker, judge)
         )
     if embedder is not None:
         # per-bucket device timing (phases/roofline sections), measured
@@ -958,9 +1035,19 @@ def build_service(
     metrics = Metrics()
     if compile_cache is not None:
         metrics.register_provider("compile_cache", compile_cache.snapshot)
-    if embedder is not None or reranker is not None:
+    if embedder is not None or reranker is not None or judge is not None:
         metrics.register_provider(
-            "device", lambda: _device_stats(embedder, reranker)
+            "device", lambda: _device_stats(embedder, reranker, judge)
+        )
+    if embedder is None and judge is not None:
+        # a judge alone: its one entry point's specializations, and every
+        # compilation the backend was asked for, under the same section
+        _compiles = (
+            compile_cache.compiles if compile_cache is not None else dict
+        )
+        metrics.register_provider(
+            "jit",
+            lambda: {"specializations": judge.jit_stats(), **_compiles()},
         )
     if embedder is not None:
         # jit-cache introspection on /metrics: AOT bucket count + live
@@ -968,13 +1055,15 @@ def build_service(
         # post-warmup" is observable in production, not just in tests)
         # and every compilation the backend was asked for, the helper
         # programs no entry point names included (config.py)
-        if compile_cache is not None:
-            metrics.register_provider(
-                "jit",
-                lambda: {**embedder.jit_stats(), **compile_cache.compiles()},
-            )
-        else:
-            metrics.register_provider("jit", embedder.jit_stats)
+        def _jit_stats():
+            stats = embedder.jit_stats()
+            if judge is not None:
+                stats["specializations"].update(judge.jit_stats())
+            if compile_cache is not None:
+                stats.update(compile_cache.compiles())
+            return stats
+
+        metrics.register_provider("jit", _jit_stats)
     if embedder is not None and getattr(embedder, "mesh_mode", False):
         # mesh-serving introspection: the shape traffic shards over and
         # the per-(mesh-shape, bucket) AOT coverage
@@ -1048,12 +1137,13 @@ def build_service(
             config, fake_upstream
         )
     batcher = None
-    if embedder is not None:
+    if embedder is not None or judge is not None:
         from .batcher import DeviceBatcher
 
         batcher = DeviceBatcher(
             embedder,
             metrics,
+            judge=judge,
             window_ms=config.batch_window_ms,
             max_batch=config.batch_max,
             pipeline_depth=config.batch_pipeline,
@@ -1333,6 +1423,7 @@ def build_service(
         profile_dir=config.profile_dir,
         batcher=batcher,
         reranker=reranker,
+        judge=judge,
         resilience=resilience,
         fault_plan=fault_plan,
         admission=admission,

@@ -11,7 +11,8 @@ which collects everything that arrives within a small window (or while a
 previous dispatch holds the device) and dispatches each compatible group as
 ONE batched device call.
 
-Three work kinds are batched:
+Three work kinds are batched, and a fourth rides the same queue and
+pipeline alone:
 
 * ``embed``       — texts -> (embeddings, token count); R requests' texts are
                     tokenized together and run as one ``embed_tokens`` batch;
@@ -20,7 +21,12 @@ Three work kinds are batched:
 * ``stream``      — one streaming-consensus update (embed one candidate into a
                     device-resident buffer + masked revote); R concurrent
                     streams' updates run as one vmapped dispatch
-                    (``stream_vote_update_many``).
+                    (``stream_vote_update_many``);
+* ``judge``       — N candidate texts + a conversation -> a local judge
+                    panel's tally (models/judge.py): the panel's calls are
+                    ONE device program of static shape, so requests are
+                    never grouped; what it shares is the queue, the two
+                    pipeline slots and the three hops.
 
 Dispatches are PIPELINED to ``pipeline_depth`` in flight (default 2), and
 the pipeline is asynchronous end to end (ISSUE 13):
@@ -168,8 +174,11 @@ class DeviceBatcher:
         prefix_dedup_min_chars: int = 48,
         host_tokenizer_workers: int = 2,
         staging_buffers: int = 2,
+        judge=None,
     ) -> None:
-        self.embedder = embedder
+        self.embedder = embedder  # None where the server has only a judge
+        # the local judge panel (models/judge.py TpuJudge), or None
+        self.judge_model = judge
         self.metrics = metrics
         # continuous batching (PACKING_ENABLED): embed + consensus items
         # share ONE dispatch key and ride the ragged segment-id layout
@@ -492,6 +501,26 @@ class DeviceBatcher:
             priority=priority,
         )
 
+    async def judge(
+        self,
+        texts: list,
+        prompt: Optional[str] = None,
+        panel=None,
+        priority: str = "latency",
+    ):
+        """N candidate texts (+ the conversation they answer) ->
+        (confidence[N], token_count, ballots): a local judge panel, the
+        calls of ``panel`` (``[(ballot seed, weight)]``) in one device
+        program.  Every item has a key of its own: a panel's program is one
+        static shape, so requests share the queue and the pipeline, never a
+        dispatch."""
+        return await self._submit(
+            "judge",
+            ("judge", _hostspan.next_id()),
+            (list(texts), prompt, panel),
+            priority=priority,
+        )
+
     def _embed_key(self, max_tokens):
         """Grouping key for embed items: packed mode groups across
         max_tokens caps (each item tokenizes under its own cap on the
@@ -792,7 +821,7 @@ class DeviceBatcher:
             lane="offline" if offline else "latency",
         )
         if self._tok_pool is not None and kind in (
-            "embed", "consensus", "ring_embed", "ring_vote"
+            "embed", "consensus", "ring_embed", "ring_vote", "judge"
         ):
             # submit-time tokenization: the item's rows (or packed plan)
             # build on the host pool NOW, overlapping earlier groups'
@@ -1410,6 +1439,8 @@ class DeviceBatcher:
         PRIMARY embedder's tokenizer; the dispatch falls back to inline
         tokenization when it is serving the CPU twin."""
         kind, key, payload = item.kind, item.key, item.payload
+        if kind == "judge":
+            return self._prepare_judge(item)
         if key and key[0] == "packed":
             # a packed plan tokenizes segment by segment inside the
             # planner: timed whole, rows and tokens not counted here
@@ -1554,6 +1585,40 @@ class DeviceBatcher:
         def finalize() -> list:
             conf_np = np.asarray(conf)
             return [(conf_np[i], int(tokens[i])) for i in range(r)]
+
+        return finalize
+
+    # -- local judge panel --------------------------------------------------
+
+    def _prepare_judge(self, item):
+        """A judge item's host work under ``host:tokenize``: each candidate
+        tokenized once, the panel's ballots and prompts built."""
+        texts, prompt, panel = item.payload
+        with _hostspan.host_span(
+            "host:tokenize", parents=(item.span,), rid=item.rid
+        ) as span:
+            prepared = self.judge_model.prepare(texts, prompt, panel)
+            span.annotate(rows=len(prepared.calls), tokens=prepared.tokens)
+        return prepared
+
+    def _dispatch_judge(self, group: list, embedder):
+        """One item, one program: causal prefill of every call, one decoded
+        key letter through the latent cache, the masked reads and the vote
+        (models/glm_moe.py ``judge_panel``)."""
+        (item,) = group
+        judge = self.judge_model
+        prepared = (
+            item.prepared.result()  # re-raises tokenizer and size errors
+            if item.prepared is not None
+            else self._prepare_judge(item)
+        )
+        with self._stats_lock:
+            self._pad_real_tokens += prepared.tokens
+            self._pad_slot_tokens += int(prepared.ids.size)
+        out = judge.dispatch(prepared)
+
+        def finalize() -> list:
+            return [judge.finalize(prepared, out)]
 
         return finalize
 

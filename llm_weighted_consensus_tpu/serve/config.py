@@ -83,6 +83,18 @@ TPU additions:
   (BASELINE config 3 as a service): candidates re-rank by
   softmax(reward).  Same synthetic-params gate as the embedder; real
   checkpoints load from HF DeBERTa-v2/v3 snapshots or orbax dirs.
+* ``JUDGE_MODEL`` / ``JUDGE_WEIGHTS`` / ``JUDGE_VOCAB`` /
+  ``JUDGE_MAX_TOKENS`` / ``JUDGE_QUANTIZE`` — a causal sparse-expert
+  latent-attention decoder (``glm-4.7-flash``; models/glm_moe.py) serving
+  ``POST /consensus {"scorer": "judge"}``: a LOCAL judge panel, each call
+  a prefill of the candidates under a seeded prefix-tree ballot, one
+  decoded key letter and a masked read of its siblings' log-probabilities
+  (models/judge.py).  ``JUDGE_WEIGHTS`` is an HF checkpoint, one
+  ``model.safetensors`` or sharded; the depth served is the checkpoint's.
+  ``JUDGE_MAX_TOKENS`` (default 8192) is the ONE sequence bucket every
+  call is padded to.  ``JUDGE_QUANTIZE=int8`` runs the dense products
+  W8A8 (``quant.dense_int8``).  A server with a judge and no embedder
+  starts and serves.  Same synthetic-params gate as the embedder.
 * ``ARCHIVE_PATH`` — JSON snapshot for the completions archive
   (checkpoint/resume): loaded at startup when the file exists, saved on
   graceful shutdown.  Unset = in-memory only.
@@ -768,6 +780,12 @@ class Config:
     rm_vocab: Optional[str] = None  # spm.model / vocab.txt
     rm_max_tokens: int = 512
     rm_quantize: str = "none"  # "int8" = W8A8 RM serving (models/quant.py)
+    # -- local judge panel (POST /consensus {"scorer": "judge"}) --
+    judge_model: Optional[str] = None  # e.g. "glm-4.7-flash"
+    judge_weights: Optional[str] = None  # HF checkpoint, one file or sharded
+    judge_vocab: Optional[str] = None  # spm.model / vocab.txt
+    judge_max_tokens: int = 8192  # the one sequence bucket of a call
+    judge_quantize: str = "none"  # "int8" = W8A8 dense products
     mesh_dp: Optional[int] = None
     mesh_tp: int = 1
     mesh_sp: Optional[int] = None
@@ -1007,6 +1025,11 @@ class Config:
             rm_vocab=env.get("RM_VOCAB"),
             rm_max_tokens=int(env.get("RM_MAX_TOKENS", 512)),
             rm_quantize=env.get("RM_QUANTIZE") or "none",
+            judge_model=env.get("JUDGE_MODEL") or None,
+            judge_weights=env.get("JUDGE_WEIGHTS") or None,
+            judge_vocab=env.get("JUDGE_VOCAB") or None,
+            judge_max_tokens=int(env.get("JUDGE_MAX_TOKENS", 8192)),
+            judge_quantize=env.get("JUDGE_QUANTIZE") or "none",
             mesh_dp=int(env["MESH_DP"]) if env.get("MESH_DP") else None,
             mesh_tp=int(env.get("MESH_TP", 1)),
             mesh_sp=int(env["MESH_SP"]) if env.get("MESH_SP") else None,

@@ -808,6 +808,7 @@ def build_app(
     prefix_dedup: bool = True,
     prefix_dedup_min_chars: int = 48,
     reranker=None,
+    judge=None,
     embed_cache=None,
     resilience=None,
     fault_plan=None,
@@ -828,14 +829,21 @@ def build_app(
     metrics = metrics or Metrics()
     register_resilience(metrics, resilience, fault_plan)
     register_overload(metrics, admission, watchdog, lifecycle, memguard)
-    register_performance(metrics, _roofline_gauge(embedder))
+    register_performance(
+        metrics, _roofline_gauge(embedder if embedder is not None else judge)
+    )
     register_quality(metrics, ledger, live_weights)
-    if embedder is not None and batcher is None:
+    if judge is not None:
+        # the judge's counters: calls, prefill and padded tokens, tokens
+        # routed to each expert and a dispatch's largest load over its mean
+        metrics.register_provider("judge", judge.stats)
+    if (embedder is not None or judge is not None) and batcher is None:
         from .batcher import DeviceBatcher
 
         batcher = DeviceBatcher(
             embedder,
             metrics,
+            judge=judge,
             window_ms=batch_window_ms,
             max_batch=batch_max,
             packing=packing,
@@ -960,10 +968,10 @@ def build_app(
         app.router.add_post(
             "/embeddings", _embeddings_handler(embedder, metrics, batcher)
         )
-    if embedder is not None or reranker is not None:
+    if embedder is not None or reranker is not None or judge is not None:
         app.router.add_post(
             "/consensus",
-            _consensus_handler(embedder, metrics, batcher, reranker),
+            _consensus_handler(embedder, metrics, batcher, reranker, judge),
         )
 
     async def healthz(request):
@@ -1031,30 +1039,72 @@ def build_app(
 MAX_CONSENSUS_CANDIDATES = 256
 
 
-def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
+def _consensus_handler(
+    embedder, metrics=None, batcher=None, reranker=None, judge=None
+):
     """POST /consensus: the device scorer as a direct service — N
     candidate texts in, a confidence distribution out.
 
-    Two scorers: ``"cosine"`` (default) is the embedding self-consistency
+    Three scorers: ``"cosine"`` (default) is the embedding self-consistency
     vote (one fused embed+vote dispatch; concurrent requests coalesce via
     the micro-batcher — the HTTP analog of the headline bench path);
     ``"rm"`` re-ranks by reward model: softmax(reward/T) over the
     candidates, each scored against the optional ``prompt`` (BASELINE
-    config 3 as a service).  No reference analog (its scoring always
-    goes through judge LLMs; SURVEY §2.6).
+    config 3 as a service); ``"judge"`` is a LOCAL judge panel
+    (models/judge.py): ``panel`` calls (default three, weights 1) of one
+    causal decoder read the candidates under differently seeded
+    prefix-tree ballots, each call's vote is the softmax over the ballot's
+    sibling key letters at the decoder's own head, and ``confidence`` is
+    the calls' weighted tally (Σ vote x weight / Σ weight) — the
+    reference's judge protocol without the HTTP hop.  Its answer also
+    carries ``ballots``, one entry a call: what an upstream judge's
+    ``top_logprobs`` would have carried.  The other two have no reference
+    analog (its scoring always goes through judge LLMs; SURVEY §2.6).
 
-    Body: {"input": [texts...], "scorer"?: "cosine"|"rm",
-    "prompt"?: str, "temperature"?: float}.  Response: {"model",
-    "scorer", "confidence": [...], "usage": {prompt_tokens,
-    total_tokens}}.
+    Body: {"input": [texts...], "scorer"?: "cosine"|"rm"|"judge",
+    "prompt"?: str, "temperature"?: float (cosine, rm),
+    "panel"?: [{"seed": int, "weight": number}] (judge)}.  Response:
+    {"model", "scorer", "confidence": [...], "usage": {prompt_tokens,
+    total_tokens}} and, for ``judge``, "ballots": [{"seed", "weight",
+    "first"?: {letter: logprob}, "key", "siblings": {letter: {"logprob",
+    "candidate"}}}].
     """
     import asyncio
     import math
     from decimal import Decimal as _Decimal
 
+    def parse_panel(raw):
+        """``panel`` to [(seed, weight)], or None for the default."""
+        from ..models.judge import MAX_PANEL
+
+        if raw is None:
+            return None
+        if not isinstance(raw, list) or not 1 <= len(raw) <= MAX_PANEL:
+            raise ValueError(
+                f"`panel` must be a list of 1 to {MAX_PANEL} calls"
+            )
+        panel = []
+        for call in raw:
+            seed = call.get("seed") if isinstance(call, dict) else None
+            weight = call.get("weight", 1) if isinstance(call, dict) else None
+            if isinstance(seed, bool) or not isinstance(seed, int):
+                raise ValueError("a panel call's `seed` must be an integer")
+            if isinstance(weight, bool) or not isinstance(
+                weight, (int, float, _Decimal)
+            ):
+                raise ValueError("a panel call's `weight` must be a number")
+            weight = float(weight)
+            if not math.isfinite(weight) or weight <= 0:
+                raise ValueError(
+                    "a panel call's `weight` must be finite and positive"
+                )
+            panel.append((seed, weight))
+        return panel
+
     def parse(raw: str):
-        """The request body to (texts, scorer, prompt, temperature), or
-        the ValueError the 400 policy echoes."""
+        """The request body to (texts, scorer, prompt, temperature,
+        panel) — the judge has a panel and no temperature, the others the
+        reverse — or the ValueError the 400 policy echoes."""
         body = jsonutil.loads(raw)
         if not isinstance(body, dict):
             raise ValueError("body must be a JSON object")
@@ -1073,8 +1123,8 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
                 "candidates per request"
             )
         scorer = body.get("scorer", "cosine")
-        if scorer not in ("cosine", "rm"):
-            raise ValueError("`scorer` must be 'cosine' or 'rm'")
+        if scorer not in ("cosine", "rm", "judge"):
+            raise ValueError("`scorer` must be 'cosine', 'rm' or 'judge'")
         if scorer == "cosine" and embedder is None:
             raise ValueError(
                 "cosine scorer unavailable: no EMBEDDER_MODEL configured"
@@ -1083,9 +1133,15 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
             raise ValueError(
                 "rm scorer unavailable: no RM_MODEL configured"
             )
+        if scorer == "judge" and judge is None:
+            raise ValueError(
+                "judge scorer unavailable: no JUDGE_MODEL configured"
+            )
         prompt = body.get("prompt")
         if prompt is not None and not isinstance(prompt, str):
             raise ValueError("`prompt` must be a string")
+        if scorer == "judge":
+            return texts, scorer, prompt, None, parse_panel(body.get("panel"))
         traw = body.get("temperature", 0.05 if scorer == "cosine" else 1.0)
         # explicit type check, not bare float(): a non-numeric value
         # must raise the ValueError the 400 policy echoes, never a
@@ -1100,7 +1156,7 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
             raise ValueError(
                 "`temperature` must be a finite positive number"
             )
-        return texts, scorer, prompt, temperature
+        return texts, scorer, prompt, temperature, None
 
     async def handler(request: web.Request):
         rid = obs.arrive(request.path, request.content_length or 0)
@@ -1109,15 +1165,21 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
             with obs.host_span(
                 "http:parse", rid=rid, bytes=len(raw)
             ) as parsing:
-                texts, scorer, prompt, temperature = parse(raw)
+                texts, scorer, prompt, temperature, panel = parse(raw)
                 parsing.annotate(n=len(texts))
         except web.HTTPException:
             raise  # e.g. 413 body-too-large must keep its status
         except Exception as e:  # parse phase is side-effect free
             return _respond(rid, lambda: _parse_error_response(e))
         loop = asyncio.get_running_loop()
+        ballots = None
         try:
-            if scorer == "rm":
+            if scorer == "judge":
+                conf, tokens, ballots = await batcher.judge(
+                    texts, prompt, panel
+                )
+                model_name = judge.model_name
+            elif scorer == "rm":
                 t0 = _time.perf_counter()
                 conf, tokens = await loop.run_in_executor(
                     None,
@@ -1169,6 +1231,7 @@ def _consensus_handler(embedder, metrics=None, batcher=None, reranker=None):
                             "prompt_tokens": tokens,
                             "total_tokens": tokens,
                         },
+                        **({} if ballots is None else {"ballots": ballots}),
                     }
                 ),
                 content_type="application/json",

@@ -42,6 +42,7 @@ KNOWN_SECTIONS = (
     "score_cache",
     "traces",
     "device",
+    "judge",
     "compile_cache",
     "jit",
     "mesh",

@@ -1,0 +1,448 @@
+"""The local judge panel (ISSUE 27): a causal sparse-expert latent-attention
+decoder behind ``POST /consensus`` ``scorer: judge``.
+
+Tiny sizes (hidden 64, 1 dense + 2 sparse layers, 8 experts, 2 a token, 4
+heads of 24 + 8 / 32, vocabulary 512, 96 tokens), float32, the kernels in
+interpret mode.  The plain reference is ``glm4_moe_lite_reference.py`` beside
+this file: numpy float64 from the equations, HF names, nothing of the program.
+"""
+
+import dataclasses
+import json
+import random
+import types
+from decimal import Decimal
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+import glm4_moe_lite_reference as reference  # noqa: E402
+from llm_weighted_consensus_tpu.ballot import PrefixTree, extract_vote  # noqa: E402
+from llm_weighted_consensus_tpu.ballot.tree import ALPHABET  # noqa: E402
+from llm_weighted_consensus_tpu.models import glm_moe  # noqa: E402
+from llm_weighted_consensus_tpu.models.configs import GLM_TEST_TINY  # noqa: E402
+from llm_weighted_consensus_tpu.models.judge import TpuJudge  # noqa: E402
+from llm_weighted_consensus_tpu.models.spm import UnigramTokenizer  # noqa: E402
+from llm_weighted_consensus_tpu.ops import causal_attention as attn  # noqa: E402
+from llm_weighted_consensus_tpu.ops import grouped_matmul as gmm  # noqa: E402
+
+C = GLM_TEST_TINY
+SEQ = 96
+
+
+def hf_config(config=C) -> dict:
+    return {
+        "vocab_size": config.vocab_size,
+        "hidden_size": config.hidden_size,
+        "num_hidden_layers": config.num_layers,
+        "num_attention_heads": config.num_heads,
+        "q_lora_rank": config.q_lora_rank,
+        "kv_lora_rank": config.kv_lora_rank,
+        "qk_nope_head_dim": config.qk_nope_head_dim,
+        "qk_rope_head_dim": config.qk_rope_head_dim,
+        "v_head_dim": config.v_head_dim,
+        "intermediate_size": config.intermediate_size,
+        "moe_intermediate_size": config.moe_intermediate_size,
+        "n_routed_experts": config.n_routed_experts,
+        "num_experts_per_tok": config.num_experts_per_tok,
+        "n_shared_experts": config.n_shared_experts,
+        "routed_scaling_factor": config.routed_scaling_factor,
+        "first_k_dense_replace": config.first_k_dense_replace,
+        "rope_theta": config.rope_theta,
+        "rms_norm_eps": config.rms_norm_eps,
+    }
+
+
+@pytest.fixture(scope="module")
+def state():
+    return reference.random_state(hf_config(), seed=3)
+
+
+@pytest.fixture(scope="module")
+def loaded(state):
+    return glm_moe.from_hf_weights(state, C)
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    rng = np.random.default_rng(1)
+    lens = np.array([90, 77, SEQ], np.int32)
+    ids = np.zeros((3, SEQ), np.int32)
+    for row, n in enumerate(lens):
+        ids[row, :n] = rng.integers(4, C.vocab_size, size=n)
+    return ids, lens
+
+
+def log_softmax(x):
+    return x - np.log(np.exp(x - x.max(-1, keepdims=True)).sum(-1, keepdims=True)) - x.max(
+        -1, keepdims=True
+    )
+
+
+# -- the decoder against the plain reference ---------------------------------
+
+
+def test_prefill_logits_match_the_reference(state, loaded, prompts):
+    params, config = loaded
+    ids, lens = prompts
+    hidden, caches, loads = glm_moe.prefill(params, jnp.asarray(ids), config)
+    assert len(caches) == config.num_layers and len(loads) == 2
+    for row, n in enumerate(lens):
+        want = log_softmax(reference.logits(state, hf_config(), ids[row, :n]))
+        got = np.asarray(glm_moe.head_logprobs(params, hidden[row, :n], config))
+        assert np.abs(got - want).max() < 2e-5
+    # every real and padded token went to exactly k experts
+    assert (np.asarray(loads).sum(axis=1) == 3 * SEQ * config.num_experts_per_tok).all()
+
+
+def panel_masks(first_letters=4, siblings=16):
+    first = np.zeros((3, 20), bool)
+    first[:, :first_letters] = True
+    second = np.zeros((3, 20, 20), bool)
+    second[:, :first_letters, :siblings] = True
+    return first, second
+
+
+def test_decode_through_the_cache_matches_the_full_forward(state, loaded, prompts):
+    """Prefill, one decoded letter through the latent cache on the absorbed
+    path, the second read: against the reference's one forward over T + 1."""
+    params, config = loaded
+    ids, lens = prompts
+    letters = jnp.arange(10, 30, dtype=jnp.int32)
+    first, second = panel_masks()
+    out = glm_moe.judge_panel(
+        params, jnp.asarray(ids), jnp.asarray(lens), letters,
+        jnp.asarray(first), jnp.asarray(second), config=config, depth=2,
+    )
+    for row, n in enumerate(lens):
+        token = int(letters[out["chosen"][row]])
+        full = log_softmax(
+            reference.logits(state, hf_config(), np.append(ids[row, :n], token))
+        )
+        got_first = np.asarray(out["first_logprobs"][row])
+        got_second = np.asarray(out["second_logprobs"][row])
+        assert np.abs(got_first[:4] - full[n - 1, 10:14]).max() < 2e-5
+        assert np.abs(got_second[:16] - full[n, 10:26]).max() < 2e-5
+        assert np.isneginf(got_first[4:]).all() and np.isneginf(got_second[16:]).all()
+        assert int(out["chosen"][row]) == int(np.argmax(full[n - 1, 10:14]))
+    votes = np.asarray(out["votes"])
+    assert np.allclose(votes.sum(axis=1), 1.0, atol=1e-6)
+    assert (votes[:, 16:] == 0).all()
+
+
+def test_absorbed_path_matches_the_prefill_path(loaded, prompts):
+    """The program against itself: position T through the cache (W_kvb folded
+    into the query and the output) and as the last row of a prefill over
+    T + 1 (keys and values rebuilt from the latent)."""
+    params, config = loaded
+    ids, lens = prompts
+    ids, lens = ids[:2], lens[:2]  # rows with room for one more token
+    token = jnp.asarray([17, 23], jnp.int32)
+    _, caches, _ = glm_moe.prefill(params, jnp.asarray(ids), config)
+    step = glm_moe.decode_step(params, token, jnp.asarray(lens), caches, config)
+    longer = ids.copy()
+    longer[np.arange(2), lens] = np.asarray(token)
+    hidden, _, _ = glm_moe.prefill(params, jnp.asarray(longer), config)
+    want = np.asarray(hidden)[np.arange(2), lens]
+    assert np.abs(np.asarray(step) - want).max() < 2e-5
+
+
+def test_router_matches_numpy_top_k_with_the_bias_used_for_choice_only():
+    rng = np.random.default_rng(5)
+    config = dataclasses.replace(C, n_routed_experts=64, num_experts_per_tok=4)
+    h = rng.standard_normal((50, config.hidden_size)).astype(np.float32)
+    weight = (rng.standard_normal((config.hidden_size, 64)) * 0.3).astype(np.float32)
+    bias = rng.standard_normal(64).astype(np.float32)  # large: it reorders
+    chosen, w = glm_moe.route(
+        jnp.asarray(h), {"router": jnp.asarray(weight), "bias": jnp.asarray(bias)}, config
+    )
+    score = 1 / (1 + np.exp(-(h.astype(np.float64) @ weight)))
+    want = np.argsort(-(score + bias), axis=1, kind="stable")[:, :4]
+    assert (np.sort(np.asarray(chosen), 1) == np.sort(want, 1)).all()
+    assert (want != np.argsort(-score, axis=1)[:, :4]).any()  # the bias chose
+    picked = np.take_along_axis(score, np.asarray(chosen), 1)
+    assert np.allclose(
+        np.asarray(w), picked / picked.sum(1, keepdims=True) * 1.8, atol=1e-5
+    )
+
+
+# -- the kernels against their einsum twins -----------------------------------
+
+
+@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 16), (16, 32), (96, 96)])
+def test_causal_kernel_matches_einsum(block_q, block_k):
+    rng = np.random.default_rng(block_q + block_k)
+    q, k, v = (
+        jnp.asarray(rng.standard_normal((2, SEQ, 4 * 32)), jnp.float32) for _ in range(3)
+    )
+    got = attn.causal_attention_blockwise(
+        q, k, v, heads=4, scale=0.2, block_q=block_q, block_k=block_k
+    )
+    want = attn.causal_attention_einsum(q, k, v, heads=4, scale=0.2)
+    assert float(jnp.abs(got - want).max()) < 2e-6
+
+
+@pytest.mark.parametrize("pairs,tile", [(37, 8), (200, 16), (64, 32)])
+def test_grouped_product_matches_einsum_at_ragged_sizes(pairs, tile):
+    rng = np.random.default_rng(pairs)
+    experts, k, n = 8, 64, 48
+    expert = rng.integers(0, experts, size=pairs).astype(np.int32)
+    expert[expert == 3] = 4  # an expert with no token
+    x = jnp.asarray(rng.standard_normal((pairs, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((experts, k, n)), jnp.float32)
+    pair_of_row, row_of_pair, tile_expert, used, counts = gmm.route_layout(
+        jnp.asarray(expert), experts, tile
+    )
+    assert int(counts[3]) == 0 and int(counts.sum()) == pairs
+    assert int(used[0]) == sum(-(-int(c) // tile) for c in counts)
+    out = gmm.grouped_expert_product(
+        x[pair_of_row], w, tile_expert, used, tile=tile, tile_n=16
+    )
+    want = jnp.einsum("mk,mkn->mn", x, w[expert])
+    assert float(jnp.abs(out[row_of_pair] - want).max()) < 1e-4
+    order = np.argsort(expert, kind="stable")
+    ragged = gmm.grouped_product_ragged(x[order], w, counts)
+    assert float(jnp.abs(ragged - want[order]).max()) < 1e-4
+
+
+# -- the judge: ballots, votes, the tally --------------------------------------
+
+
+def tiny_tokenizer(vocab_size=C.vocab_size):
+    """Whole-word pieces, the key letters and the backtick among them."""
+    pieces = [("[PAD]", 0.0, 3), ("[CLS]", 0.0, 3), ("[SEP]", 0.0, 3), ("[UNK]", 0.0, 2)]
+    marks = list(ALPHABET) + ["▁`", "``", "`:", "`", ":", "▁Select", "▁the", "▁response:"]
+    pieces += [(m, -10.0, 1) for m in marks]
+    pieces += [(f"▁w{k}", -10.0, 1) for k in range(vocab_size - len(pieces))]
+    return UnigramTokenizer(pieces, scheme="deberta")
+
+
+@pytest.fixture(scope="module")
+def judge():
+    return TpuJudge("glm-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=448, seed=2)
+
+
+def candidates(n, rng):
+    """n one-word candidates."""
+    return [f"w{rng.integers(0, 400)}" for _ in range(n)]
+
+
+def test_a_call_s_prompt_is_one_token_a_word(judge):
+    prepared = judge.prepare(["w1 w2 w3", "w4"], "w9 w8", [(5, 1.0)])
+    ids = prepared.ids[0, : prepared.lens[0]].tolist()
+    tok = judge.tokenizer
+    assert tok.unk_id not in ids
+    # BOS, 2 conversation words, 3 header words, two entries of key (3
+    # pieces at depth 1) + text, the opening backtick
+    assert len(ids) == 1 + 2 + 3 + (3 + 3) + (3 + 1) + 1
+    assert ids[0] == tok.cls_id and ids[-1] == judge.encode("`")[0]
+    assert len(set(judge.letter_ids.tolist())) == 20
+
+
+@pytest.mark.parametrize("n", [3, 20, 21, 64])
+def test_vote_matches_extract_vote_fed_the_same_letters(judge, n):
+    """The device's masked reads, handed to ``ballot.vote.extract_vote`` as
+    an upstream judge's answer (content = the key, ``top_logprobs`` at its
+    last letter = the siblings' log-probabilities), give the call's vote."""
+    rng = np.random.default_rng(n)
+    texts = candidates(n, rng)
+    panel = [(11, 3.0), (12, 2.0), (13, 1.0)]
+    confidence, tokens, ballots = judge.judge(texts, "w7 w8 w9", panel)
+    assert len(confidence) == n and abs(confidence.sum() - 1.0) < 1e-6
+    assert tokens > 0 and len(ballots) == 3
+    tally = np.zeros(n)
+    for (seed, weight), ballot in zip(panel, ballots):
+        tree_rng = random.Random(seed)
+        tree = PrefixTree.build(tree_rng, n, 20)
+        keys = [k for k, _ in tree.key_indices(tree_rng)]
+        assert ballot["key"] in keys and ballot["seed"] == seed
+        alternatives = [
+            types.SimpleNamespace(token=letter, logprob=entry["logprob"])
+            for letter, entry in ballot["siblings"].items()
+        ]
+        letters = [c for c in ballot["key"] if c in ALPHABET]
+        stream = []
+        for depth, letter in enumerate(letters):
+            stream.append(types.SimpleNamespace(token="`", top_logprobs=[]))
+            last = depth == len(letters) - 1
+            stream.append(
+                types.SimpleNamespace(token=letter, top_logprobs=alternatives if last else [])
+            )
+            stream.append(types.SimpleNamespace(token="`", top_logprobs=[]))
+        vote = extract_vote(
+            tree, *PrefixTree.regex_patterns(keys), n, ballot["key"], stream
+        )
+        assert sum(vote) == pytest.approx(Decimal(1), abs=Decimal("1e-20"))
+        for letter, entry in ballot["siblings"].items():
+            assert tree.walk(ballot["key"])[letter] == entry["candidate"]
+        if tree.depth == 2:
+            assert set(ballot["first"]) == set(tree.root)
+            assert ballot["key"][1] == max(ballot["first"], key=ballot["first"].get)
+        tally += np.array([float(v) for v in vote]) * weight
+    assert np.abs(tally / 6.0 - confidence).max() < 1e-6
+
+
+def test_a_call_too_long_for_the_bucket_is_refused(judge):
+    with pytest.raises(ValueError, match="JUDGE_MAX_TOKENS"):
+        judge.prepare(["w1 " * 300, "w2 " * 300], None, None)
+
+
+def test_int8_control_moves_the_reads_and_keeps_the_protocol():
+    base = TpuJudge("glm-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=SEQ, seed=2)
+    low = TpuJudge(
+        "glm-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=SEQ, seed=2, quantize="int8"
+    )
+    assert low.config.quantize == "int8"
+    assert "kernel_q" in low.params["layers"][0]["attn"]["q_a"]
+    assert "kernel_q" not in low.params["layers"][1]["moe"]
+    texts = candidates(8, np.random.default_rng(0))
+    a, _, ba = base.judge(texts, "w5", [(1, 1.0)])
+    b, _, bb = low.judge(texts, "w5", [(1, 1.0)])
+    assert abs(b.sum() - 1.0) < 1e-6 and set(ba[0]["siblings"]) == set(bb[0]["siblings"])
+    assert np.abs(a - b).max() > 0
+
+
+# -- /consensus scorer judge through the gateway and DeviceBatcher ------------
+
+
+def test_consensus_judge_through_gateway_and_batcher(judge):
+    from test_gateway import (
+        _tiny_embedder, _tiny_reranker, go, post_json, with_client,
+    )
+    from llm_weighted_consensus_tpu.serve import build_app
+
+    from llm_weighted_consensus_tpu import archive, registry
+    from llm_weighted_consensus_tpu.clients.chat import ApiBase, DefaultChatClient
+    from llm_weighted_consensus_tpu.clients.multichat import MultichatClient
+    from llm_weighted_consensus_tpu.clients.score import ScoreClient
+    from fakes import FakeTransport
+
+    def build(**device):
+        chat = DefaultChatClient(FakeTransport([]), [ApiBase("https://up.example", "k")])
+        reg = registry.InMemoryModelRegistry()
+        store = archive.InMemoryArchive()
+        score = ScoreClient(chat, reg, archive_fetcher=store)
+        return build_app(chat, score, MultichatClient(chat, reg, archive_fetcher=store), **device)
+
+    texts = candidates(21, np.random.default_rng(4))
+    cosine = {"input": ["the answer is 42", "the answer is 42!", "cabbage"]}
+    rm = {**cosine, "scorer": "rm", "prompt": "what is the answer?"}
+
+    async def with_judge(client):
+        dispatched = judge.stats()["dispatches"]
+        resp = await post_json(
+            client, "/consensus",
+            {"input": texts, "scorer": "judge", "prompt": "w1 w2",
+             "panel": [{"seed": 7, "weight": 2}, {"seed": 8}]},
+        )
+        assert resp.status == 200, await resp.text()
+        body = await resp.json()
+        assert body["scorer"] == "judge" and body["model"] == "glm-test-tiny"
+        assert len(body["confidence"]) == 21
+        assert sum(body["confidence"]) == pytest.approx(1.0, abs=1e-6)
+        assert [b["seed"] for b in body["ballots"]] == [7, 8]
+        assert [b["weight"] for b in body["ballots"]] == [2.0, 1.0]
+        for ballot in body["ballots"]:
+            assert set(ballot) == {"seed", "weight", "first", "key", "siblings"}
+            for entry in ballot["siblings"].values():
+                assert entry["logprob"] < 0 and 0 <= entry["candidate"] < 21
+        assert body["usage"]["prompt_tokens"] == body["usage"]["total_tokens"] > 2 * 21
+        # the default panel: three calls, weights 1
+        resp = await post_json(client, "/consensus", {"input": texts[:3], "scorer": "judge"})
+        default = await resp.json()
+        assert [b["seed"] for b in default["ballots"]] == [0, 1, 2]
+        assert "first" not in default["ballots"][0]  # depth 1
+        for bad in ({"panel": []}, {"panel": [{"seed": "x"}]}, {"panel": [{"seed": 1, "weight": 0}]}):
+            resp = await post_json(
+                client, "/consensus", {"input": texts[:3], "scorer": "judge", **bad}
+            )
+            assert resp.status == 400
+        metrics = await (await client.get("/metrics")).json()
+        assert metrics["device_batcher"]["dispatches"] >= 2
+        assert metrics["roofline"]["buckets"]["judge(n=2,s=448)"]["count"] == 1
+        assert metrics["phases"]["tokenize"]["count"] >= 2
+        assert metrics["judge"]["dispatches"] == dispatched + 2
+        return (
+            await (await post_json(client, "/consensus", cosine)).read(),
+            await (await post_json(client, "/consensus", rm)).read(),
+        )
+
+    async def without_judge(client):
+        resp = await post_json(client, "/consensus", {"input": texts[:3], "scorer": "judge"})
+        assert resp.status == 400
+        assert "JUDGE_MODEL" in (await resp.json())["message"]
+        return (
+            await (await post_json(client, "/consensus", cosine)).read(),
+            await (await post_json(client, "/consensus", rm)).read(),
+        )
+
+    embedder, reranker = _tiny_embedder(), _tiny_reranker()
+    got = go(with_client(build(embedder=embedder, reranker=reranker, judge=judge), with_judge))
+    want = go(with_client(build(embedder=embedder, reranker=reranker), without_judge))
+    assert got == want  # cosine and rm byte for byte as before
+    assert set(json.loads(want[0])) == {"model", "scorer", "confidence", "usage"}
+
+    # a server with a judge and no embedder serves
+    async def judge_only(client):
+        resp = await post_json(client, "/consensus", {"input": texts[:3], "scorer": "judge"})
+        assert resp.status == 200
+        resp = await post_json(client, "/consensus", cosine)
+        assert resp.status == 400 and "EMBEDDER_MODEL" in (await resp.json())["message"]
+
+    go(with_client(build(judge=judge), judge_only))
+    stats = judge.stats()
+    assert stats["calls"] >= 2 + 3 + 3 and stats["prefill_tokens"] > 0
+    assert stats["padded_tokens"] > 0 and sum(stats["expert_tokens"]) > 0
+
+
+def test_build_judge_gate_and_presets(monkeypatch):
+    from llm_weighted_consensus_tpu.serve import Config
+    from llm_weighted_consensus_tpu.serve.__main__ import build_judge
+
+    monkeypatch.delenv("LWC_ALLOW_RANDOM_PARAMS", raising=False)
+    config = Config.from_env({"JUDGE_MODEL": "glm-test-tiny", "JUDGE_MAX_TOKENS": "64"})
+    with pytest.raises(ValueError, match="JUDGE_WEIGHTS"):
+        build_judge(config)
+    built = build_judge(config, allow_synthetic=True)
+    assert built.max_tokens == 64 and built.jit_stats()["judge_panel"] >= 1
+    with pytest.raises(ValueError, match="not a known preset"):
+        build_judge(Config.from_env({"JUDGE_MODEL": "glm-enormous"}))
+    assert build_judge(Config.from_env({})) is None
+
+
+# -- HF-named sharded loading ---------------------------------------------------
+
+
+def test_sharded_checkpoint_loads_like_the_single_file(tmp_path, state):
+    from safetensors.numpy import save_file
+
+    from llm_weighted_consensus_tpu.models.judge import load_judge_params
+
+    single = tmp_path / "single"
+    sharded = tmp_path / "sharded"
+    single.mkdir()
+    sharded.mkdir()
+    save_file(state, str(single / "model.safetensors"))
+    names = sorted(state)
+    cut = [names[i::3] for i in range(3)]
+    weight_map = {}
+    for i, part in enumerate(cut, start=1):
+        name = f"model-{i:05d}-of-00003.safetensors"
+        save_file({k: state[k] for k in part}, str(sharded / name))
+        weight_map.update({k: name for k in part})
+    (sharded / "model.safetensors.index.json").write_text(
+        json.dumps({"metadata": {}, "weight_map": weight_map})
+    )
+    # a checkpoint that names fewer layers than the preset is served at its depth
+    deep = dataclasses.replace(C, num_layers=47)
+    a, config_a = load_judge_params(str(single), deep, dtype=jnp.float32)
+    b, config_b = load_judge_params(str(sharded), deep, dtype=jnp.float32)
+    c, _ = load_judge_params(
+        str(sharded / "model.safetensors.index.json"), deep, dtype=jnp.float32
+    )
+    assert config_a.num_layers == config_b.num_layers == C.num_layers
+    for x, y, z in zip(*(jax.tree_util.tree_leaves(t) for t in (a, b, c))):
+        assert x.shape == y.shape and bool((x == y).all()) and bool((x == z).all())

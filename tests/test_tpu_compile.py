@@ -96,3 +96,50 @@ def test_bge_large_attention_block_compiles_without_copies(
     assert kernels == [kernel], names
     # "copy-start"/"copy-done" are prefetches, not relayouts
     assert not [n for n, op, _ in found if op in ("copy", "transpose")], names
+
+
+# -- the judge's kernels at the configuration's widths (ISSUE 27) -------------
+
+
+def test_causal_attention_kernel_compiles_at_the_judge_s_shape(one_chip):
+    """A panel's attention: 3 calls x 8192 tokens, 20 heads of 256, bf16,
+    blocks of 1024 x 1024 (what ``block_for`` picks there)."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+
+    assert ca.block_for(8192) == 1024
+    x = jax.ShapeDtypeStruct((3, 8192, 20 * 256), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: ca.causal_attention_blockwise(
+            q, k, v, heads=20, scale=1 / 16, interpret=False
+        )
+    ).lower(x, x, x).compile()
+    names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    assert any(name.startswith("causal_attention_blockwise") for name in names), names
+
+
+@pytest.mark.parametrize(
+    "pairs,k,n", [(3 * 8192 * 4, 2048, 1536), (3 * 8192 * 4, 1536, 2048), (12, 2048, 1536)],
+    ids=["prefill-gate-up", "prefill-down", "decode"],
+)
+def test_grouped_expert_product_compiles_at_the_judge_s_shapes(one_chip, pairs, k, n):
+    """64 experts; a prefill's 98,304 pairs in tiles of 256 rows, a decode
+    step's 12 in tiles of 16."""
+    from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
+
+    tile = gm.tile_for(pairs, 64)
+    assert tile == (gm.TILE if pairs > 1000 else 16)
+    rows = gm.padded_rows(pairs, 64, tile)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, w, te, used: gm.grouped_expert_product(
+            x, w, te, used, tile=tile, interpret=False
+        )
+    ).lower(
+        arg((rows, k), jnp.bfloat16), arg((64, k, n), jnp.bfloat16),
+        arg((rows // tile,), jnp.int32), arg((1,), jnp.int32),
+    ).compile()
+    names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    assert any(name.startswith("grouped_expert_product") for name in names), names

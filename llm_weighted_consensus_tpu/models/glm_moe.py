@@ -35,9 +35,16 @@ moves them to (i, i + d/2), the same permutation on the query's and the
 key's rows, which leaves every q·k unchanged and lets the rotation be a
 half-swap.
 
-Routed experts run as three grouped products over the tokens routed to each
-(``ops/grouped_matmul.py``), a decode step's handful of tokens in tiles of 16
-rows through the same kernel (tiles that hold no row fetch and compute
+Routed experts run as TWO kernels over the tokens routed to each
+(``ops/grouped_matmul.py``): gate and up as one product with SwiGLU on its
+float32 accumulators, then down with the router's weight of each row on its
+accumulator.  Around them three stages, each a ``jax.named_scope`` under
+``experts_routed``: ``experts_layout`` (the pairs sorted by expert into padded
+row tiles, and the rows of ``h`` gathered into that order from column chunks
+that stay in VMEM), ``experts_swiglu`` (the kernels), ``experts_combine``
+(each token's k rows gathered back, a gather a choice, and summed; nothing
+is multiplied there).  A decode step's handful of tokens goes through the
+same path in tiles of 16 rows (tiles that hold no row fetch and compute
 nothing).  ``jax.named_scope`` names every part, so that a
 device trace can be read by layer.
 """
@@ -198,22 +205,32 @@ def route(h, p: dict, config: GlmMoeLiteConfig):
     return chosen.astype(jnp.int32), weight * config.routed_scaling_factor
 
 
-def _experts_grouped(h, chosen, p: dict, config: GlmMoeLiteConfig):
-    """h [t, hidden], chosen [t, k] -> ([t, k, hidden], counts [E])."""
+def _experts_grouped(h, chosen, weight, p: dict, config: GlmMoeLiteConfig):
+    """h [t, hidden], chosen and weight [t, k] -> (the chosen experts'
+    weighted sum [t, hidden], counts [E])."""
     t, k = chosen.shape
     experts = config.n_routed_experts
     tile = _gmm.tile_for(t * k, experts)
-    pair_of_row, row_of_pair, tile_expert, used, counts = _gmm.route_layout(
-        chosen.reshape(-1), experts, tile
-    )
-    product = partial(
-        _gmm.grouped_expert_product, tile_expert=tile_expert, tiles_used=used, tile=tile
-    )
-    x = h[pair_of_row // k]
-    gate = product(x, p["w_gate"]).astype(jnp.float32)
-    up = product(x, p["w_up"]).astype(jnp.float32)
-    y = product((jax.nn.silu(gate) * up).astype(h.dtype), p["w_down"])
-    return y[row_of_pair].reshape(t, k, -1), counts
+    with jax.named_scope("experts_layout"):
+        pair_of_row, row_of_pair, tile_expert, used, counts, row_weight = (
+            _gmm.route_layout_weighted(chosen.reshape(-1), weight.reshape(-1), experts, tile)
+        )
+        # rows gathered from column chunks small enough to stay in VMEM
+        parts = jnp.split(h, _gmm.column_chunks(*h.shape, h.dtype.itemsize), axis=1)
+        x = tuple(part[pair_of_row // k] for part in parts)
+    with jax.named_scope("experts_swiglu"):
+        product = partial(
+            _gmm.grouped_expert_product, tile_expert=tile_expert, tiles_used=used, tile=tile
+        )
+        y = product(
+            product(x, p["w_gate"], w_up=p["w_up"]), p["w_down"], row_weight=row_weight,
+            out_chunks=_gmm.column_chunks(pair_of_row.shape[0], h.shape[1], h.dtype.itemsize),
+        )
+    with jax.named_scope("experts_combine"):
+        # a gather a choice, so that no [t, k, hidden] is laid out between
+        rows = row_of_pair.reshape(t, k)
+        routed = [sum(part[rows[:, j]].astype(jnp.float32) for j in range(k)) for part in y]
+        return jnp.concatenate(routed, axis=1).astype(h.dtype), counts
 
 
 def _moe(h, p: dict, config: GlmMoeLiteConfig):
@@ -221,11 +238,10 @@ def _moe(h, p: dict, config: GlmMoeLiteConfig):
     with jax.named_scope("router"):
         chosen, weight = route(h, p, config)
     with jax.named_scope("experts_routed"):
-        y, counts = _experts_grouped(h, chosen, p, config)
-        routed = jnp.sum(y.astype(jnp.float32) * weight[..., None], axis=1)
+        routed, counts = _experts_grouped(h, chosen, weight, p, config)
     with jax.named_scope("expert_shared"):
         shared = _swiglu(h, p["shared"])
-    return routed.astype(h.dtype) + shared, counts
+    return routed + shared, counts
 
 
 def _mlp(h, layer: dict, config: GlmMoeLiteConfig):

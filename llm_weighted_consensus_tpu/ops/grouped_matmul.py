@@ -13,18 +13,47 @@ whatever the routing; tiles past the rows in use do no work.
 Grid (N / tile_n, row tiles), the row tiles innermost: consecutive row tiles
 of one expert name the same weight block, which is then fetched once per
 expert and column block, and the activations stream past it.  The whole of K
-is one block (2048 and 1536 here), so there is no accumulator.
+is one block (2048 and 1536 here), so there is no accumulator to carry.
+
+ONE kernel body, three forms by what it is handed (all under the jitted name
+``grouped_expert_product``, which is what a device trace calls them):
+
+  gate-up   ``w_up`` given: ``silu(x @ w[e]) * (x @ w_up[e])``, both products
+            over one x block in VMEM, SwiGLU on the float32 accumulators, one
+            bf16 write;
+  down      ``row_weight`` given: the router's weight of each row on the
+            float32 accumulator before the cast (0 for padding rows);
+  plain     neither: the product alone (the tests' twin of ``ragged_dot``).
+
+x may come as column chunks and the result may leave as column chunks
+(``column_chunks``): the rows are gathered into and out of the padded layout
+by XLA, and XLA gathers rows from a table in VMEM at 1.5 ms a layer where it
+takes 3.8 from HBM (one DMA descriptor of 33 ns a row).  Its memory
+assignment keeps a table of 24-28 MiB in VMEM and not one of 48 or 96.
+
+``route_layout`` makes its tables with three sorts and one-hot sums: a gather
+or scatter of SCALARS runs an element at a time on the chip, and the six of
+the first version were 3.0 of a layer's 3.9 ms of layout.
 
 ``grouped_product_ragged`` is the same product through
 ``jax.lax.ragged_dot`` over the sorted, unpadded rows: the twin the tests
 compare with.  What the chip says (TPU v5e, bf16, 98,304 pairs over 64
-experts, one 2048 x 1536 product; my chip run, PR 27, the sort and the gather
-of the rows taken off each): this kernel 4.3 ms at tiles of 256 x 512 (144
-TFLOP/s), 4.05 ms at 256 x 768 (152 TFLOP/s, 77% of the bf16 peak; 512-row
-tiles the same, a whole 1536-wide block does not fit VMEM);
-``jax.lax.ragged_dot`` 7.7 ms (80 TFLOP/s); jax's own
-``pallas.ops.tpu.megablox.gmm`` at tiling (512, 1024, 768) 4.8 ms (128
-TFLOP/s).  So the kernel here serves, at 256 x 768.
+experts; my chip runs, PR 27 and PR 28, a layer at a time outside the
+server).  One 2048 x 1536 product: this kernel 4.3 ms at tiles of 256 x 512
+(144 TFLOP/s), 4.05-4.2 at 256 x 768; ``jax.lax.ragged_dot`` 7.7 ms; jax's
+own ``pallas.ops.tpu.megablox.gmm`` at (512, 1024, 768) 4.8 ms.  Gate-up
+fused, column blocks of 384 / 512 / 768 / 1536: 8.13 / 7.89 / 7.64 / 7.36 ms
+(against 2 x 4.2 unfused plus 1.6 of XLA's SwiGLU pass); down with the row
+weights at 512 / 1024 / 2048: 4.66 / 4.27 / 4.03 (plain at 512: 4.62).  So
+both serve at the WHOLE width, one column block, which takes
+``vmem_limit_bytes`` over the default 16 MiB (two 6.3 MB weight blocks,
+double-buffered).  Rows gathered INSIDE the gate-up kernel (scalar-prefetched
+token table, one row copy a padded row from ``h`` packed two bf16 to a word,
+since Mosaic refuses a one-row slice of a bf16 array in HBM): 11.8 ms issued
+in a loop, 10.6 issued in straight-line code between the products, against
+11.2 for XLA's gather from HBM plus the fused kernel and 9.1 for XLA's gather
+from VMEM chunks plus the fused kernel: the descriptors' issue (28 ns a row)
+does not overlap the products, so XLA's gather serves.
 """
 
 from __future__ import annotations
@@ -37,6 +66,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 TILE = 256  # rows of one tile on the chip: half a tile of padding an expert
+VMEM_LIMIT = 64 << 20  # of a v5e core's 128 MiB; the default scope is 16
+GATHER_TABLE_BYTES = 32 << 20  # a table XLA's memory assignment keeps in VMEM
 
 
 def _interpret() -> bool:
@@ -56,6 +87,58 @@ def padded_rows(pairs: int, experts: int, tile: int) -> int:
     return (-(-pairs // tile) + experts) * tile
 
 
+def _lookup(table, index):
+    """``table[index]`` for a table of a few entries, as a one-hot sum: a
+    gather or scatter of scalars runs an element at a time on the TPU (7 ns
+    each: six of them were 3 ms of a layer's layout, my chip run, PR 28)."""
+    hit = index[:, None] == jnp.arange(table.shape[0], dtype=index.dtype)[None, :]
+    return jnp.sum(jnp.where(hit, table[None, :], 0), axis=1)
+
+
+def route_layout_weighted(expert_of_pair, pair_weight, experts: int, tile: int):
+    """``route_layout`` and, carried through the same sorts, ``row_weight``
+    [M_pad] float32: ``pair_weight`` [M] of the pair a row holds, 0 for
+    padding.  Three sorts and sums over one-hot comparisons; no scalar is
+    gathered or scattered."""
+    m = expert_of_pair.shape[0]
+    rows = padded_rows(m, experts, tile)
+    int32 = functools.partial(jnp.arange, dtype=jnp.int32)
+    counts = jnp.sum(
+        expert_of_pair[None, :] == int32(experts)[:, None], axis=1, dtype=jnp.int32
+    )
+    padded = -(-counts // tile) * tile
+    padded_end = jnp.cumsum(padded)
+    sorted_expert, order, weight_sorted = jax.lax.sort(
+        (expert_of_pair, int32(m), pair_weight.astype(jnp.float32)), num_keys=1
+    )
+    # a group's rows start where its padded predecessors end
+    shift = (padded_end - padded) - (jnp.cumsum(counts) - counts)
+    row_sorted = int32(m) + _lookup(shift, sorted_expert)
+    _, row_of_pair = jax.lax.sort((order, row_sorted), num_keys=1)
+    # the rows no pair fills, in order: each group's tail, then the tiles
+    # past the last group (which follow the last group's formula)
+    unfilled_end = jnp.cumsum(padded - counts)
+    j = int32(rows - m)
+    group = jnp.sum(unfilled_end[None, :] <= j[:, None], axis=1, dtype=jnp.int32)
+    unfilled = j + _lookup(padded_end - unfilled_end, jnp.minimum(group, experts - 1))
+    nobody = jnp.zeros((rows - m,), jnp.int32)
+    _, pair_of_row, row_weight = jax.lax.sort(
+        (
+            jnp.concatenate([row_sorted, unfilled]),
+            jnp.concatenate([order, nobody]),
+            jnp.concatenate([weight_sorted, nobody.astype(jnp.float32)]),
+        ),
+        num_keys=1,
+    )
+    first_rows = int32(rows // tile) * tile
+    tile_expert = jnp.minimum(
+        jnp.sum(padded_end[None, :] <= first_rows[:, None], axis=1, dtype=jnp.int32),
+        experts - 1,
+    )
+    tiles_used = (padded_end[-1:] // tile).astype(jnp.int32)
+    return pair_of_row, row_of_pair, tile_expert, tiles_used, counts, row_weight
+
+
 def route_layout(expert_of_pair, experts: int, tile: int):
     """``expert_of_pair`` [M] int32, the expert of each (token, choice) pair
     in token-major order.  Returns
@@ -66,71 +149,105 @@ def route_layout(expert_of_pair, experts: int, tile: int):
       ``tiles_used``  [1]      row tiles that hold at least one real row,
       ``counts``      [E]      pairs routed to each expert.
     """
-    m = expert_of_pair.shape[0]
-    rows = padded_rows(m, experts, tile)
-    counts = jnp.zeros((experts,), jnp.int32).at[expert_of_pair].add(1)
-    padded = -(-counts // tile) * tile
-    padded_end = jnp.cumsum(padded)
-    padded_start = padded_end - padded
-    start = jnp.cumsum(counts) - counts
-    order = jnp.argsort(expert_of_pair, stable=True)
-    sorted_expert = expert_of_pair[order]
-    rank = jnp.arange(m, dtype=jnp.int32) - start[sorted_expert]
-    row_sorted = padded_start[sorted_expert] + rank
-    row_of_pair = jnp.zeros((m,), jnp.int32).at[order].set(row_sorted)
-    pair_of_row = jnp.zeros((rows,), jnp.int32).at[row_sorted].set(order)
-    first_rows = jnp.arange(rows // tile, dtype=jnp.int32) * tile
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(padded_end, first_rows, side="right"), experts - 1
-    ).astype(jnp.int32)
-    tiles_used = (padded_end[-1:] // tile).astype(jnp.int32)
-    return pair_of_row, row_of_pair, tile_expert, tiles_used, counts
+    nothing = jnp.zeros(expert_of_pair.shape, jnp.float32)
+    return route_layout_weighted(expert_of_pair, nothing, experts, tile)[:5]
 
 
-def _kernel(tile_expert_ref, tiles_used_ref, x_ref, w_ref, o_ref):
-    del tile_expert_ref
+def _kernel(_, tiles_used_ref, *refs, x_chunks: int, swiglu: bool, weighted: bool):
+    """One row tile times its expert's weight block, float32 accumulation;
+    the epilogue on the accumulator, before the one cast: ``silu(x @ w) *
+    (x @ w_up)`` where a second weight came, ``* row_weight`` where a
+    per-row weight did.  x and the output may each come as column chunks."""
+    refs = list(refs)
+    x_refs = [refs.pop(0) for _ in range(x_chunks)]
+    w_ref = refs.pop(0)
+    up_ref = refs.pop(0) if swiglu else None
+    weight_ref = refs.pop(0) if weighted else None
+    o_refs = refs
+    dims = (((1,), (0,)), ((), ()))
 
     @pl.when(pl.program_id(1) < tiles_used_ref[0])
     def _():
-        o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(o_ref.dtype)
+        x = jnp.concatenate([ref[...] for ref in x_refs], axis=1)
+        acc = jax.lax.dot_general(x, w_ref[...], dims, preferred_element_type=jnp.float32)
+        if swiglu:
+            up = jax.lax.dot_general(x, up_ref[...], dims, preferred_element_type=jnp.float32)
+            acc = acc * jax.nn.sigmoid(acc) * up
+        if weighted:
+            acc = acc * weight_ref[...]
+        width = acc.shape[1] // len(o_refs)
+        for c, o_ref in enumerate(o_refs):
+            o_ref[...] = acc[:, c * width:(c + 1) * width].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "tile_n", "interpret"))
+def column_chunks(rows: int, width: int, itemsize: int = 2) -> int:
+    """Into how many column chunks a [rows, width] table goes where XLA is to
+    gather rows from it: the least power of two that leaves a chunk within
+    ``GATHER_TABLE_BYTES`` (and whole lane tiles wide)."""
+    chunks, size = 1, rows * width * itemsize
+    while size > chunks * GATHER_TABLE_BYTES and width % (256 * chunks) == 0:
+        chunks *= 2
+    return chunks
+
+
+@functools.partial(
+    jax.jit, static_argnames=("tile", "tile_n", "out_chunks", "interpret")
+)
 def grouped_expert_product(
-    x, w, tile_expert, tiles_used, *, tile: int, tile_n: int = 768,
+    x, w, tile_expert, tiles_used, *, w_up=None, row_weight=None, tile: int,
+    tile_n: int | None = None, out_chunks: int | None = None,
     interpret: bool | None = None,
 ):
-    """x [M_pad, K] rows in ``route_layout``'s order, w [E, K, N] ->
-    [M_pad, N]; row tile i is multiplied by ``w[tile_expert[i]]``.  Rows of
-    tiles past ``tiles_used`` are left unwritten (nothing reads them).  The
-    jitted function's name is the kernel's name in a device trace."""
-    rows, k = x.shape
+    """x [M_pad, K] rows in ``route_layout``'s order (or its column chunks,
+    a tuple), w [E, K, N] -> [M_pad, N] (with ``out_chunks`` a tuple of that
+    many column chunks, carved from one block of the whole width); row tile
+    i is multiplied by ``w[tile_expert[i]]``.  With ``w_up`` [E, K, N] the result is ``silu(x @
+    w[e]) * (x @ w_up[e])`` (gate and up in one pass over x); with
+    ``row_weight`` [M_pad] float32 each row of the product is scaled by its
+    weight.  Both act on the float32 accumulator.  Rows of tiles past
+    ``tiles_used`` are left unwritten (nothing reads them).  The jitted
+    function's name is the kernel's name in a device trace, whichever form
+    runs."""
+    xs = tuple(x) if isinstance(x, (tuple, list)) else (x,)
+    rows, k = xs[0].shape[0], sum(part.shape[1] for part in xs)
     n = w.shape[2]
-    tile_n = next(t for t in (tile_n, 512, 256, 128, n) if t <= n and n % t == 0)
-    if rows % tile:
+    tile_n = tile_n or n
+    pieces = out_chunks or 1
+    if rows % tile or n % tile_n or tile_n % pieces or (out_chunks and tile_n != n):
         raise ValueError(f"{rows} x {n} is not whole tiles of {tile} x {tile_n}")
     if interpret is None:
         interpret = _interpret()
-    return pl.pallas_call(
-        _kernel,
+    weight_spec = pl.BlockSpec((None, k, tile_n), lambda j, i, te, used: (te[i], 0, j))
+    by_row = lambda width: pl.BlockSpec((tile, width), lambda j, i, te, used: (i, 0))  # noqa: E731
+    operands = [*xs, w]
+    in_specs = [*(by_row(part.shape[1]) for part in xs), weight_spec]
+    if w_up is not None:
+        operands.append(w_up)
+        in_specs.append(weight_spec)
+    if row_weight is not None:
+        operands.append(row_weight.astype(jnp.float32).reshape(rows, 1))
+        in_specs.append(by_row(1))
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, x_chunks=len(xs), swiglu=w_up is not None,
+            weighted=row_weight is not None,
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(n // tile_n, rows // tile),
-            in_specs=[
-                pl.BlockSpec((tile, k), lambda j, i, te, used: (i, 0)),
-                pl.BlockSpec((None, k, tile_n), lambda j, i, te, used: (te[i], 0, j)),
-            ],
-            out_specs=pl.BlockSpec((tile, tile_n), lambda j, i, te, used: (i, j)),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((tile, tile_n // pieces), lambda j, i, te, used: (i, j))
+            ] * pieces,
         ),
-        out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
+        out_shape=[jax.ShapeDtypeStruct((rows, n // pieces), xs[0].dtype)] * pieces,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(tile_expert, tiles_used, x, w)
+    )(tile_expert, tiles_used, *operands)
+    return tuple(out) if out_chunks else out[0]
 
 
 def grouped_product_ragged(x_sorted, w, counts):

@@ -208,6 +208,136 @@ def test_grouped_product_matches_einsum_at_ragged_sizes(pairs, tile):
     assert float(jnp.abs(ragged - want[order]).max()) < 1e-4
 
 
+def ragged_case(pairs, tile, k, experts=8):
+    """``pairs`` rows over ``experts`` groups, expert 3 with no token, in
+    ``route_layout``'s padded order."""
+    rng = np.random.default_rng(pairs)
+    expert = rng.integers(0, experts, size=pairs).astype(np.int32)
+    expert[expert == 3] = 4
+    x = jnp.asarray(rng.standard_normal((pairs, k)), jnp.float32)
+    layout = gmm.route_layout(jnp.asarray(expert), experts, tile)
+    assert int(layout[4][3]) == 0
+    return rng, expert, x, layout
+
+
+@pytest.mark.parametrize("pairs,tile,chunks", [(37, 8, 1), (200, 16, 2), (64, 32, 4)])
+def test_fused_gate_up_matches_swiglu_at_ragged_sizes(pairs, tile, chunks):
+    """Gate and up in one kernel, SwiGLU on the float32 accumulators; the
+    rows as one array and as column chunks."""
+    experts, k, n = 8, 64, 48
+    rng, expert, x, (pair_of_row, row_of_pair, tile_expert, used, _) = ragged_case(
+        pairs, tile, k
+    )
+    w_gate = jnp.asarray(rng.standard_normal((experts, k, n)) * 0.2, jnp.float32)
+    w_up = jnp.asarray(rng.standard_normal((experts, k, n)), jnp.float32)
+    rows = x[pair_of_row]
+    out = gmm.grouped_expert_product(
+        rows if chunks == 1 else tuple(jnp.split(rows, chunks, axis=1)),
+        w_gate, tile_expert, used, w_up=w_up, tile=tile, tile_n=16,
+    )
+    gate = jnp.einsum("mk,mkn->mn", x, w_gate[expert])
+    want = jax.nn.silu(gate) * jnp.einsum("mk,mkn->mn", x, w_up[expert])
+    assert float(jnp.abs(out[row_of_pair] - want).max()) < 1e-4
+
+
+@pytest.mark.parametrize("pairs,tile,chunks", [(36, 8, 1), (200, 16, 2), (64, 32, 4)])
+def test_weighted_down_and_combine_match_the_weighted_sum(pairs, tile, chunks):
+    """The router's weight on the down product's accumulator, then the way
+    back: rows gathered per token and summed over its k choices; the
+    product as one array and as column chunks."""
+    experts, k, n, choices = 8, 48, 64, 4
+    rng, expert, x, (pair_of_row, row_of_pair, tile_expert, used, _) = ragged_case(
+        pairs, tile, k
+    )
+    w_down = jnp.asarray(rng.standard_normal((experts, k, n)), jnp.float32)
+    weight = jnp.asarray(rng.random(pairs) + 0.1, jnp.float32)
+    y = gmm.grouped_expert_product(
+        x[pair_of_row], w_down, tile_expert, used, row_weight=weight[pair_of_row],
+        tile=tile, **({"tile_n": 16} if chunks == 1 else {"out_chunks": chunks}),
+    )
+    if chunks > 1:
+        assert [part.shape for part in y] == [(x[pair_of_row].shape[0], n // chunks)] * chunks
+        y = jnp.concatenate(y, axis=1)
+    got = jnp.sum(y[row_of_pair].reshape(pairs // choices, choices, n), axis=1)
+    pair = jnp.einsum("mk,mkn->mn", x, w_down[expert]) * weight[:, None]
+    want = jnp.sum(pair.reshape(pairs // choices, choices, n), axis=1)
+    assert float(jnp.abs(got - want).max()) < 1e-4
+
+
+def moe_case(tokens, dtype, seed=0, hidden=C.hidden_size):
+    """A sparse layer of 64 experts, 4 a token, at the tiny widths."""
+    config = dataclasses.replace(
+        C, n_routed_experts=64, num_experts_per_tok=4, hidden_size=hidden
+    )
+    params = glm_moe.init_params(jax.random.PRNGKey(seed), config, dtype=jnp.float32)
+    p = params["layers"][config.first_k_dense_replace]["moe"]
+    p = {**p, "router": p["router"] * 40.0}  # scores that differ: top-4 is stable
+    rng = np.random.default_rng(tokens)
+    h = jnp.asarray(rng.standard_normal((tokens, config.hidden_size)), jnp.float32)
+    weights = {k: v for k, v in p.items() if k not in ("router", "bias")}
+    p = {**p, **jax.tree_util.tree_map(lambda a: a.astype(dtype), weights)}
+    return config, p, h.astype(dtype)
+
+
+def moe_by_token(h, p, config, round_to=None):
+    """The layer one token at a time over its chosen experts, float32; with
+    ``round_to`` the parent's unfused formula: gate, up, their product and
+    the down product each rounded to it before the weighted sum."""
+
+    def f64(a):
+        return np.asarray(a.astype(jnp.float32), np.float64)
+
+    def rounded(a):
+        return a if round_to is None else f64(jnp.asarray(a, jnp.float32).astype(round_to))
+
+    chosen, weight = glm_moe.route(h, p, config)
+    x, gate, up, down = f64(h), f64(p["w_gate"]), f64(p["w_up"]), f64(p["w_down"])
+    out = np.zeros((h.shape[0], config.hidden_size))
+    for t in range(h.shape[0]):
+        for e, w in zip(np.asarray(chosen[t]), np.asarray(weight[t], np.float64)):
+            g, u = rounded(x[t] @ gate[e]), rounded(x[t] @ up[e])
+            act = rounded(g / (1 + np.exp(-g)) * u)
+            out[t] += w * rounded(act @ down[e])
+    return out + f64(glm_moe._swiglu(h, p["shared"])), chosen
+
+
+@pytest.mark.parametrize(
+    "tokens,tile,hidden,chunks",
+    [(400, 16, C.hidden_size, 1), (3, 16, C.hidden_size, 1), (400, 16, 512, 2)],
+    ids=["prefill-tiles-of-16", "decode-12-pairs", "prefill-in-column-chunks"],
+)
+def test_moe_matches_a_loop_over_each_token_s_experts_in_float32(
+    tokens, tile, hidden, chunks, monkeypatch
+):
+    """The last case with tables too large for one gather (by a limit set
+    low): the rows go in, and the products come back, in column chunks."""
+    config, p, h = moe_case(tokens, jnp.float32, hidden=hidden)
+    if chunks > 1:
+        monkeypatch.setattr(gmm, "GATHER_TABLE_BYTES", 1 << 19)
+    assert gmm.column_chunks(tokens, hidden, 4) == chunks
+    assert gmm.tile_for(tokens * 4, 64) == tile
+    got, counts = glm_moe._moe(h, p, config)
+    want, chosen = moe_by_token(h, p, config)
+    assert np.abs(np.asarray(got, np.float64) - want).max() < 1e-4
+    assert (np.asarray(counts) == np.bincount(np.asarray(chosen).ravel(), minlength=64)).all()
+
+
+@pytest.mark.parametrize(
+    "tokens", [400, 3], ids=["prefill-tiles-of-16", "decode-12-pairs"]
+)
+def test_moe_in_bfloat16_is_within_rounding_of_the_unfused_formula(tokens):
+    """bf16 operands, float32 accumulation: against the parent's formula
+    (every product rounded to bf16) the fused layer differs by bf16's
+    rounding, and from the float32 loop by no more than that formula does."""
+    config, p, h = moe_case(tokens, jnp.bfloat16)
+    got = np.asarray(glm_moe._moe(h, p, config)[0].astype(jnp.float32), np.float64)
+    unfused, _ = moe_by_token(h, p, config, round_to=jnp.bfloat16)
+    exact, _ = moe_by_token(h, p, config)
+    size = np.abs(exact).max()
+    assert np.abs(got - unfused).max() < 2.0**-7 * size
+    assert np.abs(got - exact).max() <= np.abs(unfused - exact).max() + 2.0**-8 * size
+
+
 # -- the judge: ballots, votes, the tally --------------------------------------
 
 

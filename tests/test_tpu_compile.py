@@ -118,27 +118,34 @@ def test_causal_attention_kernel_compiles_at_the_judge_s_shape(one_chip):
 
 
 @pytest.mark.parametrize(
-    "pairs,k,n", [(3 * 8192 * 4, 2048, 1536), (3 * 8192 * 4, 1536, 2048), (12, 2048, 1536)],
-    ids=["prefill-gate-up", "prefill-down", "decode"],
+    "pairs,fused",
+    [(3 * 8192 * 4, "gate-up"), (3 * 8192 * 4, "down"), (12, "gate-up"), (12, "down")],
+    ids=["prefill-gate-up", "prefill-down", "decode", "decode-down"],
 )
-def test_grouped_expert_product_compiles_at_the_judge_s_shapes(one_chip, pairs, k, n):
+def test_grouped_expert_product_compiles_at_the_judge_s_shapes(one_chip, pairs, fused):
     """64 experts; a prefill's 98,304 pairs in tiles of 256 rows, a decode
-    step's 12 in tiles of 16."""
+    step's 12 in tiles of 16; the kernels as served: gate and up fused with
+    SwiGLU (K 2048, N 1536), down with the rows' weights (K 1536, N 2048)."""
     from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
 
     tile = gm.tile_for(pairs, 64)
     assert tile == (gm.TILE if pairs > 1000 else 16)
     rows = gm.padded_rows(pairs, 64, tile)
+    k, n = (2048, 1536) if fused == "gate-up" else (1536, 2048)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(
-        lambda x, w, te, used: gm.grouped_expert_product(
-            x, w, te, used, tile=tile, interpret=False
+    def served(x, w, extra, te, used):
+        epilogue = {"w_up": extra} if fused == "gate-up" else {"row_weight": extra}
+        return gm.grouped_expert_product(
+            x, w, te, used, tile=tile, interpret=False, **epilogue
         )
-    ).lower(
-        arg((rows, k), jnp.bfloat16), arg((64, k, n), jnp.bfloat16),
+
+    weights = arg((64, k, n), jnp.bfloat16)
+    compiled = jax.jit(served).lower(
+        arg((rows, k), jnp.bfloat16), weights,
+        weights if fused == "gate-up" else arg((rows,), jnp.float32),
         arg((rows // tile,), jnp.int32), arg((1,), jnp.int32),
     ).compile()
     names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]

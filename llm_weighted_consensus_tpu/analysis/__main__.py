@@ -96,8 +96,7 @@ def main(argv=None) -> int:
             print(f"unknown rule {exc}", file=sys.stderr)
             return 2
 
-    # the concurrency trio runs as its own timed pass (bench_host.py
-    # budgets it alongside the jaxpr/mesh audits), skippable without
+    # the concurrency trio runs as its own timed pass, skippable without
     # touching the per-function lint
     conc_names = {"LWC014", "LWC015", "LWC016"}
     skip_conc = args.no_concurrency or bool(

@@ -16,8 +16,7 @@ jit-dispatch bookkeeping (PR 3's ``jit_stats``), not on device output:
   at least one Pallas kernel in the forward, and ZERO
   ``convert_element_type`` int8→float (the storage-format anti-pattern
   — dequantizing ``kernel_q`` back to bf16 before a bf16 matmul — that
-  the fused path replaced; same predicate as the dispatch evidence
-  committed in the PR 3 bench records).
+  the fused path replaced).
 * **JXA003 f64-promotion** — no float64 avals anywhere in the traced
   serving math (an x64 leak doubles every buffer and halves MXU rate).
 * **JXA004 missing-aot-bucket / JXA005 stray-specialization** — after
@@ -121,9 +120,7 @@ def _env_packed_buckets() -> Tuple[Tuple[int, int, int], ...]:
 
 def walk_jaxpr(jaxpr, visit) -> None:
     """Depth-first over every equation, descending into sub-jaxprs
-    (pjit bodies, scan/cond branches, Pallas kernel bodies — the same
-    recursion as the PR 3 dispatch-evidence walker, so the dequant
-    predicate here matches the committed bench records)."""
+    (pjit bodies, scan/cond branches, Pallas kernel bodies)."""
     for eqn in jaxpr.eqns:
         visit(eqn)
         for sub in eqn.params.values():

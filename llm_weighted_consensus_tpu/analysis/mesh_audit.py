@@ -444,8 +444,7 @@ def audit_hlo_collectives(
                     message=(
                         f"forbidden op `{match.group(0)}` in the lowered "
                         "HLO: an all-to-all / host transfer inside the "
-                        "serving hot path wedges scarce interconnect "
-                        "(the BENCH_r04/r05 failure class)"
+                        "serving hot path wedges scarce interconnect"
                     ),
                 )
             )

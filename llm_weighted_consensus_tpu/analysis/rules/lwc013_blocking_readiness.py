@@ -15,8 +15,7 @@ Allowed:
 * ``parallel/multihost_smoke.py`` — an offline probe/benchmark, not a
   serving path; it blocks on purpose to measure.
 
-Bench scripts live outside the package and are not linted.  Note that
-``np.asarray`` on a device array also blocks, but flagging every
+Note that ``np.asarray`` on a device array also blocks, but flagging every
 asarray would drown the signal — the finalize-closure convention
 (serve/batcher.py) covers those by construction.
 """

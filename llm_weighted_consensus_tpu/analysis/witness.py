@@ -23,9 +23,9 @@ DAG and the edges observed so far:
 
 The witness never blocks the application: proxies delegate to the real
 primitive first and record after, so a violation is reported, not
-injected.  Overhead is one dict update per acquisition (the soak bench
-holds it under 2%); cross-thread state lives behind the witness's own
-leaf mutex, held only for the bookkeeping instant.
+injected.  Overhead is one dict update per acquisition; cross-thread
+state lives behind the witness's own leaf mutex, held only for the
+bookkeeping instant.
 """
 
 from __future__ import annotations

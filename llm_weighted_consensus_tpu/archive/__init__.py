@@ -8,7 +8,7 @@ src/completions_archive/{mod,fetcher}.rs (seam + union + unimplemented stub),
 src/chat/completions/client.rs:437-645 (prefetch + rehydration).
 
 The archive is also the batch re-score source: ``InMemoryArchive`` backs the
-pmap archive re-scoring path (BASELINE config 4) and can be snapshotted to
+archive re-scoring path (``archive/rescore.py``) and can be snapshotted to
 disk, which is this framework's checkpoint/resume story (SURVEY §5).
 """
 

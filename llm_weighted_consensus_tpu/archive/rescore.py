@@ -1,8 +1,7 @@
 """Archive batch re-scoring: recompute consensus over stored completions.
 
-BASELINE config 4: "completions_archive batch re-score (10k archived
-candidates, pmap)".  The use case: judge weights change (a panel is
-re-weighted, a training table is updated) and every archived score
+The use case: judge weights change (a panel is re-weighted, a training
+table is updated) and every archived score
 completion's consensus is recomputed — WITHOUT re-querying any judge.
 Votes are already stored per judge choice (``message.vote``); re-scoring is
 pure device math:

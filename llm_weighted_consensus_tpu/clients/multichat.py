@@ -11,7 +11,7 @@ and duplicates of the same generator occupy consecutive slots
 Streaming protocol mirrors the score engine's: slots stream interleaved,
 per-slot errors are error choices (never request failures), unary is the
 fold of the stream.  ``StreamingSelfConsistency`` adds the incremental
-on-device consensus update (BASELINE config 5): each finished candidate is
+on-device consensus update: each finished candidate is
 embedded and the cosine consensus recomputed, so consumers watch confidence
 converge while slower generators are still streaming.
 """
@@ -195,7 +195,7 @@ class MultichatClient:
 
 
 # ---------------------------------------------------------------------------
-# Streaming incremental consensus (BASELINE config 5)
+# Streaming incremental consensus
 # ---------------------------------------------------------------------------
 
 

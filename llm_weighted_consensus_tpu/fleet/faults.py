@@ -32,7 +32,7 @@ spec replays a fault list per pair by ordinal.
 Selectable in production-shaped runs via ``FLEET_FAULT_PLAN``, e.g.
 ``seed=7,blackhole=0.05,slow=0.1,slow_ms=150`` or
 ``blackhole=1.0,to=http://10.0.0.2:5000`` (faults only on legs toward
-the listed peers — how a bench carves a partition out of env config).
+the listed peers — how a drill carves a partition out of env config).
 
 Unset ⇒ ``FleetClient`` never consults this module: the seam is one
 ``is None`` check, byte-identical to the pre-fault-plan fleet.

@@ -7,7 +7,7 @@ device model:
 * ``bert``      — functional JAX BERT encoder (bge-small/base/large
   configs), bf16 matmuls with f32 layernorm/softmax, CLS/mean pooling;
 * ``deberta``   — disentangled-attention encoder + scalar reward head
-  (reward-model re-ranking, BASELINE config 3);
+  (reward-model re-ranking);
 * ``tokenizer`` — host-side WordPiece (real vocab when available, a
   deterministic hash tokenizer fallback so the pipeline always runs);
 * ``embedder``  — tokenize -> jitted forward -> pooled embedding, exposing

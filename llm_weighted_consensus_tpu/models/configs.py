@@ -84,9 +84,10 @@ GTE_LARGE = BertConfig(
 # BGE-M3 (BAAI/bge-m3 dense retrieval: XLM-RoBERTa-large arch, 8192-token
 # context, CLS pooling).  Positions are roberta-style; the 8194-row table
 # minus pad_token_id+1 gives the advertised 8192-token window.  Serve long
-# inputs with MESH_SP (ring attention).  The checkpoint's
-# sentencepiece.bpe.model tokenizes via models/spm.py (xlmr id scheme,
-# auto-discovered next to EMBEDDER_WEIGHTS or set EMBEDDER_VOCAB).
+# inputs on a mesh with an sp axis (ring attention, MESH_SHAPE=DPxTPxSP).
+# The checkpoint's sentencepiece.bpe.model tokenizes via models/spm.py
+# (xlmr id scheme, auto-discovered next to EMBEDDER_WEIGHTS or set
+# EMBEDDER_VOCAB).
 BGE_M3 = BertConfig(
     vocab_size=250002,
     hidden_size=1024,
@@ -99,11 +100,12 @@ BGE_M3 = BertConfig(
     position_style="roberta",
 )
 
-# Long-context encoder (bge-large dims, 8192-position table): serve with
-# MESH_SP so attention runs as a sequence-parallel ring — a single device
-# would need the full (s, s) score matrix.  No public checkpoint ships
-# with these positions; load fine-tuned weights via EMBEDDER_WEIGHTS or
-# train with train/ (position_embed rows beyond 512 train from scratch).
+# Long-context encoder (bge-large dims, 8192-position table): serve on a
+# mesh with an sp axis so attention runs as a sequence-parallel ring — a
+# single device would need the full (s, s) score matrix.  No public
+# checkpoint ships with these positions; load fine-tuned weights via
+# EMBEDDER_WEIGHTS or train with train/ (position_embed rows beyond 512
+# train from scratch).
 BERT_LONG_8K = BertConfig(
     hidden_size=1024,
     num_layers=24,

@@ -1,6 +1,6 @@
 """DeBERTa-style encoder + scalar reward head (reward-model re-ranking).
 
-BASELINE config 3 replaces the cosine self-consistency vote with a trained
+``scorer: rm`` replaces the cosine self-consistency vote with a trained
 reward model (deberta-v3 class).  The architectural difference from BERT is
 **disentangled attention**: no absolute position embeddings; instead every
 layer adds content->position and position->content terms computed against a
@@ -288,7 +288,7 @@ def from_hf_weights(
     """Map a HuggingFace DeBERTa-v2/v3 state dict into our pytree.
 
     Accepts ``DebertaV2ForSequenceClassification`` reward models (e.g.
-    the OpenAssistant deberta-v3 RM family, BASELINE config 3): the
+    the OpenAssistant deberta-v3 RM family): the
     ``pooler.dense`` + ``classifier`` head maps onto ``head_dense`` /
     ``head_out``; encoder-only checkpoints load with a random-init head
     (fine-tune via train/).  v3 shares the content projections for the

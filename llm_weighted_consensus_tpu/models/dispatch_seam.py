@@ -22,13 +22,10 @@ Device faults therefore surface at the waiter (readiness is where XLA
 reports them), and the batcher's meshfault triage handles waiter-hop
 exceptions exactly like dispatch-hop ones.
 
-Deliberately jax-free at import time: ``bench_host.py
---overlap-overhead`` measures the seam's pure-Python bookkeeping cost
-against the host p50 budget without pulling jax into the process, and
-test fakes implement a "device" by passing their own ``wait``
-callable.  :func:`wait_device_ready` is the ONE sanctioned blocking
-readiness call on the dispatch path (lint LWC013 allowlists it by
-symbol); everything else must defer.
+Deliberately jax-free at import time: test fakes implement a "device"
+by passing their own ``wait`` callable.  :func:`wait_device_ready` is
+the ONE sanctioned blocking readiness call on the dispatch path (lint
+LWC013 allowlists it by symbol); everything else must defer.
 """
 
 from __future__ import annotations
@@ -94,7 +91,7 @@ _TLS = threading.local()
 
 def active_sink() -> Optional[DispatchSink]:
     """The calling thread's deferred-readiness sink, or None when
-    dispatches should block inline (direct/bench callers)."""
+    dispatches should block inline (direct callers)."""
     return getattr(_TLS, "sink", None)
 
 

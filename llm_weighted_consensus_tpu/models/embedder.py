@@ -201,10 +201,10 @@ class TpuEmbedder:
 
     ``params=None`` random-inits (tests / no local checkpoint); pass a
     pytree from ``bert.from_hf_weights`` for real bge weights.
-    ``parallel.shard_embedder_mesh`` flips the instance into first-class
-    mesh serving (params partitioned by the rule tables, per-(mesh-shape,
-    bucket) AOT executables); the legacy ``parallel.shard_embedder`` hook
-    path still works but forgoes AOT + packing; single-device otherwise.
+    Two placements: one device (as built), or first-class mesh serving
+    once ``parallel.shard_embedder_mesh`` has flipped the instance
+    (params partitioned by the rule tables, per-(mesh-shape, bucket) AOT
+    executables).
     """
 
     def __init__(
@@ -246,12 +246,9 @@ class TpuEmbedder:
 
         self.config, params = resolve_quantize(self.config, params, quantize)
         self.params = params
-        put_batch = lambda ids, mask: (ids, mask)  # mesh hook
-        # marks the hook as the identity default: AOT executables bake
-        # input shardings at lowering time, so replacing the hook (mesh
-        # sharding) disables the AOT fast path (_aot_ready)
-        put_batch._lwc_default = True
-        self.put_batch = put_batch
+        # placement of a lazy-jit dispatch's inputs: the identity on one
+        # device; shard_embedder_mesh replaces it with the dp split
+        self.put_batch = lambda ids, mask: (ids, mask)
         # AOT-compiled executables (aot_warmup) keyed by call signature;
         # the dispatch methods consult this FIRST, so warmed buckets
         # never touch the jit dispatch cache (zero new specializations
@@ -264,21 +261,13 @@ class TpuEmbedder:
         self.aot_store = None
         self._aot_restored = 0
         # batches are padded up to a multiple of this before dispatch so
-        # the dp split divides evenly (shard_embedder sets it to dp)
+        # the dp split divides evenly (shard_embedder_mesh sets it to dp)
         self.batch_multiple = 1
-        # forward-override hook: a shard function (e.g.
-        # parallel.ring.shard_embedder_sp) may replace the whole embedding
-        # forward — (padded ids, mask) -> embeddings — keeping this module
-        # parallelism-agnostic
-        self.embed_override = None
-        # introspection: the sequence-parallel mesh when sp-sharded
-        self.sp_mesh = None
         # first-class mesh serving (parallel.shard_embedder_mesh /
         # MESH_ENABLED): params placed by the partition-rule tables and
-        # dispatches staged with real input shardings.  Unlike the
-        # legacy put_batch hook above, mesh mode KEEPS the AOT fast path
-        # (executables lower with sharded avals, keyed per mesh shape)
-        # and the packed dispatch.
+        # dispatches staged with real input shardings.  Mesh mode keeps
+        # the AOT fast path (executables lower with sharded avals, keyed
+        # per mesh shape) and the packed dispatch.
         self.mesh_mode = False
         self.mesh = None
         self.mesh_shape = None
@@ -312,19 +301,12 @@ class TpuEmbedder:
     # -- AOT bucket precompile ------------------------------------------------
 
     def _aot_ready(self) -> bool:
-        """Whether the AOT fast path is usable: single-device dispatch,
-        or the first-class mesh mode.  Mesh mode lowers with sharded
-        ShapeDtypeStructs, so its executables carry the input shardings
-        a plain-aval lowering doesn't.  The legacy hook paths (put_batch
-        replaced without mesh_mode, embed_override set, or a manual dp
-        batch_multiple) still keep the lazy-jit path."""
-        if self.mesh_mode:
-            return self.embed_override is None
-        return (
-            self.embed_override is None
-            and getattr(self.put_batch, "_lwc_default", False)
-            and self.batch_multiple == 1
-        )
+        """Whether the AOT fast path is usable: the first-class mesh
+        mode, whose executables lower with sharded ShapeDtypeStructs and
+        so carry the input shardings, or one device with unpadded
+        batches (a plain-aval executable knows nothing of rows padded to
+        a hand-set ``batch_multiple``)."""
+        return self.mesh_mode or self.batch_multiple == 1
 
     def _aot_key(self, key: tuple) -> tuple:
         """AOT table key, namespaced per mesh shape in mesh mode: the
@@ -350,11 +332,7 @@ class TpuEmbedder:
         ``mesh_sp``/``ring_sharding``/``_ring_config``).  The batcher
         routes over-length requests here only when this holds; otherwise
         they truncate at ``max_tokens`` exactly as before."""
-        return (
-            self.mesh_mode
-            and self.mesh_sp > 1
-            and self.embed_override is None
-        )
+        return self.mesh_mode and self.mesh_sp > 1
 
     def _stage_batch(self, *arrays):
         """Stage host int32 arrays for an AOT executable call: mesh mode
@@ -380,8 +358,8 @@ class TpuEmbedder:
         a PendingDispatch record hands (label, t0, output) to the
         waiter, which blocks, records the identical numbers, and frees
         this thread to stage the next group.  Without a sink (direct
-        and bench callers) the old block-until-ready bracket runs
-        inline, now also feeding the overlap gauge's interval union."""
+        callers) the block-until-ready bracket runs inline, also
+        feeding the overlap gauge's interval union."""
         sink = active_sink()
         if sink is None and not self.device_timing:
             return fn()
@@ -569,9 +547,7 @@ class TpuEmbedder:
         if not self._aot_ready():
             raise RuntimeError(
                 "AOT warmup needs the single-device embedder or the "
-                "first-class mesh mode (shard_embedder_mesh); legacy "
-                "hook-sharded embedders warm via real dispatches "
-                "(serve/__main__.py)"
+                "first-class mesh mode (shard_embedder_mesh)"
             )
         if self.mesh_mode:
             return self._aot_warmup_mesh(
@@ -833,8 +809,6 @@ class TpuEmbedder:
         pad_b += (-pad_b) % self.batch_multiple  # keep the dp split divisible
         if pad_b != b:
             ids, mask = self._stage_pad(ids, mask, pad_b)
-        if self.embed_override is not None:
-            return np.asarray(self.embed_override(ids, mask)[:b])
         label = f"embed(b={pad_b},s={ids.shape[1]})"
         exe = self._aot_lookup(
             self._aot_key(("embed", pad_b, ids.shape[1])), ids, mask
@@ -977,12 +951,10 @@ class TpuEmbedder:
 
     def supports_packing(self) -> bool:
         """Whether the ragged packed dispatch is usable.  Same gate as
-        the AOT fast path: the packed entry bypasses ``put_batch`` /
-        ``embed_override`` (its layout is not the [B, S] the legacy
-        hooks were built for), so hook-sharded embedders keep the
-        padded paths.  First-class mesh mode packs fine — its dispatch
-        pads the packed row dim to the dp multiple and shards rows like
-        any other batch."""
+        the AOT fast path: the packed entry bypasses ``put_batch``.
+        First-class mesh mode packs fine — its dispatch pads the packed
+        row dim to the dp multiple and shards rows like any other
+        batch."""
         return self._aot_ready()
 
     def tokenize_ragged(
@@ -1282,8 +1254,7 @@ class TpuEmbedder:
         Row assembly is one bulk ``tolist()`` — a single C-level
         device-to-host conversion — instead of a Python ``float(v)``
         call per element; values are identical (``tolist`` applies the
-        same per-element widening ``item()`` conversion).  Before/after
-        numbers live in BENCH_host.json ("embed_assembly")."""
+        same per-element widening ``item()`` conversion)."""
         rows = np.asarray(emb).tolist()
         return CreateEmbeddingResponse(
             object="list",

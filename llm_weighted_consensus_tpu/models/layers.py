@@ -103,8 +103,8 @@ def gelu_erf(x: jax.Array) -> jax.Array:
     f32 inputs always take XLA's exact erf; upcast from bf16 would too
     be exact — but for bf16 activations the erf lowering's ~12-op
     polynomial is the single largest non-matmul cost in the encoder
-    forward (a builder's round-4 profile on another toolchain,
-    bench_fwd.py).  The bf16 path instead uses the Abramowitz-Stegun
+    forward (a builder's profile on another toolchain, not measured
+    on this chip).  The bf16 path instead uses the Abramowitz-Stegun
     7.1.26 erfc form, which rides the TPU's hardware exp: design error
     2.2e-7 absolute (f64), and after bf16 rounding it agrees with the
     exact-erf f32 GELU to <=1 bf16 ulp on ALL finite bf16 inputs

@@ -1,4 +1,4 @@
-"""Served reward-model re-ranking (BASELINE config 3 as a service).
+"""Served reward-model re-ranking (``POST /consensus {"scorer": "rm"}``).
 
 ``TpuReranker`` mirrors ``TpuEmbedder``'s host<->device contract for the
 DeBERTa reward model: tokenize candidates on host (unigram spm for real

@@ -8,7 +8,7 @@ one interface:
   punctuation split, greedy longest-match with ``##`` continuations) given
   a ``vocab.txt``; loads bge vocabularies from local files (no network);
 * ``HashTokenizer``     — deterministic hashing into a fixed vocab so the
-  whole pipeline (tests, CPU mesh, benches without downloaded assets) runs
+  whole pipeline (tests, CPU mesh, drives without downloaded assets) runs
   with identical shapes and padding behavior.
 
 Both pad/truncate to a fixed ``max_length`` and return numpy int32 arrays

@@ -52,7 +52,6 @@ from .quality import (  # noqa: F401
     observe_outcome,
     quality_aggregator,
     quality_snapshot,
-    quality_summary,
     reset_quality,
 )
 from .sink import TraceSink  # noqa: F401

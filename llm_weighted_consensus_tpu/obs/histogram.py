@@ -2,14 +2,13 @@
 
 The old ``serve/metrics.py`` percentile source was a 1024-sample recent
 window: honest for a single process eyeballing /metrics, useless for
-anything that must *aggregate* — dashboards summing replicas, benches
-summing worker processes, phase attributions summing requests.  This is
-the standard fix (Prometheus classic histograms / DDSketch's log
-buckets): a FIXED exponential bucket layout every instance shares, so
+anything that must *aggregate* — dashboards summing replicas, phase
+attributions summing requests.  This is the standard fix (Prometheus
+classic histograms / DDSketch's log buckets): a FIXED exponential
+bucket layout every instance shares, so
 
 * ``observe`` is O(1) — one ``log2``, one index increment, no sorting,
-  no allocation (the hot-path budget ``bench_host.py
-  --metrics-overhead`` enforces);
+  no allocation;
 * two histograms **merge** by adding counts elementwise — cross-replica
   and cross-phase aggregation is exact, not approximate;
 * quantiles carry a *bounded relative error*: with growth

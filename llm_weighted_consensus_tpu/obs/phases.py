@@ -1,9 +1,8 @@
 """Phase-attributed request timing (ISSUE 11 tentpole piece 1).
 
-BENCH_r03's post-mortem had to hand-derive that device time was 35 ms
-of a 145 ms p50; this module makes that split a first-class, always-on
-aggregate.  Every scored request is bracketed through a fixed phase
-vocabulary:
+How a request's time splits between host and device is a first-class,
+always-on aggregate.  Every scored request is bracketed through a fixed
+phase vocabulary:
 
 * ``admission_wait``  — gateway door to admission slot held
 * ``http_parse``      — body parsed and validated (span ``http:parse``)
@@ -34,10 +33,8 @@ Two consumers, two mechanisms:
    mergeable log-bucket histograms (obs/histogram.py).  Global on
    purpose: the sites span the event loop, executor threads and the
    score client, and the phases section must work for harnesses that
-   drive the batcher without a gateway (bench_scaling.py).  The
-   aggregator takes a lock per observe — the executor threads are real
-   writers — and the whole observe stays inside the ≤2% hot-path
-   budget (bench_host.py --metrics-overhead).
+   drive the batcher without a gateway.  The aggregator takes a lock
+   per observe — the executor threads are real writers.
 2. **Per-request breakdown** — ``phase_breakdown(trace)`` re-derives
    the same vocabulary from a finished PR 5 span tree (interval union,
    so R concurrent judge streams attribute wall time once, not R
@@ -57,7 +54,7 @@ from typing import Dict, List, Optional, Tuple
 from .histogram import Histogram
 
 # the phase vocabulary, in request order; the /metrics ``phases``
-# section and the BENCH phase summaries render exactly these keys
+# section renders exactly these keys
 PHASES = (
     "admission_wait",
     "http_parse",

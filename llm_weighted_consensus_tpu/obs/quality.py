@@ -25,10 +25,8 @@ observable — the paper's actual subject.  Every scored request lands one
   degraded / quorum-degraded / all-failed outcome counters.
 
 Aggregation is process-global, lock-guarded and O(judges × choices)
-per request — the same work the tally itself already does — and the
-observe path is held to the existing ≤2% hot-path budget
-(``bench_host.py --quality-overhead``).  Stdlib-only, dependency-free
-below ``utils`` like the rest of ``obs/``.
+per request — the same work the tally itself already does.
+Stdlib-only, dependency-free below ``utils`` like the rest of ``obs/``.
 """
 
 from __future__ import annotations
@@ -282,7 +280,7 @@ class QualityAggregator:
     """Process-global consensus-quality aggregates.
 
     Lock-guarded like ``PhaseAggregator``: the tally seam runs on the
-    event loop today, but benches drive ``ScoreClient`` from plain
+    event loop today, but a caller may drive ``ScoreClient`` from plain
     threads and the read side (metrics renderer, /v1/judges) must never
     race an observe."""
 
@@ -481,38 +479,6 @@ class QualityAggregator:
         }
         return out
 
-    def summary(self) -> dict:
-        """Compact consensus-quality summary for BENCH records: the
-        three numbers a bench reader wants next to a latency figure."""
-        with self._lock:
-            requests = self._requests
-            degraded = self._outcomes.get("degraded", 0)
-            median = self._margin.quantile(0.5)
-            threshold = self.drift_threshold
-            rates = [
-                card.agreements / card.voted
-                for card in self._judges.values()
-                if card.voted
-            ]
-            flagged = [
-                card.model
-                for card in self._judges.values()
-                if card.drift(threshold)["flagged"]
-            ]
-        return {
-            "requests": requests,
-            "degraded_rate": (
-                round(degraded / requests, 4) if requests else None
-            ),
-            "median_confidence_margin": (
-                round(median, 4) if median is not None else None
-            ),
-            "judge_agreement_spread": (
-                round(max(rates) - min(rates), 4) if rates else None
-            ),
-            "flagged_judges": sorted(flagged),
-        }
-
     def prom_snapshot(self) -> dict:
         """Cloned margin histogram + flat per-judge gauges for the
         Prometheus renderer — clones and plain floats, so rendering
@@ -557,10 +523,6 @@ def observe_outcome(outcome: Outcome) -> None:
 
 def quality_snapshot() -> dict:
     return _AGG.snapshot()
-
-
-def quality_summary() -> dict:
-    return _AGG.summary()
 
 
 def configure_quality(

@@ -57,7 +57,7 @@ def judge_confidence(votes: jax.Array, confidence: jax.Array) -> jax.Array:
     )
 
 
-# Batched forms for archive re-scoring (BASELINE config 4): one pjit/vmap
+# Batched forms for archive re-scoring: one pjit/vmap
 # over a [B, M, N] vote tensor re-scores B archived requests at once.
 _tally_batch = jax.jit(jax.vmap(tally, in_axes=(0, 0, 0)))
 judge_confidence_batch = jax.jit(jax.vmap(judge_confidence))
@@ -77,7 +77,7 @@ def incremental_tally(
     new_weight: jax.Array,
 ):
     """Streaming update: fold one completed judge vote into the running
-    tally (BASELINE config 5 — incremental on-device consensus).
+    tally (incremental on-device consensus).
 
     Recomputes confidence after each completed vote without re-reducing the
     full vote matrix: O(N) per update.

@@ -4,11 +4,11 @@ Pure TPU territory (SURVEY §3.5 item 4): the reference keeps its trained
 weight path behind the ``weight::Fetcher`` seam and does no tensor math;
 here the embedding consensus becomes real device kernels:
 
-* ``cosine_consensus_vote`` — self-consistency scoring (BASELINE config 1):
+* ``cosine_consensus_vote`` — self-consistency scoring:
   each candidate's confidence is the softmax of its mean cosine similarity
   to all other candidates (centroid agreement);
 * ``top_k_similar`` — training-table lookup: nearest archived prompts per
-  judge (BASELINE config 3 / trained weights);
+  judge (trained weights);
 * all matmuls bf16-in/f32-accumulate for the MXU.
 """
 

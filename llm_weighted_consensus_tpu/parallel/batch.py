@@ -1,4 +1,4 @@
-"""Archive batch re-scoring over the mesh (BASELINE config 4).
+"""Archive batch re-scoring over the mesh.
 
 The checkpoint/resume analog of the reference is its completions archive
 (SURVEY §5); re-scoring 10k archived score requests is a single dp-sharded

@@ -229,53 +229,3 @@ def ring_embed(
             pooling,
             normalize,
         )
-
-
-def shard_embedder_sp(
-    embedder, mesh: Mesh, sp_axis: str = "sp", dp_axis=None
-) -> None:
-    """Wire a ``TpuEmbedder`` for sequence-parallel serving: its embedding
-    forward is replaced (``embed_override`` hook) by ``ring_embed`` over
-    ``mesh``, sequences padded to an sp multiple — enabling long-context
-    inputs whose attention would not fit one device.  Consensus-vote fused
-    paths keep their single-device dispatch (self-consistency candidates
-    are short by contract); this serves the /embeddings + trained-weights
-    lookup paths."""
-    import dataclasses
-
-    import numpy as np
-
-    sp = mesh.shape[sp_axis]
-    # batches pad to a dp multiple (same contract as shard_embedder)
-    embedder.batch_multiple = mesh.shape[dp_axis] if dp_axis else 1
-    from ..models.configs import usable_positions
-
-    # the sequence pads to an sp multiple before dispatch; cap the token
-    # window so padding can never push past the position table
-    embedder.max_tokens = min(
-        embedder.max_tokens,
-        (usable_positions(embedder.config) // sp) * sp,
-    )
-    ring_config = dataclasses.replace(
-        embedder.config, attention_impl="ring", ring_axis=sp_axis
-    )
-
-    def forward(ids, mask):
-        pad_s = (-ids.shape[1]) % sp
-        if pad_s:  # pads are masked keys — attention ignores them
-            ids = np.pad(ids, ((0, 0), (0, pad_s)))
-            mask = np.pad(mask, ((0, 0), (0, pad_s)))
-        return ring_embed(
-            embedder.params,
-            ids,
-            mask,
-            ring_config,
-            mesh,
-            sp_axis=sp_axis,
-            dp_axis=dp_axis,
-            pooling=embedder.pooling,
-            normalize=True,
-        )
-
-    embedder.embed_override = forward
-    embedder.sp_mesh = mesh  # introspection (tests, config dumps)

@@ -295,40 +295,17 @@ def gspmd_config(config):
     return dataclasses.replace(config, **changes) if changes else config
 
 
-def shard_embedder(embedder, mesh: Mesh, tp: bool = False) -> None:
-    """Wire a models.embedder.TpuEmbedder onto a mesh: params placed
-    (replicated or TP), batches split over ``dp`` via its put_batch hook.
-
-    Setting ``embedder.batch_multiple = dp`` makes the embedder pad every
-    dispatch to a dp multiple, so the split always divides; the replicated
-    fallback below is a safety net for direct put_batch callers only.
-    """
-    embedder.params = shard_bert_params(embedder.params, mesh, tp=tp)
-    embedder.config = gspmd_config(embedder.config)
-    b_sharding = batch_sharding(mesh)
-    repl = replicated(mesh)
-    dp = mesh.shape.get("dp", 1)
-
-    def put_batch(ids, mask):
-        s = b_sharding if ids.shape[0] % dp == 0 else repl
-        return jax.device_put(ids, s), jax.device_put(mask, s)
-
-    embedder.put_batch = put_batch
-    embedder.batch_multiple = dp
-    embedder.mesh = mesh
-
-
 def shard_embedder_mesh(embedder, mesh: Mesh) -> None:
     """First-class mesh serving (``MESH_ENABLED``, serve/config.py).
 
     Params are placed once at load by the partition-rule tables (batch
     rows over ``dp``, encoder kernels Megatron-split over ``tp``),
-    dispatch inputs get real NamedShardings instead of the legacy
-    put_batch replicate/split heuristic, and the embedder flips into
+    dispatch inputs get real NamedShardings, and the embedder flips into
     mesh mode so its AOT table lowers per-(mesh-shape, bucket)
     executables with the input shardings baked in (models/embedder.py).
-    Unlike ``shard_embedder`` (the hook path above, which disables AOT
-    and packing), mesh mode keeps both.
+    ``batch_multiple = dp`` makes the embedder pad every dispatch to a dp
+    multiple, so the split always divides; ``put_batch``'s replicated
+    fallback is a safety net for direct callers only.
 
     With an ``sp`` axis on the mesh (``MESH_SHAPE=dp,tp,sp``), the dense
     dispatch path is UNCHANGED — same shardings, same batch_multiple,
@@ -369,7 +346,7 @@ def shard_embedder_mesh(embedder, mesh: Mesh) -> None:
 
         embedder.ring_sharding = ring_batch_sharding(mesh)
         # ring sequences pad to an sp multiple; cap so padding can never
-        # push past the position table (same contract as shard_embedder_sp)
+        # push past the position table
         embedder.ring_max_tokens = (
             usable_positions(embedder.config) // sp
         ) * sp

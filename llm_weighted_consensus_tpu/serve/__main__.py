@@ -33,8 +33,8 @@ TABLES_KEY: web.AppKey = web.AppKey("tables", object)
 
 def _rescore_handler(store, lock, mesh=None):
     """POST /archive/rescore: re-tally archived score completions on device
-    (BASELINE config 4 as a service operation), dp-sharded when the
-    service has a mesh.
+    (``archive/rescore.py`` as a service operation), dp-sharded when
+    the service has a mesh.
 
     Body (all optional): {"weight_overrides": {judge id: weight},
     "ids": [completion ids], "revote": bool (re-extract soft votes from
@@ -185,9 +185,8 @@ async def _fake_upstream(request: web.Request) -> web.StreamResponse:
 
     ``FAKE_UPSTREAM_DELAY_MS`` (process env, read per request) adds a
     judge-latency sleep before the first frame, so load/drain scenarios
-    (bench_http.py --overload, the chaos SIGTERM drill) exercise requests
-    that HOLD their admission slot for a realistic interval instead of
-    completing in microseconds."""
+    (the chaos SIGTERM drill) exercise requests that HOLD their admission
+    slot for a realistic interval instead of completing in microseconds."""
     import os
 
     delay_ms = float(os.environ.get("FAKE_UPSTREAM_DELAY_MS", "0") or 0.0)
@@ -257,8 +256,7 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
     MESH_ENABLED it serves in first-class mesh mode — params placed once
     by the partition-rule tables, batches sharded over dp, encoder params
     Megatron-split over tp, per-(mesh-shape, bucket) AOT executables
-    (parallel/sharding.py shard_embedder_mesh); the legacy MESH_DP /
-    MESH_TP knobs keep the older put_batch hook path.
+    (parallel/sharding.py shard_embedder_mesh); without it, one device.
 
     Serving synthetic state — random-init weights (no EMBEDDER_WEIGHTS) or
     the hash tokenizer (no real vocab) — produces embeddings that LOOK
@@ -288,17 +286,6 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
         )
         if not vocab_path:
             vocab_path = find_vocab(config.embedder_weights)
-    max_tokens = config.embedder_max_tokens
-    if max_tokens is None:
-        # MESH_SP exists to serve long inputs — defaulting to 512 would
-        # silently truncate exactly the documents it's configured for
-        from ..models.configs import usable_positions
-
-        max_tokens = (
-            usable_positions(PRESETS[config.embedder_model])
-            if config.mesh_sp is not None
-            else 512
-        )
     embedder = TpuEmbedder(
         config.embedder_model,
         params=params,
@@ -313,7 +300,7 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
             if vocab_path
             else None
         ),
-        max_tokens=max_tokens,
+        max_tokens=config.embedder_max_tokens,
         quantize=config.embedder_quantize,
     )
     from ..models.tokenizer import HashTokenizer
@@ -353,8 +340,11 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
         from ..parallel.mesh import make_mesh
         from ..parallel.sharding import shard_embedder_mesh
 
-        # host-local mesh, same rationale as the legacy branch below;
-        # MESH_SHAPE unset = every local device on dp (tp=1)
+        # the serving mesh is HOST-LOCAL: a request lands on one host and
+        # must be executable without the other hosts' cooperation (they
+        # serve their own traffic).  Single-host: local == global.  See
+        # DESIGN.md §multi-host.  MESH_SHAPE unset = every local device
+        # on dp (tp=1)
         shape = config.mesh_shape
         mesh = make_mesh(
             dp=shape[0] if shape else None,
@@ -363,45 +353,6 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
             devices=jax.local_devices(),
         )
         shard_embedder_mesh(embedder, mesh)
-    elif config.mesh_sp is not None:
-        import jax
-
-        from ..parallel.mesh import make_mesh
-        from ..parallel.ring import shard_embedder_sp
-
-        if config.mesh_tp > 1:
-            raise ValueError(
-                "MESH_SP and MESH_TP are mutually exclusive (sequence "
-                "parallelism replicates encoder params)"
-            )
-        # MESH_DP unset = auto-fill (every device not consumed by sp),
-        # matching the documented dp/tp semantics
-        mesh = make_mesh(
-            dp=config.mesh_dp,
-            tp=config.mesh_sp,
-            devices=jax.local_devices(),
-            names=("dp", "sp"),
-        )
-        dp = mesh.shape["dp"]
-        shard_embedder_sp(
-            embedder, mesh, dp_axis="dp" if dp > 1 else None
-        )
-    elif config.mesh_dp is not None or config.mesh_tp > 1:
-        import jax
-
-        from ..parallel.mesh import make_mesh
-        from ..parallel.sharding import shard_embedder
-
-        # the serving mesh is HOST-LOCAL: a request lands on one host and
-        # must be executable without the other hosts' cooperation (they
-        # serve their own traffic).  Single-host: local == global.  See
-        # DESIGN.md §multi-host.
-        mesh = make_mesh(
-            dp=config.mesh_dp,
-            tp=config.mesh_tp,
-            devices=jax.local_devices(),
-        )
-        shard_embedder(embedder, mesh, tp=config.mesh_tp > 1)
     return embedder
 
 
@@ -679,9 +630,8 @@ def _warmup_embedder(
     table — zero jit specializations after startup.  First-class mesh
     embedders (MESH_ENABLED) take the AOT branch too: their buckets
     lower with sharded avals into per-(mesh-shape, bucket) executables.
-    Only the legacy hook-sharded embedders (MESH_DP/MESH_TP/MESH_SP)
-    fall back to the dispatch loop below (the plain-aval AOT lowering
-    doesn't carry their input shardings).
+    The dispatch loop below stays for ``WARMUP_AOT=0`` alone: it warms
+    the lazy-jit path by running each shape once.
 
     ``packed_buckets`` ((B, L, K) triples, wired from the PACKING_*
     knobs) additionally warms the continuous-batching entry
@@ -707,7 +657,7 @@ def _warmup_embedder(
             (n, _seq_bucket(s, embedder.max_tokens)) for n, s in specs
         )
     )
-    if aot and embedder._aot_ready():
+    if aot:
         for label, dt in embedder.aot_warmup(
             snapped,
             r_buckets,
@@ -764,9 +714,6 @@ def _build_cpu_fallback(config: Config, fake_upstream: bool):
             fallback = build_embedder(
                 dataclasses.replace(
                     config,
-                    mesh_dp=None,
-                    mesh_tp=1,
-                    mesh_sp=None,
                     mesh_enabled=False,
                     mesh_shape=None,
                     embedder_quantize="none",
@@ -982,7 +929,7 @@ def build_service(
             "mesh fault ladder: %s",
             " -> ".join(f"{d}x{t}" for d, t in meshfault.build_ladder()),
         )
-        if config.warmup and config.warmup_aot and embedder._aot_ready():
+        if config.warmup and config.warmup_aot:
             from ..models.embedder import _seq_bucket
 
             snapped = list(
@@ -1429,7 +1376,6 @@ def build_service(
         admission=admission,
         lifecycle=lifecycle,
         watchdog=watchdog,
-        meshfault=meshfault,
         trace_sink=trace_sink,
         ledger=ledger,
         fleet=fleet,
@@ -1453,10 +1399,8 @@ def build_service(
         _rescore_handler(
             store,
             archive_lock,
-            # MESH_SP serving exposes sp_mesh, dp/tp serving exposes mesh;
-            # the batched tally shards over every axis of either
-            mesh=getattr(embedder, "mesh", None)
-            or getattr(embedder, "sp_mesh", None),
+            # the batched tally shards over every axis of the serving mesh
+            mesh=getattr(embedder, "mesh", None),
         ),
     )
     if tables is not None:

@@ -185,8 +185,7 @@ class DeviceBatcher:
         # (serve/packing.py) instead of the per-kind padded buckets;
         # opt-in — the padded path stays the default contract.  Works on
         # the single-device embedder AND the first-class mesh mode (its
-        # packed dispatch dp-pads the row dim); only the legacy
-        # hook-sharded embedders decline (supports_packing).
+        # packed dispatch dp-pads the row dim).
         self.packing = bool(packing) and bool(
             getattr(embedder, "supports_packing", lambda: False)()
         )
@@ -1726,10 +1725,10 @@ class DeviceBatcher:
             getattr(embedder, "embed_packed", None) is not None
             and getattr(embedder, "supports_packing", lambda: False)()
         ):
-            # e.g. the CPU-fallback or a legacy hook-sharded embedder
-            # mid-swap: serve every item through its padded path, one by
-            # one (first-class mesh embedders pack fine and never land
-            # here)
+            # e.g. an embedder double without the packed entry (a test
+            # fake, a fallback mid-swap): serve every item through its
+            # padded path, one by one (first-class mesh embedders pack
+            # fine and never land here)
             staged = [
                 self._packed_item_fallback(item, embedder)
                 for item in group

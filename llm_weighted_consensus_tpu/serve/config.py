@@ -22,25 +22,15 @@ TPU additions:
   matmul + dequant/bias/GELU epilogue in one kernel — ops/kernels.py).
   ``int8-pallas`` / ``int8-xla`` pin the kernel vs the XLA dot_general
   fallback (debugging).  Default ``none``.
-* ``EMBEDDER_MAX_TOKENS`` — truncation window.  Default: the model's full
-  position table under ``MESH_SP`` (long-context serving must not silently
-  truncate), else 512.
-* ``MESH_DP`` / ``MESH_TP`` — serve the embedder over a (dp, tp) device
-  mesh: batches shard over ``dp``, encoder params Megatron-split over
-  ``tp`` (parallel/sharding.py).  Unset = single device.  ``MESH_DP``
-  empty + ``MESH_TP=n`` uses every device not consumed by tp for dp.
-* ``MESH_SP`` — sequence parallelism: embedding forwards run as ring
-  attention over an sp-way mesh (parallel/ring.py), enabling long-context
-  inputs (e.g. ``EMBEDDER_MODEL=bert-long-8k``).  Combines with
-  ``MESH_DP`` (batch x sequence grid); mutually exclusive with
-  ``MESH_TP``.
+* ``EMBEDDER_MAX_TOKENS`` — truncation window of the dense dispatch.
+  Default 512.
 * ``MESH_ENABLED`` — first-class mesh serving: embed and consensus
   dispatches run on a (dp, tp) ICI mesh with params placed once by the
-  partition-rule tables, real input shardings on every dispatch, and
-  per-(mesh-shape, bucket) AOT executables — AOT warmup and packing stay
-  available, unlike the legacy ``MESH_DP``/``MESH_TP`` hook path, which
-  this mode supersedes (mutually exclusive with it and with ``MESH_SP``).
-  Off by default: unset leaves the single-device path untouched.
+  partition-rule tables (batches shard over ``dp``, encoder params
+  Megatron-split over ``tp``, parallel/sharding.py), real input
+  shardings on every dispatch, and per-(mesh-shape, bucket) AOT
+  executables; AOT warmup and packing stay available.  Off by default:
+  unset is one device.
 * ``MESH_SHAPE`` — the mesh layout for ``MESH_ENABLED`` as ``DPxTP``
   (e.g. ``4x2`` = batches split 4-way, encoder params 2-way) or
   ``DPxTPxSP`` (e.g. ``2x2x2`` adds a 2-way sequence-parallel axis:
@@ -79,10 +69,10 @@ TPU additions:
   ``/profile/start`` keeps the profiler's defaults.
 * ``RM_MODEL`` / ``RM_WEIGHTS`` / ``RM_VOCAB`` / ``RM_MAX_TOKENS`` /
   ``RM_QUANTIZE`` (``int8`` = W8A8 RM serving, default ``none``) — a
-  DeBERTa reward model serving ``POST /consensus {"scorer": "rm"}``
-  (BASELINE config 3 as a service): candidates re-rank by
-  softmax(reward).  Same synthetic-params gate as the embedder; real
-  checkpoints load from HF DeBERTa-v2/v3 snapshots or orbax dirs.
+  DeBERTa reward model serving ``POST /consensus {"scorer": "rm"}``:
+  candidates re-rank by softmax(reward).  Same synthetic-params gate as
+  the embedder; real checkpoints load from HF DeBERTa-v2/v3 snapshots or
+  orbax dirs.
 * ``JUDGE_MODEL`` / ``JUDGE_WEIGHTS`` / ``JUDGE_VOCAB`` /
   ``JUDGE_MAX_TOKENS`` / ``JUDGE_QUANTIZE`` — a causal sparse-expert
   latent-attention decoder (``glm-4.7-flash``; models/glm_moe.py) serving
@@ -709,6 +699,21 @@ def _parse_mesh_shape(raw) -> Optional[tuple]:
     return tuple(parts)
 
 
+# names this program once read and no longer does: a deployment that
+# still sets one must hear of it at start-up, not fall to one device
+_REMOVED_NAMES = ("MESH_DP", "MESH_TP", "MESH_SP")
+
+
+def _refuse_removed_names(env: dict) -> None:
+    for name in _REMOVED_NAMES:
+        if env.get(name):
+            raise ValueError(
+                f"{name} is no longer read: a mesh is configured with "
+                "MESH_ENABLED=1 MESH_SHAPE=DPxTP[xSP] (e.g. 4x2, or 2x1x4 "
+                "for 4-way sequence parallelism)"
+            )
+
+
 def _parse_peer_list(raw) -> list:
     """"http://a:5000, http://b:5000" -> normalized URL list (trailing
     slashes stripped, empties dropped)."""
@@ -772,7 +777,7 @@ class Config:
     embedder_model: Optional[str] = None  # e.g. "bge-small-en"
     embedder_weights: Optional[str] = None  # local checkpoint path
     embedder_vocab: Optional[str] = None  # path to vocab.txt
-    embedder_max_tokens: Optional[int] = None  # None = context-aware default
+    embedder_max_tokens: int = 512
     embedder_quantize: str = "none"  # "int8" = W8A8 serving (models/quant.py)
     # reward-model re-ranking service (POST /consensus {"scorer": "rm"})
     rm_model: Optional[str] = None  # e.g. "deberta-v3-base"
@@ -786,9 +791,6 @@ class Config:
     judge_vocab: Optional[str] = None  # spm.model / vocab.txt
     judge_max_tokens: int = 8192  # the one sequence bucket of a call
     judge_quantize: str = "none"  # "int8" = W8A8 dense products
-    mesh_dp: Optional[int] = None
-    mesh_tp: int = 1
-    mesh_sp: Optional[int] = None
     # first-class mesh serving (parallel/sharding.py shard_embedder_mesh):
     # off by default = the single-device path bit-for-bit
     mesh_enabled: bool = False
@@ -971,6 +973,7 @@ class Config:
     @classmethod
     def from_env(cls, env: Optional[dict] = None) -> "Config":
         env = dict(os.environ if env is None else env)
+        _refuse_removed_names(env)
 
         def get_f(name, default):
             return float(env.get(name, default))
@@ -1014,11 +1017,7 @@ class Config:
             embedder_model=env.get("EMBEDDER_MODEL"),
             embedder_weights=env.get("EMBEDDER_WEIGHTS"),
             embedder_vocab=env.get("EMBEDDER_VOCAB"),
-            embedder_max_tokens=(
-                int(env["EMBEDDER_MAX_TOKENS"])
-                if env.get("EMBEDDER_MAX_TOKENS")
-                else None
-            ),
+            embedder_max_tokens=int(env.get("EMBEDDER_MAX_TOKENS") or 512),
             embedder_quantize=env.get("EMBEDDER_QUANTIZE") or "none",
             rm_model=env.get("RM_MODEL"),
             rm_weights=env.get("RM_WEIGHTS"),
@@ -1030,9 +1029,6 @@ class Config:
             judge_vocab=env.get("JUDGE_VOCAB") or None,
             judge_max_tokens=int(env.get("JUDGE_MAX_TOKENS", 8192)),
             judge_quantize=env.get("JUDGE_QUANTIZE") or "none",
-            mesh_dp=int(env["MESH_DP"]) if env.get("MESH_DP") else None,
-            mesh_tp=int(env.get("MESH_TP", 1)),
-            mesh_sp=int(env["MESH_SP"]) if env.get("MESH_SP") else None,
             mesh_enabled=env_truthy(env.get("MESH_ENABLED", "0")),
             mesh_shape=_parse_mesh_shape(env.get("MESH_SHAPE")),
             long_context_warmup=_parse_long_context_warmup(
@@ -1260,16 +1256,6 @@ class Config:
                 "MESH_SHAPE is set but MESH_ENABLED is not: the shape only "
                 "configures the first-class mesh mode (set MESH_ENABLED=1 "
                 "MESH_SHAPE=4x2)"
-            )
-        if config.mesh_enabled and (
-            config.mesh_dp is not None
-            or config.mesh_tp > 1
-            or config.mesh_sp is not None
-        ):
-            raise ValueError(
-                "MESH_ENABLED is mutually exclusive with the legacy "
-                "MESH_DP/MESH_TP/MESH_SP hooks: the first-class mesh mode "
-                "supersedes them (use MESH_SHAPE=DPxTP)"
             )
         if config.long_context_warmup and (
             config.mesh_shape is None or len(config.mesh_shape) != 3

@@ -42,12 +42,8 @@ from ..types.score_request import ChatCompletionCreateParams as ScoreParams
 from ..utils import jsonutil
 
 METRICS_KEY: web.AppKey = web.AppKey("metrics", Metrics)
-# the serving micro-batcher (present when an embedder is configured)
-BATCHER_KEY: web.AppKey = web.AppKey("batcher", object)
 # the drain/readiness state machine (serve/lifecycle.py), when wired
 LIFECYCLE_KEY: web.AppKey = web.AppKey("lifecycle", object)
-# the mesh fault-domain manager (resilience/meshfault.py), when wired
-MESHFAULT_KEY: web.AppKey = web.AppKey("meshfault", object)
 
 DONE = b"data: [DONE]\n\n"
 SSE_HEADERS = {
@@ -441,8 +437,8 @@ async def _weights_disabled(request: web.Request) -> web.Response:
 def _offline_rescore_handler(batcher, default_inflight: int = 4):
     """POST /v1/train/rescore: saturate the offline priority class with
     deterministic synthetic candidate groups and report the lane stats —
-    the HTTP face of ``python -m ...train rescore`` the bench drill
-    drives concurrently with latency traffic.
+    the HTTP face of ``python -m ...train rescore``, to be driven
+    concurrently with latency traffic.
 
     Body (all optional): ``{"groups": int, "n": int, "seed": int,
     "inflight": int, "temperature": float}``.  Runs the drive to
@@ -815,7 +811,6 @@ def build_app(
     admission=None,
     lifecycle=None,
     watchdog=None,
-    meshfault=None,
     trace_sink=None,
     ledger=None,
     fleet=None,
@@ -925,10 +920,7 @@ def build_app(
         metrics.register_provider("fleet", fleet.stats)
     if lifecycle is not None:
         app[LIFECYCLE_KEY] = lifecycle
-    if meshfault is not None:
-        app[MESHFAULT_KEY] = meshfault
     if batcher is not None:
-        app[BATCHER_KEY] = batcher
 
         async def _close_batcher(app):
             batcher.close()
@@ -982,7 +974,7 @@ def build_app(
     async def metrics_handler(request):
         # ?format=prometheus flips the same data into OpenMetrics text
         # (histogram families + exemplars); the default JSON snapshot
-        # keeps its PR 5 shape for existing scrapers and the bench tools
+        # keeps its PR 5 shape for existing scrapers
         if request.query.get("format") == "prometheus":
             return web.Response(
                 body=render_prometheus(metrics).encode("utf-8"),
@@ -1047,10 +1039,10 @@ def _consensus_handler(
 
     Three scorers: ``"cosine"`` (default) is the embedding self-consistency
     vote (one fused embed+vote dispatch; concurrent requests coalesce via
-    the micro-batcher — the HTTP analog of the headline bench path);
+    the micro-batcher — the path the benchmark's encoder cells time);
     ``"rm"`` re-ranks by reward model: softmax(reward/T) over the
-    candidates, each scored against the optional ``prompt`` (BASELINE
-    config 3 as a service); ``"judge"`` is a LOCAL judge panel
+    candidates, each scored against the optional ``prompt``;
+    ``"judge"`` is a LOCAL judge panel
     (models/judge.py): ``panel`` calls (default three, weights 1) of one
     causal decoder read the candidates under differently seeded
     prefix-tree ballots, each call's vote is the softmax over the ballot's

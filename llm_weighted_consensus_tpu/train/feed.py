@@ -79,7 +79,7 @@ def archive_groups(store, ids: Optional[list] = None) -> Iterable[list]:
 
 
 def synthetic_groups(n_groups: int, n_choices: int, seed: int = 0) -> list:
-    """Deterministic word-salad candidate groups for drills/benches:
+    """Deterministic word-salad candidate groups for drills:
     saturating the offline lane must not depend on a populated archive."""
     words = (
         "alpha bravo charlie delta echo foxtrot golf hotel india juliet "

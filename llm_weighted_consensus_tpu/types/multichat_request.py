@@ -30,5 +30,5 @@ class ChatCompletionCreateParams(Struct):
     usage: Optional[UsageInclude] = field(UsageInclude, default=None)
     # extension (no reference analog): when true and the gateway has an
     # embedder, interleave live ``multichat.consensus`` frames as candidates
-    # finish (BASELINE config 5 — streaming incremental consensus)
+    # finish (streaming incremental consensus)
     consensus: Optional[bool] = field(bool, default=None)

@@ -26,7 +26,7 @@ def next_pow2(n: int) -> int:
 def device_summary() -> dict:
     """What this process computes on, as JAX reports it — the three
     fields every record that could be mistaken for a device measurement
-    carries (server /metrics, chip_smoke.py, the bench scripts)."""
+    carries (server /metrics, chip_smoke.py)."""
     import jax
 
     devices = jax.devices()

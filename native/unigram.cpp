@@ -1,6 +1,6 @@
 // Native unigram (SentencePiece) tokenizer — the ASCII fast path of
 // models/spm.py::UnigramTokenizer (host-side hot loop: spm tokenization is
-// inside the config-3 bench timed path and the bge-m3 serving path, where
+// inside the reranker's and the bge-m3 serving path, where
 // inputs run to 8k tokens).
 //
 // Scope: exact parity with the Python implementation for pure-ASCII input:

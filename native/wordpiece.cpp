@@ -1,6 +1,6 @@
 // Native WordPiece tokenizer — the ASCII fast path of
 // models/tokenizer.py::WordPieceTokenizer (host-side hot loop: tokenization
-// is inside the serving/bench timed path).
+// is inside the serving path's timed part).
 //
 // Scope: byte-for-byte parity with the Python implementation for pure-ASCII
 // input (lowercase, whitespace/punctuation split, greedy longest-match with

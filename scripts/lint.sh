@@ -10,7 +10,7 @@ cd "$(dirname "$0")/.."
 
 rc=0
 if command -v ruff >/dev/null 2>&1; then
-  ruff check llm_weighted_consensus_tpu tests bench.py bench_host.py || rc=$?
+  ruff check llm_weighted_consensus_tpu tests || rc=$?
 else
   echo "lint.sh: ruff not installed; skipping generic lint" \
        "(first-party invariant checker still runs)" >&2

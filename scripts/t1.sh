@@ -1,83 +1,5 @@
 #!/usr/bin/env bash
-# Tier-1 verify — the ROADMAP.md command, verbatim, so every session and CI
-# hook runs the IDENTICAL gate (same markers, same plugins disabled, same
-# timeout, same DOTS_PASSED accounting).  Run from the repo root.
-set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c); \
-# obs/ tracing tests, explicitly: the glob above already collects them, but
-# this names the file so a collection error there can never pass silently.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_obs.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_obs=$?; [ $rc -eq 0 ] && rc=$rc_obs; \
-# mesh serving tests, explicitly: the dp×tp gateway path (parity, AOT
-# zero-growth, deadline/watchdog/drain, MESH_ENABLED-off identity) must
-# fail tier-1 by name even if collection of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_mesh_serving.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_mesh_t=$?; [ $rc -eq 0 ] && rc=$rc_mesh_t; \
-# mesh fault-domain tests, explicitly: the degraded-mesh serving path
-# (classification, downsize ladder, re-dispatch, admission rescale,
-# recovery, the seeded acceptance drill) must fail tier-1 by name even
-# if collection of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_meshfault.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_mf=$?; [ $rc -eq 0 ] && rc=$rc_mf; \
-# long-context serving tests, explicitly: the sequence-parallel ring
-# path (ring-vs-dense parity across sp and quantization, the sp-bearing
-# downsize drill, the MESH_SHAPE-without-sp byte-identical contract,
-# the over-length batcher e2e) must fail tier-1 by name even if
-# collection of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_longcontext.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_lc=$?; [ $rc -eq 0 ] && rc=$rc_lc; \
-# consensus-quality tests, explicitly: scorecards/kappa/drift, the outcome
-# ledger, the JUDGE_BIAS_PLAN drill, and the ledger→training round trip
-# must fail tier-1 by name even if collection of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_quality.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_q=$?; [ $rc -eq 0 ] && rc=$rc_q; \
-# host<->device overlap tests, explicitly: the deferred-readiness seam
-# (waiter-vs-bracket device-time parity, the slow-fake-device pipelining
-# drill, the overlap gauge, staging-pool recycling) must fail tier-1 by
-# name even if collection of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_perfobs.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_po=$?; [ $rc -eq 0 ] && rc=$rc_po; \
-# host fast-path tests, explicitly: splice-frame byte identity across
-# lanes (seeded orders, degraded frames, per-judge errors, the Decimal
-# exponent-drift cache hazard), Decimal<->fixed-point tally parity on
-# pathological weights, merge_streams no-task-churn, and the streamed
-# fingerprint digest parity must fail tier-1 by name even if collection
-# of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_host_fastpath.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_hf=$?; [ $rc -eq 0 ] && rc=$rc_hf; \
-# host-path perf budget gate: bench_host.py --hostpath measures the
-# fast lane's per-phase p50s (ingest/merge/tally/encode + per-chunk
-# composite) at J=8 x N=64 and fails when any phase exceeds the
-# committed analysis/host_budgets.json budget x band x machine_scale
-# (a >=25% host-path regression; the machine-speed canary re-prices
-# the limits when shared-host throttling slows the whole box).
-# Re-baseline with --write-budgets (DESIGN.md "Host fast path").
-timeout -k 10 300 env JAX_PLATFORMS=cpu python bench_host.py --hostpath > /tmp/_t1_hostpath.json; rc_hp=$?; [ $rc -eq 0 ] && rc=$rc_hp; \
-# hostile-ingest + memory-governor tests, explicitly: the byte-budget
-# plane (parser cap trips against the committed corpus, the four
-# hostile fault kinds, cap x breaker/hedge/quorum composition, the
-# seeded J=8 x N=64 bounded-RSS gateway drill) and the MemGuard drills
-# (soft shrink, hard 503 shed_reason=memory, hysteretic recovery,
-# degraded_mem on /readyz) must fail tier-1 by name even if collection
-# of the glob above breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_hostile_ingest.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_hi=$?; [ $rc -eq 0 ] && rc=$rc_hi; \
-# ingest-bounds perf gate: bench_host.py --ingest-bounds measures the
-# per-chunk cost of the SSE byte accounting (capped parser vs uncapped)
-# on a realistic judge stream and fails when the overhead exceeds 2% of
-# the host-path per-chunk p50 — the budget plane must stay effectively
-# free on the hot loop.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python bench_host.py --ingest-bounds > /tmp/_t1_ingest.json; rc_ib=$?; [ $rc -eq 0 ] && rc=$rc_ib; \
-# offline-lane + weight-learner tests, explicitly: the priority-class
-# scheduler (latency-first planning, shed exemption, lane occupancy),
-# ledger shard rotation, the miscalibrated-panel learner drill (fitted
-# accuracy beats the observed base weights on held-out records), and
-# the /v1/weights hot-swap drill (version flip mid-traffic, zero client
-# errors) must fail tier-1 by name even if the glob's collection breaks.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/test_train.py -q -p no:cacheprovider -p no:xdist -p no:randomly; rc_tr=$?; [ $rc -eq 0 ] && rc=$rc_tr; \
-# analysis gate, explicitly: tests/test_analysis.py runs the same checker
-# under pytest, but naming the CLI here means a lint finding, a jaxpr
-# serving-path regression, or a mesh-audit failure (sharding coverage /
-# collective plan / resource budgets) fails tier-1 even if test
-# collection breaks.  ANALYSIS_SKIP_MESH=1 is the escape hatch for
-# hosts where the 8-virtual-device respawn can't run; the pytest
-# invocation above is unchanged either way.
-timeout -k 10 300 env JAX_PLATFORMS=cpu python -m llm_weighted_consensus_tpu.analysis --no-mesh; rc_an=$?; [ $rc -eq 0 ] && rc=$rc_an; \
-# concurrency audit, explicitly by name: the lock-model registry and the
-# whole-program LWC014-016 rules (guarded fields cross-thread, the
-# lock-order DAG, blocking under a held lock) gate tier-1 even on hosts
-# that exported ANALYSIS_SKIP_CONCURRENCY=1 for their general lint runs
-# — the empty override strips the escape hatch for this one step.
-timeout -k 10 300 env JAX_PLATFORMS=cpu ANALYSIS_SKIP_CONCURRENCY= python -m llm_weighted_consensus_tpu.analysis --rules LWC014,LWC015,LWC016 --no-jaxpr --no-mesh; rc_cc=$?; [ $rc -eq 0 ] && rc=$rc_cc; \
-if [ -z "${ANALYSIS_SKIP_MESH:-}" ]; then timeout -k 10 300 env JAX_PLATFORMS=cpu python -c 'import sys; from llm_weighted_consensus_tpu.analysis.mesh_audit import run_mesh_audit; fs = run_mesh_audit(); [print(f.render()) for f in fs]; sys.exit(1 if fs else 0)'; rc_mesh=$?; [ $rc -eq 0 ] && rc=$rc_mesh; fi; exit $rc
+# Tier-1 verify: the driver's command (`commands` of its TESTS_LAST_RUN.json)
+# and nothing else: 1470 s, six xdist workers by file, passes counted from
+# the junit file.  Run from the repo root.
+set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}'); echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)}; echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null); exit $rc

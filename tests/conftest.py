@@ -41,6 +41,6 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "soak: sustained-load / overload scenarios (bench_http.py --overload, "
-        "scripts/chaos.sh overload+SIGTERM); always also marked slow",
+        "soak: sustained-load / overload scenarios (scripts/chaos.sh "
+        "overload+SIGTERM); always also marked slow",
     )

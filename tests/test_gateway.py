@@ -312,7 +312,8 @@ def test_config_env_parity():
         "FIRST_CHUNK_TIMEOUT_MILLIS": "1234",
         "PORT": "8080",
         "EMBEDDER_MODEL": "bge-small-en",
-        "MESH_DP": "4",
+        "MESH_ENABLED": "1",
+        "MESH_SHAPE": "4x1",
     }
     c = Config.from_env(env)
     assert [a.api_base for a in c.api_bases()] == ["https://a", "https://b"]
@@ -320,7 +321,7 @@ def test_config_env_parity():
     assert c.first_chunk_timeout_millis == 1234
     assert c.port == 8080
     assert c.embedder_model == "bge-small-en"
-    assert c.mesh_dp == 4
+    assert c.mesh_enabled and c.mesh_shape == (4, 1)
     # defaults (main.rs:5-20)
     assert c.backoff_policy().initial_interval_ms == 100
     assert c.other_chunk_timeout_millis == 60000
@@ -875,11 +876,11 @@ def test_unwritable_archive_path_names_env_var(tmp_path):
     assert "ARCHIVE_PATH" in str(err.value)
 
 
-# -- mesh-configured serving (MESH_DP / MESH_TP) ------------------------------
+# -- mesh-configured serving (MESH_ENABLED / MESH_SHAPE) ----------------------
 
 
 def test_mesh_dp_service_round_trip():
-    """MESH_DP=8 -> build_embedder places the device side on a dp mesh;
+    """MESH_SHAPE=8x1 -> build_embedder places the device side on a dp mesh;
     /embeddings and a trained-weights score request round-trip through the
     dp-sharded embedder."""
     pytest.importorskip("jax")
@@ -896,7 +897,8 @@ def test_mesh_dp_service_round_trip():
         {
             "EMBEDDER_MODEL": "test-tiny",
             "EMBEDDER_MAX_TOKENS": "32",
-            "MESH_DP": "8",
+            "MESH_ENABLED": "1",
+            "MESH_SHAPE": "8x1",
         }
     )
     embedder = build_embedder(config)

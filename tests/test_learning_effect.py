@@ -24,13 +24,7 @@ the closed-loop accuracy claim is this framework's own to prove.
 import asyncio
 
 import numpy as np
-
-# the scenario helpers below are shared with bench_all.py's evidence
-# line (config 6), which must import this module without a test runner
-try:
-    import pytest
-except ImportError:  # pragma: no cover - bench-only environments
-    pytest = None
+import pytest
 
 import jax
 
@@ -47,17 +41,11 @@ TOPIC_WORDS = {
 CANDIDATES = ["four", "five"]
 
 
-def make_embedder():
+@pytest.fixture(scope="module", name="embedder")
+def embedder_fixture():
     return TpuEmbedder(
         "test-tiny", config=configs.TEST_TINY, max_tokens=32, seed=1
     )
-
-
-if pytest is not None:
-
-    @pytest.fixture(scope="module", name="embedder")
-    def embedder_fixture():
-        return make_embedder()
 
 
 def make_panel():
@@ -187,10 +175,7 @@ def tally_top1(weights, votes) -> int:
 def evaluate_held_out(fetcher, model, n_train: int, per_topic: int = 12):
     """Held-out accuracy of learned vs static weights over both topics.
 
-    The SHARED evaluation loop for the test below and bench_all's
-    config-6 evidence line — one definition, so the pinned scenario and
-    the reported uplift cannot drift apart.  Returns (learned_acc,
-    static_acc, total, all_weights)."""
+    Returns (learned_acc, static_acc, total, all_weights)."""
     loop = asyncio.new_event_loop()
     try:
         learned_hits = static_hits = total = 0

@@ -13,7 +13,7 @@ What this pins, on the tier-1 8-virtual-device CPU mesh:
   deadline shed is still a 504 before dispatch, the watchdog brackets
   every dispatch, drain still waits for queued work;
 * config: ``MESH_ENABLED`` unset is today's single-device behavior, and
-  the knob validation refuses half-configured or legacy-mixed setups.
+  the knob validation refuses half-configured setups and the removed names.
 
 Jit caches are process-global and SHARED across embedder instances, so
 every zero-growth assertion is a delta whose reference dispatches all
@@ -202,15 +202,15 @@ def test_mesh_aot_zero_specializations_under_mixed_load():
 
 
 def test_mesh_aot_warmup_allowed_legacy_hooks_still_refused():
-    """Mesh mode takes the AOT branch ``aot_warmup`` used to refuse;
-    the legacy hook-sharded shapes still raise (their executables would
-    silently miss the put_batch placement)."""
+    """Mesh mode takes the AOT branch; a one-device embedder whose
+    batches are padded to a hand-set multiple still raises (a plain-aval
+    executable was not lowered for the padded rows)."""
     emb = mesh_embedder()
     assert emb._aot_ready()
-    legacy = make_embedder()
-    legacy.batch_multiple = 2  # the legacy dp hook contract
+    padded = make_embedder()
+    padded.batch_multiple = 2
     with pytest.raises(RuntimeError, match="mesh"):
-        legacy.aot_warmup([(N, S)])
+        padded.aot_warmup([(N, S)])
 
 
 # -- PR 4/5 per-item contracts through the mesh path --------------------------
@@ -296,10 +296,19 @@ def test_mesh_config_parses_and_validates():
     assert config.mesh_shape == (4, 2)
     with pytest.raises(ValueError, match="MESH_ENABLED is not"):
         Config.from_env({"MESH_SHAPE": "4x2"})
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        Config.from_env({"MESH_ENABLED": "1", "MESH_DP": "2"})
     with pytest.raises(ValueError, match="DPxTP"):
         Config.from_env({"MESH_ENABLED": "1", "MESH_SHAPE": "4x0"})
+
+
+@pytest.mark.parametrize("name", ["MESH_DP", "MESH_TP", "MESH_SP"])
+def test_removed_mesh_names_are_refused_with_the_replacement(name):
+    """A deployment that still sets a hook-path name must not fall
+    silently to one device, with or without mesh mode beside it."""
+    for env in ({name: "2"}, {name: "2", "MESH_ENABLED": "1"}):
+        with pytest.raises(ValueError) as err:
+            Config.from_env(env)
+        assert name in str(err.value)
+        assert "MESH_ENABLED=1 MESH_SHAPE=DPxTP[xSP]" in str(err.value)
 
 
 def test_build_embedder_mesh_enabled_round_trip():

@@ -1,6 +1,5 @@
 """Multichat fan-out client: slot semantics, dedup identity, error
-isolation, unary fold, streaming incremental consensus (SURVEY §2.10,
-BASELINE configs 2 and 5)."""
+isolation, unary fold, streaming incremental consensus (SURVEY §2.10)."""
 
 import asyncio
 from decimal import Decimal

@@ -149,7 +149,7 @@ def test_shard_embedder_same_results(dp_mesh):
     emb = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=32, seed=1)
     texts = [f"text number {i}" for i in range(8)]
     base = emb.embed_texts(texts)
-    sharding.shard_embedder(emb, dp_mesh)
+    sharding.shard_embedder_mesh(emb, dp_mesh)
     out = emb.embed_texts(texts)
     np.testing.assert_allclose(out, base, atol=1e-5)
 
@@ -170,8 +170,9 @@ def test_rescore_batch_mesh_matches_local(dp_mesh):
 
 def test_rescore_batch_arbitrary_axis_names():
     """rescore_batch shards over EVERY axis of any mesh — the sp-serving
-    mesh ("dp", "sp") included, so MESH_SP services re-score sharded
-    (ADVICE r2: sp_mesh used to silently run unsharded)."""
+    mesh ("dp", "sp") included, so a service on an sp-bearing mesh
+    re-scores sharded (ADVICE r2: such a mesh used to silently run
+    unsharded)."""
     from llm_weighted_consensus_tpu.parallel.mesh import make_mesh
 
     sp_mesh = make_mesh(dp=2, tp=4, names=("dp", "sp"))

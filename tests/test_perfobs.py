@@ -594,8 +594,8 @@ def test_prometheus_exposition_golden_format():
 
 
 def test_json_snapshot_stays_shape_compatible():
-    """The PR 5 JSON consumers (bench tools, dashboards) read count /
-    errors / p50_ms / p99_ms / trace_id per series; the histogram swap
+    """The PR 5 JSON consumers (the benchmark's readers, dashboards) read
+    count / errors / p50_ms / p99_ms / trace_id per series; the histogram swap
     must not change that shape, and the new sections are registered."""
     obs.reset_phases()
     metrics = Metrics()

@@ -264,7 +264,6 @@ def test_drift_flags_agreement_collapse_against_baseline():
     assert drift["baseline_agreement"] == 1.0
     assert drift["agreement_drop"] == 1.0
     assert agg.snapshot()["flagged"] == ["j"]
-    assert agg.summary()["flagged_judges"] == ["j"]
 
 
 def test_drift_healthy_judge_stays_unflagged():
@@ -323,10 +322,7 @@ def test_outcome_counters_and_margin_histogram():
     # only real margins land in the histogram (the all-failed request
     # has no consensus to measure)
     assert snap["confidence_margin"]["count"] == 3
-    summary = agg.summary()
-    assert summary["requests"] == 4
-    assert summary["median_confidence_margin"] is not None
-    assert summary["flagged_judges"] == []
+    assert snap["flagged"] == []
 
 
 def test_prom_snapshot_is_cloned_and_flat():

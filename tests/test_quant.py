@@ -165,7 +165,9 @@ def test_quantized_reranker_preserves_reward_ordering():
 
 def test_quantized_params_shard_on_dp_tp_mesh():
     from llm_weighted_consensus_tpu.parallel.mesh import make_mesh
-    from llm_weighted_consensus_tpu.parallel.sharding import shard_embedder
+    from llm_weighted_consensus_tpu.parallel.sharding import (
+        shard_embedder_mesh,
+    )
 
     n = min(len(jax.devices()), 4)
     if n < 4:
@@ -178,7 +180,7 @@ def test_quantized_params_shard_on_dp_tp_mesh():
     texts = ["alpha one", "alpha one", "beta two", "gamma three"]
     want = np.asarray(ref.consensus_confidence(texts))
     mesh = make_mesh(dp=2, tp=2, devices=jax.devices()[:4])
-    shard_embedder(emb, mesh, tp=True)
+    shard_embedder_mesh(emb, mesh)
     got = np.asarray(emb.consensus_confidence(texts))
     np.testing.assert_allclose(got, want, atol=2e-4)
 
@@ -337,24 +339,41 @@ def test_int8_pallas_and_xla_dispatch_evidence():
     storage-format anti-pattern the fused path replaced); int8-xla keeps
     the dot_general fallback (no kernel, int8 operands feed the matmul
     directly — still no dequant-to-bf16-then-matmul)."""
-    from bench import int8_dispatch_evidence
+    from llm_weighted_consensus_tpu.analysis.jaxpr_audit import walk_jaxpr
 
     rng = np.random.default_rng(11)
     ids = rng.integers(3, TINY.vocab_size, (4, 16)).astype(np.int32)
     mask = np.ones((4, 16), np.int32)
 
-    emb = TpuEmbedder("test-tiny", config=TINY, max_tokens=32, seed=3,
-                      quantize="int8-pallas")
-    ev = int8_dispatch_evidence(emb, ids, mask)
-    assert ev["fused_path"] is True, ev
-    assert ev["pallas_w8a8_calls"] > 0
-    assert ev["int8_to_float_dequant_converts"] == 0
+    def counts(emb):
+        closed = jax.make_jaxpr(
+            lambda p, i, m: bert.embed(
+                p, i, m, emb.config, pooling=emb.pooling
+            )
+        )(emb.params, jnp.asarray(ids), jnp.asarray(mask))
+        n = {"pallas_call": 0, "dequant": 0}
 
-    emb_xla = TpuEmbedder("test-tiny", config=TINY, max_tokens=32, seed=3,
-                          quantize="int8-xla")
-    ev_xla = int8_dispatch_evidence(emb_xla, ids, mask)
-    assert ev_xla["fused_path"] is False
-    assert ev_xla["pallas_w8a8_calls"] == 0
+        def visit(eqn):
+            if eqn.primitive.name == "pallas_call":
+                n["pallas_call"] += 1
+            if eqn.primitive.name == "convert_element_type":
+                src, dst = eqn.invars[0].aval, eqn.outvars[0].aval
+                if src.dtype == jnp.int8 and jnp.issubdtype(
+                    dst.dtype, jnp.floating
+                ):
+                    n["dequant"] += 1
+
+        walk_jaxpr(closed.jaxpr, visit)
+        return n
+
+    fused = counts(TpuEmbedder("test-tiny", config=TINY, max_tokens=32,
+                               seed=3, quantize="int8-pallas"))
+    assert fused["pallas_call"] > 0, fused
+    assert fused["dequant"] == 0, fused
+
+    xla = counts(TpuEmbedder("test-tiny", config=TINY, max_tokens=32,
+                             seed=3, quantize="int8-xla"))
+    assert xla["pallas_call"] == 0, xla
 
 
 def test_quant_mode_validation_and_auto_selection():

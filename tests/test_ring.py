@@ -179,22 +179,39 @@ def test_ring_rejects_sequence_beyond_position_table():
         bert.encode(params, ids, jnp.ones_like(ids), einsum_config)
 
 
-# -- sequence-parallel serving wiring ----------------------------------------
+# -- sequence-parallel serving wiring (mesh mode's ring route) ----------------
+
+
+def ring_route(embedder, texts):
+    """What the batcher does with an over-length request on an sp-bearing
+    mesh: tokenize to the ring window, dispatch through the ring."""
+    return embedder.embed_tokens_ring(*embedder.tokenize_ring(texts))
+
+
+def mesh_embedder(dp, sp, devices=None):
+    from llm_weighted_consensus_tpu.models.embedder import TpuEmbedder
+    from llm_weighted_consensus_tpu.parallel.mesh import make_mesh
+    from llm_weighted_consensus_tpu.parallel.sharding import (
+        shard_embedder_mesh,
+    )
+
+    emb = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
+    shard_embedder_mesh(emb, make_mesh(dp=dp, sp=sp, devices=devices))
+    return emb
 
 
 def test_shard_embedder_sp_matches_plain_embedder():
     from llm_weighted_consensus_tpu.models.embedder import TpuEmbedder
 
     plain = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
-    ringed = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
-    ring.shard_embedder_sp(ringed, sp_mesh(8))
+    ringed = mesh_embedder(dp=1, sp=8)
     texts = [
         "a longer text with many words " * 2,
         "short",
         "and a third document",
     ]
     np.testing.assert_allclose(
-        ringed.embed_texts(texts), plain.embed_texts(texts), atol=1e-4
+        ring_route(ringed, texts), plain.embed_texts(texts), atol=1e-4
     )
 
 
@@ -206,26 +223,29 @@ def test_build_embedder_mesh_sp_round_trip():
         {
             "EMBEDDER_MODEL": "test-tiny",
             "EMBEDDER_MAX_TOKENS": "64",
-            "MESH_SP": "4",
-            "MESH_DP": "2",
+            "MESH_ENABLED": "1",
+            "MESH_SHAPE": "2x1x4",
         }
     )
     embedder = build_embedder(config)
-    assert embedder.sp_mesh is not None
-    assert dict(embedder.sp_mesh.shape) == {"dp": 2, "sp": 4}
-    out = embedder.embed_texts(["long context through the ring"])
+    assert dict(embedder.mesh.shape) == {"dp": 2, "tp": 1, "sp": 4}
+    assert embedder.ring_available()
+    out = ring_route(embedder, ["long context through the ring"])
     np.testing.assert_allclose(np.linalg.norm(out, axis=1), 1.0, atol=1e-5)
 
-    with pytest.raises(ValueError, match="mutually exclusive"):
-        build_embedder(
-            Config.from_env(
-                {
-                    "EMBEDDER_MODEL": "test-tiny",
-                    "MESH_SP": "4",
-                    "MESH_TP": "2",
-                }
-            )
+    # under mesh mode tp and sp are axes of one mesh and combine (the
+    # hook path refused the pair)
+    both = build_embedder(
+        Config.from_env(
+            {
+                "EMBEDDER_MODEL": "test-tiny",
+                "MESH_ENABLED": "1",
+                "MESH_SHAPE": "2x2x2",
+            }
         )
+    )
+    assert dict(both.mesh.shape) == {"dp": 2, "tp": 2, "sp": 2}
+    assert both.ring_available()
 
 
 def test_long_context_preset_exists():
@@ -238,42 +258,37 @@ def test_long_context_preset_exists():
 
 def test_sp_serving_edge_configs():
     """Reviewer repros: non-power-of-two dp divides via batch_multiple;
-    sp that does not divide the position table caps max_tokens; sp=0 is a
-    clean config error."""
+    sp that does not divide the position table caps the ring window; sp=0
+    is a clean config error."""
     from llm_weighted_consensus_tpu.models.embedder import TpuEmbedder
     from llm_weighted_consensus_tpu.serve import Config
-    from llm_weighted_consensus_tpu.serve.__main__ import build_embedder
 
     # dp=3 x sp=2 on 6 devices: batch pads to a dp multiple, not a crash
-    import numpy as np
-
-    from jax.sharding import Mesh
-
-    emb = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
-    mesh = Mesh(np.array(jax.devices()[:6]).reshape(3, 2), ("dp", "sp"))
-    ring.shard_embedder_sp(emb, mesh, dp_axis="dp")
+    emb = mesh_embedder(dp=3, sp=2, devices=jax.devices()[:6])
     assert emb.batch_multiple == 3
     plain = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
-    texts = ["one", "two", "three", "four"]  # 4 texts, pads to 18 rows
+    texts = ["one", "two", "three", "four"]  # 4 texts, pads to 6 rows
     np.testing.assert_allclose(
-        emb.embed_texts(texts), plain.embed_texts(texts), atol=1e-4
+        ring_route(emb, texts), plain.embed_texts(texts), atol=1e-4
     )
 
-    # sp=3 does not divide max_pos 64: window capped to 63, full-length
-    # inputs still embed (never 500)
-    emb3 = TpuEmbedder("test-tiny", config=TEST_TINY, max_tokens=64, seed=2)
-    mesh3 = Mesh(np.array(jax.devices()[:3]).reshape(1, 3), ("dp", "sp"))
-    ring.shard_embedder_sp(emb3, mesh3)
-    assert emb3.max_tokens == 63
-    out = emb3.embed_texts(["word " * 200])  # truncates, embeds, no error
+    # sp=3 does not divide max_pos 64: ring window capped to 63,
+    # full-length inputs still embed (never 500)
+    emb3 = mesh_embedder(dp=1, sp=3, devices=jax.devices()[:3])
+    assert emb3.ring_max_tokens == 63
+    ids, mask = emb3.tokenize_ring(["word " * 200])  # truncates
+    assert ids.shape[1] == 63
+    out = emb3.embed_tokens_ring(ids, mask)  # embeds, no error
     np.testing.assert_allclose(np.linalg.norm(out, axis=1), 1.0, atol=1e-5)
 
-    # sp=0 is rejected at build time with a clear error
-    with pytest.raises(ValueError, match="axes must be >= 1"):
-        build_embedder(
-            Config.from_env(
-                {"EMBEDDER_MODEL": "test-tiny", "MESH_SP": "0"}
-            )
+    # sp=0 is rejected at start-up with a clear error
+    with pytest.raises(ValueError, match="positive axes"):
+        Config.from_env(
+            {
+                "EMBEDDER_MODEL": "test-tiny",
+                "MESH_ENABLED": "1",
+                "MESH_SHAPE": "2x1x0",
+            }
         )
 
 
@@ -281,15 +296,31 @@ def test_mesh_sp_autofill_dp_and_long_default_window():
     from llm_weighted_consensus_tpu.serve import Config
     from llm_weighted_consensus_tpu.serve.__main__ import build_embedder
 
-    # MESH_DP unset -> every device not consumed by sp becomes dp
-    config = Config.from_env(
-        {"EMBEDDER_MODEL": "test-tiny", "MESH_SP": "2"}
+    # dp unset -> every device not consumed by sp becomes dp
+    assert dict(mesh_embedder(dp=None, sp=2).mesh.shape) == {
+        "dp": 4, "tp": 1, "sp": 2
+    }
+    # MESH_SHAPE unset -> every local device on dp
+    alone = build_embedder(
+        Config.from_env({"EMBEDDER_MODEL": "test-tiny", "MESH_ENABLED": "1"})
     )
-    embedder = build_embedder(config)
-    assert dict(embedder.sp_mesh.shape) == {"dp": 4, "sp": 2}
-    # EMBEDDER_MAX_TOKENS unset under MESH_SP -> full position table
-    # (test-tiny: 64), NOT the 512 short-context default
-    assert embedder.max_tokens == 64
+    assert dict(alone.mesh.shape) == {"dp": 8, "tp": 1}
+    assert not alone.ring_available()
+    # the ring window is the full position table (test-tiny: 64) whatever
+    # the dense window: a long input is routed, not cut at the dense cap
+    embedder = build_embedder(
+        Config.from_env(
+            {
+                "EMBEDDER_MODEL": "test-tiny",
+                "EMBEDDER_MAX_TOKENS": "32",
+                "MESH_ENABLED": "1",
+                "MESH_SHAPE": "4x1x2",
+            }
+        )
+    )
+    assert embedder.max_tokens == 32
+    assert embedder.ring_max_tokens == 64
+    assert embedder.tokenize_ring(["word " * 200])[0].shape[1] == 64
 
 
 def test_ring_with_roberta_positions():

@@ -104,7 +104,7 @@ def test_store_appends_rows():
     assert store.get("missing") is None
 
 
-# -- archive batch re-score (BASELINE config 4) -------------------------------
+# -- archive batch re-score ---------------------------------------------------
 
 
 def test_archive_rescore_reweighting():

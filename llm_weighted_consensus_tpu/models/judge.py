@@ -42,6 +42,7 @@ import numpy as np
 
 from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
+from ..ops import causal_attention
 from . import dispatch_seam as _seam
 from . import glm_moe
 from .configs import GLM_4_7_FLASH, GLM_TEST_TINY, GlmMoeLiteConfig
@@ -136,6 +137,7 @@ class TpuJudge:
             "calls": 0,
             "prefill_tokens": 0,
             "padded_tokens": 0,
+            "attention_work_over_causal": 0.0,
             "expert_load_max_over_mean_sum": 0.0,
             "expert_tokens": [0] * self.config.n_routed_experts,
         }
@@ -272,12 +274,21 @@ class TpuJudge:
         if load.size and load.sum():
             # a dispatch's largest load over its mean, the worst layer's
             ratio = float((load.max(axis=1) / load.mean(axis=1)).max())
+        # what the attention kernel's schedule multiplies over what the
+        # causal mask keeps: a number of the bucket
+        slots = prepared.ids.shape[1]
+        block = causal_attention.block_for(slots)
+        work = causal_attention.work_over_causal(slots, block, block)
         with self._lock:
             s = self._stats
             s["dispatches"] += 1
             s["calls"] += len(prepared.calls)
             s["prefill_tokens"] += prepared.tokens
             s["padded_tokens"] += prepared.ids.size - prepared.tokens
+            # the dispatches' mean
+            s["attention_work_over_causal"] += (
+                work - s["attention_work_over_causal"]
+            ) / s["dispatches"]
             s["expert_load_max_over_mean_sum"] += ratio
             if load.size:
                 totals = load.sum(axis=0)

@@ -172,17 +172,88 @@ def test_router_matches_numpy_top_k_with_the_bias_used_for_choice_only():
 # -- the kernels against their einsum twins -----------------------------------
 
 
-@pytest.mark.parametrize("block_q,block_k", [(32, 32), (32, 16), (16, 32), (96, 96)])
-def test_causal_kernel_matches_einsum(block_q, block_k):
-    rng = np.random.default_rng(block_q + block_k)
-    q, k, v = (
-        jnp.asarray(rng.standard_normal((2, SEQ, 4 * 32)), jnp.float32) for _ in range(3)
+def _qkv(seed, b, s, heads, hd, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return tuple(
+        jnp.asarray(rng.standard_normal((b, s, heads * hd)), dtype) for _ in range(3)
     )
+
+
+# (b, s, heads, hd, block_q, block_k, dtype, tolerance): every branch of the
+# kernel's schedule at a size the interpreter runs
+CAUSAL_CASES = {
+    "whole-tiles-32": (2, SEQ, 4, 32, 32, 32, jnp.float32, 2e-6),
+    "bq-over-bk-16": (2, SEQ, 4, 32, 32, 16, jnp.float32, 2e-6),
+    "bk-over-bq-16": (2, SEQ, 4, 32, 16, 32, jnp.float32, 2e-6),
+    "one-small-block": (2, SEQ, 4, 32, 96, 96, jnp.float32, 2e-6),
+    "two-stripes": (1, 1024, 2, 32, 512, 512, jnp.float32, 2e-6),
+    "four-stripes": (1, 2048, 1, 32, 1024, 1024, jnp.float32, 2e-6),
+    "two-stripes-a-lane": (2, 1024, 2, 128, 512, 512, jnp.float32, 2e-6),
+    "one-block-in-stripes": (1, 512, 2, 128, 512, 512, jnp.float32, 2e-6),
+    "bq-over-bk-a-lane": (1, 512, 2, 128, 256, 128, jnp.float32, 2e-6),
+    "bk-over-bq-a-lane": (1, 512, 2, 128, 128, 256, jnp.float32, 2e-6),
+    "bands-below-the-diagonal": (1, 2048, 1, 128, 1024, 1024, jnp.float32, 2e-6),
+    "the-block-it-picks": (2, 768, 2, 32, 0, 0, jnp.float32, 2e-6),
+    "bfloat16": (2, 1024, 2, 128, 512, 512, jnp.bfloat16, 2e-2),
+}
+
+
+@pytest.mark.parametrize("case", CAUSAL_CASES)
+def test_causal_kernel_matches_einsum(case):
+    b, s, heads, hd, block_q, block_k, dtype, tolerance = CAUSAL_CASES[case]
+    q, k, v = _qkv(block_q + block_k + s, b, s, heads, hd, dtype)
+    scale = 1.6 / hd**0.5
     got = attn.causal_attention_blockwise(
-        q, k, v, heads=4, scale=0.2, block_q=block_q, block_k=block_k
+        q, k, v, heads=heads, scale=scale, block_q=block_q, block_k=block_k
     )
-    want = attn.causal_attention_einsum(q, k, v, heads=4, scale=0.2)
-    assert float(jnp.abs(got - want).max()) < 2e-6
+    want = attn.causal_attention_einsum(q, k, v, heads=heads, scale=scale)
+    assert got.dtype == dtype and got.shape == q.shape
+    err = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max()
+    assert float(err) < tolerance
+
+
+@pytest.mark.parametrize("block,at", [(512, 300), (512, 767), (32, 40)])
+def test_causal_kernel_ignores_the_future(block, at):
+    """Keys and values after position ``at`` changed: rows <= at are the
+    same bit for bit (a stripe that read past its own end would differ)."""
+    s = 2 * block
+    q, k, v = _qkv(at, 1, s, 2, 32)
+    later = (jnp.arange(s) > at)[None, :, None]
+
+    def run(k, v):
+        return attn.causal_attention_blockwise(
+            q, k, v, heads=2, scale=0.3, block_q=block, block_k=block
+        )
+
+    got = run(jnp.where(later, 7.5 - k, k), jnp.where(later, v * -3.0 + 1.0, v))
+    want = run(k, v)
+    assert (np.asarray(got)[:, : at + 1] == np.asarray(want)[:, : at + 1]).all()
+    assert (np.asarray(got)[:, at + 1 :] != np.asarray(want)[:, at + 1 :]).any()
+
+
+def test_work_over_causal_hand_counts():
+    # 36 tiles of 1024 x 1024 for the 8192 * 8193 / 2 pairs the mask keeps
+    assert attn.work_over_causal(8192, 1024, 1024, stripe=0) == pytest.approx(
+        36 * 1024 * 1024 / (8192 * 8193 // 2)
+    )
+    assert round(attn.work_over_causal(8192, 1024, 1024, stripe=0), 3) == 1.125
+    # the 8 tiles on the diagonal in stripes of 256: 10 of 16 chunks each
+    assert attn.stripe_for(1024, 1024) == 256
+    assert attn.work_over_causal(8192, 1024, 1024) == pytest.approx(
+        (28 * 16 + 8 * 10) * 65536 / (8192 * 8193 // 2)
+    )
+    assert round(attn.work_over_causal(8192, 1024, 1024), 3) == 1.031
+    # the waste is the stripes', whatever the block: what the judge runs
+    assert attn.block_for(8192) == 2048
+    assert round(attn.work_over_causal(8192, 2048, 2048), 3) == 1.031
+    # no split: a block of one stripe, a block that is not square
+    assert attn.stripe_for(256, 256) == 0 and attn.stripe_for(1024, 512) == 0
+    assert attn.work_over_causal(1024, 256, 256) == pytest.approx(
+        10 * 65536 / (1024 * 1025 // 2)
+    )
+    assert attn.work_over_causal(64, 32, 16) == pytest.approx(
+        (3 * 32 * 16 + 4 * 32 * 16 - 32 * 16) / (64 * 65 // 2)
+    )
 
 
 @pytest.mark.parametrize("pairs,tile", [(37, 8), (200, 16), (64, 32)])
@@ -526,6 +597,11 @@ def test_consensus_judge_through_gateway_and_batcher(judge):
     stats = judge.stats()
     assert stats["calls"] >= 2 + 3 + 3 and stats["prefill_tokens"] > 0
     assert stats["padded_tokens"] > 0 and sum(stats["expert_tokens"]) > 0
+    # every dispatch ran the one bucket: its mean is the bucket's own figure
+    block = attn.block_for(judge.max_tokens)
+    assert stats["attention_work_over_causal"] == pytest.approx(
+        attn.work_over_causal(judge.max_tokens, block, block)
+    )
 
 
 def test_build_judge_gate_and_presets(monkeypatch):

@@ -1,0 +1,8 @@
+"""forward.share.projections.qnext: per cent of the judge programs' device time under
+the ``projections`` scopes (``qnext_scopes.GROUPS``)."""
+
+import qnext_scopes
+
+
+def reduce(ctx):
+    return qnext_scopes.share(ctx, "projections")

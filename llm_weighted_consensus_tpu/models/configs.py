@@ -264,3 +264,68 @@ GLM_TEST_TINY = GlmMoeLiteConfig(
     n_routed_experts=8,
     num_experts_per_tok=2,
 )
+
+
+@dataclass(frozen=True)
+class Qwen3NextConfig:
+    """A causal decoder of gated delta-rule layers with a gated full-attention
+    layer every ``full_attention_interval``-th, every layer's second half
+    sparse (``model_type`` ``qwen3_next``): a judge behind ``POST /consensus``
+    ``scorer: judge`` (models/qwen3_next.py).
+
+    ``num_layers`` and ``num_experts`` are the published counts; a checkpoint
+    that names fewer layers (one pipeline stage of a deployment) or experts
+    0..E-1 of the router's ``num_experts`` (one chip's share where several
+    share a layer's experts) is served with the layers and experts it names
+    (``qwen3_next.from_hf_weights``).  The router stays ``num_experts`` wide."""
+
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_layers: int = 48
+    full_attention_interval: int = 4
+    num_heads: int = 16
+    num_kv_heads: int = 2
+    head_dim: int = 256
+    partial_rotary_factor: float = 0.25
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    moe_intermediate_size: int = 512
+    shared_expert_intermediate_size: int = 512
+    num_experts: int = 512
+    num_experts_per_tok: int = 10
+    rope_theta: float = 1e7
+    rms_norm_eps: float = 1e-6
+    # "int8": the dense products (both token mixers' projections, the shared
+    # expert) through quant.dense_int8; router, routed experts, the delta
+    # rule, embedding and head keep the parameters' dtype
+    quantize: str = "none"
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def is_full_attention(self, layer: int) -> bool:
+        return (layer + 1) % self.full_attention_interval == 0
+
+
+# Qwen/Qwen3-Next-80B-A3B-Instruct config.json
+QWEN3_NEXT_80B_A3B = Qwen3NextConfig()
+QWEN3_NEXT_TEST_TINY = Qwen3NextConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=4,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=32,
+    linear_num_key_heads=2,
+    linear_num_value_heads=4,
+    linear_key_head_dim=16,
+    linear_value_head_dim=16,
+    moe_intermediate_size=32,
+    shared_expert_intermediate_size=32,
+    num_experts=16,
+    num_experts_per_tok=4,
+)

@@ -1,0 +1,164 @@
+"""What the judges' decoders share (``models/glm_moe.py``, ``models/qwen3_next.py``):
+RMSNorm, the dense product with its W8A8 twin, SwiGLU, the half-swapped rotary turn,
+the int8 walk over a parameter tree, and the routed experts.
+
+Routed experts run over the tokens sent to each, as TWO kernels over the
+(token, choice) pairs sorted by expert (``ops/grouped_matmul.py``): gate and
+up as one product with SwiGLU on its float32 accumulators, then down with the
+router's weight of each row on its accumulator.  Around them three stages,
+each a ``jax.named_scope``: ``experts_layout`` (the pairs sorted by expert
+into padded row tiles, and the rows of ``h`` gathered into that order from
+column chunks that stay in VMEM), ``experts_swiglu`` (the kernels),
+``experts_combine`` (each token's k rows gathered back, a gather a choice,
+and summed; nothing is multiplied there).  A decode step's handful of tokens
+goes through the same path in tiles of 16 rows (tiles that hold no row fetch
+and compute nothing).
+
+A layer that holds experts 0..held-1 of a wider router (``held`` given) lays
+out only the pairs whose expert is here, drops none of them, and returns the
+partial sum: what the experts elsewhere would add is their chip's to add.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import grouped_matmul as _gmm
+
+
+def rms(x, weight, eps: float):
+    """x / sqrt(mean(x^2) + eps) · weight over the last dimension, in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def dense(x, p: dict):
+    """x[..., in] @ kernel[in, out]; the W8A8 twin where the loader
+    quantized this product (``JUDGE_QUANTIZE=int8``)."""
+    if "kernel_q" in p:
+        from .quant import dense_int8
+
+        return dense_int8(x, p, impl="xla")
+    return jnp.einsum(
+        "...i,io->...o", x, p["kernel"], preferred_element_type=jnp.float32
+    ).astype(x.dtype)
+
+
+def swiglu(x, p: dict):
+    gate = dense(x, p["gate"]).astype(jnp.float32)
+    up = dense(x, p["up"]).astype(jnp.float32)
+    return dense((jax.nn.silu(gate) * up).astype(x.dtype), p["down"])
+
+
+def rope_angles(positions, dim: int, theta: float):
+    """positions [...] -> (cos, sin) [..., dim / 2], float32."""
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = positions.astype(jnp.float32)[..., None] * inv
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def rope(x, cos, sin):
+    """x [..., d] with pairs (i, i + d/2); cos, sin broadcast to [..., d/2]."""
+    half = x.shape[-1] // 2
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def quantize_dense(params: dict) -> dict:
+    """``JUDGE_QUANTIZE=int8``: every ``{"kernel"}`` product of a decoder's
+    parameter tree (the token mixers' projections, a dense layer's MLP, the
+    shared expert) becomes ``quant.dense_int8``'s ``{"kernel_q", "scale",
+    "bias"}``.  Router, routed experts, arrays held bare, embedding and head
+    stay as they are."""
+    from .quant import quantize_weight
+
+    def walk(node):
+        if isinstance(node, dict):
+            if set(node) == {"kernel"}:
+                q, scale = quantize_weight(node["kernel"])
+                bias = jnp.zeros((q.shape[-1],), node["kernel"].dtype)
+                return {"kernel_q": q, "scale": scale, "bias": bias}
+            return {k: walk(v) for k, v in node.items()}
+        if isinstance(node, (list, tuple)):
+            return type(node)(walk(v) for v in node)
+        return node
+
+    return walk(params)
+
+
+# rows of the padded layout a share's usual routing fits (the first judge's
+# whole bound: tables of that many rows stay in VMEM for XLA's gathers)
+USUAL_ROWS = 114_688
+
+
+def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None = None):
+    """h [t, hidden], chosen and weight [t, k], ``p`` with ``w_gate``,
+    ``w_up`` [E, hidden, width] and ``w_down`` [E, width, hidden] -> (the
+    chosen experts' weighted sum [t, hidden], counts).  ``experts`` is the
+    router's width (it sizes the tiles: a group's mean load); with ``held``
+    the weights are those of experts 0..held-1 and counts is [held + 1], the
+    last entry the pairs routed elsewhere; else counts is [experts].
+
+    A share's layout is sized for every pair being held here (none may be
+    dropped), four times its usual load: where the tiles in use fit
+    ``USUAL_ROWS`` the rows past them are neither gathered nor laid out (one
+    ``lax.cond`` on the layout's own count; the other branch is the same code
+    over the whole bound)."""
+    t, k = chosen.shape
+    tile = _gmm.tile_for(t * k, experts)
+    with jax.named_scope("experts_layout"):
+        if held is None:
+            tables = _gmm.route_layout_weighted(
+                chosen.reshape(-1), weight.reshape(-1), experts, tile
+            )
+            here = None
+        else:
+            tables, here = _gmm.route_layout_held(
+                chosen.reshape(-1), weight.reshape(-1), held, tile
+            )
+            here = here.reshape(t, k)
+        pair_of_row, row_of_pair, tile_expert, used, counts, row_weight = tables
+        # a pair elsewhere reads a row that exists and counts as nothing
+        rows_of = row_of_pair.reshape(t, k) if here is None else jnp.where(
+            here, row_of_pair.reshape(t, k), 0
+        )
+        parts = jnp.split(h, _gmm.column_chunks(*h.shape, h.dtype.itemsize), axis=1)
+
+    def over(rows: int):
+        """The layer over the first ``rows`` rows of the layout."""
+        with jax.named_scope("experts_layout"):
+            # rows gathered from column chunks small enough to stay in VMEM
+            x = tuple(part[pair_of_row[:rows] // k] for part in parts)
+        with jax.named_scope("experts_swiglu"):
+            product = partial(
+                _gmm.grouped_expert_product, tile_expert=tile_expert[: rows // tile],
+                tiles_used=used, tile=tile,
+            )
+            y = product(
+                product(x, p["w_gate"], w_up=p["w_up"]), p["w_down"],
+                row_weight=row_weight[:rows],
+                out_chunks=_gmm.column_chunks(rows, h.shape[1], h.dtype.itemsize),
+            )
+        with jax.named_scope("experts_combine"):
+            # a gather a choice, so that no [t, k, hidden] is laid out between
+            if here is None:
+                take = lambda part, j: part[rows_of[:, j]].astype(jnp.float32)  # noqa: E731
+            else:
+                take = lambda part, j: jnp.where(  # noqa: E731
+                    here[:, j, None], part[rows_of[:, j]].astype(jnp.float32), 0.0
+                )
+            routed = [sum(take(part, j) for j in range(k)) for part in y]
+            return jnp.concatenate(routed, axis=1).astype(h.dtype)
+
+    whole = pair_of_row.shape[0]
+    if held is None or whole <= USUAL_ROWS:
+        return over(whole), counts
+    fits = used[0] * tile <= USUAL_ROWS
+    return jax.lax.cond(fits, lambda: over(USUAL_ROWS), lambda: over(whole)), counts

@@ -52,56 +52,18 @@ device trace can be read by layer.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import grouped_matmul as _gmm
 from ..ops.causal_attention import causal_attention_blockwise
-from ..ops.votes import softmax_votes
 from .configs import GlmMoeLiteConfig
-
-
-def _rms(x, weight, eps: float):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-def _dense(x, p: dict):
-    """x[..., in] @ kernel[in, out]; the W8A8 twin where the loader
-    quantized this product (``JUDGE_QUANTIZE=int8``)."""
-    if "kernel_q" in p:
-        from .quant import dense_int8
-
-        return dense_int8(x, p, impl="xla")
-    return jnp.einsum(
-        "...i,io->...o", x, p["kernel"], preferred_element_type=jnp.float32
-    ).astype(x.dtype)
-
-
-def _swiglu(x, p: dict):
-    gate = _dense(x, p["gate"]).astype(jnp.float32)
-    up = _dense(x, p["up"]).astype(jnp.float32)
-    return _dense((jax.nn.silu(gate) * up).astype(x.dtype), p["down"])
-
-
-def _rope_angles(positions, dim: int, theta: float):
-    """positions [...] -> (cos, sin) [..., dim / 2], float32."""
-    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    angle = positions.astype(jnp.float32)[..., None] * inv
-    return jnp.cos(angle), jnp.sin(angle)
-
-
-def _rope(x, cos, sin):
-    """x [..., d] with pairs (i, i + d/2); cos, sin broadcast to [..., d/2]."""
-    half = x.shape[-1] // 2
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+from .decoder_parts import dense as _dense
+from .decoder_parts import experts_grouped, quantize_dense  # noqa: F401
+from .decoder_parts import rms as _rms
+from .decoder_parts import rope as _rope
+from .decoder_parts import rope_angles as _rope_angles
+from .decoder_parts import swiglu as _swiglu
 
 
 def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
@@ -205,40 +167,12 @@ def route(h, p: dict, config: GlmMoeLiteConfig):
     return chosen.astype(jnp.int32), weight * config.routed_scaling_factor
 
 
-def _experts_grouped(h, chosen, weight, p: dict, config: GlmMoeLiteConfig):
-    """h [t, hidden], chosen and weight [t, k] -> (the chosen experts'
-    weighted sum [t, hidden], counts [E])."""
-    t, k = chosen.shape
-    experts = config.n_routed_experts
-    tile = _gmm.tile_for(t * k, experts)
-    with jax.named_scope("experts_layout"):
-        pair_of_row, row_of_pair, tile_expert, used, counts, row_weight = (
-            _gmm.route_layout_weighted(chosen.reshape(-1), weight.reshape(-1), experts, tile)
-        )
-        # rows gathered from column chunks small enough to stay in VMEM
-        parts = jnp.split(h, _gmm.column_chunks(*h.shape, h.dtype.itemsize), axis=1)
-        x = tuple(part[pair_of_row // k] for part in parts)
-    with jax.named_scope("experts_swiglu"):
-        product = partial(
-            _gmm.grouped_expert_product, tile_expert=tile_expert, tiles_used=used, tile=tile
-        )
-        y = product(
-            product(x, p["w_gate"], w_up=p["w_up"]), p["w_down"], row_weight=row_weight,
-            out_chunks=_gmm.column_chunks(pair_of_row.shape[0], h.shape[1], h.dtype.itemsize),
-        )
-    with jax.named_scope("experts_combine"):
-        # a gather a choice, so that no [t, k, hidden] is laid out between
-        rows = row_of_pair.reshape(t, k)
-        routed = [sum(part[rows[:, j]].astype(jnp.float32) for j in range(k)) for part in y]
-        return jnp.concatenate(routed, axis=1).astype(h.dtype), counts
-
-
 def _moe(h, p: dict, config: GlmMoeLiteConfig):
     """h [t, hidden] -> (output [t, hidden], pairs routed to each expert)."""
     with jax.named_scope("router"):
         chosen, weight = route(h, p, config)
     with jax.named_scope("experts_routed"):
-        routed, counts = _experts_grouped(h, chosen, weight, p, config)
+        routed, counts = experts_grouped(h, chosen, weight, p, config.n_routed_experts)
     with jax.named_scope("expert_shared"):
         shared = _swiglu(h, p["shared"])
     return routed + shared, counts
@@ -253,9 +187,11 @@ def _mlp(h, layer: dict, config: GlmMoeLiteConfig):
     return flat.reshape(h.shape), counts
 
 
-def prefill(params: dict, ids, config: GlmMoeLiteConfig):
+def prefill(params: dict, ids, config: GlmMoeLiteConfig, lens=None):
     """ids [b, s] -> (hidden [b, s, hidden] before the final norm, the
-    latent cache a layer, pairs routed to each expert a sparse layer)."""
+    latent cache a layer, pairs routed to each expert a sparse layer).
+    ``lens`` is the panel protocol's (``models/judge.py``): causal attention
+    never sees the slots past a call's length, so it is not read here."""
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["token_embed"], ids, axis=0)
     caches, loads = [], []
@@ -293,56 +229,13 @@ def head_logprobs(params: dict, hidden, config: GlmMoeLiteConfig):
         return logits - jax.nn.logsumexp(logits, axis=-1, keepdims=True)
 
 
-def _masked(logprobs, letter_ids, valid):
-    """Vocabulary log-probabilities [b, V] at the letters' token ids [K];
-    letters that are no sibling read -inf."""
-    return jnp.where(valid, logprobs[:, letter_ids], -jnp.inf)
+def experts_held(params: dict, config: GlmMoeLiteConfig) -> int:
+    """Every expert the router names is held."""
+    return config.n_routed_experts
 
 
-@partial(jax.jit, static_argnames=("config", "depth"))
-def judge_panel(
-    params, ids, lens, letter_ids, first_valid, second_valid, *,
-    config: GlmMoeLiteConfig, depth: int,
-):
-    """A panel's calls in one program.  ids [b, s] right-padded prompts of
-    ``lens`` tokens, each ending where the key begins.  ``letter_ids`` [K]
-    are the key letters' token ids; ``first_valid`` [b, K] marks the letters
-    of a ballot's first level, ``second_valid`` [b, K, K] the sibling letters
-    under each first letter.
-
-    Per call: causal prefill (which leaves the latent cache), the head at
-    the last real position, the first level's masked log-probabilities; at
-    depth 2 the likeliest letter is decoded (greedy), one step runs through
-    the cache on the absorbed path, and the head is read again under the
-    chosen branch's mask.  ``votes`` [b, K] is ``softmax_votes`` over the
-    last read, a distribution over the K letters; which candidate a letter
-    selects is the host's to say, so one program serves every candidate
-    count.
-    """
-    b = ids.shape[0]
-    hidden, caches, loads = prefill(params, ids, config)
-    last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
-    first = _masked(head_logprobs(params, last, config), letter_ids, first_valid)
-    chosen = jnp.argmax(first, axis=1).astype(jnp.int32)
-    out = {
-        "first_logprobs": first,
-        "chosen": chosen,
-        "expert_load": jnp.stack(loads) if loads else jnp.zeros((0, 1), jnp.int32),
-    }
-    read, valid = first, first_valid
-    if depth == 2:
-        with jax.named_scope("decode_step"):
-            step = decode_step(params, letter_ids[chosen], lens, caches, config)
-            valid = second_valid[jnp.arange(b), chosen]
-            read = _masked(head_logprobs(params, step, config), letter_ids, valid)
-        out["second_logprobs"] = read
-    with jax.named_scope("ballot_vote"):
-        letters = jnp.broadcast_to(jnp.arange(valid.shape[1]), valid.shape)
-        out["votes"] = softmax_votes(
-            jnp.where(valid, read, 0.0), jnp.where(valid, letters, -1), valid,
-            valid.shape[1],
-        )
-    return out
+def recurrent_layers(config: GlmMoeLiteConfig) -> int:
+    return 0
 
 
 # -- parameters ---------------------------------------------------------------
@@ -505,25 +398,3 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
         "layers": layers,
     }
     return params, config
-
-
-def quantize_dense(params: dict) -> dict:
-    """``JUDGE_QUANTIZE=int8``: every ``{"kernel"}`` product (attention
-    projections, the dense layer's MLP, the shared expert) becomes
-    ``quant.dense_int8``'s ``{"kernel_q", "scale", "bias"}``.  Router, routed
-    experts, ``w_k``/``w_v`` (shared with the absorbed path), embedding and
-    head stay as they are."""
-    from .quant import quantize_weight
-
-    def walk(node):
-        if isinstance(node, dict):
-            if set(node) == {"kernel"}:
-                q, scale = quantize_weight(node["kernel"])
-                bias = jnp.zeros((q.shape[-1],), node["kernel"].dtype)
-                return {"kernel_q": q, "scale": scale, "bias": bias}
-            return {k: walk(v) for k, v in node.items()}
-        if isinstance(node, list):
-            return [walk(v) for v in node]
-        return node
-
-    return walk(params)

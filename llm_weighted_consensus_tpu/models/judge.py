@@ -4,9 +4,24 @@ The reference's judges are upstream chat models: each is shown a ballot (the
 candidates under randomized prefix-tree keys, ``ballot/tree.py``), answers
 with a key, and its ``top_logprobs`` at the key's last letter become its vote
 (``ballot/vote.py``).  ``TpuJudge`` runs that protocol on the device: a panel
-is a few calls of one causal decoder (``models/glm_moe.py``) over the same
-candidates under differently seeded ballots, and what an upstream judge's
-``top_logprobs`` would have carried is read from the decoder's own head.
+is a few calls of one causal decoder over the same candidates under
+differently seeded ballots, and what an upstream judge's ``top_logprobs``
+would have carried is read from the decoder's own head.
+
+Two decoders serve (``JUDGE_PRESETS``; the preset's configuration class says
+which): ``models/glm_moe.py`` (latent attention, every expert held) and
+``models/qwen3_next.py`` (gated delta-rule layers three to one with gated
+full attention, a share of the router's experts held).  The panel's protocol
+is no part of either: ``judge_panel`` below is ONE jitted program over what
+a decoder module gives,
+
+  ``prefill(params, ids, config, lens=)``  -> hidden [b, s, h], a cache a
+      layer (of whatever kind the layer keeps), pairs routed a sparse layer
+  ``decode_step(params, token, lens, caches, config)``  -> hidden [b, h]
+  ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
+
+beside ``init_params``, ``from_hf_weights``, ``quantize_dense``,
+``experts_held(params, config)`` and ``recurrent_layers(config)``.
 
 A call's prompt, token by token (each piece goes through the tokenizer on
 its own, so a candidate is tokenized once however many ballots show it):
@@ -19,7 +34,7 @@ The instruction is ``ballot.prompting.ballot_instruction``'s forced-output
 form: decoding is constrained to the ballot's keys, so no key list is
 spelled out.  The next token after the prompt is the key's first letter.
 At depth 2 (more than 20 candidates) the likeliest first letter is decoded
-and ONE step through the latent cache reads the second letter under the
+and ONE step through the decoder's caches reads the second letter under the
 chosen branch's mask; the backticks between a key's letters are the
 grammar's, not the model's, and are not decoded.
 
@@ -34,6 +49,7 @@ from __future__ import annotations
 import random
 import threading
 import time
+from functools import partial
 from typing import Optional
 
 import jax
@@ -43,18 +59,83 @@ import numpy as np
 from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
 from ..ops import causal_attention
+from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
-from . import glm_moe
-from .configs import GLM_4_7_FLASH, GLM_TEST_TINY, GlmMoeLiteConfig
+from . import glm_moe, qwen3_next
+from .configs import (
+    GLM_4_7_FLASH, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY,
+    GlmMoeLiteConfig, Qwen3NextConfig,
+)
 from .tokenizer import BaseTokenizer, load_tokenizer
 
 JUDGE_PRESETS = {
     "glm-4.7-flash": GLM_4_7_FLASH,
     "glm-test-tiny": GLM_TEST_TINY,
+    "qwen3-next-80b-a3b": QWEN3_NEXT_80B_A3B,
+    "qwen3-next-test-tiny": QWEN3_NEXT_TEST_TINY,
 }
+_DECODERS = {GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next}
 DEFAULT_PANEL = ((0, 1.0), (1, 1.0), (2, 1.0))  # (ballot seed, weight) a call
 MAX_PANEL = 8
 _LETTERS = len(ALPHABET)
+
+
+def decoder_of(config):
+    """The module that runs a preset's configuration."""
+    return _DECODERS[type(config)]
+
+
+def _masked(logprobs, letter_ids, valid):
+    """Vocabulary log-probabilities [b, V] at the letters' token ids [K];
+    letters that are no sibling read -inf."""
+    return jnp.where(valid, logprobs[:, letter_ids], -jnp.inf)
+
+
+@partial(jax.jit, static_argnames=("decoder", "config", "depth"))
+def judge_panel(
+    params, ids, lens, letter_ids, first_valid, second_valid, *,
+    decoder, config, depth: int,
+):
+    """A panel's calls in one program.  ids [b, s] right-padded prompts of
+    ``lens`` tokens, each ending where the key begins.  ``letter_ids`` [K]
+    are the key letters' token ids; ``first_valid`` [b, K] marks the letters
+    of a ballot's first level, ``second_valid`` [b, K, K] the sibling letters
+    under each first letter.  ``decoder`` is the module whose ``prefill``,
+    ``decode_step`` and ``head_logprobs`` run ``config``.
+
+    Per call: causal prefill (which leaves each layer's cache as it stands
+    after token ``lens - 1``), the head at the last real position, the first
+    level's masked log-probabilities; at depth 2 the likeliest letter is
+    decoded (greedy), one step runs through the caches, and the head is read
+    again under the chosen branch's mask.  ``votes`` [b, K] is
+    ``softmax_votes`` over the last read, a distribution over the K letters;
+    which candidate a letter selects is the host's to say, so one program
+    serves every candidate count.
+    """
+    b = ids.shape[0]
+    hidden, caches, loads = decoder.prefill(params, ids, config, lens=lens)
+    last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
+    first = _masked(decoder.head_logprobs(params, last, config), letter_ids, first_valid)
+    chosen = jnp.argmax(first, axis=1).astype(jnp.int32)
+    out = {
+        "first_logprobs": first,
+        "chosen": chosen,
+        "expert_load": jnp.stack(loads) if loads else jnp.zeros((0, 1), jnp.int32),
+    }
+    read, valid = first, first_valid
+    if depth == 2:
+        with jax.named_scope("decode_step"):
+            step = decoder.decode_step(params, letter_ids[chosen], lens, caches, config)
+            valid = second_valid[jnp.arange(b), chosen]
+            read = _masked(decoder.head_logprobs(params, step, config), letter_ids, valid)
+        out["second_logprobs"] = read
+    with jax.named_scope("ballot_vote"):
+        letters = jnp.broadcast_to(jnp.arange(valid.shape[1]), valid.shape)
+        out["votes"] = softmax_votes(
+            jnp.where(valid, read, 0.0), jnp.where(valid, letters, -1), valid,
+            valid.shape[1],
+        )
+    return out
 
 
 class _Call:
@@ -88,7 +169,7 @@ class TpuJudge:
         model: str = "glm-4.7-flash",
         *,
         params: Optional[dict] = None,
-        config: Optional[GlmMoeLiteConfig] = None,
+        config=None,
         tokenizer: Optional[BaseTokenizer] = None,
         dtype=None,
         max_tokens: int = 8192,
@@ -97,6 +178,7 @@ class TpuJudge:
     ) -> None:
         self.model_name = model
         self.config = config or JUDGE_PRESETS[model]
+        self.decoder = decoder_of(self.config)
         if max_tokens % 8:
             raise ValueError("JUDGE_MAX_TOKENS must be a multiple of 8")
         self.max_tokens = int(max_tokens)
@@ -108,7 +190,7 @@ class TpuJudge:
             vocab_size=self.config.vocab_size
         )
         if params is None:
-            params = glm_moe.init_params(
+            params = self.decoder.init_params(
                 jax.random.PRNGKey(seed), self.config, dtype=dtype
             )
         if quantize not in ("none", "int8"):
@@ -116,7 +198,7 @@ class TpuJudge:
         if quantize == "int8":
             import dataclasses
 
-            params = glm_moe.quantize_dense(params)
+            params = self.decoder.quantize_dense(params)
             self.config = dataclasses.replace(self.config, quantize="int8")
         self.params = params
         self.device_timing = True
@@ -139,8 +221,19 @@ class TpuJudge:
             "padded_tokens": 0,
             "attention_work_over_causal": 0.0,
             "expert_load_max_over_mean_sum": 0.0,
-            "expert_tokens": [0] * self.config.n_routed_experts,
+            # pairs (token, choice) the routers made, those sent to an expert
+            # held here and those sent elsewhere; ``expert_tokens`` is over
+            # the held
+            "expert_pairs_routed": 0,
+            "expert_pairs_here": 0,
+            "expert_pairs_elsewhere": 0,
+            # the dispatches' mean share of a recurrent layer's positions
+            # that were padding (0 for a decoder without a recurrence)
+            "delta_rule_padding_share": 0.0,
+            "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
+        self._held = len(self._stats["expert_tokens"])
+        self._recurrent = self.decoder.recurrent_layers(self.config) > 0
 
     # -- host ---------------------------------------------------------------
 
@@ -204,13 +297,14 @@ class TpuJudge:
     # -- device -------------------------------------------------------------
 
     def _run(self, ids, lens, first_valid, second_valid, depth: int):
-        return glm_moe.judge_panel(
+        return judge_panel(
             self.params,
             jnp.asarray(ids),
             jnp.asarray(lens),
             self._letter_ids_dev,
             jnp.asarray(first_valid),
             jnp.asarray(second_valid),
+            decoder=self.decoder,
             config=self.config,
             depth=depth,
         )
@@ -270,6 +364,11 @@ class TpuJudge:
         return confidence, prepared.tokens, ballots
 
     def _count(self, prepared: PreparedPanel, load) -> None:
+        # a decoder that holds a share of its router's experts counts, after
+        # the held ones, the pairs routed elsewhere
+        elsewhere = int(load[:, self._held:].sum()) if load.size else 0
+        load = load[:, :self._held] if load.size else load
+        padding = 1.0 - prepared.tokens / prepared.ids.size if self._recurrent else 0.0
         ratio = 0.0
         if load.size and load.sum():
             # a dispatch's largest load over its mean, the worst layer's
@@ -290,8 +389,15 @@ class TpuJudge:
                 work - s["attention_work_over_causal"]
             ) / s["dispatches"]
             s["expert_load_max_over_mean_sum"] += ratio
+            s["delta_rule_padding_share"] += (
+                padding - s["delta_rule_padding_share"]
+            ) / s["dispatches"]
+            s["expert_pairs_elsewhere"] += elsewhere
+            s["expert_pairs_routed"] += elsewhere
             if load.size:
                 totals = load.sum(axis=0)
+                s["expert_pairs_here"] += int(totals.sum())
+                s["expert_pairs_routed"] += int(totals.sum())
                 s["expert_tokens"] = [
                     a + int(b) for a, b in zip(s["expert_tokens"], totals)
                 ]
@@ -317,7 +423,7 @@ class TpuJudge:
     # -- introspection --------------------------------------------------------
 
     def jit_stats(self) -> dict:
-        return {"judge_panel": glm_moe.judge_panel._cache_size()}
+        return {"judge_panel": judge_panel._cache_size()}
 
     def stats(self) -> dict:
         """The ``judge`` section of /metrics."""
@@ -331,11 +437,12 @@ class TpuJudge:
             }
 
 
-def load_judge_params(path: str, config: GlmMoeLiteConfig, dtype=None):
+def load_judge_params(path: str, config, dtype=None):
     """(params, config) from an HF checkpoint, one file or sharded
-    (``loading.open_checkpoint``); the depth served is the checkpoint's."""
+    (``loading.open_checkpoint``); the depth served is the checkpoint's, and
+    so is the share of a wider router's experts."""
     from .loading import open_checkpoint
 
     if dtype is None:
         dtype = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    return glm_moe.from_hf_weights(open_checkpoint(path), config, dtype=dtype)
+    return decoder_of(config).from_hf_weights(open_checkpoint(path), config, dtype=dtype)

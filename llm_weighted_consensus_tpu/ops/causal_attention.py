@@ -12,6 +12,9 @@ Layout: the projections' own.  q, k and v are [b, s, heads * hd] and the
 context is the [b, s, heads * hd] array the output projection reads; a head
 is one hd-wide column block (hd a multiple of 128 lanes, or the whole of
 the last dimension), so there is no head transpose around the kernel.
+Keys and values may have fewer heads than the queries (``kv_heads``): a
+query head's key block is found by ``head // group`` in the index map, and
+with a key head a query head the index map is the one it always was.
 
 The schedule follows from (s, block_q, block_k, hd) and nothing else:
 
@@ -219,21 +222,28 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("heads", "scale", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("heads", "scale", "kv_heads", "block_q", "block_k", "interpret"),
 )
 def causal_attention_blockwise(
-    q, k, v, *, heads: int, scale: float, block_q: int = 0, block_k: int = 0,
-    interpret: bool | None = None,
+    q, k, v, *, heads: int, scale: float, kv_heads: int = 0, block_q: int = 0,
+    block_k: int = 0, interpret: bool | None = None,
 ):
-    """q, k, v: [b, s, heads * hd] -> context [b, s, heads * hd]; position i
-    attends positions <= i.  The jitted function's name is the kernel's name
-    in a device trace."""
+    """q: [b, s, heads * hd], k and v: [b, s, kv_heads * hd] (``kv_heads``
+    0: a key head a query head) -> context [b, s, heads * hd]; position i
+    attends positions <= i.  With fewer key heads, query head h reads key
+    head ``h // (heads / kv_heads)`` through the block's index: no key is
+    repeated in memory.  The jitted function's name is the kernel's name in
+    a device trace."""
     b, s, width = q.shape
     hd = width // heads
+    group = heads // (kv_heads or heads)
     if interpret is None:
         interpret = _interpret()
     if hd * heads != width or (not interpret and heads > 1 and hd % _LANES):
         raise ValueError(f"heads of {hd} lanes cannot be carved from {width}")
+    if group * (kv_heads or heads) != heads or k.shape[-1] * group != width:
+        raise ValueError(f"{heads} query heads on keys of width {k.shape[-1]}")
     bq = block_q or block_for(s)
     bk = block_k or block_for(s)
     # the running maximum and sum stand once a lane where the shapes allow
@@ -245,7 +255,7 @@ def causal_attention_blockwise(
         return bi, qi_of_step[step], h
 
     def kv_index(bi, h, step, qi_of_step, ki_of_step):
-        return bi, ki_of_step[step], h
+        return bi, ki_of_step[step], h if group == 1 else h // group
 
     return pl.pallas_call(
         functools.partial(
@@ -275,11 +285,13 @@ def causal_attention_blockwise(
     )(jnp.asarray(qi_of_step), jnp.asarray(ki_of_step), q, k, v)
 
 
-def causal_attention_einsum(q, k, v, *, heads: int, scale: float):
+def causal_attention_einsum(q, k, v, *, heads: int, scale: float, kv_heads: int = 0):
     """The kernel's plain twin: whole [s, s] scores (tests, tiny sizes)."""
     b, s, width = q.shape
     hd = width // heads
-    qh, kh, vh = (x.reshape(b, s, heads, hd) for x in (q, k, v))
+    qh, kh, vh = (x.reshape(b, s, -1, hd) for x in (q, k, v))
+    if kv_heads and kv_heads != heads:
+        kh, vh = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (kh, vh))
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", qh, kh, preferred_element_type=jnp.float32
     ) * scale

@@ -153,6 +153,25 @@ def route_layout(expert_of_pair, experts: int, tile: int):
     return route_layout_weighted(expert_of_pair, nothing, experts, tile)[:5]
 
 
+def route_layout_held(expert_of_pair, pair_weight, held: int, tile: int):
+    """``route_layout_weighted`` for a layer that holds experts 0..held-1 of
+    a wider router: a pair whose expert lies elsewhere gets no tile that is
+    ever multiplied.  The pairs elsewhere are one more group behind the held
+    ones, past ``tiles_used``, so every table keeps its static size (``M +
+    (held + 1) * tile`` rows: every pair may be held here, and none that is
+    held is dropped, whatever the routing).  Returns the six tables (``counts``
+    [held + 1], the last entry the pairs elsewhere; ``row_of_pair`` of a pair
+    elsewhere points at a row no kernel writes: mask it by ``here``) and
+    ``here`` [M] bool."""
+    here = expert_of_pair < held
+    pair_of_row, row_of_pair, tile_expert, _, counts, row_weight = route_layout_weighted(
+        jnp.minimum(expert_of_pair, held), pair_weight, held + 1, tile
+    )
+    tiles_used = jnp.sum(-(-counts[:held] // tile), keepdims=True).astype(jnp.int32)
+    tile_expert = jnp.minimum(tile_expert, held - 1)  # a weight block that exists
+    return (pair_of_row, row_of_pair, tile_expert, tiles_used, counts, row_weight), here
+
+
 def _kernel(_, tiles_used_ref, *refs, x_chunks: int, swiglu: bool, weighted: bool):
     """One row tile times its expert's weight block, float32 accumulation;
     the epilogue on the accumulator, before the one cast: ``silu(x @ w) *
@@ -183,11 +202,13 @@ def _kernel(_, tiles_used_ref, *refs, x_chunks: int, swiglu: bool, weighted: boo
 def column_chunks(rows: int, width: int, itemsize: int = 2) -> int:
     """Into how many column chunks a [rows, width] table goes where XLA is to
     gather rows from it: the least power of two that leaves a chunk within
-    ``GATHER_TABLE_BYTES`` (and whole lane tiles wide)."""
+    ``GATHER_TABLE_BYTES`` (and whole lane tiles wide); one where even a
+    lane tile's width of it is larger, since a chunk that stays in HBM costs
+    a descriptor a row like the whole row does."""
     chunks, size = 1, rows * width * itemsize
     while size > chunks * GATHER_TABLE_BYTES and width % (256 * chunks) == 0:
         chunks *= 2
-    return chunks
+    return chunks if size <= chunks * GATHER_TABLE_BYTES else 1
 
 
 @functools.partial(

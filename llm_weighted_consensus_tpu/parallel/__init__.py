@@ -23,8 +23,10 @@ TPU-shaped:
 * ``dist``      — multi-host (DCN) process-group initialization.
 
 No pipeline parallelism (a 12-24 layer encoder has no use for stages).
-The judge's experts (models/glm_moe.py) all live on one chip: no expert
-parallelism yet.
+A judge's experts live on one chip.  models/qwen3_next.py serves the
+share of a wider router's experts that its checkpoint names and returns the
+partial sum; the exchange of tokens and sums over the chips that would hold
+the other shares is not built: no expert parallelism yet.
 """
 
 from .dist import maybe_initialize_distributed  # noqa: F401

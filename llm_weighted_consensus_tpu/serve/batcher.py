@@ -1603,7 +1603,7 @@ class DeviceBatcher:
     def _dispatch_judge(self, group: list, embedder):
         """One item, one program: causal prefill of every call, one decoded
         key letter through the latent cache, the masked reads and the vote
-        (models/glm_moe.py ``judge_panel``)."""
+        (models/judge.py ``judge_panel``)."""
         (item,) = group
         judge = self.judge_model
         prepared = (

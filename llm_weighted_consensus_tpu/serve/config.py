@@ -75,12 +75,20 @@ TPU additions:
   orbax dirs.
 * ``JUDGE_MODEL`` / ``JUDGE_WEIGHTS`` / ``JUDGE_VOCAB`` /
   ``JUDGE_MAX_TOKENS`` / ``JUDGE_QUANTIZE`` — a causal sparse-expert
-  latent-attention decoder (``glm-4.7-flash``; models/glm_moe.py) serving
-  ``POST /consensus {"scorer": "judge"}``: a LOCAL judge panel, each call
-  a prefill of the candidates under a seeded prefix-tree ballot, one
-  decoded key letter and a masked read of its siblings' log-probabilities
-  (models/judge.py).  ``JUDGE_WEIGHTS`` is an HF checkpoint, one
-  ``model.safetensors`` or sharded; the depth served is the checkpoint's.
+  decoder serving ``POST /consensus {"scorer": "judge"}``: a LOCAL judge
+  panel, each call a prefill of the candidates under a seeded prefix-tree
+  ballot, one decoded key letter and a masked read of its siblings'
+  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of two
+  decoders: ``glm-4.7-flash`` (latent attention, every expert held;
+  models/glm_moe.py) or ``qwen3-next-80b-a3b`` (gated delta-rule layers
+  three to one with gated full attention, a recurrent state and a
+  convolution tail cached beside the keys; models/qwen3_next.py); each has
+  a tiny twin for tests (``glm-test-tiny``, ``qwen3-next-test-tiny``).
+  ``JUDGE_WEIGHTS`` is an HF checkpoint, one ``model.safetensors`` or
+  sharded; the depth served is the checkpoint's, and so is the share of
+  the experts where it names experts 0..E-1 of a wider router (one chip's
+  share of a layer's experts: the pairs routed elsewhere are left out of
+  this chip's partial sum).
   ``JUDGE_MAX_TOKENS`` (default 8192) is the ONE sequence bucket every
   call is padded to.  ``JUDGE_QUANTIZE=int8`` runs the dense products
   W8A8 (``quant.dense_int8``).  A server with a judge and no embedder

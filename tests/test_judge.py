@@ -24,6 +24,7 @@ from llm_weighted_consensus_tpu.ballot import PrefixTree, extract_vote  # noqa: 
 from llm_weighted_consensus_tpu.ballot.tree import ALPHABET  # noqa: E402
 from llm_weighted_consensus_tpu.models import glm_moe  # noqa: E402
 from llm_weighted_consensus_tpu.models.configs import GLM_TEST_TINY  # noqa: E402
+from llm_weighted_consensus_tpu.models import judge as judge_module  # noqa: E402
 from llm_weighted_consensus_tpu.models.judge import TpuJudge  # noqa: E402
 from llm_weighted_consensus_tpu.models.spm import UnigramTokenizer  # noqa: E402
 from llm_weighted_consensus_tpu.ops import causal_attention as attn  # noqa: E402
@@ -113,9 +114,9 @@ def test_decode_through_the_cache_matches_the_full_forward(state, loaded, prompt
     ids, lens = prompts
     letters = jnp.arange(10, 30, dtype=jnp.int32)
     first, second = panel_masks()
-    out = glm_moe.judge_panel(
+    out = judge_module.judge_panel(
         params, jnp.asarray(ids), jnp.asarray(lens), letters,
-        jnp.asarray(first), jnp.asarray(second), config=config, depth=2,
+        jnp.asarray(first), jnp.asarray(second), decoder=glm_moe, config=config, depth=2,
     )
     for row, n in enumerate(lens):
         token = int(letters[out["chosen"][row]])

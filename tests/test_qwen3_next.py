@@ -1,0 +1,624 @@
+"""The second judge (``models/qwen3_next.py``): gated delta-rule layers three
+to one with gated full attention, a share of a wider router's experts held,
+behind ``POST /consensus`` ``scorer: judge``.
+
+Against the plain reference ``tests/qwen3_next_reference.py`` (numpy float64,
+the recurrent form, nothing of the program) on the CPU at the tiny preset,
+seeded weights.
+"""
+
+import dataclasses
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(__file__))
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+import qwen3_next_reference as reference  # noqa: E402
+from test_judge import candidates, log_softmax, panel_masks, tiny_tokenizer  # noqa: E402
+from llm_weighted_consensus_tpu.models import decoder_parts, qwen3_next  # noqa: E402
+from llm_weighted_consensus_tpu.models import judge as judge_module  # noqa: E402
+from llm_weighted_consensus_tpu.models.configs import QWEN3_NEXT_TEST_TINY  # noqa: E402
+from llm_weighted_consensus_tpu.models.judge import JUDGE_PRESETS, TpuJudge  # noqa: E402
+from llm_weighted_consensus_tpu.ops import causal_attention as attn  # noqa: E402
+from llm_weighted_consensus_tpu.ops import gated_delta  # noqa: E402
+from llm_weighted_consensus_tpu.ops import grouped_matmul as gmm  # noqa: E402
+
+C = QWEN3_NEXT_TEST_TINY
+SEQ = 96
+MEMORY = 0.01  # |g| a token: a state that lives across every chunk of a call
+
+
+def hf_config(config=C, **changed) -> dict:
+    return {
+        "vocab_size": config.vocab_size,
+        "hidden_size": config.hidden_size,
+        "num_hidden_layers": config.num_layers,
+        "full_attention_interval": config.full_attention_interval,
+        "num_attention_heads": config.num_heads,
+        "num_key_value_heads": config.num_kv_heads,
+        "head_dim": config.head_dim,
+        "partial_rotary_factor": config.partial_rotary_factor,
+        "linear_num_key_heads": config.linear_num_key_heads,
+        "linear_num_value_heads": config.linear_num_value_heads,
+        "linear_key_head_dim": config.linear_key_head_dim,
+        "linear_value_head_dim": config.linear_value_head_dim,
+        "linear_conv_kernel_dim": config.linear_conv_kernel_dim,
+        "moe_intermediate_size": config.moe_intermediate_size,
+        "shared_expert_intermediate_size": config.shared_expert_intermediate_size,
+        "num_experts": config.num_experts,
+        "num_experts_per_tok": config.num_experts_per_tok,
+        "rope_theta": config.rope_theta,
+        "rms_norm_eps": config.rms_norm_eps,
+        **changed,
+    }
+
+
+@pytest.fixture(scope="module")
+def state():
+    """Every expert of the router held, and a memory that lasts."""
+    return reference.random_state(hf_config(), seed=3, memory=MEMORY)
+
+
+@pytest.fixture(scope="module")
+def loaded(state):
+    return qwen3_next.from_hf_weights(state, C)
+
+
+@pytest.fixture(scope="module")
+def prompts():
+    rng = np.random.default_rng(1)
+    lens = np.array([90, 77, SEQ], np.int32)
+    ids = np.zeros((3, SEQ), np.int32)
+    for row, n in enumerate(lens):
+        ids[row, :n] = rng.integers(4, C.vocab_size, size=n)
+    return ids, lens
+
+
+# -- the chunked kernel against the recurrence -----------------------------------
+
+
+def rule_inputs(b, s, hk, hv, dk, dv, seed=0, memory=MEMORY):
+    rng = np.random.default_rng(seed)
+    q = rng.standard_normal((b, s, hk, dk)) + 0.3  # of any length: the rule
+    k = rng.standard_normal((b, s, hk, dk)) + 0.3  # takes them to unit length
+    v = rng.standard_normal((b, s, hv, dv))
+    g = -memory * (0.5 + rng.random((b, s, hv)))
+    beta = 0.2 + 0.6 * rng.random((b, s, hv))
+    return q, k, v, g, beta
+
+
+def recurrent(q, k, v, g, beta):
+    """The reference's loop, a call at a time, over unit keys and queries."""
+    per = v.shape[2] // q.shape[2]
+    q, k = reference.l2(q) * q.shape[-1] ** -0.5, reference.l2(k)
+    outs, states = zip(
+        *(
+            reference.delta_rule(
+                np.repeat(q[i], per, axis=1), np.repeat(k[i], per, axis=1), v[i], g[i], beta[i]
+            )
+            for i in range(q.shape[0])
+        )
+    )
+    return np.stack(outs), np.stack(states)
+
+
+def chunked(q, k, v, g, beta, **kwargs):
+    b, s = q.shape[:2]
+    out, state = gated_delta.gated_delta_rule(
+        *(jnp.asarray(x.reshape(b, s, -1), jnp.float32) for x in (q, k, v)),
+        jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32),
+        key_heads=q.shape[2], **kwargs,
+    )
+    return np.asarray(out, np.float64).reshape(v.shape), np.asarray(state, np.float64)
+
+
+@pytest.mark.parametrize(
+    "s,chunk,heads_per_step",
+    [(64, 16, 2), (70, 16, 4), (96, 32, 2), (100, 32, 1), (130, 64, 4), (128, 128, 4), (200, 128, 2)],
+)
+def test_chunked_rule_carries_a_long_memory_across_chunks(s, chunk, heads_per_step):
+    """|g| about 0.01 a token: what the first chunk wrote is still most of
+    the state at the last, so a wrong carry between chunks cannot pass."""
+    inputs = rule_inputs(2, s, 2, 4, 16, 16, seed=s)
+    want, want_state = recurrent(*inputs)
+    got, got_state = chunked(*inputs, chunk=chunk, heads_per_step=heads_per_step)
+    assert np.abs(got - want).max() < 2e-5
+    assert np.abs(got_state - want_state).max() < 2e-5
+    # the memory does live: the last chunk alone, from an empty state, is
+    # far from the last chunk of the whole call
+    last = (-(-s // chunk) - 1) * chunk
+    if last:
+        alone, _ = recurrent(*(x[:, last:] for x in inputs))
+        assert np.abs(alone - want[:, last:]).max() > 0.05
+
+
+def test_chunked_rule_forgets_with_the_seeded_kind_of_decay():
+    """A_log and dt_bias drawn N(0, 0.02) give |g| about 0.69 a token: the
+    rule still agrees, and here a lost carry WOULD pass (why the test above
+    draws a long memory)."""
+    inputs = rule_inputs(1, 64, 2, 4, 16, 16, seed=5, memory=0.69)
+    want, _ = recurrent(*inputs)
+    got, _ = chunked(*inputs, chunk=16)
+    assert np.abs(got - want).max() < 2e-5
+    alone, _ = recurrent(*(x[:, 48:] for x in inputs))
+    assert np.abs(alone[:, 8:] - want[:, 56:]).max() < 1e-2
+
+
+def test_positions_with_beta_0_and_g_0_leave_the_state_as_it_was():
+    q, k, v, g, beta = rule_inputs(1, 48, 2, 4, 16, 16, seed=9)
+    g[:, 37:], beta[:, 37:] = 0.0, 0.0
+    _, padded = chunked(q, k, v, g, beta, chunk=16)
+    _, exact = recurrent(*(x[:, :37] for x in (q, k, v, g, beta)))
+    assert np.abs(padded - exact).max() < 2e-5
+
+
+def test_the_rule_divides_a_head_s_output_by_its_root_mean_square_where_asked():
+    inputs = rule_inputs(1, 40, 2, 4, 16, 16, seed=4)
+    want, _ = recurrent(*inputs)
+    want = want / np.sqrt(np.mean(want * want, axis=-1, keepdims=True) + 1e-6)
+    got, _ = chunked(*inputs, chunk=16, norm_eps=1e-6)
+    assert np.abs(got - want).max() < 2e-5
+    b, s = inputs[0].shape[:2]
+    twin, _ = gated_delta.gated_delta_recurrent(
+        *(jnp.asarray(x.reshape(b, s, -1), jnp.float32) for x in inputs[:3]),
+        jnp.asarray(inputs[3], jnp.float32), jnp.asarray(inputs[4], jnp.float32),
+        key_heads=2, norm_eps=1e-6,
+    )
+    assert np.abs(np.asarray(twin).reshape(want.shape) - want).max() < 2e-5
+
+
+def test_one_step_is_the_recurrence():
+    q, k, v, g, beta = rule_inputs(2, 9, 4, 4, 16, 16, seed=2)
+    _, state = recurrent(*(x[:, :8] for x in (q, k, v, g, beta)))
+    want, want_state = recurrent(q, k, v, g, beta)
+    out, new = gated_delta.gated_delta_step(
+        jnp.asarray(state, jnp.float32), *(jnp.asarray(x[:, 8], jnp.float32) for x in (q, k, v, g, beta))
+    )
+    assert np.abs(np.asarray(out) - want[:, 8]).max() < 2e-5
+    assert np.abs(np.asarray(new) - want_state).max() < 2e-5
+
+
+# -- the causal kernel with fewer key heads ----------------------------------------
+
+
+def test_causal_kernel_16_query_heads_on_2_key_heads():
+    rng = np.random.default_rng(0)
+    b, s, heads, kv, hd = 2, 64, 16, 2, 8
+    q = jnp.asarray(rng.standard_normal((b, s, heads * hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, s, kv * hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, kv * hd)), jnp.float32)
+    want = attn.causal_attention_einsum(q, k, v, heads=heads, kv_heads=kv, scale=hd ** -0.5)
+    for block in (16, 32, 64):
+        got = attn.causal_attention_blockwise(
+            q, k, v, heads=heads, kv_heads=kv, scale=hd ** -0.5, block_q=block, block_k=block
+        )
+        assert np.abs(np.asarray(got - want)).max() < 2e-5
+    # by hand: query head 9 reads key head 1
+    scores = np.einsum("qd,kd->qk", np.asarray(q[0, :, 72:80]), np.asarray(k[0, :, 8:16]))
+    scores = np.where(np.tril(np.ones((s, s), bool)), scores * hd ** -0.5, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    assert np.abs(probs @ np.asarray(v[0, :, 8:16]) - np.asarray(want[0, :, 72:80])).max() < 2e-5
+
+
+def test_a_key_head_a_query_head_is_the_program_it_was():
+    """20 heads on 20 key heads: the index map divides by nothing and the
+    traced program is the same whether ``kv_heads`` is named or not."""
+    q = jax.ShapeDtypeStruct((1, 64, 20 * 8), jnp.float32)
+    plain = jax.make_jaxpr(
+        lambda q, k, v: attn.causal_attention_blockwise(q, k, v, heads=20, scale=0.25)
+    )(q, q, q)
+    named = jax.make_jaxpr(
+        lambda q, k, v: attn.causal_attention_blockwise(q, k, v, heads=20, kv_heads=20, scale=0.25)
+    )(q, q, q)
+    grouped = jax.make_jaxpr(
+        lambda q, k, v: attn.causal_attention_blockwise(q, k, v, heads=20, kv_heads=4, scale=0.25)
+    )(q, jax.ShapeDtypeStruct((1, 64, 4 * 8), jnp.float32), jax.ShapeDtypeStruct((1, 64, 4 * 8), jnp.float32))
+    assert str(plain) == str(named)
+    assert str(grouped) != str(plain)
+    with pytest.raises(ValueError, match="query heads on keys"):
+        attn.causal_attention_blockwise(
+            jnp.zeros((1, 64, 160)), jnp.zeros((1, 64, 24)), jnp.zeros((1, 64, 24)),
+            heads=20, kv_heads=4, scale=0.25,
+        )
+
+
+# -- the decoder against the plain reference -------------------------------------------
+
+
+def test_prefill_logits_match_the_reference(state, loaded, prompts):
+    params, config = loaded
+    ids, lens = prompts
+    hidden, caches, loads = qwen3_next.prefill(
+        params, jnp.asarray(ids), config, lens=jnp.asarray(lens)
+    )
+    assert len(caches) == config.num_layers == len(loads) == 4
+    for row, n in enumerate(lens):
+        want = log_softmax(reference.logits(state, hf_config(), ids[row, :n]))
+        got = np.asarray(qwen3_next.head_logprobs(params, hidden[row, :n], config))
+        assert np.abs(got - want).max() < 3e-5
+    # every real and padded token went to exactly k experts, all held here
+    loads = np.asarray(loads)
+    assert loads.shape == (4, C.num_experts + 1) and (loads[:, -1] == 0).all()
+    assert (loads.sum(axis=1) == 3 * SEQ * config.num_experts_per_tok).all()
+
+
+def test_right_padded_calls_leave_state_and_tail_at_their_own_length(state, loaded, prompts):
+    """Three calls of different ``lens`` in one batch: each linear layer's
+    recurrent state and convolution tail equal the unpadded call's."""
+    params, config = loaded
+    ids, lens = prompts
+    _, caches, _ = qwen3_next.prefill(params, jnp.asarray(ids), config, lens=jnp.asarray(lens))
+    for row, n in enumerate(lens):
+        want = []
+        reference.hidden(state, hf_config(), ids[row, :n], caches=want)
+        linear = [c for i, c in enumerate(caches) if not config.is_full_attention(i)]
+        assert len(want) == len(linear) == 3
+        for (tail, rule), (want_tail, want_rule) in zip(linear, want):
+            size = np.abs(want_rule).max()
+            assert size > 1e-3  # a state worth comparing
+            assert np.abs(np.asarray(tail[row]) - want_tail).max() < 1e-6
+            assert np.abs(np.asarray(rule[row]) - want_rule).max() < 1e-4 * size
+    # and the same call in a narrower bucket, alone, gives the same state
+    alone = qwen3_next.prefill(
+        params, jnp.asarray(ids[1:2, :80]), config, lens=jnp.asarray(lens[1:2])
+    )[1]
+    assert np.abs(np.asarray(alone[0][1][0] - caches[0][1][1])).max() < 1e-7
+
+
+def test_a_call_shorter_than_the_convolution_has_zeros_in_its_tail(loaded):
+    params, config = loaded
+    ids = jnp.asarray(np.full((1, 16), 7, np.int32))
+    _, caches, _ = qwen3_next.prefill(params, ids, config, lens=jnp.asarray([2], jnp.int32))
+    tail = np.asarray(caches[0][0][0])
+    assert (tail[0] == 0).all() and np.abs(tail[1:]).max() > 0
+
+
+def test_decode_through_both_caches_matches_the_full_forward(state, loaded, prompts):
+    """Prefill, one decoded letter (one recurrent step through the linear
+    layers, one row against the cached keys in the full layer), the second
+    read: against the reference's one forward over T + 1."""
+    params, config = loaded
+    ids, lens = prompts
+    letters = jnp.arange(10, 30, dtype=jnp.int32)
+    first, second = panel_masks()
+    out = judge_module.judge_panel(
+        params, jnp.asarray(ids), jnp.asarray(lens), letters,
+        jnp.asarray(first), jnp.asarray(second), decoder=qwen3_next, config=config, depth=2,
+    )
+    for row, n in enumerate(lens):
+        token = int(letters[out["chosen"][row]])
+        full = log_softmax(reference.logits(state, hf_config(), np.append(ids[row, :n], token)))
+        got_first = np.asarray(out["first_logprobs"][row])
+        got_second = np.asarray(out["second_logprobs"][row])
+        assert np.abs(got_first[:4] - full[n - 1, 10:14]).max() < 3e-5
+        assert np.abs(got_second[:16] - full[n, 10:26]).max() < 3e-5
+        assert np.isneginf(got_first[4:]).all() and np.isneginf(got_second[16:]).all()
+        assert int(out["chosen"][row]) == int(np.argmax(full[n - 1, 10:14]))
+    votes = np.asarray(out["votes"])
+    assert np.allclose(votes.sum(axis=1), 1.0, atol=1e-6) and (votes[:, 16:] == 0).all()
+
+
+def test_router_is_softmax_top_k_normalised(state, loaded):
+    params, config = loaded
+    h = np.random.default_rng(2).standard_normal((50, C.hidden_size)).astype(np.float32)
+    gate = np.asarray(state["model.layers.1.mlp.gate.weight"], np.float64)
+    want_chosen, want_weight = reference.route(h.astype(np.float64), gate, C.num_experts_per_tok)
+    chosen, weight = qwen3_next.route(jnp.asarray(h), params["layers"][1]["moe"], config)
+    assert (np.asarray(chosen) == want_chosen).all()
+    assert np.abs(np.asarray(weight) - want_weight).max() < 1e-6
+    assert np.allclose(np.asarray(weight).sum(axis=1), 1.0, atol=1e-6)
+
+
+# -- the share, tied to the model ----------------------------------------------------------
+
+
+def share_of(state, experts):
+    """The checkpoint a chip holding ``experts`` (renumbered from 0) would be
+    given: everything but the other chips' experts."""
+    out = {k: v for k, v in state.items() if ".mlp.experts." not in k}
+    for name, value in state.items():
+        if ".mlp.experts." in name:
+            head, rest = name.split(".mlp.experts.")
+            e, kind = rest.split(".", 1)
+            if int(e) in experts:
+                out[f"{head}.mlp.experts.{experts.index(int(e))}.{kind}"] = value
+    return out
+
+
+def test_four_shares_add_up_to_the_uncut_layer(state):
+    """The router is 16 wide and 4 a token; four chips hold 4 experts each.
+    Chip c's layer gives Σ over the pairs whose expert it holds; the four
+    partial sums and the gated shared expert, counted once, are the
+    reference's whole layer.  (A chip holds experts 0..3 of ITS numbering:
+    the router's rows are permuted so that its experts come first.)"""
+    cfg = hf_config()
+    rng = np.random.default_rng(7)
+    h = (rng.standard_normal((40, C.hidden_size)) * 0.5).astype(np.float32)
+    base = "model.layers.0"
+
+    def get(name):
+        return np.asarray(state[name], np.float64)
+
+    whole = reference.sparse_half(h.astype(np.float64), state, get, cfg, base)
+    total = np.zeros_like(whole)
+    pairs_here = 0
+    for chip in range(4):
+        mine = list(range(4 * chip, 4 * chip + 4))
+        order = mine + [e for e in range(16) if e not in mine]
+        held = share_of(state, mine)
+        held[f"{base}.mlp.gate.weight"] = state[f"{base}.mlp.gate.weight"][order]
+        for i in range(1, 4):
+            held[f"model.layers.{i}.mlp.gate.weight"] = state[f"model.layers.{i}.mlp.gate.weight"][order]
+        params, config = qwen3_next.from_hf_weights(held, C)
+        assert qwen3_next.experts_held(params, config) == 4
+        moe = params["layers"][0]["moe"]
+        got, counts = qwen3_next._moe(jnp.asarray(h), moe, config)
+        # the reference's partial sum over the same experts, the shared
+        # expert in it once
+        want = reference.sparse_half(
+            h.astype(np.float64), state, get, cfg, base, experts=mine, shared=True
+        )
+        assert np.abs(np.asarray(got, np.float64) - want).max() < 2e-6
+        shared = reference.sparse_half(h.astype(np.float64), state, get, cfg, base, experts=[])
+        total += np.asarray(got, np.float64) - (shared if chip else 0.0)
+        counts = np.asarray(counts)
+        assert counts.shape == (5,) and counts.sum() == 40 * C.num_experts_per_tok
+        pairs_here += counts[:4].sum()
+    assert pairs_here == 40 * C.num_experts_per_tok  # every pair held somewhere, once
+    assert np.abs(total - whole).max() < 5e-6
+
+
+@pytest.mark.parametrize("held,tokens,k", [(4, 300, 1), (4, 64, 4), (2, 40, 3)])
+def test_no_pair_held_here_is_dropped_at_the_most_uneven_routing(held, tokens, k):
+    """Every token to ONE held expert (and its other choices elsewhere): the
+    layout holds them all, the kernels multiply them all."""
+    rng = np.random.default_rng(tokens)
+    hidden, width, router = 32, 16, 16
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    p = {
+        "w_gate": jnp.asarray(rng.standard_normal((held, hidden, width)) * 0.1, jnp.float32),
+        "w_up": jnp.asarray(rng.standard_normal((held, hidden, width)) * 0.1, jnp.float32),
+        "w_down": jnp.asarray(rng.standard_normal((held, width, hidden)) * 0.1, jnp.float32),
+    }
+    chosen = np.full((tokens, k), 1, np.int32)  # the one held expert everyone wants
+    chosen[:, 1:] = held + 1 + np.arange(k - 1)  # the rest elsewhere
+    weight = rng.random((tokens, k)).astype(np.float32)
+    got, counts = decoder_parts.experts_grouped(
+        h, jnp.asarray(chosen), jnp.asarray(weight), p, router, held=held
+    )
+    counts = np.asarray(counts)
+    assert counts[1] == tokens and counts[-1] == tokens * (k - 1) and counts.sum() == tokens * k
+    x = np.asarray(h, np.float64)
+    gate = x @ np.asarray(p["w_gate"][1], np.float64)
+    up = x @ np.asarray(p["w_up"][1], np.float64)
+    want = (reference.silu(gate) * up) @ np.asarray(p["w_down"][1], np.float64)
+    assert np.abs(np.asarray(got, np.float64) - want * weight[:, :1]).max() < 1e-5
+
+
+def test_held_layout_puts_the_pairs_elsewhere_past_the_tiles_used():
+    expert = jnp.asarray([0, 5, 1, 7, 1, 6, 0, 9], jnp.int32)
+    weight = jnp.arange(8, dtype=jnp.float32) + 1
+    (pair_of_row, row_of_pair, tile_expert, used, counts, row_weight), here = gmm.route_layout_held(
+        expert, weight, 2, 16
+    )
+    assert np.asarray(here).tolist() == [True, False, True, False, True, False, True, False]
+    assert np.asarray(counts).tolist() == [2, 2, 4] and int(used[0]) == 2
+    assert int(np.asarray(tile_expert).max()) == 1  # a weight block that exists
+    rows = np.asarray(row_of_pair)
+    assert sorted(rows[[0, 6]]) == [0, 1] and sorted(rows[[2, 4]]) == [16, 17]
+    assert (rows[[1, 3, 5, 7]] >= 32).all()
+    assert np.asarray(row_weight)[rows[[0, 6, 2, 4]]].tolist() == [1.0, 7.0, 3.0, 5.0]
+    assert np.asarray(pair_of_row)[rows].tolist() == list(range(8))
+
+
+def test_a_checkpoint_naming_128_of_512_experts_and_8_of_48_layers_loads_at_that_share():
+    """The checkpoint carries the share: no variable says it."""
+    wide = dataclasses.replace(
+        C, num_layers=48, num_experts=512, num_experts_per_tok=10, moe_intermediate_size=8,
+        shared_expert_intermediate_size=8, vocab_size=64,
+    )
+    cfg = hf_config(wide, num_hidden_layers=8)
+    state = reference.random_state(cfg, seed=1, held=128)
+    params, served = qwen3_next.from_hf_weights(state, wide)
+    assert served.num_layers == 8 and served.num_experts == 512
+    assert [("attn" in layer) for layer in params["layers"]] == [False, False, False, True] * 2
+    moe = params["layers"][0]["moe"]
+    assert moe["router"].shape == (C.hidden_size, 512) and moe["w_gate"].shape[0] == 128
+    assert qwen3_next.experts_held(params, served) == 128
+    ids = np.random.default_rng(0).integers(4, 64, size=(1, 24)).astype(np.int32)
+    hidden, _, loads = qwen3_next.prefill(params, jnp.asarray(ids), served)
+    want = log_softmax(reference.logits(state, cfg, ids[0]))
+    got = np.asarray(qwen3_next.head_logprobs(params, hidden[0], served))
+    assert np.abs(got - want).max() < 3e-5
+    loads = np.asarray(loads)
+    assert loads.shape == (8, 129) and (loads.sum(axis=1) == 240).all()
+    assert 0 < loads[:, :128].sum() < loads.sum()  # some here, some elsewhere
+    with pytest.raises(ValueError, match="names no expert"):
+        qwen3_next.from_hf_weights(share_of(state, []), wide)
+
+
+# -- the judge: prompts, ballots, counters -------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def judge():
+    # a bucket of its own: the dispatch label's count is the process's, and the
+    # first judge's tests pin theirs
+    return TpuJudge("qwen3-next-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=440, seed=2)
+
+
+def test_presets_name_both_decoders():
+    from llm_weighted_consensus_tpu.models import glm_moe
+
+    assert judge_module.decoder_of(JUDGE_PRESETS["qwen3-next-80b-a3b"]) is qwen3_next
+    assert judge_module.decoder_of(JUDGE_PRESETS["qwen3-next-test-tiny"]) is qwen3_next
+    assert judge_module.decoder_of(JUDGE_PRESETS["glm-4.7-flash"]) is glm_moe
+    published = JUDGE_PRESETS["qwen3-next-80b-a3b"]
+    assert (published.num_layers, published.num_experts, published.num_experts_per_tok) == (48, 512, 10)
+    assert qwen3_next.recurrent_layers(published) == 36 and published.rotary_dim == 64
+
+
+def test_judge_counts_the_pairs_held_and_the_padding(judge):
+    before = judge.stats()
+    confidence, tokens, ballots = judge.judge(
+        candidates(24, np.random.default_rng(3)), "w7 w8 w9", [(5, 3.0), (6, 2.0), (7, 1.0)]
+    )
+    assert len(confidence) == 24 and abs(confidence.sum() - 1.0) < 1e-6 and len(ballots) == 3
+    stats = judge.stats()
+    slots = 3 * judge.max_tokens
+    pairs = slots * C.num_experts_per_tok * C.num_layers
+    assert stats["expert_pairs_here"] - before["expert_pairs_here"] == pairs
+    assert stats["expert_pairs_elsewhere"] == 0  # random init holds every expert
+    assert len(stats["expert_tokens"]) == C.num_experts
+    assert sum(stats["expert_tokens"]) == stats["expert_pairs_here"]
+    assert stats["delta_rule_padding_share"] == pytest.approx(1.0 - tokens / slots)
+    assert judge.jit_stats()["judge_panel"] >= 1
+
+
+def test_a_judge_holding_a_share_counts_the_pairs_elsewhere():
+    params = qwen3_next.init_params(jax.random.PRNGKey(0), C, held=4)
+    held = TpuJudge(
+        "qwen3-next-test-tiny", params=params, tokenizer=tiny_tokenizer(), max_tokens=96
+    )
+    held.judge(candidates(6, np.random.default_rng(1)), "w1", [(1, 1.0)])
+    stats = held.stats()
+    assert len(stats["expert_tokens"]) == 4
+    total = 96 * C.num_experts_per_tok * C.num_layers
+    assert stats["expert_pairs_here"] + stats["expert_pairs_elsewhere"] == total
+    assert 0 < stats["expert_pairs_here"] < total
+    assert sum(stats["expert_tokens"]) == stats["expert_pairs_here"]
+
+
+def test_int8_control_moves_the_reads_and_keeps_the_protocol():
+    base = TpuJudge("qwen3-next-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=SEQ, seed=2)
+    low = TpuJudge(
+        "qwen3-next-test-tiny", tokenizer=tiny_tokenizer(), max_tokens=SEQ, seed=2, quantize="int8"
+    )
+    assert low.config.quantize == "int8"
+    assert "kernel_q" in low.params["layers"][0]["linear"]["in_qkv"]
+    assert "kernel_q" in low.params["layers"][3]["attn"]["q"]
+    assert "kernel_q" in low.params["layers"][0]["moe"]["shared"]["up"]
+    assert "kernel_q" not in low.params["layers"][0]["moe"]
+    texts = candidates(8, np.random.default_rng(0))
+    a, _, ba = base.judge(texts, "w5", [(1, 1.0)])
+    b, _, bb = low.judge(texts, "w5", [(1, 1.0)])
+    assert abs(b.sum() - 1.0) < 1e-6 and set(ba[0]["siblings"]) == set(bb[0]["siblings"])
+    assert np.abs(a - b).max() > 0
+
+
+# -- /consensus scorer judge through the gateway and DeviceBatcher ---------------------------
+
+
+def test_consensus_judge_through_gateway_and_batcher(judge):
+    from fakes import FakeTransport
+    from test_gateway import go, post_json, with_client
+
+    from llm_weighted_consensus_tpu import archive, registry
+    from llm_weighted_consensus_tpu.clients.chat import ApiBase, DefaultChatClient
+    from llm_weighted_consensus_tpu.clients.multichat import MultichatClient
+    from llm_weighted_consensus_tpu.clients.score import ScoreClient
+    from llm_weighted_consensus_tpu.serve import build_app
+
+    chat = DefaultChatClient(FakeTransport([]), [ApiBase("https://up.example", "k")])
+    reg = registry.InMemoryModelRegistry()
+    store = archive.InMemoryArchive()
+    score = ScoreClient(chat, reg, archive_fetcher=store)
+    app = build_app(chat, score, MultichatClient(chat, reg, archive_fetcher=store), judge=judge)
+    texts = candidates(21, np.random.default_rng(4))
+
+    async def drive(client):
+        dispatched = judge.stats()["dispatches"]
+        # the label's count is the process's (another judge's tests share it)
+        was = (await (await client.get("/metrics")).json()).get("roofline", {}).get("buckets", {})
+        was = was.get("judge(n=2,s=440)", {}).get("count", 0)
+        resp = await post_json(
+            client, "/consensus",
+            {"input": texts, "scorer": "judge", "prompt": "w1 w2",
+             "panel": [{"seed": 7, "weight": 2}, {"seed": 8}]},
+        )
+        assert resp.status == 200, await resp.text()
+        body = await resp.json()
+        assert body["scorer"] == "judge" and body["model"] == "qwen3-next-test-tiny"
+        assert len(body["confidence"]) == 21
+        assert sum(body["confidence"]) == pytest.approx(1.0, abs=1e-6)
+        assert [b["seed"] for b in body["ballots"]] == [7, 8]
+        for ballot in body["ballots"]:
+            assert set(ballot) == {"seed", "weight", "first", "key", "siblings"}
+        resp = await post_json(client, "/consensus", {"input": texts[:3], "scorer": "judge"})
+        assert [b["seed"] for b in (await resp.json())["ballots"]] == [0, 1, 2]
+        metrics = await (await client.get("/metrics")).json()
+        assert metrics["roofline"]["buckets"]["judge(n=2,s=440)"]["count"] == was + 1
+        assert metrics["judge"]["dispatches"] == dispatched + 2
+        assert metrics["judge"]["model"] == "qwen3-next-test-tiny"
+        for key in ("expert_pairs_here", "expert_pairs_elsewhere", "delta_rule_padding_share"):
+            assert key in metrics["judge"]
+
+    go(with_client(app, drive))
+
+
+def test_build_judge_knows_the_presets(monkeypatch):
+    from llm_weighted_consensus_tpu.serve import Config
+    from llm_weighted_consensus_tpu.serve.__main__ import build_judge
+
+    monkeypatch.delenv("LWC_ALLOW_RANDOM_PARAMS", raising=False)
+    config = Config.from_env({"JUDGE_MODEL": "qwen3-next-test-tiny", "JUDGE_MAX_TOKENS": "64"})
+    with pytest.raises(ValueError, match="JUDGE_WEIGHTS"):
+        build_judge(config)
+    built = build_judge(config, allow_synthetic=True)
+    assert built.max_tokens == 64 and built.decoder is qwen3_next
+    with pytest.raises(ValueError, match="qwen3-next-80b-a3b"):
+        build_judge(Config.from_env({"JUDGE_MODEL": "qwen3-next"}))
+
+
+def test_a_checkpoint_on_disk_is_served_at_its_share(tmp_path):
+    from safetensors.numpy import save_file
+
+    from llm_weighted_consensus_tpu.models.judge import load_judge_params
+
+    cfg = hf_config(num_hidden_layers=4)
+    state = reference.random_state(cfg, seed=4, held=8)
+    save_file(state, str(tmp_path / "model.safetensors"))
+    (tmp_path / "ignored.json").write_text(json.dumps({}))
+    deep = dataclasses.replace(C, num_layers=48)
+    params, config = load_judge_params(str(tmp_path), deep, dtype=jnp.float32)
+    assert config.num_layers == 4 and qwen3_next.experts_held(params, config) == 8
+
+
+def test_a_share_s_usual_load_and_its_whole_bound_are_one_answer(monkeypatch):
+    """The layout's rows past the usual load are skipped where the tiles in
+    use fit; where they do not, the whole bound runs: the same sums."""
+    rng = np.random.default_rng(3)
+    tokens, k, hidden, width, held, router = 200, 4, 32, 16, 4, 16
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    p = {
+        "w_gate": jnp.asarray(rng.standard_normal((held, hidden, width)) * 0.1, jnp.float32),
+        "w_up": jnp.asarray(rng.standard_normal((held, hidden, width)) * 0.1, jnp.float32),
+        "w_down": jnp.asarray(rng.standard_normal((held, width, hidden)) * 0.1, jnp.float32),
+    }
+    chosen = np.stack([rng.permutation(router)[:k] for _ in range(tokens)]).astype(np.int32)
+    weight = rng.random((tokens, k)).astype(np.float32)
+    answers = []
+    for usual in (10**9, 512, 64):  # one path; the usual load fits; it does not
+        monkeypatch.setattr(decoder_parts, "USUAL_ROWS", usual)
+        got, counts = decoder_parts.experts_grouped(
+            h, jnp.asarray(chosen), jnp.asarray(weight), p, router, held=held
+        )
+        answers.append(np.asarray(got))
+        assert int(np.asarray(counts)[:held].sum()) == int((chosen < held).sum())
+    assert np.abs(answers[0] - answers[1]).max() < 1e-6
+    assert np.abs(answers[0] - answers[2]).max() < 1e-6
+    x = np.asarray(h, np.float64)
+    want = np.zeros_like(x)
+    for e in range(held):
+        y = (reference.silu(x @ np.asarray(p["w_gate"][e], np.float64)) * (
+            x @ np.asarray(p["w_up"][e], np.float64))) @ np.asarray(p["w_down"][e], np.float64)
+        want += y * (weight * (chosen == e)).sum(axis=1, keepdims=True)
+    assert np.abs(answers[0] - want).max() < 1e-5

@@ -156,3 +156,86 @@ def test_grouped_expert_product_compiles_at_the_judge_s_shapes(one_chip, pairs, 
     ).compile()
     names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
     assert any(name.startswith("grouped_expert_product") for name in names), names
+
+
+# -- the second judge's kernels at its configuration's widths (ISSUE 31) -------
+
+
+def test_gated_delta_kernel_compiles_at_the_judge_s_shape(one_chip):
+    """A linear layer's rule over a panel: 3 calls x 8192 positions, 32 value
+    heads on 16 key heads of 128, bf16, chunks of 128, as ``gated_delta_rule``
+    lays them out.  The jit holds the kernel under the name the benchmark
+    reads and nothing else."""
+    from llm_weighted_consensus_tpu.ops import gated_delta as gd
+
+    b, s, hk, hv, d = 3, 8192, 16, 32, 128
+    heads = gd._heads_a_step(hv, hv // hk, gd.HEADS_PER_STEP)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = arg((b, s // gd.CHUNK, hv // heads, heads, gd.CHUNK), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, g, beta: gd.gated_delta_chunked(
+            q, k, v, g, beta, key_heads=hk, interpret=False
+        )
+    ).lower(
+        arg((b, s, hk * d), jnp.bfloat16), arg((b, s, hk * d), jnp.bfloat16),
+        arg((b, s, hv * d), jnp.bfloat16), rows, rows,
+    ).compile()
+    # the kernel returns two arrays: its line's type is a tuple, which
+    # ``instructions`` does not read
+    text = compiled.as_text()
+    calls = re.findall(r"%([\w.]+) = \([^=]*\) custom-call\(", text)
+    assert len(calls) == 1 and calls[0].startswith("gated_delta_chunked"), calls
+    assert not re.findall(r" (?:copy|transpose|fusion)\(", text)
+
+
+def test_causal_attention_kernel_compiles_with_16_query_heads_on_2_key_heads(one_chip):
+    """A full-attention layer of the second judge: a key head's block found
+    by ``head // 8``, nothing repeated in memory."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+
+    q = jax.ShapeDtypeStruct((3, 8192, 16 * 256), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((3, 8192, 2 * 256), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: ca.causal_attention_blockwise(
+            q, k, v, heads=16, kv_heads=2, scale=1 / 16, interpret=False
+        )
+    ).lower(q, kv, kv).compile()
+    found = instructions(compiled.as_text())
+    calls = [name for name, op, _ in found if op == "custom-call"]
+    assert len(calls) == 1 and calls[0].startswith("causal_attention_blockwise"), calls
+    assert not [(n, op) for n, op, _ in found if op in ("copy", "transpose", "fusion")]
+
+
+@pytest.mark.parametrize("fused", ["gate-up", "down"])
+def test_grouped_expert_product_compiles_at_the_held_share_s_shapes(one_chip, fused):
+    """128 experts held of a router 512 wide, 10 a token: the static bound is
+    every pair of a panel (245,760) and one more group, tiles of 256; gate and
+    up fused (K 2048, N 512), down with the rows' weights (K 512, N 2048) in
+    ONE column chunk (the table is too large for chunks that stay in VMEM)."""
+    from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
+
+    pairs = 3 * 8192 * 10
+    tile = gm.tile_for(pairs, 512)
+    rows = gm.padded_rows(pairs, 129, tile)
+    assert tile == gm.TILE and gm.column_chunks(rows, 2048) == 1
+    assert gm.column_chunks(3 * 8192, 2048) == 4
+    k, n = (2048, 512) if fused == "gate-up" else (512, 2048)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def served(x, w, extra, te, used):
+        epilogue = {"w_up": extra} if fused == "gate-up" else {"row_weight": extra}
+        return gm.grouped_expert_product(x, w, te, used, tile=tile, interpret=False, **epilogue)
+
+    weights = arg((128, k, n), jnp.bfloat16)
+    compiled = jax.jit(served).lower(
+        arg((rows, k), jnp.bfloat16), weights,
+        weights if fused == "gate-up" else arg((rows,), jnp.float32),
+        arg((rows // tile,), jnp.int32), arg((1,), jnp.int32),
+    ).compile()
+    names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    assert any(name.startswith("grouped_expert_product") for name in names), names

@@ -17,6 +17,9 @@ and compute nothing).
 A layer that holds experts 0..held-1 of a wider router (``held`` given) lays
 out only the pairs whose expert is here, drops none of them, and returns the
 partial sum: what the experts elsewhere would add is their chip's to add.
+Its way back WALKS the pairs held (``ops/grouped_matmul.py::held_rows_sum``,
+a kernel of that name under ``experts_combine``): one row fetched a held
+pair, where k gathers a token would fetch three rows in four to mask them.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import grouped_matmul as _gmm
 
@@ -110,7 +114,19 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
     dropped), four times its usual load: where the tiles in use fit
     ``USUAL_ROWS`` the rows past them are neither gathered nor laid out (one
     ``lax.cond`` on the layout's own count; the other branch is the same code
-    over the whole bound)."""
+    over the whole bound).
+
+    The way back, by what the layer holds.  Every expert held (``held`` None):
+    a gather a choice from column chunks of the down product that stay in
+    VMEM, each read a row that is summed; at a row every 2-3 ns that is at par
+    with a copy a row out of HBM, so it stays as it is.  A share: three pairs
+    in four are elsewhere and the same gathers would fetch a row for each to
+    mask it, so the down product leaves a row a slab and ``held_rows_sum``
+    fetches one row a pair held here (the pairs elsewhere are sorted out of
+    its lists before it runs); the sum is the same float32 sum in the order
+    of the choices, bit for bit.
+    A width whose rows are not whole tiles of words (``row_slabs`` 0: the tiny
+    presets) keeps the masked gathers."""
     t, k = chosen.shape
     tile = _gmm.tile_for(t * k, experts)
     with jax.named_scope("experts_layout"):
@@ -123,12 +139,16 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
             tables, here = _gmm.route_layout_held(
                 chosen.reshape(-1), weight.reshape(-1), held, tile
             )
-            here = here.reshape(t, k)
         pair_of_row, row_of_pair, tile_expert, used, counts, row_weight = tables
-        # a pair elsewhere reads a row that exists and counts as nothing
-        rows_of = row_of_pair.reshape(t, k) if here is None else jnp.where(
-            here, row_of_pair.reshape(t, k), 0
-        )
+        # a share's rows are walked where a row is whole tiles of words
+        walk = here is not None and _gmm.row_slabs(h.shape[1], h.dtype) > 0
+        if here is None:
+            rows_of = row_of_pair.reshape(t, k)
+        elif walk:  # a pair elsewhere is skipped: a row no table has
+            rows_of = jnp.where(here, row_of_pair, -1)
+        else:  # a pair elsewhere reads a row that exists and counts as nothing
+            here = here.reshape(t, k)
+            rows_of = jnp.where(here, row_of_pair.reshape(t, k), 0)
         parts = jnp.split(h, _gmm.column_chunks(*h.shape, h.dtype.itemsize), axis=1)
 
     def over(rows: int):
@@ -143,10 +163,14 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
             )
             y = product(
                 product(x, p["w_gate"], w_up=p["w_up"]), p["w_down"],
-                row_weight=row_weight[:rows],
-                out_chunks=_gmm.column_chunks(rows, h.shape[1], h.dtype.itemsize),
+                row_weight=row_weight[:rows], slabs=walk,
+                out_chunks=None if walk else _gmm.column_chunks(
+                    rows, h.shape[1], h.dtype.itemsize
+                ),
             )
         with jax.named_scope("experts_combine"):
+            if walk:
+                return _gmm.held_rows_sum(y, rows_of, k=k, width=h.shape[1])
             # a gather a choice, so that no [t, k, hidden] is laid out between
             if here is None:
                 take = lambda part, j: part[rows_of[:, j]].astype(jnp.float32)  # noqa: E731
@@ -162,3 +186,19 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
         return over(whole), counts
     fits = used[0] * tile <= USUAL_ROWS
     return jax.lax.cond(fits, lambda: over(USUAL_ROWS), lambda: over(whole)), counts
+
+
+def layers_past_usual(load, experts: int) -> int:
+    """On the host, from the counts a dispatch brought back (``load`` [layers,
+    held + 1], a share's ``counts`` a sparse layer): the layers whose tiles in
+    use passed ``USUAL_ROWS``, so that ``experts_grouped``'s ``lax.cond`` ran
+    them over the whole bound."""
+    load = np.asarray(load)
+    if not load.size:
+        return 0
+    pairs, held = int(load[0].sum()), load.shape[1] - 1
+    tile = _gmm.tile_for(pairs, experts)
+    if _gmm.padded_rows(pairs, held + 1, tile) <= USUAL_ROWS:
+        return 0
+    used = (-(-load[:, :held] // tile)).sum(axis=1) * tile
+    return int((used > USUAL_ROWS).sum())

@@ -238,6 +238,11 @@ def recurrent_layers(config: GlmMoeLiteConfig) -> int:
     return 0
 
 
+def whole_bound_layers(load, config: GlmMoeLiteConfig) -> int:
+    """Every expert is held: the layout has one bound and no usual load."""
+    return 0
+
+
 # -- parameters ---------------------------------------------------------------
 
 

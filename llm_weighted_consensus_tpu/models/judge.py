@@ -21,7 +21,9 @@ a decoder module gives,
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
 beside ``init_params``, ``from_hf_weights``, ``quantize_dense``,
-``experts_held(params, config)`` and ``recurrent_layers(config)``.
+``experts_held(params, config)``, ``recurrent_layers(config)`` and
+``whole_bound_layers(load, config)`` (the host's count, from the pairs
+routed, of the sparse layers that ran over their layout's whole bound).
 
 A call's prompt, token by token (each piece goes through the tokenizer on
 its own, so a candidate is tokenized once however many ballots show it):
@@ -227,6 +229,9 @@ class TpuJudge:
             "expert_pairs_routed": 0,
             "expert_pairs_here": 0,
             "expert_pairs_elsewhere": 0,
+            # sparse layers, summed over dispatches, whose tiles in use passed
+            # the usual load's rows and ran over the layout's whole bound
+            "expert_layers_whole_bound": 0,
             # the dispatches' mean share of a recurrent layer's positions
             # that were padding (0 for a decoder without a recurrence)
             "delta_rule_padding_share": 0.0,
@@ -366,6 +371,7 @@ class TpuJudge:
     def _count(self, prepared: PreparedPanel, load) -> None:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
+        whole_bound = self.decoder.whole_bound_layers(load, self.config)
         elsewhere = int(load[:, self._held:].sum()) if load.size else 0
         load = load[:, :self._held] if load.size else load
         padding = 1.0 - prepared.tokens / prepared.ids.size if self._recurrent else 0.0
@@ -394,6 +400,7 @@ class TpuJudge:
             ) / s["dispatches"]
             s["expert_pairs_elsewhere"] += elsewhere
             s["expert_pairs_routed"] += elsewhere
+            s["expert_layers_whole_bound"] += whole_bound
             if load.size:
                 totals = load.sum(axis=0)
                 s["expert_pairs_here"] += int(totals.sum())

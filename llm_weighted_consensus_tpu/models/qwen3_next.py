@@ -70,7 +70,8 @@ from ..ops.causal_attention import causal_attention_blockwise
 from ..ops.gated_delta import gated_delta_rule, gated_delta_step
 from .configs import Qwen3NextConfig
 from .decoder_parts import (  # noqa: F401  (quantize_dense: the panel's protocol)
-    dense, experts_grouped, quantize_dense, rms, rope, rope_angles, swiglu,
+    dense, experts_grouped, layers_past_usual, quantize_dense, rms, rope, rope_angles,
+    swiglu,
 )
 
 
@@ -346,6 +347,12 @@ def experts_held(params: dict, config: Qwen3NextConfig) -> int:
 
 def recurrent_layers(config: Qwen3NextConfig) -> int:
     return sum(not config.is_full_attention(i) for i in range(config.num_layers))
+
+
+def whole_bound_layers(load, config: Qwen3NextConfig) -> int:
+    """Of a dispatch's sparse layers (``load``: ``prefill``'s pairs routed a
+    layer), those that ran over the layout's whole bound."""
+    return layers_past_usual(load, config.num_experts)
 
 
 # -- parameters ---------------------------------------------------------------------------

@@ -622,3 +622,191 @@ def test_a_share_s_usual_load_and_its_whole_bound_are_one_answer(monkeypatch):
             x @ np.asarray(p["w_up"][e], np.float64))) @ np.asarray(p["w_down"][e], np.float64)
         want += y * (weight * (chosen == e)).sum(axis=1, keepdims=True)
     assert np.abs(answers[0] - want).max() < 1e-5
+
+
+# -- a share's way back from the padded layout: the walk (ISSUE 32) --------------------------
+
+
+def gather_twin(h, chosen, weight, p, experts, held, rows=None):
+    """The way back as it was before the walk, kept here as the plain twin:
+    the same layout and kernels, the down product as column chunks, then a
+    masked gather a choice and a float32 sum in the order of the choices."""
+    t, k = chosen.shape
+    tile = gmm.tile_for(t * k, experts)
+    tables, here = gmm.route_layout_held(chosen.reshape(-1), weight.reshape(-1), held, tile)
+    pair_of_row, row_of_pair, tile_expert, used, _, row_weight = tables
+    here = here.reshape(t, k)
+    rows = rows or pair_of_row.shape[0]
+    rows_of = jnp.where(here, row_of_pair.reshape(t, k), 0)
+    kernels = dict(tile_expert=tile_expert[: rows // tile], tiles_used=used, tile=tile)
+    x = h[pair_of_row[:rows] // k]
+    y = gmm.grouped_expert_product(
+        gmm.grouped_expert_product(x, p["w_gate"], w_up=p["w_up"], **kernels), p["w_down"],
+        row_weight=row_weight[:rows], out_chunks=2, **kernels,
+    )
+    take = lambda part, j: jnp.where(  # noqa: E731
+        here[:, j, None], part[rows_of[:, j]].astype(jnp.float32), 0.0
+    )
+    routed = [sum(take(part, j) for j in range(k)) for part in y]
+    return jnp.concatenate(routed, axis=1).astype(h.dtype)
+
+
+def share_case(tokens, k, dtype, routing, seed=0):
+    """A layer's inputs at a width whose rows are whole tiles of words, 12 of
+    a router's 32 experts held.  ``mixed``: a token in four has none of its
+    choices held, one in four all of them, the rest as they fall; ``one``:
+    every token to ONE held expert and elsewhere with its other choices."""
+    rng = np.random.default_rng(seed)
+    hidden = 2048 if dtype == jnp.bfloat16 else 1024
+    width, held, router = 16, 12, 32
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), dtype)
+    p = {
+        name: jnp.asarray(rng.standard_normal(shape) * 0.1, dtype)
+        for name, shape in (
+            ("w_gate", (held, hidden, width)), ("w_up", (held, hidden, width)),
+            ("w_down", (held, width, hidden)),
+        )
+    }
+    chosen = np.stack([rng.permutation(router)[:k] for _ in range(tokens)]).astype(np.int32)
+    if routing == "mixed":
+        chosen[0::4] = held + np.stack([rng.permutation(router - held)[:k] for _ in chosen[0::4]])
+        chosen[1::4] = np.stack([rng.permutation(held)[:k] for _ in chosen[1::4]])
+    else:
+        chosen[:, 0] = 1
+        chosen[:, 1:] = held + 1 + np.arange(k - 1)
+    weight = rng.random((tokens, k)).astype(np.float32)
+    return h, jnp.asarray(chosen), jnp.asarray(weight), p, router, held
+
+
+@pytest.mark.parametrize(
+    "tokens,k,dtype,routing,bound",
+    [
+        (128, 4, jnp.float32, "mixed", "one"),
+        (200, 4, jnp.float32, "mixed", "fits"),
+        (200, 4, jnp.float32, "mixed", "whole"),
+        (300, 1, jnp.float32, "one", "one"),
+        (130, 10, jnp.float32, "one", "fits"),
+        (256, 10, jnp.float32, "mixed", "whole"),
+        (300, 10, jnp.bfloat16, "mixed", "one"),
+        (256, 4, jnp.bfloat16, "mixed", "fits"),
+        (136, 1, jnp.bfloat16, "mixed", "whole"),
+        (3, 10, jnp.bfloat16, "mixed", "one"),
+        (3, 4, jnp.float32, "one", "one"),
+    ],
+    ids=lambda v: getattr(v, "__name__", str(v)),
+)
+def test_a_share_s_walk_is_the_gather_form_bit_for_bit(
+    monkeypatch, tokens, k, dtype, routing, bound
+):
+    """One row fetched a held pair and a token's rows summed in float32 in
+    the order of its choices: the same bits as k masked gathers, for tokens
+    none of whose choices is held and tokens all of whose are, whole steps of
+    tokens and not, a decode step's three, either branch of the ``lax.cond``."""
+    h, chosen, weight, p, router, held = share_case(tokens, k, dtype, routing)
+    assert gmm.row_slabs(h.shape[1], dtype) == 8
+    here = np.asarray(chosen) < held
+    if routing == "mixed" and tokens > 8:
+        assert not here[0::4].any() and here[1::4].all()
+    tile = gmm.tile_for(tokens * k, router)
+    counts = np.bincount(np.asarray(chosen)[here], minlength=held)
+    used = int((-(-counts // tile)).sum()) * tile
+    whole = gmm.padded_rows(tokens * k, held + 1, tile)
+    usual = {"one": whole, "fits": used, "whole": used - tile}[bound]
+    assert bound == "one" or usual < whole
+    monkeypatch.setattr(decoder_parts, "USUAL_ROWS", usual)
+    got, load = decoder_parts.experts_grouped(h, chosen, weight, p, router, held=held)
+    assert np.asarray(load)[:held].tolist() == counts.tolist()
+    assert decoder_parts.layers_past_usual(np.asarray(load)[None], router) == (bound == "whole")
+    want = gather_twin(h, chosen, weight, p, router, held)
+    assert got.dtype == want.dtype == dtype and got.shape == (tokens, h.shape[1])
+    assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert np.abs(np.asarray(want, np.float32)).max() > 0.1  # sums of something
+    assert not np.asarray(got, np.float32)[~here.any(axis=1)].any()
+
+
+@pytest.mark.parametrize(
+    "width,dtype,slabs",
+    [(2048, jnp.bfloat16, 8), (1024, jnp.float32, 8), (2048, jnp.float32, 16),
+     (4096, jnp.bfloat16, 16), (1024, jnp.bfloat16, 0), (64, jnp.float32, 0),
+     (2048, jnp.float16, 0), (2048, jnp.int8, 0)],
+    ids=lambda v: getattr(v, "__name__", str(v)),
+)
+def test_rows_are_walked_only_where_a_row_is_whole_tiles_of_words(width, dtype, slabs):
+    assert gmm.row_slabs(width, dtype) == slabs
+
+
+def primitives(jaxpr, scope=""):
+    """(primitive name, the name stack it stands under) of every equation, the
+    jaxprs of calls, branches and loops gone into."""
+    for eqn in jaxpr.eqns:
+        stack = scope + "/" + str(eqn.source_info.name_stack)
+        # a jitted function called inside goes by its own name
+        name = eqn.params.get("name") if "jaxpr" in eqn.params else None
+        yield (name if isinstance(name, str) else eqn.primitive.name), stack
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from primitives(inner, stack)
+
+
+@pytest.mark.parametrize("held", [None, 12], ids=["every-expert-held", "a-share"])
+def test_only_a_share_is_walked_and_every_expert_held_keeps_the_gathers(held):
+    """The first judge's way back is untouched: with every expert held the
+    jaxpr holds k gathers a column chunk under ``experts_combine`` and no walk,
+    at a width a share WOULD walk; a share holds the walk there and no gather."""
+    h, chosen, weight, p, router, _ = share_case(64, 4, jnp.float32, "mixed")
+    if held is None:
+        p = {name: jnp.concatenate([w, w, w[:8]]) for name, w in p.items()}  # all 32 held
+    traced = jax.make_jaxpr(
+        lambda h, chosen, weight, p: decoder_parts.experts_grouped(
+            h, chosen, weight, p, router, held=held
+        )
+    )(h, chosen, weight, p)
+    found = list(primitives(traced.jaxpr))
+    combine = [name for name, stack in found if "experts_combine" in stack]
+    chunks = gmm.column_chunks(gmm.padded_rows(64 * 4, router, gmm.tile_for(64 * 4, router)), 1024, 4)
+    walks = [name for name, _ in found if name == "held_rows_sum"]
+    kernels = [name for name, _ in found if name == "grouped_expert_product"]
+    assert len(kernels) == 2
+    if held is None:
+        assert combine.count("gather") == 4 * chunks == 4 and not walks
+    else:
+        assert "gather" not in combine and walks == ["held_rows_sum"]
+        assert combine.count("held_rows_sum") == 1
+
+
+@pytest.mark.parametrize(
+    "loads,usual,expected",
+    [
+        ([[30, 30, 30, 30, 392]] * 3, 256, 0),  # even routing, a quarter held
+        ([[128, 128, 128, 128, 0]] * 3, 256, 3),  # every pair to a held expert
+        ([[30, 30, 30, 30, 392], [200, 100, 100, 100, 12], [0, 0, 0, 256, 256]], 256, 1),
+        ([[128, 128, 128, 128, 0]] * 3, 10**6, 0),  # a layout with one bound
+        ([], 256, 0),
+    ],
+    ids=["even", "all-held", "one-layer", "one-bound", "no-sparse-layer"],
+)
+def test_layers_past_the_usual_load_are_counted_from_the_pairs_routed(
+    monkeypatch, loads, usual, expected
+):
+    monkeypatch.setattr(decoder_parts, "USUAL_ROWS", usual)
+    load = np.asarray(loads, np.int32).reshape(len(loads), 5)
+    assert decoder_parts.layers_past_usual(load, 16) == expected
+
+
+@pytest.mark.parametrize("held", [4, 16], ids=["a-quarter-held", "every-pair-held"])
+def test_judge_counts_the_layers_that_ran_over_the_whole_bound(monkeypatch, held):
+    """``/metrics`` ``judge.expert_layers_whole_bound``: 0 where the routing
+    is even over a router of which a quarter is held, every sparse layer of
+    the dispatch where every pair goes to a held expert."""
+    monkeypatch.setattr(decoder_parts, "USUAL_ROWS", 256)
+    params = qwen3_next.init_params(jax.random.PRNGKey(0), C, held=held)
+    judge = TpuJudge(
+        "qwen3-next-test-tiny", params=params, tokenizer=tiny_tokenizer(), max_tokens=88
+    )
+    assert judge.stats()["expert_layers_whole_bound"] == 0
+    judge.judge(candidates(6, np.random.default_rng(1)), "w1", [(1, 1.0)])
+    stats = judge.stats()
+    assert stats["expert_pairs_routed"] == 88 * C.num_experts_per_tok * C.num_layers
+    assert stats["expert_layers_whole_bound"] == (C.num_layers if held == 16 else 0)

@@ -239,3 +239,40 @@ def test_grouped_expert_product_compiles_at_the_held_share_s_shapes(one_chip, fu
     ).compile()
     names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
     assert any(name.startswith("grouped_expert_product") for name in names), names
+
+
+@pytest.mark.parametrize(
+    "tokens,rows",
+    [(3 * 8192, 114_688), (3 * 8192, None), (3, None)],
+    ids=["prefill-usual-load", "prefill-whole-bound", "decode"],
+)
+def test_a_share_s_walk_compiles_at_the_held_share_s_shapes(one_chip, tokens, rows):
+    """The way back for a share (ISSUE 32): the down product leaves a row a
+    slab of one (8, 128) tile of words, and ``held_rows_sum`` copies a tile a
+    held pair out of HBM (a one-row slice of a tiled table Mosaic refuses,
+    which only this compile shows); over the usual load's rows, over the whole
+    bound, and for a decode step's three tokens in tiles of 16."""
+    from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
+
+    k, hidden, width = 10, 2048, 512
+    tile = gm.tile_for(tokens * k, 512)
+    rows = rows or gm.padded_rows(tokens * k, 129, tile)
+    assert gm.row_slabs(hidden, jnp.bfloat16) == 8 and rows % tile == 0
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def served(x, w, weight, te, used, rows_of):
+        y = gm.grouped_expert_product(
+            x, w, te, used, row_weight=weight, tile=tile, slabs=True, interpret=False
+        )
+        return gm.held_rows_sum(y, rows_of, k=k, width=hidden, interpret=False)
+
+    compiled = jax.jit(served).lower(
+        arg((rows, width), jnp.bfloat16), arg((128, width, hidden), jnp.bfloat16),
+        arg((rows,), jnp.float32), arg((rows // tile,), jnp.int32), arg((1,), jnp.int32),
+        arg((tokens * k,), jnp.int32),
+    ).compile()
+    names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    assert any(name.startswith("held_rows_sum") for name in names), names
+    assert any(name.startswith("grouped_expert_product") for name in names), names

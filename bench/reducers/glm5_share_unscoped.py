@@ -1,0 +1,8 @@
+"""forward.share.unscoped.glm5: per cent of the judge programs' device time under
+the ``unscoped`` scopes (``glm5_scopes.GROUPS``)."""
+
+import glm5_scopes
+
+
+def reduce(ctx):
+    return glm5_scopes.share(ctx, "unscoped")

@@ -217,7 +217,15 @@ class GlmMoeLiteConfig:
 
     ``num_layers`` is the published depth; a checkpoint that names fewer
     layers (one pipeline stage of a deployment) is served at the depth it
-    names (``glm_moe.from_hf_weights``)."""
+    names (``glm_moe.from_hf_weights``), and so are the experts 0..E-1 it
+    names of a router ``n_routed_experts`` wide (one chip's share of a
+    layer's experts) and the rows of the vocabulary it holds.
+
+    ``index_topk`` > 0 is ``model_type`` ``glm_moe_dsa``: a learned sparse
+    selection in front of the attention (the DeepSeek-V3.2 indexer).  A layer
+    whose ``indexer_types`` entry is ``full`` owns an indexer and chooses each
+    query's ``index_topk`` keys; a ``shared`` layer attends over the choice
+    of the last ``full`` layer before it."""
 
     vocab_size: int = 154880
     hidden_size: int = 2048
@@ -241,6 +249,13 @@ class GlmMoeLiteConfig:
     # MLP, the shared expert) through quant.dense_int8; router, routed
     # experts, embedding and head keep the parameters' dtype
     quantize: str = "none"
+    # the indexer: heads of ``index_head_dim`` against ONE key a position,
+    # the first ``qk_rope_head_dim`` dims of each turned; 0 keys: no indexer
+    index_n_heads: int = 0
+    index_head_dim: int = 128
+    index_topk: int = 0
+    indexer_types: tuple = ()  # "full" | "shared" a layer
+    index_norm_eps: float = 1e-6  # the LayerNorm over an index key
 
     @property
     def qk_head_dim(self) -> int:
@@ -263,6 +278,51 @@ GLM_TEST_TINY = GlmMoeLiteConfig(
     moe_intermediate_size=48,
     n_routed_experts=8,
     num_experts_per_tok=2,
+)
+
+
+# zai-org/GLM-5.2 config.json (``glm_moe_dsa``): three leading dense layers;
+# a layer owns an indexer where it is dense or every fourth one from layer 6
+GLM_5_2 = GlmMoeLiteConfig(
+    hidden_size=6144,
+    num_layers=78,
+    num_heads=64,
+    q_lora_rank=2048,
+    intermediate_size=12288,
+    moe_intermediate_size=2048,
+    n_routed_experts=256,
+    num_experts_per_tok=8,
+    routed_scaling_factor=2.5,
+    first_k_dense_replace=3,
+    rope_theta=8e6,
+    indexer_types=tuple(
+        "full" if i < 3 or (i - 2) % 4 == 0 else "shared" for i in range(78)
+    ),
+    index_n_heads=32,
+    index_head_dim=128,
+    index_topk=2048,
+)
+# one period of the indexer pattern behind one dense layer, as the cell cuts it
+GLM_DSA_TEST_TINY = GlmMoeLiteConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=5,
+    num_heads=4,
+    q_lora_rank=32,
+    kv_lora_rank=16,
+    qk_nope_head_dim=24,
+    qk_rope_head_dim=8,
+    v_head_dim=32,
+    intermediate_size=128,
+    moe_intermediate_size=48,
+    n_routed_experts=16,
+    num_experts_per_tok=2,
+    routed_scaling_factor=2.5,
+    rope_theta=8e6,
+    index_n_heads=4,
+    index_head_dim=16,
+    index_topk=32,
+    indexer_types=("full", "shared", "shared", "shared", "full"),
 )
 
 

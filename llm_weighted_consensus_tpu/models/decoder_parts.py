@@ -97,9 +97,17 @@ def quantize_dense(params: dict) -> dict:
     return walk(params)
 
 
-# rows of the padded layout a share's usual routing fits (the first judge's
-# whole bound: tables of that many rows stay in VMEM for XLA's gathers)
+# rows of the padded layout a share's usual routing fits, at most (the first
+# judge's whole bound: tables of that many rows stay in VMEM for XLA's gathers)
 USUAL_ROWS = 114_688
+
+
+def usual_rows(pairs: int, experts: int, held: int, tile: int) -> int:
+    """Rows of the padded layout that a share's usual routing fits: four
+    times the load the held experts see where the router spreads its pairs
+    evenly, in whole tiles, and ``USUAL_ROWS`` at most (a quarter of a router
+    held: ``USUAL_ROWS``; a sixteenth of one: a quarter of the pairs)."""
+    return min(USUAL_ROWS, -(-4 * pairs * held // (experts * tile)) * tile)
 
 
 def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None = None):
@@ -111,10 +119,10 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
     last entry the pairs routed elsewhere; else counts is [experts].
 
     A share's layout is sized for every pair being held here (none may be
-    dropped), four times its usual load: where the tiles in use fit
-    ``USUAL_ROWS`` the rows past them are neither gathered nor laid out (one
-    ``lax.cond`` on the layout's own count; the other branch is the same code
-    over the whole bound).
+    dropped), many times its usual load: where that bound passes
+    ``USUAL_ROWS`` and the tiles in use fit ``usual_rows`` the rows past them
+    are neither gathered nor laid out (one ``lax.cond`` on the layout's own
+    count; the other branch is the same code over the whole bound).
 
     The way back, by what the layer holds.  Every expert held (``held`` None):
     a gather a choice from column chunks of the down product that stay in
@@ -184,14 +192,15 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
     whole = pair_of_row.shape[0]
     if held is None or whole <= USUAL_ROWS:
         return over(whole), counts
-    fits = used[0] * tile <= USUAL_ROWS
-    return jax.lax.cond(fits, lambda: over(USUAL_ROWS), lambda: over(whole)), counts
+    usual = usual_rows(t * k, experts, held, tile)
+    fits = used[0] * tile <= usual
+    return jax.lax.cond(fits, lambda: over(usual), lambda: over(whole)), counts
 
 
 def layers_past_usual(load, experts: int) -> int:
     """On the host, from the counts a dispatch brought back (``load`` [layers,
     held + 1], a share's ``counts`` a sparse layer): the layers whose tiles in
-    use passed ``USUAL_ROWS``, so that ``experts_grouped``'s ``lax.cond`` ran
+    use passed ``usual_rows``, so that ``experts_grouped``'s ``lax.cond`` ran
     them over the whole bound."""
     load = np.asarray(load)
     if not load.size:
@@ -201,4 +210,4 @@ def layers_past_usual(load, experts: int) -> int:
     if _gmm.padded_rows(pairs, held + 1, tile) <= USUAL_ROWS:
         return 0
     used = (-(-load[:, :held] // tile)).sum(axis=1) * tile
-    return int((used > USUAL_ROWS).sum())
+    return int((used > usual_rows(pairs, experts, held, tile)).sum())

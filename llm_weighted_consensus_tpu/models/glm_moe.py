@@ -1,7 +1,8 @@
 """A causal decoder with latent attention and sparse experts: the judge.
 
 ``model_type`` ``glm4_moe_lite`` (zai-org/GLM-4.7-Flash), the DeepSeek-V3
-layout, written from its configuration:
+layout, and ``glm_moe_dsa`` (zai-org/GLM-5.2), the same layers behind a
+learned sparse selection; written from their configurations:
 
   x0      = embed[ids]
   per layer:
@@ -20,6 +21,29 @@ The router scores every expert with ``sigmoid(W_g · h)`` in float32, chooses
 the top k of score + ``e_score_correction_bias`` (the bias chooses, it does
 not weigh), and weighs the chosen by their unbiased scores, normalised to sum
 1, times ``routed_scaling_factor``.
+
+With an indexer (``index_topk`` > 0; the DeepSeek-V3.2 ``Indexer``) a query
+attends only the keys a second, small attention chooses.  A layer whose
+``indexer_types`` entry is ``full`` owns one:
+
+    q_I   = W_Iq · rms(W_qa · h)                 heads of ``index_head_dim``
+    k_I   = LayerNorm(W_Ik · h)                  ONE key a position
+    w     = W_Iw · h · heads^-1/2 · dim^-1/2     a weight a head, float32
+    score[t, s] = Σ_j w[t, j] · ReLU(q_I[t, j] · k_I[s])          s <= t
+    S_t   = the min(index_topk, t + 1) positions of largest score
+
+(the first ``qk_rope_head_dim`` dims of an index head and of the key turned),
+and the softmax above runs over s in S_t.  A ``shared`` layer attends over the
+S_t of the last ``full`` layer before it and has no indexer weights: the
+selection is the one value the layer loop carries.  ``ops/sparse_index.py``
+scores and chooses; the choice is one int8 a (query, key) pair, which the
+attention kernel reads as a tile beside q, k and v.  The cache then has three
+kinds: the latent, the rotary key and, on a ``full`` layer, the index keys,
+from which the decoded token chooses among the positions it sees.
+
+A sparse layer may hold a SHARE of its router's experts (the checkpoint names
+experts 0..E-1 of ``n_routed_experts``): ``experts_grouped(..., held=E)``
+returns this chip's partial sum.
 
 Two attention paths over the same weights.  PREFILL makes keys and values
 from the latent (``W_kvb`` applied) and runs the causal blockwise kernel
@@ -57,9 +81,12 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.causal_attention import causal_attention_blockwise
+from ..ops.sparse_index import (
+    index_scores, index_scores_einsum, index_select, select_topk_dense,
+)
 from .configs import GlmMoeLiteConfig
 from .decoder_parts import dense as _dense
-from .decoder_parts import experts_grouped, quantize_dense  # noqa: F401
+from .decoder_parts import experts_grouped, layers_past_usual, quantize_dense  # noqa: F401
 from .decoder_parts import rms as _rms
 from .decoder_parts import rope as _rope
 from .decoder_parts import rope_angles as _rope_angles
@@ -67,7 +94,8 @@ from .decoder_parts import swiglu as _swiglu
 
 
 def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
-    """h [..., hidden] -> q [..., heads, nope + rope], the rope dims turned;
+    """h [..., hidden] -> (q [..., heads, nope + rope], the rope dims turned,
+    the normalised query latent [..., q_lora_rank] an indexer reads too);
     cos, sin broadcast against [..., heads, rope / 2]."""
     nope = config.qk_nope_head_dim
     cq = _rms(_dense(h, p["q_a"]), p["q_a_norm"], config.rms_norm_eps)
@@ -75,7 +103,7 @@ def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
     q = q.reshape(*q.shape[:-1], config.num_heads, config.qk_head_dim)
     return jnp.concatenate(
         [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1
-    )
+    ), cq
 
 
 def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
@@ -87,13 +115,47 @@ def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
     return c, _rope(kv[..., rank:], cos, sin)
 
 
-def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig):
-    """h [b, s, hidden] -> (attention output [b, s, hidden], (c, kr))."""
+def _turn_head(x, cos, sin, config: GlmMoeLiteConfig):
+    """An index head's first ``qk_rope_head_dim`` dims turned, the rest as
+    they are: x [..., index_head_dim]."""
+    rope = config.qk_rope_head_dim
+    return jnp.concatenate([_rope(x[..., :rope], cos, sin), x[..., rope:]], axis=-1)
+
+
+def _index_terms(h, cq, p: dict, cos, sin, config: GlmMoeLiteConfig):
+    """The indexer's three products over h [..., hidden] and the query latent
+    cq: (q_I [..., heads * dim] turned, k_I [..., dim] normalised and turned,
+    w [..., heads] float32, the heads' weights with both scales folded in).
+    cos, sin [..., rope / 2], a position's."""
+    heads, dim = config.index_n_heads, config.index_head_dim
+    with jax.named_scope("index_q"):
+        q = _dense(cq, p["q"])
+        q = _turn_head(
+            q.reshape(*q.shape[:-1], heads, dim), cos[..., None, :], sin[..., None, :], config
+        ).reshape(q.shape)
+        w = jnp.einsum(
+            "...i,io->...o", h, p["w"], preferred_element_type=jnp.float32
+        ) * (heads**-0.5 * dim**-0.5)
+    with jax.named_scope("index_k"):
+        k = _dense(h, p["k"]).astype(jnp.float32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + config.index_norm_eps)
+        k = (k * p["k_norm"].astype(jnp.float32) + p["k_bias"].astype(jnp.float32)).astype(h.dtype)
+        k = _turn_head(k, cos, sin, config)
+    return q, k, w
+
+
+def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig, keep=None):
+    """h [b, s, hidden] -> (attention output [b, s, hidden], the layer's
+    cache, the selection it attended over).  A layer with an indexer chooses
+    each query's keys (``keep`` [b, s, s] int8, ``ops/sparse_index.py``) and
+    caches its index keys beside (c, kr); a layer without one attends over
+    the ``keep`` it is handed, the last chosen; None is every causal key."""
     b, s, _ = h.shape
     heads, dq = config.num_heads, config.qk_head_dim
     cos, sin = _rope_angles(jnp.arange(s), config.qk_rope_head_dim, config.rope_theta)
     with jax.named_scope("latent_q"):
-        q = _queries(h, p, cos[:, None, :], sin[:, None, :], config)
+        q, cq = _queries(h, p, cos[:, None, :], sin[:, None, :], config)
         q = q.reshape(b, s, heads * dq)
     with jax.named_scope("latent_kv"):
         c, kr = _latent(h, p, cos, sin, config)
@@ -106,24 +168,36 @@ def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig):
         v = jnp.einsum(
             "bsc,cv->bsv", c, p["w_v"], preferred_element_type=jnp.float32
         ).astype(h.dtype)
-    with jax.named_scope("causal_attention"):
+    cache = (c, kr)
+    if "indexer" in p:
+        q_i, k_i, w = _index_terms(h, cq, p["indexer"], cos, sin, config)
+        with jax.named_scope("index_scores"):
+            scores = index_scores(q_i, k_i, w, heads=config.index_n_heads)
+        with jax.named_scope("index_select"):
+            keep = index_select(scores, k=config.index_topk)
+        cache = (c, kr, k_i)
+    with jax.named_scope("causal_attention" if keep is None else "selected_attention"):
         ctx = causal_attention_blockwise(
-            q, k, v, heads=heads, scale=1.0 / math.sqrt(dq)
+            q, k, v, keep, heads=heads, scale=1.0 / math.sqrt(dq)
         )
     with jax.named_scope("attn_out"):
         out = _dense(ctx, p["o"])
-    return out, (c, kr)
+    return out, cache, keep
 
 
-def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig):
+def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig, chosen=None):
     """One token a call through the latent cache, the absorbed path.
     h [b, hidden] at position ``lens[b]``; cache (c [b, s, rank], kr
-    [b, s, rope]) holds positions < lens[b] (later slots are padding)."""
+    [b, s, rope]) holds positions < lens[b] (later slots are padding), and
+    on a layer with an indexer its index keys [b, s, dim] too: there the
+    token chooses ``index_topk`` of the positions it sees, and ``chosen``
+    [b, s + 1] bool goes on to the layers without one.  Returns (output,
+    chosen)."""
     b = h.shape[0]
     heads, dq, nope = config.num_heads, config.qk_head_dim, config.qk_nope_head_dim
     cos, sin = _rope_angles(lens, config.qk_rope_head_dim, config.rope_theta)
     with jax.named_scope("latent_q"):
-        q = _queries(h, p, cos[:, None, :], sin[:, None, :], config)  # [b, heads, dq]
+        q, cq = _queries(h, p, cos[:, None, :], sin[:, None, :], config)  # [b, heads, dq]
         # W_kvb's key half folded into the query: scores against the latent
         q_lat = jnp.einsum(
             "bhd,chd->bhc", q, p["w_k"], preferred_element_type=jnp.float32
@@ -141,6 +215,17 @@ def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig):
         slots = c_all.shape[1]
         t = jnp.arange(slots)[None, :]
         seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
+        if "indexer" in p:
+            q_i, k_i, w = _index_terms(h, cq, p["indexer"], cos, sin, config)
+            with jax.named_scope("index_scores"):
+                index = index_scores_einsum(
+                    q_i[:, None, :], jnp.concatenate([cache[2], k_i[:, None, :]], axis=1),
+                    w[:, None, :], heads=config.index_n_heads,
+                )[:, 0]
+            with jax.named_scope("index_select"):
+                chosen = select_topk_dense(index, seen, config.index_topk)
+        if chosen is not None:
+            seen = chosen
         scores = jnp.where(seen[:, None, :], scores / math.sqrt(dq), -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
         o_lat = jnp.einsum(
@@ -152,7 +237,7 @@ def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig):
             "bhc,chv->bhv", o_lat, w_v, preferred_element_type=jnp.float32
         ).astype(h.dtype)
     with jax.named_scope("attn_out"):
-        return _dense(ctx.reshape(b, heads * config.v_head_dim), p["o"])
+        return _dense(ctx.reshape(b, heads * config.v_head_dim), p["o"]), chosen
 
 
 def route(h, p: dict, config: GlmMoeLiteConfig):
@@ -167,12 +252,22 @@ def route(h, p: dict, config: GlmMoeLiteConfig):
     return chosen.astype(jnp.int32), weight * config.routed_scaling_factor
 
 
+def _held(p: dict, config: GlmMoeLiteConfig):
+    """The experts 0..held-1 a sparse layer holds of its router's, or None
+    where it holds them all."""
+    held = p["w_gate"].shape[0]
+    return None if held == config.n_routed_experts else held
+
+
 def _moe(h, p: dict, config: GlmMoeLiteConfig):
-    """h [t, hidden] -> (output [t, hidden], pairs routed to each expert)."""
+    """h [t, hidden] -> (output [t, hidden], pairs routed to each expert; a
+    share's partial sum, and last the pairs routed elsewhere)."""
     with jax.named_scope("router"):
         chosen, weight = route(h, p, config)
     with jax.named_scope("experts_routed"):
-        routed, counts = experts_grouped(h, chosen, weight, p, config.n_routed_experts)
+        routed, counts = experts_grouped(
+            h, chosen, weight, p, config.n_routed_experts, held=_held(p, config)
+        )
     with jax.named_scope("expert_shared"):
         shared = _swiglu(h, p["shared"])
     return routed + shared, counts
@@ -187,23 +282,34 @@ def _mlp(h, layer: dict, config: GlmMoeLiteConfig):
     return flat.reshape(h.shape), counts
 
 
-def prefill(params: dict, ids, config: GlmMoeLiteConfig, lens=None):
+def prefill(params: dict, ids, config: GlmMoeLiteConfig, lens=None, tallies=None):
     """ids [b, s] -> (hidden [b, s, hidden] before the final norm, the
     latent cache a layer, pairs routed to each expert a sparse layer).
     ``lens`` is the panel protocol's (``models/judge.py``): causal attention
-    never sees the slots past a call's length, so it is not read here."""
+    never sees the slots past a call's length, so it is not read here.
+    Where the decoder has an indexer, a ``tallies`` dict handed in receives
+    ``index_keys`` [2] int32: the (query, key) pairs its layers with an
+    indexer chose, and the causal pairs they chose from, over every slot."""
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["token_embed"], ids, axis=0)
     caches, loads = [], []
+    keep, picked, owners = None, jnp.int32(0), 0
     for layer in params["layers"]:
         h = _rms(x, layer["input_norm"], config.rms_norm_eps)
-        out, cache = _attention_prefill(h, layer["attn"], config)
+        out, cache, keep = _attention_prefill(h, layer["attn"], config, keep)
+        if "indexer" in layer["attn"]:
+            with jax.named_scope("index_select"):
+                picked = picked + jnp.sum(keep, dtype=jnp.int32)
+            owners += 1
         x = x + out
         caches.append(cache)
         out, counts = _mlp(_rms(x, layer["post_norm"], config.rms_norm_eps), layer, config)
         x = x + out
         if counts is not None:
             loads.append(counts)
+    if owners and tallies is not None:
+        b, s = ids.shape
+        tallies["index_keys"] = jnp.stack([picked, jnp.int32(owners * b * (s * (s + 1) // 2))])
     return x, caches, loads
 
 
@@ -211,9 +317,13 @@ def decode_step(params: dict, token, lens, caches, config: GlmMoeLiteConfig):
     """One token a call at position ``lens`` -> hidden [b, hidden]."""
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["token_embed"], token, axis=0)
+    chosen = None
     for layer, cache in zip(params["layers"], caches):
         h = _rms(x, layer["input_norm"], config.rms_norm_eps)
-        x = x + _attention_decode(h, layer["attn"], lens, cache, config)
+        # a decoder without an indexer hands nothing on: its call is as it was
+        carried = () if chosen is None else (chosen,)
+        out, chosen = _attention_decode(h, layer["attn"], lens, cache, config, *carried)
+        x = x + out
         out, _ = _mlp(_rms(x, layer["post_norm"], config.rms_norm_eps), layer, config)
         x = x + out
     return x
@@ -230,7 +340,11 @@ def head_logprobs(params: dict, hidden, config: GlmMoeLiteConfig):
 
 
 def experts_held(params: dict, config: GlmMoeLiteConfig) -> int:
-    """Every expert the router names is held."""
+    """The experts this chip holds, 0..E-1 of the router's (all of them
+    unless the checkpoint named a share)."""
+    for layer in params["layers"]:
+        if "moe" in layer:
+            return int(layer["moe"]["w_gate"].shape[0])
     return config.n_routed_experts
 
 
@@ -239,15 +353,24 @@ def recurrent_layers(config: GlmMoeLiteConfig) -> int:
 
 
 def whole_bound_layers(load, config: GlmMoeLiteConfig) -> int:
-    """Every expert is held: the layout has one bound and no usual load."""
-    return 0
+    """Of a dispatch's sparse layers, those that ran over the layout's whole
+    bound.  With every expert held (``load`` [layers, experts]) the layout
+    has one bound and no usual load."""
+    if load.size and load.shape[1] == config.n_routed_experts:
+        return 0
+    return layers_past_usual(load, config.n_routed_experts)
 
 
 # -- parameters ---------------------------------------------------------------
 
 
-def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32) -> dict:
-    """Random parameters in the served layout (tests, shape work)."""
+def _owns_indexer(config: GlmMoeLiteConfig, layer: int) -> bool:
+    return bool(config.index_topk) and config.indexer_types[layer] == "full"
+
+
+def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32, held=None) -> dict:
+    """Random parameters in the served layout (tests, shape work); ``held``
+    experts 0..held-1 of the router's where a share is wanted."""
     std = 0.02
     drawn = iter(range(1 << 30))
 
@@ -285,16 +408,26 @@ def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32) -> dict:
                 "o": dense(heads * config.v_head_dim, h),
             },
         }
+        if _owns_indexer(config, i):
+            dim = config.index_head_dim
+            layer["attn"]["indexer"] = {
+                "q": dense(config.q_lora_rank, config.index_n_heads * dim),
+                "k": dense(h, dim),
+                "k_norm": scale(dim),
+                "k_bias": normal(dim),
+                "w": normal(h, config.index_n_heads),
+            }
         if i < config.first_k_dense_replace:
             layer["mlp"] = swiglu(config.intermediate_size)
         else:
             e, inter = config.n_routed_experts, config.moe_intermediate_size
+            here = held or e
             layer["moe"] = {
                 "router": normal(h, e, dt=jnp.float32),
                 "bias": normal(e, dt=jnp.float32),
-                "w_gate": normal(e, h, inter),
-                "w_up": normal(e, h, inter),
-                "w_down": normal(e, inter, h),
+                "w_gate": normal(here, h, inter),
+                "w_up": normal(here, h, inter),
+                "w_down": normal(here, inter, h),
                 "shared": swiglu(inter * config.n_shared_experts),
             }
         layers.append(layer)
@@ -316,8 +449,16 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
     """HF-named tensors (a mapping that may open each tensor lazily:
     ``loading.open_checkpoint``) -> (params, config).  A layer goes to the
     device before the next is read, so the host never holds the checkpoint
-    whole.  The depth is the checkpoint's: the layers it names, from 0 up.
+    whole.  What is served is what the checkpoint names: its layers, from 0
+    up; of each, whether it is dense or sparse (``mlp.gate.weight``) and
+    whether it owns an indexer (``self_attn.indexer.wq_b.weight``: one
+    pipeline stage of a deployment names its own layers from 0, whatever
+    their place in the published pattern); experts 0..E-1 of the preset's
+    router (fewer than it is wide: one chip's share); and the rows of the
+    vocabulary that ``embed_tokens`` holds.
     """
+    import dataclasses
+
     import numpy as np
 
     prefix = "model." if "model.embed_tokens.weight" in state else ""
@@ -326,10 +467,24 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
         depth += 1
     if depth == 0:
         raise ValueError("the checkpoint names no layer (layers.0.input_layernorm.weight)")
-    if depth != config.num_layers:
-        import dataclasses
-
-        config = dataclasses.replace(config, num_layers=depth)
+    embed = np.asarray(state[prefix + "embed_tokens.weight"])
+    sparse = [f"{prefix}layers.{i}.mlp.gate.weight" in state for i in range(depth)]
+    dense_layers = sparse.index(True) if True in sparse else depth
+    if not all(sparse[dense_layers:]):
+        raise ValueError("a dense layer behind a sparse one is not served")
+    held = 0
+    while f"{prefix}layers.{dense_layers}.mlp.experts.{held}.gate_proj.weight" in state:
+        held += 1
+    owners = tuple(
+        "full" if f"{prefix}layers.{i}.self_attn.indexer.wq_b.weight" in state else "shared"
+        for i in range(depth)
+    ) if config.index_topk else ()
+    if owners and owners[0] != "full":
+        raise ValueError("the first layer served owns no indexer: nothing to attend over")
+    config = dataclasses.replace(
+        config, num_layers=depth, first_k_dense_replace=dense_layers,
+        indexer_types=owners, vocab_size=int(embed.shape[0]),
+    )
 
     def get(name):
         return np.asarray(state[prefix + name])
@@ -348,6 +503,22 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
     heads, rank = config.num_heads, config.kv_lora_rank
     nope, rope, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
     order = np.asarray(_deinterleave(rope))
+
+    def indexer(base):
+        """An index head's first ``rope`` dims are stored interleaved like the
+        attention's: the same move on the query's rows, the key's and the
+        key norm's leaves every q_I . k_I as it was."""
+        dim = config.index_head_dim
+        turned = np.concatenate([order, np.arange(rope, dim)])
+        q = get(f"{base}.wq_b.weight").reshape(config.index_n_heads, dim, -1)[:, turned]
+        return {
+            "q": {"kernel": swap(put(q.reshape(config.index_n_heads * dim, -1)))},
+            "k": {"kernel": swap(put(get(f"{base}.wk.weight")[turned]))},
+            "k_norm": put(get(f"{base}.k_norm.weight")[turned]),
+            "k_bias": put(get(f"{base}.k_norm.bias")[turned]),
+            "w": swap(put(get(f"{base}.weights_proj.weight"))),
+        }
+
     layers = []
     for i in range(depth):
         base = f"layers.{i}"
@@ -373,6 +544,8 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
                 "o": dense(f"{att}.o_proj"),
             },
         }
+        if _owns_indexer(config, i):
+            layer["attn"]["indexer"] = indexer(f"{att}.indexer")
         if i < config.first_k_dense_replace:
             layer["mlp"] = swiglu(f"{base}.mlp")
         else:
@@ -380,7 +553,7 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
                 stacked = np.stack(
                     [
                         get(f"{base}.mlp.experts.{e}.{kind}_proj.weight")
-                        for e in range(config.n_routed_experts)
+                        for e in range(held)
                     ]
                 )
                 return swap(put(stacked))
@@ -397,7 +570,7 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
             }
         layers.append(layer)
     params = {
-        "token_embed": put(get("embed_tokens.weight")),
+        "token_embed": put(embed),
         "final_norm": put(get("norm.weight")),
         "lm_head": swap(put(np.asarray(state["lm_head.weight"]))),
         "layers": layers,

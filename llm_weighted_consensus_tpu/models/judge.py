@@ -8,15 +8,19 @@ is a few calls of one causal decoder over the same candidates under
 differently seeded ballots, and what an upstream judge's ``top_logprobs``
 would have carried is read from the decoder's own head.
 
-Two decoders serve (``JUDGE_PRESETS``; the preset's configuration class says
-which): ``models/glm_moe.py`` (latent attention, every expert held) and
-``models/qwen3_next.py`` (gated delta-rule layers three to one with gated
-full attention, a share of the router's experts held).  The panel's protocol
-is no part of either: ``judge_panel`` below is ONE jitted program over what
-a decoder module gives,
+Three decoders serve (``JUDGE_PRESETS``; the preset's configuration class
+says which module): ``models/glm_moe.py`` runs ``glm-4.7-flash`` (latent
+attention, every expert held) and ``glm-5.2`` (a learned sparse selection in
+front of latent attention, a share of the router's experts held), and
+``models/qwen3_next.py`` the third (gated delta-rule layers three to one with
+gated full attention, a share held).  The panel's protocol is no part of
+any: ``judge_panel`` below is ONE jitted program over what a decoder module
+gives,
 
-  ``prefill(params, ids, config, lens=)``  -> hidden [b, s, h], a cache a
-      layer (of whatever kind the layer keeps), pairs routed a sparse layer
+  ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h], a
+      cache a layer (of whatever kind the layer keeps), pairs routed a sparse
+      layer; what else it counted on the device goes into the ``tallies``
+      dict by name (``index_keys``: pairs chosen and causal pairs)
   ``decode_step(params, token, lens, caches, config)``  -> hidden [b, h]
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
@@ -65,14 +69,16 @@ from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
 from . import glm_moe, qwen3_next
 from .configs import (
-    GLM_4_7_FLASH, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY,
-    GlmMoeLiteConfig, Qwen3NextConfig,
+    GLM_4_7_FLASH, GLM_5_2, GLM_DSA_TEST_TINY, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B,
+    QWEN3_NEXT_TEST_TINY, GlmMoeLiteConfig, Qwen3NextConfig,
 )
 from .tokenizer import BaseTokenizer, load_tokenizer
 
 JUDGE_PRESETS = {
     "glm-4.7-flash": GLM_4_7_FLASH,
     "glm-test-tiny": GLM_TEST_TINY,
+    "glm-5.2": GLM_5_2,
+    "glm-dsa-test-tiny": GLM_DSA_TEST_TINY,
     "qwen3-next-80b-a3b": QWEN3_NEXT_80B_A3B,
     "qwen3-next-test-tiny": QWEN3_NEXT_TEST_TINY,
 }
@@ -115,7 +121,8 @@ def judge_panel(
     serves every candidate count.
     """
     b = ids.shape[0]
-    hidden, caches, loads = decoder.prefill(params, ids, config, lens=lens)
+    tallies: dict = {}
+    hidden, caches, loads = decoder.prefill(params, ids, config, lens=lens, tallies=tallies)
     last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
     first = _masked(decoder.head_logprobs(params, last, config), letter_ids, first_valid)
     chosen = jnp.argmax(first, axis=1).astype(jnp.int32)
@@ -123,6 +130,7 @@ def judge_panel(
         "first_logprobs": first,
         "chosen": chosen,
         "expert_load": jnp.stack(loads) if loads else jnp.zeros((0, 1), jnp.int32),
+        **tallies,
     }
     read, valid = first, first_valid
     if depth == 2:
@@ -235,6 +243,10 @@ class TpuJudge:
             # the dispatches' mean share of a recurrent layer's positions
             # that were padding (0 for a decoder without a recurrence)
             "delta_rule_padding_share": 0.0,
+            # (query, key) pairs, summed over dispatches and the layers that
+            # own an indexer: those a query may see, and those it chose
+            "index_keys_causal": 0,
+            "index_keys_selected": 0,
             "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
         self._held = len(self._stats["expert_tokens"])
@@ -365,10 +377,10 @@ class TpuJudge:
             entry["siblings"] = siblings
             ballots.append(entry)
         confidence = tally / sum(call.weight for call in prepared.calls)
-        self._count(prepared, np.asarray(out["expert_load"]))
+        self._count(prepared, np.asarray(out["expert_load"]), out.get("index_keys"))
         return confidence, prepared.tokens, ballots
 
-    def _count(self, prepared: PreparedPanel, load) -> None:
+    def _count(self, prepared: PreparedPanel, load, index_keys=None) -> None:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
@@ -401,6 +413,10 @@ class TpuJudge:
             s["expert_pairs_elsewhere"] += elsewhere
             s["expert_pairs_routed"] += elsewhere
             s["expert_layers_whole_bound"] += whole_bound
+            if index_keys is not None:
+                selected, causal = np.asarray(index_keys)
+                s["index_keys_selected"] += int(selected)
+                s["index_keys_causal"] += int(causal)
             if load.size:
                 totals = load.sum(axis=0)
                 s["expert_pairs_here"] += int(totals.sum())

@@ -295,10 +295,11 @@ def _sparse(x, layer: dict, config: Qwen3NextConfig):
 # -- the panel's protocol (models/judge.py) ------------------------------------------------
 
 
-def prefill(params: dict, ids, config: Qwen3NextConfig, lens=None):
+def prefill(params: dict, ids, config: Qwen3NextConfig, lens=None, tallies=None):
     """ids [b, s] right-padded calls of ``lens`` tokens -> (hidden [b, s,
     hidden] before the final norm, a layer's cache of its kind, pairs routed
-    a layer).  Without ``lens`` every slot is a token."""
+    a layer).  Without ``lens`` every slot is a token.  ``tallies`` is the
+    panel protocol's; this decoder counts nothing into it."""
     if lens is None:
         lens = jnp.full((ids.shape[0],), ids.shape[1], jnp.int32)
     with jax.named_scope("embed_tokens"):

@@ -133,10 +133,10 @@ def work_over_causal(
     return pairs / (seq * (seq + 1) // 2)
 
 
-def _kernel(
-    qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale, bq, bk, stripe, band,
-):
+def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, band):
+    # with a selection, its tile comes behind v: 1 where the query may meet the key
+    keep_ref = rest[0] if len(rest) == 5 else None
+    o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     step = pl.program_id(2)
     qi, ki = qi_ref[step], ki_ref[step]
     last = (qi * bq + bq - 1) // bk  # the key block that holds the diagonal's end
@@ -174,7 +174,10 @@ def _kernel(
                 q, k_ref[c0:c0 + cols, :], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [rows, cols], not yet scaled: scale > 0 keeps the maximum
-            if mask:
+            if keep_ref is not None:  # the selection is causal already
+                chosen = keep_ref[rs, c0:c0 + cols].astype(jnp.int32) != 0
+                raw = jnp.where(chosen, raw, _NEG)
+            elif mask:
                 row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
                 col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
                 if mask == "tile":
@@ -226,15 +229,19 @@ def _kernel(
     static_argnames=("heads", "scale", "kv_heads", "block_q", "block_k", "interpret"),
 )
 def causal_attention_blockwise(
-    q, k, v, *, heads: int, scale: float, kv_heads: int = 0, block_q: int = 0,
-    block_k: int = 0, interpret: bool | None = None,
+    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0,
+    block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
 ):
     """q: [b, s, heads * hd], k and v: [b, s, kv_heads * hd] (``kv_heads``
     0: a key head a query head) -> context [b, s, heads * hd]; position i
     attends positions <= i.  With fewer key heads, query head h reads key
     head ``h // (heads / kv_heads)`` through the block's index: no key is
-    repeated in memory.  The jitted function's name is the kernel's name in
-    a device trace."""
+    repeated in memory.  With ``keep`` [b, s, s] int8 (a learned sparse
+    selection, ``ops/sparse_index.py``; 0 wherever key > query) a query meets
+    only the keys its row marks: every pair of the lower triangle's blocks is
+    still multiplied, and a pair not chosen is masked before the softmax in
+    place of the causal mask; a head reads the tile again.  The jitted
+    function's name is the kernel's name in a device trace."""
     b, s, width = q.shape
     hd = width // heads
     group = heads // (kv_heads or heads)
@@ -257,6 +264,10 @@ def causal_attention_blockwise(
     def kv_index(bi, h, step, qi_of_step, ki_of_step):
         return bi, ki_of_step[step], h if group == 1 else h // group
 
+    def keep_index(bi, h, step, qi_of_step, ki_of_step):
+        return bi, qi_of_step[step], ki_of_step[step]
+
+    selection = [] if keep is None else [keep]
     return pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, bq=bq, bk=bk, stripe=stripe_for(bq, bk), band=band
@@ -268,6 +279,7 @@ def causal_attention_blockwise(
                 pl.BlockSpec((None, bq, hd), q_index),
                 pl.BlockSpec((None, bk, hd), kv_index),
                 pl.BlockSpec((None, bk, hd), kv_index),
+                *[pl.BlockSpec((None, bq, bk), keep_index) for _ in selection],
             ],
             out_specs=pl.BlockSpec((None, bq, hd), q_index),
             scratch_shapes=[
@@ -282,10 +294,12 @@ def causal_attention_blockwise(
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(jnp.asarray(qi_of_step), jnp.asarray(ki_of_step), q, k, v)
+    )(jnp.asarray(qi_of_step), jnp.asarray(ki_of_step), q, k, v, *selection)
 
 
-def causal_attention_einsum(q, k, v, *, heads: int, scale: float, kv_heads: int = 0):
+def causal_attention_einsum(
+    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0
+):
     """The kernel's plain twin: whole [s, s] scores (tests, tiny sizes)."""
     b, s, width = q.shape
     hd = width // heads
@@ -295,8 +309,10 @@ def causal_attention_einsum(q, k, v, *, heads: int, scale: float, kv_heads: int 
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", qh, kh, preferred_element_type=jnp.float32
     ) * scale
-    keep = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
+    seen = jnp.tril(jnp.ones((s, s), bool))
+    if keep is not None:
+        seen = seen & (keep != 0)[:, None]
+    probs = jax.nn.softmax(jnp.where(seen, scores, _NEG), axis=-1)
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", probs.astype(v.dtype), vh,
         preferred_element_type=jnp.float32,

@@ -93,6 +93,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 TILE = 256  # rows of one tile on the chip: half a tile of padding an expert
 VMEM_LIMIT = 64 << 20  # of a v5e core's 128 MiB; the default scope is 16
+WEIGHT_WINDOW_BYTES = 32 << 20  # the weight blocks of a step, double-buffered
 GATHER_TABLE_BYTES = 32 << 20  # a table XLA's memory assignment keeps in VMEM
 LANES = 128  # words of one sublane
 WALK_TOKENS = 128  # tokens of one step of ``held_rows_sum``
@@ -296,6 +297,18 @@ def grouped_expert_product(
     xs = tuple(x) if isinstance(x, (tuple, list)) else (x,)
     rows, k = xs[0].shape[0], sum(part.shape[1] for part in xs)
     n = w.shape[2]
+    # the whole width is one column block where its weight windows fit
+    # (2048 x 1536 twice, double-buffered: 25 MB); a wider expert's (6144 x
+    # 2048) go in halves until they do, and a slab, which needs its row
+    # whole, takes the room over ``VMEM_LIMIT`` instead
+    windows = 2 * (2 if w_up is not None else 1) * k * n * w.dtype.itemsize
+    vmem_limit = VMEM_LIMIT
+    if tile_n is None and not (slabs or out_chunks):
+        tile_n = n
+        while windows > WEIGHT_WINDOW_BYTES and tile_n % (2 * LANES) == 0:
+            tile_n, windows = tile_n // 2, windows // 2
+    elif windows > WEIGHT_WINDOW_BYTES and tile_n in (None, n):
+        vmem_limit += windows - WEIGHT_WINDOW_BYTES
     tile_n = tile_n or n
     pieces = out_chunks or 1
     if rows % tile or n % tile_n or tile_n % pieces or (out_chunks and tile_n != n):
@@ -339,7 +352,7 @@ def grouped_expert_product(
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT,
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(tile_expert, tiles_used, *operands)

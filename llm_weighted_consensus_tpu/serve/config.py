@@ -78,17 +78,24 @@ TPU additions:
   decoder serving ``POST /consensus {"scorer": "judge"}``: a LOCAL judge
   panel, each call a prefill of the candidates under a seeded prefix-tree
   ballot, one decoded key letter and a masked read of its siblings'
-  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of two
-  decoders: ``glm-4.7-flash`` (latent attention, every expert held;
-  models/glm_moe.py) or ``qwen3-next-80b-a3b`` (gated delta-rule layers
-  three to one with gated full attention, a recurrent state and a
-  convolution tail cached beside the keys; models/qwen3_next.py); each has
-  a tiny twin for tests (``glm-test-tiny``, ``qwen3-next-test-tiny``).
+  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of three
+  decoders: ``glm-4.7-flash`` (latent attention over every causal key,
+  every expert held; models/glm_moe.py), ``glm-5.2`` (the same module under
+  a configuration with an indexer: a learned sparse selection, the 2048
+  highest-scored keys a query, chosen on the layers that own an indexer and
+  shared with the layers behind them, in front of 64-head latent attention;
+  the indexer's keys cached beside the latent and the rotary key) or
+  ``qwen3-next-80b-a3b`` (gated delta-rule layers three to one with gated
+  full attention, a recurrent state and a convolution tail cached beside
+  the keys; models/qwen3_next.py); each has a tiny twin for tests
+  (``glm-test-tiny``, ``glm-dsa-test-tiny``, ``qwen3-next-test-tiny``).
   ``JUDGE_WEIGHTS`` is an HF checkpoint, one ``model.safetensors`` or
-  sharded; the depth served is the checkpoint's, and so is the share of
-  the experts where it names experts 0..E-1 of a wider router (one chip's
-  share of a layer's experts: the pairs routed elsewhere are left out of
-  this chip's partial sum).
+  sharded; what is served is what it names: its layers from 0 up (and of
+  each whether it is dense or sparse and whether it owns an indexer: one
+  pipeline stage of a deployment), experts 0..E-1 of a wider router (one
+  chip's share of a layer's experts: the pairs routed elsewhere are left
+  out of this chip's partial sum) and, for the ``glm`` decoders, the rows
+  of the vocabulary its embedding holds (a slice is a smaller vocabulary).
   ``JUDGE_MAX_TOKENS`` (default 8192) is the ONE sequence bucket every
   call is padded to.  ``JUDGE_QUANTIZE=int8`` runs the dense products
   W8A8 (``quant.dense_int8``).  A server with a judge and no embedder

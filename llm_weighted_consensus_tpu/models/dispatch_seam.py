@@ -13,10 +13,15 @@ module is the replacement contract:
   sink instead of blocking;
 * the batcher's **waiter thread** later runs :func:`drain_sink`, which
   blocks on each output, records the per-(mesh-shape, bucket) device
-  time and the (enqueue, ready) interval — the ``overlap`` gauge in
-  the ``phases`` metrics section is the union of those intervals over
-  wall time — and recycles any host staging buffers the dispatch
-  checked out of the :class:`StagingPool`.
+  time, tells the device's account (``obs/account.py``) the program is
+  ready, and recycles any host staging buffers the dispatch checked out
+  of the :class:`StagingPool`.
+
+The account is fed here, at the two moments of a dispatch this module
+already holds: its enqueue (``DispatchSink.add``, with the record's
+``t0``, the sink's lane and its oldest item's timestamps; the inline
+bracket of :func:`dispatch`) and its ready (``drain_sink``'s ``t1``, the
+bracket's).
 
 Device faults therefore surface at the waiter (readiness is where XLA
 reports them), and the batcher's meshfault triage handles waiter-hop
@@ -36,6 +41,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..obs.account import device_account as _account
+
 
 def wait_device_ready(out) -> None:
     """Default readiness waiter: block until ``out``'s device buffers
@@ -49,7 +56,7 @@ def wait_device_ready(out) -> None:
 class PendingDispatch:
     """One enqueued-but-not-ready device dispatch."""
 
-    __slots__ = ("label", "t0", "out", "wait", "timed")
+    __slots__ = ("label", "t0", "out", "wait", "timed", "ticket")
 
     def __init__(
         self,
@@ -64,8 +71,10 @@ class PendingDispatch:
         self.out = out
         self.wait = wait
         # False when METRICS_DEVICE_TIMING=0: the waiter still blocks
-        # (finalize would anyway) but records nothing
+        # (finalize would anyway) but records no device time
         self.timed = timed
+        # the account's handle on this program (``DispatchSink.add``)
+        self.ticket = None
 
 
 class DispatchSink:
@@ -73,15 +82,22 @@ class DispatchSink:
     ``deferred_readiness``: the pending device dispatches plus the host
     staging buffers checked out for them (returned to the pool only
     after readiness — a ``device_put`` may still be reading the host
-    buffer asynchronously before that)."""
+    buffer asynchronously before that).  ``lane`` and ``marks`` are the
+    batcher's, for the device's account: the group's priority class and a
+    call that gives its oldest item's timestamps, asked at the enqueue
+    (``DeviceAccount.enqueue``)."""
 
-    __slots__ = ("pending", "staged")
+    __slots__ = ("pending", "staged", "lane", "marks")
 
-    def __init__(self) -> None:
+    def __init__(self, lane=None, marks=None) -> None:
         self.pending: List[PendingDispatch] = []
         self.staged: list = []
+        self.lane = lane
+        self.marks = marks
 
     def add(self, record: PendingDispatch) -> PendingDispatch:
+        marks = self.marks() if self.marks is not None else None
+        record.ticket = _account().enqueue(record.t0, self.lane, marks)
         self.pending.append(record)
         return record
 
@@ -128,37 +144,47 @@ def dispatch(label: str, fn: Callable, timed: bool = True):
     if sink is not None:
         sink.add(PendingDispatch(label, t0, out, timed=timed))
         return out
-    wait_device_ready(out)
+    ticket = _account().enqueue(t0)
+    try:
+        wait_device_ready(out)
+    except BaseException:
+        _account().ready(ticket, time.perf_counter(), served=False)
+        raise
+    t1 = time.perf_counter()
+    _account().ready(ticket, t1)
     if timed:
-        t1 = time.perf_counter()
         from ..obs import phases as _phases
 
         _phases.observe_device(label, (t1 - t0) * 1e3)
-        _phases.observe_device_interval(t0, t1)
     return out
 
 
 def drain_sink(
     sink: DispatchSink,
     observe_device: Optional[Callable[[str, float], None]] = None,
-    observe_interval: Optional[Callable[[float, float], None]] = None,
     release: Optional[Callable] = None,
     clock: Callable[[], float] = time.perf_counter,
 ) -> None:
     """The waiter hop: block on every pending dispatch in enqueue
-    order, recording each timed one's device ms (same label contract as
-    the old bracket) and its (enqueue, ready) interval for the overlap
-    gauge.  Staging buffers recycle only on a clean drain — a raising
-    ``wait`` (device fault) propagates to the caller's triage and the
-    buffers are dropped for the GC instead."""
-    for record in sink.pending:
-        record.wait(record.out)
-        t1 = clock()
-        if record.timed:
-            if observe_device is not None:
+    order, telling the device's account each one's ready and recording
+    each timed one's device ms (same label contract as the old bracket).
+    Staging buffers recycle only on a clean drain — a raising ``wait``
+    (device fault) propagates to the caller's triage, the buffers are
+    dropped for the GC instead and the account gives up the programs not
+    seen ready."""
+    account = _account()
+    try:
+        for record in sink.pending:
+            record.wait(record.out)
+            t1 = clock()
+            account.ready(record.ticket, t1)
+            if record.timed and observe_device is not None:
                 observe_device(record.label, (t1 - record.t0) * 1e3)
-            if observe_interval is not None:
-                observe_interval(record.t0, t1)
+    except BaseException:
+        t1 = clock()
+        for record in sink.pending:
+            account.ready(record.ticket, t1, served=False)
+        raise
     if release is not None:
         for buf in sink.staged:
             release(buf)

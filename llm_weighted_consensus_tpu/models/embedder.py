@@ -10,7 +10,6 @@ seeds ``weight_data`` cost, score client.rs:330-337).
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Iterable, Optional
 
@@ -22,12 +21,7 @@ from ..types.chat_response import Usage
 from ..types.embeddings import CreateEmbeddingResponse, Embedding
 from . import bert
 from .configs import PRESETS, BertConfig
-from .dispatch_seam import (
-    PendingDispatch,
-    StagingPool,
-    active_sink,
-    wait_device_ready,
-)
+from .dispatch_seam import StagingPool, active_sink, dispatch
 from .tokenizer import BaseTokenizer, load_tokenizer
 
 
@@ -353,35 +347,22 @@ class TpuEmbedder:
         time into the global phase aggregator (the ``device_dispatch``
         phase + the roofline gauge's per-bucket p50).
 
-        Two readiness modes (dispatch_seam.py): under the batcher's
-        deferred-readiness sink the PJRT call is merely ENQUEUED here —
-        a PendingDispatch record hands (label, t0, output) to the
-        waiter, which blocks, records the identical numbers, and frees
-        this thread to stage the next group.  Without a sink (direct
-        callers) the block-until-ready bracket runs inline, also
-        feeding the overlap gauge's interval union."""
-        sink = active_sink()
-        if sink is None and not self.device_timing:
+        Two readiness modes (``dispatch_seam.dispatch``): under the
+        batcher's deferred-readiness sink the PJRT call is merely
+        ENQUEUED here — a PendingDispatch record hands (label, t0,
+        output) to the waiter, which blocks, records the identical
+        numbers, and frees this thread to stage the next group.  Without
+        a sink (direct callers) the block-until-ready bracket runs
+        inline.  Either way the device's account (obs/account.py) is
+        told of the enqueue and of the ready."""
+        if active_sink() is None and not self.device_timing:
             return fn()
-        t0 = time.perf_counter()
-        out = fn()
         if self.mesh_mode:
             dp, tp = self.mesh_shape
             label = f"{label}@dp{dp}xtp{tp}"
             if self.mesh_sp > 1:
                 label = f"{label}xsp{self.mesh_sp}"
-        if sink is not None:
-            sink.add(
-                PendingDispatch(label, t0, out, timed=self.device_timing)
-            )
-            return out
-        wait_device_ready(out)
-        t1 = time.perf_counter()
-        from ..obs import phases as _phases
-
-        _phases.observe_device(label, (t1 - t0) * 1e3)
-        _phases.observe_device_interval(t0, t1)
-        return out
+        return dispatch(label, fn, timed=self.device_timing)
 
     def _finish(self, out):
         """Materialize a dispatch output for host consumers — unless a

@@ -348,10 +348,6 @@ def experts_held(params: dict, config: GlmMoeLiteConfig) -> int:
     return config.n_routed_experts
 
 
-def recurrent_layers(config: GlmMoeLiteConfig) -> int:
-    return 0
-
-
 def whole_bound_layers(load, config: GlmMoeLiteConfig) -> int:
     """Of a dispatch's sparse layers, those that ran over the layout's whole
     bound.  With every expert held (``load`` [layers, experts]) the layout

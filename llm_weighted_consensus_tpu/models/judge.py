@@ -25,7 +25,7 @@ gives,
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
 beside ``init_params``, ``from_hf_weights``, ``quantize_dense``,
-``experts_held(params, config)``, ``recurrent_layers(config)`` and
+``experts_held(params, config)`` and
 ``whole_bound_layers(load, config)`` (the host's count, from the pairs
 routed, of the sparse layers that ran over their layout's whole bound).
 
@@ -64,7 +64,6 @@ import numpy as np
 
 from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
-from ..ops import causal_attention
 from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
 from . import glm_moe, qwen3_next
@@ -229,7 +228,6 @@ class TpuJudge:
             "calls": 0,
             "prefill_tokens": 0,
             "padded_tokens": 0,
-            "attention_work_over_causal": 0.0,
             "expert_load_max_over_mean_sum": 0.0,
             # pairs (token, choice) the routers made, those sent to an expert
             # held here and those sent elsewhere; ``expert_tokens`` is over
@@ -240,9 +238,6 @@ class TpuJudge:
             # sparse layers, summed over dispatches, whose tiles in use passed
             # the usual load's rows and ran over the layout's whole bound
             "expert_layers_whole_bound": 0,
-            # the dispatches' mean share of a recurrent layer's positions
-            # that were padding (0 for a decoder without a recurrence)
-            "delta_rule_padding_share": 0.0,
             # (query, key) pairs, summed over dispatches and the layers that
             # own an indexer: those a query may see, and those it chose
             "index_keys_causal": 0,
@@ -250,7 +245,6 @@ class TpuJudge:
             "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
         self._held = len(self._stats["expert_tokens"])
-        self._recurrent = self.decoder.recurrent_layers(self.config) > 0
 
     # -- host ---------------------------------------------------------------
 
@@ -386,30 +380,17 @@ class TpuJudge:
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
         elsewhere = int(load[:, self._held:].sum()) if load.size else 0
         load = load[:, :self._held] if load.size else load
-        padding = 1.0 - prepared.tokens / prepared.ids.size if self._recurrent else 0.0
         ratio = 0.0
         if load.size and load.sum():
             # a dispatch's largest load over its mean, the worst layer's
             ratio = float((load.max(axis=1) / load.mean(axis=1)).max())
-        # what the attention kernel's schedule multiplies over what the
-        # causal mask keeps: a number of the bucket
-        slots = prepared.ids.shape[1]
-        block = causal_attention.block_for(slots)
-        work = causal_attention.work_over_causal(slots, block, block)
         with self._lock:
             s = self._stats
             s["dispatches"] += 1
             s["calls"] += len(prepared.calls)
             s["prefill_tokens"] += prepared.tokens
             s["padded_tokens"] += prepared.ids.size - prepared.tokens
-            # the dispatches' mean
-            s["attention_work_over_causal"] += (
-                work - s["attention_work_over_causal"]
-            ) / s["dispatches"]
             s["expert_load_max_over_mean_sum"] += ratio
-            s["delta_rule_padding_share"] += (
-                padding - s["delta_rule_padding_share"]
-            ) / s["dispatches"]
             s["expert_pairs_elsewhere"] += elsewhere
             s["expert_pairs_routed"] += elsewhere
             s["expert_layers_whole_bound"] += whole_bound

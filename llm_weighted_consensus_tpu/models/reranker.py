@@ -17,7 +17,6 @@ MAX_CONSENSUS_CANDIDATES.
 
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Iterable, Optional
 
@@ -25,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import phases as _phases
 from . import deberta
+from . import dispatch_seam as _seam
 from .configs import DEBERTA_TEST_TINY, DEBERTA_V3_BASE, DebertaConfig
 from .tokenizer import BaseTokenizer, load_tokenizer
 
@@ -111,20 +110,17 @@ class TpuReranker:
         # device time per (N, sequence bucket) in the ``roofline``
         # section of /metrics and under the ``device_dispatch`` phase
         label = f"rm_vote(n={ids.shape[0]},s={ids.shape[1]})"
-        t0 = time.perf_counter()
-        conf = np.asarray(
-            _reward_and_vote(
+        conf = _seam.dispatch(
+            label,
+            lambda: _reward_and_vote(
                 self.params,
                 jnp.asarray(ids),
                 jnp.asarray(mask),
                 float(temperature),
                 self.config,
-            )
+            ),
         )
-        t1 = time.perf_counter()
-        _phases.observe_device(label, (t1 - t0) * 1e3)
-        _phases.observe_device_interval(t0, t1)
-        return conf, int(mask.sum())
+        return np.asarray(conf), int(mask.sum())
 
 
 def load_rm_params(path: str, config: DebertaConfig, dtype=None):

@@ -13,12 +13,18 @@ ambient helper here is a single contextvar read returning None.
 ``hostspan`` — the one helper behind every boundary of the serving host
 path: a ``host_span`` feeds the phase histograms, the profiler's trace and
 the span tree from one call (ISSUE 24).
+
+``account`` — the device's time as the host sees it, one cumulative record
+of enqueued, served, starved and idle time (ISSUE 37).
 """
 
+from .account import DeviceAccount, device_account  # noqa: F401
 from .histogram import Histogram  # noqa: F401
 from .hostspan import (  # noqa: F401
     HOST_SPANS,
     arrive,
+    current_request,
+    depart,
     host_span,
     next_id,
     request_id,

@@ -23,6 +23,11 @@ and the one call
    holds its requests' spans (a thread with no ambient context, a group of
    several requests), else on the ambient span, where there is one.
 
+A request's own two ends feed the device's account (account.py) from here
+too: ``arrive`` opens it, the end of ``http:read`` stamps its body read, the
+end of ``http:respond`` closes it (``depart`` for a handler that never
+answered), and the batcher keeps ``current_request()`` with the item.
+
 With no profile and no root span it is the phase observe alone.  The
 annotation class is handed in by whoever starts the profiler
 (``set_profiler_annotation``): ``obs/`` imports no jax.
@@ -42,6 +47,7 @@ import itertools
 import time
 from typing import Iterable, Optional
 
+from .account import device_account
 from .phases import observe_phase
 from .span import current_span
 
@@ -50,6 +56,7 @@ from .span import current_span
 # every name to KNOWN_SPANS, and lint LWC010 holds the call sites to both.
 HOST_SPANS = {
     "http:arrive": None,  # an instant: the request's first line
+    "http:read": "http_read",  # the body off the socket
     "http:parse": "http_parse",
     "host:tokenize": "tokenize",
     "batcher:idle": None,  # a wait, not work
@@ -65,7 +72,28 @@ HOST_SPANS = {
 
 _annotation = None  # the profiler's annotation class while a profile runs
 _RID: contextvars.ContextVar = contextvars.ContextVar("lwc_rid", default=None)
+_REQUEST: contextvars.ContextVar = contextvars.ContextVar(
+    "lwc_request", default=None
+)
 _SEQ = itertools.count(1)
+
+
+class _Request:
+    """A request's own timestamps for the device's account (account.py):
+    when it arrived, when its body was read, and whether the account still
+    counts it in the server."""
+
+    __slots__ = ("arrived", "read", "open")
+
+    def __init__(self, arrived: float) -> None:
+        self.arrived = arrived
+        self.read = None
+        self.open = True
+
+    def close(self) -> None:
+        if self.open:
+            self.open = False
+            device_account().request_close()
 
 
 def set_profiler_annotation(factory) -> None:
@@ -103,7 +131,26 @@ def arrive(route: str, nbytes: int):
     rid = request_id()
     with host_span("http:arrive", rid=rid, route=route, bytes=nbytes):
         pass
+    depart()  # a request before this one on the connection that never answered
+    request = _Request(time.perf_counter())
+    _REQUEST.set(request)
+    device_account().request_open(request.arrived)
     return rid
+
+
+def current_request():
+    """The ambient request's timestamps (None for a caller that never passed
+    a handler): the batcher keeps them with the item it submits."""
+    return _REQUEST.get()
+
+
+def depart() -> None:
+    """The handler is over: a request that left without an ``http:respond``
+    (its client gone, a 413 raised above the handler) leaves the account
+    here.  Nothing to do where ``http:respond`` closed it."""
+    request = _REQUEST.get()
+    if request is not None:
+        request.close()
 
 
 class host_span:
@@ -164,3 +211,15 @@ class host_span:
         phase = HOST_SPANS[self.name]
         if phase is not None:
             observe_phase(phase, ms)
+        if self.name in _REQUEST_ENDS:
+            request = _REQUEST.get()
+            if request is not None:
+                _REQUEST_ENDS[self.name](request)
+
+
+def _body_read(request: _Request) -> None:
+    request.read = time.perf_counter()
+
+
+# the two spans whose END is one of a request's timestamps
+_REQUEST_ENDS = {"http:read": _body_read, "http:respond": _Request.close}

@@ -5,6 +5,8 @@ always-on aggregate.  Every scored request is bracketed through a fixed
 phase vocabulary:
 
 * ``admission_wait``  — gateway door to admission slot held
+* ``http_read``       — the request's body read off the socket (span
+  ``http:read``)
 * ``http_parse``      — body parsed and validated (span ``http:parse``)
 * ``tokenize``        — an item's texts to padded rows, on the host
   tokenizer pool at submit or inline in the stage hop (``host:tokenize``)
@@ -12,10 +14,12 @@ phase vocabulary:
 * ``pack_plan``       — host-side ragged packing plan (packed path)
 * ``stage``           — a group's rows joined, padded, put on the device
   and its program enqueued (``batcher:stage``)
-* ``device_dispatch`` — the device executable itself, measured
-  enqueue-to-ready at the embedder seam (models/dispatch_seam.py: the
-  batcher's waiter thread blocks; direct callers pay an inline
-  bracket), per (mesh-shape, bucket)
+* ``device_dispatch`` — a device executable's SOJOURN, enqueue to ready
+  at the embedder seam (models/dispatch_seam.py: the batcher's waiter
+  thread blocks; direct callers pay an inline bracket), per (mesh-shape,
+  bucket): with two programs in flight it holds the wait behind the one
+  ahead, which ``obs/account.py`` splits off (``service_ms`` /
+  ``waited_ms``)
 * ``finalize``        — results fetched, converted and split per item
   (``host:finalize``)
 * ``host_tally``      — consensus tally / packed reassembly on host
@@ -48,15 +52,16 @@ Stdlib-only, dependency-free below ``utils`` like the rest of ``obs/``.
 from __future__ import annotations
 
 import threading
-from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from .account import device_account
 from .histogram import Histogram
 
 # the phase vocabulary, in request order; the /metrics ``phases``
 # section renders exactly these keys
 PHASES = (
     "admission_wait",
+    "http_read",
     "http_parse",
     "tokenize",
     "batcher_queue",
@@ -77,16 +82,10 @@ class PhaseAggregator:
     while HTTP phases land from the event loop; each observe is one
     O(1) histogram increment under an uncontended lock."""
 
-    # retained (enqueue, ready) device intervals for the overlap gauge;
-    # a rolling window so the gauge tracks the CURRENT pipelining
-    # behavior, not the process lifetime average
-    INTERVAL_WINDOW = 4096
-
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._phases: Dict[str, Histogram] = {}
         self._device: Dict[str, Histogram] = {}
-        self._intervals: deque = deque(maxlen=self.INTERVAL_WINDOW)
 
     def observe_phase(self, phase: str, ms: float) -> None:
         with self._lock:
@@ -110,43 +109,19 @@ class PhaseAggregator:
                 phist = self._phases["device_dispatch"] = Histogram()
             phist.observe(ms)
 
-    def observe_device_interval(self, start: float, end: float) -> None:
-        """One device dispatch's (enqueue, ready) interval in
-        ``time.perf_counter`` seconds — the raw material for the
-        ``overlap`` gauge (pipelined dispatches' intervals genuinely
-        overlap; a serialized pipeline's tile end to start)."""
-        with self._lock:
-            self._intervals.append((float(start), float(end)))
-
-    def device_intervals(self) -> List[Tuple[float, float]]:
-        """The retained interval window (tests and the gauge)."""
-        with self._lock:
-            return list(self._intervals)
-
     # -- read side ------------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """The /metrics ``phases`` section: per-phase histogram summary
-        plus the ``overlap`` gauge (ISSUE 13): device-busy
-        union-interval over wall time across the retained dispatch
-        window.  ~1.0 means pipelined dispatches keep the device
-        continuously busy; a fully serialized pipeline with host work
-        between dispatches reads well below 1.  None until two
-        dispatches have landed (no overlap to speak of)."""
+        """The /metrics ``phases`` section: per-phase histogram summary,
+        in ``PHASES`` order, the observed phases only.  (Whether pipelined
+        dispatches keep the device fed is the account's to say:
+        ``device_batcher.account``, obs/account.py.)"""
         with self._lock:
             rows = {
                 phase: hist.to_json_obj()
                 for phase, hist in self._phases.items()
             }
-            intervals = list(self._intervals)
-        out: dict = {phase: rows[phase] for phase in PHASES if phase in rows}
-        overlap = None
-        if len(intervals) >= 2:
-            wall = max(e for _, e in intervals) - min(s for s, _ in intervals)
-            if wall > 0:
-                overlap = round(min(_union_ms(intervals) / wall, 1.0), 4)
-        out["overlap"] = overlap
-        return out
+        return {phase: rows[phase] for phase in PHASES if phase in rows}
 
     def device_snapshot(self) -> Dict[str, dict]:
         """Per-(mesh-shape, bucket) device-time summaries."""
@@ -175,7 +150,6 @@ class PhaseAggregator:
         with self._lock:
             self._phases.clear()
             self._device.clear()
-            self._intervals.clear()
 
 
 def _clone(hist: Histogram) -> Histogram:
@@ -197,16 +171,14 @@ def observe_device(bucket: str, ms: float) -> None:
     _AGG.observe_device(bucket, ms)
 
 
-def observe_device_interval(start: float, end: float) -> None:
-    _AGG.observe_device_interval(start, end)
-
-
 def phases_snapshot() -> dict:
     return _AGG.snapshot()
 
 
 def reset_phases() -> None:
+    """Tests: the aggregator and, beside it, the device's account."""
     _AGG.reset()
+    device_account().reset()
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +188,7 @@ def reset_phases() -> None:
 
 def _union_ms(intervals: List[Tuple[float, float]]) -> float:
     """Total length of the union of (start, end) intervals — concurrent
-    judge streams (or pipelined dispatches) attribute wall time once."""
+    judge streams attribute wall time once."""
     if not intervals:
         return 0.0
     intervals.sort()
@@ -241,7 +213,7 @@ def phase_breakdown(trace) -> dict:
     span's annotated host sub-costs ``pack_plan_ms`` / ``host_tally_ms``,
     stamped per item by the packed dispatch) is device time, then what
     is left of the item's ``batcher:<kind>`` span is queue time;
-    ``http:parse``, ``http:respond``, ``consensus:tally`` and
+    ``http:read``, ``http:parse``, ``http:respond``, ``consensus:tally`` and
     ``judge:stream`` map directly; ``admission_wait_ms`` rides a root
     annotation (the admission middleware runs before any child span
     exists).  Returns ``{phase: ms}`` plus ``e2e_ms`` and the
@@ -288,6 +260,7 @@ def phase_breakdown(trace) -> dict:
         )
         if root is not None
         else 0.0,
+        "http_read": _union_ms(of("http:read")),
         "http_parse": _union_ms(of("http:parse")),
         "tokenize": grown["tokenize"],
         "batcher_queue": grown["batcher_queue"],
@@ -314,6 +287,7 @@ def phase_breakdown(trace) -> dict:
 # ``batcher:<kind>`` span is whatever else starts with ``batcher:``)
 _BREAKDOWN_SPANS = frozenset(
     (
+        "http:read",
         "http:parse",
         "host:tokenize",
         "batcher:stage",

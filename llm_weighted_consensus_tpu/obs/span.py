@@ -64,6 +64,7 @@ KNOWN_SPANS = (
     "host:tokenize",
     "host:finalize",
     "http:arrive",
+    "http:read",
     "http:parse",
     "http:respond",
     "lwc:clock",

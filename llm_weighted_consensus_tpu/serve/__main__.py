@@ -20,6 +20,7 @@ from ..clients.chat import AiohttpTransport, ApiBase, DefaultChatClient
 from ..clients.multichat import MultichatClient
 from ..clients.score import ScoreClient
 from ..weights import WeightFetchers
+from . import startup
 from .config import Config, configure_compile_cache, load_dotenv
 from .gateway import LIFECYCLE_KEY, _parse_error_response, build_app
 
@@ -278,31 +279,35 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
 
     params = None
     vocab_path = config.embedder_vocab
-    if config.embedder_weights:
-        from ..models.loading import find_vocab, load_params
+    # the checkpoint opened to the parameters on the device: ``weights``
+    # of the /metrics ``startup`` section
+    with startup.stopwatch("weights"):
+        if config.embedder_weights:
+            from ..models.loading import find_vocab, load_params
 
-        params = load_params(
-            config.embedder_weights, PRESETS[config.embedder_model]
-        )
-        if not vocab_path:
-            vocab_path = find_vocab(config.embedder_weights)
-    embedder = TpuEmbedder(
-        config.embedder_model,
-        params=params,
-        # only override the tokenizer when a real vocab is available;
-        # TpuEmbedder's default hash fallback sizes to the model vocab.
-        # scheme matters only for spm protos (bge-m3 -> xlmr convention)
-        tokenizer=(
-            load_tokenizer(
-                vocab_path,
-                scheme=scheme_for_model(config.embedder_model),
+            params = load_params(
+                config.embedder_weights, PRESETS[config.embedder_model]
             )
-            if vocab_path
-            else None
-        ),
-        max_tokens=config.embedder_max_tokens,
-        quantize=config.embedder_quantize,
-    )
+            if not vocab_path:
+                vocab_path = find_vocab(config.embedder_weights)
+        embedder = TpuEmbedder(
+            config.embedder_model,
+            params=params,
+            # only override the tokenizer when a real vocab is available;
+            # TpuEmbedder's default hash fallback sizes to the model vocab.
+            # scheme matters only for spm protos (bge-m3 -> xlmr convention)
+            tokenizer=(
+                load_tokenizer(
+                    vocab_path,
+                    scheme=scheme_for_model(config.embedder_model),
+                )
+                if vocab_path
+                else None
+            ),
+            max_tokens=config.embedder_max_tokens,
+            quantize=config.embedder_quantize,
+        )
+        _params_on_device(embedder)
     from ..models.tokenizer import HashTokenizer
 
     synthetic = []
@@ -352,8 +357,19 @@ def build_embedder(config: Config, allow_synthetic: bool = False):
             sp=shape[2] if shape and len(shape) > 2 else 1,
             devices=jax.local_devices(),
         )
-        shard_embedder_mesh(embedder, mesh)
+        with startup.stopwatch("weights"):
+            shard_embedder_mesh(embedder, mesh)
+            _params_on_device(embedder)
     return embedder
+
+
+def _params_on_device(model) -> None:
+    """Block until ``model.params`` are there: the transfers a loader
+    starts are asynchronous, and the ``weights`` stopwatch ends when they
+    have landed, not when they were asked for."""
+    from ..models.dispatch_seam import wait_device_ready
+
+    wait_device_ready(model.params)
 
 
 def build_reranker(config: Config, allow_synthetic: bool = False):
@@ -451,7 +467,6 @@ def build_judge(config: Config, allow_synthetic: bool = False):
     if not config.judge_model:
         return None
     import logging
-    import time as _time
 
     from ..models.judge import JUDGE_PRESETS, TpuJudge, load_judge_params
     from ..models.tokenizer import HashTokenizer, load_tokenizer
@@ -468,24 +483,28 @@ def build_judge(config: Config, allow_synthetic: bool = False):
     if config.judge_weights:
         from ..models.loading import find_vocab
 
-        t0 = _time.perf_counter()
-        params, preset = load_judge_params(config.judge_weights, preset)
+        with startup.stopwatch("weights") as loading:
+            params, preset = load_judge_params(config.judge_weights, preset)
         log.info(
             "judge: %d layers of %s loaded in %.1fs",
-            preset.num_layers, config.judge_weights, _time.perf_counter() - t0,
+            preset.num_layers, config.judge_weights, loading.seconds,
         )
         if not vocab_path:
             vocab_path = find_vocab(config.judge_weights)
-    judge = TpuJudge(
-        config.judge_model,
-        params=params,
-        config=preset,
-        tokenizer=(
-            load_tokenizer(vocab_path, scheme="deberta") if vocab_path else None
-        ),
-        max_tokens=config.judge_max_tokens,
-        quantize=config.judge_quantize,
-    )
+    with startup.stopwatch("weights"):
+        judge = TpuJudge(
+            config.judge_model,
+            params=params,
+            config=preset,
+            tokenizer=(
+                load_tokenizer(vocab_path, scheme="deberta")
+                if vocab_path
+                else None
+            ),
+            max_tokens=config.judge_max_tokens,
+            quantize=config.judge_quantize,
+        )
+        _params_on_device(judge)
     synthetic = []
     if params is None:
         synthetic.append("random-init judge weights (no JUDGE_WEIGHTS)")
@@ -512,9 +531,11 @@ def build_judge(config: Config, allow_synthetic: bool = False):
             detail,
         )
     judge.device_timing = config.metrics_device_timing
+    with startup.stopwatch("warmup"):
+        warmed_s = judge.warmup()
     log.info(
         "judge: panel program (calls=3, s=%d) compiled in %.1fs",
-        judge.max_tokens, judge.warmup(),
+        judge.max_tokens, warmed_s,
     )
     return judge
 
@@ -845,6 +866,14 @@ def build_service(
     model_registry = registry.InMemoryModelRegistry()
     # --fake-upstream is demo/test mode: synthetic embedder params are
     # allowed (still logged); production startup refuses them
+    if config.embedder_model or config.rm_model or config.judge_model:
+        # the backend's own start-up (the TPU runtime's, seconds) is paid
+        # by whoever touches a device first: here, so that ``weights`` of
+        # the ``startup`` section holds the checkpoints alone
+        # (``listening`` holds it either way)
+        import jax
+
+        jax.devices()
     embedder = build_embedder(config, allow_synthetic=fake_upstream)
     reranker = build_reranker(config, allow_synthetic=fake_upstream)
     judge = build_judge(config, allow_synthetic=fake_upstream)
@@ -861,7 +890,7 @@ def build_service(
         # enqueue-to-ready: under the batcher the readiness wait runs on
         # a waiter thread (models/dispatch_seam.py), so timing no longer
         # serializes the dispatch pipeline; =0 only darkens the device
-        # rows, roofline attainment and the overlap gauge
+        # rows and roofline attainment
         embedder.device_timing = config.metrics_device_timing
     if embedder is not None and config.aot_cache_dir:
         # AOT_CACHE_DIR: fleet-shared serialized-executable store — the
@@ -894,14 +923,15 @@ def build_service(
             packed_buckets.extend(
                 (1, l, k) for l in _L_BUCKETS if l < l_top
             )
-        _warmup_embedder(
-            embedder,
-            config.warmup,
-            config.warmup_r,
-            aot=config.warmup_aot,
-            packed_buckets=packed_buckets,
-            ring_buckets=config.long_context_warmup,
-        )
+        with startup.stopwatch("warmup"):
+            _warmup_embedder(
+                embedder,
+                config.warmup,
+                config.warmup_r,
+                aot=config.warmup_aot,
+                packed_buckets=packed_buckets,
+                ring_buckets=config.long_context_warmup,
+            )
     # mesh fault domains (MESH_FAULT_ENABLED, resilience/meshfault.py):
     # the downsize ladder is declared — and every fallback rung AOT-warmed
     # under its own ("mesh", dp, tp) key namespace — at startup, so a
@@ -980,6 +1010,11 @@ def build_service(
     # metrics exist regardless of the device side: the result cache's
     # counters (and the HTTP series) are host-only observability
     metrics = Metrics()
+    # set-up's stopwatches (serve/startup.py); ``compile`` as it stands
+    # when read
+    metrics.register_provider(
+        "startup", lambda: startup.snapshot(compile_cache)
+    )
     if compile_cache is not None:
         metrics.register_provider("compile_cache", compile_cache.snapshot)
     if embedder is not None or reranker is not None or judge is not None:
@@ -1509,6 +1544,7 @@ async def _serve(
     runner = web.AppRunner(app)
     await runner.setup()
     await web.TCPSite(runner, config.address, config.port).start()
+    startup.listening()
     print(f"listening on {config.address}:{config.port}", flush=True)
 
     # SIGINT/SIGTERM set a stop event instead of raising KeyboardInterrupt

@@ -40,7 +40,7 @@ the pipeline is asynchronous end to end (ISSUE 13):
   k+1's staging genuinely overlaps group k's device execution, even
   with ``METRICS_DEVICE_TIMING=1``;
 * **waiter thread** — blocks on the enqueued outputs, records the
-  per-bucket device time + the ``overlap`` gauge interval, recycles the
+  per-bucket device time, tells the device's account, recycles the
   staging buffers, and materializes per-item results.  Device faults
   surface here and feed the same meshfault triage as dispatch-thread
   ones.
@@ -58,13 +58,13 @@ import asyncio
 import functools
 import threading
 import time
-from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import numpy as np
 
 from ..models import dispatch_seam as _seam
+from ..obs.account import device_account
 from ..obs import hostspan as _hostspan
 
 
@@ -72,6 +72,7 @@ class _Item:
     __slots__ = (
         "kind", "key", "payload", "future", "deadline", "span",
         "redispatches", "submitted", "prepared", "lane", "rid",
+        "request", "rows_ready", "started",
     )
 
     def __init__(
@@ -103,6 +104,13 @@ class _Item:
         # id where it has a root span, else a number of the process-wide
         # sequence; captured at submit like the span, for the same reason
         self.rid = _hostspan.request_id()
+        # what the device's account cuts a starved interval at (obs/
+        # account.py): the request's own arrival and body read, captured
+        # like the rid; the end of ``_prepare_item``; ``_run_group``'s t0
+        # (``marks``)
+        self.request = _hostspan.current_request()
+        self.rows_ready = None
+        self.started = None
         # times this item was re-queued after a classified device fault
         # (resilience/meshfault.py) — bounded so a fault loop can never
         # recycle one item forever
@@ -111,6 +119,19 @@ class _Item:
         # resolving to this item's pre-built rows (padded kinds) or its
         # packed plan; None when the pool is off or the kind streams
         self.prepared = None
+
+    def marks(self) -> tuple:
+        """(arrived, body read, submitted, rows ready, started) as they
+        stand when asked: the dispatch seam asks at the ENQUEUE, when the
+        rows the stage hop waited for are there."""
+        request = self.request
+        return (
+            request.arrived if request is not None else None,
+            request.read if request is not None else None,
+            self.submitted,
+            self.rows_ready,
+            self.started,
+        )
 
 
 def _rids(group: list) -> str:
@@ -309,24 +330,21 @@ class DeviceBatcher:
         pool = getattr(embedder, "staging_pool", None)
         if pool is not None:
             pool.per_bucket = self.staging_buffers
-        # recent device-dispatch intervals, for the busy-fraction gauge
-        self._busy: deque = deque(maxlen=1024)
-        # (start time, lane) of dispatches currently in flight
-        self._inflight: dict = {}
+        # whether the device had a program, all lanes and each: views of
+        # the one account the dispatch seam feeds (obs/account.py)
+        self._account = device_account()
+        # the groups currently in flight (``idle()``)
+        self._inflight: set = set()
         self._started = time.perf_counter()
         self._dispatches = 0
         self._items = 0
-        # per-lane accounting (ISSUE 20): dispatches/items counters plus
-        # a busy-interval ring per priority class, so /metrics exposes
-        # per-class utilization/occupancy.  Event-loop-only like the
-        # combined counters above — _observe is the sole writer — so no
-        # lock (and no concurrency_model.py registry row) is needed
+        # per-lane accounting (ISSUE 20): dispatches/items counters per
+        # priority class, so /metrics exposes per-class utilization.
+        # Event-loop-only like the combined counters above — _observe is
+        # the sole writer — so no lock (and no concurrency_model.py
+        # registry row) is needed
         self._lane_dispatches = {"latency": 0, "offline": 0}
         self._lane_items = {"latency": 0, "offline": 0}
-        self._lane_busy = {
-            "latency": deque(maxlen=1024),
-            "offline": deque(maxlen=1024),
-        }
         if metrics is not None:
             metrics.register_provider("device_batcher", self.utilization)
             if embed_cache is not None:
@@ -637,18 +655,7 @@ class DeviceBatcher:
 
     def utilization(self, window_sec: float = 60.0) -> dict:
         now = time.perf_counter()
-        lo = now - window_sec
-        span = max(min(window_sec, now - self._started), 1e-9)
-
-        def busy_fraction(intervals, inflight_lane=None):
-            busy = sum(
-                max(0.0, min(end, now) - max(start, lo))
-                for start, end in intervals
-            )
-            for start, lane in self._inflight.values():
-                if inflight_lane is None or lane == inflight_lane:
-                    busy += now - max(start, lo)
-            return round(min(busy / span, 1.0), 4)
+        since = max(now - window_sec, self._started)
         # consistent counter snapshot: the dispatch workers mutate these
         # under the same lock; the staging-pool stats() call below stays
         # OUTSIDE it (the pool has its own lock — no nesting, no edge)
@@ -664,7 +671,10 @@ class DeviceBatcher:
             fallback_dispatches = self.fallback_dispatches
         return {
             "queue_depth": len(self._pending),
-            "busy_fraction": busy_fraction(self._busy),
+            # the share of the last ``window_sec`` in which the device
+            # had a program enqueued (pipelined programs once)
+            "busy_fraction": self._account.occupancy(None, since, now),
+            "account": self._account.snapshot(),
             # per-priority-class utilization (ISSUE 20): the offline
             # lane's occupancy is the acceptance gauge for the train/
             # feed drill (>= 90% on an otherwise-idle mesh)
@@ -677,8 +687,8 @@ class DeviceBatcher:
                     ),
                     "dispatches": self._lane_dispatches[lane],
                     "items": self._lane_items[lane],
-                    "busy_fraction": busy_fraction(
-                        self._lane_busy[lane], inflight_lane=lane
+                    "busy_fraction": self._account.occupancy(
+                        lane, since, now
                     ),
                 }
                 for lane in ("latency", "offline")
@@ -738,38 +748,10 @@ class DeviceBatcher:
     def lane_occupancy(
         self, lane: str, since: float, until: Optional[float] = None
     ) -> float:
-        """Fraction of ``[since, until]`` the device had ``lane`` work
-        in flight, with overlapping pipelined intervals MERGED (unlike
-        the clamped busy-fraction gauge, this is an honest coverage
-        measure — the acceptance gauge for the offline-occupancy
-        drill).  Event-loop read over event-loop-written state."""
-        now = time.perf_counter() if until is None else until
-        window = now - since
-        if window <= 0:
-            return 0.0
-        intervals = [
-            (max(start, since), min(end, now))
-            for start, end in self._lane_busy.get(lane, ())
-            if end > since and start < now
-        ]
-        intervals += [
-            (max(start, since), now)
-            for start, inflight_lane in self._inflight.values()
-            if inflight_lane == lane and start < now
-        ]
-        if not intervals:
-            return 0.0
-        intervals.sort()
-        covered = 0.0
-        cur_lo, cur_hi = intervals[0]
-        for lo, hi in intervals[1:]:
-            if lo > cur_hi:
-                covered += cur_hi - cur_lo
-                cur_lo, cur_hi = lo, hi
-            else:
-                cur_hi = max(cur_hi, hi)
-        covered += cur_hi - cur_lo
-        return round(min(covered / window, 1.0), 4)
+        """Fraction of ``[since, until]`` the device had a program of
+        ``lane`` enqueued, pipelined programs counted once (the acceptance
+        gauge for the offline-occupancy drill): the account's view."""
+        return self._account.occupancy(lane, since, until)
 
     # -- internals -----------------------------------------------------------
 
@@ -1058,10 +1040,11 @@ class DeviceBatcher:
     async def _run_group(self, loop, group) -> None:
         t0 = time.perf_counter()
         token = object()
-        self._inflight[token] = (t0, group[0].lane)
+        self._inflight.add(token)
         from ..obs import phases as _phases
 
         for item in group:
+            item.started = t0
             _phases.observe_phase(
                 "batcher_queue", (t0 - item.submitted) * 1e3
             )
@@ -1098,7 +1081,7 @@ class DeviceBatcher:
             # readiness moved OFF the dispatch thread (ISSUE 13): the
             # hop above returns at enqueue, freeing its executor worker
             # to stage the next group; this waiter hop blocks on the
-            # enqueued outputs, records device time + overlap intervals,
+            # enqueued outputs, records device time,
             # and materializes per-item results
             results = await loop.run_in_executor(
                 self._waiters, self._finalize_group, staged
@@ -1228,17 +1211,12 @@ class DeviceBatcher:
 
     def _observe(self, group, t0, token, *, error: bool) -> None:
         end = time.perf_counter()
-        self._inflight.pop(token, None)
-        # overlapping pipelined intervals can double-count; the busy
-        # fraction gauge clamps at 1.0, which is the honest reading of
-        # "the device path has work in flight"
-        self._busy.append((t0, end))
+        self._inflight.discard(token)
         self._dispatches += 1
         self._items += len(group)
         lane = group[0].lane
         self._lane_dispatches[lane] += 1
         self._lane_items[lane] += len(group)
-        self._lane_busy[lane].append((t0, end))
         series = self._est_kind(group[0])
         if not error:
             # warm per-kind dispatch-time estimate for the deadline shed
@@ -1373,7 +1351,10 @@ class DeviceBatcher:
                 with self.fallback_context():
                     return fn(group, self.fallback_embedder)()
             return fn(group, self.fallback_embedder)()
-        sink = _seam.DispatchSink()
+        # the lane and the oldest item's timestamps ride the sink to the
+        # enqueue, where the device's account takes them
+        oldest = min(group, key=lambda item: item.submitted)
+        sink = _seam.DispatchSink(lane=oldest.lane, marks=oldest.marks)
         if self.meshfault is not None:
             # shared side of the shape gate: this dispatch's embedder
             # reads (params, batch_multiple, shardings) are serialized
@@ -1399,8 +1380,8 @@ class DeviceBatcher:
 
     def _finalize_group(self, staged):
         """Waiter hop (lwc-waiter thread): block on the group's enqueued
-        outputs, record per-bucket device time + the overlap gauge's
-        (enqueue, ready) intervals, recycle staging buffers, then run
+        outputs, record per-bucket device time (the seam tells the
+        device's account each ready), recycle staging buffers, then run
         the finalize closure (np conversions + per-item splits).  Device
         faults raise here and ride ``_run_group``'s triage."""
         if not isinstance(staged, _StagedGroup):
@@ -1417,7 +1398,6 @@ class DeviceBatcher:
             _seam.drain_sink(
                 staged.sink,
                 observe_device=_phases.observe_device,
-                observe_interval=_phases.observe_device_interval,
                 release=pool.release if pool is not None else None,
             )
         with _hostspan.host_span(
@@ -1436,7 +1416,14 @@ class DeviceBatcher:
         pre-built padded rows for embed/consensus items, or the local-
         index packed plan for packed-key items.  Always runs against the
         PRIMARY embedder's tokenizer; the dispatch falls back to inline
-        tokenization when it is serving the CPU twin."""
+        tokenization when it is serving the CPU twin.  Its end is the
+        item's ``rows_ready`` (the device's account, ``_Item.marks``)."""
+        try:
+            return self._prepare_rows(item)
+        finally:
+            item.rows_ready = time.perf_counter()
+
+    def _prepare_rows(self, item):
         kind, key, payload = item.kind, item.key, item.payload
         if kind == "judge":
             return self._prepare_judge(item)

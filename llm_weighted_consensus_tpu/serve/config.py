@@ -434,13 +434,14 @@ analysis/roofline.py — DESIGN.md "Performance observability"):
 * ``METRICS_DEVICE_TIMING`` — per-bucket device-time measurement at the
   embedder seam: every dispatch is timed enqueue-to-ready and lands in
   the ``phases`` / ``roofline`` sections of ``GET /metrics`` keyed by
-  its (mesh-shape, bucket) label, plus the ``overlap`` gauge (device-
-  busy union-interval over wall time across recent dispatches).  Under
+  its (mesh-shape, bucket) label.  Under
   the batcher the readiness wait runs on a waiter thread
   (models/dispatch_seam.py), so timing does NOT serialize the dispatch
   pipeline; direct embedder callers pay an inline bracket.  Default on;
-  ``0`` skips the recording (device rows, roofline attainment and the
-  overlap gauge go dark, the other phases keep reporting).
+  ``0`` skips the recording (device rows and roofline attainment go
+  dark, the other phases keep reporting; the device's account,
+  ``device_batcher.account``, hears of every dispatch that passes the
+  waiter either way, and of no inline one that is not waited for).
   ``GET /metrics?format=prometheus`` renders the same data as
   OpenMetrics text with trace-id exemplars on the hot series.
 

@@ -210,6 +210,29 @@ def _parse_error_response(e: Exception) -> web.Response:
     )
 
 
+async def _read_body(request: web.Request, rid) -> str:
+    """``http:read``: the body off the socket, between ``http:arrive`` and
+    ``http:parse``."""
+    with obs.host_span("http:read", rid=rid) as reading:
+        raw = await request.text()
+        reading.annotate(bytes=len(raw))
+    return raw
+
+
+def _arrives(handler):
+    """Around a handler that calls ``obs.arrive``: a request that leaves
+    without an ``http:respond`` (its client gone and the handler cancelled,
+    a 413 raised to the middleware) leaves the device's account too."""
+
+    async def accounted(request: web.Request):
+        try:
+            return await handler(request)
+        finally:
+            obs.depart()
+
+    return accounted
+
+
 def _respond(rid, build) -> web.Response:
     """``http:respond``: from the result (or the error) in hand to the
     response object built, serialization included."""
@@ -514,7 +537,7 @@ def _make_handler(params_cls, create_streaming, create_unary, fastpath=False):
     async def handler(request: web.Request):
         rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            raw = await request.text()
+            raw = await _read_body(request, rid)
             with obs.host_span("http:parse", rid=rid, bytes=len(raw)):
                 params = params_cls.from_json_obj(jsonutil.loads(raw))
         except web.HTTPException:
@@ -541,7 +564,7 @@ def _make_handler(params_cls, create_streaming, create_unary, fastpath=False):
             ),
         )
 
-    return handler
+    return _arrives(handler)
 
 
 async def _with_consensus_frames(stream, embedder, metrics=None, batcher=None):
@@ -641,7 +664,9 @@ def _profile_handlers(profile_dir: str):
     ``obs`` here, the one place a profile starts), and the trace opens
     and closes with a ``lwc:clock`` mark that carries ``perf_counter_ns``
     and ``epoch_ns``: any host time of this process, or of a client on
-    the same machine, can be laid on the trace.  ``POST /v1/profile``
+    the same machine, can be laid on the trace; and the device's account
+    (``enqueued_ms``, ``starved_ms``, ``idle_ms``: obs/account.py), so the
+    account's window between the marks is the trace's.  ``POST /v1/profile``
     turns the Python tracer off: the host planes then hold those spans
     and the runtime's own events instead of every Python frame, and the
     trace is a third to two thirds smaller.  ``/profile/start`` keeps the
@@ -653,11 +678,18 @@ def _profile_handlers(profile_dir: str):
     state = {"active": False, "lock": asyncio.Lock()}
 
     def clock_mark() -> None:
+        # the device's account as it stands at the mark: the trace's own
+        # idle time between the two marks can be set beside it by hand
+        booked = obs.device_account().snapshot()
         with obs.host_span(
             "lwc:clock",
             parents=(),
             perf_counter_ns=_time.perf_counter_ns(),
             epoch_ns=_time.time_ns(),
+            **{
+                key: booked[key]
+                for key in ("enqueued_ms", "starved_ms", "idle_ms")
+            },
         ):
             pass
 
@@ -1153,7 +1185,7 @@ def _consensus_handler(
     async def handler(request: web.Request):
         rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            raw = await request.text()
+            raw = await _read_body(request, rid)
             with obs.host_span(
                 "http:parse", rid=rid, bytes=len(raw)
             ) as parsing:
@@ -1230,14 +1262,14 @@ def _consensus_handler(
             ),
         )
 
-    return handler
+    return _arrives(handler)
 
 
 def _embeddings_handler(embedder, metrics=None, batcher=None):
     async def handler(request: web.Request):
         rid = obs.arrive(request.path, request.content_length or 0)
         try:
-            raw = await request.text()
+            raw = await _read_body(request, rid)
             with obs.host_span("http:parse", rid=rid, bytes=len(raw)):
                 params = CreateEmbeddingParams.from_json_obj(
                     jsonutil.loads(raw)
@@ -1293,4 +1325,4 @@ def _embeddings_handler(embedder, metrics=None, batcher=None):
             ),
         )
 
-    return handler
+    return _arrives(handler)

@@ -38,6 +38,7 @@ KNOWN_SECTIONS = (
     "device_watchdog",
     "lifecycle",
     "device_batcher",
+    "startup",
     "embed_cache",
     "score_cache",
     "traces",
@@ -84,6 +85,12 @@ KNOWN_PROM_FAMILIES = (
     "lwc_lane_dispatches",
     "lwc_lane_items",
     "lwc_lane_busy_fraction",
+    "lwc_device_time_ms",
+    "lwc_device_program_ms",
+    "lwc_device_dispatches",
+    "lwc_device_starved_by_ms",
+    "lwc_device_stalls",
+    "lwc_device_starved_interval_ms",
     "lwc_weights_swaps",
     "lwc_weights_shadow",
 )
@@ -228,6 +235,7 @@ def render_prometheus(metrics: Metrics) -> str:
     and per-bucket device-time histograms from the global phase
     aggregator, and the roofline attainment gauges when the roofline
     section is registered.  Ends with the mandatory ``# EOF``."""
+    from ..obs import account as _account
     from ..obs import phases as _phases
 
     lines: List[str] = []
@@ -449,6 +457,68 @@ def render_prometheus(metrics: Metrics) -> str:
                 f'lwc_lane_busy_fraction{{lane="{_esc(lane)}"}} '
                 f"{row.get('busy_fraction', 0.0):.6g}"
             )
+
+    account = batcher.get("account") if isinstance(batcher, dict) else None
+    if isinstance(account, dict):
+        # the device's time as the host sees it (obs/account.py): every
+        # one a total since the process started
+        lines += prom_family(
+            "lwc_device_time_ms",
+            "counter",
+            "Wall time by what the device had: a program enqueued, none "
+            "with a request in the server (starved), none and no request.",
+        )
+        for state in ("enqueued", "starved", "idle"):
+            lines.append(
+                f'lwc_device_time_ms_total{{state="{state}"}} '
+                f"{account.get(state + '_ms', 0.0):.6g}"
+            )
+        lines += prom_family(
+            "lwc_device_program_ms",
+            "counter",
+            "Enqueue to ready, split: a program's own time on the FIFO "
+            "stream (service) and its wait behind the one ahead (waited).",
+        )
+        for part in ("service", "waited"):
+            lines.append(
+                f'lwc_device_program_ms_total{{part="{part}"}} '
+                f"{account.get(part + '_ms', 0.0):.6g}"
+            )
+        lines += prom_family(
+            "lwc_device_dispatches",
+            "counter",
+            "Programs seen ready by the device's account.",
+        )
+        lines.append(
+            f"lwc_device_dispatches_total {account.get('dispatches', 0)}"
+        )
+        lines += prom_family(
+            "lwc_device_starved_by_ms",
+            "counter",
+            "Starved time by the host phase that held the device.",
+        )
+        for key, ms in (account.get("starved_by") or {}).items():
+            lines.append(
+                f'lwc_device_starved_by_ms_total{{phase="{_esc(key)}"}} '
+                f"{ms:.6g}"
+            )
+        lines += prom_family(
+            "lwc_device_stalls",
+            "counter",
+            "Starved intervals of 50 ms and more.",
+        )
+        lines.append(f"lwc_device_stalls_total {account.get('stalls', 0)}")
+        lines += prom_family(
+            "lwc_device_starved_interval_ms",
+            "histogram",
+            "Lengths of the starved intervals, fixed log buckets.",
+        )
+        lines += _render_hist(
+            "lwc_device_starved_interval_ms",
+            "device",
+            "0",
+            _account.device_account().starved_histogram(),
+        )
 
     weights = metrics.provider_section("weights")
     if isinstance(weights, dict):

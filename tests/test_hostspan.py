@@ -6,9 +6,9 @@
 * the sites — through the real gateway and batcher on the tiny config,
   each span of the vocabulary fires once per request or once per group,
   and the ids agree across the four threads a request passes;
-* the capture — ``POST /v1/profile`` on the CPU holds the nine span names
-  with their attributes, both ``lwc:clock`` marks and no Python-tracer
-  frame;
+* the capture — ``POST /v1/profile`` on the CPU holds the ten span names
+  with their attributes, both ``lwc:clock`` marks (the device's account on
+  each) and no Python-tracer frame;
 * the scopes — metadata only: the optimised HLO is the same with and
   without them;
 * ``jit.backend_compiles`` — one more for a fresh shape, flat under
@@ -44,8 +44,8 @@ from fakes import FakeTransport
 
 SEED = 24
 TEXTS = [f"candidate answer number {i} with a few words" for i in range(4)]
-NINE = (
-    "http:arrive", "http:parse", "host:tokenize", "batcher:idle",
+TEN = (
+    "http:arrive", "http:read", "http:parse", "host:tokenize", "batcher:idle",
     "batcher:stage", "device:wait", "host:finalize", "http:respond",
     "lwc:clock",
 )
@@ -277,8 +277,8 @@ def test_each_site_fires_once_per_request_with_one_rid(annotations):
     go(with_client(app, run))
     seen = by_name(annotations)
     for name in (
-        "http:arrive", "http:parse", "host:tokenize", "batcher:stage",
-        "device:wait", "host:finalize", "http:respond",
+        "http:arrive", "http:read", "http:parse", "host:tokenize",
+        "batcher:stage", "device:wait", "host:finalize", "http:respond",
     ):
         assert len(seen[name]) == 2, (name, seen.get(name))
     assert len(seen["batcher:idle"]) >= 1  # between the two requests
@@ -287,6 +287,7 @@ def test_each_site_fires_once_per_request_with_one_rid(annotations):
     for i, rid in enumerate(rids):
         # the same id on the event loop, the tokenizer pool, the dispatch
         # executor (as the group's one rid) and back
+        assert seen["http:read"][i]["attrs"]["rid"] == rid
         assert seen["http:parse"][i]["attrs"]["rid"] == rid
         assert seen["host:tokenize"][i]["attrs"]["rid"] == rid
         assert seen["http:respond"][i]["attrs"]["rid"] == rid
@@ -308,7 +309,10 @@ def test_each_site_fires_once_per_request_with_one_rid(annotations):
     assert threads["host:finalize"].startswith("lwc-waiter")
     assert threads["http:parse"] == threads["http:respond"] == threads["batcher:idle"]
     counts = phase_counts()
-    for phase in ("http_parse", "tokenize", "stage", "finalize", "http_respond"):
+    for phase in (
+        "http_read", "http_parse", "tokenize", "stage", "finalize",
+        "http_respond",
+    ):
         assert counts[phase] == 2, counts
     assert counts["batcher_queue"] == 2 and counts["device_dispatch"] == 2
 
@@ -449,13 +453,24 @@ def test_cpu_profile_holds_the_spans_the_clock_marks_and_no_python_frames(tmp_pa
                 if event.name in hostspan.HOST_SPANS:
                     events.setdefault(event.name, []).append(dict(event.stats))
     assert frames == 0
-    for name in NINE:
+    for name in TEN:
         assert name in events, (name, sorted(events))
     assert len(events["lwc:clock"]) == 2
     for mark in events["lwc:clock"]:
         assert mark["perf_counter_ns"] > 0 and mark["epoch_ns"] > 1e18
-    first, last = sorted(m["perf_counter_ns"] for m in events["lwc:clock"])
-    assert 0.5e9 < last - first < 5e9
+    opened, closed = sorted(
+        events["lwc:clock"], key=lambda m: m["perf_counter_ns"]
+    )
+    span_ms = (closed["perf_counter_ns"] - opened["perf_counter_ns"]) / 1e6
+    assert 0.5e3 < span_ms < 5e3
+    # the account's window between the marks is the trace's: what it
+    # booked there is the marks' distance, less a starved stretch still open
+    booked = sum(
+        float(closed[key]) - float(opened[key])
+        for key in ("enqueued_ms", "starved_ms", "idle_ms")
+    )
+    assert 0.0 < booked <= span_ms + 1.0
+    assert booked > span_ms - 250.0
     assert len(events["http:arrive"]) == 2
     rids = {a["rid"] for a in events["http:arrive"]}
     assert {r["rid"] for r in events["http:respond"]} == rids
@@ -464,6 +479,10 @@ def test_cpu_profile_holds_the_spans_the_clock_marks_and_no_python_frames(tmp_pa
     assert all(s["label"].startswith("vote1(") for s in events["batcher:stage"])
     assert {a["route"] for a in events["http:arrive"]} == {"/consensus"}
     assert all(p["n"] == 4 and p["bytes"] > 0 for p in events["http:parse"])
+    assert {r["rid"] for r in events["http:read"]} == rids
+    assert [r["bytes"] for r in events["http:read"]] == [
+        p["bytes"] for p in events["http:parse"]
+    ]
 
 
 # -- the scopes -----------------------------------------------------------------

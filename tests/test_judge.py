@@ -598,11 +598,16 @@ def test_consensus_judge_through_gateway_and_batcher(judge):
     stats = judge.stats()
     assert stats["calls"] >= 2 + 3 + 3 and stats["prefill_tokens"] > 0
     assert stats["padded_tokens"] > 0 and sum(stats["expert_tokens"]) > 0
-    # every dispatch ran the one bucket: its mean is the bucket's own figure
-    block = attn.block_for(judge.max_tokens)
-    assert stats["attention_work_over_causal"] == pytest.approx(
-        attn.work_over_causal(judge.max_tokens, block, block)
+    # every dispatch ran the one bucket, whose schedule multiplies no less
+    # than the mask keeps: the counters say the one, the function the other
+    # (a number of the bucket; ``/metrics`` holds no mean of it)
+    assert (
+        stats["prefill_tokens"] + stats["padded_tokens"]
+        == stats["calls"] * judge.max_tokens
     )
+    block = attn.block_for(judge.max_tokens)
+    assert attn.work_over_causal(judge.max_tokens, block, block) >= 1.0
+    assert "attention_work_over_causal" not in stats
 
 
 def test_build_judge_gate_and_presets(monkeypatch):

@@ -37,6 +37,7 @@ from llm_weighted_consensus_tpu.clients.chat import (
 )
 from llm_weighted_consensus_tpu.clients.score import ScoreClient
 from llm_weighted_consensus_tpu.obs import TraceSink
+from llm_weighted_consensus_tpu.obs.account import STARVED_KEYS, DeviceAccount
 from llm_weighted_consensus_tpu.obs.histogram import (
     _BOUNDS,
     GROWTH,
@@ -147,8 +148,8 @@ def test_aggregator_snapshot_orders_phases_and_feeds_the_device_table():
     snap = agg.snapshot()
     assert list(snap) == [
         "tokenize", "batcher_queue", "device_dispatch", "upstream_judge",
-        "http_respond", "overlap",
-    ]  # PHASES order, only observed phases
+        "http_respond",
+    ]  # PHASES order, only observed phases: no gauge rides the section
     assert snap["device_dispatch"] == {
         "count": 1, "sum_ms": 60.0,
         "p50_ms": snap["device_dispatch"]["p50_ms"],
@@ -158,8 +159,12 @@ def test_aggregator_snapshot_orders_phases_and_feeds_the_device_table():
     assert dev["vote1(n=8,s=16)"]["count"] == 1
 
 
-def test_aggregator_empty_snapshot_is_the_overlap_gauge_alone():
-    assert PhaseAggregator().snapshot() == {"overlap": None}
+def test_aggregator_empty_snapshot_is_empty_and_so_is_a_fresh_account():
+    assert PhaseAggregator().snapshot() == {}
+    # whether the device was kept fed is the account's to say, in totals
+    snap = DeviceAccount(clock=lambda: 0.0).snapshot()
+    assert snap["wall_ms"] == snap["enqueued_ms"] == snap["dispatches"] == 0
+    assert set(snap["starved_by"]) == set(STARVED_KEYS)
 
 
 def test_interval_union_attributes_concurrent_work_once():
@@ -168,20 +173,31 @@ def test_interval_union_attributes_concurrent_work_once():
     assert _union_ms([]) == 0.0
 
 
-# -- host<->device overlap (ISSUE 13) -----------------------------------------
+# -- host<->device overlap (ISSUE 13), read from the account (ISSUE 37) -------
 
 
-def test_overlap_gauge_from_device_intervals():
-    agg = PhaseAggregator()
-    assert agg.snapshot()["overlap"] is None
-    agg.observe_device_interval(0.0, 1.0)
-    assert agg.snapshot()["overlap"] is None  # one dispatch: undefined
-    agg.observe_device_interval(0.5, 1.5)  # pipelined: tiles the wall
-    assert agg.snapshot()["overlap"] == pytest.approx(1.0)
-    agg.observe_device_interval(2.5, 3.0)  # a host-side gap opens
-    assert agg.snapshot()["overlap"] == pytest.approx(2.0 / 3.0, abs=1e-3)
-    agg.reset()
-    assert agg.snapshot()["overlap"] is None
+def test_account_windows_what_the_overlap_gauge_could_not():
+    """The old gauge's own schedule: two pipelined programs tile the wall,
+    then a host-side gap opens.  The account says the same in totals that
+    two readings window."""
+    acct = DeviceAccount(clock=lambda: 0.0)  # the stamps below are by hand
+    assert acct.snapshot()["enqueued_ms"] == 0
+    a = acct.enqueue(0.0)
+    b = acct.enqueue(0.5)  # pipelined: enqueued before the first is ready
+    acct.ready(a, 1.0)
+    acct.ready(b, 1.5)
+    first = acct.snapshot()
+    # the union, not the sum: 1.5 s of programs in 1.5 s of wall
+    assert first["enqueued_ms"] == pytest.approx(1500.0)
+    assert first["wall_ms"] == pytest.approx(1500.0)
+    c = acct.enqueue(2.5)  # a host-side gap opened
+    acct.ready(c, 3.0)
+    second = acct.snapshot()
+    assert second["enqueued_ms"] - first["enqueued_ms"] == pytest.approx(500.0)
+    assert second["wall_ms"] - first["wall_ms"] == pytest.approx(1500.0)
+    assert second["idle_ms"] == pytest.approx(1000.0)  # no request waited
+    acct.reset()
+    assert acct.snapshot()["dispatches"] == 0
 
 
 def test_staging_pool_reuses_buffers_per_shape():
@@ -304,10 +320,11 @@ class _SlowDeviceEmbedder:
             )
             return out
         if self.device_timing:
+            ticket = obs.device_account().enqueue(t0)
             _fake_wait(out)
             t1 = time.perf_counter()
+            obs.device_account().ready(ticket, t1)
             _ph.observe_device(label, (t1 - t0) * 1e3)
-            _ph.observe_device_interval(t0, t1)
         return out
 
 
@@ -336,15 +353,24 @@ def test_pipelined_dispatches_overlap_with_device_timing_on():
 
     wall = go(run())
     batcher.close()
-    intervals = ph.aggregator().device_intervals()
-    assert len(intervals) == 2
-    # the second dispatch enqueued before the first became ready
-    assert max(s for s, _ in intervals) < min(e for _, e in intervals)
+    account = obs.device_account().snapshot()
+    assert account["dispatches"] == 2
+    # the second dispatch enqueued before the first became ready: the
+    # union of the two is well under their sum, and the second sat
+    # behind the first for most of its sojourn
+    assert account["enqueued_ms"] < 1.5 * T * 1e3
+    assert account["service_ms"] + account["waited_ms"] > 1.8 * T * 1e3
+    assert account["waited_ms"] > 0.5 * T * 1e3
     assert wall < 1.5 * T, wall
     # device time still recorded per (bucket) label, one per group
     dev = ph.aggregator().device_snapshot()
     assert dev["fake(b=1)"]["count"] == 2
-    assert ph.phases_snapshot()["overlap"] >= 0.8
+    # sojourn (the phase) is service + waited (the account), to round-off
+    assert dev["fake(b=1)"]["sum_ms"] == pytest.approx(
+        account["service_ms"] + account["waited_ms"], abs=1.0
+    )
+    # the device had a program for most of the pair's wall time
+    assert account["enqueued_ms"] >= 0.8 * T * 1e3
     obs.reset_phases()
 
 
@@ -367,7 +393,6 @@ def test_waiter_and_bracket_device_times_agree():
     seam.drain_sink(
         sink,
         observe_device=ph.observe_device,
-        observe_interval=ph.observe_device_interval,
     )
     row = ph.aggregator().device_snapshot()["fake(b=1)"]
     assert row["count"] == 2
@@ -406,7 +431,6 @@ def test_real_embedder_waiter_matches_bracket_labels():
     seam.drain_sink(
         sink,
         observe_device=ph.observe_device,
-        observe_interval=ph.observe_device_interval,
     )
     deferred = ph.aggregator().device_snapshot()
     assert set(deferred) == bracket  # same (mesh-shape, bucket) labels

@@ -513,7 +513,10 @@ def test_judge_counts_the_pairs_held_and_the_padding(judge):
     assert stats["expert_pairs_elsewhere"] == 0  # random init holds every expert
     assert len(stats["expert_tokens"]) == C.num_experts
     assert sum(stats["expert_tokens"]) == stats["expert_pairs_here"]
-    assert stats["delta_rule_padding_share"] == pytest.approx(1.0 - tokens / slots)
+    # the recurrence's padding share is the section's own two counters
+    padded = stats["padded_tokens"] - before["padded_tokens"]
+    prefill = stats["prefill_tokens"] - before["prefill_tokens"]
+    assert padded / (prefill + padded) == pytest.approx(1.0 - tokens / slots)
     assert judge.jit_stats()["judge_panel"] >= 1
 
 
@@ -592,7 +595,7 @@ def test_consensus_judge_through_gateway_and_batcher(judge):
         assert metrics["roofline"]["buckets"]["judge(n=2,s=440)"]["count"] == was + 1
         assert metrics["judge"]["dispatches"] == dispatched + 2
         assert metrics["judge"]["model"] == "qwen3-next-test-tiny"
-        for key in ("expert_pairs_here", "expert_pairs_elsewhere", "delta_rule_padding_share"):
+        for key in ("expert_pairs_here", "expert_pairs_elsewhere", "padded_tokens"):
             assert key in metrics["judge"]
 
     go(with_client(app, drive))

@@ -372,16 +372,25 @@ def test_offline_exempt_from_queue_depth_shed(embedder):
 
 
 def test_lane_occupancy_merges_pipelined_intervals(embedder):
+    from llm_weighted_consensus_tpu.obs.account import DeviceAccount
+
     batcher = DeviceBatcher(embedder, None)
+    # the one account the seam feeds, here with stamps made by hand
+    account = batcher._account = DeviceAccount(clock=lambda: 0.0)
     # two overlapping dispatch intervals + one still in flight: honest
     # coverage merges them instead of summing past 100%
-    batcher._lane_busy["offline"].extend([(0.0, 10.0), (5.0, 15.0)])
+    first = account.enqueue(0.0, lane="offline")
+    second = account.enqueue(5.0, lane="offline")
+    account.ready(first, 10.0)
+    account.ready(second, 15.0)
     assert batcher.lane_occupancy("offline", 0.0, until=20.0) == 0.75
-    batcher._inflight["tok"] = (12.0, "offline")
+    inflight = account.enqueue(15.0, lane="offline")
     assert batcher.lane_occupancy("offline", 0.0, until=20.0) == 1.0
     assert batcher.lane_occupancy("latency", 0.0, until=20.0) == 0.0
-    del batcher._inflight["tok"]
+    account.ready(inflight, 15.0)
     assert batcher.lane_occupancy("offline", 16.0, until=16.0) == 0.0
+    # the lanes together are the account's own union, never over 1
+    assert batcher.lane_occupancy(None, 0.0, until=20.0) == 0.75
 
 
 def test_offline_feed_sustains_occupancy_on_idle_mesh(embedder):

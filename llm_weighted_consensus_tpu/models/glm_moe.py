@@ -57,7 +57,19 @@ Rotary dims: the checkpoint stores a head's rotary dims interleaved (pair
 (2i, 2i+1) turns together, as in the DeepSeek-V3 checkpoints).  The loader
 moves them to (i, i + d/2), the same permutation on the query's and the
 key's rows, which leaves every q·k unchanged and lets the rotation be a
-half-swap.
+half-swap.  WHERE the turn happens follows from the array's shape
+(``_turn_heads``).  A prefill's queries [b, s, heads * hd] with heads of
+whole 128-lane columns and the rotary dims inside one of them (both published
+presets: 192 | 64 of 256 lanes; an index head's first 64 of 128) stay as the
+product wrote them and are turned IN PLACE by ``ops/rotary.py``'s kernel,
+which reads and writes only the column that holds the rotary lanes; nothing of
+the queries' size is sliced, padded or concatenated.  Any other shape (the
+tiny presets' 24 | 8, a decode step's [b, width] rows) is cut apart, turned by
+``decoder_parts.rope`` and put together again: the same float32 arithmetic,
+rounded once.  The ONE rotary key (``_latent``, what the cache holds) is narrow
+and always takes ``decoder_parts.rope``; a prefill then writes it into every
+head's rope lanes through the key product itself (``_keys``: ``w_k``'s rope
+lanes are zero, so [c | kr] x [w_k ; I] puts it there exactly), at every shape.
 
 Routed experts run as TWO kernels over the tokens routed to each
 (``ops/grouped_matmul.py``): gate and up as one product with SwiGLU on its
@@ -80,6 +92,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import rotary
 from ..ops.causal_attention import causal_attention_blockwise
 from ..ops.sparse_index import (
     index_scores, index_scores_einsum, index_select, select_topk_dense,
@@ -93,17 +106,29 @@ from .decoder_parts import rope_angles as _rope_angles
 from .decoder_parts import swiglu as _swiglu
 
 
+def _turn_heads(x, cos, sin, heads: int, first: int):
+    """x [..., heads * hd] -> the same, lanes [first, first + dims) of every
+    head turned; cos, sin [..., dims / 2], a position's.  Heads of whole
+    128-lane columns over [b, s, width] are turned where they lie
+    (``ops/rotary.py``); any other shape (a tiny preset, a decode step's rows)
+    is cut apart, turned and put together again."""
+    dims = 2 * cos.shape[-1]
+    if cos.shape[:-1] == x.shape[1:-1] and rotary.fits(x.shape, heads, first, dims):
+        return rotary.turn_lanes(x, cos, sin, heads=heads, first=first)
+    xh = x.reshape(*x.shape[:-1], heads, -1)
+    turned = _rope(xh[..., first:first + dims], cos[..., None, :], sin[..., None, :])
+    return jnp.concatenate(
+        [xh[..., :first], turned, xh[..., first + dims:]], axis=-1
+    ).reshape(x.shape)
+
+
 def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
-    """h [..., hidden] -> (q [..., heads, nope + rope], the rope dims turned,
-    the normalised query latent [..., q_lora_rank] an indexer reads too);
-    cos, sin broadcast against [..., heads, rope / 2]."""
-    nope = config.qk_nope_head_dim
+    """h [..., hidden] -> (q [..., heads * (nope + rope)], the rope dims
+    turned, the normalised query latent [..., q_lora_rank] an indexer reads
+    too); cos, sin [..., rope / 2], a position's."""
     cq = _rms(_dense(h, p["q_a"]), p["q_a_norm"], config.rms_norm_eps)
     q = _dense(cq, p["q_b"])
-    q = q.reshape(*q.shape[:-1], config.num_heads, config.qk_head_dim)
-    return jnp.concatenate(
-        [q[..., :nope], _rope(q[..., nope:], cos, sin)], axis=-1
-    ), cq
+    return _turn_heads(q, cos, sin, config.num_heads, config.qk_nope_head_dim), cq
 
 
 def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
@@ -115,11 +140,20 @@ def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
     return c, _rope(kv[..., rank:], cos, sin)
 
 
-def _turn_head(x, cos, sin, config: GlmMoeLiteConfig):
-    """An index head's first ``qk_rope_head_dim`` dims turned, the rest as
-    they are: x [..., index_head_dim]."""
-    rope = config.qk_rope_head_dim
-    return jnp.concatenate([_rope(x[..., :rope], cos, sin), x[..., rope:]], axis=-1)
+def _keys(c, kr, w_k):
+    """The latent c [b, s, rank] and the rotary key kr [b, s, rope] -> k
+    [b, s, heads * (nope + rope)]: W_kvb's key half applied, the one rotary
+    key in every head's rope lanes.  ``w_k``'s rope lanes are zero, so the
+    rotary key rides the same product through an identity into them,
+    [c | kr] x [w_k ; I]: nothing of the keys' size is passed over again."""
+    rank, heads, dq = w_k.shape
+    rope = kr.shape[-1]
+    into = jnp.eye(rope, dq, k=dq - rope, dtype=w_k.dtype)
+    w = jnp.concatenate([w_k, jnp.broadcast_to(into[:, None, :], (rope, heads, dq))])
+    return jnp.einsum(
+        "bsc,cn->bsn", jnp.concatenate([c, kr], axis=-1),
+        w.reshape(rank + rope, heads * dq), preferred_element_type=jnp.float32,
+    ).astype(c.dtype)
 
 
 def _index_terms(h, cq, p: dict, cos, sin, config: GlmMoeLiteConfig):
@@ -129,10 +163,8 @@ def _index_terms(h, cq, p: dict, cos, sin, config: GlmMoeLiteConfig):
     cos, sin [..., rope / 2], a position's."""
     heads, dim = config.index_n_heads, config.index_head_dim
     with jax.named_scope("index_q"):
-        q = _dense(cq, p["q"])
-        q = _turn_head(
-            q.reshape(*q.shape[:-1], heads, dim), cos[..., None, :], sin[..., None, :], config
-        ).reshape(q.shape)
+        # an index head's first ``qk_rope_head_dim`` dims turn, the rest stay
+        q = _turn_heads(_dense(cq, p["q"]), cos, sin, heads, 0)
         w = jnp.einsum(
             "...i,io->...o", h, p["w"], preferred_element_type=jnp.float32
         ) * (heads**-0.5 * dim**-0.5)
@@ -141,7 +173,7 @@ def _index_terms(h, cq, p: dict, cos, sin, config: GlmMoeLiteConfig):
         k = k - jnp.mean(k, axis=-1, keepdims=True)
         k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + config.index_norm_eps)
         k = (k * p["k_norm"].astype(jnp.float32) + p["k_bias"].astype(jnp.float32)).astype(h.dtype)
-        k = _turn_head(k, cos, sin, config)
+        k = _turn_heads(k, cos, sin, 1, 0)
     return q, k, w
 
 
@@ -151,20 +183,14 @@ def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig, keep=None):
     each query's keys (``keep`` [b, s, s] int8, ``ops/sparse_index.py``) and
     caches its index keys beside (c, kr); a layer without one attends over
     the ``keep`` it is handed, the last chosen; None is every causal key."""
-    b, s, _ = h.shape
+    s = h.shape[1]
     heads, dq = config.num_heads, config.qk_head_dim
     cos, sin = _rope_angles(jnp.arange(s), config.qk_rope_head_dim, config.rope_theta)
     with jax.named_scope("latent_q"):
-        q, cq = _queries(h, p, cos[:, None, :], sin[:, None, :], config)
-        q = q.reshape(b, s, heads * dq)
+        q, cq = _queries(h, p, cos, sin, config)
     with jax.named_scope("latent_kv"):
         c, kr = _latent(h, p, cos, sin, config)
-        # w_k's rope lanes are zero: the one rotary key is added into them
-        k = jnp.einsum(
-            "bsc,chd->bshd", c, p["w_k"], preferred_element_type=jnp.float32
-        ).astype(h.dtype)
-        k = k + jnp.pad(kr, ((0, 0), (0, 0), (config.qk_nope_head_dim, 0)))[:, :, None, :]
-        k = k.reshape(b, s, heads * dq)
+        k = _keys(c, kr, p["w_k"])
         v = jnp.einsum(
             "bsc,cv->bsv", c, p["w_v"], preferred_element_type=jnp.float32
         ).astype(h.dtype)
@@ -197,7 +223,8 @@ def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig, chosen=
     heads, dq, nope = config.num_heads, config.qk_head_dim, config.qk_nope_head_dim
     cos, sin = _rope_angles(lens, config.qk_rope_head_dim, config.rope_theta)
     with jax.named_scope("latent_q"):
-        q, cq = _queries(h, p, cos[:, None, :], sin[:, None, :], config)  # [b, heads, dq]
+        q, cq = _queries(h, p, cos, sin, config)
+        q = q.reshape(b, heads, dq)
         # W_kvb's key half folded into the query: scores against the latent
         q_lat = jnp.einsum(
             "bhd,chd->bhc", q, p["w_k"], preferred_element_type=jnp.float32

@@ -374,3 +374,44 @@ def test_a_sixteenth_s_experts_compile_at_6144_wide(one_chip, tokens, rows):
     names = [name for name, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
     assert sum(name.startswith("grouped_expert_product") for name in names) == 2, names
     assert any(name.startswith("held_rows_sum") for name in names), names
+
+
+# -- the latent-attention judges' rotary turn where the heads lie (ISSUE 38) --
+
+
+@pytest.mark.parametrize(
+    "heads,latent", [(20, 768), (64, 2048)], ids=["first-judge", "third-judge"]
+)
+def test_the_rotary_turn_compiles_in_place_behind_the_query_product(
+    one_chip, monkeypatch, heads, latent
+):
+    """``glm_moe._queries``' second half at a panel's shape: the query product
+    [3 x 8192, heads x 256] and the turn of each head's lanes 192-255.  Mosaic
+    takes the block (2048 rows of one 128-lane column, aliased in to out), the
+    kernel keeps its name, and beside the product and the kernel nothing of
+    the queries' size is made: no copy, no slice, no pad, no second fusion."""
+    from llm_weighted_consensus_tpu.models import glm_moe
+    from llm_weighted_consensus_tpu.ops import rotary
+
+    monkeypatch.setattr(rotary, "_interpret", lambda: False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def turned(cq, w, cos, sin):
+        q = jnp.einsum("bsi,io->bso", cq, w, preferred_element_type=jnp.float32)
+        return glm_moe._turn_heads(q.astype(cq.dtype), cos, sin, heads, 192)
+
+    angles = arg((8192, 32), jnp.float32)
+    compiled = jax.jit(turned).lower(
+        arg((3, 8192, latent), jnp.bfloat16), arg((latent, heads * 256), jnp.bfloat16),
+        angles, angles,
+    ).compile()
+    text = compiled.as_text()
+    calls = [n for n, op, _ in instructions(text) if op == "custom-call"]
+    assert len([n for n in calls if n.startswith("turn_lanes")]) == 1, calls
+    # what writes an array of the queries' size, the product's own steps left out
+    wide = re.findall(rf"= bf16\[3,8192,{heads * 256}\]\S* ([\w\-]+)\(", text)
+    wide = sorted(op for op in wide if op not in ("convolution", "convert", "parameter"))
+    assert wide == ["custom-call", "fusion"], wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20  # the two tables

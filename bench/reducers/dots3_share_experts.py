@@ -1,0 +1,8 @@
+"""forward.share.experts.dots3: per cent of the judge programs' device time under
+the ``experts`` scopes (``dots3_scopes.GROUPS``)."""
+
+import dots3_scopes
+
+
+def reduce(ctx):
+    return dots3_scopes.share(ctx, "experts")

@@ -1,0 +1,8 @@
+"""forward.share.projections.dots3: per cent of the judge programs' device time under
+the ``projections`` scopes (``dots3_scopes.GROUPS``)."""
+
+import dots3_scopes
+
+
+def reduce(ctx):
+    return dots3_scopes.share(ctx, "projections")

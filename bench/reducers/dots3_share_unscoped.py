@@ -1,0 +1,8 @@
+"""forward.share.unscoped.dots3: per cent of the judge programs' device time under
+the ``unscoped`` scopes (``dots3_scopes.GROUPS``)."""
+
+import dots3_scopes
+
+
+def reduce(ctx):
+    return dots3_scopes.share(ctx, "unscoped")

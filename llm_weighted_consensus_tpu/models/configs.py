@@ -225,7 +225,19 @@ class GlmMoeLiteConfig:
     selection in front of the attention (the DeepSeek-V3.2 indexer).  A layer
     whose ``indexer_types`` entry is ``full`` owns an indexer and chooses each
     query's ``index_topk`` keys; a ``shared`` layer attends over the choice
-    of the last ``full`` layer before it."""
+    of the last ``full`` layer before it.
+
+    ``layer_types`` non-empty is ``model_type`` ``dots3_note``: attention of
+    TWO geometries in one stack.  A ``full_attention`` layer takes the fields
+    above and below as they stand and owns an indexer (``index_topk`` > 0); a
+    ``sliding_attention`` layer takes the ``swa_*`` ones, attends the
+    ``sliding_window`` keys up to and with its own position, and has neither
+    indexer nor selection.  ``attention_gate`` puts a sigmoid scalar a head on
+    the attention's output (the headwise gate, from the layer's normed input);
+    ``lora_rescale`` scales the normalised query and key-value latents by
+    sqrt(hidden / rank) (``apply_mla_qkv_lora_rescale``).  A layer reads all
+    of it as ONE record, ``geometry(layer)``; a decoder of one kind is the
+    case where every layer's record is the same."""
 
     vocab_size: int = 154880
     hidden_size: int = 2048
@@ -256,10 +268,81 @@ class GlmMoeLiteConfig:
     index_topk: int = 0
     indexer_types: tuple = ()  # "full" | "shared" a layer
     index_norm_eps: float = 1e-6  # the LayerNorm over an index key
+    # two kinds of attention layer: "full_attention" | "sliding_attention" a
+    # layer (empty: every layer full), a sliding layer's own geometry, the
+    # window's keys (a query's own position among them)
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    attention_gate: bool = False  # sigmoid(W_g h) a head on the attention's output
+    lora_rescale: bool = False  # latents times sqrt(hidden / rank)
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def slides(self, layer: int) -> bool:
+        return bool(self.layer_types) and self.layer_types[layer] == "sliding_attention"
+
+    def geometry(self, layer: int) -> "AttentionGeometry":
+        """What layer ``layer``'s attention is made of, by its kind."""
+        swa = "swa_" if self.slides(layer) else ""
+        rank_q = getattr(self, swa + "q_lora_rank")
+        rank_kv = getattr(self, swa + "kv_lora_rank")
+        rescale = self.lora_rescale
+        return AttentionGeometry(
+            heads=getattr(self, swa + "num_heads"),
+            q_lora_rank=rank_q,
+            kv_lora_rank=rank_kv,
+            nope=getattr(self, swa + "qk_nope_head_dim"),
+            rope=getattr(self, swa + "qk_rope_head_dim"),
+            v=getattr(self, swa + "v_head_dim"),
+            theta=getattr(self, swa + "rope_theta"),
+            window=self.sliding_window if swa else 0,
+            gate=self.attention_gate,
+            q_scale=(self.hidden_size / rank_q) ** 0.5 if rescale else 1.0,
+            kv_scale=(self.hidden_size / rank_kv) ** 0.5 if rescale else 1.0,
+        )
+
+
+@dataclass(frozen=True)
+class AttentionGeometry:
+    """One attention layer's shapes (``GlmMoeLiteConfig.geometry``): a head is
+    ``nope`` | ``rope`` dims wide against the keys and ``v`` against the
+    values; ``window`` > 0 keys a query (its own among them), 0 every key
+    before it; ``q_scale`` and ``kv_scale`` multiply the normalised latents."""
+
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    window: int
+    gate: bool
+    q_scale: float
+    kv_scale: float
+
+    @property
+    def head_dim(self) -> int:
+        """The published width of a head against the keys."""
+        return self.nope + self.rope
+
+    @property
+    def laid(self) -> int:
+        """Lanes a head takes in the served arrays: a head wider than one
+        128-lane column is laid in whole columns (192 -> 256, zero lanes
+        between the nope and the rope dims, so that the rotary lanes stay the
+        head's last and inside one column); q.k is what it was."""
+        dq = self.head_dim
+        return dq if dq <= 128 else -(-dq // 128) * 128
 
 
 # zai-org/GLM-4.7-Flash config.json
@@ -323,6 +406,81 @@ GLM_DSA_TEST_TINY = GlmMoeLiteConfig(
     index_head_dim=16,
     index_topk=32,
     indexer_types=("full", "shared", "shared", "shared", "full"),
+)
+
+
+# dots-studio/dots3-note-prev config.json (``dots3_note``): one leading dense
+# layer; full attention (an indexer each) on layers 0, 1 and every fourth
+# from 5, sliding attention of its own geometry on the others
+DOTS3_NOTE_PREV = GlmMoeLiteConfig(
+    vocab_size=152064,
+    hidden_size=5120,
+    num_layers=46,
+    num_heads=128,
+    q_lora_rank=1024,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    intermediate_size=13824,
+    moe_intermediate_size=1536,
+    n_routed_experts=256,
+    num_experts_per_tok=8,
+    routed_scaling_factor=1.0,
+    first_k_dense_replace=1,
+    rope_theta=8e7,
+    index_n_heads=64,
+    index_head_dim=128,
+    index_topk=2048,
+    layer_types=tuple(
+        "full_attention" if i == 0 or i % 4 == 1 else "sliding_attention" for i in range(46)
+    ),
+    sliding_window=513,
+    swa_num_heads=64,
+    swa_q_lora_rank=1024,
+    swa_kv_lora_rank=1024,
+    swa_qk_nope_head_dim=192,
+    swa_qk_rope_head_dim=64,
+    swa_v_head_dim=128,
+    swa_rope_theta=5e4,
+    attention_gate=True,
+    lora_rescale=True,
+)
+# the cut's order (full dense, full sparse, three sliding sparse); a window
+# far shorter than the tests' sequences, value heads narrower than key heads
+DOTS3_TEST_TINY = GlmMoeLiteConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=5,
+    num_heads=4,
+    q_lora_rank=32,
+    kv_lora_rank=16,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    intermediate_size=128,
+    moe_intermediate_size=48,
+    n_routed_experts=16,
+    num_experts_per_tok=2,
+    routed_scaling_factor=1.0,
+    rope_theta=8e7,
+    index_n_heads=4,
+    index_head_dim=16,
+    index_topk=32,
+    layer_types=(
+        "full_attention", "full_attention", "sliding_attention", "sliding_attention",
+        "sliding_attention",
+    ),
+    sliding_window=17,
+    swa_num_heads=2,
+    swa_q_lora_rank=32,
+    swa_kv_lora_rank=32,
+    swa_qk_nope_head_dim=24,
+    swa_qk_rope_head_dim=8,
+    swa_v_head_dim=16,
+    swa_rope_theta=5e4,
+    attention_gate=True,
+    lora_rescale=True,
 )
 
 

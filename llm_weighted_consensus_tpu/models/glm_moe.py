@@ -1,8 +1,9 @@
 """A causal decoder with latent attention and sparse experts: the judge.
 
 ``model_type`` ``glm4_moe_lite`` (zai-org/GLM-4.7-Flash), the DeepSeek-V3
-layout, and ``glm_moe_dsa`` (zai-org/GLM-5.2), the same layers behind a
-learned sparse selection; written from their configurations:
+layout, ``glm_moe_dsa`` (zai-org/GLM-5.2), the same layers behind a learned
+sparse selection, and ``dots3_note`` (dots-studio/dots3-note-prev), whose
+attention layers are of TWO kinds; written from their configurations:
 
   x0      = embed[ids]
   per layer:
@@ -41,6 +42,42 @@ attention kernel reads as a tile beside q, k and v.  The cache then has three
 kinds: the latent, the rotary key and, on a ``full`` layer, the index keys,
 from which the decoded token chooses among the positions it sees.
 
+Two kinds of attention layer in one stack (``layer_types`` non-empty).  Every
+attention layer reads ONE small record, ``config.geometry(layer)``
+(``configs.AttentionGeometry``): heads, the two latent ranks, nope | rope |
+value dims, the rotary base, a window, a gate, the latents' scales.  A
+decoder of one kind is the case where every layer's record is the same; the
+loops, the kernels' calls and the loader have no other path.
+
+    kind of layer i  = layer_types[i]          full_attention | sliding_attention
+    full:    the plain fields; owns an indexer (EVERY full layer: none shares)
+    sliding: the ``swa_*`` fields; query t attends t - window < s <= t
+             (``sliding_window`` keys, its own among them); no indexer, and
+             whatever an earlier layer chose is nothing to it
+    cq    = a_q  · rms(W_qa · h)               a_q  = sqrt(hidden / q_lora_rank)
+    c     = a_kv · rms(c)                      a_kv = sqrt(hidden / kv_lora_rank)
+            (``lora_rescale``; folded into the norm's weight in float32, so the
+            latent is rounded once; the rotary key is never rescaled)
+    g     = sigmoid(W_g · h)   [heads]         ``attention_gate``: a scalar a head
+    x     = x + W_o · concat_j(g_j · a_j)      (scope ``attn_gate``)
+
+A value head may be narrower than a key head (128 against 192 or 256): the
+attention kernels take the two widths apart.  A key head wider than one
+128-lane column that is not whole columns (128 | 64 = 192) is LAID in whole
+columns by the loader (``AttentionGeometry.laid``: zero rows 128..191 of the
+head in ``q_b``, zero lanes in ``w_k``, the rotary lanes last and inside one
+column, so ``ops/rotary.py`` turns them in place); every q·k is what it was and
+the softmax scale stays 1 / sqrt(192).  A sliding layer's prefill runs
+``window_attention_blockwise`` (the blockwise kernel's body over the band's
+steps, scope ``window_attention``) and leaves a WINDOWED cache: the (c, kr) of
+the ``window - 1`` positions before a call's length, all the next token can
+see.  So the cache has four kinds in one list (a full layer's latent, rotary
+key and index keys over every position; a sliding layer's latent, of its own
+rank, and rotary key over the window), and a decode step is absorbed
+attention over the token's own top ``index_topk`` on the full layers and over
+the window on the sliding ones.  ``prefill`` counts ``window_keys`` (the pairs
+inside the bands, the causal pairs) beside ``index_keys``.
+
 A sparse layer may hold a SHARE of its router's experts (the checkpoint names
 experts 0..E-1 of ``n_routed_experts``): ``experts_grouped(..., held=E)``
 returns this chip's partial sum.
@@ -59,8 +96,9 @@ moves them to (i, i + d/2), the same permutation on the query's and the
 key's rows, which leaves every q·k unchanged and lets the rotation be a
 half-swap.  WHERE the turn happens follows from the array's shape
 (``_turn_heads``).  A prefill's queries [b, s, heads * hd] with heads of
-whole 128-lane columns and the rotary dims inside one of them (both published
-presets: 192 | 64 of 256 lanes; an index head's first 64 of 128) stay as the
+whole 128-lane columns and the rotary dims inside one of them (the published
+presets: 192 | 64 of 256 lanes, or 128 | 64 laid in 256; an index head's first
+64 of 128) stay as the
 product wrote them and are turned IN PLACE by ``ops/rotary.py``'s kernel,
 which reads and writes only the column that holds the rotary lanes; nothing of
 the queries' size is sliced, padded or concatenated.  Any other shape (the
@@ -93,11 +131,13 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import rotary
-from ..ops.causal_attention import causal_attention_blockwise
+from ..ops.causal_attention import (
+    band_pairs, causal_attention_blockwise, window_attention_blockwise,
+)
 from ..ops.sparse_index import (
     index_scores, index_scores_einsum, index_select, select_topk_dense,
 )
-from .configs import GlmMoeLiteConfig
+from .configs import AttentionGeometry, GlmMoeLiteConfig
 from .decoder_parts import dense as _dense
 from .decoder_parts import experts_grouped, layers_past_usual, quantize_dense  # noqa: F401
 from .decoder_parts import rms as _rms
@@ -122,22 +162,45 @@ def _turn_heads(x, cos, sin, heads: int, first: int):
     ).reshape(x.shape)
 
 
-def _queries(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
-    """h [..., hidden] -> (q [..., heads * (nope + rope)], the rope dims
-    turned, the normalised query latent [..., q_lora_rank] an indexer reads
-    too); cos, sin [..., rope / 2], a position's."""
-    cq = _rms(_dense(h, p["q_a"]), p["q_a_norm"], config.rms_norm_eps)
+def _scaled(weight, scale: float):
+    """A norm's weight with a latent's rescale folded in (float32, so the
+    normalised latent is still rounded once); the weight itself at 1."""
+    return weight if scale == 1.0 else weight.astype(jnp.float32) * scale
+
+
+def _queries(h, p: dict, cos, sin, geo: AttentionGeometry, eps: float):
+    """h [..., hidden] -> (q [..., heads * laid], a head's last rope lanes
+    turned, the normalised (and rescaled) query latent [..., q_lora_rank] an
+    indexer reads too); cos, sin [..., rope / 2], a position's."""
+    cq = _rms(_dense(h, p["q_a"]), _scaled(p["q_a_norm"], geo.q_scale), eps)
     q = _dense(cq, p["q_b"])
-    return _turn_heads(q, cos, sin, config.num_heads, config.qk_nope_head_dim), cq
+    return _turn_heads(q, cos, sin, geo.heads, geo.laid - geo.rope), cq
 
 
-def _latent(h, p: dict, cos, sin, config: GlmMoeLiteConfig):
-    """h [..., hidden] -> (c [..., kv_lora_rank] normalised, rotary key
-    [..., rope] turned): what the cache holds."""
-    rank = config.kv_lora_rank
+def _latent(h, p: dict, cos, sin, geo: AttentionGeometry, eps: float):
+    """h [..., hidden] -> (c [..., kv_lora_rank] normalised (and rescaled),
+    rotary key [..., rope] turned, never rescaled): what the cache holds."""
+    rank = geo.kv_lora_rank
     kv = _dense(h, p["kv_a"])
-    c = _rms(kv[..., :rank], p["kv_a_norm"], config.rms_norm_eps)
+    c = _rms(kv[..., :rank], _scaled(p["kv_a_norm"], geo.kv_scale), eps)
     return c, _rope(kv[..., rank:], cos, sin)
+
+
+def _gated(ctx, h, p: dict, heads: int):
+    """The headwise gate: ctx [..., heads * dv] times sigmoid(W_g h) a head; a
+    layer without a gate hands ctx back.  A head's scalar is spread over its
+    dv lanes by a product with a 0/1 matrix [heads, heads * dv] (exact: one
+    term a lane), so the multiply is that product's epilogue over the flat
+    context: cutting the context into [.., heads, dv] to broadcast the gate
+    made XLA lay out the broadcast and its reshape whole, 3.2 GB of float32 a
+    full layer (the chip's compiler in the sandbox, PR 39)."""
+    if "gate" not in p:
+        return ctx
+    with jax.named_scope("attn_gate"):
+        g = jax.nn.sigmoid(_dense(h, p["gate"]).astype(jnp.float32)).astype(ctx.dtype)
+        spread = jnp.repeat(jnp.eye(heads, dtype=ctx.dtype), ctx.shape[-1] // heads, axis=1)
+        wide = jnp.einsum("...h,hn->...n", g, spread, preferred_element_type=jnp.float32)
+        return (ctx.astype(jnp.float32) * wide).astype(ctx.dtype)
 
 
 def _keys(c, kr, w_k):
@@ -177,71 +240,102 @@ def _index_terms(h, cq, p: dict, cos, sin, config: GlmMoeLiteConfig):
     return q, k, w
 
 
-def _attention_prefill(h, p: dict, config: GlmMoeLiteConfig, keep=None):
+def _attention_prefill(
+    h, p: dict, config: GlmMoeLiteConfig, keep=None, geo: AttentionGeometry | None = None,
+    lens=None,
+):
     """h [b, s, hidden] -> (attention output [b, s, hidden], the layer's
     cache, the selection it attended over).  A layer with an indexer chooses
     each query's keys (``keep`` [b, s, s] int8, ``ops/sparse_index.py``) and
     caches its index keys beside (c, kr); a layer without one attends over
-    the ``keep`` it is handed, the last chosen; None is every causal key."""
+    the ``keep`` it is handed, the last chosen; None is every causal key.  A
+    layer with a WINDOW attends its band whatever it is handed, and caches
+    the (c, kr) of the ``window - 1`` positions before ``lens`` only (what the
+    next token can see; slots before position 0 are padding).  ``geo`` is the
+    layer's geometry where layers are of two kinds; a decoder of one kind has
+    one, and its call names none."""
     s = h.shape[1]
-    heads, dq = config.num_heads, config.qk_head_dim
-    cos, sin = _rope_angles(jnp.arange(s), config.qk_rope_head_dim, config.rope_theta)
+    geo = geo or config.geometry(0)
+    heads, eps = geo.heads, config.rms_norm_eps
+    cos, sin = _rope_angles(jnp.arange(s), geo.rope, geo.theta)
     with jax.named_scope("latent_q"):
-        q, cq = _queries(h, p, cos, sin, config)
+        q, cq = _queries(h, p, cos, sin, geo, eps)
     with jax.named_scope("latent_kv"):
-        c, kr = _latent(h, p, cos, sin, config)
+        c, kr = _latent(h, p, cos, sin, geo, eps)
         k = _keys(c, kr, p["w_k"])
         v = jnp.einsum(
             "bsc,cv->bsv", c, p["w_v"], preferred_element_type=jnp.float32
         ).astype(h.dtype)
     cache = (c, kr)
-    if "indexer" in p:
-        q_i, k_i, w = _index_terms(h, cq, p["indexer"], cos, sin, config)
-        with jax.named_scope("index_scores"):
-            scores = index_scores(q_i, k_i, w, heads=config.index_n_heads)
-        with jax.named_scope("index_select"):
-            keep = index_select(scores, k=config.index_topk)
-        cache = (c, kr, k_i)
-    with jax.named_scope("causal_attention" if keep is None else "selected_attention"):
-        ctx = causal_attention_blockwise(
-            q, k, v, keep, heads=heads, scale=1.0 / math.sqrt(dq)
-        )
+    scale = 1.0 / math.sqrt(geo.head_dim)
+    if geo.window:
+        with jax.named_scope("window_attention"):
+            ctx = window_attention_blockwise(
+                q, k, v, heads=heads, scale=scale, window=geo.window
+            )
+        with jax.named_scope("latent_kv"):
+            back = geo.window - 1
+            ends = jnp.full((h.shape[0],), s, jnp.int32) if lens is None else lens
+            at = jnp.maximum(ends[:, None] - back + jnp.arange(back), 0)[..., None]
+            cache = tuple(jnp.take_along_axis(x, at, axis=1) for x in cache)
+    else:
+        if "indexer" in p:
+            q_i, k_i, w = _index_terms(h, cq, p["indexer"], cos, sin, config)
+            with jax.named_scope("index_scores"):
+                scores = index_scores(q_i, k_i, w, heads=config.index_n_heads)
+            with jax.named_scope("index_select"):
+                keep = index_select(scores, k=config.index_topk)
+            cache = (c, kr, k_i)
+        with jax.named_scope("causal_attention" if keep is None else "selected_attention"):
+            ctx = causal_attention_blockwise(q, k, v, keep, heads=heads, scale=scale)
+    ctx = _gated(ctx, h, p, heads)
     with jax.named_scope("attn_out"):
         out = _dense(ctx, p["o"])
     return out, cache, keep
 
 
-def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig, chosen=None):
+def _attention_decode(
+    h, p: dict, lens, cache, config: GlmMoeLiteConfig, chosen=None,
+    geo: AttentionGeometry | None = None,
+):
     """One token a call through the latent cache, the absorbed path.
     h [b, hidden] at position ``lens[b]``; cache (c [b, s, rank], kr
     [b, s, rope]) holds positions < lens[b] (later slots are padding), and
     on a layer with an indexer its index keys [b, s, dim] too: there the
     token chooses ``index_topk`` of the positions it sees, and ``chosen``
-    [b, s + 1] bool goes on to the layers without one.  Returns (output,
-    chosen)."""
+    [b, s + 1] bool goes on to the layers without one.  A layer with a WINDOW
+    holds the ``window - 1`` positions before ``lens[b]`` (slot j is position
+    ``lens[b] - (window - 1) + j``; those before 0 are padding) and attends
+    them and itself, whatever was chosen elsewhere.  ``geo`` as in
+    ``_attention_prefill``.  Returns (output, chosen)."""
     b = h.shape[0]
-    heads, dq, nope = config.num_heads, config.qk_head_dim, config.qk_nope_head_dim
-    cos, sin = _rope_angles(lens, config.qk_rope_head_dim, config.rope_theta)
+    geo = geo or config.geometry(0)
+    heads, eps = geo.heads, config.rms_norm_eps
+    cos, sin = _rope_angles(lens, geo.rope, geo.theta)
     with jax.named_scope("latent_q"):
-        q, cq = _queries(h, p, cos, sin, config)
-        q = q.reshape(b, heads, dq)
+        q, cq = _queries(h, p, cos, sin, geo, eps)
+        q = q.reshape(b, heads, geo.laid)
         # W_kvb's key half folded into the query: scores against the latent
         q_lat = jnp.einsum(
             "bhd,chd->bhc", q, p["w_k"], preferred_element_type=jnp.float32
         ).astype(h.dtype)
     with jax.named_scope("latent_kv"):
-        c_new, kr_new = _latent(h, p, cos, sin, config)
+        c_new, kr_new = _latent(h, p, cos, sin, geo, eps)
         c_all = jnp.concatenate([cache[0], c_new[:, None, :]], axis=1)
         kr_all = jnp.concatenate([cache[1], kr_new[:, None, :]], axis=1)
-    with jax.named_scope("causal_attention"):
+    with jax.named_scope("window_attention" if geo.window else "causal_attention"):
         scores = jnp.einsum(
             "bhc,btc->bht", q_lat, c_all, preferred_element_type=jnp.float32
         ) + jnp.einsum(
-            "bhr,btr->bht", q[..., nope:], kr_all, preferred_element_type=jnp.float32
+            "bhr,btr->bht", q[..., geo.laid - geo.rope:], kr_all,
+            preferred_element_type=jnp.float32,
         )
         slots = c_all.shape[1]
         t = jnp.arange(slots)[None, :]
-        seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
+        if geo.window:  # the slots at or past position 0, and itself
+            seen = t >= slots - 1 - lens[:, None]
+        else:
+            seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
         if "indexer" in p:
             q_i, k_i, w = _index_terms(h, cq, p["indexer"], cos, sin, config)
             with jax.named_scope("index_scores"):
@@ -251,20 +345,21 @@ def _attention_decode(h, p: dict, lens, cache, config: GlmMoeLiteConfig, chosen=
                 )[:, 0]
             with jax.named_scope("index_select"):
                 chosen = select_topk_dense(index, seen, config.index_topk)
-        if chosen is not None:
+        if chosen is not None and not geo.window:
             seen = chosen
-        scores = jnp.where(seen[:, None, :], scores / math.sqrt(dq), -1e30)
+        scores = jnp.where(seen[:, None, :], scores / math.sqrt(geo.head_dim), -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
         o_lat = jnp.einsum(
             "bht,btc->bhc", probs, c_all, preferred_element_type=jnp.float32
         ).astype(h.dtype)
         # ... and its value half folded into the output
-        w_v = p["w_v"].reshape(config.kv_lora_rank, heads, config.v_head_dim)
+        w_v = p["w_v"].reshape(geo.kv_lora_rank, heads, geo.v)
         ctx = jnp.einsum(
             "bhc,chv->bhv", o_lat, w_v, preferred_element_type=jnp.float32
         ).astype(h.dtype)
+    ctx = _gated(ctx.reshape(b, heads * geo.v), h, p, heads)
     with jax.named_scope("attn_out"):
-        return _dense(ctx.reshape(b, heads * config.v_head_dim), p["o"]), chosen
+        return _dense(ctx, p["o"]), chosen
 
 
 def route(h, p: dict, config: GlmMoeLiteConfig):
@@ -316,14 +411,22 @@ def prefill(params: dict, ids, config: GlmMoeLiteConfig, lens=None, tallies=None
     never sees the slots past a call's length, so it is not read here.
     Where the decoder has an indexer, a ``tallies`` dict handed in receives
     ``index_keys`` [2] int32: the (query, key) pairs its layers with an
-    indexer chose, and the causal pairs they chose from, over every slot."""
+    indexer chose, and the causal pairs they chose from, over every slot.
+    Where it has layers with a window, ``window_keys`` [2]: the pairs inside
+    their bands, and the causal pairs those were taken from; such a layer's
+    cache is what the token at ``lens`` can see (``_attention_prefill``)."""
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["token_embed"], ids, axis=0)
     caches, loads = [], []
-    keep, picked, owners = None, jnp.int32(0), 0
-    for layer in params["layers"]:
+    keep, picked, owners, windows = None, jnp.int32(0), 0, []
+    for i, layer in enumerate(params["layers"]):
         h = _rms(x, layer["input_norm"], config.rms_norm_eps)
-        out, cache, keep = _attention_prefill(h, layer["attn"], config, keep)
+        geo = config.geometry(i)
+        windows += [geo.window] if geo.window else []
+        # a decoder of one kind names no geometry and has no cache that ends
+        # at ``lens``: its call is as it was
+        kind = {"geo": geo, "lens": lens} if config.layer_types else {}
+        out, cache, keep = _attention_prefill(h, layer["attn"], config, keep, **kind)
         if "indexer" in layer["attn"]:
             with jax.named_scope("index_select"):
                 picked = picked + jnp.sum(keep, dtype=jnp.int32)
@@ -334,9 +437,14 @@ def prefill(params: dict, ids, config: GlmMoeLiteConfig, lens=None, tallies=None
         x = x + out
         if counts is not None:
             loads.append(counts)
+    b, s = ids.shape
     if owners and tallies is not None:
-        b, s = ids.shape
         tallies["index_keys"] = jnp.stack([picked, jnp.int32(owners * b * (s * (s + 1) // 2))])
+    if windows and tallies is not None:
+        banded = sum(band_pairs(s, window) for window in windows)
+        tallies["window_keys"] = jnp.asarray(
+            [b * banded, len(windows) * b * (s * (s + 1) // 2)], jnp.int32
+        )
     return x, caches, loads
 
 
@@ -345,11 +453,13 @@ def decode_step(params: dict, token, lens, caches, config: GlmMoeLiteConfig):
     with jax.named_scope("embed_tokens"):
         x = jnp.take(params["token_embed"], token, axis=0)
     chosen = None
-    for layer, cache in zip(params["layers"], caches):
+    for i, (layer, cache) in enumerate(zip(params["layers"], caches)):
         h = _rms(x, layer["input_norm"], config.rms_norm_eps)
-        # a decoder without an indexer hands nothing on: its call is as it was
+        # a decoder without an indexer hands nothing on, one of one kind names
+        # no geometry: its call is as it was
         carried = () if chosen is None else (chosen,)
-        out, chosen = _attention_decode(h, layer["attn"], lens, cache, config, *carried)
+        kind = {"geo": config.geometry(i)} if config.layer_types else {}
+        out, chosen = _attention_decode(h, layer["attn"], lens, cache, config, *carried, **kind)
         x = x + out
         out, _ = _mlp(_rms(x, layer["post_norm"], config.rms_norm_eps), layer, config)
         x = x + out
@@ -388,7 +498,13 @@ def whole_bound_layers(load, config: GlmMoeLiteConfig) -> int:
 
 
 def _owns_indexer(config: GlmMoeLiteConfig, layer: int) -> bool:
-    return bool(config.index_topk) and config.indexer_types[layer] == "full"
+    """Where layers are of two kinds every full layer owns its indexer and a
+    sliding one has none; else ``indexer_types`` says."""
+    if not config.index_topk:
+        return False
+    if config.layer_types:
+        return not config.slides(layer)
+    return config.indexer_types[layer] == "full"
 
 
 def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32, held=None) -> dict:
@@ -413,28 +529,39 @@ def init_params(rng, config: GlmMoeLiteConfig, dtype=jnp.float32, held=None) -> 
         h = config.hidden_size
         return {"gate": dense(h, width), "up": dense(h, width), "down": dense(width, h)}
 
-    h, heads, rank = config.hidden_size, config.num_heads, config.kv_lora_rank
+    h = config.hidden_size
     layers = []
     for i in range(config.num_layers):
-        w_k = normal(rank, heads, config.qk_head_dim)
+        geo = config.geometry(i)
+        heads, rank = geo.heads, geo.kv_lora_rank
+        w_k = normal(rank, heads, geo.laid)
+
+        def q_b():
+            # the lanes a laid head adds, [nope, laid - rope), are zero
+            w = normal(geo.q_lora_rank, heads, geo.laid)
+            w = w.at[:, :, geo.nope:geo.laid - geo.rope].set(0)
+            return {"kernel": w.reshape(geo.q_lora_rank, heads * geo.laid)}
+
         layer = {
             "input_norm": scale(h),
             "post_norm": scale(h),
             "attn": {
-                "q_a": dense(h, config.q_lora_rank),
-                "q_a_norm": scale(config.q_lora_rank),
-                "q_b": dense(config.q_lora_rank, heads * config.qk_head_dim),
-                "kv_a": dense(h, rank + config.qk_rope_head_dim),
+                "q_a": dense(h, geo.q_lora_rank),
+                "q_a_norm": scale(geo.q_lora_rank),
+                "q_b": q_b(),
+                "kv_a": dense(h, rank + geo.rope),
                 "kv_a_norm": scale(rank),
-                "w_k": w_k.at[:, :, config.qk_nope_head_dim:].set(0),
-                "w_v": normal(rank, heads * config.v_head_dim),
-                "o": dense(heads * config.v_head_dim, h),
+                "w_k": w_k.at[:, :, geo.nope:].set(0),
+                "w_v": normal(rank, heads * geo.v),
+                "o": dense(heads * geo.v, h),
             },
         }
+        if geo.gate:
+            layer["attn"]["gate"] = dense(h, heads)
         if _owns_indexer(config, i):
             dim = config.index_head_dim
             layer["attn"]["indexer"] = {
-                "q": dense(config.q_lora_rank, config.index_n_heads * dim),
+                "q": dense(geo.q_lora_rank, config.index_n_heads * dim),
                 "k": dense(h, dim),
                 "k_norm": scale(dim),
                 "k_bias": normal(dim),
@@ -498,15 +625,19 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
     held = 0
     while f"{prefix}layers.{dense_layers}.mlp.experts.{held}.gate_proj.weight" in state:
         held += 1
-    owners = tuple(
-        "full" if f"{prefix}layers.{i}.self_attn.indexer.wq_b.weight" in state else "shared"
-        for i in range(depth)
-    ) if config.index_topk else ()
-    if owners and owners[0] != "full":
-        raise ValueError("the first layer served owns no indexer: nothing to attend over")
+    named = [
+        f"{prefix}layers.{i}.self_attn.indexer.wq_b.weight" in state for i in range(depth)
+    ]
+    owners, kinds = (), ()
+    if config.layer_types:  # two kinds: a full layer owns its indexer, a sliding one has none
+        kinds = tuple("full_attention" if own else "sliding_attention" for own in named)
+    elif config.index_topk:
+        owners = tuple("full" if own else "shared" for own in named)
+        if owners[0] != "full":
+            raise ValueError("the first layer served owns no indexer: nothing to attend over")
     config = dataclasses.replace(
         config, num_layers=depth, first_k_dense_replace=dense_layers,
-        indexer_types=owners, vocab_size=int(embed.shape[0]),
+        indexer_types=owners, layer_types=kinds, vocab_size=int(embed.shape[0]),
     )
 
     def get(name):
@@ -523,8 +654,7 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
     def swiglu(base):
         return {k: dense(f"{base}.{k}_proj") for k in ("gate", "up", "down")}
 
-    heads, rank = config.num_heads, config.kv_lora_rank
-    nope, rope, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
+    rope = config.qk_rope_head_dim
     order = np.asarray(_deinterleave(rope))
 
     def indexer(base):
@@ -546,12 +676,24 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
     for i in range(depth):
         base = f"layers.{i}"
         att = f"{base}.self_attn"
-        q_b = get(f"{att}.q_b_proj.weight").reshape(heads, nope + rope, -1)
-        q_b = np.concatenate([q_b[:, :nope], q_b[:, nope:][:, order]], axis=1)
+        geo = config.geometry(i)
+        heads, rank, nope, dv = geo.heads, geo.kv_lora_rank, geo.nope, geo.v
+        order = np.asarray(_deinterleave(geo.rope))
+        q_b = get(f"{att}.q_b_proj.weight")
+        if q_b.shape != (heads * geo.head_dim, geo.q_lora_rank):
+            raise ValueError(
+                f"layer {i} ({'sliding' if geo.window else 'full'} by what it names): "
+                f"q_b_proj is {q_b.shape}, its kind's is {(heads * geo.head_dim, geo.q_lora_rank)}"
+            )
+        q_b = q_b.reshape(heads, geo.head_dim, -1)
+        # a head wider than one column is laid in whole columns: zero rows
+        # between its nope and rope dims (``AttentionGeometry.laid``)
+        between = np.zeros((heads, geo.laid - geo.head_dim, q_b.shape[2]), q_b.dtype)
+        q_b = np.concatenate([q_b[:, :nope], between, q_b[:, nope:][:, order]], axis=1)
         kv_a = get(f"{att}.kv_a_proj_with_mqa.weight")
         kv_a = np.concatenate([kv_a[:rank], kv_a[rank:][order]], axis=0)
         kv_b = get(f"{att}.kv_b_proj.weight").reshape(heads, nope + dv, rank)
-        w_k = np.zeros((heads, nope + rope, rank), kv_b.dtype)
+        w_k = np.zeros((heads, geo.laid, rank), kv_b.dtype)
         w_k[:, :nope] = kv_b[:, :nope]
         layer = {
             "input_norm": put(get(f"{base}.input_layernorm.weight")),
@@ -559,7 +701,7 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
             "attn": {
                 "q_a": dense(f"{att}.q_a_proj"),
                 "q_a_norm": put(get(f"{att}.q_a_layernorm.weight")),
-                "q_b": {"kernel": swap(put(q_b.reshape(heads * (nope + rope), -1)))},
+                "q_b": {"kernel": swap(put(q_b.reshape(heads * geo.laid, -1)))},
                 "kv_a": {"kernel": swap(put(kv_a))},
                 "kv_a_norm": put(get(f"{att}.kv_a_layernorm.weight")),
                 "w_k": jnp.transpose(put(w_k), (2, 0, 1)),
@@ -567,6 +709,8 @@ def from_hf_weights(state, config: GlmMoeLiteConfig, dtype=jnp.float32):
                 "o": dense(f"{att}.o_proj"),
             },
         }
+        if geo.gate:
+            layer["attn"]["gate"] = dense(f"{att}.g_proj")
         if _owns_indexer(config, i):
             layer["attn"]["indexer"] = indexer(f"{att}.indexer")
         if i < config.first_k_dense_replace:

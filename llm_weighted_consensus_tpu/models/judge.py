@@ -8,19 +8,24 @@ is a few calls of one causal decoder over the same candidates under
 differently seeded ballots, and what an upstream judge's ``top_logprobs``
 would have carried is read from the decoder's own head.
 
-Three decoders serve (``JUDGE_PRESETS``; the preset's configuration class
+Four decoders serve (``JUDGE_PRESETS``; the preset's configuration class
 says which module): ``models/glm_moe.py`` runs ``glm-4.7-flash`` (latent
-attention, every expert held) and ``glm-5.2`` (a learned sparse selection in
-front of latent attention, a share of the router's experts held), and
-``models/qwen3_next.py`` the third (gated delta-rule layers three to one with
-gated full attention, a share held).  The panel's protocol is no part of
-any: ``judge_panel`` below is ONE jitted program over what a decoder module
-gives,
+attention, every expert held), ``glm-5.2`` (a learned sparse selection in
+front of latent attention, a share of the router's experts held) and
+``dots3-note-prev`` (attention layers of TWO kinds told apart by the layer's
+kind: full layers behind an indexer each, sliding layers of another geometry
+over a window of 513 keys, a sigmoid gate a head, rescaled latents; a
+windowed latent cache beside the three kinds the second has), and
+``models/qwen3_next.py`` the fourth (gated delta-rule layers three to one
+with gated full attention, a share held).  The panel's protocol is no part
+of any: ``judge_panel`` below is ONE jitted program over what a decoder
+module gives,
 
   ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h], a
       cache a layer (of whatever kind the layer keeps), pairs routed a sparse
       layer; what else it counted on the device goes into the ``tallies``
-      dict by name (``index_keys``: pairs chosen and causal pairs)
+      dict by name (``index_keys``: pairs chosen and causal pairs;
+      ``window_keys``: pairs inside the sliding layers' bands and causal pairs)
   ``decode_step(params, token, lens, caches, config)``  -> hidden [b, h]
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
@@ -68,8 +73,9 @@ from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
 from . import glm_moe, qwen3_next
 from .configs import (
-    GLM_4_7_FLASH, GLM_5_2, GLM_DSA_TEST_TINY, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B,
-    QWEN3_NEXT_TEST_TINY, GlmMoeLiteConfig, Qwen3NextConfig,
+    DOTS3_NOTE_PREV, DOTS3_TEST_TINY, GLM_4_7_FLASH, GLM_5_2, GLM_DSA_TEST_TINY,
+    GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY, GlmMoeLiteConfig,
+    Qwen3NextConfig,
 )
 from .tokenizer import BaseTokenizer, load_tokenizer
 
@@ -78,6 +84,8 @@ JUDGE_PRESETS = {
     "glm-test-tiny": GLM_TEST_TINY,
     "glm-5.2": GLM_5_2,
     "glm-dsa-test-tiny": GLM_DSA_TEST_TINY,
+    "dots3-note-prev": DOTS3_NOTE_PREV,
+    "dots3-test-tiny": DOTS3_TEST_TINY,
     "qwen3-next-80b-a3b": QWEN3_NEXT_80B_A3B,
     "qwen3-next-test-tiny": QWEN3_NEXT_TEST_TINY,
 }
@@ -242,6 +250,10 @@ class TpuJudge:
             # own an indexer: those a query may see, and those it chose
             "index_keys_causal": 0,
             "index_keys_selected": 0,
+            # ... and over the layers with a window: those a query may see,
+            # and those inside its band
+            "window_keys_causal": 0,
+            "window_keys_band": 0,
             "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
         self._held = len(self._stats["expert_tokens"])
@@ -371,10 +383,13 @@ class TpuJudge:
             entry["siblings"] = siblings
             ballots.append(entry)
         confidence = tally / sum(call.weight for call in prepared.calls)
-        self._count(prepared, np.asarray(out["expert_load"]), out.get("index_keys"))
+        self._count(
+            prepared, np.asarray(out["expert_load"]), out.get("index_keys"),
+            out.get("window_keys"),
+        )
         return confidence, prepared.tokens, ballots
 
-    def _count(self, prepared: PreparedPanel, load, index_keys=None) -> None:
+    def _count(self, prepared: PreparedPanel, load, index_keys=None, window_keys=None) -> None:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
@@ -398,6 +413,10 @@ class TpuJudge:
                 selected, causal = np.asarray(index_keys)
                 s["index_keys_selected"] += int(selected)
                 s["index_keys_causal"] += int(causal)
+            if window_keys is not None:
+                band, causal = np.asarray(window_keys)
+                s["window_keys_band"] += int(band)
+                s["window_keys_causal"] += int(causal)
             if load.size:
                 totals = load.sum(axis=0)
                 s["expert_pairs_here"] += int(totals.sum())

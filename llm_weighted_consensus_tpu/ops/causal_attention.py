@@ -8,13 +8,25 @@ serve it.  This kernel walks the keys in blocks and keeps a running maximum,
 a running sum and an unnormalised accumulator per query block (the online
 softmax), so that nothing of size s x s ever exists.
 
-Layout: the projections' own.  q, k and v are [b, s, heads * hd] and the
-context is the [b, s, heads * hd] array the output projection reads; a head
-is one hd-wide column block (hd a multiple of 128 lanes, or the whole of
-the last dimension), so there is no head transpose around the kernel.
+Layout: the projections' own.  q and k are [b, s, heads * hd], v is
+[b, s, heads * dv] and the context is the [b, s, heads * dv] array the output
+projection reads; a head is one column block, ``hd`` wide against the keys and
+``dv`` wide against the values (each a multiple of 128 lanes, or the whole of
+the last dimension), so there is no head transpose around the kernel.  ``hd``
+is what q and k SHARE and nothing else: the accumulator and the context take
+the value width, which may be narrower (128 against 192 or 256).  A key head
+that is not whole columns (128 | 64 = 192) cannot be a block: the loader lays
+it in whole columns with zero lanes (``models/glm_moe.py``), which leaves
+every q.k what it was and costs the MXU, which contracts in 128s, nothing.
 Keys and values may have fewer heads than the queries (``kv_heads``): a
 query head's key block is found by ``head // group`` in the index map, and
 with a key head a query head the index map is the one it always was.
+
+Two jitted names over one body.  ``causal_attention_blockwise`` attends every
+key at or before the query (or a selection among them);
+``window_attention_blockwise`` the ``window`` keys up to and with the query.
+What differs for a window is the table of steps (only the blocks the band
+touches), a second masked edge, and the blocks, which follow the window.
 
 The schedule follows from (s, block_q, block_k, hd) and nothing else:
 
@@ -32,6 +44,16 @@ The schedule follows from (s, block_q, block_k, hd) and nothing else:
   one stripe) stays one whole masked tile.  ``work_over_causal`` is what
   that leaves: 1.031 of the causal pairs at 8192, from 1.125 by whole
   tiles of 1024.
+- With a window, a query block's steps begin at the block that holds the
+  first key of its first query (``_steps``), the running state is reset
+  there, and the block the band's OLD edge crosses is one whole masked tile
+  (both edges in one mask).  The blocks follow the window (``window_block``:
+  the largest inside its reach; 512 for 513 keys, where a query block meets
+  exactly two key blocks), because today's 2048 would multiply 7.2 times the
+  band.  ``work_over_window`` is the count beside ``work_over_causal``:
+  pairs multiplied over the ``band_pairs`` kept; at 8192 slots and 513 keys
+  1.74 at blocks of 512 (the diagonal in stripes, 15 whole edge tiles), 1.50
+  at 256, 3.86 at 1024.
 - A score costs a subtract, a multiply and an ``exp2``: the maximum is
   taken over raw scores (scale > 0) and scale * log2(e) is one constant.
   Where the shapes allow, the running maximum and sum are kept once a lane
@@ -103,15 +125,52 @@ def stripe_for(block_q: int, block_k: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _steps(seq: int, block_q: int, block_k: int):
+def _steps(seq: int, block_q: int, block_k: int, window: int = 0):
     """(query block, key block) of every grid step: the lower triangle's
-    pairs, a query block's key blocks in order."""
+    pairs, a query block's key blocks in order; with a ``window`` (keys a
+    query, its own among them) only the pairs the band touches, from the
+    block that holds the first key of the block's first query."""
     pairs = [
         (qi, ki)
         for qi in range(seq // block_q)
-        for ki in range((qi * block_q + block_q - 1) // block_k + 1)
+        for ki in range(
+            max(0, qi * block_q - window + 1) // block_k if window else 0,
+            (qi * block_q + block_q - 1) // block_k + 1,
+        )
     ]
     return tuple(np.asarray(column, np.int32) for column in zip(*pairs))
+
+
+def window_block(seq: int, window: int) -> int:
+    """The block a windowed layer takes: the largest that divides ``seq`` and
+    lies inside the window's reach, so that a query block meets the fewest
+    key blocks the band allows (window 513: blocks of 512, two a query
+    block; today's 2048 would multiply seven times the band)."""
+    return block_for(seq, cap=max(window - 1, _BLOCKS[-1]))
+
+
+def band_pairs(seq: int, window: int) -> int:
+    """(query, key) pairs with query - window < key <= query: what a windowed
+    layer attends over ``seq`` slots."""
+    w = min(window, seq)
+    return w * (w + 1) // 2 + (seq - w) * w
+
+
+def work_over_window(seq: int, block_q: int, block_k: int, window: int) -> float:
+    """Score pairs the kernel multiplies over the ``band_pairs`` the window
+    keeps.  The block the diagonal crosses is walked as ``work_over_causal``
+    says (in stripes where ``stripe_for`` splits it and the window covers the
+    block); every other step is a whole tile."""
+    qi, ki = _steps(seq, block_q, block_k, window)
+    stripe = stripe_for(block_q, block_k) if block_q <= window else 0
+    crossed = int((ki * block_k + block_k - 1 > qi * block_q).sum())
+    pairs = (len(qi) - crossed) * block_q * block_k
+    if stripe:
+        n = block_q // stripe
+        pairs += crossed * stripe * stripe * n * (n + 1) // 2
+    else:
+        pairs += crossed * block_q * block_k
+    return pairs / band_pairs(seq, window)
 
 
 def work_over_causal(
@@ -133,14 +192,18 @@ def work_over_causal(
     return pairs / (seq * (seq + 1) // 2)
 
 
-def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, band):
+def _kernel(
+    qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, band, window=0
+):
     # with a selection, its tile comes behind v: 1 where the query may meet the key
     keep_ref = rest[0] if len(rest) == 5 else None
     o_ref, m_ref, l_ref, acc_ref = rest[-4:]
     step = pl.program_id(2)
     qi, ki = qi_ref[step], ki_ref[step]
     last = (qi * bq + bq - 1) // bk  # the key block that holds the diagonal's end
-    lanes, hd = m_ref.shape[1], acc_ref.shape[1]
+    # ... and the one that holds the first key of the block's first query
+    first = jnp.maximum(qi * bq - (window - 1), 0) // bk if window else 0
+    lanes, hd = m_ref.shape[1], acc_ref.shape[1]  # hd: a VALUE head's lanes
     c = scale * _LOG2E  # exp(scale * x) = exp2(c * x): one multiply a score
 
     def across(x, n):
@@ -155,7 +218,7 @@ def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, b
         chunks = [p[:, j:j + lanes] for j in range(0, p.shape[1], lanes)]
         return functools.reduce(jnp.add, chunks)
 
-    @pl.when(ki == 0)
+    @pl.when(ki == first)
     def _():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -182,7 +245,10 @@ def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, b
                 col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
                 if mask == "tile":
                     row, col = qi * bq + r0 + row, ki * bk + c0 + col
-                raw = jnp.where(col <= row, raw, _NEG)
+                seen = col <= row
+                if window and mask == "tile":  # the band's old edge as well
+                    seen = seen & (col > row - window)
+                raw = jnp.where(seen, raw, _NEG)
             scores.append(raw)
         m_prev = m_ref[rs, :]
         m_new = functools.reduce(
@@ -202,8 +268,18 @@ def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, b
     # a key block lies wholly below the diagonal when its last column is at
     # or before the query block's first row
     below = ki * bk + bk - 1 <= qi * bq
+    inside = below
+    if window:
+        # ... and wholly inside the band when its first column is within the
+        # window of the query block's last row; the block the band's old edge
+        # crosses is masked as the diagonal's is, one whole tile
+        inside = below & (ki * bk > qi * bq + bq - 1 - window)
 
-    @pl.when(below)
+        @pl.when(below & jnp.logical_not(inside))
+        def _():
+            update(0, bq, [(0, bk, "tile")])
+
+    @pl.when(inside)
     def _():
         for r0 in range(0, bq, band):
             update(r0, band, [(0, bk, None)])
@@ -224,39 +300,41 @@ def _kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, b
         o_ref[...] = (acc_ref[...] * norm).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("heads", "scale", "kv_heads", "block_q", "block_k", "interpret"),
-)
-def causal_attention_blockwise(
-    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0,
-    block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
-):
-    """q: [b, s, heads * hd], k and v: [b, s, kv_heads * hd] (``kv_heads``
-    0: a key head a query head) -> context [b, s, heads * hd]; position i
-    attends positions <= i.  With fewer key heads, query head h reads key
-    head ``h // (heads / kv_heads)`` through the block's index: no key is
-    repeated in memory.  With ``keep`` [b, s, s] int8 (a learned sparse
-    selection, ``ops/sparse_index.py``; 0 wherever key > query) a query meets
-    only the keys its row marks: every pair of the lower triangle's blocks is
-    still multiplied, and a pair not chosen is masked before the softmax in
-    place of the causal mask; a head reads the tile again.  The jitted
-    function's name is the kernel's name in a device trace."""
+def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpret, window=0):
+    """The pallas_call behind both jitted names below."""
     b, s, width = q.shape
     hd = width // heads
     group = heads // (kv_heads or heads)
+    dv = v.shape[-1] * group // heads  # a value head may be narrower than a key head
     if interpret is None:
         interpret = _interpret()
-    if hd * heads != width or (not interpret and heads > 1 and hd % _LANES):
+    if hd * heads != width:
         raise ValueError(f"heads of {hd} lanes cannot be carved from {width}")
+    if not interpret and heads > 1 and (hd % _LANES or dv % _LANES):
+        which = "key" if hd % _LANES else "value"
+        raise ValueError(
+            f"a {which} head of {dv if which == 'value' else hd} lanes is not whole "
+            f"{_LANES}-lane columns (key heads {hd}, value heads {dv}): a block is one "
+            "head's columns of the projections' own layout.  The loader can lay such a "
+            "head in whole columns with zero lanes (zero rows of the query and key "
+            "products: every q.k stays what it was; zero value lanes need zero rows of "
+            "the output product)"
+        )
     if group * (kv_heads or heads) != heads or k.shape[-1] * group != width:
         raise ValueError(f"{heads} query heads on keys of width {k.shape[-1]}")
-    bq = block_q or block_for(s)
-    bk = block_k or block_for(s)
+    if dv * heads != v.shape[-1] * group:
+        raise ValueError(f"{heads} query heads on values of width {v.shape[-1]}")
+    if window and keep is not None:
+        raise ValueError("a windowed layer attends its band, not a selection")
+    block = window_block(s, window) if window else block_for(s)
+    bq, bk = block_q or block, block_k or block
     # the running maximum and sum stand once a lane where the shapes allow
-    lanes = _LANES if bk % _LANES == 0 and hd % _LANES == 0 else 1
+    lanes = _LANES if bk % _LANES == 0 and hd % _LANES == 0 and dv % _LANES == 0 else 1
     band = _BAND if bq % _BAND == 0 else bq
-    qi_of_step, ki_of_step = _steps(s, bq, bk)
+    qi_of_step, ki_of_step = _steps(s, bq, bk, window)
+    # the diagonal's block goes in stripes where the window covers the block
+    stripe = stripe_for(bq, bk) if not window or bq <= window else 0
+    windowed = {"window": window} if window else {}
 
     def q_index(bi, h, step, qi_of_step, ki_of_step):
         return bi, qi_of_step[step], h
@@ -270,7 +348,7 @@ def causal_attention_blockwise(
     selection = [] if keep is None else [keep]
     return pl.pallas_call(
         functools.partial(
-            _kernel, scale=scale, bq=bq, bk=bk, stripe=stripe_for(bq, bk), band=band
+            _kernel, scale=scale, bq=bq, bk=bk, stripe=stripe, band=band, **windowed
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -278,17 +356,17 @@ def causal_attention_blockwise(
             in_specs=[
                 pl.BlockSpec((None, bq, hd), q_index),
                 pl.BlockSpec((None, bk, hd), kv_index),
-                pl.BlockSpec((None, bk, hd), kv_index),
+                pl.BlockSpec((None, bk, dv), kv_index),
                 *[pl.BlockSpec((None, bq, bk), keep_index) for _ in selection],
             ],
-            out_specs=pl.BlockSpec((None, bq, hd), q_index),
+            out_specs=pl.BlockSpec((None, bq, dv), q_index),
             scratch_shapes=[
                 pltpu.VMEM((bq, lanes), jnp.float32),
                 pltpu.VMEM((bq, lanes), jnp.float32),
-                pltpu.VMEM((bq, hd), jnp.float32),
+                pltpu.VMEM((bq, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, heads * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
@@ -297,19 +375,71 @@ def causal_attention_blockwise(
     )(jnp.asarray(qi_of_step), jnp.asarray(ki_of_step), q, k, v, *selection)
 
 
-def causal_attention_einsum(
-    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0
+@functools.partial(
+    jax.jit,
+    static_argnames=("heads", "scale", "kv_heads", "block_q", "block_k", "interpret"),
+)
+def causal_attention_blockwise(
+    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0,
+    block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
 ):
-    """The kernel's plain twin: whole [s, s] scores (tests, tiny sizes)."""
+    """q: [b, s, heads * hd], k: [b, s, kv_heads * hd], v: [b, s, kv_heads *
+    dv] (``kv_heads`` 0: a key head a query head) -> context [b, s, heads *
+    dv]; position i attends positions <= i.  ``hd`` is what q and k share; a
+    value head may be of another width ``dv`` (the accumulator's and the
+    context's), and on a TPU both are whole 128-lane columns unless there is
+    one head.  With fewer key heads, query head h reads key
+    head ``h // (heads / kv_heads)`` through the block's index: no key is
+    repeated in memory.  With ``keep`` [b, s, s] int8 (a learned sparse
+    selection, ``ops/sparse_index.py``; 0 wherever key > query) a query meets
+    only the keys its row marks: every pair of the lower triangle's blocks is
+    still multiplied, and a pair not chosen is masked before the softmax in
+    place of the causal mask; a head reads the tile again.  The jitted
+    function's name is the kernel's name in a device trace."""
+    return _attend(
+        q, k, v, keep, heads=heads, scale=scale, kv_heads=kv_heads, block_q=block_q,
+        block_k=block_k, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("heads", "scale", "window", "kv_heads", "block_q", "block_k", "interpret"),
+)
+def window_attention_blockwise(
+    q, k, v, *, heads: int, scale: float, window: int, kv_heads: int = 0,
+    block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
+):
+    """``causal_attention_blockwise`` over a WINDOW: position i attends the
+    ``window`` positions i - window < j <= i (its own among them).  The same
+    kernel body; what differs is the table of steps (``_steps``: only the
+    pairs of blocks the band touches; ``work_over_window`` says what that
+    multiplies over the band), a second masked edge (the block the band's
+    old edge crosses is one whole masked tile, as an unsplit diagonal block
+    is) and the blocks, which follow the window (``window_block``).  Under
+    its own jitted name, so that a device trace tells the two apart."""
+    return _attend(
+        q, k, v, None, heads=heads, scale=scale, kv_heads=kv_heads, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window,
+    )
+
+
+def causal_attention_einsum(
+    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0, window: int = 0
+):
+    """The kernels' plain twin: whole [s, s] scores (tests, tiny sizes)."""
     b, s, width = q.shape
     hd = width // heads
-    qh, kh, vh = (x.reshape(b, s, -1, hd) for x in (q, k, v))
+    qh, kh = (x.reshape(b, s, -1, hd) for x in (q, k))
+    vh = v.reshape(b, s, kh.shape[2], -1)
     if kv_heads and kv_heads != heads:
         kh, vh = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (kh, vh))
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", qh, kh, preferred_element_type=jnp.float32
     ) * scale
     seen = jnp.tril(jnp.ones((s, s), bool))
+    if window:
+        seen = seen & ~jnp.tril(jnp.ones((s, s), bool), -window)
     if keep is not None:
         seen = seen & (keep != 0)[:, None]
     probs = jax.nn.softmax(jnp.where(seen, scores, _NEG), axis=-1)
@@ -317,4 +447,4 @@ def causal_attention_einsum(
         "bhqk,bkhd->bqhd", probs.astype(v.dtype), vh,
         preferred_element_type=jnp.float32,
     )
-    return out.reshape(b, s, width).astype(q.dtype)
+    return out.reshape(b, s, -1).astype(q.dtype)

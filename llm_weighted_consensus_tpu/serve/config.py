@@ -78,21 +78,28 @@ TPU additions:
   decoder serving ``POST /consensus {"scorer": "judge"}``: a LOCAL judge
   panel, each call a prefill of the candidates under a seeded prefix-tree
   ballot, one decoded key letter and a masked read of its siblings'
-  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of three
+  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of four
   decoders: ``glm-4.7-flash`` (latent attention over every causal key,
   every expert held; models/glm_moe.py), ``glm-5.2`` (the same module under
   a configuration with an indexer: a learned sparse selection, the 2048
   highest-scored keys a query, chosen on the layers that own an indexer and
   shared with the layers behind them, in front of 64-head latent attention;
-  the indexer's keys cached beside the latent and the rotary key) or
-  ``qwen3-next-80b-a3b`` (gated delta-rule layers three to one with gated
+  the indexer's keys cached beside the latent and the rotary key),
+  ``dots3-note-prev`` (the same module again, its attention layers of two
+  kinds told apart by ``layer_types``: full layers of 128 heads each behind
+  an indexer of its own, sliding layers of 64 heads of another geometry over
+  the 513 keys up to the query, every head behind a sigmoid gate, the
+  latents rescaled; a sliding layer caches only the last 512 positions a
+  call) or ``qwen3-next-80b-a3b`` (gated delta-rule layers three to one with gated
   full attention, a recurrent state and a convolution tail cached beside
   the keys; models/qwen3_next.py); each has a tiny twin for tests
-  (``glm-test-tiny``, ``glm-dsa-test-tiny``, ``qwen3-next-test-tiny``).
+  (``glm-test-tiny``, ``glm-dsa-test-tiny``, ``dots3-test-tiny``,
+  ``qwen3-next-test-tiny``).
   ``JUDGE_WEIGHTS`` is an HF checkpoint, one ``model.safetensors`` or
   sharded; what is served is what it names: its layers from 0 up (and of
-  each whether it is dense or sparse and whether it owns an indexer: one
-  pipeline stage of a deployment), experts 0..E-1 of a wider router (one
+  each whether it is dense or sparse and whether it owns an indexer, which
+  under ``dots3-note-prev`` says its kind, full or sliding: one pipeline
+  stage of a deployment), experts 0..E-1 of a wider router (one
   chip's share of a layer's experts: the pairs routed elsewhere are left
   out of this chip's partial sum) and, for the ``glm`` decoders, the rows
   of the vocabulary its embedding holds (a slice is a smaller vocabulary).

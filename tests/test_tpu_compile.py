@@ -415,3 +415,110 @@ def test_the_rotary_turn_compiles_in_place_behind_the_query_product(
     wide = sorted(op for op in wide if op not in ("convolution", "convert", "parameter"))
     assert wide == ["custom-call", "fusion"], wide
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20  # the two tables
+
+
+def test_the_window_kernel_compiles_at_the_fourth_judge_s_shape(one_chip):
+    """A sliding layer's attention: 64 heads of 256 lanes against the keys,
+    VALUES OF 128, over 3 x 8192 slots under a window of 513 at the blocks the
+    window gives (512: a query block meets two key blocks).  Mosaic takes the
+    second masked edge and the narrower accumulator; the kernel runs under its
+    own name, and nothing stands around it."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+
+    qk = jax.ShapeDtypeStruct((3, 8192, 64 * 256), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((3, 8192, 64 * 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: ca.window_attention_blockwise(
+            q, k, v, heads=64, scale=1 / 16, window=513, interpret=False
+        )
+    ).lower(qk, qk, v).compile()
+    assert ca.window_block(8192, 513) == 512 and len(ca._steps(8192, 512, 512, 513)[0]) == 31
+    found = instructions(compiled.as_text())
+    calls = [name for name, op, _ in found if op == "custom-call"]
+    assert len(calls) == 1 and calls[0].startswith("window_attention_blockwise"), calls
+    assert not [(n, op) for n, op, _ in found if op in ("copy", "transpose", "fusion")]
+
+
+def test_a_full_layer_s_kernels_compile_at_128_heads_laid_in_256_lanes(one_chip):
+    """The fourth judge's full layer: 128 heads of 128 | 64 laid in 256 lanes,
+    values of 128, the selection's tile beside them; and its indexer at 64
+    heads (the third judge's has 32)."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+    from llm_weighted_consensus_tpu.ops import sparse_index as si
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qk, v = arg((3, 8192, 128 * 256), jnp.bfloat16), arg((3, 8192, 128 * 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, keep: ca.causal_attention_blockwise(
+            q, k, v, keep, heads=128, scale=192**-0.5, interpret=False
+        )
+    ).lower(qk, qk, v, arg((3, 8192, 8192), jnp.int8)).compile()
+    found = instructions(compiled.as_text())
+    calls = [name for name, op, _ in found if op == "custom-call"]
+    assert len(calls) == 1 and calls[0].startswith("causal_attention_blockwise"), calls
+    assert not [(n, op) for n, op, _ in found if op in ("copy", "transpose", "fusion")]
+    scores = jax.jit(
+        lambda q, k, w: si.index_scores(q, k, w, heads=64, interpret=False)
+    ).lower(
+        arg((3, 8192, 64 * 128), jnp.bfloat16), arg((3, 8192, 128), jnp.bfloat16),
+        arg((3, 8192, 64), jnp.float32),
+    ).compile()
+    # (XLA may stage the small operands in VMEM ahead of the kernel: a call of its own)
+    found = instructions(scores.as_text())
+    calls = [n for n, op, _ in found if op == "custom-call" and n.startswith("index_scores")]
+    assert len(calls) == 1, found
+    # a head of 192 lanes as published is refused before Mosaic is asked
+    with pytest.raises(ValueError, match="a key head of 192 lanes"):
+        jax.jit(
+            lambda q, k, v: ca.causal_attention_blockwise(
+                q, k, v, heads=128, scale=1.0, interpret=False
+            )
+        ).lower(arg((3, 8192, 128 * 192), jnp.bfloat16), arg((3, 8192, 128 * 192), jnp.bfloat16), v)
+
+
+def test_the_fourth_judge_s_panel_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """``judge_panel`` for ``dots3-note-prev`` as its cell cuts it (published
+    layers 0-4, 16 of 256 experts, an eighth of the vocabulary, bf16) over a
+    panel of 3 x 8192 slots at depth 2: every kernel of both kinds of layer is
+    taken by the chip's compiler in ONE program, and its count of the device's
+    memory (arguments + temporaries) is under the issue's 14.0 GB, the rule
+    that chose 16 experts over 32 (32: 8.28 + 6.32 = 14.60 GB, PERF.md).  A
+    count of the compiler's, not a reading of the chip."""
+    from llm_weighted_consensus_tpu.models import glm_moe, judge
+    from llm_weighted_consensus_tpu.ops import (
+        causal_attention, grouped_matmul, rotary, sparse_index,
+    )
+
+    for module in (causal_attention, grouped_matmul, rotary, sparse_index):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    preset = configs.DOTS3_NOTE_PREV
+    cut = replace(preset, num_layers=5, vocab_size=19008, layer_types=preset.layer_types[:5])
+    shapes = jax.eval_shape(
+        lambda: glm_moe.init_params(jax.random.PRNGKey(0), cut, dtype=jnp.bfloat16, held=16)
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), shapes)
+    b, s, letters = 3, 8192, 20
+    compiled = judge.judge_panel.lower(
+        params, arg((b, s), jnp.int32), arg((b,), jnp.int32), arg((letters,), jnp.int32),
+        arg((b, letters), jnp.bool_), arg((b, letters, letters), jnp.bool_),
+        decoder=glm_moe, config=cut, depth=2,
+    ).compile()
+    kernels = [n for n, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    for name, count in (
+        ("window_attention_blockwise", 3), ("causal_attention_blockwise", 2),
+        ("index_scores", 2), ("index_select", 2),
+    ):
+        assert sum(k.startswith(name) for k in kernels) == count, (name, kernels)
+    assert any(k.startswith("grouped_expert_product") for k in kernels)
+    assert any(k.startswith("turn_lanes") for k in kernels)  # 256-lane heads turn in place
+    memory = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(shapes))
+    assert 5.2e9 < weights < 5.3e9 and memory.argument_size_in_bytes >= weights
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < held < 14.0e9, held

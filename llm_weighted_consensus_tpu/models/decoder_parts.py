@@ -133,8 +133,12 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
     fetches one row a pair held here (the pairs elsewhere are sorted out of
     its lists before it runs); the sum is the same float32 sum in the order
     of the choices, bit for bit.
-    A width whose rows are not whole tiles of words (``row_slabs`` 0: the tiny
-    presets) keeps the masked gathers."""
+    The walk serves every width whose row is whole sublanes of words and at
+    least one whole (8, 128) tile of them, the slab padded up to whole tiles
+    where the row does not fill them (5120 x bfloat16: 20 sublanes in a slab
+    of 24).  A row under one whole tile of words, or not whole sublanes
+    (``row_slabs`` (0, 0): the tiny presets, hidden 64-512), keeps the masked
+    gathers: its slab would be mostly padding."""
     t, k = chosen.shape
     tile = _gmm.tile_for(t * k, experts)
     with jax.named_scope("experts_layout"):
@@ -148,8 +152,8 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
                 chosen.reshape(-1), weight.reshape(-1), held, tile
             )
         pair_of_row, row_of_pair, tile_expert, used, counts, row_weight = tables
-        # a share's rows are walked where a row is whole tiles of words
-        walk = here is not None and _gmm.row_slabs(h.shape[1], h.dtype) > 0
+        # a share's rows are walked where a row is a whole tile of words or more
+        walk = here is not None and _gmm.row_slabs(h.shape[1], h.dtype)[0] > 0
         if here is None:
             rows_of = row_of_pair.reshape(t, k)
         elif walk:  # a pair elsewhere is skipped: a row no table has

@@ -499,6 +499,54 @@ def test_the_eight_shares_add_up_to_the_uncut_reference_layer():
     assert np.abs(total - whole).max() < 5e-6
 
 
+# -- the held experts' way back at the published width (ISSUE 40) ----------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=lambda v: v.__name__)
+def test_the_published_width_takes_the_walk_and_gives_the_fall_back_s_bits(monkeypatch, dtype):
+    """Hidden 5120 is 20 sublanes of bf16 words (40 of float32), no whole
+    (8, 128) tile: a small layer at the configuration's own width and routing
+    (8 of a router 256 wide, 16 held) lowers ``held_rows_sum`` over a padded
+    slab and no gather on its way back, and its sum is bit for bit what the
+    masked gathers, the path of every width until this issue, give."""
+    from test_qwen3_next import primitives
+    from llm_weighted_consensus_tpu.ops import grouped_matmul as gmm
+
+    call_names = lambda fn: [name for name, _ in primitives(jax.make_jaxpr(fn)(h, p).jaxpr)]  # noqa: E731
+    cfg = DOTS3_NOTE_PREV
+    hidden, k, router, held = cfg.hidden_size, cfg.num_experts_per_tok, cfg.n_routed_experts, 16
+    slab, filled = gmm.row_slabs(hidden, dtype)
+    assert (slab, filled) == ((24, 20) if dtype == jnp.bfloat16 else (40, 40))
+    rng = np.random.default_rng(11)
+    tokens, width = 40, 16
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), dtype)
+    p = {
+        name: jnp.asarray(rng.standard_normal(shape) * 0.1, dtype)
+        for name, shape in (
+            ("w_gate", (held, hidden, width)), ("w_up", (held, hidden, width)),
+            ("w_down", (held, width, hidden)),
+        )
+    }
+    # a token in four with every choice held, one in four with none, the rest as they fall
+    chosen = np.stack([rng.permutation(router)[:k] for _ in range(tokens)]).astype(np.int32)
+    chosen[0::4] = np.stack([rng.permutation(held)[:k] for _ in chosen[0::4]])
+    chosen[1::4] = held + np.stack([rng.permutation(router - held)[:k] for _ in chosen[1::4]])
+    chosen, weight = jnp.asarray(chosen), jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    layer = lambda h, p: decoder_parts.experts_grouped(h, chosen, weight, p, router, held=held)  # noqa: E731
+    names = call_names(layer)
+    assert names.count("held_rows_sum") == 1 and "gather" not in names[names.index("held_rows_sum"):]
+    got, counts = layer(h, p)
+    monkeypatch.setattr(gmm, "row_slabs", lambda width, dtype: (0, 0))  # the parent's answer
+    masked = lambda h, p: layer(h, p)  # noqa: E731  (a trace of its own, not the cached one)
+    fall_back = call_names(masked)
+    assert "held_rows_sum" not in fall_back and fall_back.count("gather") >= k
+    want, _ = masked(h, p)
+    assert got.dtype == want.dtype == dtype and got.shape == (tokens, hidden)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.array_equal(got, want) and np.abs(want).max() > 0.1
+    assert not got[1::4].any() and int(np.asarray(counts)[:held].sum()) == int((chosen < held).sum())
+
+
 # -- the loader ----------------------------------------------------------------------------
 
 

@@ -8,6 +8,7 @@ seeded weights.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -681,11 +682,18 @@ def gather_twin(h, chosen, weight, p, experts, held, rows=None):
         gmm.grouped_expert_product(x, p["w_gate"], w_up=p["w_up"], **kernels), p["w_down"],
         row_weight=row_weight[:rows], out_chunks=2, **kernels,
     )
+    return masked_sums(y, rows_of, here).astype(h.dtype)
+
+
+def masked_sums(chunks, rows_of, here):
+    """Column chunks of a down product, rows_of and here [t, k] -> [t, width]
+    float32: a masked gather a choice, summed in the order of the choices."""
     take = lambda part, j: jnp.where(  # noqa: E731
         here[:, j, None], part[rows_of[:, j]].astype(jnp.float32), 0.0
     )
-    routed = [sum(take(part, j) for j in range(k)) for part in y]
-    return jnp.concatenate(routed, axis=1).astype(h.dtype)
+    return jnp.concatenate(
+        [sum(take(part, j) for j in range(here.shape[1])) for part in chunks], axis=1
+    )
 
 
 def share_case(tokens, k, dtype, routing, seed=0):
@@ -740,7 +748,7 @@ def test_a_share_s_walk_is_the_gather_form_bit_for_bit(
     none of whose choices is held and tokens all of whose are, whole steps of
     tokens and not, a decode step's three, either branch of the ``lax.cond``."""
     h, chosen, weight, p, router, held = share_case(tokens, k, dtype, routing)
-    assert gmm.row_slabs(h.shape[1], dtype) == 8
+    assert gmm.row_slabs(h.shape[1], dtype) == (8, 8)
     here = np.asarray(chosen) < held
     if routing == "mixed" and tokens > 8:
         assert not here[0::4].any() and here[1::4].all()
@@ -763,13 +771,80 @@ def test_a_share_s_walk_is_the_gather_form_bit_for_bit(
 
 @pytest.mark.parametrize(
     "width,dtype,slabs",
-    [(2048, jnp.bfloat16, 8), (1024, jnp.float32, 8), (2048, jnp.float32, 16),
-     (4096, jnp.bfloat16, 16), (1024, jnp.bfloat16, 0), (64, jnp.float32, 0),
-     (2048, jnp.float16, 0), (2048, jnp.int8, 0)],
+    [(2048, jnp.bfloat16, (8, 8)), (1024, jnp.float32, (8, 8)), (2048, jnp.float32, (16, 16)),
+     (4096, jnp.bfloat16, (16, 16)), (1024, jnp.bfloat16, (0, 0)), (64, jnp.float32, (0, 0)),
+     (2048, jnp.float16, (0, 0)), (2048, jnp.int8, (0, 0)),
+     # since ISSUE 40: (the slab's stride, the sublanes a row fills)
+     (6144, jnp.bfloat16, (24, 24)), (5120, jnp.bfloat16, (24, 20)),
+     (2560, jnp.bfloat16, (16, 10)), (1280, jnp.float32, (16, 10)),
+     (2304, jnp.bfloat16, (16, 9)), (512, jnp.float32, (0, 0)), (512, jnp.bfloat16, (0, 0)),
+     (2112, jnp.bfloat16, (0, 0)), (2049, jnp.bfloat16, (0, 0))],
     ids=lambda v: getattr(v, "__name__", str(v)),
 )
 def test_rows_are_walked_only_where_a_row_is_whole_tiles_of_words(width, dtype, slabs):
+    """A row of at least one whole tile of words is walked, its slab padded up
+    to whole (8, 128) tiles; a row under one (the tiny presets), one that is
+    not whole sublanes of words, a dtype with no packing: not walked."""
     assert gmm.row_slabs(width, dtype) == slabs
+
+
+# -- the walk at a width that is no whole tile of words: a padded slab (ISSUE 40) ----------
+
+
+def padded_case(dtype, held_share, tokens=200, k=4, seed=3):
+    """A down product's inputs at a width whose row pads its slab (10 of 16
+    sublanes: a scaled-down 5120), 12 of a router's 32 experts named held, the
+    pairs ``mixed`` over them, all ``elsewhere`` or all ``here``."""
+    rng = np.random.default_rng(seed)
+    hidden = 2560 if dtype == jnp.bfloat16 else 1280
+    width, held, router = 16, 12, 32
+    choose = {"mixed": router, "elsewhere": router - held, "here": held}[held_share]
+    chosen = np.stack([rng.permutation(choose)[:k] for _ in range(tokens)]).astype(np.int32)
+    chosen += held if held_share == "elsewhere" else 0
+    weight = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    tile = gmm.tile_for(tokens * k, router)
+    tables, here = gmm.route_layout_held(
+        jnp.asarray(chosen).reshape(-1), weight.reshape(-1), held, tile
+    )
+    rows = tables[0].shape[0]
+    x = jnp.asarray(rng.standard_normal((rows, width)), dtype)
+    w = jnp.asarray(rng.standard_normal((held, width, hidden)) * 0.1, dtype)
+    return x, w, tables, here, tile, (tokens, k, hidden)
+
+
+@pytest.mark.parametrize("held_share", ["mixed", "elsewhere", "here"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=lambda v: v.__name__)
+def test_a_padded_slab_s_walk_is_the_gather_form_bit_for_bit(dtype, held_share):
+    """``grouped_expert_product(slabs=True)`` then ``held_rows_sum`` at a width
+    whose slab is padded, against the column chunks and k masked gathers: the
+    same bits, with every pad sublane of y holding NaN bits beforehand (the
+    kernel leaves the pad unwritten: on the chip it holds whatever was there),
+    with no pair held and with every pair held."""
+    x, w, tables, here, tile, (tokens, k, hidden) = padded_case(dtype, held_share)
+    _, row_of_pair, tile_expert, used, _, row_weight = tables
+    slab, filled = gmm.row_slabs(hidden, dtype)
+    assert (slab, filled) == (16, 10)
+    here_np = np.asarray(here).reshape(tokens, k)
+    assert {"mixed": 0 < here_np.mean() < 1, "elsewhere": not here_np.any(),
+            "here": here_np.all()}[held_share]
+    product = functools.partial(
+        gmm.grouped_expert_product, x, w, tile_expert, used, row_weight=row_weight, tile=tile
+    )
+    y = product(slabs=True)
+    assert y.shape == (x.shape[0] * slab, gmm.LANES)
+    poison = np.asarray(y).copy().reshape(x.shape[0], slab, gmm.LANES)
+    nan = np.uint32(0x7FC07FC0).view(poison.dtype)  # as a float32 and as either bfloat16
+    poison[:, filled:] = nan
+    poison[x.shape[0] - 1] = nan  # a row no pair holds
+    got = gmm.held_rows_sum(
+        jnp.asarray(poison.reshape(y.shape)), jnp.where(here, row_of_pair, -1), k=k, width=hidden
+    )
+    rows_of = jnp.where(here, row_of_pair, 0).reshape(tokens, k)
+    want = masked_sums(product(out_chunks=2), rows_of, here.reshape(tokens, k)).astype(dtype)
+    assert got.dtype == want.dtype == dtype and got.shape == (tokens, hidden)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got).all() and np.array_equal(got, want)
+    assert (np.abs(want).max() > 0.1) == (held_share != "elsewhere")
 
 
 def primitives(jaxpr, scope=""):

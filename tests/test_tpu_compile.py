@@ -261,7 +261,7 @@ def test_a_share_s_walk_compiles_at_the_held_share_s_shapes(one_chip, tokens, ro
     k, hidden, width = 10, 2048, 512
     tile = gm.tile_for(tokens * k, 512)
     rows = rows or gm.padded_rows(tokens * k, 129, tile)
-    assert gm.row_slabs(hidden, jnp.bfloat16) == 8 and rows % tile == 0
+    assert gm.row_slabs(hidden, jnp.bfloat16) == (8, 8) and rows % tile == 0
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -333,26 +333,37 @@ def test_causal_attention_kernel_compiles_with_a_selection_at_64_heads(one_chip)
 
 
 @pytest.mark.parametrize(
+    "hidden,width,slabs", [(6144, 2048, (24, 24)), (5120, 1536, (24, 20))],
+    ids=["third-judge-6144", "fourth-judge-5120"],
+)
+@pytest.mark.parametrize(
     "tokens,rows", [(3 * 8192, 49_152), (3 * 8192, None), (3, None)],
     ids=["prefill-usual-load", "prefill-whole-bound", "decode"],
 )
-def test_a_sixteenth_s_experts_compile_at_6144_wide(one_chip, tokens, rows):
-    """16 experts held of a router 256 wide, 8 a token, rows 6144 wide and
-    experts 2048: gate and up fused go in column blocks (two weight windows of
-    the whole width would be 100 MB), the down product leaves a row a slab of
-    three (8, 128) tiles of words with its whole weight in VMEM (the limit is
-    raised by what the window takes over the usual), and ``held_rows_sum``
-    walks slabs of 24 sublanes."""
+def test_a_sixteenth_s_experts_compile_at_their_widths(
+    one_chip, tokens, rows, hidden, width, slabs
+):
+    """16 experts held of a router 256 wide, 8 a token.  Rows 6144 wide and
+    experts 2048 (the third judge): gate and up fused go in column blocks (two
+    weight windows of the whole width would be 100 MB), the down product
+    leaves a row a slab of three (8, 128) tiles of words with its whole weight
+    in VMEM (the limit is raised by what the window takes over the usual), and
+    ``held_rows_sum`` walks slabs of 24 sublanes.  Rows 5120 wide and experts
+    1536 (the fourth judge, ISSUE 40): a bf16 row is 20 sublanes of words, no
+    whole tile, so the slab is PADDED to 24, the down product stores 20 at a
+    stride of 24 and the walk copies all 24 a held pair and turns 20 back
+    (that Mosaic takes a block of whole tiles partly written only this
+    compile shows)."""
     from llm_weighted_consensus_tpu.models import decoder_parts
     from llm_weighted_consensus_tpu.ops import grouped_matmul as gm
 
-    k, hidden, width, held = 8, 6144, 2048, 16
+    k, held = 8, 16
     tile = gm.tile_for(tokens * k, 256)
     whole = gm.padded_rows(tokens * k, held + 1, tile)
     if rows:
         assert decoder_parts.usual_rows(tokens * k, 256, held, tile) == rows < whole
     rows = rows or whole
-    assert gm.row_slabs(hidden, jnp.bfloat16) == 24 and rows % tile == 0
+    assert gm.row_slabs(hidden, jnp.bfloat16) == slabs and rows % tile == 0
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -363,6 +374,7 @@ def test_a_sixteenth_s_experts_compile_at_6144_wide(one_chip, tokens, rows):
             interpret=False,
         )
         y = product(product(x, w_gate, w_up=w_up), w_down, row_weight=weight, slabs=True)
+        assert y.shape == (rows * slabs[0], gm.LANES)
         return gm.held_rows_sum(y, rows_of, k=k, width=hidden, interpret=False)
 
     up = arg((held, hidden, width), jnp.bfloat16)

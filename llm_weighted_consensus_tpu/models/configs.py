@@ -547,3 +547,83 @@ QWEN3_NEXT_TEST_TINY = Qwen3NextConfig(
     num_experts=16,
     num_experts_per_tok=4,
 )
+
+
+@dataclass(frozen=True)
+class AfmoeConfig:
+    """A causal decoder of grouped-query attention of TWO kinds in one stack
+    (``model_type`` ``afmoe``, arcee-ai/Trinity-Large-Preview): a
+    ``sliding_attention`` layer turns its heads (rotary over all of a head's
+    dims) and attends the ``sliding_window`` keys up to and with its own
+    position, a ``full_attention`` layer turns nothing and attends every key
+    before it; every head's output behind an elementwise sigmoid gate, four
+    RMSNorms a layer (before each branch and on each branch's OUTPUT, before
+    the sum), the first ``num_dense_layers`` layers dense, the others sparse
+    with one shared expert: a judge behind ``POST /consensus`` ``scorer:
+    judge`` (models/afmoe.py).
+
+    ``num_layers``, ``num_experts`` and ``vocab_size`` are the published
+    counts.  A checkpoint that names a RUN of the published layers
+    (``model.layers.5`` .. ``model.layers.9``: one pipeline stage of a
+    deployment) is served at those layers, each of the kind ``layer_types``
+    gives its published number; experts 0..E-1 of the router's
+    ``num_experts`` (one chip's share where several share a layer's experts)
+    and the rows of the vocabulary it holds likewise
+    (``afmoe.from_hf_weights``).  The router stays ``num_experts`` wide."""
+
+    vocab_size: int = 200192
+    hidden_size: int = 3072
+    num_layers: int = 60
+    num_heads: int = 48
+    num_kv_heads: int = 8
+    head_dim: int = 128
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 3072
+    num_dense_layers: int = 6
+    num_experts: int = 256
+    num_experts_per_tok: int = 4
+    num_shared_experts: int = 1
+    route_scale: float = 2.448
+    # "sliding_attention" | "full_attention" a layer
+    layer_types: tuple = tuple(
+        "full_attention" if i % 4 == 3 else "sliding_attention" for i in range(60)
+    )
+    sliding_window: int = 4096  # keys a query attends, its own among them
+    rope_theta: float = 1e4
+    rms_norm_eps: float = 1e-5
+    mup_enabled: bool = True  # the embedding times sqrt(hidden_size)
+    # "int8": the dense products (the attention's five, a dense layer's MLP,
+    # the shared expert) through quant.dense_int8; router, routed experts,
+    # embedding and head keep the parameters' dtype
+    quantize: str = "none"
+
+    def slides(self, layer: int) -> bool:
+        return self.layer_types[layer] == "sliding_attention"
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.num_dense_layers
+
+
+# arcee-ai/Trinity-Large-Preview config.json (``afmoe``): six leading dense
+# layers, a full layer every fourth, the others over a window of 4096 keys
+TRINITY_LARGE_PREVIEW = AfmoeConfig()
+# the cell's order (sliding dense; sliding, full, sliding, sliding sparse), six
+# query heads a key head as published, a window shorter than the tests' sequences
+AFMOE_TEST_TINY = AfmoeConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=5,
+    num_heads=12,
+    num_kv_heads=2,
+    head_dim=16,
+    intermediate_size=128,
+    moe_intermediate_size=64,
+    num_dense_layers=1,
+    num_experts=16,
+    num_experts_per_tok=2,
+    layer_types=(
+        "sliding_attention", "sliding_attention", "full_attention", "sliding_attention",
+        "sliding_attention",
+    ),
+    sliding_window=24,
+)

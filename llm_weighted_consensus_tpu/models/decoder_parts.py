@@ -1,6 +1,9 @@
-"""What the judges' decoders share (``models/glm_moe.py``, ``models/qwen3_next.py``):
-RMSNorm, the dense product with its W8A8 twin, SwiGLU, the half-swapped rotary turn,
-the int8 walk over a parameter tree, and the routed experts.
+"""What the judges' decoders share (``models/glm_moe.py``, ``models/qwen3_next.py``,
+``models/afmoe.py``): RMSNorm, the dense product with its W8A8 twin, SwiGLU, the
+half-swapped rotary turn (and the turn of some lanes of every head where they lie),
+the elementwise gate on an attention's output, one decoded row against cached keys of
+fewer heads, the sigmoid router, the int8 walk over a parameter tree, and the routed
+experts.
 
 Routed experts run over the tokens sent to each, as TWO kernels over the
 (token, choice) pairs sorted by expert (``ops/grouped_matmul.py``): gate and
@@ -24,6 +27,7 @@ pair, where k gathers a token would fetch three rows in four to mask them.
 
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -31,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import grouped_matmul as _gmm
+from ..ops import rotary as _rotary
 
 
 def rms(x, weight, eps: float):
@@ -73,6 +78,71 @@ def rope(x, cos, sin):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     ).astype(x.dtype)
+
+
+def turn_heads(x, cos, sin, heads: int, first: int):
+    """x [..., heads * hd] -> the same, lanes [first, first + dims) of every
+    head turned; cos, sin [..., dims / 2], a position's.  Heads of whole
+    128-lane columns over [b, s, width] are turned where they lie
+    (``ops/rotary.py``); any other shape (a tiny preset, a decode step's rows)
+    is cut apart, turned and put together again."""
+    dims = 2 * cos.shape[-1]
+    if cos.shape[:-1] == x.shape[1:-1] and _rotary.fits(x.shape, heads, first, dims):
+        return _rotary.turn_lanes(x, cos, sin, heads=heads, first=first)
+    xh = x.reshape(*x.shape[:-1], heads, -1)
+    turned = rope(xh[..., first:first + dims], cos[..., None, :], sin[..., None, :])
+    return jnp.concatenate(
+        [xh[..., :first], turned, xh[..., first + dims:]], axis=-1
+    ).reshape(x.shape)
+
+
+def gated(ctx, gate):
+    """The elementwise gate: ctx · sigmoid(gate), lane for lane, in float32."""
+    return (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
+
+
+def attend_cached(q, k_all, v_all, lens, kv_heads: int, window: bool = False):
+    """One decoded row a call against its cached keys and its own: q [b, heads
+    * hd] at position ``lens[b]``, k_all and v_all [b, slots, kv_heads * hd]
+    (a key head serving ``heads / kv_heads`` query heads), the row's own key
+    and value in the LAST slot -> context [b, kv_heads, heads / kv_heads, hd].
+    The slots before it hold positions 0 .. slots - 2, of which those >=
+    ``lens[b]`` are padding; or, with ``window``, the ``slots - 1`` positions
+    before ``lens[b]`` (slot j is position ``lens[b] - (slots - 1) + j``; those
+    before position 0 are padding).  Scores and softmax in float32."""
+    b, slots = k_all.shape[:2]
+    hd = k_all.shape[-1] // kv_heads
+    q = q.reshape(b, kv_heads, -1, hd)
+    scores = jnp.einsum(
+        "bgrd,btgd->bgrt", q, k_all.reshape(b, slots, kv_heads, hd),
+        preferred_element_type=jnp.float32,
+    )
+    t = jnp.arange(slots)[None, :]
+    if window:  # the slots at or past position 0, and itself
+        seen = t >= slots - 1 - lens[:, None]
+    else:
+        seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
+    scores = jnp.where(seen[:, None, None, :], scores / math.sqrt(hd), -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum(
+        "bgrt,btgd->bgrd", probs, v_all.reshape(b, slots, kv_heads, hd),
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
+
+
+def route_sigmoid(h, p: dict, k: int, scale: float):
+    """h [t, hidden] -> (experts [t, k] int32, weights [t, k] float32): every
+    expert scored ``sigmoid(W_r · h)`` in float32, the top k of score +
+    ``p["bias"]`` (the bias chooses, it does not weigh), the chosen weighed by
+    their unbiased scores over their sum, times ``scale``."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST
+    )
+    score = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(score + p["bias"], k)
+    weight = jnp.take_along_axis(score, chosen, axis=1)
+    weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+    return chosen.astype(jnp.int32), weight * scale
 
 
 def quantize_dense(params: dict) -> dict:

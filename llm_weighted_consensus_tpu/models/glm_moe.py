@@ -130,7 +130,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ops import rotary
 from ..ops.causal_attention import (
     band_pairs, causal_attention_blockwise, window_attention_blockwise,
 )
@@ -143,23 +142,9 @@ from .decoder_parts import experts_grouped, layers_past_usual, quantize_dense  #
 from .decoder_parts import rms as _rms
 from .decoder_parts import rope as _rope
 from .decoder_parts import rope_angles as _rope_angles
+from .decoder_parts import route_sigmoid
 from .decoder_parts import swiglu as _swiglu
-
-
-def _turn_heads(x, cos, sin, heads: int, first: int):
-    """x [..., heads * hd] -> the same, lanes [first, first + dims) of every
-    head turned; cos, sin [..., dims / 2], a position's.  Heads of whole
-    128-lane columns over [b, s, width] are turned where they lie
-    (``ops/rotary.py``); any other shape (a tiny preset, a decode step's rows)
-    is cut apart, turned and put together again."""
-    dims = 2 * cos.shape[-1]
-    if cos.shape[:-1] == x.shape[1:-1] and rotary.fits(x.shape, heads, first, dims):
-        return rotary.turn_lanes(x, cos, sin, heads=heads, first=first)
-    xh = x.reshape(*x.shape[:-1], heads, -1)
-    turned = _rope(xh[..., first:first + dims], cos[..., None, :], sin[..., None, :])
-    return jnp.concatenate(
-        [xh[..., :first], turned, xh[..., first + dims:]], axis=-1
-    ).reshape(x.shape)
+from .decoder_parts import turn_heads as _turn_heads
 
 
 def _scaled(weight, scale: float):
@@ -364,14 +349,7 @@ def _attention_decode(
 
 def route(h, p: dict, config: GlmMoeLiteConfig):
     """h [t, hidden] -> (experts [t, k] int32, weights [t, k] float32)."""
-    logits = jnp.dot(
-        h.astype(jnp.float32), p["router"], precision=jax.lax.Precision.HIGHEST
-    )
-    score = jax.nn.sigmoid(logits)
-    _, chosen = jax.lax.top_k(score + p["bias"], config.num_experts_per_tok)
-    weight = jnp.take_along_axis(score, chosen, axis=1)
-    weight = weight / jnp.sum(weight, axis=1, keepdims=True)
-    return chosen.astype(jnp.int32), weight * config.routed_scaling_factor
+    return route_sigmoid(h, p, config.num_experts_per_tok, config.routed_scaling_factor)
 
 
 def _held(p: dict, config: GlmMoeLiteConfig):

@@ -8,7 +8,7 @@ is a few calls of one causal decoder over the same candidates under
 differently seeded ballots, and what an upstream judge's ``top_logprobs``
 would have carried is read from the decoder's own head.
 
-Four decoders serve (``JUDGE_PRESETS``; the preset's configuration class
+Five decoders serve (``JUDGE_PRESETS``; the preset's configuration class
 says which module): ``models/glm_moe.py`` runs ``glm-4.7-flash`` (latent
 attention, every expert held), ``glm-5.2`` (a learned sparse selection in
 front of latent attention, a share of the router's experts held) and
@@ -17,8 +17,12 @@ kind: full layers behind an indexer each, sliding layers of another geometry
 over a window of 513 keys, a sigmoid gate a head, rescaled latents; a
 windowed latent cache beside the three kinds the second has), and
 ``models/qwen3_next.py`` the fourth (gated delta-rule layers three to one
-with gated full attention, a share held).  The panel's protocol is no part
-of any: ``judge_panel`` below is ONE jitted program over what a decoder
+with gated full attention, a share held), and ``models/afmoe.py`` the fifth,
+``trinity-large-preview`` (grouped-query attention of two kinds: sliding layers
+that turn their heads over a window of 4096 keys, full layers that turn
+nothing; an elementwise gate, four norms a layer; a windowed cache of keys and
+values beside the whole-length one; a share held).  The panel's protocol is no
+part of any: ``judge_panel`` below is ONE jitted program over what a decoder
 module gives,
 
   ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h], a
@@ -71,11 +75,11 @@ from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
 from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
-from . import glm_moe, qwen3_next
+from . import afmoe, glm_moe, qwen3_next
 from .configs import (
-    DOTS3_NOTE_PREV, DOTS3_TEST_TINY, GLM_4_7_FLASH, GLM_5_2, GLM_DSA_TEST_TINY,
-    GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY, GlmMoeLiteConfig,
-    Qwen3NextConfig,
+    AFMOE_TEST_TINY, DOTS3_NOTE_PREV, DOTS3_TEST_TINY, GLM_4_7_FLASH, GLM_5_2,
+    GLM_DSA_TEST_TINY, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY,
+    TRINITY_LARGE_PREVIEW, AfmoeConfig, GlmMoeLiteConfig, Qwen3NextConfig,
 )
 from .tokenizer import BaseTokenizer, load_tokenizer
 
@@ -88,8 +92,10 @@ JUDGE_PRESETS = {
     "dots3-test-tiny": DOTS3_TEST_TINY,
     "qwen3-next-80b-a3b": QWEN3_NEXT_80B_A3B,
     "qwen3-next-test-tiny": QWEN3_NEXT_TEST_TINY,
+    "trinity-large-preview": TRINITY_LARGE_PREVIEW,
+    "afmoe-test-tiny": AFMOE_TEST_TINY,
 }
-_DECODERS = {GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next}
+_DECODERS = {GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next, AfmoeConfig: afmoe}
 DEFAULT_PANEL = ((0, 1.0), (1, 1.0), (2, 1.0))  # (ballot seed, weight) a call
 MAX_PANEL = 8
 _LETTERS = len(ALPHABET)

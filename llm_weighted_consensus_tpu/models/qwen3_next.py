@@ -70,9 +70,10 @@ from ..ops.causal_attention import causal_attention_blockwise
 from ..ops.gated_delta import gated_delta_rule, gated_delta_step
 from .configs import Qwen3NextConfig
 from .decoder_parts import (  # noqa: F401  (quantize_dense: the panel's protocol)
-    dense, experts_grouped, layers_past_usual, quantize_dense, rms, rope, rope_angles,
-    swiglu,
+    attend_cached, dense, experts_grouped, layers_past_usual, quantize_dense, rms, rope,
+    rope_angles, swiglu,
 )
+from .decoder_parts import gated as _gated
 
 
 def _rms0(x, weight, eps: float):
@@ -198,10 +199,6 @@ def _qkv(h, p: dict, positions, config: Qwen3NextConfig):
     return q, k, dense(h, p["v"]), dense(h, p["gate"])
 
 
-def _gated(ctx, gate):
-    return (ctx.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(ctx.dtype)
-
-
 def _attention_prefill(h, p: dict, config: Qwen3NextConfig):
     """h [b, s, hidden] -> (the layer's output, (rotated keys, values))."""
     s = h.shape[1]
@@ -226,20 +223,7 @@ def _attention_decode(h, p: dict, lens, cache, config: Qwen3NextConfig):
         k_all = jnp.concatenate([cache[0], k_new[:, None, :]], axis=1)
         v_all = jnp.concatenate([cache[1], v_new[:, None, :]], axis=1)
     with jax.named_scope("causal_attention"):
-        slots = k_all.shape[1]
-        q = q.reshape(b, kv, heads // kv, hd)
-        scores = jnp.einsum(
-            "bgrd,btgd->bgrt", q, k_all.reshape(b, slots, kv, hd),
-            preferred_element_type=jnp.float32,
-        )
-        t = jnp.arange(slots)[None, :]
-        seen = (t < lens[:, None]) | (t == slots - 1)  # the cache, and itself
-        scores = jnp.where(seen[:, None, None, :], scores / math.sqrt(hd), -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum(
-            "bgrt,btgd->bgrd", probs, v_all.reshape(b, slots, kv, hd),
-            preferred_element_type=jnp.float32,
-        ).astype(h.dtype)
+        ctx = attend_cached(q, k_all, v_all, lens, kv)
     with jax.named_scope("attn_out"):
         return dense(_gated(ctx.reshape(b, heads * hd), gate), p["o"])
 

@@ -567,6 +567,7 @@ def live_metrics():
             return {"backend_compiles": 1, "backend_compile_s": 1.5}
 
     saved = dict(startup._SECONDS)
+    allowed = os.environ.get("LWC_ALLOW_RANDOM_PARAMS")
     os.environ["LWC_ALLOW_RANDOM_PARAMS"] = "1"
     try:
         config = Config.from_env(
@@ -583,7 +584,12 @@ def live_metrics():
 
         return go(with_client(app, run))
     finally:
-        os.environ.pop("LWC_ALLOW_RANDOM_PARAMS", None)
+        # as it was: conftest sets it for the session, and the files this
+        # worker runs next (``--dist loadfile``) build embedders under it
+        if allowed is None:
+            os.environ.pop("LWC_ALLOW_RANDOM_PARAMS", None)
+        else:
+            os.environ["LWC_ALLOW_RANDOM_PARAMS"] = allowed
         startup._SECONDS.clear()
         startup._SECONDS.update(saved)
 

@@ -534,3 +534,112 @@ def test_the_fourth_judge_s_panel_compiles_and_fits_the_chip(one_chip, monkeypat
     assert 5.2e9 < weights < 5.3e9 and memory.argument_size_in_bytes >= weights
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < held < 14.0e9, held
+
+
+# -- the fifth judge: grouped-query attention of two kinds at 16k slots (ISSUE 41) --
+
+
+def test_the_window_kernel_compiles_at_the_fifth_judge_s_shape(one_chip):
+    """A sliding layer's attention: 48 query heads on 8 key heads of 128 lanes
+    over 3 x 16,384 slots under a window of 4096, at the blocks the window
+    gives (2048: a query block meets three key blocks, the old edge's a whole
+    masked tile, the diagonal's in stripes).  Mosaic takes the group of six,
+    both edges and the blocks of 2048 inside the kernel's VMEM limit; the
+    kernel runs under its own name, and nothing stands around it.  The full
+    layer's causal kernel at the same heads likewise."""
+    from llm_weighted_consensus_tpu.ops import causal_attention as ca
+
+    q = jax.ShapeDtypeStruct((3, 16384, 48 * 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((3, 16384, 8 * 128), jnp.bfloat16, sharding=one_chip)
+    assert ca.window_block(16384, 4096) == 2048 and len(ca._steps(16384, 2048, 2048, 4096)[0]) == 21
+    for name, call in (
+        ("window_attention_blockwise", lambda q, k, v: ca.window_attention_blockwise(
+            q, k, v, heads=48, kv_heads=8, scale=128**-0.5, window=4096, interpret=False)),
+        ("causal_attention_blockwise", lambda q, k, v: ca.causal_attention_blockwise(
+            q, k, v, heads=48, kv_heads=8, scale=128**-0.5, interpret=False)),
+    ):
+        compiled = jax.jit(call).lower(q, kv, kv).compile()
+        found = instructions(compiled.as_text())
+        calls = [n for n, op, _ in found if op == "custom-call"]
+        assert len(calls) == 1 and calls[0].startswith(name), calls
+        assert not [(n, op) for n, op, _ in found if op in ("copy", "transpose", "fusion")]
+
+
+@pytest.mark.parametrize("turn", [False, True], ids=["full", "sliding"])
+def test_the_head_norm_compiles_in_place_behind_the_query_product(one_chip, monkeypatch, turn):
+    """``afmoe._heads`` behind the query product at a panel's shape, [3 x
+    16384, 48 x 128]: Mosaic takes the block (2048 rows of one head's column,
+    a lane reduction a row, aliased in to out), the kernel keeps its name, and
+    beside the product and the kernel nothing of the queries' size is made
+    (written as a reshape to [b, s, heads, 128] the norm made XLA lay out three
+    float32 arrays of that size)."""
+    from llm_weighted_consensus_tpu.models import afmoe
+    from llm_weighted_consensus_tpu.ops import head_norm
+
+    monkeypatch.setattr(head_norm, "_interpret", lambda: False)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def normed(h, w, scale):
+        q = jnp.einsum("bsi,io->bso", h, w, preferred_element_type=jnp.float32).astype(h.dtype)
+        return afmoe._heads(q, scale, jnp.arange(16384), configs.TRINITY_LARGE_PREVIEW, turn)
+
+    compiled = jax.jit(normed).lower(
+        arg((3, 16384, 3072), jnp.bfloat16), arg((3072, 6144), jnp.bfloat16),
+        arg((128,), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    calls = [n for n, op, _ in instructions(text) if op == "custom-call"]
+    assert len([n for n in calls if n.startswith("head_norm_turn")]) == 1, calls
+    wide = re.findall(r"= (?:bf16|f32)\[3,16384,(?:6144|48,128)\]\S* ([\w\-]+)\(", text)
+    wide = sorted(op for op in wide if op not in ("convolution", "convert", "parameter"))
+    assert wide == ["custom-call", "fusion"], wide
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20  # the two tables
+
+
+def test_the_fifth_judge_s_panel_compiles_and_fits_the_chip(one_chip, monkeypatch):
+    """``judge_panel`` for ``trinity-large-preview`` as its cell cuts it
+    (published layers 5-9, 32 of 256 experts, an eighth of the vocabulary, bf16)
+    over a panel of 3 x 16,384 slots at depth 2: every kernel of both kinds of
+    layer is taken by the chip's compiler in ONE program, and its count of the
+    device's memory (arguments + temporaries) is under the issue's 14.0 GB, so
+    the rule that would have held 16 experts did not fire (8.65 + 4.19 = 12.84
+    GB, PERF.md).  A count of the compiler's, not a reading of the chip."""
+    from llm_weighted_consensus_tpu.models import afmoe, judge
+    from llm_weighted_consensus_tpu.ops import causal_attention, grouped_matmul, head_norm
+
+    for module in (causal_attention, grouped_matmul, head_norm):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    preset = configs.TRINITY_LARGE_PREVIEW
+    cut = replace(
+        preset, num_layers=5, num_dense_layers=1, vocab_size=25024,
+        layer_types=preset.layer_types[5:10],
+    )
+    shapes = jax.eval_shape(
+        lambda: afmoe.init_params(jax.random.PRNGKey(0), cut, dtype=jnp.bfloat16, held=32)
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), shapes)
+    b, s, letters = 3, 16384, 20
+    compiled = judge.judge_panel.lower(
+        params, arg((b, s), jnp.int32), arg((b,), jnp.int32), arg((letters,), jnp.int32),
+        arg((b, letters), jnp.bool_), arg((b, letters, letters), jnp.bool_),
+        decoder=afmoe, config=cut, depth=2,
+    ).compile()
+    kernels = [n for n, op, _ in instructions(compiled.as_text()) if op == "custom-call"]
+    for name, count in (
+        ("window_attention_blockwise", 4), ("causal_attention_blockwise", 1),
+        ("head_norm_turn", 10),
+    ):
+        assert sum(k.startswith(name) for k in kernels) == count, (name, kernels)
+    assert any(k.startswith("grouped_expert_product") for k in kernels)
+    assert any(k.startswith("held_rows_sum") for k in kernels)  # 3072 x bf16: 12 sublanes in a slab of 16
+    memory = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(shapes))
+    assert weights == 8_650_101_248 and memory.argument_size_in_bytes >= weights
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 0.25 * 16e9 < held < 14.0e9, held

@@ -56,8 +56,8 @@ A sliding layer's prefill runs ``window_attention_blockwise``, a full layer's
 under its own jitted name), both with ``kv_heads``: a query head finds its key
 head's block through the index map and no key is repeated in memory.  At
 16,384 slots and a window of 4096 the window's blocks are 2048 and a query
-block meets three key blocks (``work_over_window`` 1.25: the old edge's block
-a whole masked tile, the diagonal's in stripes).  Heads of one 128-lane column
+block meets three key blocks (``work_over_window`` 1.0625: the old edge's
+block and the diagonal's both in stripes).  Heads of one 128-lane column
 are normalised and turned where the product wrote them, in one pass
 (``ops/head_norm.py``).
 

@@ -46,14 +46,28 @@ The schedule follows from (s, block_q, block_k, hd) and nothing else:
   tiles of 1024.
 - With a window, a query block's steps begin at the block that holds the
   first key of its first query (``_steps``), the running state is reset
-  there, and the block the band's OLD edge crosses is one whole masked tile
-  (both edges in one mask).  The blocks follow the window (``window_block``:
-  the largest inside its reach; 512 for 513 keys, where a query block meets
-  exactly two key blocks), because today's 2048 would multiply 7.2 times the
-  band.  ``work_over_window`` is the count beside ``work_over_causal``:
-  pairs multiplied over the ``band_pairs`` kept; at 8192 slots and 513 keys
-  1.74 at blocks of 512 (the diagonal in stripes, 15 whole edge tiles), 1.50
-  at 256, 3.86 at 1024.
+  there, and the block the band's OLD edge crosses is walked as the
+  diagonal's is, in the same stripes mirrored: a stripe of query rows skips
+  the keys before the chunk that holds its first row's first key, masks the
+  one or two chunks the edge passes through (both edges in one mask, by
+  position) and multiplies the chunks behind them unmasked
+  (``_edge_parts``).  Where that edge lies in the tile follows from the
+  shapes alone: with square blocks the distance qi - ki says which block a
+  step meets, and one or two distances are the old edge's
+  (``_edge_offsets``).  Any other shape (a block that does not split, a
+  window narrower than the block) stays one whole masked tile.  A row may
+  see NO key of that block (the last of a query block where the window is
+  whole blocks): its maximum stays ``_NEG``, ``exp2(0)`` puts ones into its
+  sums, and the next block's first real key wipes them (alpha = 0); every
+  row meets its own key in a later step.  The blocks follow the window
+  (``window_block``: the largest inside its reach; 512 for 513 keys, where a
+  query block meets exactly two key blocks), because today's 2048 would
+  multiply 7.2 times the band.  ``work_over_window`` is the count beside
+  ``work_over_causal``: pairs multiplied over the ``band_pairs`` kept.  At
+  16,384 slots and 4096 keys 1.0625 at blocks of 2048 (1.25 with the old
+  edge's block whole); at 8192 slots and 513 keys 1.50 at blocks of 512
+  (1.74 with 15 whole edge tiles), 1.50 at 256 (whole tiles of one stripe),
+  3.86 at 1024.
 - A score costs a subtract, a multiply and an ``exp2``: the maximum is
   taken over raw scores (scale > 0) and scale * log2(e) is one constant.
   Where the shapes allow, the running maximum and sum are kept once a lane
@@ -156,20 +170,49 @@ def band_pairs(seq: int, window: int) -> int:
     return w * (w + 1) // 2 + (seq - w) * w
 
 
+def _edge_offsets(seq: int, block_q: int, block_k: int, window: int) -> tuple[int, ...]:
+    """``qi - ki`` of the steps whose key block the band's OLD edge crosses
+    (wholly below the diagonal, not wholly inside the band).  With square
+    blocks that distance alone says which block a step meets, and there are
+    one or two such distances, fixed by the shapes."""
+    qi, ki = _steps(seq, block_q, block_k, window)
+    below = ki * block_k + block_k - 1 <= qi * block_q
+    inside = ki * block_k > qi * block_q + block_q - 1 - window
+    return tuple(np.unique((qi - ki)[below & ~inside]).tolist())
+
+
+def _edge_parts(r0: int, stripe: int, block_k: int, d: int) -> list:
+    """Column ranges of the old edge's key block that the stripe of query rows
+    [r0, r0 + stripe) meets, as ``update`` takes them; a row sees the columns
+    ``col > row + d``.  The chunks before the one that holds the first row's
+    first key are skipped, the one or two the edge passes through are masked,
+    the rest are one unmasked range; none where no row sees a key."""
+    skipped = max(r0 + d + 1, 0) // stripe * stripe
+    whole = min(max(-(-(r0 + stripe + d) // stripe) * stripe, 0), block_k)
+    parts = [(c0, stripe, "tile") for c0 in range(skipped, whole, stripe)]
+    return parts + ([(whole, block_k - whole, None)] if whole < block_k else [])
+
+
 def work_over_window(seq: int, block_q: int, block_k: int, window: int) -> float:
     """Score pairs the kernel multiplies over the ``band_pairs`` the window
-    keeps.  The block the diagonal crosses is walked as ``work_over_causal``
-    says (in stripes where ``stripe_for`` splits it and the window covers the
-    block); every other step is a whole tile."""
+    keeps.  Where ``stripe_for`` splits the block and the window covers it,
+    the block the diagonal crosses is walked as ``work_over_causal`` says and
+    the block the old edge crosses in the same stripes mirrored
+    (``_edge_parts``); every other step is a whole tile."""
     qi, ki = _steps(seq, block_q, block_k, window)
     stripe = stripe_for(block_q, block_k) if block_q <= window else 0
-    crossed = int((ki * block_k + block_k - 1 > qi * block_q).sum())
-    pairs = (len(qi) - crossed) * block_q * block_k
-    if stripe:
-        n = block_q // stripe
-        pairs += crossed * stripe * stripe * n * (n + 1) // 2
-    else:
-        pairs += crossed * block_q * block_k
+    tile = block_q * block_k
+    if not stripe:
+        return len(qi) * tile / band_pairs(seq, window)
+    n = block_q // stripe
+    by_offset = {0: stripe * stripe * n * (n + 1) // 2}  # the diagonal's block
+    for delta in _edge_offsets(seq, block_q, block_k, window):
+        by_offset[delta] = stripe * sum(
+            cols
+            for r0 in range(0, block_q, stripe)
+            for _, cols, _ in _edge_parts(r0, stripe, block_k, delta * block_q - window)
+        )
+    pairs = sum(by_offset.get(delta, tile) for delta in (qi - ki).tolist())
     return pairs / band_pairs(seq, window)
 
 
@@ -193,7 +236,8 @@ def work_over_causal(
 
 
 def _kernel(
-    qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, band, window=0
+    qi_ref, ki_ref, q_ref, k_ref, v_ref, *rest, scale, bq, bk, stripe, band, window=0,
+    edges=(),
 ):
     # with a selection, its tile comes behind v: 1 where the query may meet the key
     keep_ref = rest[0] if len(rest) == 5 else None
@@ -272,12 +316,24 @@ def _kernel(
     if window:
         # ... and wholly inside the band when its first column is within the
         # window of the query block's last row; the block the band's old edge
-        # crosses is masked as the diagonal's is, one whole tile
+        # crosses goes as the diagonal's does: in stripes where it splits
+        # (``edges``: the distances qi - ki of such blocks), else one masked tile
         inside = below & (ki * bk > qi * bq + bq - 1 - window)
 
-        @pl.when(below & jnp.logical_not(inside))
-        def _():
-            update(0, bq, [(0, bk, "tile")])
+        if not edges:
+
+            @pl.when(below & jnp.logical_not(inside))
+            def _():
+                update(0, bq, [(0, bk, "tile")])
+
+        for delta in edges:  # the diagonal's stripes mirrored: a row sees col > row + d
+
+            @pl.when(qi - ki == delta)
+            def _(d=delta * bq - window):
+                for r0 in range(0, bq, stripe):
+                    parts = _edge_parts(r0, stripe, bk, d)
+                    if parts:
+                        update(r0, stripe, parts)
 
     @pl.when(inside)
     def _():
@@ -335,6 +391,8 @@ def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpre
     # the diagonal's block goes in stripes where the window covers the block
     stripe = stripe_for(bq, bk) if not window or bq <= window else 0
     windowed = {"window": window} if window else {}
+    if window and stripe:  # ... and so does the block the old edge crosses
+        windowed["edges"] = _edge_offsets(s, bq, bk, window)
 
     def q_index(bi, h, step, qi_of_step, ki_of_step):
         return bi, qi_of_step[step], h
@@ -415,9 +473,11 @@ def window_attention_blockwise(
     kernel body; what differs is the table of steps (``_steps``: only the
     pairs of blocks the band touches; ``work_over_window`` says what that
     multiplies over the band), a second masked edge (the block the band's
-    old edge crosses is one whole masked tile, as an unsplit diagonal block
-    is) and the blocks, which follow the window (``window_block``).  Under
-    its own jitted name, so that a device trace tells the two apart."""
+    old edge crosses goes in the diagonal's stripes mirrored where the block
+    splits and the window covers it, else as one whole masked tile, as an
+    unsplit diagonal block does) and the blocks, which follow the window
+    (``window_block``).  Under its own jitted name, so that a device trace
+    tells the two apart."""
     return _attend(
         q, k, v, None, heads=heads, scale=scale, kv_heads=kv_heads, block_q=block_q,
         block_k=block_k, interpret=interpret, window=window,

@@ -415,8 +415,9 @@ def test_the_eight_shares_add_up_to_the_uncut_reference_layer():
 def test_window_attention_with_six_query_heads_a_key_head_is_the_einsum(s, window, block, heads, kv, hd):
     """The cell's geometry scaled down in proportion: the window is TWO blocks
     (``window_block`` gives the largest under it), so a query block meets
-    three key blocks: the old edge's (one whole masked tile), one wholly
-    inside the band, the diagonal's; a group of SIX query heads a key head."""
+    three key blocks: the old edge's (one whole masked tile at blocks this
+    small), one wholly inside the band, the diagonal's; a group of SIX query
+    heads a key head."""
     assert attn.window_block(s, window) == block
     qi, ki = attn._steps(s, block, block, window)
     assert max(np.bincount(qi)) == 3 and (qi - ki).max() == 2
@@ -448,20 +449,122 @@ def test_the_diagonal_s_stripes_hold_under_a_window_wider_than_the_block():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
 
 
+@pytest.mark.parametrize(
+    "s,window,block,heads,kv,hd,edges",
+    [
+        (2048, 1024, 512, 6, 1, 8, (2,)),  # the cell in small: a window of exactly two blocks
+        (1536, 513, 512, 2, 1, 8, (1,)),  # a window of a block and a key (the fourth judge's)
+        (2048, 700, 512, 2, 1, 8, (1, 2)),  # no multiple of the stripe: the edge through two chunks
+        (2048, 512, 512, 1, 1, 16, (1,)),  # a window of one block: the edge block's diagonal masked
+        (1536, 300, 512, 2, 1, 8, ()),  # a window narrower than the block: one whole masked tile
+        (1024, 513, 256, 2, 1, 8, ()),  # blocks of one stripe: one whole masked tile
+    ],
+)
+def test_the_old_edge_s_stripes_are_the_einsum(s, window, block, heads, kv, hd, edges):
+    """The block the band's old edge crosses, walked in the diagonal's stripes
+    mirrored wherever the block splits and the window covers it (``edges``:
+    the distances qi - ki of such blocks), and one whole masked tile
+    elsewhere: the same context either way."""
+    stripe = attn.stripe_for(block, block) if block <= window else 0
+    assert (attn._edge_offsets(s, block, block, window) if stripe else ()) == edges
+    kw = dict(heads=heads, kv_heads=kv, scale=hd**-0.5, window=window)
+    rng = np.random.default_rng(s + window)
+    q = jnp.asarray(rng.standard_normal((1, s, heads * hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, s, kv * hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, s, kv * hd)), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda *a: attn.window_attention_blockwise(*a, block_q=block, block_k=block, **kw)
+    )(q, k, v))
+    assert (f"bool[{block},{block}]" in text) == (not edges)  # no whole tile of scores is masked
+    want = attn.causal_attention_einsum(q, k, v, **kw)
+    got = attn.window_attention_blockwise(q, k, v, block_q=block, block_k=block, **kw)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-6
+
+
+def test_a_row_that_meets_no_key_of_the_edge_block_is_wiped_by_the_next():
+    """Where the window is whole blocks, the last row of a query block sees no
+    key of its old-edge block: its running maximum stays masked there and the
+    softmax of nothing puts ones into its sums, which the next block's first
+    real key wipes (alpha = 0).  Values of 1e4 in the edge block would show in
+    that row if anything of them were left."""
+    s, window, block, hd = 1536, 1024, 512, 8
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, s, hd)), jnp.float32) for _ in range(3))
+    v = v.at[:, :block].set(1e4)
+    kw = dict(heads=1, scale=hd**-0.5, window=window)
+    assert attn._edge_parts(block - 256, 256, block, 0) == [(block - 256, 256, "tile")]
+    got = np.asarray(attn.window_attention_blockwise(q, k, v, **kw))
+    want = np.asarray(attn.causal_attention_einsum(q, k, v, **kw))
+    assert np.abs(want[0, s - 1]).max() < 1 < np.abs(want[0, s - 2]).max()  # its oldest key is 512
+    assert np.abs(got[0, s - 1] - want[0, s - 1]).max() < 2e-6
+    assert np.abs(got - want).max() < 2e-6 * 1e4
+
+
+def _pairs_multiplied_by_branch(jaxpr):
+    """Of the window kernel's traced body: the q.k score pairs each
+    ``pl.when`` branch multiplies, in the body's order."""
+    def dots(jaxpr):
+        found = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                (contract_l, contract_r), _ = eqn.params["dimension_numbers"]
+                if (tuple(contract_l), tuple(contract_r)) == ((1,), (1,)):  # q.k, not p.v
+                    found += int(np.prod(eqn.outvars[0].aval.shape))
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) else (value,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        found += dots(inner)
+        return found
+
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    return [dots(e.params["branches"][1].jaxpr) for e in call.params["jaxpr"].eqns if e.primitive.name == "cond"]
+
+
+@pytest.mark.parametrize(
+    "s,window,block,edge_steps",
+    [(16384, 4096, 2048, {2: 6}), (8192, 513, 512, {1: 15}), (2048, 700, 512, {1: 3, 2: 2})],
+)
+def test_work_over_window_is_what_the_traced_kernel_multiplies(s, window, block, edge_steps):
+    """The count read off the kernel's own trace: the shapes of the q.k
+    products under each ``pl.when``, times the steps of the table that take
+    that branch, is ``work_over_window`` x ``band_pairs``.  At the fifth
+    judge's shape 62,390,272 pairs for the band's 58,722,304 (1.0625)."""
+    x = jax.ShapeDtypeStruct((1, s, 8), jnp.float32)
+    closed = jax.make_jaxpr(
+        lambda q, k, v: attn.window_attention_blockwise.__wrapped__(q, k, v, heads=1, scale=1.0, window=window)
+    )(x, x, x)
+    by_branch = [n for n in _pairs_multiplied_by_branch(closed.jaxpr) if n]
+    qi, ki = attn._steps(s, block, block, window)
+    steps = np.bincount(qi - ki)
+    assert {d: int(steps[d]) for d in attn._edge_offsets(s, block, block, window)} == edge_steps
+    inside = [d for d in range(1, len(steps)) if d not in edge_steps]
+    # the body's order: the old edge's block by its distance, a block inside the band, the diagonal's
+    assert len(by_branch) == len(edge_steps) + 2
+    total = sum(by_branch[i] * n for i, n in enumerate(edge_steps.values()))
+    total += by_branch[-2] * int(sum(steps[d] for d in inside)) + by_branch[-1] * int(steps[0])
+    assert by_branch[-2] == block * block and by_branch[-1] < block * block
+    assert total == round(attn.work_over_window(s, block, block, window) * attn.band_pairs(s, window))
+    if s == 16384:
+        assert total == 62_390_272 and by_branch[0] == by_branch[-1] == 36 * 256 * 256
+
+
 def test_the_cell_s_blocks_steps_and_work_are_pinned():
     """16,384 slots under a window of 4096: blocks of 2048, three key blocks a
-    query block (21 steps a head for the causal kernel's 36), 1.25 times the
-    band's pairs multiplied (the old edge's block whole, the diagonal's in
-    stripes of 256), the band 43.7% of the causal pairs."""
+    query block (21 steps a head for the causal kernel's 36), 1.0625 times the
+    band's pairs multiplied (the old edge's block and the diagonal's both in
+    stripes of 256: 36 chunks of 64; 1.25 with the edge's block whole), the
+    band 43.7% of the causal pairs."""
     assert attn.window_block(16384, 4096) == 2048
     qi, ki = attn._steps(16384, 2048, 2048, 4096)
     assert len(qi) == 21 and len(attn._steps(16384, 2048, 2048)[0]) == 36
     assert np.bincount(qi).tolist() == [1, 2, 3, 3, 3, 3, 3, 3]
-    assert attn.work_over_window(16384, 2048, 2048, 4096) == pytest.approx(1.24996, abs=1e-5)
+    assert attn.work_over_window(16384, 2048, 2048, 4096) == pytest.approx(62_390_272 / 58_722_304, rel=1e-9)
     assert attn.work_over_causal(16384, 2048, 2048) == pytest.approx(1.01556, abs=1e-5)
     assert attn.band_pairs(16384, 4096) == 58_722_304
     assert attn.band_pairs(16384, 4096) / (16384 * 16385 // 2) == pytest.approx(0.43749, abs=1e-5)
     assert attn.window_block(8192, 513) == 512  # the fourth judge's blocks are what they were
+    assert attn.work_over_window(8192, 512, 512, 513) == pytest.approx(1.49708, abs=1e-5)
 
 
 @pytest.mark.parametrize("turn", [False, True], ids=["full", "sliding"])

@@ -84,7 +84,7 @@ from ..ops.causal_attention import (
 from .configs import AfmoeConfig
 from .decoder_parts import (  # noqa: F401  (quantize_dense: the panel's protocol)
     attend_cached, dense, experts_grouped, gated, layers_past_usual, quantize_dense, rms,
-    rope_angles, route_sigmoid, swiglu, turn_heads,
+    rope_angles, route_sigmoid, swiglu, tiles_laid_and_in_use, turn_heads,
 )
 
 # -- attention, of either kind -----------------------------------------------------
@@ -283,6 +283,13 @@ def whole_bound_layers(load, config: AfmoeConfig) -> int:
     if load.size and load.shape[1] == config.num_experts:
         return 0
     return layers_past_usual(load, config.num_experts)
+
+
+def expert_tiles(load, config: AfmoeConfig) -> tuple[int, int]:
+    """Of a dispatch's sparse layers, the row tiles their layouts laid and
+    those that hold a pair (``decoder_parts.tiles_laid_and_in_use``)."""
+    share = bool(load.size) and load.shape[1] != config.num_experts
+    return tiles_laid_and_in_use(load, config.num_experts, share)
 
 
 # -- parameters -------------------------------------------------------------------------

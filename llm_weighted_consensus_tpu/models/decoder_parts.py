@@ -271,17 +271,43 @@ def experts_grouped(h, chosen, weight, p: dict, experts: int, held: int | None =
     return jax.lax.cond(fits, lambda: over(usual), lambda: over(whole)), counts
 
 
-def layers_past_usual(load, experts: int) -> int:
+def _tiles(load, experts: int, share: bool):
     """On the host, from the counts a dispatch brought back (``load`` [layers,
-    held + 1], a share's ``counts`` a sparse layer): the layers whose tiles in
-    use passed ``usual_rows``, so that ``experts_grouped``'s ``lax.cond`` ran
-    them over the whole bound."""
+    held + 1], a share's ``counts`` a sparse layer; [layers, experts] where
+    every expert is held and ``share`` is false): a layer's row tiles that
+    hold a pair, and the tiles of the layout's two sizes as
+    ``experts_grouped`` reckons them, the usual load's and the whole bound's
+    (the same where the layout has one)."""
+    pairs, groups = int(load[0].sum()), load.shape[1]
+    held = groups - 1 if share else groups
+    tile = _gmm.tile_for(pairs, experts)
+    in_use = (-(-load[:, :held] // tile)).sum(axis=1)
+    whole = _gmm.padded_rows(pairs, groups, tile)
+    usual = whole
+    if share and whole > USUAL_ROWS:
+        usual = usual_rows(pairs, experts, held, tile)
+    return in_use, usual // tile, whole // tile
+
+
+def layers_past_usual(load, experts: int) -> int:
+    """The layers of a share's dispatch whose tiles in use passed
+    ``usual_rows``, so that ``experts_grouped``'s ``lax.cond`` ran them over
+    the whole bound."""
     load = np.asarray(load)
     if not load.size:
         return 0
-    pairs, held = int(load[0].sum()), load.shape[1] - 1
-    tile = _gmm.tile_for(pairs, experts)
-    if _gmm.padded_rows(pairs, held + 1, tile) <= USUAL_ROWS:
-        return 0
-    used = (-(-load[:, :held] // tile)).sum(axis=1) * tile
-    return int((used > usual_rows(pairs, experts, held, tile)).sum())
+    in_use, usual, _ = _tiles(load, experts, True)
+    return int((in_use > usual).sum())
+
+
+def tiles_laid_and_in_use(load, experts: int, share: bool = True) -> tuple[int, int]:
+    """The row tiles of a dispatch's sparse layers: those the layouts laid,
+    every one a grid step a column block of ``grouped_expert_product`` (the
+    usual load's or the whole bound's, as the ``lax.cond`` chose), and those
+    of them that hold a pair.  The rest are steps that run no product and,
+    since ISSUE 43, move no block."""
+    load = np.asarray(load)
+    if not load.size:
+        return 0, 0
+    in_use, usual, whole = _tiles(load, experts, share)
+    return int(np.where(in_use > usual, whole, usual).sum()), int(in_use.sum())

@@ -138,7 +138,9 @@ from ..ops.sparse_index import (
 )
 from .configs import AttentionGeometry, GlmMoeLiteConfig
 from .decoder_parts import dense as _dense
-from .decoder_parts import experts_grouped, layers_past_usual, quantize_dense  # noqa: F401
+from .decoder_parts import (  # noqa: F401
+    experts_grouped, layers_past_usual, quantize_dense, tiles_laid_and_in_use,
+)
 from .decoder_parts import rms as _rms
 from .decoder_parts import rope as _rope
 from .decoder_parts import rope_angles as _rope_angles
@@ -470,6 +472,13 @@ def whole_bound_layers(load, config: GlmMoeLiteConfig) -> int:
     if load.size and load.shape[1] == config.n_routed_experts:
         return 0
     return layers_past_usual(load, config.n_routed_experts)
+
+
+def expert_tiles(load, config: GlmMoeLiteConfig) -> tuple[int, int]:
+    """Of a dispatch's sparse layers, the row tiles their layouts laid and
+    those that hold a pair (``decoder_parts.tiles_laid_and_in_use``)."""
+    share = bool(load.size) and load.shape[1] != config.n_routed_experts
+    return tiles_laid_and_in_use(load, config.n_routed_experts, share)
 
 
 # -- parameters ---------------------------------------------------------------

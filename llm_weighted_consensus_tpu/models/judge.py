@@ -34,9 +34,11 @@ module gives,
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
 beside ``init_params``, ``from_hf_weights``, ``quantize_dense``,
-``experts_held(params, config)`` and
+``experts_held(params, config)``,
 ``whole_bound_layers(load, config)`` (the host's count, from the pairs
-routed, of the sparse layers that ran over their layout's whole bound).
+routed, of the sparse layers that ran over their layout's whole bound) and
+``expert_tiles(load, config)`` (likewise, the row tiles the sparse layers'
+layouts laid and those of them that hold a pair).
 
 A call's prompt, token by token (each piece goes through the tokenizer on
 its own, so a candidate is tokenized once however many ballots show it):
@@ -252,6 +254,11 @@ class TpuJudge:
             # sparse layers, summed over dispatches, whose tiles in use passed
             # the usual load's rows and ran over the layout's whole bound
             "expert_layers_whole_bound": 0,
+            # row tiles, summed over sparse layers and dispatches, that the
+            # layouts laid (a grid step each a column block of the experts'
+            # kernels) and those of them that hold a pair
+            "expert_tiles_laid": 0,
+            "expert_tiles_in_use": 0,
             # (query, key) pairs, summed over dispatches and the layers that
             # own an indexer: those a query may see, and those it chose
             "index_keys_causal": 0,
@@ -399,6 +406,7 @@ class TpuJudge:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
+        tiles_laid, tiles_in_use = self.decoder.expert_tiles(load, self.config)
         elsewhere = int(load[:, self._held:].sum()) if load.size else 0
         load = load[:, :self._held] if load.size else load
         ratio = 0.0
@@ -415,6 +423,8 @@ class TpuJudge:
             s["expert_pairs_elsewhere"] += elsewhere
             s["expert_pairs_routed"] += elsewhere
             s["expert_layers_whole_bound"] += whole_bound
+            s["expert_tiles_laid"] += tiles_laid
+            s["expert_tiles_in_use"] += tiles_in_use
             if index_keys is not None:
                 selected, causal = np.asarray(index_keys)
                 s["index_keys_selected"] += int(selected)

@@ -71,7 +71,7 @@ from ..ops.gated_delta import gated_delta_rule, gated_delta_step
 from .configs import Qwen3NextConfig
 from .decoder_parts import (  # noqa: F401  (quantize_dense: the panel's protocol)
     attend_cached, dense, experts_grouped, layers_past_usual, quantize_dense, rms, rope,
-    rope_angles, swiglu,
+    rope_angles, swiglu, tiles_laid_and_in_use,
 )
 from .decoder_parts import gated as _gated
 
@@ -338,6 +338,12 @@ def whole_bound_layers(load, config: Qwen3NextConfig) -> int:
     """Of a dispatch's sparse layers (``load``: ``prefill``'s pairs routed a
     layer), those that ran over the layout's whole bound."""
     return layers_past_usual(load, config.num_experts)
+
+
+def expert_tiles(load, config: Qwen3NextConfig) -> tuple[int, int]:
+    """Of a dispatch's sparse layers, the row tiles their layouts laid and
+    those that hold a pair (``decoder_parts.tiles_laid_and_in_use``)."""
+    return tiles_laid_and_in_use(load, config.num_experts)
 
 
 # -- parameters ---------------------------------------------------------------------------

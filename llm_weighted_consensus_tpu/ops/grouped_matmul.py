@@ -8,7 +8,14 @@ row tiles of ``tile`` rows (``route_layout``).  Every row tile then belongs
 to ONE expert, whose index a scalar-prefetched table gives to the weight
 block's index map: the kernel is a plain tiled product whose weight operand
 is picked per row tile.  ``M + E * tile`` rows bound the padded total,
-whatever the routing; tiles past the rows in use do no work.
+whatever the routing.  A tile past the rows in use runs no product (its step's
+body stands under ``pl.when``), and since PR 43 its step moves no block
+either: every block indexed by the row tile names ``_row_block``'s, the last
+tile in use again on every step past it, and a step that names the block the
+step before it named fetches nothing and writes nothing back.  Until then such
+a step fetched its x block and wrote its (never written) out block back, and
+with no product to hide behind that traffic was the step's whole time (see
+"The tiles no pair fills" below).
 
 Grid (N / tile_n, row tiles), the row tiles innermost: consecutive row tiles
 of one expert name the same weight block, which is then fetched once per
@@ -100,6 +107,25 @@ the walk), 4.81 together; the down product 6144 wide (1024 columns of zeros
 behind ``w_down``), the unpadded slab of 24 walked and the sum cut back to
 5120 columns 5.85.  The padded slab serves.  A row under one whole tile of
 words (the tiny presets) keeps the gathers: its slab would be mostly padding.
+
+The tiles no pair fills (my chip run, PR 43, each kernel alone at a share's
+shapes, min of 7, parent and change in one call:
+``scripts/time_expert_tiles.py``).  The fifth judge's sparse layer: a layout of
+98,304 rows = 384 row tiles as ``decoder_parts.usual_rows`` sizes it, four
+times the even load, of which the seeded router fills 112; 32 experts held,
+3072 x 3072; gate-up in four column blocks of 768, the down product whole, a
+row a slab of 16 sublanes.  With every step naming its own row tile's blocks:
+gate-up 10.09 ms over the layout as laid against 6.84 over the layout CUT to
+its 112 tiles, the down product 5.32 against 3.82: the 272 tiles that hold
+nothing cost 3.25 + 1.50 ms a layer, 7.9 + 3.7 MB moved a tile (the x block of
+1.57 MB once a column block and four out blocks of 0.39; 1.57 in and a slab
+block of 2.10 out) at 660 GB/s.  With ``_row_block``: 6.91 against 6.78 and
+3.86 against 3.88, 0.14 + 0.0 ms: the empty steps themselves, 0.13 us each.
+The third judge's (192 tiles, 60 in use, 16 held, 6144 x 2048, column blocks
+of 512, a slab of 24): 2.46 + 0.94 ms before, 0.03 + 0.04 after.  With NO tile
+in use the kernels still take 2.30 and 1.55 ms (6.63 and 3.65 before): a step
+fetches its weight block whether or not it multiplies, 1.2 and 0.6 GB of them
+a kernel, which the products hide where there are products.
 """
 
 from __future__ import annotations
@@ -271,6 +297,17 @@ def _kernel(
             o_ref[...] = acc[:, c * width:(c + 1) * width].astype(o_ref.dtype)
 
 
+def _row_block(i, tiles_used):
+    """The block of rows that row tile ``i``'s step names: its own up to the
+    last tile in use, and that one again on every step past it (block 0
+    where no tile is in use).  A step that names the block the step before it
+    named fetches nothing and writes nothing back, which is how the weight
+    block has been fetched once an expert since PR 27; its body runs no
+    product either, so the last tile in use stays in VMEM through the steps
+    past it and goes back once, as it was."""
+    return jnp.minimum(i, jnp.maximum(tiles_used[0] - 1, 0))
+
+
 def column_chunks(rows: int, width: int, itemsize: int = 2) -> int:
     """Into how many column chunks a [rows, width] table goes where XLA is to
     gather rows from it: the least power of two that leaves a chunk within
@@ -352,18 +389,24 @@ def grouped_expert_product(
         slab, _ = row_slabs(n, dtype)
         if not slab or out_chunks or tile_n != n:
             raise ValueError(f"a row of {n} x {dtype} is not laid a row a slab")
-        out_specs = [pl.BlockSpec((tile * slab, LANES), lambda j, i, te, used: (i, 0))]
+        out_specs = [
+            pl.BlockSpec((tile * slab, LANES), lambda j, i, te, used: (_row_block(i, used), 0))
+        ]
         words = jnp.uint32 if dtype == jnp.bfloat16 else dtype
         out_shape = [jax.ShapeDtypeStruct((rows * slab, LANES), words)]
     else:
         out_specs = [
-            pl.BlockSpec((tile, tile_n // pieces), lambda j, i, te, used: (i, j))
+            pl.BlockSpec(
+                (tile, tile_n // pieces), lambda j, i, te, used: (_row_block(i, used), j)
+            )
         ] * pieces
         out_shape = [jax.ShapeDtypeStruct((rows, n // pieces), dtype)] * pieces
     if interpret is None:
         interpret = _interpret()
     weight_spec = pl.BlockSpec((None, k, tile_n), lambda j, i, te, used: (te[i], 0, j))
-    by_row = lambda width: pl.BlockSpec((tile, width), lambda j, i, te, used: (i, 0))  # noqa: E731
+    by_row = lambda width: pl.BlockSpec(  # noqa: E731
+        (tile, width), lambda j, i, te, used: (_row_block(i, used), 0)
+    )
     operands = [*xs, w]
     in_specs = [*(by_row(part.shape[1]) for part in xs), weight_spec]
     if w_up is not None:

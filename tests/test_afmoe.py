@@ -43,6 +43,7 @@ from llm_weighted_consensus_tpu.models.configs import (  # noqa: E402
 from llm_weighted_consensus_tpu.models.judge import JUDGE_PRESETS, TpuJudge  # noqa: E402
 from llm_weighted_consensus_tpu.ops import causal_attention as attn  # noqa: E402
 from llm_weighted_consensus_tpu.ops import head_norm  # noqa: E402
+from llm_weighted_consensus_tpu.ops import grouped_matmul as gmm  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 C = AFMOE_TEST_TINY
@@ -713,6 +714,11 @@ def test_judge_counts_the_band_over_four_sliding_layers(judge):
     assert grew("index_keys_causal") == 0  # no indexer anywhere
     assert grew("expert_pairs_routed") == 4 * 3 * s * C.num_experts_per_tok
     assert grew("expert_pairs_elsewhere") == 0  # the preset's own parameters hold every expert
+    # every expert held: one bound of row tiles a sparse layer, most of them in use
+    pairs = 3 * s * C.num_experts_per_tok
+    tile = gmm.tile_for(pairs, C.num_experts)
+    assert grew("expert_tiles_laid") == 4 * gmm.padded_rows(pairs, C.num_experts, tile) // tile
+    assert grew("expert_tiles_laid") / 2 < grew("expert_tiles_in_use") <= grew("expert_tiles_laid")
 
 
 def test_a_share_counts_the_pairs_here_and_elsewhere():
@@ -727,6 +733,11 @@ def test_a_share_counts_the_pairs_here_and_elsewhere():
     assert stats["expert_pairs_routed"] == 4 * 2 * SEQ * C.num_experts_per_tok
     assert 0 < stats["expert_pairs_here"] < stats["expert_pairs_elsewhere"]
     assert stats["expert_layers_whole_bound"] == 0
+    # four experts and the pairs elsewhere: five groups' bound a sparse layer
+    pairs = 2 * SEQ * C.num_experts_per_tok
+    tile = gmm.tile_for(pairs, C.num_experts)
+    assert stats["expert_tiles_laid"] == 4 * gmm.padded_rows(pairs, 5, tile) // tile
+    assert 0 < stats["expert_tiles_in_use"] < stats["expert_tiles_laid"] / 2
 
 
 def test_the_other_judges_programs_name_none_of_this_decoder_s_own_scopes():

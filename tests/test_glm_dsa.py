@@ -520,6 +520,7 @@ def test_a_judge_holding_a_share_counts_the_pairs_elsewhere():
     assert 0 < stats["expert_pairs_here"] < total
     assert sum(stats["expert_tokens"]) == stats["expert_pairs_here"]
     assert stats["expert_layers_whole_bound"] == 0
+    assert 0 < stats["expert_tiles_in_use"] < stats["expert_tiles_laid"]
 
 
 def test_the_first_judge_keeps_no_selection_counters_running():

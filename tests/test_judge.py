@@ -336,6 +336,65 @@ def test_weighted_down_and_combine_match_the_weighted_sum(pairs, tile, chunks):
     assert float(jnp.abs(got - want).max()) < 1e-4
 
 
+# -- the row tiles no pair fills move no block (ISSUE 43) -----------------------
+
+
+def test_a_step_past_the_tiles_in_use_names_the_last_block_in_use():
+    def block(i, used):
+        return int(gmm._row_block(jnp.int32(i), jnp.asarray([used], jnp.int32)))
+
+    # under, at and past ``tiles_used``: a tile in use names its own block
+    assert [block(i, 3) for i in (0, 1, 2, 3, 4, 11)] == [0, 1, 2, 2, 2, 2]
+    assert [block(i, 12) for i in (0, 11)] == [0, 11]  # every tile in use
+    assert [block(i, 1) for i in (0, 1, 11)] == [0, 0, 0]
+    assert [block(i, 0) for i in (0, 1, 11)] == [0, 0, 0]  # no pair held: a block that exists
+
+
+def every_step_its_own_block(monkeypatch):
+    """``grouped_expert_product`` as it stood before ISSUE 43 (every grid step
+    names its own row tile's blocks, in use or not), outside its jit so that
+    no compiled form of the served one answers for it."""
+    monkeypatch.setattr(gmm, "_row_block", lambda i, used: i)
+    return gmm.grouped_expert_product.__wrapped__
+
+
+@pytest.mark.parametrize("in_use", ["a-third", "one-tile", "none"])
+@pytest.mark.parametrize("form", ["gate-up", "down-chunks", "down-slabs"])
+def test_tiles_past_those_in_use_change_no_row_of_a_tile_in_use(monkeypatch, form, in_use):
+    """4 experts held of a router's 16, most pairs elsewhere: a grid of 17 row
+    tiles of which six, one or none hold a pair, three column blocks a row
+    tile in the gate-up form.  Every row of a tile in use has the bits it had
+    when every step named its own block; the rows past them nothing reads."""
+    pairs, tile, held, router, k = 96, 8, 4, 16, 48
+    rng = np.random.default_rng(7)
+    expert = rng.integers(held, router, size=pairs).astype(np.int32)
+    here = {"a-third": 34, "one-tile": 5, "none": 0}[in_use]
+    expert[:here] = 2 if in_use == "one-tile" else rng.integers(0, held, size=here)
+    weight = jnp.asarray(rng.random(pairs) + 0.1, jnp.float32)
+    (pair_of_row, _, tile_expert, used, _, row_weight), _ = gmm.route_layout_held(
+        jnp.asarray(expert), weight, held, tile
+    )
+    tiles = pair_of_row.shape[0] // tile
+    assert tiles == 17 and int(used[0]) == {"a-third": 6, "one-tile": 1, "none": 0}[in_use]
+    dtype, n = (jnp.bfloat16, 2048) if form == "down-slabs" else (jnp.float32, 48)
+    x = jnp.asarray(rng.standard_normal((pairs, k)), dtype)[pair_of_row]
+    w, w_up = (jnp.asarray(rng.standard_normal((held, k, n)) * 0.2, dtype) for _ in range(2))
+    how = {
+        "gate-up": dict(w_up=w_up, tile_n=16),
+        "down-chunks": dict(row_weight=row_weight, out_chunks=2),
+        "down-slabs": dict(row_weight=row_weight, slabs=True),
+    }[form]
+    got = gmm.grouped_expert_product(x, w, tile_expert, used, tile=tile, **how)
+    want = every_step_its_own_block(monkeypatch)(x, w, tile_expert, used, tile=tile, **how)
+    if form == "down-chunks":
+        got, want = jnp.concatenate(got, axis=1), jnp.concatenate(want, axis=1)
+    per_tile = tile * (gmm.row_slabs(n, dtype)[0] if form == "down-slabs" else 1)
+    assert got.shape == want.shape and got.shape[0] == tiles * per_tile
+    rows = int(used[0]) * per_tile
+    got, want = np.asarray(got[:rows]), np.asarray(want[:rows])
+    assert np.array_equal(got, want) and got.any() == (in_use != "none")
+
+
 def moe_case(tokens, dtype, seed=0, hidden=C.hidden_size):
     """A sparse layer of 64 experts, 4 a token, at the tiny widths."""
     config = dataclasses.replace(

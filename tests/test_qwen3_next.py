@@ -847,6 +847,64 @@ def test_a_padded_slab_s_walk_is_the_gather_form_bit_for_bit(dtype, held_share):
     assert (np.abs(want).max() > 0.1) == (held_share != "elsewhere")
 
 
+# -- the row tiles no pair fills move no block (ISSUE 43) -------------------------------------
+
+
+@pytest.mark.parametrize(
+    "tokens,k,dtype,routing",
+    [(200, 4, jnp.float32, "mixed"), (256, 10, jnp.bfloat16, "mixed"),
+     (130, 10, jnp.float32, "one"), (128, 4, jnp.bfloat16, "none")],
+    ids=lambda v: getattr(v, "__name__", str(v)),
+)
+def test_a_share_s_sum_is_what_it_was_when_every_step_named_its_own_block(
+    monkeypatch, tokens, k, dtype, routing
+):
+    """``experts_grouped`` with 12 of 32 experts held, its layout many times
+    its tiles in use (a third of it, one tile, none): the sum, bit for bit,
+    of the kernels whose every grid step fetched and wrote its own row tile's
+    blocks (``grouped_expert_product`` before ISSUE 43)."""
+    h, chosen, weight, p, router, held = share_case(tokens, k, dtype, routing.replace("none", "one"))
+    if routing == "none":
+        chosen = jnp.maximum(chosen, held)  # the one held choice goes elsewhere too
+    got, load = decoder_parts.experts_grouped(h, chosen, weight, p, router, held=held)
+    laid, in_use = decoder_parts.tiles_laid_and_in_use(np.asarray(load)[None], router)
+    tile = gmm.tile_for(tokens * k, router)
+    assert laid == gmm.padded_rows(tokens * k, held + 1, tile) // tile
+    assert 0 <= in_use < laid / 2
+    if routing != "mixed":  # every token's one held choice to ONE expert, or to none
+        assert in_use == (-(-tokens // tile) if routing == "one" else 0)
+    monkeypatch.setattr(gmm, "_row_block", lambda i, used: i)
+    monkeypatch.setattr(gmm, "grouped_expert_product", gmm.grouped_expert_product.__wrapped__)
+    want, _ = decoder_parts.experts_grouped(h, chosen, weight, p, router, held=held)
+    assert got.dtype == want.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.array_equal(got, want) and (np.abs(want).max() > 0.1) == (routing != "none")
+
+
+@pytest.mark.parametrize(
+    "loads,experts,share,usual,expected",
+    [
+        # a quarter of 16 held, tiles of 32: 17 tiles the whole bound, 8 the usual load's
+        ([[30, 30, 30, 30, 392]] * 3, 16, True, 256, (3 * 8, 3 * 4)),
+        ([[128, 128, 128, 128, 0]] * 3, 16, True, 256, (3 * 21, 3 * 16)),  # past the usual
+        ([[30, 30, 30, 30, 392], [200, 100, 100, 100, 12], [0, 0, 0, 33, 479]], 16, True, 256,
+         (8 + 21 + 8, 4 + 19 + 2)),
+        ([[30, 30, 30, 30, 392]] * 3, 16, True, 10**6, (3 * 21, 3 * 4)),  # one bound
+        ([[0, 0, 0, 0, 512]], 16, True, 256, (8, 0)),  # no pair held
+        # every expert of 8 held, tiles of 64: one bound of 8 + 8 tiles whatever the usual
+        ([[100, 28, 0, 0, 384, 0, 0, 0]], 8, False, 256, (16, 2 + 1 + 6)),
+        ([], 16, True, 256, (0, 0)),
+    ],
+    ids=["even", "all-held", "one-layer", "one-bound", "none-held", "no-share", "no-sparse-layer"],
+)
+def test_row_tiles_laid_and_in_use_are_counted_from_the_pairs_routed(
+    monkeypatch, loads, experts, share, usual, expected
+):
+    monkeypatch.setattr(decoder_parts, "USUAL_ROWS", usual)
+    load = np.asarray(loads, np.int32).reshape(len(loads), 5 if share else experts)
+    assert decoder_parts.tiles_laid_and_in_use(load, experts, share) == expected
+
+
 def primitives(jaxpr, scope=""):
     """(primitive name, the name stack it stands under) of every equation, the
     jaxprs of calls, branches and loops gone into."""
@@ -922,3 +980,6 @@ def test_judge_counts_the_layers_that_ran_over_the_whole_bound(monkeypatch, held
     stats = judge.stats()
     assert stats["expert_pairs_routed"] == 88 * C.num_experts_per_tok * C.num_layers
     assert stats["expert_layers_whole_bound"] == (C.num_layers if held == 16 else 0)
+    # 88 tokens x 4 choices in tiles of 16: the usual load's 16 or the whole bound's 39
+    assert stats["expert_tiles_laid"] == C.num_layers * (39 if held == 16 else 16)
+    assert 0 < stats["expert_tiles_in_use"] <= stats["expert_tiles_laid"]

@@ -591,7 +591,7 @@ def child_kernels(dry_run: bool) -> int:
     )
 
     # attention, through the encoder's own attention block so the served
-    # heads_per_step is the one compiled: fused vs einsum, padded and packed
+    # heads_per_step is the one compiled: fused vs einsum
     layer = jax.tree_util.tree_map(
         lambda a: a[0],
         bert.init_params(jax.random.PRNGKey(1), config, dtype=dtype)["layers"],
@@ -600,16 +600,6 @@ def child_kernels(dry_run: bool) -> int:
     lens = rng.integers(long_seq // 2, long_seq + 1, long_n)
     mask = jnp.asarray(np.arange(long_seq)[None, :] < lens[:, None], jnp.int32)
     bias = jnp.where(mask[:, None, None, :] > 0, 0.0, -1e9).astype(jnp.float32)
-    # two packed sequences per row, then pad slots
-    seg = jnp.asarray(
-        np.where(
-            np.arange(long_seq)[None, :] < lens[:, None] // 2, 1,
-            np.where(np.arange(long_seq)[None, :] < lens[:, None], 2, 0),
-        ),
-        jnp.int32,
-    )
-    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
-    seg_bias = jnp.where(same, 0.0, -1e9).astype(jnp.float32)[:, None]
     fused_cfg = dataclasses.replace(config, attention_impl="fused")
     einsum_cfg = dataclasses.replace(config, attention_impl="einsum")
     check(
@@ -617,12 +607,6 @@ def child_kernels(dry_run: bool) -> int:
         lambda x, b: bert._attention(x, layer, b, fused_cfg),
         lambda x, b: bert._attention(x, layer, b, einsum_cfg),
         (x, bias),
-    )
-    check(
-        "fused_attention_tiled_seg",
-        lambda x, b, s: bert._attention(x, layer, b, fused_cfg, s),
-        lambda x, b, s: bert._attention(x, layer, b, einsum_cfg, s),
-        (x, seg_bias, seg),
     )
 
     # the quantized matmuls at the three encoder shapes, M = N * seq rows

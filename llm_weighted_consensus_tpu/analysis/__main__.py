@@ -20,8 +20,8 @@ machine-readable findings; positional paths lint specific files
 instead of the whole package.  The jaxpr audit's own knobs
 (``ANALYSIS_JAXPR_MODEL`` / ``_SPECS`` / ``_R_BUCKETS``) are documented
 in ``jaxpr_audit.py``; the mesh audit's (``ANALYSIS_MESH_MODEL`` /
-``_DP`` / ``_TP`` / ``_SPECS`` / ``_R_BUCKETS`` / ``_PACKED_BUCKETS``,
-``ANALYSIS_BUDGETS``) in ``mesh_audit.py``.
+``_DP`` / ``_TP`` / ``_SPECS`` / ``_R_BUCKETS``, ``ANALYSIS_BUDGETS``)
+in ``mesh_audit.py``.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Per-bucket resource budgets for the mesh audit (JXA009/JXA010).
 
-``analysis/budgets.json`` commits, for every padded and packed AOT
-bucket the simulated-mesh audit lowers, the measured static footprint:
+``analysis/budgets.json`` commits, for every AOT bucket the
+simulated-mesh audit lowers, the measured static footprint:
 ``hbm_bytes`` (argument + output + temp buffer bytes from XLA's
 ``memory_analysis``), ``flops`` and ``bytes_accessed`` (XLA
 ``cost_analysis``).  The audit re-measures on every run and compares

@@ -477,7 +477,7 @@ class ProjectIndex:
     def _local_aliases(
         self, fkey: FKey, entry: FuncEntry
     ) -> Dict[str, Set[FKey]]:
-        """``fn = self._dispatch_packed`` / ``fn = getattr(self,
+        """``fn = self._dispatch_embed`` / ``fn = getattr(self,
         "_dispatch_" + kind)`` local single-name aliases."""
         aliases: Dict[str, Set[FKey]] = {}
         table = self.class_methods.get(

@@ -180,14 +180,8 @@ CONCURRENCY_MODEL = {
             "module": "llm_weighted_consensus_tpu/serve/batcher.py",
             "kind": "lock",
             "guards": (
-                "_pack_real_tokens",
-                "_pack_slot_tokens",
                 "_pad_real_tokens",
                 "_pad_slot_tokens",
-                "prefix_dedup_hits",
-                "prefix_dedup_tokens_saved",
-                "packed_fallback_items",
-                "_packed_occupancy",
                 "fallback_dispatches",
             ),
         },

@@ -25,13 +25,6 @@ jit-dispatch bookkeeping (PR 3's ``jit_stats``), not on device output:
   gateway dispatches creates ZERO new jit specializations
   (``jit_stats`` delta per entry point).
 
-The packed serving path (PR 7 continuous batching) is audited with the
-same rules: ``bert.embed_packed`` is traced per packed-capacity bucket
-``("packed", B, L, K)`` for JXA001/2/3, and the AOT guard warms the
-packed buckets (``aot_warmup(..., packed_buckets=...)``), asserts their
-keys landed (JXA004), and drives packed traffic through them asserting
-zero new ``embed_packed`` jit specializations (JXA005).
-
 The quantized-path rules extend to the packed-int4 W4A8 mode: the
 serving entry points are re-traced with ``int4-pallas`` pinned
 (JXA002's dequant predicate widens to uint8 nibble storage -> float,
@@ -45,9 +38,7 @@ traced under a live ``sp`` mesh for JXA001/2/3 whenever the backend has
 Env knobs (all optional): ``ANALYSIS_JAXPR_MODEL`` (preset, default
 ``test-tiny``), ``ANALYSIS_JAXPR_SPECS`` (comma list of ``NxS``,
 default ``4x16``), ``ANALYSIS_JAXPR_R_BUCKETS`` (comma list, default
-``2``), ``ANALYSIS_JAXPR_PACKED_BUCKETS`` (comma list of ``BxLxK``,
-default ``1x64x8,2x64x8``; empty value audits no packed buckets),
-``ANALYSIS_SKIP_JAXPR=1`` to skip the audit entirely (the CLI honors
+``2``), ``ANALYSIS_SKIP_JAXPR=1`` to skip the audit entirely (the CLI honors
 it; tier-1 does not set it).
 
 jax is imported lazily inside the entry points so importing
@@ -76,10 +67,6 @@ _HOST_PRIMS = {
 _DEFAULT_MODEL = "test-tiny"
 _DEFAULT_SPECS = ((4, 16),)
 _DEFAULT_R_BUCKETS = (2,)
-# small CPU-sized packed-capacity buckets ("packed", B, L, K): enough to
-# trace the segment-masked forward and exercise the AOT lookup without
-# compiling serving-width shapes in tier-1
-_DEFAULT_PACKED_BUCKETS = ((1, 64, 8), (2, 64, 8))
 
 
 def _env_specs() -> Tuple[Tuple[int, int], ...]:
@@ -98,19 +85,6 @@ def _env_r_buckets() -> Tuple[int, ...]:
     if not raw.strip():
         return _DEFAULT_R_BUCKETS
     return tuple(int(p) for p in raw.split(",") if p.strip())
-
-
-def _env_packed_buckets() -> Tuple[Tuple[int, int, int], ...]:
-    raw = os.environ.get("ANALYSIS_JAXPR_PACKED_BUCKETS")
-    if raw is None:
-        return _DEFAULT_PACKED_BUCKETS
-    buckets = []
-    for part in raw.split(","):
-        if not part.strip():
-            continue
-        b, l, k = part.strip().lower().split("x")
-        buckets.append((int(b), int(l), int(k)))
-    return tuple(buckets)
 
 
 # ---------------------------------------------------------------------------
@@ -276,13 +250,10 @@ def audit_traced(
 # ---------------------------------------------------------------------------
 
 
-def _structure_findings(
-    model: str, specs, r_buckets, packed_buckets
-) -> List[Finding]:
+def _structure_findings(model: str, specs, r_buckets) -> List[Finding]:
     """Trace every serving entry point with the Pallas int8 impl pinned
     (``int8-pallas`` traces fine off-TPU; compilation isn't needed for
-    structure) and run the JXA001/2/3 checks per AOT bucket — including
-    the packed entry point per packed-capacity bucket."""
+    structure) and run the JXA001/2/3 checks per AOT bucket."""
     import jax
     import jax.numpy as jnp
 
@@ -360,22 +331,6 @@ def _structure_findings(
                     temp,
                 ),
                 f"stream(cap={cap},s={s})",
-                expect_pallas=True,
-            )
-        )
-    # packed entry point (continuous batching): the segment-masked
-    # forward must satisfy the same invariants at every capacity bucket
-    for b, l, k in packed_buckets:
-        pids = sds((b, l), jnp.int32)
-        pstarts = sds((b, k), jnp.int32)
-        findings.extend(
-            audit_traced(
-                lambda p, i, g, pos, st: bert.embed_packed(
-                    p, i, g, pos, st, embedder.config,
-                    pooling=embedder.pooling, normalize=True,
-                ),
-                (embedder.params, pids, pids, pids, pstarts),
-                f"packed(b={b},l={l},k={k})",
                 expect_pallas=True,
             )
         )
@@ -496,12 +451,11 @@ def _ring_structure_findings(model: str, specs) -> List[Finding]:
     return findings
 
 
-def _aot_findings(model: str, specs, r_buckets, packed_buckets) -> List[Finding]:
+def _aot_findings(model: str, specs, r_buckets) -> List[Finding]:
     """The specialization guard: warm every serving bucket with the
     auto int8 impl (the one CPU can execute), assert every expected
     key landed in the executable table, drive one of everything the
-    gateway dispatches — padded AND packed — and assert the jit caches
-    did not grow."""
+    gateway dispatches and assert the jit caches did not grow."""
     import numpy as np
 
     from ..models.embedder import TpuEmbedder, _bucket, _seq_bucket
@@ -509,11 +463,7 @@ def _aot_findings(model: str, specs, r_buckets, packed_buckets) -> List[Finding]
     embedder = TpuEmbedder(model, max_tokens=64, seed=0, quantize="int8")
     findings: List[Finding] = []
     warm_specs = [(n, s) for n, s in specs]
-    embedder.aot_warmup(
-        warm_specs,
-        r_buckets=list(r_buckets),
-        packed_buckets=list(packed_buckets),
-    )
+    embedder.aot_warmup(warm_specs, r_buckets=list(r_buckets))
 
     rng = np.random.default_rng(7)
     for n, s in specs:
@@ -537,21 +487,6 @@ def _aot_findings(model: str, specs, r_buckets, packed_buckets) -> List[Finding]
                         ),
                     )
                 )
-    for b, l, k in packed_buckets:
-        key = ("packed", b, l, k)
-        if key not in embedder._aot:
-            findings.append(
-                Finding(
-                    rule="JXA004",
-                    path=f"jaxpr:aot({model})",
-                    line=0,
-                    message=(
-                        f"packed-capacity bucket {key} missing from the "
-                        "AOT executable table after warmup — packed "
-                        "dispatches at this shape will lazily specialize"
-                    ),
-                )
-            )
     stats0 = embedder.jit_stats()["specializations"]
     for n, s in specs:
         s = _seq_bucket(s, embedder.max_tokens)
@@ -567,23 +502,6 @@ def _aot_findings(model: str, specs, r_buckets, packed_buckets) -> List[Finding]
             embedder.consensus_confidence_tokens_many(
                 np.stack([ids] * r), np.stack([mask] * r)
             )
-    for b, l, k in packed_buckets:
-        # two segments per row, ragged fills — exactly what the
-        # continuous batcher dispatches at this capacity bucket
-        pids = np.zeros((b, l), np.int32)
-        pseg = np.zeros((b, l), np.int32)
-        ppos = np.zeros((b, l), np.int32)
-        pstarts = np.zeros((b, k), np.int32)
-        vocab = embedder.config.vocab_size
-        for r in range(b):
-            n0, n1 = 5 + r, 3
-            pids[r, : n0 + n1] = rng.integers(3, vocab, n0 + n1)
-            pseg[r, :n0] = 1
-            pseg[r, n0 : n0 + n1] = 2
-            ppos[r, :n0] = np.arange(n0)
-            ppos[r, n0 : n0 + n1] = np.arange(n1)
-            pstarts[r, 1] = n0
-        embedder.embed_packed(pids, pseg, ppos, pstarts)
     stats1 = embedder.jit_stats()["specializations"]
     for entry, count in stats1.items():
         grew = count - stats0.get(entry, 0)
@@ -673,21 +591,14 @@ def run_jaxpr_audit(
     model: Optional[str] = None,
     specs: Optional[Sequence[Tuple[int, int]]] = None,
     r_buckets: Optional[Sequence[int]] = None,
-    packed_buckets: Optional[Sequence[Tuple[int, int, int]]] = None,
 ) -> List[Finding]:
-    """The full audit: structure (traced int8-pallas path, padded and
-    packed entry points) + AOT coverage/specialization guard.  CPU-safe;
-    ~seconds on test-tiny."""
+    """The full audit: structure (traced int8-pallas path) + AOT
+    coverage/specialization guard.  CPU-safe; ~seconds on test-tiny."""
     model = model or os.environ.get("ANALYSIS_JAXPR_MODEL", _DEFAULT_MODEL)
     specs = tuple(specs) if specs is not None else _env_specs()
     r_buckets = (
         tuple(r_buckets) if r_buckets is not None else _env_r_buckets()
     )
-    packed_buckets = (
-        tuple(packed_buckets)
-        if packed_buckets is not None
-        else _env_packed_buckets()
-    )
-    findings = _structure_findings(model, specs, r_buckets, packed_buckets)
-    findings += _aot_findings(model, specs, r_buckets, packed_buckets)
+    findings = _structure_findings(model, specs, r_buckets)
+    findings += _aot_findings(model, specs, r_buckets)
     return findings

@@ -8,11 +8,9 @@ under a simulated v5e-8 mesh (8 virtual CPU devices via
 plumbing, dp=4 × tp=2 by default) and audits the ACTUAL serving
 executables in the embedder's AOT table — the same
 ``jit``-with-shardings callables the batcher dispatches, not a parallel
-re-lowering that could drift from what serves traffic.  Only
-``deberta.reward_packed`` keeps a fresh lowering (the reranker has no
-AOT table; see ``_measure_reward_packed``).  Checked: the partition
-plan, the collective plan, and the resource envelope, before a single
-TPU chip is rented:
+re-lowering that could drift from what serves traffic.  Checked: the
+partition plan, the collective plan, and the resource envelope, before
+a single TPU chip is rented:
 
 * **JXA006 rule coverage** — against the first-class partition-rule
   tables in ``parallel/sharding.py``, every param leaf of every audited
@@ -75,8 +73,7 @@ default ``test-tiny``), ``ANALYSIS_MESH_DP`` / ``ANALYSIS_MESH_TP``
 (mesh shape, default 4×2), ``ANALYSIS_MESH_SP`` (ring mesh sp axis,
 default 2; 1 disables the ring audit), ``ANALYSIS_MESH_SPECS``
 (``NxS`` list, default ``8x16``), ``ANALYSIS_MESH_R_BUCKETS`` (default
-``2``), ``ANALYSIS_MESH_PACKED_BUCKETS`` (``BxLxK`` list, default
-``8x64x8``), ``ANALYSIS_MESH_RING_BUCKETS`` (``NxS`` list, default
+``2``), ``ANALYSIS_MESH_RING_BUCKETS`` (``NxS`` list, default
 ``2x64``; empty disables the ring audit), ``ANALYSIS_BUDGETS``
 (budgets file override), ``ANALYSIS_ROOFLINE``
 (roofline file override), ``ANALYSIS_SKIP_MESH=1``
@@ -115,11 +112,9 @@ from .roofline import (
 )
 
 _DEFAULT_MODEL = "test-tiny"
-_DEFAULT_RM_MODEL = "deberta-test-tiny"
 _DEFAULT_DP, _DEFAULT_TP = 4, 2
 _DEFAULT_SPECS = ((8, 16),)
 _DEFAULT_R_BUCKETS = (2,)
-_DEFAULT_PACKED_BUCKETS = ((8, 64, 8),)
 # the long-context ring audit folds the sp axis out of dp (dp//sp x tp
 # x sp) so the device budget stays dp*tp; sp=2 over the default 4x2
 # mesh gives the 2x2x2 sp-bearing shape serve/__main__.py would build
@@ -173,17 +168,6 @@ def _env_r_buckets() -> Tuple[int, ...]:
     return tuple(int(p) for p in raw.split(",") if p.strip())
 
 
-def _env_packed_buckets() -> Tuple[Tuple[int, int, int], ...]:
-    raw = os.environ.get("ANALYSIS_MESH_PACKED_BUCKETS")
-    if raw is None or not raw.strip():
-        return _DEFAULT_PACKED_BUCKETS
-    return tuple(
-        tuple(int(x) for x in part.strip().lower().split("x"))
-        for part in raw.split(",")
-        if part.strip()
-    )
-
-
 def _env_sp() -> int:
     return _env_int("ANALYSIS_MESH_SP", _DEFAULT_SP)
 
@@ -221,14 +205,10 @@ def _scope() -> dict:
     dp, tp = _env_mesh()
     return {
         "model": _env_model(),
-        "rm_model": _DEFAULT_RM_MODEL,
         "dp": dp,
         "tp": tp,
         "specs": ["x".join(map(str, s)) for s in _env_specs()],
         "r_buckets": list(_env_r_buckets()),
-        "packed_buckets": [
-            "x".join(map(str, b)) for b in _env_packed_buckets()
-        ],
         "sp": _env_sp(),
         "ring_buckets": [
             "x".join(map(str, b)) for b in _env_ring_buckets()
@@ -460,7 +440,7 @@ def _exe_figures(exe) -> Dict[str, float]:
     """The budget/roofline figures of one compiled executable: static
     HBM footprint (``memory_analysis``) plus flops / bytes-accessed
     (``cost_analysis``) — the shared measurement for every audited
-    bucket (padded, packed, ring, reward)."""
+    bucket (dense and ring)."""
     mem = exe.memory_analysis()
     figures = {
         "hbm_bytes": float(
@@ -476,26 +456,8 @@ def _exe_figures(exe) -> Dict[str, float]:
     return figures
 
 
-def _packed_inputs(rng, vocab: int, b: int, l: int, k: int):
-    import numpy as np
-
-    pids = np.zeros((b, l), np.int32)
-    pseg = np.zeros((b, l), np.int32)
-    ppos = np.zeros((b, l), np.int32)
-    pstarts = np.zeros((b, k), np.int32)
-    for row in range(b):
-        n0, n1 = 5 + row % 3, 3
-        pids[row, : n0 + n1] = rng.integers(3, vocab, n0 + n1)
-        pseg[row, :n0] = 1
-        pseg[row, n0 : n0 + n1] = 2
-        ppos[row, :n0] = np.arange(n0)
-        ppos[row, n0 : n0 + n1] = np.arange(n1)
-        pstarts[row, 1] = n0
-    return pids, pseg, ppos, pstarts
-
-
 def audit_serving_executables(
-    embedder, ref, specs, r_buckets, packed_buckets
+    embedder, ref, specs, r_buckets
 ) -> Tuple[List[Finding], Dict[str, Dict[str, float]]]:
     """JXA008–011 against a warmed mesh embedder's AOT table — the very
     ``jit``-with-shardings executables the batcher dispatches, not a
@@ -606,15 +568,6 @@ def audit_serving_executables(
                  (gids, gmask), ref_out)
             )
 
-    for b, l, k in packed_buckets:
-        pids, pseg, ppos, pstarts = _packed_inputs(rng, vocab, b, l, k)
-        ref_out = np.asarray(ref.embed_packed(pids, pseg, ppos, pstarts))
-        pb = b + (-b) % bm  # the dispatch pads rows to the dp multiple
-        cases.append(
-            ("packed", f"packed(b={pb},l={l},k={k})",
-             ("packed", pb, l, k), (pids, pseg, ppos, pstarts), ref_out)
-        )
-
     before = embedder.jit_stats()["specializations"]
     for kind, label, key, args, ref_out in cases:
         account(label, key)
@@ -624,12 +577,10 @@ def audit_serving_executables(
             )
         elif kind == "embed":
             got = embedder.embed_tokens(*args)
-        elif kind == "many":
+        else:
             got = embedder.consensus_confidence_tokens_many(
                 args[0], args[1], temperature=temp
             )
-        else:
-            got = embedder.embed_packed(*args)
         check(label, got, ref_out)
     after = embedder.jit_stats()["specializations"]
     grew = {
@@ -655,14 +606,11 @@ def audit_serving_executables(
 
 
 def _measure_buckets(
-    model: str, dp: int, tp: int, specs, r_buckets, packed_buckets
+    model: str, dp: int, tp: int, specs, r_buckets
 ) -> Tuple[List[Finding], Dict[str, Dict[str, float]]]:
     """Build the first-class mesh embedder exactly as serve/__main__.py
     does — ``shard_embedder_mesh`` + ``aot_warmup`` — then audit its AOT
-    table (``audit_serving_executables``) and the reward model's packed
-    lowering."""
-    import numpy as np
-
+    table (``audit_serving_executables``)."""
     from ..models.embedder import TpuEmbedder
     from ..parallel.mesh import make_mesh
     from ..parallel.sharding import shard_embedder_mesh
@@ -673,21 +621,13 @@ def _measure_buckets(
     embedder = TpuEmbedder(model, max_tokens=64, seed=0, quantize="none")
     shard_embedder_mesh(embedder, mesh)
     embedder.aot_warmup(
-        list(specs),
-        r_buckets=[r for r in r_buckets if r >= 2],
-        packed_buckets=list(packed_buckets),
+        list(specs), r_buckets=[r for r in r_buckets if r >= 2]
     )
-    findings, measured = audit_serving_executables(
-        embedder, ref, specs, r_buckets, packed_buckets
-    )
-    rm_findings, rm_measured = _measure_reward_packed(mesh, packed_buckets)
-    findings += rm_findings
-    measured.update(rm_measured)
-    return findings, measured
+    return audit_serving_executables(embedder, ref, specs, r_buckets)
 
 
 def _audit_fault_ladder(
-    model: str, dp: int, tp: int, specs, r_buckets, packed_buckets
+    model: str, dp: int, tp: int, specs, r_buckets
 ) -> List[Finding]:
     """JXA012: walk the MeshFaultManager downsize ladder as an incident
     would — warm it, then downsize rung by rung — and on every fallback
@@ -711,7 +651,7 @@ def _audit_fault_ladder(
     shard_embedder_mesh(embedder, make_mesh(dp=dp, tp=tp))
     manager = MeshFaultManager(embedder, shape=(dp, tp))
     r2 = [r for r in r_buckets if r >= 2]
-    manager.warm_ladder(list(specs), r2, list(packed_buckets))
+    manager.warm_ladder(list(specs), r2)
 
     rng = np.random.default_rng(0)
     vocab = embedder.config.vocab_size
@@ -752,9 +692,6 @@ def _audit_fault_ladder(
             pad_b += (-pad_b) % bm
             keys.append(("embed", pad_b, s))
             keys.extend(("many", r, n, s) for r in r2)
-        for b, l, k in packed_buckets:
-            pb = b + (-b) % bm
-            keys.append(("packed", pb, l, k))
         for key in keys:
             if embedder._aot.get(embedder._aot_key(key)) is None:
                 findings.append(
@@ -1064,78 +1001,6 @@ def _audit_ring_fault_ladder(
     return findings
 
 
-def _measure_reward_packed(
-    mesh, packed_buckets
-) -> Tuple[List[Finding], Dict[str, Dict[str, float]]]:
-    """The reward-model packed path, under the deberta rule table.
-
-    Unlike the embedder buckets this IS a fresh lowering: the reranker
-    has no AOT table (serving jits ``deberta.reward_packed`` lazily),
-    so there is no committed executable to audit — the audit lowers the
-    same entry point the reranker's jit would, under the same rule-table
-    sharding."""
-    import jax
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..models import deberta
-    from ..models.reranker import RM_PRESETS
-    from ..parallel.sharding import deberta_partition_rules, shard_by_rules
-
-    findings: List[Finding] = []
-    measured: Dict[str, Dict[str, float]] = {}
-    batch_s = NamedSharding(mesh, P("dp", None))
-    rng = np.random.default_rng(0)
-    atol = 1e-4
-
-    def put(arr, sharding):
-        return jax.device_put(arr, sharding)
-
-    rm_config = RM_PRESETS[_DEFAULT_RM_MODEL]
-    rm_params = deberta.init_params(jax.random.PRNGKey(1), rm_config)
-    rm_params_s = shard_by_rules(
-        rm_params, mesh, deberta_partition_rules()
-    )
-    rm_vocab = rm_config.vocab_size
-    for b, l, k in packed_buckets:
-        pids, pseg, _ppos, pstarts = _packed_inputs(rng, rm_vocab, b, l, k)
-
-        def reward_fn(p, i, g, st):
-            return deberta.reward_packed(p, i, g, st, rm_config)
-
-        label = f"reward_packed(b={b},l={l},k={k})"
-        jitted = jax.jit(reward_fn)
-        args = [
-            put(pids, batch_s), put(pseg, batch_s), put(pstarts, batch_s)
-        ]
-        compiled = jitted.lower(rm_params_s, *args).compile()
-        findings.extend(audit_hlo_collectives(compiled.as_text(), label))
-        measured[label] = _exe_figures(compiled)
-        # JXA011: only the used slots are defined output (unused slots
-        # carry garbage rewards by contract) — compare slots 0..1
-        sharded_out = np.asarray(compiled(rm_params_s, *args))
-        ref = np.asarray(reward_fn(rm_params, pids, pseg, pstarts))
-        if not np.allclose(
-            sharded_out[:, :2], ref[:, :2], atol=atol, rtol=1e-4
-        ):
-            worst = float(
-                np.max(np.abs(sharded_out[:, :2] - ref[:, :2]))
-            )
-            findings.append(
-                Finding(
-                    rule="JXA011",
-                    path=f"mesh:{label}",
-                    line=0,
-                    message=(
-                        "sharded reward output diverges from the single-"
-                        f"device reference (max abs diff {worst:.2e} > "
-                        f"{atol}): the partition plan changed the math"
-                    ),
-                )
-            )
-    return findings, measured
-
-
 # ---------------------------------------------------------------------------
 # Orchestration: in-process when devices suffice, else self-respawn
 # ---------------------------------------------------------------------------
@@ -1209,14 +1074,14 @@ def _audit_in_process(
     dp, tp = _env_mesh()
     bucket_findings, measured = _measure_buckets(
         _env_model(), dp, tp,
-        _env_specs(), _env_r_buckets(), _env_packed_buckets(),
+        _env_specs(), _env_r_buckets(),
     )
     findings += bucket_findings
     # JXA012 rung figures carry no committed budget baseline; the ladder
     # audit contributes findings only, never entries in ``measured``.
     findings += _audit_fault_ladder(
         _env_model(), dp, tp,
-        _env_specs(), _env_r_buckets(), _env_packed_buckets(),
+        _env_specs(), _env_r_buckets(),
     )
     # long-context ring buckets on the sp-bearing mesh: same JXA008–011
     # treatment (figures land in budgets/roofline next to the dense

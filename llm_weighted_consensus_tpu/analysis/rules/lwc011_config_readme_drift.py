@@ -9,7 +9,7 @@ no-op.  Both directions are mechanical, so both are lint:
 * **undocumented** — an ALL_CAPS env-name literal read inside a
   ``from_env`` function that the nearest README never mentions;
 * **stale** — a backticked ALL_CAPS token in that README whose family
-  prefix (text up to the first ``_``: ``TRACE_``, ``PACKING_``,
+  prefix (text up to the first ``_``: ``TRACE_``, ``BATCH_``,
   ``ANALYSIS_``, ...) matches some knob the parsed set *does* read, but
   which itself appears in no parsed module — families the repo has
   never owned (``JAX_*``, ``XLA_*`` platform vars) are out of scope.

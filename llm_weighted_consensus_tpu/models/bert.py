@@ -85,7 +85,7 @@ def init_params(rng: jax.Array, config: BertConfig, dtype=jnp.float32) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
+def _attention(x, p, mask_bias, config: BertConfig):
     b, s, h = x.shape
     nh, hd = config.num_heads, config.head_dim
     fused = _use_fused_attention(config, b, s, hd, x.dtype)
@@ -101,13 +101,6 @@ def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
         v = heads(_dense_cfg(x, p["attn_v"], config))
     scale = 1.0 / float(hd) ** 0.5
     if config.attention_impl == "ring":
-        if segment_ids is not None:
-            # the ring rotates k/v shards across chips; a same-segment
-            # mask would need the GLOBAL segment row on every chip —
-            # packed serving stays on the dense paths
-            raise ValueError(
-                "ring attention does not support packed segment_ids"
-            )
         # sequence-parallel ring attention: only valid inside a shard_map
         # over config.ring_axis (parallel/ring.py::ring_encode sets it up)
         from ..parallel.ring import ring_attention
@@ -117,30 +110,17 @@ def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
                 q, k, v, mask_bias[:, 0, 0, :], scale, config.ring_axis
             )
     elif fused:
-        from ..ops.attention import (
-            best_heads_per_step,
-            fused_attention_tiled,
-            fused_attention_tiled_seg,
-        )
+        from ..ops.attention import best_heads_per_step, fused_attention_tiled
 
         # forced mode may arrive with best==0 (caller takes the
         # VMEM responsibility); the kernel runs its one-row step then
         kk = best_heads_per_step(b, s, nh, hd, x.dtype.itemsize)
-        if segment_ids is not None:
-            with jax.named_scope("fused_attention_seg"):
-                # packed layout: the kernel builds the same-segment mask
-                # in VMEM from the int32 segment row
-                ctx = fused_attention_tiled_seg(
-                    q, k, v, segment_ids, scale, nh, heads_per_step=kk
-                )
-        else:
-            with jax.named_scope("fused_attention"):
-                # mask_bias is [b, 1, 1, s]; the kernel wants the
-                # [b, s] key bias
-                ctx = fused_attention_tiled(
-                    q, k, v, mask_bias[:, 0, 0, :], scale, nh,
-                    heads_per_step=kk,
-                )
+        with jax.named_scope("fused_attention"):
+            # mask_bias is [b, 1, 1, s]; the kernel wants the [b, s] key bias
+            ctx = fused_attention_tiled(
+                q, k, v, mask_bias[:, 0, 0, :], scale, nh,
+                heads_per_step=kk,
+            )
     else:
         with jax.named_scope("einsum_attention"):
             # [b, nh, s, s] logits: f32 accumulation on the MXU, stored in
@@ -155,8 +135,8 @@ def _attention(x, p, mask_bias, config: BertConfig, segment_ids=None):
                 )
                 * scale
             )
-            # [b, 1, 1, s] key-padding bias, or [b, 1, s, s] same-segment
-            # bias on the packed path — both broadcast over heads
+            # [b, 1, 1, s] key-padding bias, broadcast over heads and
+            # queries
             logits = logits + mask_bias.astype(x.dtype)
             probs = jax.nn.softmax(
                 logits.astype(jnp.float32), axis=-1
@@ -196,8 +176,8 @@ def _use_fused_attention(
     return jax.default_backend() == "tpu" and s >= 512
 
 
-def _layer(x, p, mask_bias, config: BertConfig, segment_ids=None):
-    attn = _attention(x, p, mask_bias, config, segment_ids)
+def _layer(x, p, mask_bias, config: BertConfig):
+    attn = _attention(x, p, mask_bias, config)
     with jax.named_scope("attn_ln"):
         x = _layer_norm(x + attn, p["attn_ln"], config.layer_norm_eps)
     # GELU fuses into the mlp_in epilogue on the int8 path (layers.mlp_cfg)
@@ -214,31 +194,18 @@ def encode(
     config: BertConfig,
     token_type_ids: Optional[jax.Array] = None,
     position_offset=0,
-    segment_ids: Optional[jax.Array] = None,
-    positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """input_ids[b, s], attention_mask[b, s] -> hidden[b, s, h].
 
     ``position_offset`` shifts the position embeddings — used by the
     sequence-parallel forward (parallel/ring.py) where each shard holds a
-    slice of the global sequence.
-
-    The packed (continuous-batching) layout passes ``segment_ids[b, s]``
-    (int32, 0 = pad slot, >=1 = packed sequence id) and ``positions[b, s]``
-    (within-segment offsets, each segment restarting at 0): attention is
-    confined to same-segment tokens and every segment sees exactly the
-    position embeddings its padded twin would, so a packed row reproduces
-    the per-row forward bit-for-bit up to float reduction order.  Because
-    positions are per-segment, a packed row may be LONGER than the
-    model's position table — only each segment is bounded by it
-    (serve/packing.py enforces that at plan time)."""
+    slice of the global sequence."""
     from .configs import position_base
 
     b, s = input_ids.shape
     base = position_base(config)
     if (
-        positions is None
-        and isinstance(position_offset, int)
+        isinstance(position_offset, int)
         and base + s + position_offset > config.max_position_embeddings
     ):
         # gathers clamp out-of-range indices — fail loudly instead of
@@ -253,34 +220,20 @@ def encode(
         # left-aligned masks make roberta's cumsum positions an arange
         # with a base offset (pad positions get wrong embeddings but their
         # hidden states are masked out of attention and pooling)
-        if positions is None:
-            pos_index = (jnp.arange(s) + position_offset + base)[None, :]
-        else:
-            pos_index = positions + base
+        pos_index = (jnp.arange(s) + position_offset + base)[None, :]
         x = x + params["position_embed"][pos_index]
         if token_type_ids is None:
             token_type_ids = jnp.zeros_like(input_ids)
         x = x + params["type_embed"][token_type_ids]
         x = _layer_norm(x, params["embed_ln"], config.layer_norm_eps)
 
-    if segment_ids is None:
-        mask_bias = jnp.where(
-            attention_mask[:, None, None, :] > 0, 0.0, -1e9
-        ).astype(jnp.float32)
-    else:
-        # same-segment visibility, pad slots (seg 0) see and are seen by
-        # nothing; fully-masked pad QUERY rows softmax to uniform (all
-        # logits equal), never 0/0 — their hidden states are dropped by
-        # pool_segments.  The einsum path consumes this [b, 1, s, s]
-        # bias; the fused path rebuilds the mask in-kernel from the raw
-        # segment row instead of paying the [b, s, s] HBM materialization
-        seg = segment_ids.astype(jnp.int32)
-        same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
-        mask_bias = jnp.where(same, 0.0, -1e9).astype(jnp.float32)[:, None]
+    mask_bias = jnp.where(
+        attention_mask[:, None, None, :] > 0, 0.0, -1e9
+    ).astype(jnp.float32)
 
     # scan over stacked layers: ONE compiled layer body for any depth
     def body(carry, layer_p):
-        return _layer(carry, layer_p, mask_bias, config, segment_ids), None
+        return _layer(carry, layer_p, mask_bias, config), None
 
     with jax.named_scope("encoder_layers"):
         x, _ = jax.lax.scan(body, x, params["layers"])
@@ -313,46 +266,6 @@ def pool(
         return emb
 
 
-def pool_segments(
-    hidden: jax.Array,
-    segment_ids: jax.Array,
-    seg_starts: jax.Array,
-    pooling: str = "cls",
-    normalize: bool = True,
-) -> jax.Array:
-    """hidden[b, s, h], segment_ids[b, s], seg_starts[b, k] -> emb[b, k, h].
-
-    Per-segment pooling for the packed layout: slot j of row b pools the
-    tokens with ``segment_ids == j + 1``.  ``seg_starts[b, j]`` is the row
-    offset of that segment's first token ([CLS] — the padded path's
-    ``hidden[:, 0]``).  Unused slots (no tokens at that segment id) pool
-    to the zero vector under ``mean`` and to whatever token sits at
-    offset 0 under ``cls`` — the host-side unpack only reads slots the
-    planner filled, so both are fine."""
-    with jax.named_scope("pool"):
-        if pooling == "cls":
-            emb = jnp.take_along_axis(hidden, seg_starts[:, :, None], axis=1)
-        elif pooling == "mean":
-            # f32 reductions regardless of activation dtype (module contract)
-            k = seg_starts.shape[1]
-            one_hot = (
-                segment_ids[:, :, None] == (jnp.arange(k) + 1)[None, None, :]
-            ).astype(jnp.float32)  # [b, s, k]
-            emb = jnp.einsum(
-                "bsh,bsk->bkh",
-                hidden.astype(jnp.float32),
-                one_hot,
-                preferred_element_type=jnp.float32,
-            ) / jnp.maximum(jnp.sum(one_hot, axis=1), 1.0)[:, :, None]
-        else:
-            raise ValueError(f"unknown pooling {pooling!r}")
-        emb = emb.astype(jnp.float32)
-        if normalize:
-            norm = jnp.sqrt(jnp.sum(emb * emb, axis=-1, keepdims=True))
-            emb = emb / jnp.maximum(norm, 1e-12)
-        return emb
-
-
 @partial(jax.jit, static_argnames=("config", "pooling", "normalize"))
 def embed(
     params: dict,
@@ -365,36 +278,6 @@ def embed(
     """The jitted end-to-end embedding forward: ids -> pooled vectors."""
     hidden = encode(params, input_ids, attention_mask, config)
     return pool(hidden, attention_mask, pooling, normalize)
-
-
-@partial(jax.jit, static_argnames=("config", "pooling", "normalize"))
-def embed_packed(
-    params: dict,
-    input_ids: jax.Array,
-    segment_ids: jax.Array,
-    positions: jax.Array,
-    seg_starts: jax.Array,
-    config: BertConfig,
-    pooling: str = "cls",
-    normalize: bool = True,
-) -> jax.Array:
-    """The packed twin of ``embed``: many variable-length sequences per
-    dense row -> one pooled vector per segment slot [b, k, h].
-
-    ids/segment_ids/positions are [b, s] int32 (segment id 0 = pad slot,
-    positions restart at 0 per segment); seg_starts[b, k] int32 indexes
-    each slot's first token.  Specializes per (b, s, k) packed-capacity
-    bucket — the small fixed set that replaces the (R, N, S) lattice."""
-    attention_mask = (segment_ids > 0).astype(jnp.int32)
-    hidden = encode(
-        params,
-        input_ids,
-        attention_mask,
-        config,
-        segment_ids=segment_ids,
-        positions=positions,
-    )
-    return pool_segments(hidden, segment_ids, seg_starts, pooling, normalize)
 
 
 # ---------------------------------------------------------------------------

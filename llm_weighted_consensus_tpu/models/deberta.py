@@ -178,29 +178,16 @@ def encode(
     input_ids: jax.Array,
     attention_mask: jax.Array,
     config: DebertaConfig,
-    segment_ids: jax.Array = None,
 ) -> jax.Array:
-    """``segment_ids[b, s]`` (int32, 0 = pad slot) switches the mask to
-    the packed same-segment form (the ragged continuous-batching layout,
-    serve/packing.py).  Disentangled attention has NO absolute positions —
-    only bucketed relative distances — and packed segments are contiguous
-    row spans, so within-segment relative distances are exactly those of
-    the padded forward; the segment mask removes every cross-segment
-    (wrong-distance) term.  No position plumbing needed, unlike bert.py."""
     with jax.named_scope("embeddings"):
         x = params["token_embed"][input_ids]
         x = _layer_norm(x, params["embed_ln"], config.layer_norm_eps)
         rel = _layer_norm(
             params["rel_embed"], params["rel_ln"], config.layer_norm_eps
         )
-    if segment_ids is None:
-        mask_bias = jnp.where(
-            attention_mask[:, None, None, :] > 0, 0.0, -1e9
-        ).astype(jnp.float32)
-    else:
-        seg = segment_ids.astype(jnp.int32)
-        same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
-        mask_bias = jnp.where(same, 0.0, -1e9).astype(jnp.float32)[:, None]
+    mask_bias = jnp.where(
+        attention_mask[:, None, None, :] > 0, 0.0, -1e9
+    ).astype(jnp.float32)
 
     def body(carry, layer_p):
         attn = _disentangled_attention(carry, rel, layer_p, mask_bias, config)
@@ -245,32 +232,6 @@ def reward(
         z = _dense(cls, params["head_dense"]).astype(jnp.float32)
         z = jax.nn.gelu(z, approximate=False)
         return _dense(z, params["head_out"]).astype(jnp.float32)[:, 0]
-
-
-@partial(jax.jit, static_argnames=("config",))
-def reward_packed(
-    params: dict,
-    input_ids: jax.Array,
-    segment_ids: jax.Array,
-    seg_starts: jax.Array,
-    config: DebertaConfig,
-) -> jax.Array:
-    """Packed twin of ``reward``: ids/segment_ids[b, s] + seg_starts[b, k]
-    -> one scalar reward per segment slot [b, k].  Each slot's "CLS" is
-    its segment's first token, gathered where the padded path reads
-    ``hidden[:, 0]``; unused slots produce garbage rewards the host-side
-    unpack never reads."""
-    attention_mask = (segment_ids > 0).astype(jnp.int32)
-    hidden = encode(
-        params, input_ids, attention_mask, config, segment_ids=segment_ids
-    )
-    with jax.named_scope("head"):
-        cls = jnp.take_along_axis(
-            hidden, seg_starts[:, :, None], axis=1
-        ).astype(jnp.float32)  # [b, k, h]
-        z = _dense(cls, params["head_dense"]).astype(jnp.float32)
-        z = jax.nn.gelu(z, approximate=False)
-        return _dense(z, params["head_out"]).astype(jnp.float32)[:, :, 0]
 
 
 @partial(jax.jit, static_argnames=("temperature",))

@@ -113,8 +113,8 @@ def active_sink() -> Optional[DispatchSink]:
 
 class deferred_readiness:
     """Context manager scoping a :class:`DispatchSink` to the calling
-    thread.  ``deferred_readiness(None)`` suspends an outer scope (the
-    packed fallback path uses this to run a padded dispatch inline)."""
+    thread.  ``deferred_readiness(None)`` suspends an outer scope:
+    dispatches inside it block inline."""
 
     __slots__ = ("sink", "_prev")
 

@@ -261,7 +261,7 @@ class TpuEmbedder:
         # MESH_ENABLED): params placed by the partition-rule tables and
         # dispatches staged with real input shardings.  Mesh mode keeps
         # the AOT fast path (executables lower with sharded avals, keyed
-        # per mesh shape) and the packed dispatch.
+        # per mesh shape).
         self.mesh_mode = False
         self.mesh = None
         self.mesh_shape = None
@@ -496,7 +496,6 @@ class TpuEmbedder:
         self,
         specs: list,
         r_buckets: list = (),
-        packed_buckets: list = (),
         ring_buckets: list = (),
     ) -> list:
         """AOT-lower-and-compile (``.lower().compile()``) every serving
@@ -531,9 +530,7 @@ class TpuEmbedder:
                 "first-class mesh mode (shard_embedder_mesh)"
             )
         if self.mesh_mode:
-            return self._aot_warmup_mesh(
-                specs, r_buckets, packed_buckets, ring_buckets
-            )
+            return self._aot_warmup_mesh(specs, r_buckets, ring_buckets)
         # ring buckets need an sp mesh; single-device warmup ignores them
         sds = jax.ShapeDtypeStruct
         temp_av = sds((), jnp.float32)
@@ -575,28 +572,12 @@ class TpuEmbedder:
                         r, n, self.config, self.pooling,
                     ),
                 )
-        # packed-capacity buckets (continuous batching, serve/packing.py):
-        # (rows, row_tokens, max_segments) triples — the small fixed set
-        # replacing the (R, N, S) lattice on the packed dispatch path
-        for b_rows, l_tokens, k_segs in packed_buckets:
-            row_av = sds((b_rows, l_tokens), jnp.int32)
-            starts_av = sds((b_rows, k_segs), jnp.int32)
-            self._aot_compile(
-                timings,
-                ("packed", b_rows, l_tokens, k_segs),
-                f"packed {b_rows}x{l_tokens}/k{k_segs}",
-                lambda a=row_av, st=starts_av: bert.embed_packed.lower(
-                    self.params, a, a, a, st,
-                    self.config, pooling=self.pooling, normalize=True,
-                ),
-            )
         return timings
 
     def _aot_warmup_mesh(
         self,
         specs: list,
         r_buckets: list = (),
-        packed_buckets: list = (),
         ring_buckets: list = (),
     ) -> list:
         """The mesh-mode half of ``aot_warmup``: lower every serving
@@ -658,25 +639,6 @@ class TpuEmbedder:
                         )
                     ),
                 )
-        for b_rows, l_tokens, k_segs in packed_buckets:
-            # the packed dispatch pads its row dim to the dp multiple
-            # (all-zero rows: segment id 0 is the fully-masked pad slot,
-            # which forwards cleanly), so warm the padded bucket
-            pb = b_rows + (-b_rows) % bm
-            starts_av = sds(
-                (pb, k_segs), jnp.int32, sharding=self.batch_sharding
-            )
-            self._aot_compile(
-                timings,
-                self._aot_key(("packed", pb, l_tokens, k_segs)),
-                f"{tag} packed {pb}x{l_tokens}/k{k_segs}",
-                lambda a=iav(pb, l_tokens), st=starts_av: (
-                    bert.embed_packed.lower(
-                        self.params, a, a, a, st,
-                        self.config, pooling=self.pooling, normalize=True,
-                    )
-                ),
-            )
         # long-context ring buckets (N, S): only meaningful with an sp
         # mesh axis — without one the ring shard_map has no axis to ring
         # over, and warming nothing here keeps the 2-axis AOT table
@@ -750,7 +712,6 @@ class TpuEmbedder:
                 "stream_vote_update_many": (
                     _stream_vote_update_many._cache_size()
                 ),
-                "embed_packed": bert.embed_packed._cache_size(),
                 "ring_embed": _ring_embed_jit._cache_size(),
                 "ring_embed_and_vote": _ring_embed_and_vote._cache_size(),
             },
@@ -927,92 +888,6 @@ class TpuEmbedder:
                 self._ring_config, self.mesh, "sp", "dp", self.pooling,
             ),
         )
-
-    # -- packed (continuous-batching) path ------------------------------------
-
-    def supports_packing(self) -> bool:
-        """Whether the ragged packed dispatch is usable.  Same gate as
-        the AOT fast path: the packed entry bypasses ``put_batch``.
-        First-class mesh mode packs fine — its dispatch pads the packed
-        row dim to the dp multiple and shards rows like any other
-        batch."""
-        return self._aot_ready()
-
-    def tokenize_ragged(
-        self, texts: Iterable[str], max_tokens: Optional[int] = None
-    ) -> list:
-        """texts -> list of 1-D int32 token rows, padding stripped.  The
-        packing planner consumes these as segments; each row is exactly
-        what ``tokenize`` would produce for that text before padding, so
-        a packed segment embeds the same token stream as its padded
-        twin."""
-        cap = min(max_tokens or self.max_tokens, self.max_tokens)
-        ids, mask = self.tokenizer.encode_batch(list(texts), cap)
-        lens = mask.sum(axis=1)
-        return [ids[i, : int(lens[i])] for i in range(ids.shape[0])]
-
-    def embed_packed(
-        self,
-        ids: np.ndarray,
-        segment_ids: np.ndarray,
-        positions: np.ndarray,
-        seg_starts: np.ndarray,
-    ) -> np.ndarray:
-        """Packed layout [B, L] (+ seg_starts[B, K]) -> per-segment-slot
-        embeddings [B, K, H] (f32, l2-normalized).  One device dispatch;
-        consults the AOT table at the ("packed", B, L, K) bucket first so
-        warmed packed traffic creates zero jit specializations."""
-        b, l = ids.shape
-        k = seg_starts.shape[1]
-        if self.mesh_mode:
-            # pad the row dim to the dp multiple with all-zero rows —
-            # segment id 0 is the fully-masked pad slot, so they forward
-            # cleanly — then slice the pad slots back off
-            pad = (-b) % self.batch_multiple
-            if pad:
-                ids = np.pad(np.asarray(ids), ((0, pad), (0, 0)))
-                segment_ids = np.pad(
-                    np.asarray(segment_ids), ((0, pad), (0, 0))
-                )
-                positions = np.pad(np.asarray(positions), ((0, pad), (0, 0)))
-                seg_starts = np.pad(
-                    np.asarray(seg_starts), ((0, pad), (0, 0))
-                )
-        pb = ids.shape[0]
-        label = f"packed(b={pb},l={l},k={k})"
-        exe = self._aot_lookup(
-            self._aot_key(("packed", pb, l, k)), ids, segment_ids
-        )
-        if exe is not None and (
-            positions.dtype == np.int32 and seg_starts.dtype == np.int32
-        ):
-            dev_ids, dev_segs, dev_pos, dev_starts = self._stage_batch(
-                ids, segment_ids, positions, seg_starts
-            )
-            out = self._timed_dispatch(
-                label,
-                lambda: exe(
-                    self.params, dev_ids, dev_segs, dev_pos, dev_starts
-                ),
-            )
-            return self._finish(out)[:b]
-        dev_ids, dev_segs, dev_pos, dev_starts = self._stage_batch(
-            ids, segment_ids, positions, seg_starts
-        )
-        out = self._timed_dispatch(
-            label,
-            lambda: bert.embed_packed(
-                self.params,
-                dev_ids,
-                dev_segs,
-                dev_pos,
-                dev_starts,
-                self.config,
-                pooling=self.pooling,
-                normalize=True,
-            ),
-        )
-        return self._finish(out)[:b]
 
     def consensus_confidence(
         self,

@@ -11,7 +11,6 @@ phase vocabulary:
 * ``tokenize``        — an item's texts to padded rows, on the host
   tokenizer pool at submit or inline in the stage hop (``host:tokenize``)
 * ``batcher_queue``   — item enqueued to its group taking the device
-* ``pack_plan``       — host-side ragged packing plan (packed path)
 * ``stage``           — a group's rows joined, padded, put on the device
   and its program enqueued (``batcher:stage``)
 * ``device_dispatch`` — a device executable's SOJOURN, enqueue to ready
@@ -22,7 +21,7 @@ phase vocabulary:
   ``waited_ms``)
 * ``finalize``        — results fetched, converted and split per item
   (``host:finalize``)
-* ``host_tally``      — consensus tally / packed reassembly on host
+* ``host_tally``      — consensus tally on host
 * ``upstream_judge``  — judge LLM streaming fan-out
 * ``http_respond``    — result in hand to response object built
   (``http:respond``)
@@ -65,7 +64,6 @@ PHASES = (
     "http_parse",
     "tokenize",
     "batcher_queue",
-    "pack_plan",
     "stage",
     "device_dispatch",
     "finalize",
@@ -209,9 +207,7 @@ def phase_breakdown(trace) -> dict:
     Span-derived, each interval of wall time attributed once, to the
     innermost thing that names it: the host spans (``host:tokenize``,
     ``batcher:stage``, ``host:finalize``) first, then what is left of
-    the ``device:dispatch`` bracket around them (minus the batcher
-    span's annotated host sub-costs ``pack_plan_ms`` / ``host_tally_ms``,
-    stamped per item by the packed dispatch) is device time, then what
+    the ``device:dispatch`` bracket around them is device time, then what
     is left of the item's ``batcher:<kind>`` span is queue time;
     ``http:read``, ``http:parse``, ``http:respond``, ``consensus:tally`` and
     ``judge:stream`` map directly; ``admission_wait_ms`` rides a root
@@ -221,8 +217,6 @@ def phase_breakdown(trace) -> dict:
     named phases sum to within 10% of ``e2e_ms`` on a served request."""
     by_name: Dict[str, List[Tuple[float, float]]] = {}
     batcher: List[Tuple[float, float]] = []
-    pack_plan_ms = 0.0
-    tally_attr_ms = 0.0
     root = trace.spans[0] if trace.spans else None
     for span in trace.spans:
         dur = span.duration_ms()
@@ -234,8 +228,6 @@ def phase_breakdown(trace) -> dict:
             by_name.setdefault(name, []).append((start, start + dur))
         elif name.startswith("batcher:"):
             batcher.append((start, start + dur))
-            pack_plan_ms += float(span.attributes.get("pack_plan_ms", 0.0))
-            tally_attr_ms += float(span.attributes.get("host_tally_ms", 0.0))
 
     def of(name: str) -> List[Tuple[float, float]]:
         return by_name.get(name, [])
@@ -264,13 +256,10 @@ def phase_breakdown(trace) -> dict:
         "http_parse": _union_ms(of("http:parse")),
         "tokenize": grown["tokenize"],
         "batcher_queue": grown["batcher_queue"],
-        "pack_plan": pack_plan_ms,
         "stage": grown["stage"],
-        "device_dispatch": max(
-            0.0, grown["device_dispatch"] - pack_plan_ms - tally_attr_ms
-        ),
+        "device_dispatch": grown["device_dispatch"],
         "finalize": grown["finalize"],
-        "host_tally": _union_ms(of("consensus:tally")) + tally_attr_ms,
+        "host_tally": _union_ms(of("consensus:tally")),
         "upstream_judge": _union_ms(of("judge:stream")),
         "http_respond": _union_ms(of("http:respond")),
     }

@@ -19,7 +19,7 @@ at hd 64, 4 at hd 32, all of them where h < 128); grid
 by row and head by head with plain 2-D products on static lane slices of
 the block.  The additive padding bias [b, s] (0 for real tokens, -1e9
 for padding) goes in as [b, 1, s], one row per batch row, shared by its
-heads; the packed layout's segment ids likewise.
+heads.
 
 What the chip says (TPU v5e, bf16, one bge-large layer's attention, my
 chip runs, PR 25): at 64 x 512 the kernel takes 0.94 ms where the
@@ -27,8 +27,8 @@ transposing kernel it replaces took 2.21 ms with its copies (0.9 ms of
 that the kernel), at 512 x 512 7.45 ms against 24.4 ms, the einsum path
 3.06 and 28.2 ms.  Two other forms of the same block were timed and not
 kept: products on the whole 128-lane block with the other head's lanes
-zeroed (1.12 ms at 64 x 512; faster below s=512 and under the segment
-mask), and every row of a block unrolled (no faster than two).  The
+zeroed (1.12 ms at 64 x 512; faster below s=512), and every row of a
+block unrolled (no faster than two).  The
 serving policy (``models/bert.py`` ``_use_fused_attention``: the kernel
 from s=512) is older than this layout; PERF.md's open questions hold
 what the chip says under 512.
@@ -82,26 +82,19 @@ def heads_per_block(nh: int, hd: int) -> int:
 
 
 def _attn_kernel(
-    q_ref, k_ref, v_ref, row_ref, out_ref, *, scale: float, hd: int,
-    segmented: bool,
+    q_ref, k_ref, v_ref, row_ref, out_ref, *, scale: float, hd: int
 ):
     # q/k/v/out blocks: [bb, s, g*hd], bb batch rows by the g heads of one
     # column block of the encoder's [b, s, h]; row block: [bb, 1, s], the
-    # f32 key-padding bias or (packed layout) the int32 segment ids, one
-    # row per batch row and shared by its heads.  One (row, head) tile at
-    # a time: plain 2-D products, matmul inputs in the storage dtype (bf16
+    # f32 key-padding bias, one row per batch row and shared by its
+    # heads.  One (row, head) tile at a time: plain 2-D products,
+    # matmul inputs in the storage dtype (bf16
     # feeds the MXU natively with f32 accumulation), softmax in f32 — the
     # einsum path's numerics.
-    bb, s, width = q_ref.shape
+    bb, _, width = q_ref.shape
 
     def one_row(r):
         row = row_ref[r]  # [1, s]
-        if segmented:
-            # query i attends key j iff seg[i] == seg[j] and seg[j] > 0 (0
-            # marks pad slots); built in VMEM, once for the row's heads (a
-            # pre-materialized [b, s, s] bias would triple the HBM traffic
-            # at s=512)
-            same = (row.reshape(s, 1) == row) & (row > 0)
         for j in range(width // hd):
             lanes = slice(j * hd, (j + 1) * hd)
             q = q_ref[r, :, lanes]  # [s, hd]
@@ -114,13 +107,7 @@ def _attn_kernel(
                 )
                 * scale
             )  # [s, s] f32
-            if segmented:
-                # pad-slot query rows are fully masked: every logit is the
-                # same -1e9, so the softmax is uniform (never 0/0) and the
-                # garbage rows are dropped by segment pooling downstream
-                logits = jnp.where(same, logits, -1e9)
-            else:
-                logits = logits + row  # key-side padding bias
+            logits = logits + row  # key-side padding bias
             mx = jnp.max(logits, axis=-1, keepdims=True)
             e = jnp.exp(logits - mx)
             probs = (e / jnp.sum(e, axis=-1, keepdims=True)).astype(v.dtype)
@@ -144,7 +131,29 @@ def _attn_kernel(
     jax.lax.fori_loop(0, bb // pair, rows, 0)
 
 
-def _fused_attention(q, k, v, rows, scale, nh, heads_per_step, segmented):
+# The kernel's device events are named after the jit that holds the
+# pallas_call, and the benchmark reads them by this name
+# (bench/reducers/attention_roofline.py).
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "nh", "heads_per_step"))
+def fused_attention_tiled(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    bias: jax.Array,
+    scale: float,
+    nh: int,
+    heads_per_step: int = 8,
+) -> jax.Array:
+    """q/k/v[b, s, h] as the projections leave them, bias[b, s] additive
+    key padding -> ctx[b, s, h] as ``attn_out`` reads it.
+
+    Softmax(QK^T * scale + bias) V fused, ``heads_per_step`` (batch row,
+    head) tiles per grid step: ``heads_per_step // g`` rows of one
+    g-head column block, which amortizes per-step grid/DMA overhead.
+    ``best_heads_per_step`` picks one within the VMEM budget.
+    """
     b, s, h = q.shape
     hd = h // nh
     g = heads_per_block(nh, hd)
@@ -166,9 +175,7 @@ def _fused_attention(q, k, v, rows, scale, nh, heads_per_step, segmented):
         (bb, 1, s), lambda i, j: (i, 0, 0), memory_space=pltpu.VMEM
     )
     return pl.pallas_call(
-        functools.partial(
-            _attn_kernel, scale=scale, hd=hd, segmented=segmented
-        ),
+        functools.partial(_attn_kernel, scale=scale, hd=hd),
         grid=(b // bb, nh // g),
         in_specs=[qkv_spec, qkv_spec, qkv_spec, row_spec],
         out_specs=qkv_spec,
@@ -178,61 +185,7 @@ def _fused_attention(q, k, v, rows, scale, nh, heads_per_step, segmented):
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=_interpret(),
-    )(q, k, v, rows[:, None, :])
-
-
-# The two entries stay two jitted functions: the kernel's device events
-# are named after the jit that holds the pallas_call, and the benchmark
-# reads them by these names (bench/reducers/attention_roofline.py).
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "nh", "heads_per_step"))
-def fused_attention_tiled(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    bias: jax.Array,
-    scale: float,
-    nh: int,
-    heads_per_step: int = 8,
-) -> jax.Array:
-    """q/k/v[b, s, h] as the projections leave them, bias[b, s] additive
-    key padding -> ctx[b, s, h] as ``attn_out`` reads it.
-
-    Softmax(QK^T * scale + bias) V fused, ``heads_per_step`` (batch row,
-    head) tiles per grid step: ``heads_per_step // g`` rows of one
-    g-head column block, which amortizes per-step grid/DMA overhead.
-    ``best_heads_per_step`` picks one within the VMEM budget.
-    """
-    return _fused_attention(
-        q, k, v, bias.astype(jnp.float32), scale, nh, heads_per_step, False
-    )
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "nh", "heads_per_step"))
-def fused_attention_tiled_seg(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    segment_ids: jax.Array,
-    scale: float,
-    nh: int,
-    heads_per_step: int = 8,
-) -> jax.Array:
-    """q/k/v[b, s, h], segment_ids[b, s] int32 (0 = pad slot) ->
-    ctx[b, s, h] with attention confined to same-segment tokens.
-
-    The packed-serving twin of ``fused_attention_tiled``: the same kernel
-    body, layout and numerics, but the key-side padding bias is replaced
-    by an in-kernel segment equality mask so one dense row can carry many
-    independent sequences (serve/packing.py builds the layout).  VMEM
-    cost matches the padded kernel (the int32 seg row replaces the f32
-    bias row), so ``best_heads_per_step`` applies unchanged.
-    """
-    return _fused_attention(
-        q, k, v, segment_ids.astype(jnp.int32), scale, nh, heads_per_step,
-        True,
-    )
+    )(q, k, v, bias.astype(jnp.float32)[:, None, :])
 
 
 def best_heads_per_step(
@@ -251,10 +204,9 @@ def best_heads_per_step(
 
     Per step the kernel holds 4 [bb, s, g*hd] operand/output blocks in the
     storage dtype (``itemsize`` bytes/element, x2 for double-buffering,
-    lanes padded to whole tiles), the bias rows (``bias_itemsize``; the
-    packed variant's int32 segment row has the same width, 8 sublanes a
-    row, x2), and the [s, s] score/prob tiles of the ONE head in work
-    (``score_itemsize``, f32 today).  The per-dtype byte widths are
+    lanes padded to whole tiles), the bias rows (``bias_itemsize``, 8
+    sublanes a row, x2), and the [s, s] score/prob tiles of the ONE head
+    in work (``score_itemsize``, f32 today).  The per-dtype byte widths are
     parameters — not baked-in 4s — so a narrower score accumulator or
     bias layout reuses this one fit model, mirroring ``w8a8_shape_fits``'s
     ``w_bytes``.  A function of shapes and item sizes only.

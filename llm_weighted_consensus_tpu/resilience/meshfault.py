@@ -378,7 +378,6 @@ class MeshFaultManager:
         self,
         specs: list,
         r_buckets: list = (),
-        packed_buckets: list = (),
         ring_buckets: list = (),
     ) -> list:
         """AOT-warm every fallback rung so a downsize never compiles.
@@ -399,9 +398,7 @@ class MeshFaultManager:
             for rung in reversed(self._rungs):
                 shard_embedder_mesh(self.embedder, rung.mesh)
                 timings.extend(
-                    self.embedder.aot_warmup(
-                        specs, r_buckets, packed_buckets, ring_buckets
-                    )
+                    self.embedder.aot_warmup(specs, r_buckets, ring_buckets)
                 )
         return timings
 

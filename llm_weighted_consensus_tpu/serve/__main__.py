@@ -627,7 +627,6 @@ def _warmup_embedder(
     specs: list,
     r_buckets: list = (),
     aot: bool = True,
-    packed_buckets: list = (),
     ring_buckets: list = (),
 ) -> None:
     """Pre-compile the consensus path for the given ``NxS`` shapes at
@@ -654,12 +653,6 @@ def _warmup_embedder(
     The dispatch loop below stays for ``WARMUP_AOT=0`` alone: it warms
     the lazy-jit path by running each shape once.
 
-    ``packed_buckets`` ((B, L, K) triples, wired from the PACKING_*
-    knobs) additionally warms the continuous-batching entry
-    (``bert.embed_packed``) at each packed-capacity bucket — the small
-    fixed set replacing the (R, N, S) lattice on the packed path.  AOT
-    only: packing requires the single-device or mesh-mode embedder.
-
     ``ring_buckets`` (LONG_CONTEXT_WARMUP NxS specs) warms the
     sequence-parallel ring dispatch on an sp-bearing mesh — AOT only,
     and a no-op unless the embedder's mesh carries an sp axis."""
@@ -680,10 +673,7 @@ def _warmup_embedder(
     )
     if aot:
         for label, dt in embedder.aot_warmup(
-            snapped,
-            r_buckets,
-            packed_buckets=packed_buckets,
-            ring_buckets=ring_buckets,
+            snapped, r_buckets, ring_buckets=ring_buckets
         ):
             log.info("warmup AOT %s compiled in %.1fs", label, dt)
         return
@@ -903,33 +893,13 @@ def build_service(
         embedder.aot_store = AotStore(
             config.aot_cache_dir, meta=embedder.aot_cache_meta()
         )
-    packed_buckets = []
     if embedder is not None and config.warmup:
-        if config.packing_enabled and embedder.supports_packing():
-            # the hot packed-capacity buckets (serve/packing.py): every
-            # pow2 row count up to the per-call cap at full seq width
-            # (saturated bursts), plus the single-row call at each
-            # narrower seq bucket (lone small requests).  Cold (B, L)
-            # pairs off this set ride the jit path — log-bounded by the
-            # pow2 x ladder lattice
-            from .packing import _L_BUCKETS
-
-            l_top = config.packing_row_tokens
-            k = config.packing_max_segments
-            b = 1
-            while b <= config.packing_max_rows:
-                packed_buckets.append((b, l_top, k))
-                b *= 2
-            packed_buckets.extend(
-                (1, l, k) for l in _L_BUCKETS if l < l_top
-            )
         with startup.stopwatch("warmup"):
             _warmup_embedder(
                 embedder,
                 config.warmup,
                 config.warmup_r,
                 aot=config.warmup_aot,
-                packed_buckets=packed_buckets,
                 ring_buckets=config.long_context_warmup,
             )
     # mesh fault domains (MESH_FAULT_ENABLED, resilience/meshfault.py):
@@ -969,10 +939,7 @@ def build_service(
                 )
             )
             for label, dt in meshfault.warm_ladder(
-                snapped,
-                config.warmup_r,
-                packed_buckets,
-                config.long_context_warmup,
+                snapped, config.warmup_r, config.long_context_warmup
             ):
                 _mf_log.info(
                     "mesh fault ladder AOT %s compiled in %.1fs", label, dt
@@ -1130,12 +1097,6 @@ def build_service(
             max_batch=config.batch_max,
             pipeline_depth=config.batch_pipeline,
             max_rows=config.batch_max_rows,
-            packing=config.packing_enabled,
-            packing_row_tokens=config.packing_row_tokens,
-            packing_max_rows=config.packing_max_rows,
-            packing_max_segments=config.packing_max_segments,
-            prefix_dedup=config.prefix_dedup,
-            prefix_dedup_min_chars=config.prefix_dedup_min_chars,
             host_tokenizer_workers=config.host_tokenizer_workers,
             staging_buffers=config.staging_buffers,
             embed_cache=embed_cache,

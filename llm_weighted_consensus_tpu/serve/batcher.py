@@ -31,9 +31,9 @@ pipeline alone:
 Dispatches are PIPELINED to ``pipeline_depth`` in flight (default 2), and
 the pipeline is asynchronous end to end (ISSUE 13):
 
-* **submit time** — each item's tokenization (and packed pack-plan) runs
-  in a small host worker pool (``HOST_TOKENIZER_WORKERS``) the moment it
-  is submitted, so ``_dispatch_*`` only concatenates pre-built rows;
+* **submit time** — each item's tokenization runs in a small host
+  worker pool (``HOST_TOKENIZER_WORKERS``) the moment it is submitted,
+  so ``_dispatch_*`` only concatenates pre-built rows;
 * **dispatch thread** — pads into reusable staging buffers, starts the
   ``device_put`` (baked batch sharding in mesh mode), and returns as
   soon as the PJRT call is ENQUEUED (models/dispatch_seam.py) — group
@@ -116,8 +116,8 @@ class _Item:
         # recycle one item forever
         self.redispatches = 0
         # submit-time tokenization (HOST_TOKENIZER_WORKERS): a future
-        # resolving to this item's pre-built rows (padded kinds) or its
-        # packed plan; None when the pool is off or the kind streams
+        # resolving to this item's pre-built rows; None when the pool is
+        # off or the kind streams
         self.prepared = None
 
     def marks(self) -> tuple:
@@ -187,12 +187,6 @@ class DeviceBatcher:
         fallback_embedder=None,
         fallback_context=None,
         meshfault=None,
-        packing: bool = False,
-        packing_row_tokens: int = 512,
-        packing_max_rows: int = 8,
-        packing_max_segments: int = 64,
-        prefix_dedup: bool = True,
-        prefix_dedup_min_chars: int = 48,
         host_tokenizer_workers: int = 2,
         staging_buffers: int = 2,
         judge=None,
@@ -201,40 +195,16 @@ class DeviceBatcher:
         # the local judge panel (models/judge.py TpuJudge), or None
         self.judge_model = judge
         self.metrics = metrics
-        # continuous batching (PACKING_ENABLED): embed + consensus items
-        # share ONE dispatch key and ride the ragged segment-id layout
-        # (serve/packing.py) instead of the per-kind padded buckets;
-        # opt-in — the padded path stays the default contract.  Works on
-        # the single-device embedder AND the first-class mesh mode (its
-        # packed dispatch dp-pads the row dim).
-        self.packing = bool(packing) and bool(
-            getattr(embedder, "supports_packing", lambda: False)()
-        )
-        self.packing_row_tokens = max(16, int(packing_row_tokens))
-        self.packing_max_rows = max(1, int(packing_max_rows))
-        self.packing_max_segments = max(1, int(packing_max_segments))
-        # shared-prefix dedup (PREFIX_DEDUP, packed path only): a
-        # consensus request's N candidates usually share the conversation
-        # prefix; embed it ONCE as its own segment and compose
-        # per-candidate embeddings from (prefix, suffix) part vectors
-        self.prefix_dedup = bool(prefix_dedup)
-        self.prefix_dedup_min_chars = max(1, int(prefix_dedup_min_chars))
-        # packing efficiency accounting (satellite: /metrics): real vs
-        # dispatched token slots per path, dedup hits, bucket occupancy.
-        # The stats lock exists because these counters mutate on the
-        # dispatch executor (pipeline_depth >= 2 workers) while
-        # utilization() reads them on the event loop: += on a plain int
-        # is read-modify-write, and two workers interleaving it drop
-        # increments (registered in analysis/concurrency_model.py)
+        # padding accounting (/metrics ``padded``): real vs dispatched
+        # token slots.  The stats lock exists because these counters
+        # mutate on the dispatch executor (pipeline_depth >= 2 workers)
+        # while utilization() reads them on the event loop: += on a
+        # plain int is read-modify-write, and two workers interleaving
+        # it drop increments (registered in
+        # analysis/concurrency_model.py)
         self._stats_lock = threading.Lock()
-        self._pack_real_tokens = 0
-        self._pack_slot_tokens = 0
         self._pad_real_tokens = 0
         self._pad_slot_tokens = 0
-        self.prefix_dedup_hits = 0
-        self.prefix_dedup_tokens_saved = 0
-        self.packed_fallback_items = 0
-        self._packed_occupancy: dict = {}
         # bounded queue (ADMISSION_MAX_QUEUE_DEPTH): arrivals beyond
         # this many pending items fail fast with OverloadedError (503)
         # instead of growing the queue without limit; 0 = unbounded
@@ -392,7 +362,8 @@ class DeviceBatcher:
                 priority=priority,
             )
             return emb, int(np.asarray(row_tokens).sum())
-        key = self._embed_key(max_tokens)
+        # a group is tokenized whole with one cap, so the cap is in the key
+        key = ("embed", max_tokens)
         cache = self.embed_cache
         if cache is None or not cache.enabled or not texts:
             emb, row_tokens = await self._submit(
@@ -490,14 +461,10 @@ class DeviceBatcher:
         token count from the SAME tokenization (callers must not
         re-tokenize on the event loop for usage accounting).  Batches
         with same-N same-temperature requests via
-        ``consensus_confidence_tokens_many`` — or, with packing enabled,
-        with EVERY other packed-eligible item regardless of N and
-        temperature (the packed dispatch votes per item on host).
+        ``consensus_confidence_tokens_many``.
 
         Over-length candidate sets on a sequence-parallel mesh route to
-        the ring dispatch instead (full-length scoring, no truncation)
-        — bypassing the packed key too: a packed row is capped at the
-        dense window, so an over-length segment can never ride it."""
+        the ring dispatch instead (full-length scoring, no truncation)."""
         texts = list(texts)
         if await self._route_ring(texts):
             return await self._submit(
@@ -506,14 +473,9 @@ class DeviceBatcher:
                 (texts, temperature),
                 priority=priority,
             )
-        key = (
-            ("packed",)
-            if self.packing
-            else ("consensus", len(texts), float(temperature))
-        )
         return await self._submit(
             "consensus",
-            key,
+            ("consensus", len(texts), float(temperature)),
             (texts, temperature),
             priority=priority,
         )
@@ -537,15 +499,6 @@ class DeviceBatcher:
             (list(texts), prompt, panel),
             priority=priority,
         )
-
-    def _embed_key(self, max_tokens):
-        """Grouping key for embed items: packed mode groups across
-        max_tokens caps (each item tokenizes under its own cap on the
-        device thread); the padded path tokenizes the whole group with
-        one cap, so the cap stays in the key."""
-        if self.packing:
-            return ("packed",)
-        return ("embed", max_tokens)
 
     async def _route_ring(
         self, texts: list, max_tokens: Optional[int] = None
@@ -660,14 +613,8 @@ class DeviceBatcher:
         # under the same lock; the staging-pool stats() call below stays
         # OUTSIDE it (the pool has its own lock — no nesting, no edge)
         with self._stats_lock:
-            pack_real = self._pack_real_tokens
-            pack_slot = self._pack_slot_tokens
             pad_real = self._pad_real_tokens
             pad_slot = self._pad_slot_tokens
-            dedup_hits = self.prefix_dedup_hits
-            dedup_saved = self.prefix_dedup_tokens_saved
-            pack_fallback = self.packed_fallback_items
-            occupancy = dict(self._packed_occupancy)
             fallback_dispatches = self.fallback_dispatches
         return {
             "queue_depth": len(self._pending),
@@ -718,24 +665,8 @@ class DeviceBatcher:
             "cancelled_items": self.cancelled_items,
             "fallback_active": self._use_fallback,
             "fallback_dispatches": fallback_dispatches,
-            # packing-efficiency counters (ISSUE 7): real tokens actually
-            # embedded vs device slots dispatched, per path — the padding
-            # waste the packed layout exists to reclaim
-            "packing": {
-                "enabled": self.packing,
-                "real_tokens": pack_real,
-                "slot_tokens": pack_slot,
-                "padding_waste": round(1.0 - pack_real / pack_slot, 4)
-                if pack_slot
-                else 0.0,
-                "prefix_dedup_hits": dedup_hits,
-                "prefix_dedup_tokens_saved": dedup_saved,
-                "fallback_items": pack_fallback,
-                # packed row-bucket B -> device calls at that bucket
-                "bucket_occupancy": {
-                    str(b): c for b, c in sorted(occupancy.items())
-                },
-            },
+            # real tokens actually embedded vs device slots dispatched:
+            # what the buckets' padding costs
             "padded": {
                 "real_tokens": pad_real,
                 "slot_tokens": pad_slot,
@@ -804,8 +735,8 @@ class DeviceBatcher:
         if self._tok_pool is not None and kind in (
             "embed", "consensus", "ring_embed", "ring_vote", "judge"
         ):
-            # submit-time tokenization: the item's rows (or packed plan)
-            # build on the host pool NOW, overlapping earlier groups'
+            # submit-time tokenization: the item's rows build on the
+            # host pool NOW, overlapping earlier groups'
             # device time; tokenizer errors park in the future and
             # re-raise on the dispatch thread, same path as before
             try:
@@ -921,13 +852,6 @@ class DeviceBatcher:
                 finally:
                     waker.cancel()
 
-    @staticmethod
-    def _est_kind(item) -> str:
-        """The EWMA/metrics series an item's dispatch runs under: packed
-        groups mix embed and consensus kinds, so they estimate and report
-        as one "packed" series."""
-        return "packed" if item.key and item.key[0] == "packed" else item.kind
-
     def _next_group(self) -> list:
         """Plan ONE dispatch group from the live pending queue: the head
         item's key, joined by every same-key arrival (order preserved) up
@@ -955,14 +879,6 @@ class DeviceBatcher:
         if not pending:
             return []
         key = pending[0].key
-        # packed groups are bounded by estimated SEGMENTS (one packed
-        # call's worth at a time — the dispatch may still split into
-        # multiple bucket calls); padded groups by encoder rows
-        row_budget = (
-            self.packing_max_rows * self.packing_max_segments
-            if key and key[0] == "packed"
-            else self.max_rows
-        )
         take: list = []
         rest: list = []
         rows = 0
@@ -974,7 +890,7 @@ class DeviceBatcher:
                 item.key == key
                 and not closed
                 and len(take) < self.max_batch
-                and (not take or rows + r <= row_budget)
+                and (not take or rows + r <= self.max_rows)
             ):
                 take.append(item)
                 rows += r
@@ -1014,7 +930,7 @@ class DeviceBatcher:
                 continue
             deadline = item.deadline
             if deadline is not None:
-                estimate = self._ewma_ms.get(self._est_kind(item))
+                estimate = self._ewma_ms.get(item.kind)
                 doomed = deadline.expired() or (
                     estimate is not None
                     and deadline.remaining() * 1e3 < estimate
@@ -1070,7 +986,7 @@ class DeviceBatcher:
         ]
         error = False
         wd_token = (
-            self.watchdog.begin(self._est_kind(group[0]))
+            self.watchdog.begin(group[0].kind)
             if self.watchdog is not None
             else None
         )
@@ -1217,7 +1133,7 @@ class DeviceBatcher:
         lane = group[0].lane
         self._lane_dispatches[lane] += 1
         self._lane_items[lane] += len(group)
-        series = self._est_kind(group[0])
+        series = group[0].kind
         if not error:
             # warm per-kind dispatch-time estimate for the deadline shed
             ms = (end - t0) * 1e3
@@ -1336,10 +1252,7 @@ class DeviceBatcher:
         return staged
 
     def _stage(self, group: list):
-        if group[0].key and group[0].key[0] == "packed":
-            fn = self._dispatch_packed
-        else:
-            fn = getattr(self, "_dispatch_" + group[0].kind)
+        fn = getattr(self, "_dispatch_" + group[0].kind)
         if self._use_fallback and self.fallback_embedder is not None:
             with self._stats_lock:
                 self.fallback_dispatches += 1
@@ -1413,8 +1326,8 @@ class DeviceBatcher:
 
     def _prepare_item(self, item):
         """Submit-time host work for one item (lwc-hosttok thread):
-        pre-built padded rows for embed/consensus items, or the local-
-        index packed plan for packed-key items.  Always runs against the
+        pre-built padded rows for embed/consensus items, a judge's
+        calls for a judge item.  Always runs against the
         PRIMARY embedder's tokenizer; the dispatch falls back to inline
         tokenization when it is serving the CPU twin.  Its end is the
         item's ``rows_ready`` (the device's account, ``_Item.marks``)."""
@@ -1424,18 +1337,9 @@ class DeviceBatcher:
             item.rows_ready = time.perf_counter()
 
     def _prepare_rows(self, item):
-        kind, key, payload = item.kind, item.key, item.payload
+        kind, payload = item.kind, item.payload
         if kind == "judge":
             return self._prepare_judge(item)
-        if key and key[0] == "packed":
-            # a packed plan tokenizes segment by segment inside the
-            # planner: timed whole, rows and tokens not counted here
-            with _hostspan.host_span(
-                "host:tokenize", parents=(item.span,), rid=item.rid
-            ):
-                return self._plan_packed_payload(
-                    kind, payload, self.embedder
-                )
         ring = kind in ("ring_embed", "ring_vote")
         texts, second = payload  # the cap of an embed, a vote's temperature
         cap = (second,) if kind in ("embed", "ring_embed") else ()
@@ -1687,283 +1591,6 @@ class DeviceBatcher:
         with self._stats_lock:
             self._pad_real_tokens += int(mask.sum())
             self._pad_slot_tokens += int(pad_b * ids.shape[1])
-
-    # -- packed (continuous-batching) dispatch --------------------------------
-
-    def _dispatch_packed(self, group: list, embedder):
-        """One mixed group (embed + consensus items, any N, any cap) ->
-        per-item results through the ragged segment-id layout.
-
-        Stage (dispatch thread): collect each item's pack plan — built at
-        submit time on the host pool when possible — first-fit pack every
-        segment in the group into ("packed", B, L, K) bucket calls, and
-        ENQUEUE ``embedder.embed_packed`` per call.  Finalize (waiter
-        thread, after readiness): materialize segment vectors, then
-        reassemble — embed items gather their per-text vectors; consensus
-        items compose candidate vectors (prefix-weighted when deduped)
-        and vote ON HOST (``packing.consensus_vote_np`` — numerics-
-        matched to the device vote) so mixed-N requests share a dispatch
-        without per-N jit specializations.  Items whose sequences exceed
-        the packed row fall back to their padded dispatch, staged inside
-        this same group."""
-        from . import packing as _packing
-
-        if not (
-            getattr(embedder, "embed_packed", None) is not None
-            and getattr(embedder, "supports_packing", lambda: False)()
-        ):
-            # e.g. an embedder double without the packed entry (a test
-            # fake, a fallback mid-swap): serve every item through its
-            # padded path, one by one (first-class mesh embedders pack
-            # fine and never land here)
-            staged = [
-                self._packed_item_fallback(item, embedder)
-                for item in group
-            ]
-            return lambda: [(np.asarray(a), t) for a, t in staged]
-        from ..obs import phases as _phases
-
-        row_tokens = self.packing_row_tokens
-        segments: list = []  # ragged int32 token rows, group-global
-        # pack_plan phase: ragged tokenization + first-fit packing (the
-        # host work BEFORE any device call); submit-time plans make the
-        # per-item loop a rebase, inline planning covers the rest.  Runs
-        # on the executor thread, so it reports to the lock-guarded
-        # global aggregator and stamps each item's batcher span
-        # (annotate is a plain dict update — no span creation off the
-        # event loop)
-        t_plan = time.perf_counter()
-        plans = [
-            self._plan_packed_item(item, embedder, segments)
-            for item in group
-        ]
-        plan_ms = (time.perf_counter() - t_plan) * 1e3
-        # oversized items dispatch their padded path NOW, on the same
-        # thread and inside the same guard/deferred scope as the packed
-        # calls; their host fetches ride finalize with everything else
-        fallback_staged: dict = {}
-        for i, plan in enumerate(plans):
-            if plan[0] == "fallback":
-                with self._stats_lock:
-                    self.packed_fallback_items += 1
-                fallback_staged[i] = self._packed_item_fallback(
-                    group[i], embedder
-                )
-        seg_vecs: list = [None] * len(segments)
-        call_outs: list = []  # (call, enqueued device out) pairs
-        if segments:
-            t_plan = time.perf_counter()
-            calls = _packing.build_calls(
-                segments,
-                row_tokens,
-                self.packing_max_rows,
-                self.packing_max_segments,
-            )
-            plan_ms += (time.perf_counter() - t_plan) * 1e3
-            for call in calls:
-                out = embedder.embed_packed(
-                    call.ids, call.segment_ids, call.positions,
-                    call.seg_starts,
-                )
-                b = call.ids.shape[0]
-                with self._stats_lock:
-                    self._pack_real_tokens += call.real_tokens
-                    self._pack_slot_tokens += call.slot_tokens
-                    self._packed_occupancy[b] = (
-                        self._packed_occupancy.get(b, 0) + 1
-                    )
-                call_outs.append((call, out))
-        _phases.observe_phase("pack_plan", plan_ms)
-        share_plan = plan_ms / len(group)
-        for item in group:
-            if item.span is not None:
-                item.span.annotate(pack_plan_ms=round(share_plan, 3))
-
-        def finalize() -> list:
-            for call, out in call_outs:
-                out_np = np.asarray(out, np.float32)
-                for si, (r, slot) in call.slots.items():
-                    seg_vecs[si] = out_np[r, slot]
-            # host_tally phase: per-item reassembly + the host-side vote
-            # (packing.consensus_vote_np) — waiter-thread work that
-            # overlaps the NEXT group's staging and device time
-            t_tally = time.perf_counter()
-            results: list = [None] * len(group)
-            for i, (item, plan) in enumerate(zip(group, plans)):
-                if plan[0] == "fallback":
-                    a, t = fallback_staged[i]
-                    results[i] = (np.asarray(a), t)
-                else:
-                    results[i] = self._assemble_packed_item(
-                        item, plan, segments, seg_vecs, embedder
-                    )
-            tally_ms = (time.perf_counter() - t_tally) * 1e3
-            _phases.observe_phase("host_tally", tally_ms)
-            share_tally = tally_ms / len(group)
-            for item in group:
-                if item.span is not None:
-                    item.span.annotate(host_tally_ms=round(share_tally, 3))
-            return results
-
-        return finalize
-
-    def _plan_packed_item(self, item, embedder, segments: list):
-        """One item's group-global assembly plan: consume the submit-time
-        plan when it was built against THIS embedder (the CPU twin's
-        tokenizer may differ), else plan inline; extend the group
-        segments and apply the dedup counters the pure planner deferred."""
-        if embedder is self.embedder and item.prepared is not None:
-            plan, rows, stats = item.prepared.result()
-        else:
-            plan, rows, stats = self._plan_packed_payload(
-                item.kind, item.payload, embedder
-            )
-        base = len(segments)
-        segments.extend(rows)
-        if stats is not None:
-            _, hits, saved = stats
-            with self._stats_lock:
-                self.prefix_dedup_hits += hits
-                self.prefix_dedup_tokens_saved += saved
-        return self._rebase_plan(plan, base)
-
-    def _plan_packed_payload(self, kind, payload, embedder):
-        """Pure pack planning for one item's payload -> (local plan,
-        ragged rows, dedup-stats delta).  Plan segment indices are
-        0-based relative to ``rows`` so the plan can build at SUBMIT time
-        (host pool), before the item's position in any dispatch group is
-        known; ``_plan_packed_item`` rebases it.  Counters are applied
-        only when the plan is consumed, so a shed item's speculative plan
-        costs nothing observable.  Oversized items plan as
-        ("fallback",)."""
-        from . import packing as _packing
-
-        row_tokens = self.packing_row_tokens
-        seg_cap = min(row_tokens, embedder.max_tokens)
-        if kind == "embed":
-            texts, cap = payload
-            rows = embedder.tokenize_ragged(
-                texts, min(cap, seg_cap) if cap else seg_cap
-            )
-            if any(not 0 < len(r) <= row_tokens for r in rows):
-                return (("fallback",), [], None)
-            return (("embed", list(range(len(rows)))), rows, None)
-        texts, temperature = payload
-        prefix = (
-            _packing.shared_prefix(texts, self.prefix_dedup_min_chars)
-            if self.prefix_dedup
-            else None
-        )
-        if prefix is not None:
-            parts = [prefix] + [t[len(prefix) :] for t in texts]
-            # empty suffixes (candidate == prefix) embed nothing: their
-            # candidate vector IS the prefix vector
-            part_texts = [parts[0]] + [s for s in parts[1:] if s]
-            rows = embedder.tokenize_ragged(part_texts, seg_cap)
-            # a prefix this short is all [CLS]/[SEP] overhead — or the
-            # pieces no longer fit the packed row: vote on full texts
-            if len(rows[0]) >= 4 and all(
-                0 < len(r) <= row_tokens for r in rows
-            ):
-                seg_iter = iter(range(1, len(rows)))
-                suffix_segs = [
-                    next(seg_iter) if s else None for s in parts[1:]
-                ]
-                stats = (
-                    "dedup",
-                    len(texts) - 1,
-                    (len(texts) - 1) * len(rows[0]),
-                )
-                return (
-                    ("consensus_dedup", 0, suffix_segs, temperature),
-                    rows,
-                    stats,
-                )
-        rows = embedder.tokenize_ragged(texts, seg_cap)
-        if any(not 0 < len(r) <= row_tokens for r in rows):
-            return (("fallback",), [], None)
-        return (
-            ("consensus", list(range(len(rows))), temperature),
-            rows,
-            None,
-        )
-
-    @staticmethod
-    def _rebase_plan(plan, base: int):
-        """Shift a local-index pack plan's segment indices by ``base``
-        (the group-global offset its rows landed at)."""
-        if plan[0] == "embed":
-            return ("embed", [base + i for i in plan[1]])
-        if plan[0] == "consensus_dedup":
-            _, prefix_idx, suffix_segs, temperature = plan
-            return (
-                "consensus_dedup",
-                base + prefix_idx,
-                [
-                    base + si if si is not None else None
-                    for si in suffix_segs
-                ],
-                temperature,
-            )
-        if plan[0] == "consensus":
-            return ("consensus", [base + i for i in plan[1]], plan[2])
-        return plan  # ("fallback",)
-
-    def _assemble_packed_item(
-        self, item, plan, segments: list, seg_vecs: list, embedder
-    ):
-        from . import packing as _packing
-
-        if plan[0] == "embed":
-            idxs = plan[1]
-            emb = np.stack([seg_vecs[i] for i in idxs]).astype(
-                np.float32, copy=False
-            )
-            tokens = np.asarray([len(segments[i]) for i in idxs])
-            return (emb, tokens)
-        if plan[0] == "consensus_dedup":
-            _, prefix_idx, suffix_segs, temperature = plan
-            p_vec = seg_vecs[prefix_idx]
-            p_tok = len(segments[prefix_idx])
-            cand = np.stack(
-                [
-                    _packing.compose_prefix_suffix(
-                        p_vec,
-                        p_tok,
-                        seg_vecs[si] if si is not None else None,
-                        len(segments[si]) if si is not None else 0,
-                    )
-                    for si in suffix_segs
-                ]
-            )
-            conf = _packing.consensus_vote_np(cand, temperature)
-            tokens = p_tok + sum(
-                len(segments[si]) for si in suffix_segs if si is not None
-            )
-            return (conf, int(tokens))
-        _, idxs, temperature = plan
-        cand = np.stack([seg_vecs[i] for i in idxs])
-        conf = _packing.consensus_vote_np(cand, temperature)
-        return (conf, int(sum(len(segments[i]) for i in idxs)))
-
-    def _packed_item_fallback(self, item, embedder):
-        """Stage one packed-key item through its padded dispatch (the
-        packed row cannot hold it, or the embedder cannot pack).  The
-        returned (handle, tokens) pair is host-materialized by the
-        caller's finalize closure, after readiness."""
-        if item.kind == "embed":
-            texts, cap = item.payload
-            ids, mask = embedder.tokenize(texts, cap)
-            self._count_padded(embedder, ids, mask)
-            emb = embedder.embed_tokens(ids, mask)
-            return (emb, mask.sum(axis=1))
-        texts, temperature = item.payload
-        ids, mask = embedder.tokenize(texts)
-        with self._stats_lock:
-            self._pad_real_tokens += int(mask.sum())
-            self._pad_slot_tokens += int(ids.size)
-        conf = embedder.consensus_confidence_tokens(ids, mask, temperature)
-        return (conf, int(mask.sum()))
 
     def _dispatch_stream(self, group: list, embedder):
         if len(group) == 1:

@@ -29,8 +29,8 @@ TPU additions:
   partition-rule tables (batches shard over ``dp``, encoder params
   Megatron-split over ``tp``, parallel/sharding.py), real input
   shardings on every dispatch, and per-(mesh-shape, bucket) AOT
-  executables; AOT warmup and packing stay available.  Off by default:
-  unset is one device.
+  executables; AOT warmup stays available.  Off by default: unset is one
+  device.
 * ``MESH_SHAPE`` — the mesh layout for ``MESH_ENABLED`` as ``DPxTP``
   (e.g. ``4x2`` = batches split 4-way, encoder params 2-way) or
   ``DPxTPxSP`` (e.g. ``2x2x2`` adds a 2-way sequence-parallel axis:
@@ -150,10 +150,10 @@ TPU additions:
   The overlap holds with device timing on too: the dispatch thread
   returns at PJRT enqueue and a waiter thread records readiness
   (models/dispatch_seam.py).  Default 2; 1 = fully serialized.
-* ``HOST_TOKENIZER_WORKERS`` — host threads tokenizing (and pack-
-  planning) each item at SUBMIT time, so ``_dispatch_*`` only
-  concatenates pre-built rows and group k+1's tokenization never rides
-  the dispatch thread behind group k.  ``0`` tokenizes on the dispatch
+* ``HOST_TOKENIZER_WORKERS`` — host threads tokenizing each item at
+  SUBMIT time, so ``_dispatch_*`` only concatenates pre-built rows and
+  group k+1's tokenization never rides the dispatch thread behind
+  group k.  ``0`` tokenizes on the dispatch
   thread (the pre-overlap behavior).  Default 2.
 * ``HOST_FASTPATH`` — host fast lane for the streaming consensus path:
   per-chunk SSE frames are assembled by splicing changed fields into
@@ -191,32 +191,6 @@ TPU additions:
 * ``BATCH_MAX_ROWS`` — encoder rows per fused dispatch; a synchronized
   burst of requests chunks into this many rows per dispatch so the
   pipeline has pieces to overlap.  Default 512.
-* ``PACKING_ENABLED`` — continuous batching (serve/packing.py): embed
-  and consensus device work rides a ragged segment-id layout — many
-  variable-length sequences packed end-to-end per dense row — instead
-  of one padded row each, and requests with DIFFERENT candidate counts,
-  temperatures, and truncation caps share a dispatch.  Off by default
-  (the padded (R, N, S)-bucketed dispatch is the legacy-exact path);
-  requires a single-device embedder (mesh-sharded setups fall back to
-  padded automatically).
-* ``PACKING_ROW_TOKENS`` — token capacity L of one packed row; also the
-  per-sequence ceiling on the packed path (longer sequences fall back
-  to the padded dispatch per item).  Default 512.
-* ``PACKING_MAX_ROWS`` — max rows B per packed device call; the row dim
-  buckets to powers of two up to this, giving the small fixed
-  ("packed", B, L, K) executable set that replaces the (R, N, S)
-  lattice.  Default 8.
-* ``PACKING_MAX_SEGMENTS`` — max sequences K per packed row (the slot
-  dim of the pooled [B, K, H] output).  Default 64.
-* ``PREFIX_DEDUP`` — with packing: a consensus request's N candidates
-  sharing a long common prefix (the conversation) tokenize + embed that
-  prefix ONCE; candidate vectors compose as the token-count-weighted
-  normalized sum of prefix and suffix vectors (a defined approximation
-  contract — DESIGN.md "Continuous batching").  Default on (packed
-  mode only).
-* ``PREFIX_DEDUP_MIN_CHARS`` — minimum shared-prefix length (chars,
-  after cutting back to a whitespace boundary) worth deduping.
-  Default 48.
 * ``SCORE_CACHE_TTL`` — seconds a cached consensus result stays
   servable.  ``0`` (the default) disables the result cache entirely:
   the service behaves exactly as before the cache existed.  When >0,
@@ -853,16 +827,6 @@ class Config:
     host_fastpath: bool = False
     # reusable host staging buffers per (shape, dtype); 0 = no reuse
     staging_buffers: int = 2
-    # continuous batching (serve/packing.py): ragged segment-id packing
-    # on the embed/consensus device path; off = legacy padded dispatch
-    packing_enabled: bool = False
-    packing_row_tokens: int = 512  # packed row capacity L (and per-seq cap)
-    packing_max_rows: int = 8  # rows B per packed call (pow2-bucketed)
-    packing_max_segments: int = 64  # sequences K per packed row
-    # shared-prefix dedup across a consensus request's N candidates
-    # (packed mode only; composition contract in DESIGN.md)
-    prefix_dedup: bool = True
-    prefix_dedup_min_chars: int = 48
     # [(n_candidates, seq), ...] consensus shapes to pre-compile at
     # startup (WARMUP env, e.g. "64x112,64x128"); [] = lazy compiles
     warmup: list = field(default_factory=list)
@@ -1084,18 +1048,6 @@ class Config:
             ),
             host_fastpath=env_truthy(env.get("HOST_FASTPATH", "0")),
             staging_buffers=_non_negative_int(env, "STAGING_BUFFERS", 2),
-            packing_enabled=env_truthy(env.get("PACKING_ENABLED", "0")),
-            packing_row_tokens=max(
-                16, int(env.get("PACKING_ROW_TOKENS", 512))
-            ),
-            packing_max_rows=max(1, int(env.get("PACKING_MAX_ROWS", 8))),
-            packing_max_segments=max(
-                1, int(env.get("PACKING_MAX_SEGMENTS", 64))
-            ),
-            prefix_dedup=env_truthy(env.get("PREFIX_DEDUP", "1")),
-            prefix_dedup_min_chars=max(
-                1, int(env.get("PREFIX_DEDUP_MIN_CHARS", 48))
-            ),
             warmup=_parse_warmup(env.get("WARMUP")),
             warmup_r=_parse_warmup_r(env.get("WARMUP_R")),
             warmup_aot=env_truthy(env.get("WARMUP_AOT", "1")),

@@ -829,12 +829,6 @@ def build_app(
     batcher=None,
     batch_window_ms: float = 3.0,
     batch_max: int = 64,
-    packing: bool = False,
-    packing_row_tokens: int = 512,
-    packing_max_rows: int = 8,
-    packing_max_segments: int = 64,
-    prefix_dedup: bool = True,
-    prefix_dedup_min_chars: int = 48,
     reranker=None,
     judge=None,
     embed_cache=None,
@@ -873,12 +867,6 @@ def build_app(
             judge=judge,
             window_ms=batch_window_ms,
             max_batch=batch_max,
-            packing=packing,
-            packing_row_tokens=packing_row_tokens,
-            packing_max_rows=packing_max_rows,
-            packing_max_segments=packing_max_segments,
-            prefix_dedup=prefix_dedup,
-            prefix_dedup_min_chars=prefix_dedup_min_chars,
             embed_cache=embed_cache,
             watchdog=watchdog,
             max_queue_depth=(

@@ -114,10 +114,6 @@ async def _rescore_async(args) -> dict:
         max_batch=config.batch_max,
         pipeline_depth=config.batch_pipeline,
         max_rows=config.batch_max_rows,
-        packing=config.packing_enabled,
-        packing_row_tokens=config.packing_row_tokens,
-        packing_max_rows=config.packing_max_rows,
-        packing_max_segments=config.packing_max_segments,
         host_tokenizer_workers=config.host_tokenizer_workers,
         staging_buffers=config.staging_buffers,
     )

@@ -521,7 +521,7 @@ def test_mesh_audit_catches_wrong_math_in_committed_executable():
     emb._aot[emb._aot_key(("vote1", n, s))] = wrong
 
     findings, _ = audit_serving_executables(
-        emb, ref, specs=((n, s),), r_buckets=(), packed_buckets=()
+        emb, ref, specs=((n, s),), r_buckets=()
     )
     hits = [
         f for f in findings if f.rule == "JXA011" and "vote1" in f.path
@@ -542,9 +542,7 @@ def test_mesh_audit_catches_unwarmed_fault_ladder_rung():
     real = MeshFaultManager.warm_ladder
     MeshFaultManager.warm_ladder = lambda self, *a, **k: []
     try:
-        findings = _audit_fault_ladder(
-            "test-tiny", 4, 2, ((4, 16),), (), ()
-        )
+        findings = _audit_fault_ladder("test-tiny", 4, 2, ((4, 16),), ())
     finally:
         MeshFaultManager.warm_ladder = real
     missing = [
@@ -699,7 +697,7 @@ def test_budgets_flag_missing_and_stale_entries():
     """Injected regressions: an audited bucket with no committed entry,
     and a committed bucket the audit no longer lowers — both JXA010."""
     measured = dict(_IN_BAND)
-    measured["packed(b=8,l=64,k=8)"] = {"hbm_bytes": 1.0}
+    measured["many(r=4,n=8,s=16)"] = {"hbm_bytes": 1.0}
     findings = compare_budgets(measured, _BUDGETS, scope={"model": "toy"})
     assert [f.rule for f in findings] == ["JXA010"]
     assert "no committed budget" in findings[0].message

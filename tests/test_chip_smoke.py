@@ -124,11 +124,11 @@ def test_warmed_bucket_is_served_from_the_aot_table(dry_run):
 
 def test_kernel_checks_pass_and_interpret_mode_is_visible(dry_run):
     checks = dry_run["checks"]
-    assert len(checks) == 12 and all(c["pass"] for c in checks)
+    assert len(checks) == 11 and all(c["pass"] for c in checks)
     # on CPU no kernel is a Mosaic custom call, and the output says so;
     # the no-argument run REQUIRES the custom call in the compiled HLO
     kernels = [c for c in checks if not c["check"].startswith("forward")]
-    assert len(kernels) == 9 and not any(c["mosaic"] for c in kernels)
+    assert len(kernels) == 8 and not any(c["mosaic"] for c in kernels)
     assert dry_run["stages"]["serve"]["param_dtype"] == "float32"
 
 

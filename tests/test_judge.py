@@ -669,6 +669,27 @@ def test_consensus_judge_through_gateway_and_batcher(judge):
     assert "attention_work_over_causal" not in stats
 
 
+def test_padded_counters_of_a_judge_panel(judge):
+    """What the benchmark's ``packing.padding_share`` reads in a judge's
+    cell: a panel's real tokens against calls x JUDGE_MAX_TOKENS slots."""
+    import asyncio
+
+    from llm_weighted_consensus_tpu.serve.batcher import DeviceBatcher
+
+    batcher = DeviceBatcher(None, None, judge=judge)
+    texts = candidates(21, np.random.default_rng(4))
+    panel = [(7, 2.0), (8, 1.0)]
+    asyncio.new_event_loop().run_until_complete(
+        batcher.judge(texts, "w1 w2", panel)
+    )
+    counters = batcher.utilization()["padded"]
+    real = judge.prepare(texts, "w1 w2", panel).tokens
+    assert 0 < real < 2 * judge.max_tokens
+    assert counters["real_tokens"] == real
+    assert counters["slot_tokens"] == 2 * judge.max_tokens
+    assert counters["padding_waste"] == round(1.0 - real / (2 * 448), 4)
+
+
 def test_build_judge_gate_and_presets(monkeypatch):
     from llm_weighted_consensus_tpu.serve import Config
     from llm_weighted_consensus_tpu.serve.__main__ import build_judge

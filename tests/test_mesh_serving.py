@@ -5,7 +5,7 @@ What this pins, on the tier-1 8-virtual-device CPU mesh:
 
 * batcher end-to-end parity — the dp-sharded embedder returns the same
   results as the single-device embedder through the same DeviceBatcher,
-  on the padded, packed, and int8-pallas-interpret paths;
+  on the padded and int8-pallas-interpret paths;
 * per-(mesh-shape, bucket) AOT — ``aot_warmup`` on a mesh embedder
   compiles namespaced executables and post-warmup mesh traffic creates
   ZERO new jit specializations (the ISSUE acceptance);
@@ -55,13 +55,6 @@ def mesh_embedder(dp=DP, tp=TP, **kw):
     return emb
 
 
-PACKED_KW = dict(
-    packing=True,
-    packing_row_tokens=64,
-    packing_max_rows=4,
-    packing_max_segments=8,
-)
-
 TEXTS = [f"candidate number {i % 3} for the mesh" for i in range(6)]
 
 
@@ -102,37 +95,6 @@ def test_mesh_batcher_padded_matches_single_device():
     ] == 1
 
 
-def test_mesh_batcher_packed_matches_single_device():
-    """The packed path on the mesh embedder (rows padded to the dp
-    multiple, one packed dispatch) ≡ the single-device padded answers."""
-    ref = make_embedder()
-    emb = mesh_embedder()
-    assert emb.supports_packing()
-    metrics = Metrics()
-    batcher = DeviceBatcher(emb, metrics, window_ms=20.0, **PACKED_KW)
-
-    async def run():
-        return await asyncio.gather(
-            batcher.embed(TEXTS[:2]),
-            batcher.consensus(TEXTS[:3], 0.05),
-            batcher.consensus(TEXTS, 0.07),
-        )
-
-    (vecs, _), (conf_a, _), (conf_b, _) = go(run())
-    np.testing.assert_allclose(vecs, ref.embed_texts(TEXTS[:2]), atol=1e-5)
-    np.testing.assert_allclose(
-        conf_a,
-        np.asarray(ref.consensus_confidence(TEXTS[:3], temperature=0.05)),
-        atol=1e-5,
-    )
-    np.testing.assert_allclose(
-        conf_b,
-        np.asarray(ref.consensus_confidence(TEXTS, temperature=0.07)),
-        atol=1e-5,
-    )
-    assert metrics.snapshot()["series"]["device:batch:packed"]["count"] == 1
-
-
 def test_mesh_batcher_int8_pallas_matches_single_device():
     """The int8-pallas interpret-mode kernels run under GSPMD exactly as
     on one device: batcher answers agree with the single-device int8
@@ -161,27 +123,20 @@ def test_mesh_aot_zero_specializations_under_mixed_load():
     every (mesh-shape, bucket) executable and post-warmup mesh traffic
     creates zero jit-specialization growth."""
     emb = mesh_embedder()
-    timings = emb.aot_warmup(
-        [(N, S)], r_buckets=[R], packed_buckets=[(4, 64, 8)]
-    )
-    # consensus + embed + grouped + packed, one executable each
-    assert len(timings) == 4, [label for label, _ in timings]
+    timings = emb.aot_warmup([(N, S)], r_buckets=[R])
+    # consensus + embed + grouped, one executable each
+    assert len(timings) == 3, [label for label, _ in timings]
     # keys are namespaced per mesh shape — a 2x4 mesh could never
     # collide with these executables
     assert set(emb._aot) == {
         ("mesh", DP, TP, "vote1", N, S),
         ("mesh", DP, TP, "embed", 16, S),
         ("mesh", DP, TP, "many", R, N, S),
-        ("mesh", DP, TP, "packed", 4, 64, 8),
     }
 
     rng = np.random.default_rng(12)
     ids = rng.integers(3, TINY.vocab_size, (N, S)).astype(np.int32)
     mask = np.ones((N, S), np.int32)
-    pids = rng.integers(3, TINY.vocab_size, (4, 64)).astype(np.int32)
-    pseg = np.ones((4, 64), np.int32)
-    ppos = np.tile(np.arange(64, dtype=np.int32), (4, 1))
-    pstarts = np.zeros((4, 8), np.int32)
 
     stats0 = emb.jit_stats()["specializations"]
     out = [
@@ -195,7 +150,6 @@ def test_mesh_aot_zero_specializations_under_mixed_load():
                 np.stack([ids] * R), np.stack([mask] * R)
             )
         ),
-        np.asarray(emb.embed_packed(pids, pseg, ppos, pstarts)),
     ]
     assert all(np.all(np.isfinite(o)) for o in out)
     assert emb.jit_stats()["specializations"] == stats0

@@ -352,9 +352,9 @@ ATTN_SHAPES = {
 }
 
 
-def attn_case(shape, dtype, packed, impl, b=4, s=32, seed=0):
+def attn_case(shape, dtype, impl, b=4, s=32, seed=0):
     """One attention block's inputs: (config, layer params, x, mask_bias,
-    segment_ids or None, real-token mask)."""
+    real-token mask)."""
     from dataclasses import replace
 
     cfg = replace(TINY, attention_impl=impl, **ATTN_SHAPES[shape])
@@ -367,14 +367,8 @@ def attn_case(shape, dtype, packed, impl, b=4, s=32, seed=0):
     lens = rng.integers(s // 2, s + 1, b)
     pos = np.arange(s)[None, :]
     real = pos < lens[:, None]
-    if not packed:
-        bias = jnp.where(jnp.asarray(real)[:, None, None, :], 0.0, -1e9)
-        return cfg, layer, x, bias.astype(jnp.float32), None, real
-    # two packed sequences a row, then pad slots
-    seg = np.where(pos < lens[:, None] // 2, 1, np.where(real, 2, 0))
-    same = (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] > 0)
-    bias = jnp.asarray(np.where(same, 0.0, -1e9), jnp.float32)[:, None]
-    return cfg, layer, x, bias, jnp.asarray(seg, jnp.int32), real
+    bias = jnp.where(jnp.asarray(real)[:, None, None, :], 0.0, -1e9)
+    return cfg, layer, x, bias.astype(jnp.float32), real
 
 
 def walk_eqns(jaxpr):
@@ -392,19 +386,16 @@ def walk_eqns(jaxpr):
                     yield from walk_eqns(inner)
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
-def test_fused_attention_stays_in_the_encoder_layout(packed):
+def test_fused_attention_stays_in_the_encoder_layout():
     """The structural guard that the relayout copies cannot come back:
     around the kernel no transpose, and no array with hd as its minor
     dimension (half-filled 128-lane tiles)."""
-    cfg, layer, x, bias, seg, _ = attn_case(
-        "hd64-h256", jnp.float32, packed, "fused"
-    )
+    cfg, layer, x, bias, _ = attn_case("hd64-h256", jnp.float32, "fused")
     hd = cfg.head_dim
     assert hd not in (x.shape[1], cfg.hidden_size, cfg.intermediate_size)
     jaxpr = jax.make_jaxpr(
-        lambda x, bias, seg: bert._attention(x, layer, bias, cfg, seg)
-    )(x, bias, seg)
+        lambda x, bias: bert._attention(x, layer, bias, cfg)
+    )(x, bias)
     eqns = list(walk_eqns(jaxpr.jaxpr))
     names = [e.primitive.name for e in eqns]
     assert names.count("pallas_call") == 1
@@ -416,19 +407,18 @@ def test_fused_attention_stays_in_the_encoder_layout(packed):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
 @pytest.mark.parametrize("shape", list(ATTN_SHAPES))
-def test_fused_attention_block_matches_einsum(shape, packed, dtype):
+def test_fused_attention_block_matches_einsum(shape, dtype):
     dt = jnp.dtype(dtype)
-    cfg, layer, x, bias, seg, real = attn_case(shape, dt, packed, "fused")
+    cfg, layer, x, bias, real = attn_case(shape, dt, "fused")
     from dataclasses import replace
 
-    got = bert._attention(x, layer, bias, cfg, seg)
+    got = bert._attention(x, layer, bias, cfg)
     want = bert._attention(
-        x, layer, bias, replace(cfg, attention_impl="einsum"), seg
+        x, layer, bias, replace(cfg, attention_impl="einsum")
     )
     assert got.shape == want.shape == x.shape and got.dtype == dt
-    # pad-slot query rows attend nothing and are dropped by pooling
+    # pad-slot query rows are dropped by pooling
     rows = np.asarray(real)[:, :, None]
     tol = 2e-5 if dt == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -501,16 +491,13 @@ def test_use_fused_attention_policy(
     assert bert._use_fused_attention(cfg, b, s, hd, dt) is fused
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
-def test_auto_attention_off_tpu_is_the_einsum_path(packed):
+def test_auto_attention_off_tpu_is_the_einsum_path():
     """What every bucket under 512 runs (and every bucket off a TPU):
     the einsum path, its head reshape in place and no kernel."""
-    cfg, layer, x, bias, seg, _ = attn_case(
-        "hd64-h256", jnp.float32, packed, "auto"
-    )
+    cfg, layer, x, bias, _ = attn_case("hd64-h256", jnp.float32, "auto")
     jaxpr = jax.make_jaxpr(
-        lambda x, bias, seg: bert._attention(x, layer, bias, cfg, seg)
-    )(x, bias, seg)
+        lambda x, bias: bert._attention(x, layer, bias, cfg)
+    )(x, bias)
     eqns = list(walk_eqns(jaxpr.jaxpr))
     names = [e.primitive.name for e in eqns]
     assert "pallas_call" not in names

@@ -51,10 +51,9 @@ def instructions(hlo_text):
     )
 
 
-@pytest.mark.parametrize("packed", [False, True], ids=["padded", "packed"])
 @pytest.mark.parametrize("b", [64, 512], ids=["solo", "group-of-8"])
 def test_bge_large_attention_block_compiles_without_copies(
-    one_chip, compiled_kernels, b, packed
+    one_chip, compiled_kernels, b
 ):
     """bert._attention at the benchmark's bucket (bge-large, 512 tokens,
     one request and a full group), bf16: Mosaic takes the served block,
@@ -75,17 +74,13 @@ def test_bge_large_attention_block_compiles_without_copies(
         )["layers"],
     )
     x = arg((b, s, h), dt)
-    if packed:
-        bias, seg = arg((b, 1, s, s), jnp.float32), arg((b, s), jnp.int32)
-    else:
-        bias, seg = arg((b, 1, 1, s), jnp.float32), None
+    bias = arg((b, 1, 1, s), jnp.float32)
     compiled = (
-        jax.jit(lambda x, p, bias, seg: bert._attention(x, p, bias, cfg, seg))
-        .lower(x, layer, bias, seg)
+        jax.jit(lambda x, p, bias: bert._attention(x, p, bias, cfg))
+        .lower(x, layer, bias)
         .compile()
     )
     found = instructions(compiled.as_text())
-    kernel = "fused_attention_tiled_seg" if packed else "fused_attention_tiled"
     names = [name for name, op, _ in found]
     # Mosaic kernels only: the compiler's own custom calls (a weight's
     # prefetch into fast memory) are not the program's
@@ -94,7 +89,7 @@ def test_bge_large_attention_block_compiles_without_copies(
         for name, op, rest in found
         if op == "custom-call" and '"tpu_custom_call"' in rest
     ]
-    assert kernels == [kernel], names
+    assert kernels == ["fused_attention_tiled"], names
     # "copy-start"/"copy-done" are prefetches, not relayouts
     assert not [n for n, op, _ in found if op in ("copy", "transpose")], names
 

@@ -1,0 +1,8 @@
+"""forward.share.unscoped.phi4flash: per cent of the judge programs' device time under
+the ``unscoped`` scopes (``phi4flash_scopes.GROUPS``)."""
+
+import phi4flash_scopes
+
+
+def reduce(ctx):
+    return phi4flash_scopes.share(ctx, "unscoped")

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 
@@ -626,4 +627,88 @@ AFMOE_TEST_TINY = AfmoeConfig(
         "sliding_attention",
     ),
     sliding_window=24,
+)
+
+
+@dataclass(frozen=True)
+class Phi4FlashConfig:
+    """A decoder that feeds a decoder (``model_type`` ``phi4flash``,
+    microsoft/Phi-4-mini-flash-reasoning: SambaY).  Layers 0 .. ``kv_layer``
+    (the SELF decoder) alternate Mamba layers (even) with differential
+    attention (odd): sliding over ``sliding_window`` keys but for the last,
+    ``kv_layer``, which is full and whose keys and values are THE cache.
+    The layers behind it (the CROSS decoder) alternate gated memory units
+    (even: a gate on the scan output of Mamba layer ``kv_layer - 1`` at the
+    same position) with cross attention (odd: a query against ``kv_layer``'s
+    keys and values, none of its own).  LayerNorm with a bias, a SwiGLU MLP
+    every layer, a tied head, no rotary turn anywhere: a judge behind ``POST
+    /consensus`` ``scorer: judge`` (models/sambay.py).
+
+    Differential attention pairs consecutive heads: query heads (2j, 2j + 1)
+    are the two softmaxes of pair j, key heads (2m, 2m + 1) the keys of each
+    for the query pairs with j // (``num_heads`` / ``num_kv_heads``) == m, and
+    value heads (2m, 2m + 1) side by side are ONE value head of twice
+    ``head_dim`` that both softmaxes weigh."""
+
+    vocab_size: int = 200064
+    hidden_size: int = 2560
+    num_layers: int = 32
+    num_heads: int = 40
+    num_kv_heads: int = 20
+    intermediate_size: int = 10240
+    sliding_window: int = 512  # keys a query attends, its own among them
+    mb_per_layer: int = 2  # a Mamba layer every second layer of the self decoder
+    d_state: int = 16
+    d_conv: int = 4
+    expand: int = 2
+    layer_norm_eps: float = 1e-5
+    # "int8": every dense product through quant.dense_int8; embedding (the
+    # tied head), norms, lambdas, biases and the scan's own parameters keep
+    # the parameters' dtype
+    quantize: str = "none"
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_heads
+
+    @property
+    def d_inner(self) -> int:
+        return self.expand * self.hidden_size
+
+    @property
+    def dt_rank(self) -> int:
+        return -(-self.hidden_size // 16)
+
+    @property
+    def kv_layer(self) -> int:
+        """The full-attention layer whose keys and values every cross layer
+        reads: the last of the self decoder, which is half the stack and one."""
+        return self.num_layers // 2 + 1
+
+    def kind(self, layer: int) -> str:
+        """``mamba`` | ``sliding`` | ``full`` | ``memory`` | ``cross``."""
+        if layer % self.mb_per_layer == 0:
+            return "mamba" if layer < self.kv_layer else "memory"
+        if layer < self.kv_layer:
+            return "sliding"
+        return "full" if layer == self.kv_layer else "cross"
+
+    def lambda_init(self, layer: int) -> float:
+        """The differential weight's constant part at depth ``layer``."""
+        return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+# microsoft/Phi-4-mini-flash-reasoning config.json (``phi4flash``), uncut
+PHI_4_MINI_FLASH_REASONING = Phi4FlashConfig()
+# every kind of layer in eight: 0..3 the self decoder's pairs (Mamba, sliding),
+# 4 the memory's Mamba, 5 the full layer, 6 a memory unit, 7 a cross layer; a
+# window shorter than the tests' sequences, two query pairs a key pair as published
+PHI4FLASH_TEST_TINY = Phi4FlashConfig(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=8,
+    num_heads=8,
+    num_kv_heads=4,
+    intermediate_size=128,
+    sliding_window=8,
 )

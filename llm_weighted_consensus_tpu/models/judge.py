@@ -8,7 +8,7 @@ is a few calls of one causal decoder over the same candidates under
 differently seeded ballots, and what an upstream judge's ``top_logprobs``
 would have carried is read from the decoder's own head.
 
-Five decoders serve (``JUDGE_PRESETS``; the preset's configuration class
+Six decoders serve (``JUDGE_PRESETS``; the preset's configuration class
 says which module): ``models/glm_moe.py`` runs ``glm-4.7-flash`` (latent
 attention, every expert held), ``glm-5.2`` (a learned sparse selection in
 front of latent attention, a share of the router's experts held) and
@@ -21,15 +21,21 @@ with gated full attention, a share held), and ``models/afmoe.py`` the fifth,
 ``trinity-large-preview`` (grouped-query attention of two kinds: sliding layers
 that turn their heads over a window of 4096 keys, full layers that turn
 nothing; an elementwise gate, four norms a layer; a windowed cache of keys and
-values beside the whole-length one; a share held).  The panel's protocol is no
+values beside the whole-length one; a share held), and ``models/sambay.py`` the
+sixth, ``phi-4-mini-flash-reasoning`` (a decoder that feeds a decoder: Mamba
+layers and differential attention over a window, one full layer whose keys
+eight layers read, gated memory units; no experts; its last layers run at the
+row the panel reads and nowhere else).  The panel's protocol is no
 part of any: ``judge_panel`` below is ONE jitted program over what a decoder
 module gives,
 
-  ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h], a
-      cache a layer (of whatever kind the layer keeps), pairs routed a sparse
-      layer; what else it counted on the device goes into the ``tallies``
-      dict by name (``index_keys``: pairs chosen and causal pairs;
-      ``window_keys``: pairs inside the sliding layers' bands and causal pairs)
+  ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h] (or
+      [b, 1, h]: the row at ``lens - 1`` alone, from a decoder that computed
+      no other), a cache a layer (of whatever kind the layer keeps), pairs
+      routed a sparse layer; what else it counted on the device goes into the
+      ``tallies`` dict by name (``index_keys``: pairs chosen and causal pairs;
+      ``window_keys``: pairs inside the sliding layers' bands and causal pairs;
+      ``layer_positions``: (position, layer) pairs computed, and layers x slots)
   ``decode_step(params, token, lens, caches, config)``  -> hidden [b, h]
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
@@ -77,11 +83,12 @@ from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
 from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
-from . import afmoe, glm_moe, qwen3_next
+from . import afmoe, glm_moe, qwen3_next, sambay
 from .configs import (
     AFMOE_TEST_TINY, DOTS3_NOTE_PREV, DOTS3_TEST_TINY, GLM_4_7_FLASH, GLM_5_2,
-    GLM_DSA_TEST_TINY, GLM_TEST_TINY, QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY,
-    TRINITY_LARGE_PREVIEW, AfmoeConfig, GlmMoeLiteConfig, Qwen3NextConfig,
+    GLM_DSA_TEST_TINY, GLM_TEST_TINY, PHI4FLASH_TEST_TINY, PHI_4_MINI_FLASH_REASONING,
+    QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY, TRINITY_LARGE_PREVIEW, AfmoeConfig,
+    GlmMoeLiteConfig, Phi4FlashConfig, Qwen3NextConfig,
 )
 from .tokenizer import BaseTokenizer, load_tokenizer
 
@@ -96,8 +103,13 @@ JUDGE_PRESETS = {
     "qwen3-next-test-tiny": QWEN3_NEXT_TEST_TINY,
     "trinity-large-preview": TRINITY_LARGE_PREVIEW,
     "afmoe-test-tiny": AFMOE_TEST_TINY,
+    "phi-4-mini-flash-reasoning": PHI_4_MINI_FLASH_REASONING,
+    "phi4flash-test-tiny": PHI4FLASH_TEST_TINY,
 }
-_DECODERS = {GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next, AfmoeConfig: afmoe}
+_DECODERS = {
+    GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next, AfmoeConfig: afmoe,
+    Phi4FlashConfig: sambay,
+}
 DEFAULT_PANEL = ((0, 1.0), (1, 1.0), (2, 1.0))  # (ballot seed, weight) a call
 MAX_PANEL = 8
 _LETTERS = len(ALPHABET)
@@ -138,7 +150,10 @@ def judge_panel(
     b = ids.shape[0]
     tallies: dict = {}
     hidden, caches, loads = decoder.prefill(params, ids, config, lens=lens, tallies=tallies)
-    last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
+    if hidden.shape[1] == 1:  # a decoder that ran its last layers at the row read alone
+        last = hidden[:, 0]
+    else:
+        last = jnp.take_along_axis(hidden, (lens - 1)[:, None, None], axis=1)[:, 0]
     first = _masked(decoder.head_logprobs(params, last, config), letter_ids, first_valid)
     chosen = jnp.argmax(first, axis=1).astype(jnp.int32)
     out = {
@@ -267,6 +282,11 @@ class TpuJudge:
             # and those inside its band
             "window_keys_causal": 0,
             "window_keys_band": 0,
+            # (position, layer) pairs, summed over dispatches' prefills: those a
+            # decoder computed (one that runs its last layers at the row read
+            # alone), and layers x slots
+            "layer_positions_run": 0,
+            "layer_positions_whole": 0,
             "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
         self._held = len(self._stats["expert_tokens"])
@@ -398,11 +418,14 @@ class TpuJudge:
         confidence = tally / sum(call.weight for call in prepared.calls)
         self._count(
             prepared, np.asarray(out["expert_load"]), out.get("index_keys"),
-            out.get("window_keys"),
+            out.get("window_keys"), out.get("layer_positions"),
         )
         return confidence, prepared.tokens, ballots
 
-    def _count(self, prepared: PreparedPanel, load, index_keys=None, window_keys=None) -> None:
+    def _count(
+        self, prepared: PreparedPanel, load, index_keys=None, window_keys=None,
+        layer_positions=None,
+    ) -> None:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
@@ -433,6 +456,10 @@ class TpuJudge:
                 band, causal = np.asarray(window_keys)
                 s["window_keys_band"] += int(band)
                 s["window_keys_causal"] += int(causal)
+            if layer_positions is not None:
+                run, whole = np.asarray(layer_positions)
+                s["layer_positions_run"] += int(run)
+                s["layer_positions_whole"] += int(whole)
             if load.size:
                 totals = load.sum(axis=0)
                 s["expert_pairs_here"] += int(totals.sum())

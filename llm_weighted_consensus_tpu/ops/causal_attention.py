@@ -20,7 +20,10 @@ it in whole columns with zero lanes (``models/glm_moe.py``), which leaves
 every q.k what it was and costs the MXU, which contracts in 128s, nothing.
 Keys and values may have fewer heads than the queries (``kv_heads``): a
 query head's key block is found by ``head // group`` in the index map, and
-with a key head a query head the index map is the one it always was.
+with a key head a query head the index map is the one it always was.  The
+values may have fewer heads still (``value_heads``: a differential pair's two
+key heads weigh ONE value head of twice the width, ``models/sambay.py``); a
+query head's value block is found the same way, by its own group.
 
 Two jitted names over one body.  ``causal_attention_blockwise`` attends every
 key at or before the query (or a selection among them);
@@ -356,12 +359,17 @@ def _kernel(
         o_ref[...] = (acc_ref[...] * norm).astype(o_ref.dtype)
 
 
-def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpret, window=0):
+def _attend(
+    q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpret, window=0,
+    value_heads=0,
+):
     """The pallas_call behind both jitted names below."""
     b, s, width = q.shape
     hd = width // heads
     group = heads // (kv_heads or heads)
-    dv = v.shape[-1] * group // heads  # a value head may be narrower than a key head
+    # a value head may be narrower than a key head, or wider and shared by several
+    value_group = heads // value_heads if value_heads else group
+    dv = v.shape[-1] * value_group // heads
     if interpret is None:
         interpret = _interpret()
     if hd * heads != width:
@@ -378,7 +386,7 @@ def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpre
         )
     if group * (kv_heads or heads) != heads or k.shape[-1] * group != width:
         raise ValueError(f"{heads} query heads on keys of width {k.shape[-1]}")
-    if dv * heads != v.shape[-1] * group:
+    if dv * heads != v.shape[-1] * value_group:
         raise ValueError(f"{heads} query heads on values of width {v.shape[-1]}")
     if window and keep is not None:
         raise ValueError("a windowed layer attends its band, not a selection")
@@ -400,6 +408,9 @@ def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpre
     def kv_index(bi, h, step, qi_of_step, ki_of_step):
         return bi, ki_of_step[step], h if group == 1 else h // group
 
+    def value_index(bi, h, step, qi_of_step, ki_of_step):
+        return bi, ki_of_step[step], h // value_group
+
     def keep_index(bi, h, step, qi_of_step, ki_of_step):
         return bi, qi_of_step[step], ki_of_step[step]
 
@@ -414,7 +425,7 @@ def _attend(q, k, v, keep, *, heads, scale, kv_heads, block_q, block_k, interpre
             in_specs=[
                 pl.BlockSpec((None, bq, hd), q_index),
                 pl.BlockSpec((None, bk, hd), kv_index),
-                pl.BlockSpec((None, bk, dv), kv_index),
+                pl.BlockSpec((None, bk, dv), value_index if value_heads else kv_index),
                 *[pl.BlockSpec((None, bq, bk), keep_index) for _ in selection],
             ],
             out_specs=pl.BlockSpec((None, bq, dv), q_index),
@@ -462,11 +473,14 @@ def causal_attention_blockwise(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("heads", "scale", "window", "kv_heads", "block_q", "block_k", "interpret"),
+    static_argnames=(
+        "heads", "scale", "window", "kv_heads", "value_heads", "block_q", "block_k",
+        "interpret",
+    ),
 )
 def window_attention_blockwise(
     q, k, v, *, heads: int, scale: float, window: int, kv_heads: int = 0,
-    block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
+    value_heads: int = 0, block_q: int = 0, block_k: int = 0, interpret: bool | None = None,
 ):
     """``causal_attention_blockwise`` over a WINDOW: position i attends the
     ``window`` positions i - window < j <= i (its own among them).  The same
@@ -476,24 +490,25 @@ def window_attention_blockwise(
     old edge crosses goes in the diagonal's stripes mirrored where the block
     splits and the window covers it, else as one whole masked tile, as an
     unsplit diagonal block does) and the blocks, which follow the window
-    (``window_block``).  Under its own jitted name, so that a device trace
-    tells the two apart."""
+    (``window_block``).  With ``value_heads`` v is [b, s, value_heads * dv]
+    and query head h weighs value head ``h // (heads / value_heads)``.  Under
+    its own jitted name, so that a device trace tells the two apart."""
     return _attend(
         q, k, v, None, heads=heads, scale=scale, kv_heads=kv_heads, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window,
+        block_k=block_k, interpret=interpret, window=window, value_heads=value_heads,
     )
 
 
 def causal_attention_einsum(
-    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0, window: int = 0
+    q, k, v, keep=None, *, heads: int, scale: float, kv_heads: int = 0, window: int = 0,
+    value_heads: int = 0,
 ):
     """The kernels' plain twin: whole [s, s] scores (tests, tiny sizes)."""
     b, s, width = q.shape
     hd = width // heads
     qh, kh = (x.reshape(b, s, -1, hd) for x in (q, k))
-    vh = v.reshape(b, s, kh.shape[2], -1)
-    if kv_heads and kv_heads != heads:
-        kh, vh = (jnp.repeat(x, heads // kv_heads, axis=2) for x in (kh, vh))
+    vh = v.reshape(b, s, value_heads or kh.shape[2], -1)
+    kh, vh = (jnp.repeat(x, heads // x.shape[2], axis=2) for x in (kh, vh))
     scores = jnp.einsum(
         "bqhd,bkhd->bhqk", qh, kh, preferred_element_type=jnp.float32
     ) * scale

@@ -349,12 +349,17 @@ ATTN_SHAPES = {
     "hd64-h256": dict(hidden_size=256, num_heads=4),
     "hd32-h384": dict(hidden_size=384, num_heads=12),
     "test-tiny": dict(hidden_size=64, num_heads=4),
+    # a head fills its tile: no idle lane beside it for the row's sum
+    "hd128-h256": dict(hidden_size=256, num_heads=2),
 }
 
 
-def attn_case(shape, dtype, impl, b=4, s=32, seed=0):
+def attn_case(shape, dtype, impl, b=4, s=32, seed=0, keys="ragged", gain=1.0):
     """One attention block's inputs: (config, layer params, x, mask_bias,
-    real-token mask)."""
+    real-token mask).  ``keys``: every row ragged from s // 2; or row 0 with
+    ``one`` real key somewhere; or row 0 all padding but the ``first`` token.
+    ``gain`` multiplies x: at 30 the scores pass what ``exp`` can hold
+    unshifted."""
     from dataclasses import replace
 
     cfg = replace(TINY, attention_impl=impl, **ATTN_SHAPES[shape])
@@ -363,10 +368,14 @@ def attn_case(shape, dtype, impl, b=4, s=32, seed=0):
         bert.init_params(jax.random.PRNGKey(seed), cfg, dtype=dtype)["layers"],
     )
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((b, s, cfg.hidden_size)), dtype)
+    x = jnp.asarray(gain * rng.standard_normal((b, s, cfg.hidden_size)), dtype)
     lens = rng.integers(s // 2, s + 1, b)
     pos = np.arange(s)[None, :]
     real = pos < lens[:, None]
+    if keys == "one":
+        real[0] = pos[0] == rng.integers(1, s)
+    elif keys == "first":
+        real[0] = pos[0] == 0
     bias = jnp.where(jnp.asarray(real)[:, None, None, :], 0.0, -1e9)
     return cfg, layer, x, bias.astype(jnp.float32), real
 
@@ -406,11 +415,33 @@ def test_fused_attention_stays_in_the_encoder_layout():
             assert not shape or shape[-1] != hd, (e.primitive.name, shape)
 
 
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", list(ATTN_SHAPES))
-def test_fused_attention_block_matches_einsum(shape, dtype):
+def _attn_block_cases():
+    """(shape, dtype, what ``attn_case`` takes beside them), with its id."""
+    f32, bf16 = "float32", "bfloat16"
+    tiled = ["hd64-h256", "hd32-h384", "hd128-h256"]
+    cases = [(w, d, {}) for w in [*tiled[:2], "test-tiny", tiled[2]]
+             for d in (f32, bf16)]
+    # the kernel's own lengths: one lane group of keys, the cells' bucket,
+    # the longest it takes (f32 at 1024 is past VMEM on the chip, not here)
+    cases += [(w, d, dict(b=2, s=128)) for w in tiled for d in (f32, bf16)]
+    cases += [(w, d, dict(b=2, s=512))
+              for w, d in zip(tiled, (bf16, f32, bf16))]
+    cases += [(tiled[0], bf16, dict(b=1, s=1024)),
+              (tiled[2], f32, dict(b=1, s=1024))]
+    # what the row's sum and maximum must survive: a row of one real key, a
+    # row of [CLS] alone, scores that ``exp`` unshifted would overflow on
+    cases += [(w, d, dict(s=128, keys=keys))
+              for w in tiled for keys in ("one", "first") for d in (f32, bf16)]
+    cases += [(w, f32, dict(s=128, gain=30.0)) for w in tiled]
+    for shape, dtype, case in cases:
+        tag = "".join(f"-{k[0]}{v}" for k, v in case.items() if k != "b")
+        yield pytest.param(shape, dtype, case, id=f"{shape}-{dtype}{tag}")
+
+
+@pytest.mark.parametrize("shape, dtype, case", _attn_block_cases())
+def test_fused_attention_block_matches_einsum(shape, dtype, case):
     dt = jnp.dtype(dtype)
-    cfg, layer, x, bias, real = attn_case(shape, dt, "fused")
+    cfg, layer, x, bias, real = attn_case(shape, dt, "fused", **case)
     from dataclasses import replace
 
     got = bert._attention(x, layer, bias, cfg)
@@ -418,9 +449,18 @@ def test_fused_attention_block_matches_einsum(shape, dtype):
         x, layer, bias, replace(cfg, attention_impl="einsum")
     )
     assert got.shape == want.shape == x.shape and got.dtype == dt
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    if case.get("gain"):
+        # the case is what it says: the largest score is past exp's range
+        q = bert._dense_cfg(x, layer["attn_q"], cfg)
+        k = bert._dense_cfg(x, layer["attn_k"], cfg)
+        hd = cfg.head_dim
+        top = jnp.einsum("bqd,bkd->bqk", q[..., :hd], k[..., :hd]).max()
+        assert top / hd**0.5 > 89.0, top
     # pad-slot query rows are dropped by pooling
     rows = np.asarray(real)[:, :, None]
-    tol = 2e-5 if dt == jnp.float32 else 2e-2
+    # the values, and so the context, grow with the gain
+    tol = (2e-5 if dt == jnp.float32 else 2e-2) * case.get("gain", 1.0)
     np.testing.assert_allclose(
         np.asarray(got, np.float32) * rows,
         np.asarray(want, np.float32) * rows,
@@ -434,34 +474,50 @@ def fit_need(b, s, nh, hd, itemsize, kk):
 
     g = attention.heads_per_block(nh, hd)
     width = -(-g * hd // 128) * 128
-    return (kk // g) * (8 * s * width * itemsize + 16 * s * 4) + 2 * s * s * 4
+    # heads that share a tile: result, numerators, denominators, [v | 1]
+    shared = hd < 128 and 128 % hd == 0 and g * hd % 128 == 0
+    products = s * 128 * (3 * 4 + itemsize if shared else 4)
+    blocks = (kk // g) * (8 * s * width * itemsize + 16 * s * 4)
+    return blocks + 2 * s * s * 4 + products
 
 
 @pytest.mark.parametrize(
-    "b, s, nh, hd, itemsize, g",
+    "b, s, nh, hd, itemsize, g, rows",
     [
-        (64, 512, 16, 64, 2, 2),  # bge-large, one request
-        (512, 512, 16, 64, 2, 2),  # bge-large, a full group of 8
-        (64, 512, 12, 32, 2, 4),  # bge-small: four heads to 128 lanes
-        (64, 512, 12, 64, 2, 2),  # bge-base
-        (4, 32, 4, 16, 4, 4),  # test-tiny: the whole hidden, under a tile
-        (3, 512, 16, 64, 2, 2),  # an odd batch: one row a step
-        (64, 512, 3, 48, 2, 0),  # 144 lanes: no whole tiles, not under one
-        (64, 1024, 16, 64, 4, 2),  # f32 at 1024: the score tiles alone
+        (64, 512, 16, 64, 2, 2, 4),  # bge-large, one request
+        (512, 512, 16, 64, 2, 2, 4),  # bge-large, a full group of 8
+        (64, 512, 12, 32, 2, 4, 4),  # bge-small: four heads to 128 lanes
+        (64, 512, 12, 64, 2, 2, 4),  # bge-base
+        (4, 32, 4, 16, 4, 4, 4),  # test-tiny: the whole hidden, under a tile
+        (3, 512, 16, 64, 2, 2, 1),  # an odd batch: one row a step
+        (64, 512, 3, 48, 2, 0, 0),  # 144 lanes: no whole tiles, not under one
+        (64, 1024, 16, 64, 4, 2, 0),  # f32 at 1024: the score tiles alone
+        (64, 1024, 16, 64, 2, 2, 1),  # bf16 at 1024: one row a step, served
+        (64, 512, 8, 128, 2, 1, 4),  # a head a tile: the sum stays on the tile
+        (64, 1024, 8, 128, 2, 1, 2),
     ],
 )
-def test_best_heads_per_step_is_the_new_blocks_fit(b, s, nh, hd, itemsize, g):
+def test_best_heads_per_step_is_the_new_blocks_fit(
+    b, s, nh, hd, itemsize, g, rows
+):
     from llm_weighted_consensus_tpu.ops import attention
 
     assert attention.heads_per_block(nh, hd) == g
     kk = attention.best_heads_per_step(b, s, nh, hd, itemsize)
     budget = attention.VMEM_BUDGET
+    assert kk == rows * g
     if g == 0 or fit_need(b, s, nh, hd, itemsize, g) > budget:
         assert kk == 0  # callers fall back to einsum
         return
-    rows = kk // g
-    assert kk == rows * g and rows >= 1 and b % rows == 0
+    assert rows >= 1 and b % rows == 0
     assert fit_need(b, s, nh, hd, itemsize, kk) <= budget
+    # the largest: twice the rows would not divide b, pass the step's
+    # most, or not fit
+    assert (
+        b % (2 * rows)
+        or 2 * rows > attention.MAX_ROWS_PER_STEP
+        or fit_need(b, s, nh, hd, itemsize, 2 * kk) > budget
+    )
 
 
 @pytest.mark.parametrize(

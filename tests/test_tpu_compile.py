@@ -51,6 +51,17 @@ def instructions(hlo_text):
     )
 
 
+def mosaic_kernels(found):
+    """Names of the Mosaic kernels among ``instructions``: the compiler's
+    own custom calls (a weight's prefetch into fast memory) are not the
+    program's."""
+    return [
+        name.split(".")[0]
+        for name, op, rest in found
+        if op == "custom-call" and '"tpu_custom_call"' in rest
+    ]
+
+
 @pytest.mark.parametrize("b", [64, 512], ids=["solo", "group-of-8"])
 def test_bge_large_attention_block_compiles_without_copies(
     one_chip, compiled_kernels, b
@@ -82,16 +93,39 @@ def test_bge_large_attention_block_compiles_without_copies(
     )
     found = instructions(compiled.as_text())
     names = [name for name, op, _ in found]
-    # Mosaic kernels only: the compiler's own custom calls (a weight's
-    # prefetch into fast memory) are not the program's
-    kernels = [
-        name.split(".")[0]
-        for name, op, rest in found
-        if op == "custom-call" and '"tpu_custom_call"' in rest
-    ]
-    assert kernels == ["fused_attention_tiled"], names
+    assert mosaic_kernels(found) == ["fused_attention_tiled"], names
     # "copy-start"/"copy-done" are prefetches, not relayouts
     assert not [n for n, op, _ in found if op in ("copy", "transpose")], names
+
+
+@pytest.mark.parametrize(
+    "s, nh, hd, rows",
+    [
+        (512, 12, 32, 4),  # bge-small: four heads a tile
+        (512, 8, 128, 4),  # a head a tile: no idle lane for the sum
+        (1024, 16, 64, 1),  # the longest bucket: one row a step within VMEM
+        (1024, 8, 128, 2),
+    ],
+    ids=["hd32", "hd128", "hd64-s1024", "hd128-s1024"],
+)
+def test_attention_kernel_compiles_at_every_head_width(
+    one_chip, compiled_kernels, s, nh, hd, rows
+):
+    """What the interpreter cannot refuse: the lane turn and the selects of
+    the form that takes the row's sum from the second product's idle lanes
+    (heads of 32 and 64), the plain form beside it (heads of 128), each at
+    the rows a step ``best_heads_per_step`` reckons to fit."""
+    kk = attention.best_heads_per_step(64, s, nh, hd, 2)
+    assert kk == rows * attention.heads_per_block(nh, hd)
+    x = jax.ShapeDtypeStruct((64, s, nh * hd), jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((64, s), jnp.float32, sharding=one_chip)
+    kernel = functools.partial(
+        attention.fused_attention_tiled,
+        scale=hd**-0.5, nh=nh, heads_per_step=kk,
+    )
+    compiled = jax.jit(kernel).lower(x, x, x, bias).compile()
+    found = instructions(compiled.as_text())
+    assert mosaic_kernels(found) == ["fused_attention_tiled"], found
 
 
 # -- the judge's kernels at the configuration's widths (ISSUE 27) -------------

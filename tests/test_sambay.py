@@ -697,25 +697,72 @@ def long_memory(b, s, channels, n=16, seed=0):
 
 
 @pytest.mark.parametrize(
-    "s, chunk, block, lens",
+    "s, chunk, block, lens, channels, n",
     [
-        (256, 64, 0, [256, 137]),  # four chunks; a call that ends inside the third
-        (300, 64, 0, [300, 65]),  # a length that is no whole chunk: padded behind
-        (192, 32, 128, [100, 192]),  # two channel blocks, six chunks
-        (40, 128, 0, [40, 9]),  # shorter than one chunk
+        (256, 64, 0, [256, 137], 256, 16),  # four chunks; a call that ends inside the third
+        (300, 64, 0, [300, 65], 256, 16),  # a length that is no whole chunk: padded behind
+        (192, 32, 128, [100, 192], 256, 16),  # two channel blocks, six chunks
+        (40, 128, 0, [40, 9], 256, 16),  # shorter than one chunk
+        # a call that ends INSIDE a trip's eight positions, on its last and on the next trip's first
+        (256, 64, 0, [71, 135], 256, 16),
+        (256, 64, 0, [72, 136], 256, 16),
+        (256, 64, 0, [73, 129], 256, 16),
+        (256, 64, 0, [64, 128], 256, 16),  # ... and on a chunk's edge
+        (77, 32, 0, [77, 50], 256, 16),  # no multiple of eight: three chunks, the last padded
+        (9, 128, 0, [9, 3], 256, 16),  # one chunk of sixteen, no packed tile of rows
+        (96, 32, 0, [0, 1], 256, 16),  # a call of nothing leaves zeros; a call of one token
+        (96, 32, 0, [96, 41], 2048, 16),  # ``_GROUP`` whole, twice: the groups' loop goes round
+        (96, 32, 0, [96, 41], 768, 16),  # no whole ``_GROUP`` nor 512: 256 at a time
+        (96, 32, 0, [96, 41], 128, 16),  # one lane group
+        (96, 32, 0, [96, 41], 384, 16),  # three lane groups: one at a time
+        (96, 32, 0, [96, 41], 72, 16),  # a width that is no multiple of 128: the lanes are the channels
+        (96, 32, 0, [96, 41], 256, 8),  # a state of one group of eight sublanes
+        (96, 32, 0, [96, 41], 256, 32),  # ... and of four: halved down to eight first
     ],
 )
-def test_the_scan_kernel_carries_its_state_from_chunk_to_chunk(s, chunk, block, lens):
-    args = long_memory(2, s, 256, seed=s)
+def test_the_scan_kernel_carries_its_state_from_chunk_to_chunk(s, chunk, block, lens, channels, n):
+    args = long_memory(2, s, channels, n=n, seed=s)
     lens = jnp.asarray(lens, jnp.int32)
     got, state = scan.selective_scan(**args, lens=lens, chunk=chunk, block=block)
     want, state_want = scan.selective_scan_recurrent(**args, lens=lens)
-    assert got.shape == (2, s, 256) and state.shape == (2, 256, 16) and state.dtype == jnp.float32
-    for row, n in enumerate(np.asarray(lens)):
-        assert np.abs(np.asarray(got[row, :n]) - np.asarray(want[row, :n])).max() < 5e-5, row
+    assert got.shape == (2, s, channels) and state.shape == (2, channels, n)
+    assert state.dtype == jnp.float32
+    for row, real in enumerate(np.asarray(lens)):
+        assert np.abs(np.asarray(got[row, :real]) - np.asarray(want[row, :real])).max(initial=0) < 5e-5, row
     # the state after ``lens - 1``, whatever stands behind it
     assert np.abs(np.asarray(state) - np.asarray(state_want)).max() < 5e-5
-    assert float(jnp.abs(state_want).max()) > 1.0  # a state worth carrying
+    if min(np.asarray(lens)) > 8:
+        assert float(jnp.abs(state_want).max()) > 1.0  # a state worth carrying
+    else:
+        assert not np.asarray(state[np.asarray(lens) == 0]).any()
+
+
+def test_a_state_that_is_no_whole_group_of_sublanes_is_refused():
+    args = long_memory(1, 16, 128, n=12)
+    with pytest.raises(ValueError, match="d_state 12"):
+        scan.selective_scan(**args, lens=jnp.asarray([16], jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_rows_of_a_trip_come_out_in_position_order(dtype):
+    """A state that forgets at once (exp(dt A) = 0), B = C = 1 and D = 0 leave
+    y[t, c] = n dt x[t, c]: with x[t] = t + 1 every position of a trip's eight
+    reads its own number, so a part stored to another position's rows, a tile
+    summed from the wrong sublanes or rows written in another order show as
+    another row's number and not as round-off."""
+    s, channels, n = 24, 256, 16
+    x = jnp.broadcast_to(jnp.arange(1.0, s + 1)[None, :, None], (1, s, channels))
+    x = x * (1.0 + jnp.arange(channels) % 3)[None, None, :]  # ... and every lane group's own
+    one = jnp.ones((1, s, n), dtype)
+    got, _ = scan.selective_scan(
+        x.astype(dtype), jnp.full((1, s, channels), jnp.log(jnp.expm1(1.0)), dtype),
+        jnp.zeros((channels,)), jnp.full((channels, n), -200.0), one, one, jnp.zeros((channels,)),
+        jnp.asarray([s], jnp.int32),
+    )
+    raw = np.asarray(jnp.log(jnp.expm1(1.0)).astype(dtype).astype(jnp.float32))
+    dt = np.log1p(np.exp(raw))  # softplus of what the storage dtype kept
+    want = n * dt * np.asarray(x[0])
+    assert np.abs(np.asarray(got[0], np.float32) / want - 1.0).max() < (1e-5 if dtype == jnp.float32 else 5e-3)
 
 
 def test_a_scan_that_lost_its_state_between_chunks_would_read_wrong():
@@ -752,14 +799,17 @@ def test_padding_behind_lens_leaves_the_state_and_a_decoded_token_goes_on_from_i
     assert np.abs(np.asarray(after) - np.asarray(state_further)).max() < 5e-5
 
 
-def test_the_scan_in_bfloat16_keeps_its_state_in_float32():
-    args = long_memory(1, 128, 128, seed=5)
+@pytest.mark.parametrize("s, channels, lens", [(128, 128, 128), (75, 640, 37)])
+def test_the_scan_in_bfloat16_keeps_its_state_in_float32(s, channels, lens):
+    args = long_memory(1, s, channels, seed=5)
     low = {k: (v.astype(jnp.bfloat16) if k in ("x", "dt_raw", "b", "c") else v) for k, v in args.items()}
-    lens = jnp.asarray([128], jnp.int32)
+    lens = jnp.asarray([lens], jnp.int32)
     got, state = scan.selective_scan(**low, lens=lens, chunk=32)
     want, state_want = scan.selective_scan_recurrent(
         **{k: v.astype(jnp.float32) for k, v in low.items()}, lens=lens
     )
+    real = int(lens[0])
     assert got.dtype == jnp.bfloat16 and state.dtype == jnp.float32
     assert np.abs(np.asarray(state) - np.asarray(state_want)).max() < 5e-5
-    assert np.abs(np.asarray(got, np.float32) - np.asarray(want)).max() < 0.1  # y's own rounding
+    # y's own rounding
+    assert np.abs(np.asarray(got[:, :real], np.float32) - np.asarray(want[:, :real])).max() < 0.1

@@ -27,7 +27,16 @@ program read through its latent cache).  Numbers compared:
                            every letter of a read behind such a token moves
                            together, far (0.2-0.5 against 0.01); a median
                            leaves those few reads out and reads what every
-                           read shares, the precision's rounding.
+                           read shares, the precision's rounding.  They are
+                           not always few: of a level's 24 reads 1-8 sit
+                           behind such a row, and twelve did in one run of
+                           the driver's (PR 48).
+                           So where the configuration gives a
+                           ``read_margin_floor`` and the reference says how
+                           firmly IT routed each read's own row
+                           (``read_logits_and_margins``), the median is over
+                           the reads routed by the floor or more
+                           (``firm_medians``): a rule on the reference alone.
   ``ballot_mismatches``    calls whose served letters, chosen key or
                            letter -> candidate map are not the ballot the
                            call's seed gives (a seed ignored, an order or a
@@ -69,6 +78,21 @@ def _centred(values) -> np.ndarray:
     return values - values.mean()
 
 
+def firm_medians(by_level: dict, margins: dict, floor: float, least: int) -> tuple:
+    """The reads that count and each level's median over them.  A read counts
+    when the REFERENCE routed its own row by ``floor`` or more: under that the
+    row is routed by rounding, in bf16 as often one way as the other, and the
+    read says which way and not how well.  A level that keeps fewer than
+    ``least`` such reads is read over all of its reads, as every level is at
+    floor 0 and under a reference that gives no margins: no level goes
+    unread."""
+    firm = {}
+    for which, values in by_level.items():
+        kept = [v for v, m in zip(values, margins.get(which, ())) if m >= floor]
+        firm[which] = kept if len(kept) >= least and which in margins else list(values)
+    return firm, {which: float(np.median(v)) for which, v in firm.items()}
+
+
 def run(config, cfg, state, picked, vectors, dry, cache_dir) -> dict:
     params = config["check"]
     setup_jax(cache_dir, dry)
@@ -105,30 +129,40 @@ def run(config, cfg, state, picked, vectors, dry, cache_dir) -> dict:
         tally_err = max(
             tally_err, float(np.abs(tally - np.asarray(kept["confidence"])).max())
         )
-    diffs, rotated, by_level = [], [], {}
+    diffs, rotated, by_level, margin_by_level = [], [], {}, {}
 
-    def compare(level: dict, read, which: str) -> None:
+    def compare(level: dict, read, which: str, margin: float) -> None:
         """level: {letter: served log-probability}; read: reference logits
-        over the alphabet."""
+        over the alphabet; margin: how firmly the reference routed the
+        read's own row (None from a reference that does not say)."""
         letters = sorted(level)
         got = _centred([level[letter] for letter in letters])
         want = _centred([read[ref.ALPHABET.index(letter)] for letter in letters])
         diffs.append(got - want)
         rotated.append(np.roll(got, 1) - want)
         by_level.setdefault(which, []).append(float(math.sqrt(np.mean(diffs[-1] ** 2))))
+        if margin is not None:
+            margin_by_level.setdefault(which, []).append(float(margin))
 
-    for (depth, served), read in zip(
-        plans, ref.read_logits(state, cfg, calls, letter_ids)
-    ):
+    if hasattr(ref, "read_logits_and_margins"):
+        reads, margins = ref.read_logits_and_margins(state, cfg, calls, letter_ids)
+    else:
+        reads = ref.read_logits(state, cfg, calls, letter_ids)
+        margins = [[None] * len(rows) for _, rows in calls]
+    for (depth, served), read, margin in zip(plans, reads, margins):
         if depth == 2:
-            compare(served["first"], read[0], "first")
+            compare(served["first"], read[0], "first", margin[0])
         compare(
-            {k: e["logprob"] for k, e in served["siblings"].items()}, read[-1], "last"
+            {k: e["logprob"] for k, e in served["siblings"].items()},
+            read[-1], "last", margin[-1],
         )
 
     flat = np.concatenate(diffs) if diffs else np.zeros(0)
     rms = float(math.sqrt(np.mean(flat**2))) if flat.size else float("inf")
-    medians = {which: float(np.median(v)) for which, v in by_level.items()}
+    firm, medians = firm_medians(
+        by_level, margin_by_level, float(params.get("read_margin_floor", 0.0)),
+        int(params.get("read_margin_min_reads", 1)),
+    )
     typical = max(medians.values()) if medians else float("inf")
     return {
         "numbers": [
@@ -144,7 +178,20 @@ def run(config, cfg, state, picked, vectors, dry, cache_dir) -> dict:
         "calls": len(calls),
         "worst_abs": float(np.abs(flat).max()) if flat.size else None,
         "read_rms_median_by_level": medians,
+        "reads_in_median": {which: len(v) for which, v in firm.items()},
+        "read_rms_median_all_reads": max(
+            (float(np.median(v)) for v in by_level.values()), default=None
+        ),
         "read_rms_max": max((max(v) for v in by_level.values()), default=None),
+        # not compared: every read's own root mean square, in the sample's
+        # order, so that a median that reads high shows which reads carry it
+        "read_rms_by_level": {
+            which: [float(f"{v:.3g}") for v in values] for which, values in by_level.items()
+        },
+        "read_margin_by_level": {
+            which: [float(f"{v:.3g}") for v in values]
+            for which, values in margin_by_level.items()
+        },
         # not compared: what ballot_logit_rms would read had every level's
         # log-probabilities come back one letter out of place
         "ballot_logit_rms_if_rotated": float(

@@ -13,9 +13,9 @@ here, read the same way with ONE rule more: an operation's scope is
 ``decode_step`` where that is anywhere on its path, else ``cross_decoder``
 where that is, else the innermost of ``SCOPES``; an operation with no path
 takes its one consumer's; what is left is ``unscoped``.  The same trace form,
-programs and kinds as ``scope_time``; the program has no ``lax.cond``, and the
-container ``cond`` is left out as ``trinity_scopes`` leaves it, should one
-appear, so that this table's shares add up to the program.
+programs, kinds and containers as ``scope_time`` (the program has no
+``lax.cond``; ``xplane.CONTAINERS`` leaves ``cond`` out should one appear), so
+that this table's shares add up to the program.
 
 ``qnext_scopes``' ``family_of`` and ``mfu`` are called as they are (the family
 has no experts: the counted pairs are none and count nothing), and so is
@@ -41,7 +41,6 @@ SCOPES = frozenset(
     )
 )
 WHOLE = ("decode_step", "cross_decoder")  # anywhere on a path, in this order
-CONTAINERS = (*xplane.CONTAINERS, "cond")
 # the six shares that are metrics; the rest of 100 (embedding, head reads,
 # the vote) is PERF.md's table, by scope
 GROUPS = {
@@ -95,7 +94,7 @@ def by_scope(trace: dict, prefixes: list):
         first, last = bisect.bisect_left(starts, lo), bisect.bisect_left(starts, hi)
         for index, _, dur in trace["ops"][first:last]:
             kind = xplane._op_key(trace["instructions"][index]["name"])
-            if kind in CONTAINERS:
+            if kind in xplane.CONTAINERS:
                 continue
             key = (scope[index], kind)
             out[key] = out.get(key, 0.0) + dur

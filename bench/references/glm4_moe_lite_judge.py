@@ -198,7 +198,9 @@ def _make_layer(cfg: dict):
         products over its own run of rows (``jax.lax.ragged_dot``)."""
         t = h.shape[0]
         score = jax.nn.sigmoid(h @ p["gate"].T)
-        _, chosen = jax.lax.top_k(score + p["bias"], k_top)
+        ranked, chosen = jax.lax.top_k(score + p["bias"], k_top + 1)
+        margin = ranked[:, k_top - 1] - ranked[:, k_top]  # last chosen - first left out
+        chosen = chosen[:, :k_top]
         weight = jnp.take_along_axis(score, chosen, axis=1)
         weight = weight / jnp.sum(weight, axis=1, keepdims=True) * scaling
         expert_of_pair = chosen.reshape(-1)
@@ -213,14 +215,17 @@ def _make_layer(cfg: dict):
                     p["e_down"])
         y = y * weight.reshape(-1)[order][:, None]
         routed = jnp.zeros_like(h).at[order // k_top].add(y)
-        return routed + _swiglu(h, p["s_gate"], p["s_up"], p["s_down"])
+        return routed + _swiglu(h, p["s_gate"], p["s_up"], p["s_down"]), margin
 
     @jax.jit
     def layer(x, p, mlp):
+        """-> x, and each row's router margin (inf where no router chose)."""
         x, h = attention(x, p)
         if "gate" in mlp:
-            return x + sparse(h, mlp)
-        return x + _swiglu(h, mlp["d_gate"], mlp["d_up"], mlp["d_down"])
+            out, margin = sparse(h, mlp)
+            return x + out, margin
+        out = _swiglu(h, mlp["d_gate"], mlp["d_up"], mlp["d_down"])
+        return x + out, jnp.full((x.shape[0],), jnp.inf, jnp.float32)
 
     @jax.jit
     def head(x, rows, norm, weight, ids):
@@ -244,11 +249,21 @@ def read_logits(state, cfg: dict, calls: list, letter_ids: list) -> list:
     to read.  Returns, per call, logits [len(rows), len(letter_ids)] at those
     positions for those token ids, float64 on the host.  Every call goes
     through a layer before the next layer's weights are read."""
+    return read_logits_and_margins(state, cfg, calls, letter_ids)[0]
+
+
+def read_logits_and_margins(state, cfg: dict, calls: list, letter_ids: list) -> tuple:
+    """``read_logits``, and beside each read how firmly THIS forward routed
+    the read's own row: per call [len(rows)], the least over the sparse layers
+    of (the last chosen expert's score + bias) - (the first left out's).  A
+    row whose margin is under what bf16 rounds away is routed by rounding, in
+    any implementation; the check says which reads it therefore leaves out of
+    its median, by this number alone and by nothing of the program."""
     import jax
     import jax.numpy as jnp
 
     if not calls:
-        return []
+        return [], []
     layer, head = _functions(cfg)
     experts = cfg["n_routed_experts"]
 
@@ -277,6 +292,7 @@ def read_logits(state, cfg: dict, calls: list, letter_ids: list) -> list:
             padded[: len(ids)] = ids
             xs.append(embed[jnp.asarray(padded)])
         del embed
+        margins = [np.full((len(rows),), np.inf) for _, rows in calls]
         for i in range(cfg["num_hidden_layers"]):
             base = f"model.layers.{i}"
             att = f"{base}.self_attn"
@@ -300,14 +316,17 @@ def read_logits(state, cfg: dict, calls: list, letter_ids: list) -> list:
                     **{f"e_{kind}": stacked(base, kind) for kind in ("gate", "up", "down")},
                     **swiglu_weights(f"{base}.mlp.shared_experts", "s"),
                 }
-            xs = [layer(x, p, mlp) for x in xs]
+            for j, (_, rows) in enumerate(calls):
+                xs[j], margin = layer(xs[j], p, mlp)
+                margins[j] = np.minimum(margins[j], np.asarray(margin, np.float64)[rows])
             del p, mlp
         norm, weight = f32("model.norm.weight"), f32("lm_head.weight")
         ids = jnp.asarray(np.asarray(letter_ids, np.int32))
-        return [
+        logits = [
             np.asarray(
                 head(x, jnp.asarray(np.asarray(rows, np.int32)), norm, weight, ids),
                 np.float64,
             )
             for x, (_, rows) in zip(xs, calls)
         ]
+    return logits, margins

@@ -138,3 +138,21 @@ def test_poisson_bursts_puts_whole_groups_at_fixed_times():
     assert a == b
     # a window too short for a burst is Poisson alone
     assert len(gen.arrival_times(m, 36, 10.0)) == 36
+
+
+@pytest.mark.parametrize("seed", [11, 2**31 + 29])
+def test_a_larger_pool_of_the_judge_panel_starts_with_the_smaller_one(seed):
+    """``judge_panel.generate`` draws its requests in order from one
+    generator, so a window's pool at 6.0 a second (PR 48) opens with the 200
+    requests the pool at 4.0 held: what the callers reached before, they
+    reach again, request for request."""
+    from generators import judge_panel
+
+    six = mix("n64-c8k.closed4")
+    assert six["pool_per_s"] == 6.0
+    before = judge_panel.generate({**six, "pool_per_s": 4.0}, seed, 50.0, 154_000)
+    after = judge_panel.generate(six, seed, 50.0, 154_000)
+    assert (len(before), len(after)) == (200, 300)
+    for a, b in zip(before, after):
+        assert (a["index"], a["caller"], a["turn"]) == (b["index"], b["caller"], b["turn"])
+        assert judge_panel.render_body(a) == judge_panel.render_body(b)

@@ -106,6 +106,54 @@ def test_the_reference_is_the_programs_forward_in_float32():
         assert np.abs(centred(got) - centred(want)).max() < 5e-6
 
 
+def test_the_reference_says_how_firmly_it_routed_each_read():
+    """With the router's weights nought every score is a half, so the bias
+    alone ranks the experts: a read's margin is then the least, over the
+    sparse layers, of the k-th largest bias less the next."""
+    _, _, config, cfg, _, _ = load_cell()
+    ref = byname.module("references", config["reference"])
+    state = checkpoints.make_state(config["family"], cfg, 2**31 + 11)
+    f32 = {k: np.asarray(v).astype(np.float32) for k, v in state.items()}
+    rng = np.random.default_rng(3)
+    calls = [
+        (rng.integers(32, cfg["vocab_size"], size=n).tolist(), [n - 1, n // 3])
+        for n in (90, 41)
+    ]
+    letters = list(range(4, 24))
+    reads, margins = ref.read_logits_and_margins(f32, cfg, calls, letters)
+    again = ref.read_logits(f32, cfg, calls, letters)
+    for read, same, margin, (_, rows) in zip(reads, again, margins, calls):
+        assert np.array_equal(read, same)
+        assert margin.shape == (len(rows),) and np.all(margin >= 0) and np.all(margin < 1)
+    assert len({float(m) for margin in margins for m in margin}) == 4  # a row's own
+    k, want = cfg["num_experts_per_tok"], np.inf
+    for name in f32:
+        if name.endswith("mlp.gate.weight"):
+            f32[name] = np.zeros_like(f32[name])
+        if name.endswith("e_score_correction_bias"):
+            ranked = np.sort(f32[name].astype(np.float64))[::-1]
+            want = min(want, ranked[k - 1] - ranked[k])
+    _, margins = ref.read_logits_and_margins(f32, cfg, calls, letters)
+    for margin in margins:
+        assert np.allclose(margin, want, rtol=0, atol=1e-7)
+
+
+def test_reads_the_reference_routed_by_rounding_are_left_out_of_the_median():
+    check = byname.module("checks", "judge_ballot_logit")
+    by_level = {"first": [0.3, 0.3, 0.3, 0.01], "last": [0.01, 0.02, 0.4, 0.03]}
+    margins = {"first": [1e-5, 2e-5, 3e-5, 0.01], "last": [0.01, 0.02, 1e-5, 0.03]}
+    firm, medians = check.firm_medians(by_level, margins, 0.0, 1)
+    assert firm == by_level and medians == {"first": 0.3, "last": 0.025}
+    firm, medians = check.firm_medians(by_level, margins, 1e-4, 1)
+    assert firm == {"first": [0.01], "last": [0.01, 0.02, 0.03]}
+    assert medians == {"first": 0.01, "last": 0.02}
+    # a level that keeps too few is read over all of its reads
+    firm, medians = check.firm_medians(by_level, margins, 1e-4, 2)
+    assert firm["first"] == by_level["first"] and medians == {"first": 0.3, "last": 0.02}
+    # and under a reference that gives no margin every read counts
+    assert check.firm_medians(by_level, {}, 1e-4, 2)[0] == by_level
+
+
 # -- the whole command ------------------------------------------------------------
 
 
@@ -305,7 +353,7 @@ def test_the_new_metrics_read_nothing_from_a_program_without_a_judge():
     after = {"phases": {"tokenize": {"sum_ms": 5.0, "count": 3}}}
     spec = json.load(open(os.path.join(BENCH, "layer_metrics", "experts.load_max_over_mean.json")))
     assert layers.read_metrics(spec["read"], before, after) is None
-    spec = json.load(open(os.path.join(BENCH, "layer_metrics", "batcher.tokenize_ms.judge.json")))
+    spec = json.load(open(os.path.join(BENCH, "layer_metrics", "batcher.tokenize_ms.closed.json")))
     assert layers.read_metrics(spec["read"], before, after) == 2.0
 
 

@@ -135,7 +135,7 @@ def test_every_call_fits_the_bucket(seed):
     gen = byname.module("generators", mix["generator"])
     tok = PUBLISHED["tokenizer"]
     requests = gen.generate(mix, seed, 50.0, PUBLISHED["vocab_size"] - tok["specials"])
-    assert len(requests) == 200  # 4.0 a second of window: far over what a window answers
+    assert len(requests) == 300  # 6.0 a second of window: far over what a window answers
     tokens = {gen.request_tokens(r, tok["overhead"]) for r in requests}
     assert tokens == {7525}  # 2 + 800 + 3 + 64 x 5 + 64 x 100: the same work a request
     assert max(tokens) <= PUBLISHED["max_tokens"] and max(tokens) > 0.9 * PUBLISHED["max_tokens"]

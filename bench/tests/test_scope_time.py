@@ -14,8 +14,15 @@ import struct
 
 import pytest
 
+import dots3_scopes
+import glm5_scopes
+import judge_scopes
 import layers
+import phi4flash_scopes
+import qnext_scopes
 import scope_time
+import trinity_scopes
+import xplane
 import xspace
 from reducers import (
     attention_roofline,
@@ -148,8 +155,9 @@ def made_up_trace():
     """Four executions of one program of 1000 ns; the middle two are kept.
     Each: mlp 400, attention kernel 200 with a 100 ns copy around it,
     projections 150, a prefetch without a path (50) whose one consumer is the
-    mlp, a copy without a path with two consumers (30), a layer norm 40, and
-    the ``while`` that spans them all."""
+    mlp, a copy without a path with two consumers (30), a layer norm 40, the
+    ``while`` that spans them all, and a ``cond`` (a ``lax.cond`` XLA left
+    alone) that spans two of them: the projections and the prefetch's start."""
     instructions = [
         ins("while.9", "jit(f)/jit(embed)/encoder_layers/while:"),
         ins("fusion.1", PATH + "mlp/dot_general:", ["copy-done.1", "copy.7"]),
@@ -162,6 +170,7 @@ def made_up_trace():
         ins("fusion.8", PATH + "mlp_ln/div:", ["fusion.1"]),
         # the same name in ANOTHER program has its own consumers
         ins("copy.7", None, ["x"], program="2"),
+        ins("cond.5", PATH + "mlp/cond:"),
     ]
     durations = [(0, 1000), (1, 400), (2, 200), (3, 100), (4, 150), (5, 20),
                  (6, 30), (7, 30), (8, 40)]
@@ -174,6 +183,8 @@ def made_up_trace():
             if index == 0:
                 ops.append([0, base, 1000])
                 continue
+            if index == 4:
+                ops.append([10, at, 170])  # the cond over its branch: 150 + 20
             ops.append([index, at, dur])
             at += dur
     modules.append(["jit_helper(5)", 50_000, 77])
@@ -188,6 +199,7 @@ def test_time_by_scope_on_a_made_up_trace():
         "mlp", "mlp",  # the prefetch inherits through copy-done from the mlp
         "unscoped",  # two consumers: not guessed
         "mlp_ln", "unscoped",
+        "mlp",  # the cond has a path of its own; its TIME is its branch's
     ]
     assert scope_time.programs(trace, PREFIXES) == [(10_000, 11_000), (20_000, 21_000)]
     table, program_ns = scope_time.by_scope(trace, PREFIXES)
@@ -197,7 +209,7 @@ def test_time_by_scope_on_a_made_up_trace():
         ("fused_attention", "fused_attention_tiled"): 400,
         ("fused_attention", "copy"): 200, ("qkv_proj", "fusion"): 300,
         ("unscoped", "copy"): 60, ("mlp_ln", "fusion"): 80,
-    }  # the while is left out: its body's operations are on the line too
+    }  # the while and the cond are left out: their bodies' operations are on the line too
     ctx = {"scoped": trace, "config": {"trace_modules": PREFIXES}}
     shares = {
         "attention": forward_share_attention.reduce(ctx),
@@ -212,6 +224,39 @@ def test_time_by_scope_on_a_made_up_trace():
     assert "mlp_ln                   4.000%" in text
     assert "(between operations)     3.000%" in text
     assert scope_time.kernel_ns(trace, PREFIXES, attention_roofline.KERNELS) == 400
+
+
+@pytest.mark.parametrize(
+    "table",
+    [judge_scopes, qnext_scopes, glm5_scopes, dots3_scopes, trinity_scopes, phi4flash_scopes],
+    ids=lambda t: t.__name__,
+)
+def test_a_cond_is_a_container_under_every_judge_s_table(table):
+    """The second, third and fourth judges' tables had no word for ``cond``
+    (PERF.md, question 27), the fifth and sixth a tuple of their own; now one
+    list serves all: the conditional's own event spans its branch's operations
+    and is counted with none of them, whatever a table calls their scopes."""
+    trace = made_up_trace()
+    got, program_ns = table.by_scope(trace, PREFIXES)
+    assert program_ns == 2000
+    assert not any(kind == "cond" for _, kind in got)
+    assert sum(got.values()) == 1940  # 970 a program: the 30 between operations left
+    without = dict(trace, ops=[op for op in trace["ops"] if op[0] != 10])
+    assert table.by_scope(without, PREFIXES) == (got, program_ns)
+
+
+def test_a_cond_is_not_among_the_device_operations():
+    """``xplane.busy``: the union of the spans is the same with the
+    conditional's event or without it, and ``device_ops`` does not name it."""
+    trace = made_up_trace()
+    names = [ins["name"] for ins in trace["instructions"]]
+    ops = [(f"%{names[i]} = f32[] x()", start, dur, {}) for i, start, dur in trace["ops"]]
+    device = {"name": "/device:TPU:0", "ops": ops, "modules": [], "lines": []}
+    out = xplane.busy({"devices": [device], "host": []})
+    assert "cond" not in [kind for kind, _ in out["device_ops"]]
+    assert dict(out["device_ops"])["fusion"] == pytest.approx(4 * (400 + 150 + 40) * 1e-9)
+    device["ops"] = [op for op in ops if not op[0].startswith("%cond")]
+    assert xplane.busy({"devices": [device], "host": []})["busy_s"] == out["busy_s"]
 
 
 def test_a_trace_without_paths_or_programs_gives_nothing():
@@ -319,12 +364,23 @@ def test_data_only_metrics_on_two_made_up_documents(name, expected):
     assert layers.read_metrics(spec, {}, {"phases": {}, "jit": {"aot_buckets": 9}}) is None
 
 
+PR24 = (
+    "edge.parse_ms", "edge.respond_ms", "batcher.tokenize_ms", "dispatch.stage_ms",
+    "dispatch.finalize_ms", "jit.compiles_in_window", "device.idle_with_work_share",
+    *(f"forward.share.{g}.{loop}" for g in ("attention", "projections", "mlp", "unscoped")
+      for loop in ("open", "closed")),
+    "kernel.attention_roofline.open", "kernel.attention_roofline.closed",
+)
+
+
 def test_every_new_metric_has_its_file_its_cell_and_one_end_to_end_metric():
+    """PR 24's seventeen, by name and not by their place in the list: cells
+    and metrics have come since (PERF.md, question 21)."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    steady, closed = (w["name"] for w in bench["workloads"])
-    new = bench["per_layer"][8:]
-    assert len(new) == 17
+    steady, closed = (w["name"] for w in bench["workloads"][:2])
+    new = [m for m in bench["per_layer"] if m["name"] in PR24]
+    assert len(new) == len(PR24) == 17
     for metric in new:
         path = os.path.join(HERE, "..", "layer_metrics", metric["name"] + ".json")
         with open(path) as f:

@@ -351,10 +351,12 @@ BAND, CAUSAL = 4 * 3 * 58_722_304, 4 * 3 * 134_225_920
 COUNTED = (
     {"dispatches": 5, "expert_pairs_here": 100_000, "expert_pairs_routed": 800_000,
      "window_keys_band": BAND, "window_keys_causal": CAUSAL,
-     "expert_load_max_over_mean_sum": 10.0},
+     "expert_load_max_over_mean_sum": 10.0,
+     "expert_tiles_laid": 5 * 384, "expert_tiles_in_use": 5 * 120},
     {"dispatches": 8, "expert_pairs_here": 400_000, "expert_pairs_routed": 3_159_296,
      "window_keys_band": 4 * BAND, "window_keys_causal": 4 * CAUSAL,
-     "expert_load_max_over_mean_sum": 19.0},
+     "expert_load_max_over_mean_sum": 19.0,
+     "expert_tiles_laid": 8 * 384, "expert_tiles_in_use": 5 * 120 + 3 * 124},
 )
 
 
@@ -408,7 +410,9 @@ def test_the_counters_give_the_shares():
 
     assert read("window.band_share.trinity") == pytest.approx(43.749, abs=1e-3)
     assert read("experts.held_pairs_share.trinity") == pytest.approx(100 * 300_000 / 2_359_296)
-    assert read("experts.load_max_over_mean.trinity") == pytest.approx(3.0)
+    assert read("experts.load_max_over_mean") == pytest.approx(3.0)
+    # of the 384 row tiles a dispatch's four sparse layers lay, those that hold a pair
+    assert read("experts.tiles_in_use_share") == pytest.approx(100 * 124 / 384)
 
 
 def test_another_judge_s_program_gives_nothing_to_read():

@@ -8,12 +8,11 @@ before the sum).  The other four tables are fixed sets (PERF.md, question 24),
 so the table is here, read the same way: an operation's scope is
 ``decode_step`` where that is anywhere on its path, else the innermost of
 ``SCOPES``; an operation with no path takes its one consumer's; what is left
-is ``unscoped``.  The same trace form, programs and kinds as ``scope_time``,
-and ONE container more: the held experts' ``lax.cond`` reaches the trace as an
-operation named ``cond`` that spans its branch's own events
-(``xplane.CONTAINERS`` knows ``conditional``: PERF.md, question 27, where the
-other held judges' ``unscoped`` holds the branch's time a second time); left
-out here, so that this table's shares add up to the program.
+is ``unscoped``.  The same trace form, programs, kinds and containers as
+``scope_time``: the held experts' ``lax.cond`` reaches the trace as an
+operation named ``cond`` that spans its branch's own events, and
+``xplane.CONTAINERS`` leaves it out (since PR 48, in every table), so that
+this table's shares add up to the program.
 
 The experts' operations in every share of a peak come from the program's
 counter of the pairs that reached an expert held here: ``qnext_scopes``'
@@ -39,7 +38,6 @@ SCOPES = frozenset(
         "head_read", "decode_step", "ballot_vote",
     )
 )
-CONTAINERS = (*xplane.CONTAINERS, "cond")
 # the six shares that are metrics; the rest of 100 (embedding, head reads,
 # the vote) is PERF.md's table, by scope
 GROUPS = {
@@ -92,7 +90,7 @@ def by_scope(trace: dict, prefixes: list):
         first, last = bisect.bisect_left(starts, lo), bisect.bisect_left(starts, hi)
         for index, _, dur in trace["ops"][first:last]:
             kind = xplane._op_key(trace["instructions"][index]["name"])
-            if kind in CONTAINERS:
+            if kind in xplane.CONTAINERS:
                 continue
             key = (scope[index], kind)
             out[key] = out.get(key, 0.0) + dur

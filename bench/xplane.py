@@ -79,8 +79,9 @@ def read(path: str) -> dict:
 
 
 # control flow whose event spans the events of its own body (the layer scan
-# is one ``while``): left out of the per-operation sums, or they count twice
-CONTAINERS = ("while", "conditional", "call")
+# is one ``while``; a ``lax.cond`` that XLA leaves alone keeps the name
+# ``cond``): left out of the per-operation sums, or they count twice
+CONTAINERS = ("while", "conditional", "call", "cond")
 
 
 def _op_key(name: str) -> str:

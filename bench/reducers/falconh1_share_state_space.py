@@ -1,0 +1,8 @@
+"""forward.share.state_space.falconh1: per cent of the judge programs' device time under
+the ``state_space`` scopes (``falconh1_scopes.GROUPS``)."""
+
+import falconh1_scopes
+
+
+def reduce(ctx):
+    return falconh1_scopes.share(ctx, "state_space")

@@ -712,3 +712,95 @@ PHI4FLASH_TEST_TINY = Phi4FlashConfig(
     intermediate_size=128,
     sliding_window=8,
 )
+
+
+@dataclass(frozen=True)
+class FalconH1Config:
+    """A decoder whose every block runs a Mamba-2 (SSD) mixer and grouped-query
+    attention SIDE BY SIDE on one normed input and sums them, then a SwiGLU
+    MLP (``model_type`` ``falcon_h1``, tiiuae/Falcon-H1-34B-Instruct): a judge
+    behind ``POST /consensus`` ``scorer: judge`` (models/falcon_h1.py).  Every
+    layer is the same kind.  Every product stands behind one of the published
+    µP multipliers, each a key of the configuration: the embedding's, the
+    head's, the attention's input, output and keys', the mixer's input, output
+    and the five of its input product's sections (z, x, B, C, dt), the MLP's
+    gate and output.
+
+    The mixer is ``ssm_heads`` heads of ``ssm_head_dim`` (``d_ssm`` wide: where
+    the configuration gives ``mamba_d_ssm`` its ``mamba_expand`` does not
+    apply) with ONE decay a head, B and C of ``d_state`` shared by the heads
+    of each of ``ssm_groups`` groups, a causal convolution of ``d_conv`` taps
+    a channel over [x | B | C], and an RMSNorm over the gated scan output in
+    ``ssm_groups`` groups (``mamba_rms_norm`` true, ``norm_before_gate``
+    false).  The attention turns all ``head_dim`` dims of every head
+    (``rope_theta`` 1e11), a key head serving ``num_heads / num_kv_heads``
+    query heads, no bias anywhere."""
+
+    vocab_size: int = 261120
+    hidden_size: int = 5120
+    num_layers: int = 72
+    num_heads: int = 20
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 21504
+    rope_theta: float = 1e11
+    rms_norm_eps: float = 1e-5
+    d_ssm: int = 4096
+    d_state: int = 256
+    d_conv: int = 4
+    ssm_heads: int = 32
+    ssm_groups: int = 2
+    embedding_multiplier: float = 5.656854249492381
+    lm_head_multiplier: float = 0.0078125
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 0.0375
+    key_multiplier: float = 0.011048543456039804
+    ssm_in_multiplier: float = 0.25
+    ssm_out_multiplier: float = 0.08838834764831845
+    # over the input product's sections [z | x | B | C | dt]
+    ssm_multipliers: tuple = (
+        0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738,
+    )
+    mlp_multipliers: tuple = (0.1767766952966369, 0.011160714285714284)  # gate, down
+    # "int8": every dense product (the mixer's input and output products, q, k,
+    # v, o, the MLP's three) through quant.dense_int8; embedding, head, norms,
+    # the convolution and the scan's own parameters keep the parameters' dtype
+    quantize: str = "none"
+
+    @property
+    def ssm_head_dim(self) -> int:
+        return self.d_ssm // self.ssm_heads
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.d_ssm + 2 * self.ssm_groups * self.d_state
+
+
+# tiiuae/Falcon-H1-34B-Instruct config.json (``falcon_h1``)
+FALCON_H1_34B_INSTRUCT = FalconH1Config()
+# three query heads a key head and three mixer heads a group (neither a power of
+# two: a head that read group j % 2 would read the wrong B and C), every
+# multiplier a value of its own so that one dropped or swapped shows
+FALCON_H1_TEST_TINY = FalconH1Config(
+    vocab_size=512,
+    hidden_size=64,
+    num_layers=2,
+    num_heads=6,
+    num_kv_heads=2,
+    head_dim=16,
+    intermediate_size=128,
+    d_ssm=48,
+    d_state=16,
+    ssm_heads=6,
+    ssm_groups=2,
+    embedding_multiplier=1.7,
+    lm_head_multiplier=0.6,
+    attention_in_multiplier=1.3,
+    attention_out_multiplier=0.45,
+    key_multiplier=0.8,
+    ssm_in_multiplier=0.75,
+    ssm_out_multiplier=0.55,
+    ssm_multipliers=(0.9, 1.2, 0.7, 1.4, 0.65),
+    mlp_multipliers=(0.85, 0.35),
+)

@@ -8,7 +8,7 @@ is a few calls of one causal decoder over the same candidates under
 differently seeded ballots, and what an upstream judge's ``top_logprobs``
 would have carried is read from the decoder's own head.
 
-Six decoders serve (``JUDGE_PRESETS``; the preset's configuration class
+Seven decoders serve (``JUDGE_PRESETS``; the preset's configuration class
 says which module): ``models/glm_moe.py`` runs ``glm-4.7-flash`` (latent
 attention, every expert held), ``glm-5.2`` (a learned sparse selection in
 front of latent attention, a share of the router's experts held) and
@@ -25,17 +25,21 @@ values beside the whole-length one; a share held), and ``models/sambay.py`` the
 sixth, ``phi-4-mini-flash-reasoning`` (a decoder that feeds a decoder: Mamba
 layers and differential attention over a window, one full layer whose keys
 eight layers read, gated memory units; no experts; its last layers run at the
-row the panel reads and nowhere else).  The panel's protocol is no
-part of any: ``judge_panel`` below is ONE jitted program over what a decoder
+row the panel reads and nowhere else), and ``models/falcon_h1.py`` the seventh,
+``falcon-h1-34b-instruct`` (a Mamba-2 (SSD) mixer and grouped-query attention
+side by side in every block, every product behind a published µP multiplier;
+no experts; every LAYER keeps two kinds of cache at once).  The panel's
+protocol is no part of any: ``judge_panel`` below is ONE jitted program over what a decoder
 module gives,
 
   ``prefill(params, ids, config, lens=, tallies=)``  -> hidden [b, s, h] (or
       [b, 1, h]: the row at ``lens - 1`` alone, from a decoder that computed
-      no other), a cache a layer (of whatever kind the layer keeps), pairs
-      routed a sparse layer; what else it counted on the device goes into the
+      no other), a cache a layer (of whatever kind, or kinds, the layer keeps),
+      pairs routed a sparse layer; what else it counted on the device goes into the
       ``tallies`` dict by name (``index_keys``: pairs chosen and causal pairs;
       ``window_keys``: pairs inside the sliding layers' bands and causal pairs;
-      ``layer_positions``: (position, layer) pairs computed, and layers x slots)
+      ``layer_positions``: (position, layer) pairs computed, and layers x slots;
+      ``state_positions``: positions that moved a scan state, and layers x slots)
   ``decode_step(params, token, lens, caches, config)``  -> hidden [b, h]
   ``head_logprobs(params, hidden, config)``  -> [b, vocabulary] float32
 
@@ -83,11 +87,12 @@ from ..ballot.prompting import ballot_instruction
 from ..ballot.tree import ALPHABET, PrefixTree
 from ..ops.votes import softmax_votes
 from . import dispatch_seam as _seam
-from . import afmoe, glm_moe, qwen3_next, sambay
+from . import afmoe, falcon_h1, glm_moe, qwen3_next, sambay
 from .configs import (
-    AFMOE_TEST_TINY, DOTS3_NOTE_PREV, DOTS3_TEST_TINY, GLM_4_7_FLASH, GLM_5_2,
-    GLM_DSA_TEST_TINY, GLM_TEST_TINY, PHI4FLASH_TEST_TINY, PHI_4_MINI_FLASH_REASONING,
-    QWEN3_NEXT_80B_A3B, QWEN3_NEXT_TEST_TINY, TRINITY_LARGE_PREVIEW, AfmoeConfig,
+    AFMOE_TEST_TINY, DOTS3_NOTE_PREV, DOTS3_TEST_TINY, FALCON_H1_34B_INSTRUCT,
+    FALCON_H1_TEST_TINY, GLM_4_7_FLASH, GLM_5_2, GLM_DSA_TEST_TINY, GLM_TEST_TINY,
+    PHI4FLASH_TEST_TINY, PHI_4_MINI_FLASH_REASONING, QWEN3_NEXT_80B_A3B,
+    QWEN3_NEXT_TEST_TINY, TRINITY_LARGE_PREVIEW, AfmoeConfig, FalconH1Config,
     GlmMoeLiteConfig, Phi4FlashConfig, Qwen3NextConfig,
 )
 from .tokenizer import BaseTokenizer, load_tokenizer
@@ -105,12 +110,22 @@ JUDGE_PRESETS = {
     "afmoe-test-tiny": AFMOE_TEST_TINY,
     "phi-4-mini-flash-reasoning": PHI_4_MINI_FLASH_REASONING,
     "phi4flash-test-tiny": PHI4FLASH_TEST_TINY,
+    "falcon-h1-34b-instruct": FALCON_H1_34B_INSTRUCT,
+    "falcon-h1-test-tiny": FALCON_H1_TEST_TINY,
 }
 _DECODERS = {
     GlmMoeLiteConfig: glm_moe, Qwen3NextConfig: qwen3_next, AfmoeConfig: afmoe,
-    Phi4FlashConfig: sambay,
+    Phi4FlashConfig: sambay, FalconH1Config: falcon_h1,
 }
 DEFAULT_PANEL = ((0, 1.0), (1, 1.0), (2, 1.0))  # (ballot seed, weight) a call
+# a pair a decoder's ``prefill`` counted on the device (``tallies``) -> the two
+# counters of /metrics ``judge`` it is summed into, dispatch by dispatch
+_TALLIES = {
+    "index_keys": ("index_keys_selected", "index_keys_causal"),
+    "window_keys": ("window_keys_band", "window_keys_causal"),
+    "layer_positions": ("layer_positions_run", "layer_positions_whole"),
+    "state_positions": ("state_positions_moved", "state_positions_whole"),
+}
 MAX_PANEL = 8
 _LETTERS = len(ALPHABET)
 
@@ -287,6 +302,11 @@ class TpuJudge:
             # alone), and layers x slots
             "layer_positions_run": 0,
             "layer_positions_whole": 0,
+            # positions, summed over dispatches and the layers that keep a scan
+            # state: those that moved it (a padded slot moves none), and
+            # layers x slots
+            "state_positions_moved": 0,
+            "state_positions_whole": 0,
             "expert_tokens": [0] * self.decoder.experts_held(params, self.config),
         }
         self._held = len(self._stats["expert_tokens"])
@@ -417,15 +437,12 @@ class TpuJudge:
             ballots.append(entry)
         confidence = tally / sum(call.weight for call in prepared.calls)
         self._count(
-            prepared, np.asarray(out["expert_load"]), out.get("index_keys"),
-            out.get("window_keys"), out.get("layer_positions"),
+            prepared, np.asarray(out["expert_load"]),
+            {name: out[name] for name in _TALLIES if name in out},
         )
         return confidence, prepared.tokens, ballots
 
-    def _count(
-        self, prepared: PreparedPanel, load, index_keys=None, window_keys=None,
-        layer_positions=None,
-    ) -> None:
+    def _count(self, prepared: PreparedPanel, load, tallies: dict) -> None:
         # a decoder that holds a share of its router's experts counts, after
         # the held ones, the pairs routed elsewhere
         whole_bound = self.decoder.whole_bound_layers(load, self.config)
@@ -448,18 +465,9 @@ class TpuJudge:
             s["expert_layers_whole_bound"] += whole_bound
             s["expert_tiles_laid"] += tiles_laid
             s["expert_tiles_in_use"] += tiles_in_use
-            if index_keys is not None:
-                selected, causal = np.asarray(index_keys)
-                s["index_keys_selected"] += int(selected)
-                s["index_keys_causal"] += int(causal)
-            if window_keys is not None:
-                band, causal = np.asarray(window_keys)
-                s["window_keys_band"] += int(band)
-                s["window_keys_causal"] += int(causal)
-            if layer_positions is not None:
-                run, whole = np.asarray(layer_positions)
-                s["layer_positions_run"] += int(run)
-                s["layer_positions_whole"] += int(whole)
+            for name, pair in tallies.items():
+                for key, counted in zip(_TALLIES[name], np.asarray(pair)):
+                    s[key] += int(counted)
             if load.size:
                 totals = load.sum(axis=0)
                 s["expert_pairs_here"] += int(totals.sum())

@@ -78,7 +78,7 @@ TPU additions:
   decoder serving ``POST /consensus {"scorer": "judge"}``: a LOCAL judge
   panel, each call a prefill of the candidates under a seeded prefix-tree
   ballot, one decoded key letter and a masked read of its siblings'
-  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of six
+  log-probabilities (models/judge.py).  ``JUDGE_MODEL`` names one of seven
   decoders: ``glm-4.7-flash`` (latent attention over every causal key,
   every expert held; models/glm_moe.py), ``glm-5.2`` (the same module under
   a configuration with an indexer: a learned sparse selection, the 2048
@@ -97,16 +97,22 @@ TPU additions:
   heads: sliding layers that turn their heads and attend the 4096 keys up
   to the query, full layers that turn nothing; an elementwise gate, a norm
   behind each branch; a sliding layer caches its window's keys and values
-  only; models/afmoe.py) or ``phi-4-mini-flash-reasoning`` (a decoder that
+  only; models/afmoe.py), ``phi-4-mini-flash-reasoning`` (a decoder that
   feeds a decoder, no experts, whole on one chip: Mamba layers and
   differential attention over the 512 keys up to the query, one full layer
   whose keys and values are cached once for the seven cross layers behind
   it, gated memory units on the last Mamba layer's scan; a Mamba layer
   caches its state and its convolution's tail, a sliding layer its window;
   the layers behind the full one run at the row the panel reads and nowhere
-  else; models/sambay.py); each has a tiny twin for tests
+  else; models/sambay.py) or ``falcon-h1-34b-instruct`` (a Mamba-2 (SSD)
+  mixer and grouped-query attention, 20 heads on 4 key heads, side by side
+  on one normed input in every block, every product behind a published µP
+  multiplier, no experts; every layer caches its keys and values AND its
+  mixer's convolution tail and scan state; models/falcon_h1.py); each has a
+  tiny twin for tests
   (``glm-test-tiny``, ``glm-dsa-test-tiny``, ``dots3-test-tiny``,
-  ``qwen3-next-test-tiny``, ``afmoe-test-tiny``, ``phi4flash-test-tiny``).
+  ``qwen3-next-test-tiny``, ``afmoe-test-tiny``, ``phi4flash-test-tiny``,
+  ``falcon-h1-test-tiny``).
   ``JUDGE_WEIGHTS`` is an HF checkpoint, one ``model.safetensors`` or
   sharded; what is served is what it names: its layers from 0 up (and of
   each whether it is dense or sparse and whether it owns an indexer, which
@@ -116,8 +122,8 @@ TPU additions:
   layer's kind is the preset's ``layer_types`` entry of that number),
   experts 0..E-1 of a wider router (one
   chip's share of a layer's experts: the pairs routed elsewhere are left
-  out of this chip's partial sum) and, for the ``glm``, ``afmoe`` and
-  ``phi4flash`` decoders, the rows
+  out of this chip's partial sum) and, for the ``glm``, ``afmoe``,
+  ``phi4flash`` and ``falcon_h1`` decoders, the rows
   of the vocabulary its embedding holds (a slice is a smaller vocabulary).
   ``JUDGE_MAX_TOKENS`` (default 8192) is the ONE sequence bucket every
   call is padded to.  ``JUDGE_QUANTIZE=int8`` runs the dense products

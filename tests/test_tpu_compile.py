@@ -745,3 +745,49 @@ def test_the_sixth_judge_s_panel_compiles_whole_and_fits_the_chip(one_chip, monk
     assert weights == 7_975_411_200 and memory.argument_size_in_bytes >= weights
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 0.25 * 16e9 < held < 12.0e9, held
+
+
+def test_the_seventh_judge_s_panel_compiles_at_six_layers_and_fits_the_chip(one_chip, monkeypatch):
+    """``judge_panel`` for ``falcon-h1-34b-instruct`` as the benchmark serves it
+    (published layers 0..5 of 72, the whole vocabulary of 261,120 rows and the
+    untied head, NO width cut, bf16) over a panel of 3 x 8192 slots at depth 2:
+    Mosaic takes the chunked state-space dual kernel at 32 heads of 128 on 2
+    groups of 256 states (sixteen heads a grid step, the state in VMEM) and the
+    causal kernel at five query heads a key head, both in ONE program, six of
+    each.  THE RULE OF THE CUT (ISSUE 49): six layers ship if the compiler's
+    count of the device's memory (arguments and temporaries) is at most 14.0
+    GB, else five, else four; the vocabulary is not sliced to buy depth.  A
+    count of the compiler's, not a reading of the chip."""
+    from llm_weighted_consensus_tpu.models import falcon_h1, judge
+    from llm_weighted_consensus_tpu.ops import causal_attention, rotary, ssd
+
+    for module in (causal_attention, rotary, ssd):
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    # a kernel's jit traced at these very shapes off the chip (interpreted) must not be met again
+    jax.clear_caches()
+    preset = replace(configs.FALCON_H1_34B_INSTRUCT, num_layers=6)
+    shapes = jax.eval_shape(
+        lambda: falcon_h1.init_params(jax.random.PRNGKey(0), preset, dtype=jnp.bfloat16)
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), shapes)
+    b, s, letters = 3, 8192, 20
+    compiled = judge.judge_panel.lower(
+        params, arg((b, s), jnp.int32), arg((b,), jnp.int32), arg((letters,), jnp.int32),
+        arg((b, letters), jnp.bool_), arg((b, letters, letters), jnp.bool_),
+        decoder=falcon_h1, config=preset, depth=2,
+    ).compile()
+    text = compiled.as_text()
+    for name, count in (("ssd_chunked", 6), ("causal_attention_blockwise", 6)):
+        calls = re.findall(rf"^\s*(?:ROOT )?%?{name}[\w.]* = .*custom-call\(", text, re.M)
+        assert len(calls) == count, (name, len(calls))
+    memory = compiled.memory_analysis()
+    weights = sum(a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(shapes))
+    # the checkpoint's 10,509,188,224, A_log in float32 (32 a layer, two bytes more each)
+    assert weights == 10_509_188_224 + 6 * 32 * 2 and memory.argument_size_in_bytes >= weights
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 10,509,338,112 of arguments + 2,134,012,928 of temporaries = 12.64 GB when this was written
+    assert 0.25 * 16e9 < held <= 14.0e9, held
